@@ -16,9 +16,12 @@ import jax
 
 if os.environ.get("QUDA_TPU_FORCE_CPU"):
     jax.config.update("jax_platforms", "cpu")
-# the C ABI speaks double; without x64 complex128 silently degrades to c64
-if jax.config.jax_platforms in ("cpu", None) or os.environ.get(
-        "QUDA_TPU_FORCE_CPU"):
+# the C ABI speaks double; without x64 complex128 silently degrades to
+# c64.  Only where double exists: on a TPU backend (jax_platforms unset
+# is exactly the chip machine's case) x64 makes Mosaic refuse every
+# pallas kernel (i64 index maps fail to legalise, the dslash lowering
+# recurses out), so the default backend decides, not the config string.
+if jax.default_backend() != "tpu":
     jax.config.update("jax_enable_x64", True)
 
 from ..fields.geometry import LatticeGeometry

@@ -602,7 +602,6 @@ class DiracStaggeredPCPairs:
         anything resolve_axis_policies accepts."""
         from jax.sharding import PartitionSpec as P
 
-        from ..parallel import compat
         from ..parallel.pallas_dslash import (
             dslash_staggered_eo_pallas_sharded,
             dslash_staggered_eo_pallas_sharded_v3)
@@ -627,14 +626,15 @@ class DiracStaggeredPCPairs:
                     policy=policy).astype(odt)
         n_g = 4 if improved else 2
         if improved:
-            fn = compat.shard_map(
+            fn = jax.shard_map(
                 local, mesh=self._mesh,
-                in_specs=(gspec,) * n_g + (pspec,), out_specs=pspec)
+                in_specs=(gspec,) * n_g + (pspec,), out_specs=pspec,
+                check_vma=False)
         else:
-            fn = compat.shard_map(
+            fn = jax.shard_map(
                 lambda fh, fb, psi: local(fh, fb, None, None, psi),
                 mesh=self._mesh, in_specs=(gspec, gspec, pspec),
-                out_specs=pspec)
+                out_specs=pspec, check_vma=False)
         return jax.jit(fn)
 
     def _sharded_args(self, target_parity):

@@ -664,7 +664,6 @@ class _PackedHopMixin:
         spec string, or {axis: policy} dict)."""
         from jax.sharding import PartitionSpec as P
 
-        from ..parallel import compat
         from ..parallel.pallas_dslash import (dslash_eo_pallas_sharded,
                                               dslash_eo_pallas_sharded_v3)
         pspec = P(None, None, None, "t", "z", ("y", "x"))
@@ -683,9 +682,9 @@ class _PackedHopMixin:
                     self._mesh, interpret=self._pallas_interpret,
                     out_dtype=out_dtype, tb_sign=self._tb_sign,
                     policy=policy)
-        return jax.jit(compat.shard_map(
+        return jax.jit(jax.shard_map(
             local, mesh=self._mesh, in_specs=(gspec, gspec, pspec),
-            out_specs=pspec))
+            out_specs=pspec, check_vma=False))
 
     def _resolve_sharded_policy(self, target_parity, out_dtype):
         """The PER-AXIS policy engine (round 18): a pinned policy (bare
@@ -1070,10 +1069,9 @@ class DiracWilsonPCPacked:
         """Pair-storage companion at an arbitrary storage dtype.
 
         With f32 storage this is the PRECISE operator in a fully
-        complex-free representation — required end-to-end on TPU
-        runtimes that cannot execute complex64 (see bench.py), and the
-        native-order analog of QUDA keeping solver fields in float2/
-        float4 orders (no complex type on the device either).
+        complex-free representation — what the pallas kernels consume,
+        and the native-order analog of QUDA keeping solver fields in
+        float2/float4 orders (no complex type on the device either).
         ``use_pallas`` swaps the stencil for the hand-tuned pallas eo
         kernel; ``pallas_version`` 2 (the measured single-chip winner,
         PERF.md round 5 — the env default) uses the gather kernel with

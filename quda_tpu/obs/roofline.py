@@ -6,17 +6,14 @@ profiler tsv, lib/tune.cpp:528-610).  PERF.md rounds 2-8 derived those
 numbers BY HAND from ad-hoc bench prints; this module is the single
 home for (a) the per-site flops/bytes models of every kernel form and
 (b) the arithmetic joining them with measured seconds into
-achieved-GFLOPS / achieved-BW / %-of-demonstrated-peak rows — the bench
+achieved-GFLOPS / achieved-BW / %-of-published-peak rows — the bench
 harness and the API solves consume these helpers instead of private
 math, so a model update lands everywhere at once.
 
-Demonstrated peaks (NOT theoretical): the best single-chip numbers this
-codebase has measured (PERF.md round 5, TPU v5 lite, 24^4 Wilson v2
-f32): 5,673 GFLOPS kernel rate and ~4.8 TB/s effective bandwidth.  The
-percent-of-peak columns answer "how much of what this hardware has
-already demonstrated does this measurement reach" — on other platforms
-(CPU CI) they are still computed but meaningless, and callers should
-gate on platform before quoting them.
+Peaks are the PUBLISHED per-chip figures of the device that is present
+(:data:`DEVICE_PEAKS`, keyed by ``device_kind``).  A TPU whose kind is
+not in the table is an error, never a default; off-TPU (CPU CI) there
+is no peak and the percent-of-peak columns are None.
 """
 
 from __future__ import annotations
@@ -24,9 +21,32 @@ from __future__ import annotations
 import threading
 from typing import Dict, List, Optional
 
-# best demonstrated single-chip rates (PERF.md round 5 measurement)
-DEMONSTRATED_PEAK_GFLOPS = 5673.0
-DEMONSTRATED_PEAK_GBPS = 4800.0
+# Published per-chip peaks, keyed by jax's ``device_kind``.
+# TPU v5e: 197 TFLOP/s bf16, 819 GB/s HBM (Google Cloud documentation,
+# "TPU v5e").  The flop peak is the bf16 MXU rate — the f32 VPU stencil
+# kernels cannot approach it; the bandwidth peak is the one that bounds
+# them.
+DEVICE_PEAKS: Dict[str, dict] = {
+    "TPU v5 lite": {"gflops": 197000.0, "gbps": 819.0,
+                    "source": "Google Cloud documentation, 'TPU v5e'"},
+}
+
+
+def device_peaks(device_kind: Optional[str] = None) -> Optional[dict]:
+    """The published peaks of ``device_kind`` (default: the first jax
+    device).  None off-TPU; KeyError for a TPU kind the table lacks."""
+    if device_kind is None:
+        import jax
+        dev = jax.devices()[0]
+        if dev.platform != "tpu":
+            return None
+        device_kind = dev.device_kind
+    if device_kind not in DEVICE_PEAKS:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r} in "
+            f"obs/roofline.DEVICE_PEAKS (known: {sorted(DEVICE_PEAKS)})")
+    return DEVICE_PEAKS[device_kind]
+
 
 # Per-site flops / bytes models (f32 pairs, per UPDATED site, one
 # operator application).  Sources: PERF.md round 2 (v2 traffic table),
@@ -233,9 +253,12 @@ def achieved(flops: float, bytes_: float, secs: float) -> dict:
 
 def attribute(form: str, sites: int, applies: float, seconds: float,
               nrhs: int = 1, flops_per_site: Optional[float] = None,
-              dslash_per_apply: float = 1.0, **extra) -> dict:
+              dslash_per_apply: float = 1.0,
+              device_kind: Optional[str] = None, **extra) -> dict:
     """One roofline row: a kernel form applied ``applies`` times over
-    ``sites`` updated sites (per RHS) in ``seconds`` wall.
+    ``sites`` updated sites (per RHS) in ``seconds`` wall.  The
+    percent-of-peak columns use :func:`device_peaks` (``device_kind``
+    default: the device present; None columns off-TPU).
 
     Units: ``flops_per_site`` (caller-supplied or the model's) is per
     APPLY per site, but ``bytes_per_site`` in KERNEL_MODELS is per
@@ -254,6 +277,7 @@ def attribute(form: str, sites: int, applies: float, seconds: float,
     bts = (bps * sites * applies * dslash_per_apply * max(1, int(nrhs))
            if bps is not None else None)
     th = achieved(flops, bts or 0.0, seconds)
+    peaks = device_peaks(device_kind)
     row = {"form": form, "sites": int(sites), "applies": float(applies),
            "nrhs": int(nrhs),
            "dslash_per_apply": float(dslash_per_apply),
@@ -261,11 +285,11 @@ def attribute(form: str, sites: int, applies: float, seconds: float,
            "flops_per_site": fps, "bytes_per_site": bps,
            "gflops": th["gflops"],
            "gbps": th["gbps"] if bts is not None else None,
-           "pct_peak_gflops": round(100.0 * th["gflops"]
-                                    / DEMONSTRATED_PEAK_GFLOPS, 2),
-           "pct_peak_bw": (round(100.0 * th["gbps"]
-                                 / DEMONSTRATED_PEAK_GBPS, 2)
-                           if bts is not None else None)}
+           "pct_peak_gflops": (round(100.0 * th["gflops"]
+                                     / peaks["gflops"], 2)
+                               if peaks else None),
+           "pct_peak_bw": (round(100.0 * th["gbps"] / peaks["gbps"], 2)
+                           if peaks and bts is not None else None)}
     row.update(extra)
     return row
 
@@ -321,7 +345,7 @@ def save(fname: str = "roofline.tsv",
     rows.  The ICI attribution rows of the comms ledger (obs/comms.py
     ``attribute_solve``) are appended alongside the HBM rows: same
     form/seconds/gbps columns, percent column against the nominal ICI
-    link bandwidth instead of the HBM demonstrated peak."""
+    link bandwidth instead of the published HBM peak."""
     import os
 
     from . import comms as ocomms

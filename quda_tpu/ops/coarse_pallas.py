@@ -55,21 +55,27 @@ def coarse_apply_ref(links: jnp.ndarray, psi9: jnp.ndarray) -> jnp.ndarray:
 
 
 def _pick_bs(S: int, E: int) -> int:
-    """Largest divisor of S whose VMEM working set (9 link blocks + 9
-    psi blocks + out, f32) fits the scoped budget
-    (QUDA_TPU_PALLAS_VMEM_MB — shared with the fine-level kernels)."""
+    """Largest admissible site block whose VMEM working set (9 link
+    blocks + 9 psi blocks + out, f32) fits the scoped budget
+    (QUDA_TPU_PALLAS_VMEM_MB — shared with the fine-level kernels).
+    Admissible = a divisor of S that is a multiple of 8 or S itself:
+    the site axis is the second-to-last dimension of the psi/out
+    blocks, which Mosaic tiles by 8 sublanes (a 27-site block at
+    S=1296 is refused on the chip)."""
     from ..utils import config as qconf
     budget = int(float(qconf.get("QUDA_TPU_PALLAS_VMEM_MB",
                                  fresh=True)) * 2 ** 20)
     epad = -(-E // 128) * 128          # lane padding
     per_site = 4 * (9 * E * epad + 9 * epad + epad)
-    best = 1
-    for bs in range(1, S + 1):
-        if S % bs:
-            continue
-        if bs * per_site <= budget:
-            best = bs
-    return best
+    fits = [bs for bs in range(1, S + 1)
+            if S % bs == 0 and (bs % 8 == 0 or bs == S)
+            and bs * per_site <= budget]
+    if not fits:
+        raise ValueError(
+            f"coarse_apply_pallas: no site block of S={S} (a multiple "
+            f"of 8, or S) fits the {budget >> 20} MiB VMEM budget at "
+            f"E={E} (QUDA_TPU_PALLAS_VMEM_MB)")
+    return fits[-1]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_sites"))

@@ -335,9 +335,8 @@ def _hop_packed_pairs(psi_s, u, table, adjoint: bool):
 def dslash_packed_pairs(gauge_pp: jnp.ndarray, psi_pp: jnp.ndarray,
                         X: int, Y: int, out_dtype=None) -> jnp.ndarray:
     """Full-lattice Wilson hop on PAIR-FORM packed arrays — no complex
-    dtype anywhere (some TPU runtimes cannot execute complex64; this is
-    also the honest single-precision path to compare against GPU f32
-    dslash numbers).
+    dtype anywhere (the honest single-precision path to compare against
+    GPU f32 dslash numbers, and the layout the pallas kernels share).
 
     gauge_pp: (4,3,3,2,T,Z,Y*X) storage (f32 or bf16), phases folded;
     psi_pp: (4,3,2,T,Z,Y*X).  Compute f32; output cast to ``out_dtype``

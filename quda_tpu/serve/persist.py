@@ -6,11 +6,12 @@ raced; the analog gap on the XLA side is the COMPILE — a fresh worker
 re-lowering and re-compiling every solve executable is the "compile
 storm" ROADMAP item 2 names.  Two halves close it:
 
-* the **persistent XLA compilation cache**: ``enable_compilation_cache``
-  points ``jax_compilation_cache_dir`` at
-  ``<QUDA_TPU_RESOURCE_PATH>/jax_compilation_cache`` (knob
-  ``QUDA_TPU_SERVE_COMPILE_CACHE``) so executables built by one process
-  deserialise in the next instead of recompiling;
+* the **persistent XLA compilation cache**:
+  ``utils.compile_cache.enable_compile_cache`` (also called by
+  ``init_quda``) keeps it where ``JAX_COMPILATION_CACHE_DIR`` says, else
+  at the fixed ``<checkout>/.jax_cache`` (knob
+  ``QUDA_TPU_SERVE_COMPILE_CACHE=0`` turns it off) so executables built
+  by one process deserialise in the next instead of recompiling;
 * the **executable-key index**: obs/metrics counts a ``compiles_total``
   the first time a (api, form, shape, dtype, solver) key executes *in
   this process* — honest for a cold process, wrong for a warm one whose
@@ -45,55 +46,6 @@ _precache_keys: "set | None" = None
 def _resource_path() -> str:
     from ..utils import config as qconf
     return str(qconf.get("QUDA_TPU_RESOURCE_PATH", fresh=True))
-
-
-def _cache_mode() -> str:
-    from ..utils import config as qconf
-    return str(qconf.get("QUDA_TPU_SERVE_COMPILE_CACHE", fresh=True))
-
-
-def compilation_cache_dir() -> Optional[str]:
-    """The directory the persistent XLA compilation cache would use
-    (None when disabled): under the resource path, or the working
-    directory's ./jax_compilation_cache when forced on without one."""
-    mode = _cache_mode()
-    if mode == "0":
-        return None
-    root = _resource_path()
-    if not root:
-        if mode != "1":
-            return None
-        root = "."
-    return os.path.join(root, "jax_compilation_cache")
-
-
-def enable_compilation_cache() -> Optional[str]:
-    """Point jax at the persistent compilation cache (idempotent);
-    returns the directory, or None when disabled/unsupported.  The
-    min-compile-time/min-entry-size floors are zeroed so CPU drill
-    executables persist too (the default floors are tuned for
-    minute-class chip compiles); failure to configure is a warning,
-    never an error — a worker without a cache is slow, not broken."""
-    d = compilation_cache_dir()
-    if d is None:
-        return None
-    try:
-        import jax
-        os.makedirs(d, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", d)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                          -1)
-    except Exception as e:          # noqa: BLE001 — best-effort wiring
-        from ..utils import logging as qlog
-        qlog.warn_once(
-            "serve_compile_cache",
-            f"serve: persistent compilation cache unavailable "
-            f"({type(e).__name__}: {e}); worker restarts will "
-            "recompile")
-        return None
-    return d
 
 
 def warm_keys_path() -> Optional[str]:
@@ -142,9 +94,10 @@ def save_warm_keys() -> int:
     nothing — saving its keys would poison the next worker's
     compile accounting (the warm_start seeding guard's dual)."""
     from ..obs import metrics as omet
+    from ..utils.compile_cache import compile_cache_dir
     path = warm_keys_path()
     if (not path or _precache_keys is None
-            or compilation_cache_dir() is None):
+            or compile_cache_dir() is None):
         return 0
     # only keys whose compile happened WITH the cache wired (or that
     # were themselves loaded from the index) are provably persisted;
@@ -179,7 +132,8 @@ def warm_start() -> dict:
     from ..obs import metrics as omet
     from ..obs import trace as otr
     global _precache_keys
-    cache_dir = enable_compilation_cache()
+    from ..utils.compile_cache import enable_compile_cache
+    cache_dir = enable_compile_cache()
     # the key index is only honest WITH the compilation cache: keys
     # claim "this executable is already built and persisted" — seeding
     # them while the cache is disabled/unconfigurable would record

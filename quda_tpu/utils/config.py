@@ -431,21 +431,16 @@ _register("QUDA_TPU_POSTMORTEM_MAX_BUNDLES", "int", 8,
 # -- benchmark harness (bench.py / bench_suite.py) --------------------------
 for _n, _k, _d, _doc in (
         ("QUDA_TPU_BENCH_CPU", "bool", False,
-         "force the benchmark onto the CPU backend"),
+         "run the benchmark on the CPU backend (without it bench.py / "
+         "bench_suite.py fail when jax finds no accelerator)"),
         ("QUDA_TPU_BENCH_L", "int", 0,
          "benchmark lattice extent (0 = platform default)"),
         ("QUDA_TPU_BENCH_N1", "int", 8, "short timing-chain length"),
         ("QUDA_TPU_BENCH_N2", "int", 200, "long timing-chain length"),
         ("QUDA_TPU_BENCH_REPS", "int", 5, "timing repetitions"),
-        ("QUDA_TPU_BENCH_PROBE_S", "float", 75.0,
-         "TPU probe subprocess timeout (seconds)"),
-        ("QUDA_TPU_BENCH_PROBE_RETRIES", "int", 2,
-         "TPU probe attempts before CPU fallback"),
-        ("QUDA_TPU_BENCH_PROBE_WAIT_S", "float", 30.0,
-         "wait between TPU probe attempts (seconds)"),
         ("QUDA_TPU_BENCH_DEADLINE_S", "float", 1200.0,
-         "wall-clock budget: on expiry bench.py prints the best record "
-         "accumulated so far and exits 0 (0 disables)"),
+         "wall-clock budget: on expiry bench.py prints the partial "
+         "record accumulated so far and exits NON-ZERO (0 disables)"),
         ("QUDA_TPU_BENCH_SOLVER_L", "int", 16,
          "solver-suite lattice extent"),
         ("QUDA_TPU_BENCH_SOLVER_L_CHIP", "int", 24,
@@ -563,13 +558,16 @@ _register("QUDA_TPU_SERVE_HBM_BUDGET_MB", "float", 0.0,
           reference="device_malloc ledger-driven residency "
                     "(lib/malloc.cpp) for gaugePrecise et al.")
 _register("QUDA_TPU_SERVE_COMPILE_CACHE", "choice", "",
-          "persistent XLA compilation cache for solve-service workers: "
-          "'1' force, '0' off, empty = on when a resource path is "
-          "configured.  Points jax_compilation_cache_dir at "
-          "<QUDA_TPU_RESOURCE_PATH>/jax_compilation_cache so a fresh "
-          "worker process deserialises already-built executables "
-          "instead of recompiling (the compile-storm half of ROADMAP "
-          "item 2; the tunecache warm start is the race-storm half)",
+          "persistent XLA compilation cache (utils/compile_cache.py, "
+          "wired by init_quda and the solve-service warm start): '0' "
+          "off, anything else on.  The cache lives where "
+          "JAX_COMPILATION_CACHE_DIR says (jax reads it; nothing is set "
+          "in code), else at the fixed <checkout>/.jax_cache — the path "
+          "is part of the cache key, so it never follows the working "
+          "directory — and a fresh process deserialises already-built "
+          "executables instead of recompiling (the compile-storm half "
+          "of ROADMAP item 2; the tunecache warm start is the "
+          "race-storm half)",
           ("", "0", "1"),
           reference="QUDA_RESOURCE_PATH persistent tunecache as the "
                     "cross-process warm-start surface")

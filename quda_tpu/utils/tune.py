@@ -45,6 +45,9 @@ _profile: Dict[str, dict] = {}
 _loaded_path = None
 _platform_key: Optional[str] = None
 _stale_noticed = False
+# (key, candidate) of every entrant that raised in a race this process
+# (the in-process record chip_smoke.py reads: zero on a healthy chip)
+_failed: list = []
 
 
 def _resource_path():
@@ -60,15 +63,13 @@ def platform_key() -> str:
     '|' and whitespace are folded so the key splits cleanly."""
     global _platform_key
     if _platform_key is None:
-        try:
-            import jax
-            devs = jax.devices()
-            kind = str(getattr(devs[0], "device_kind", "")
-                       or devs[0].platform)
-            kind = re.sub(r"[\s|]+", "-", kind).strip("-")
-            _platform_key = f"{devs[0].platform}:{kind}:n{len(devs)}"
-        except Exception:
-            _platform_key = "unknown:unknown:n0"
+        import jax
+        devs = jax.devices()   # no device visible -> raises, never a
+        #                        made-up 'unknown' key to race under
+        kind = str(getattr(devs[0], "device_kind", "")
+                   or devs[0].platform)
+        kind = re.sub(r"[\s|]+", "-", kind).strip("-")
+        _platform_key = f"{devs[0].platform}:{kind}:n{len(devs)}"
     return _platform_key
 
 
@@ -252,6 +253,16 @@ def tune(name: str, volume, candidates: Dict[str, Callable], args: tuple,
         except Exception as e:
             _obs_event("tune_candidate_failed", key=key, param=param,
                        error=str(e)[:120])
+            # the trace event is cut to 120 chars and exists only in a
+            # trace session: a candidate the compiler refuses (the
+            # pallas entrant, typically) must not drop out of the race
+            # unseen — full message, once per (kernel, candidate)
+            from . import logging as qlog
+            qlog.warn_once(
+                f"tune_candidate_failed:{name}:{param}",
+                f"tune: candidate {param!r} of {key} failed and left "
+                f"the race: {type(e).__name__}: {e}")
+            _failed.append((key, param))
             continue
         record_launch(name, volume, f"{aux}|{param}", t)
         _obs_event("tune_candidate", key=key, param=param, seconds=t)

@@ -211,26 +211,43 @@ def test_record_row_accumulates_for_compare(tmp_path):
 
 def test_dry_gate_on_committed_history(tmp_path, capsys):
     """Tier-1 enforcement of bench-history consumability: the dry
-    compare gate runs against the REPO'S OWN committed BENCH_*/
-    MULTICHIP_* rounds on every PR — every file parses, every verdict
-    row is well-formed, and any gate failure is one of the KNOWN,
-    PERF.md-documented dips (the round-11 r05 CPU regression), so a
-    round that silently breaks the history format (or introduces a new
-    undocumented regression) fails here, not on the next chip window.
+    compare gate runs over a history in the COMMITTED formats (one
+    legacy-schema round, gated rounds, a MULTICHIP round, and one round
+    with a documented dip — written here; the root's old records are
+    gone) — every file parses, every verdict row is well-formed, and
+    any gate failure is the KNOWN dip, so a change that silently breaks
+    the history format (or flags an undocumented regression) fails
+    here, not on the next chip run.
 
-    When a new round legitimately changes the failure set, update
-    _KNOWN_DIPS and the PERF.md note together."""
-    _KNOWN_DIPS = {"wilson_dslash_gflops_chip", "dslash_path/xla_pairs"}
+    When the written history legitimately changes the failure set,
+    update _KNOWN_DIPS with it."""
+    _KNOWN_DIPS = {"dslash/wilson_pallas_packed"}
+    d = tmp_path / "hist"
+    d.mkdir()
+    legacy = {"metric": "wilson_dslash_gflops_chip", "value": 0.8,
+              "unit": "GFLOPS", "vs_baseline": 0.001}   # no platform
+    (d / "BENCH_r01.json").write_text(json.dumps(
+        {"n": 1, "rc": 0, "tail": json.dumps(legacy) + "\n",
+         "parsed": legacy}))
+    _write_round(d, 2, [_dslash_row(5000.0), _solver_row(100)])
+    _write_round(d, 3, [_dslash_row(5100.0), _solver_row(101)])
+    (d / "MULTICHIP_r03.json").write_text(json.dumps(
+        {"n": 3, "rc": 0, "tail": json.dumps(dict(
+            _dslash_row(3000.0, name="wilson_sharded"),
+            suite="sharded", mesh="t2z2")) + "\n"}))
+    _write_round(d, 4, [_dslash_row(4250.0),          # the documented dip
+                        _solver_row(100)])
     trends = tmp_path / "trends.tsv"
-    rc = bench_suite.main(["--compare", "--dry", f"--trends={trends}"])
+    rc = bench_suite.main(["--compare", "--dry", f"--trends={trends}",
+                           f"--history={d}"])
     out = capsys.readouterr().out
     rows = [json.loads(ln) for ln in out.splitlines() if ln.strip()]
     summary = [r for r in rows if "history_files" in r]
     assert summary, f"no compare summary row in: {out[:500]}"
     s = summary[0]
-    # every committed round loaded and parsed (nothing unparseable,
-    # nothing skipped): the dry gate saw the full history
-    assert s["history_files"] >= 10
+    # every round loaded and parsed (nothing unparseable, nothing
+    # skipped): the dry gate saw the full history
+    assert s["history_files"] >= 5
     assert s["current_rows"] > 0
     assert not s["history_stats"].get("unparseable")
     # verdict rows are well-formed and failures stay within the
@@ -238,10 +255,9 @@ def test_dry_gate_on_committed_history(tmp_path, capsys):
     verdicts = [r for r in rows
                 if r.get("suite") == "compare" and "metric" in r]
     failing = {r["metric"] for r in verdicts if "rejected" in r}
-    assert failing <= _KNOWN_DIPS, (
-        f"dry gate flags UNDOCUMENTED regressions {failing - _KNOWN_DIPS}"
-        " — either fix the history or document the dip in PERF.md and "
-        "extend _KNOWN_DIPS")
+    assert failing == _KNOWN_DIPS, (
+        f"dry gate flags {failing}, expected exactly the documented "
+        f"dip {_KNOWN_DIPS}")
     assert rc == min(len([r for r in verdicts if "rejected" in r]), 120)
     assert trends.exists() and "metric" in trends.read_text()
 

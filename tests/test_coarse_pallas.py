@@ -104,12 +104,21 @@ def test_pick_bs_divides_and_respects_budget():
     S, E = 16, 16
     bs = _pick_bs(S, E)
     assert S % bs == 0
-    # a starved budget forces the minimum block; a generous one takes
-    # the whole lattice in one grid step
-    with qconf.overrides(QUDA_TPU_PALLAS_VMEM_MB="0.08"):
-        assert _pick_bs(S, E) == 1
+    # a starved budget forces the minimum ADMISSIBLE block (the site
+    # axis is sublane-tiled on the chip: a multiple of 8, or S itself);
+    # a generous one takes the whole lattice in one grid step; a budget
+    # below the minimum block is an error, not a 1-site block the
+    # compiler would refuse
+    with qconf.overrides(QUDA_TPU_PALLAS_VMEM_MB="0.7"):
+        assert _pick_bs(S, E) == 8
     with qconf.overrides(QUDA_TPU_PALLAS_VMEM_MB="512"):
         assert _pick_bs(S, E) == S
+    with qconf.overrides(QUDA_TPU_PALLAS_VMEM_MB="0.08"):
+        with pytest.raises(ValueError, match="multiple of 8"):
+            _pick_bs(S, E)
+    # the production MG shape (24^4 fine, 4^4 blocks, 24 vectors): the
+    # largest fitting DIVISOR is 27, which the chip's compiler refuses
+    assert _pick_bs(1296, 48) == 24
 
 
 def test_resolve_coarse_form_pins():

@@ -15,14 +15,8 @@ from quda_tpu.obs import comms as ocomms
 from quda_tpu.obs import metrics as omet
 from quda_tpu.obs import roofline as orf
 from quda_tpu.obs import trace as otr
-from quda_tpu.parallel import compat
 from quda_tpu.parallel.mesh import make_lattice_mesh
 from quda_tpu.utils import config as qconf
-
-pytestmark = pytest.mark.skipif(
-    not compat.has_shard_map(),
-    reason="no shard_map API in this jax version")
-
 
 @pytest.fixture(autouse=True)
 def _comms_isolation():
@@ -60,9 +54,9 @@ def _sharded_shift_fn(mesh, shape):
     from quda_tpu.parallel.halo import make_sharded_shift
     shift = make_sharded_shift(mesh)
     spec = P("t", "z", "y", "x")
-    return jax.jit(compat.shard_map(
+    return jax.jit(jax.shard_map(
         lambda a: shift(a, 2, +1), mesh=mesh, in_specs=(spec,),
-        out_specs=spec))
+        out_specs=spec, check_vma=False))
 
 
 def test_off_is_noop(monkeypatch):
@@ -421,9 +415,6 @@ def test_acceptance_sharded_solve_ledger_matches_model(policy,
     sharded Wilson CG solve's ledger rows total exactly the analytic
     halo model per device per dslash invocation, for the active
     policy (the ledger rides the existing knobs — maybe_start)."""
-    if policy == "fused_halo" and compat.interpret_params() is None:
-        pytest.skip("fused-halo needs the distributed Mosaic "
-                    "interpreter (pltpu.InterpretParams)")
     monkeypatch.setenv("QUDA_TPU_TRACE", "1")
     monkeypatch.setenv("QUDA_TPU_METRICS", "1")
     qconf.reset_cache()

@@ -311,10 +311,7 @@ def test_mesh_policy_emits_one_time_provenance_notice(monkeypatch,
     policy — a policy must never take effect silently (successor of the
     retired forced-v3 override notice)."""
     import quda_tpu.models.wilson as mwil
-    from quda_tpu.parallel import compat
     from quda_tpu.parallel.mesh import make_lattice_mesh
-    if not compat.has_shard_map():
-        pytest.skip("no shard_map API in this jax version")
     if len(jax.devices()) != 8:
         pytest.skip("needs the 8-device virtual mesh")
     monkeypatch.setenv("QUDA_TPU_PALLAS_VERSION", "2")

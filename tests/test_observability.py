@@ -236,10 +236,19 @@ def test_roofline_attribute_wilson_v2_fixture():
     bts = 1152 * sites * 100
     assert row["gflops"] == round(flops / 0.1 / 1e9, 2)
     assert row["gbps"] == round(bts / 0.1 / 1e9, 2)
+    # percent-of-peak is against the PUBLISHED peaks of the device
+    # present: none on the CPU, the v5e table row when named, and an
+    # unknown TPU kind is an error (never a default)
+    assert row["pct_peak_gflops"] is None and row["pct_peak_bw"] is None
+    row = orf.attribute("wilson_v2", sites, 100, 0.1,
+                        device_kind="TPU v5 lite")
+    assert orf.DEVICE_PEAKS["TPU v5 lite"]["gbps"] == 819.0
     assert row["pct_peak_gflops"] == round(
-        100.0 * row["gflops"] / orf.DEMONSTRATED_PEAK_GFLOPS, 2)
-    assert row["pct_peak_bw"] == round(
-        100.0 * row["gbps"] / orf.DEMONSTRATED_PEAK_GBPS, 2)
+        100.0 * row["gflops"] / 197000.0, 2)
+    assert row["pct_peak_bw"] == round(100.0 * row["gbps"] / 819.0, 2)
+    with pytest.raises(KeyError, match="no published peaks"):
+        orf.attribute("wilson_v2", sites, 100, 0.1,
+                      device_kind="TPU v99")
 
 
 def test_roofline_mrhs_model_amortises_gauge():

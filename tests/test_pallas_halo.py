@@ -13,23 +13,16 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
-from quda_tpu.parallel import compat
 from quda_tpu.parallel.pallas_halo import (wilson_zbwd_composed,
                                            wilson_zbwd_fused_halo)
 
-# The fused kernels hold in-kernel remote copies: executing them off-chip
-# needs the Mosaic interpreter's cross-device DMA emulation
-# (pltpu.InterpretParams), which 0.4.x-era jax does not provide — a
-# capability skip, not a version pin.  The composed (pure-XLA) references
-# below run everywhere and pin the hop math regardless.
-needs_dist_interpret = pytest.mark.skipif(
-    not compat.has_dist_interpret(),
-    reason="no distributed Mosaic interpreter (pltpu.InterpretParams) "
-           "in this jax version — in-kernel RDMA cannot be emulated")
+# The fused kernels hold in-kernel remote copies: off-chip they run
+# under the Mosaic interpreter's cross-device DMA emulation
+# (pltpu.InterpretParams).  The composed (pure-XLA) references below
+# pin the hop math regardless.
 
 
 @pytest.mark.mid
-@needs_dist_interpret
 def test_fused_halo_matches_composed():
     # small on purpose: the Mosaic interpreter with cross-device DMA
     # emulation costs minutes at Z=16/YX=64 on a 1-core host, and the
@@ -49,7 +42,6 @@ def test_fused_halo_matches_composed():
 
 
 @pytest.mark.mid
-@needs_dist_interpret
 def test_bidir_fused_halo_matches_composed():
     """Both z hops, two RDMAs in flight behind one neighbour barrier."""
     from quda_tpu.parallel.pallas_halo import (wilson_z_composed,
@@ -71,7 +63,6 @@ def test_bidir_fused_halo_matches_composed():
 
 
 @pytest.mark.mid
-@needs_dist_interpret
 def test_bidir_fused_halo_t_axis_matches_composed():
     """The t-axis widening (round 8): both t hops on (4,3,2,T,Z,YX)
     blocks, two RDMAs behind one neighbour barrier — the other slab axis

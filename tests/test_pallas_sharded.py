@@ -7,17 +7,6 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from quda_tpu.parallel import compat
-
-# The file drives shard_map through the compat seam
-# (parallel/compat.py), which resolves either the top-level
-# jax.shard_map (check_vma) or the 0.4.x experimental one (check_rep) —
-# a capability probe, not a version pin; environments with neither skip
-# cleanly so a red here is a real regression, not environment noise.
-pytestmark = pytest.mark.skipif(
-    not compat.has_shard_map(),
-    reason="no shard_map API in this jax version")
-
 from quda_tpu.fields.geometry import LatticeGeometry
 from quda_tpu.fields.gauge import GaugeField
 from quda_tpu.fields.spinor import ColorSpinorField
@@ -52,11 +41,11 @@ def test_sharded_pallas_matches_single_device(grid):
     psi_spec = P(None, None, None, "t", "z", None)
     g_spec = P(None, None, None, None, "t", "z", None)
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         lambda g, gb, p: dslash_pallas_sharded(g, gb, p, X, mesh,
                                                interpret=True),
         mesh=mesh, in_specs=(g_spec, g_spec, psi_spec),
-        out_specs=psi_spec)
+        out_specs=psi_spec, check_vma=False)
 
     gp_s = jax.device_put(gp, NamedSharding(mesh, g_spec))
     gbw_s = jax.device_put(gbw, NamedSharding(mesh, g_spec))
@@ -91,11 +80,11 @@ def test_sharded_pallas_v3_matches_single_device(grid):
     psi_spec = P(None, None, None, "t", "z", None)
     g_spec = P(None, None, None, None, "t", "z", None)
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         lambda g, p: dslash_pallas_sharded_v3(g, p, X, mesh,
                                               interpret=True),
         mesh=mesh, in_specs=(g_spec, psi_spec),
-        out_specs=psi_spec)
+        out_specs=psi_spec, check_vma=False)
 
     gp_s = jax.device_put(gp, NamedSharding(mesh, g_spec))
     pp_s = jax.device_put(pp, NamedSharding(mesh, psi_spec))
@@ -129,10 +118,11 @@ def test_sharded_staggered_v3_matches_single_device(grid):
     mesh = make_lattice_mesh(grid=grid, n_src=1)
     psi_spec = P(None, None, "t", "z", None)
     g_spec = P(None, None, None, None, "t", "z", None)
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         lambda g, p: dslash_staggered_pallas_sharded_v3(
             g, p, X, mesh, interpret=True),
-        mesh=mesh, in_specs=(g_spec, psi_spec), out_specs=psi_spec)
+        mesh=mesh, in_specs=(g_spec, psi_spec), out_specs=psi_spec,
+        check_vma=False)
     fat_s = jax.device_put(fat_pp, NamedSharding(mesh, g_spec))
     psi_s = jax.device_put(psi_pp, NamedSharding(mesh, psi_spec))
     out = jax.jit(fn)(fat_s, psi_s)
@@ -166,11 +156,11 @@ def test_sharded_improved_staggered_v3_matches_single_device():
     mesh = make_lattice_mesh(grid=(4, 2, 1, 1), n_src=1)
     psi_spec = P(None, None, "t", "z", None)
     g_spec = P(None, None, None, None, "t", "z", None)
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         lambda f, l, p: dslash_staggered_pallas_sharded_v3(
             f, p, X, mesh, long_pl=l, interpret=True),
         mesh=mesh, in_specs=(g_spec, g_spec, psi_spec),
-        out_specs=psi_spec)
+        out_specs=psi_spec, check_vma=False)
     fat_s = jax.device_put(fat_pp, NamedSharding(mesh, g_spec))
     long_s = jax.device_put(long_pp, NamedSharding(mesh, g_spec))
     psi_s = jax.device_put(psi_pp, NamedSharding(mesh, psi_spec))
@@ -209,11 +199,11 @@ def test_sharded_wilson_eo_v3_matches_single_device(parity):
     mesh = make_lattice_mesh(grid=(4, 2, 1, 1), n_src=1)
     psi_spec = P(None, None, None, "t", "z", None)
     g_spec = P(None, None, None, None, "t", "z", None)
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         lambda uh, ut, p: dslash_eo_pallas_sharded_v3(
             uh, ut, p, dims, parity, mesh, interpret=True),
         mesh=mesh, in_specs=(g_spec, g_spec, psi_spec),
-        out_specs=psi_spec)
+        out_specs=psi_spec, check_vma=False)
     uh_s = jax.device_put(g_eo_pp[parity], NamedSharding(mesh, g_spec))
     ut_s = jax.device_put(g_eo_pp[1 - parity], NamedSharding(mesh, g_spec))
     src_s = jax.device_put(src_pp, NamedSharding(mesh, psi_spec))
@@ -292,13 +282,13 @@ def test_sharded_staggered_eo_v3_matches_single_device(parity):
     mesh = make_lattice_mesh(grid=(4, 2, 1, 1), n_src=1)
     psi_spec = P(None, None, "t", "z", None)
     g_spec = P(None, None, None, None, "t", "z", None)
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         lambda fh, ft, lh, lt, p: dslash_staggered_eo_pallas_sharded_v3(
             fh, ft, p, dims, parity, mesh, long_here_pl=lh,
             long_there_pl=lt, interpret=True),
         mesh=mesh,
         in_specs=(g_spec, g_spec, g_spec, g_spec, psi_spec),
-        out_specs=psi_spec)
+        out_specs=psi_spec, check_vma=False)
     args = [jax.device_put(a, NamedSharding(mesh, g_spec))
             for a in (fat_eo_pp[parity], fat_eo_pp[1 - parity],
                       long_eo_pp[parity], long_eo_pp[1 - parity])]
@@ -345,11 +335,11 @@ def _run_sharded_eo_v2(dims, g_eo_pp, parity, src_pp, policy,
     # GLOBAL pre-shift of the backward links, THEN shard: the cross-
     # shard links are then already resident per shard (the v2 design)
     u_bw = wpp.backward_gauge_eo(ut, dims, parity)
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         lambda a, b, p: dslash_eo_pallas_sharded(
             a, b, p, dims, parity, mesh, interpret=True, policy=policy),
         mesh=mesh, in_specs=(g_spec, g_spec, psi_spec),
-        out_specs=psi_spec)
+        out_specs=psi_spec, check_vma=False)
     uh_s = jax.device_put(uh, NamedSharding(mesh, g_spec))
     ub_s = jax.device_put(u_bw, NamedSharding(mesh, g_spec))
     src_s = jax.device_put(src_pp, NamedSharding(mesh, psi_spec))
@@ -415,11 +405,11 @@ def test_sharded_wilson_eo_v3_recon12_matches_single_device():
     g_spec = P(None, None, None, None, "t", "z", None)
     uh = wpp.to_recon12(g_eo_pp[parity])
     ut = wpp.to_recon12(g_eo_pp[1 - parity])
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         lambda a, b, p: dslash_eo_pallas_sharded_v3(
             a, b, p, dims, parity, mesh, interpret=True),
         mesh=mesh, in_specs=(g_spec, g_spec, psi_spec),
-        out_specs=psi_spec)
+        out_specs=psi_spec, check_vma=False)
     out = jax.jit(fn)(jax.device_put(uh, NamedSharding(mesh, g_spec)),
                       jax.device_put(ut, NamedSharding(mesh, g_spec)),
                       jax.device_put(src_pp,
@@ -429,9 +419,6 @@ def test_sharded_wilson_eo_v3_recon12_matches_single_device():
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(not compat.has_dist_interpret(),
-                    reason="fused_halo needs the distributed Mosaic "
-                           "interpreter (pltpu.InterpretParams) off-chip")
 @pytest.mark.parametrize("parity", [0, 1])
 def test_sharded_wilson_eo_v2_fused_halo_matches_facefix(parity):
     """Policy A/B: the fused in-kernel RDMA slab exchange must be
@@ -489,10 +476,6 @@ def test_sharded_operator_defaults_to_v2_and_races_policy(tmp_path,
     # unpartitioned ones pinned at the facefix transport
     assert set(won) == {"t", "z", "y", "x"}
     assert all(v in ("xla_facefix", "fused_halo") for v in won.values())
-    # off-chip without the distributed interpreter the RDMA candidate
-    # cannot run, so every axis race must settle on ppermute
-    if not compat.has_dist_interpret():
-        assert all(v == "xla_facefix" for v in won.values())
     # the winners are persisted: one cache entry PER PARTITIONED AXIS
     # (t and z here) and a second operator re-reads them without
     # re-racing (tune returns the cached params)
@@ -553,12 +536,12 @@ def test_sharded_staggered_v2_matches_single_device():
     mesh = make_lattice_mesh(grid=(4, 2, 1, 1), n_src=1)
     psi_spec = P(None, None, "t", "z", None)
     g_spec = P(None, None, None, None, "t", "z", None)
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         lambda f, fb, l, lb, p: dslash_staggered_pallas_sharded(
             f, fb, p, X, mesh, long_pl=l, long_bw_pl=lb,
             interpret=True),
         mesh=mesh, in_specs=(g_spec,) * 4 + (psi_spec,),
-        out_specs=psi_spec)
+        out_specs=psi_spec, check_vma=False)
     args = [jax.device_put(a, NamedSharding(mesh, g_spec))
             for a in (fat_pp, fat_bw, long_pp, long_bw)]
     psi_s = jax.device_put(psi_pp, NamedSharding(mesh, psi_spec))
@@ -610,12 +593,12 @@ def test_sharded_staggered_eo_v2_matches_single_device(parity):
     mesh = make_lattice_mesh(grid=(4, 2, 1, 1), n_src=1)
     psi_spec = P(None, None, "t", "z", None)
     g_spec = P(None, None, None, None, "t", "z", None)
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         lambda fh, fb, lh, lb, p: dslash_staggered_eo_pallas_sharded(
             fh, fb, p, dims, parity, mesh, long_here_pl=lh,
             long_bw_pl=lb, interpret=True),
         mesh=mesh, in_specs=(g_spec,) * 4 + (psi_spec,),
-        out_specs=psi_spec)
+        out_specs=psi_spec, check_vma=False)
     args = [jax.device_put(a, NamedSharding(mesh, g_spec))
             for a in (fat_eo_pp[parity], fat_bw, long_eo_pp[parity],
                       long_bw)]

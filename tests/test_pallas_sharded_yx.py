@@ -18,12 +18,6 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from quda_tpu.parallel import compat
-
-pytestmark = pytest.mark.skipif(
-    not compat.has_shard_map(),
-    reason="no shard_map API in this jax version")
-
 from quda_tpu.fields.geometry import LatticeGeometry
 from quda_tpu.fields.gauge import GaugeField
 from quda_tpu.fields.spinor import ColorSpinorField, even_odd_split
@@ -126,11 +120,11 @@ def _run_sharded_eo(dims, g_eo_pp, parity, src_pp, grid, policy,
     # shard (the v2 design, x-generalized)
     u_bw = wpp.backward_gauge_eo(ut, dims, parity)
     rl = lambda a: fuse_block_layout(a, n_y, n_x, Y, X // 2)
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         lambda a, b, p: dslash_eo_pallas_sharded(
             a, b, p, dims, parity, mesh, interpret=True, policy=policy),
         mesh=mesh, in_specs=(G_SPEC, G_SPEC, PSI_SPEC),
-        out_specs=PSI_SPEC)
+        out_specs=PSI_SPEC, check_vma=False)
     uh_s = jax.device_put(rl(uh), NamedSharding(mesh, G_SPEC))
     ub_s = jax.device_put(rl(u_bw), NamedSharding(mesh, G_SPEC))
     src_s = jax.device_put(rl(src_pp), NamedSharding(mesh, PSI_SPEC))
@@ -165,11 +159,11 @@ def test_sharded_wilson_full_y_matches_single_device():
 
     mesh = make_lattice_mesh(grid=(1, 1, 2, 1), n_src=1,
                              devices=jax.devices()[:2])
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         lambda g, gb, p: dslash_pallas_sharded(g, gb, p, X, mesh,
                                                interpret=True),
         mesh=mesh, in_specs=(G_SPEC, G_SPEC, PSI_SPEC),
-        out_specs=PSI_SPEC)
+        out_specs=PSI_SPEC, check_vma=False)
     out = jax.jit(fn)(jax.device_put(gp, NamedSharding(mesh, G_SPEC)),
                       jax.device_put(gbw, NamedSharding(mesh, G_SPEC)),
                       jax.device_put(pp, NamedSharding(mesh, PSI_SPEC)))
@@ -211,9 +205,9 @@ def test_psum_free_on_size1_mesh_axes():
     x = jnp.arange(16, dtype=jnp.float32).reshape(2, 2, 2, 2)
 
     def compiled_allreduce_groups(body):
-        fn = jax.jit(compat.shard_map(body, mesh=mesh, in_specs=(spec,),
-                                      out_specs=P(None, None, None,
-                                                  None)))
+        fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(spec,),
+                                   out_specs=P(None, None, None, None),
+                                   check_vma=False))
         txt = fn.lower(x).compile().as_text()
         groups = [ln.split("replica_groups=")[1].split(",")[0]
                   for ln in txt.splitlines()
@@ -341,9 +335,6 @@ def test_sharded_wilson_eo_3axes_with_x_matches_single_device():
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(not compat.has_dist_interpret(),
-                    reason="fused_halo needs the distributed Mosaic "
-                           "interpreter (pltpu.InterpretParams)")
 @pytest.mark.parametrize("parity", [0, 1])
 def test_sharded_wilson_eo_fused_halo_y_matches_facefix(parity):
     """Per-axis policy A/B on the 3D mesh: fused RDMA on the contiguous
@@ -403,12 +394,12 @@ def test_sharded_staggered_eo_3d_matches_single_device(parity):
     long_bw = stp.backward_links_eo(long_eo_pp[1 - parity], dims,
                                     parity, 3)
     mesh = make_lattice_mesh(grid=(2, 2, 2, 1), n_src=1)
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         lambda fh, fb, lh, lb, p: dslash_staggered_eo_pallas_sharded(
             fh, fb, p, dims, parity, mesh, long_here_pl=lh,
             long_bw_pl=lb, interpret=True),
         mesh=mesh, in_specs=(G_SPEC,) * 4 + (STAG_PSI_SPEC,),
-        out_specs=STAG_PSI_SPEC)
+        out_specs=STAG_PSI_SPEC, check_vma=False)
     args = [jax.device_put(a, NamedSharding(mesh, G_SPEC))
             for a in (fat_eo_pp[parity], fat_bw, long_eo_pp[parity],
                       long_bw)]
@@ -449,12 +440,12 @@ def test_sharded_staggered_full_yx_matches_single_device():
     mesh = make_lattice_mesh(grid=grid, n_src=1)
     n_y, n_x = grid[2], grid[3]
     rl = lambda a: fuse_block_layout(a, n_y, n_x, Y, X)
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         lambda f, fb, l, lb, p: dslash_staggered_pallas_sharded(
             f, fb, p, X, mesh, long_pl=l, long_bw_pl=lb,
             interpret=True),
         mesh=mesh, in_specs=(G_SPEC,) * 4 + (STAG_PSI_SPEC,),
-        out_specs=STAG_PSI_SPEC)
+        out_specs=STAG_PSI_SPEC, check_vma=False)
     args = [jax.device_put(rl(a), NamedSharding(mesh, G_SPEC))
             for a in (fat_pp, fat_bw, long_pp, long_bw)]
     psi_s = jax.device_put(rl(psi_pp),
@@ -480,7 +471,6 @@ from quda_tpu.ops import blas
 from quda_tpu.ops import wilson_packed as wpk
 from quda_tpu.ops import wilson_pallas_packed as wpp
 from quda_tpu.ops.wilson import split_gauge_eo
-from quda_tpu.parallel import compat
 from quda_tpu.parallel.mesh import (fuse_block_layout, make_lattice_mesh,
                                     unfuse_block_layout)
 from quda_tpu.parallel.pallas_dslash import dslash_eo_pallas_sharded
@@ -505,12 +495,12 @@ u_bw = wpp.backward_gauge_eo(g_eo_pp[1 - parity], dims, parity)
 rl = lambda a: fuse_block_layout(a, 2, 2, Y, X // 2)
 psi_spec = P(None, None, None, "t", "z", ("y", "x"))
 g_spec = P(None, None, None, None, "t", "z", ("y", "x"))
-fn = compat.shard_map(
+fn = jax.shard_map(
     lambda a, b, p: dslash_eo_pallas_sharded(
         a, b, p, dims, parity, mesh, interpret=True,
         policy="xla_facefix"),
     mesh=mesh, in_specs=(g_spec, g_spec, psi_spec),
-    out_specs=psi_spec)
+    out_specs=psi_spec, check_vma=False)
 out = jax.jit(fn)(
     jax.device_put(rl(g_eo_pp[parity]), NamedSharding(mesh, g_spec)),
     jax.device_put(rl(u_bw), NamedSharding(mesh, g_spec)),

@@ -275,10 +275,7 @@ def test_sharded_mesh_downgrades_precision_forms():
     sharded-r12 path, exterior face fixes included)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from quda_tpu.parallel import compat
     from quda_tpu.parallel.mesh import make_lattice_mesh
-    if not compat.has_shard_map():
-        pytest.skip("no shard_map API in this jax version")
     if len(jax.devices()) != 8:
         pytest.skip("needs the 8-device virtual mesh")
     geom = LatticeGeometry((4, 4, 8, 16))

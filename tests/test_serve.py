@@ -23,8 +23,10 @@ CPU drills, all tier-1:
 """
 
 import json
+import os
 import queue as _queue
 
+import jax
 import numpy as np
 import pytest
 
@@ -416,7 +418,7 @@ def test_resident_mg_state_never_serves_stale_hierarchy():
 
 # -- cross-process warm start ------------------------------------------------
 
-def test_acceptance_two_workers_warm_start(tmp_path):
+def test_acceptance_two_workers_warm_start(tmp_path, monkeypatch):
     """The ISSUE-12 acceptance drill end to end.  Worker session A
     serves coalesced MRHS batches against 2 resident gauges under a
     ledger-bounded residency budget and persists its executable-key
@@ -426,6 +428,11 @@ def test_acceptance_two_workers_warm_start(tmp_path):
     executions_total advances, and its fleet_report.txt carries the
     Service section with batch/SLO/availability rows."""
     from quda_tpu.serve import SolveService
+    # the cache is placed from OUTSIDE: with JAX_COMPILATION_CACHE_DIR
+    # set, no code path may re-point jax_compilation_cache_dir
+    cache_dir = tmp_path / "jax_compilation_cache"
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(cache_dir))
+    jax_dir_before = jax.config.jax_compilation_cache_dir
     param = _wilson_param()
     gauge_bytes = omem.nbytes_of(
         np.zeros((4, L, L, L, L, 3, 3), np.complex64))
@@ -452,13 +459,17 @@ def test_acceptance_two_workers_warm_start(tmp_path):
     keys_file = tmp_path / "executable_keys.json"
     saved = json.load(open(keys_file))
     assert any(saved.values())
-    # the persistent XLA compilation cache was wired under the
-    # resource path (population depends on whether THIS process
-    # actually compiled: an executable served from the in-process jit
-    # cache writes nothing, which is exactly the storm-free behavior)
-    cache_dir = tmp_path / "jax_compilation_cache"
+    # the warm start reports the externally placed cache directory and
+    # set nothing in code (jax reads the variable itself at start-up;
+    # without it the fixed <checkout>/.jax_cache is wired instead)
     assert svc.warm["cache_dir"] == str(cache_dir)
-    assert cache_dir.is_dir()
+    assert jax.config.jax_compilation_cache_dir == jax_dir_before
+    from quda_tpu.utils import compile_cache as qcc
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    assert qcc.compile_cache_dir() == os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(cache_dir))
 
     # "worker process B": the metrics session (and its seen-key set)
     # is gone with end_quda above; a fresh service session under the
