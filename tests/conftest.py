@@ -6,6 +6,7 @@ exercised on a virtual 8-device CPU mesh (the strictly-better analog of
 QUDA's single no-op communicator + mpirun -np N on one node).
 """
 
+import faulthandler
 import os
 
 # Must be set before the backend initialises.
@@ -13,6 +14,15 @@ flags = os.environ.get("XLA_FLAGS", "")
 if "--xla_force_host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = (
         flags + " --xla_force_host_platform_device_count=8").strip()
+# One BLAS thread: the numpy/ARPACK oracles are 4^4-sized, and OpenBLAS's
+# idle threads spin (test_iram_nonhermitian alone: 144 s of CPU for 39 s
+# of wall, 42 s of CPU with one thread and the same wall), which six
+# xdist workers on eight cores pay for many times over.  OpenBLAS reads
+# these when it loads: scipy's copy loads after this line, and numpy's
+# (a pytest plugin imports numpy first) in the xdist workers, which
+# inherit the environment of the controller that ran this line.
+os.environ.setdefault("OPENBLAS_NUM_THREADS", "1")
+os.environ.setdefault("OMP_NUM_THREADS", "1")
 
 import jax  # noqa: E402
 
@@ -26,12 +36,13 @@ jax.config.update("jax_enable_compilation_cache", False)
 
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
+from _pytest.faulthandler import (  # noqa: E402
+    fault_handler_stderr_fd_key)
 
 # -- smoke tier --------------------------------------------------------------
 # One fast, representative case per subsystem (reference: ctest labels,
 # tests/CMakeLists.txt:414-470 tier quick checks the same way).  Run with
-#   python -m pytest tests/ -m smoke -q        (~4 minutes)
-# The full suite remains the default (no marker filter).
+#   python -m pytest tests/ -m smoke -q
 SMOKE = {
     "test_wilson.py": None,                 # whole file is fast oracles
     "test_core.py": None,
@@ -66,10 +77,10 @@ SMOKE = {
 # -- mid tier ----------------------------------------------------------------
 # Structural/consistency coverage of the HEAVY files (MG hierarchies, pair
 # sector, df64) that smoke skips, while leaving the long end-to-end solves
-# to the full suite.  `pytest -m "smoke or mid"` is the review tier: it
-# must finish in ~10 minutes on this CPU, and any single file run with
-# that filter completes well inside a review window (VERDICT r4 item 8 —
-# the unfiltered 4-file pair-MG slice blew a 9.5-minute budget).
+# to the full suite.  `pytest -m "smoke or mid"` is the review tier: any
+# single file run with that filter completes well inside a review
+# window (VERDICT r4 item 8 — the unfiltered 4-file pair-MG slice blew
+# a 9.5-minute budget).
 MID = {
     "test_pair_mg.py": ["test_pair_transfer_matches_complex",
                         "test_pair_coarse_links_match_complex",
@@ -91,13 +102,13 @@ MID = {
 
 def pytest_configure(config):
     config.addinivalue_line(
-        "markers", "smoke: fast one-per-subsystem tier (~4 min total)")
+        "markers", "smoke: fast one-per-subsystem tier")
     config.addinivalue_line(
         "markers", "mid: structural coverage of the heavy files; "
-                   "'smoke or mid' is the ~10-minute review tier")
+                   "'smoke or mid' is the review tier")
     config.addinivalue_line(
-        "markers", "slow: multi-minute end-to-end runs (production-"
-                   "volume harnesses); included in the default full run")
+        "markers", "slow: over a minute alone on an idle host, or a "
+                   "production-volume harness; tier-1 runs -m 'not slow'")
 
 
 def pytest_collection_modifyitems(config, items):
@@ -112,37 +123,29 @@ def pytest_collection_modifyitems(config, items):
             item.add_marker(pytest.mark.mid)
 
 
-# -- slow-marker audit --------------------------------------------------------
-# Tier-1 runs `-m "not slow"` under a hard wall clock (ROADMAP); the
-# recurring budget leak is an interpret-mode pallas test (a ~20-60 s
-# interpreter compile per kernel shape) landing in the fast tier
-# unmarked.  Any non-slow test whose call phase exceeds the budget is
-# listed in the terminal summary so the next PR marks it — an audit
-# aid, not a failure.
-SLOW_AUDIT_BUDGET_S = float(os.environ.get("QUDA_TPU_TEST_SLOW_BUDGET_S",
-                                           "30"))
-_SLOW_AUDIT: list = []
+# -- per-test limit -----------------------------------------------------------
+# Tier-1 runs under the driver's wall clock, and under `--dist load` a
+# worker that hangs keeps the tests already handed to it until that
+# clock kills the whole run.  A test stuck inside a C call with the GIL
+# released (the interpret-mode halo deadlock was one) never runs a
+# Python signal handler, so the watchdog is faulthandler's: every
+# thread's traceback goes to the real stderr (pytest's own dup of it:
+# sys.stderr is captured while a test runs), the worker exits, xdist
+# reports that one test as failed and starts a new worker.  180 s is
+# twice the 90 s no sound non-slow test may take in a full run, so
+# load alone never trips it.  Fixtures are not under the limit.
+LIMIT_S = 180
 
 
 @pytest.hookimpl(hookwrapper=True)
 def pytest_runtest_call(item):
-    import time
-    t0 = time.perf_counter()
-    yield
-    dt = time.perf_counter() - t0
-    if dt > SLOW_AUDIT_BUDGET_S and "slow" not in item.keywords:
-        _SLOW_AUDIT.append((item.nodeid, dt))
-
-
-def pytest_terminal_summary(terminalreporter):
-    if _SLOW_AUDIT:
-        terminalreporter.section("slow-marker audit")
-        terminalreporter.write_line(
-            f"non-slow tests over the {SLOW_AUDIT_BUDGET_S:.0f}s budget "
-            "(mark slow or shrink; tier-1 runs -m 'not slow' under a "
-            "hard timeout):")
-        for nodeid, dt in sorted(_SLOW_AUDIT, key=lambda x: -x[1]):
-            terminalreporter.write_line(f"  {dt:7.1f}s  {nodeid}")
+    faulthandler.dump_traceback_later(
+        LIMIT_S, exit=True,
+        file=item.config.stash[fault_handler_stderr_fd_key])
+    try:
+        yield
+    finally:
+        faulthandler.cancel_dump_traceback_later()
 
 
 @pytest.fixture
@@ -155,15 +158,33 @@ def key():
     return jax.random.PRNGKey(7)
 
 
-@pytest.fixture(autouse=True, scope="module")
-def _clear_jax_caches_per_module():
-    """Release compiled executables between test modules.
+# A full-suite run performs thousands of jit compilations per process;
+# the accumulated XLA:CPU (LLVM JIT) state eventually segfaults inside
+# backend_compile (observed 2026-07-30 at ~350 compilations in, in
+# whichever module ran there — the same module passes standalone).
+# Dropping the pjit caches bounds the resident compiled code, at the
+# cost of re-tracing: under `--dist load` a worker changes module every
+# few tests, and a drop at every change cost a sixth of the CPU time
+# (four files in one process: 313 s with, 259 s without).  So drop at a
+# module boundary only once COMPILES_PER_DROP programs were compiled
+# since the last drop; test_twisted.py alone compiles more than that.
+COMPILES_PER_DROP = 500
+_compiles = 0
 
-    A full-suite run performs ~450 jit compilations in one process; the
-    accumulated XLA:CPU (LLVM JIT) state eventually segfaults inside
-    backend_compile (observed 2026-07-30 at ~350 compilations in, in
-    whichever module ran there — the same module passes standalone).
-    Dropping the pjit caches after each module keeps the resident
-    compiled-code footprint bounded at the cost of some re-tracing."""
+
+def _count_compile(event, duration_secs, **kwargs):
+    global _compiles
+    if event == "/jax/core/compile/backend_compile_duration":
+        _compiles += 1
+
+
+jax.monitoring.register_event_duration_secs_listener(_count_compile)
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _drop_jax_caches_between_modules():
     yield
-    jax.clear_caches()
+    global _compiles
+    if _compiles >= COMPILES_PER_DROP:
+        _compiles = 0
+        jax.clear_caches()

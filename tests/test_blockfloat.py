@@ -127,6 +127,8 @@ def test_int8_links_scale_is_per_direction_site():
     assert rel < 5e-3
 
 
+# 132 s alone (PR 25): an interpreted df64 CG to 1e-10, at 4^4 already
+@pytest.mark.slow
 def test_int8_links_df64_acceptance_drill(monkeypatch):
     """Round-16 acceptance drill: 'quarter' sloppy = int8 block-float
     links under the df64 reliable-update CG.  The quantised sloppy

@@ -57,6 +57,10 @@ def _live_isolation(monkeypatch, tmp_path):
     monkeypatch.delenv("QUDA_TPU_LIVE_PORT", raising=False)
     monkeypatch.delenv("QUDA_TPU_METRICS_FLUSH_SEC", raising=False)
     olive.stop()
+    # an earlier test of this worker may have left the API initialised
+    # (test_eig's arpack bridge does): SolveService.start() then skips
+    # init_quda, so no metrics session opens and /metrics reads empty
+    api.end_quda()
     omet.stop(flush_files=False)
     omem.reset()
     otr.stop(flush_files=False)

@@ -220,7 +220,8 @@ def test_invert_quda_half_sloppy_branches(api_ctx, inv, solve):
     tol = 1e-9
     p = InvertParam(dslash_type="wilson", kappa=KAPPA, inv_type=inv,
                     solve_type=solve, tol=tol, maxiter=2000,
-                    cuda_prec="double", cuda_prec_sloppy="half")
+                    cuda_prec="double", cuda_prec_sloppy="half",
+                    gcrNkrylov=4)    # the unrolled cycle's compile
     x = invert_quda(b, p)
     d = DiracWilson(gauge, GEOM, KAPPA)
     r2 = blas.norm2(b - d.M(jnp.asarray(x)))

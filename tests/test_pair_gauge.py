@@ -182,6 +182,8 @@ def test_pair_hmc_energy_conservation(fields):
     assert dh1 < 1.0
 
 
+# 175 s alone (PR 25): one XLA:CPU compile of the whole step
+@pytest.mark.slow
 def test_rhmc_step_has_no_complex_dtype(fields):
     """One full RHMC kick-drift chain (HISQ fermion force + path-table
     gauge force + momentum kick + exp update + plaquette) traces with NO

@@ -270,6 +270,8 @@ def test_pallas_v3_recon12_matches_full(antiperiodic):
     assert err < 1e-5
 
 
+# 73 s alone (PR 25): four interpreted kernel compiles
+@pytest.mark.slow
 def test_pallas_eo_v3_recon12_solve_matches():
     """The reconstruct-12 eo operator (QUDA_TPU_RECONSTRUCT=12 wiring
     through DiracWilsonPCPackedSloppy) reproduces the full-storage

@@ -22,6 +22,12 @@ from quda_tpu.parallel.pallas_halo import (wilson_zbwd_composed,
 # pin the hop math regardless.
 
 
+def _run_fused(fused, psi, u, mesh):
+    # The interpreted call returns before its io_callback threads have
+    # run; eager jax work on the same CPU client then deadlocks them.
+    return jax.block_until_ready(fused(psi, u, mesh, interpret=True))
+
+
 @pytest.mark.mid
 def test_fused_halo_matches_composed():
     # small on purpose: the Mosaic interpreter with cross-device DMA
@@ -34,7 +40,7 @@ def test_fused_halo_matches_composed():
     uz = jax.random.normal(k2, (3, 3, 2, Z, YX), jnp.float32)
 
     mesh = Mesh(np.array(jax.devices()[:8]).reshape(8), ("z",))
-    got = wilson_zbwd_fused_halo(psi, uz, mesh, interpret=True)
+    got = _run_fused(wilson_zbwd_fused_halo, psi, uz, mesh)
     want = wilson_zbwd_composed(psi, uz)
     err = float(jnp.max(jnp.abs(got - want)))
     scale = float(jnp.max(jnp.abs(want)))
@@ -55,7 +61,7 @@ def test_bidir_fused_halo_matches_composed():
     psi = jax.random.normal(k1, (4, 3, 2, Z, YX), jnp.float32)
     uz = jax.random.normal(k2, (3, 3, 2, Z, YX), jnp.float32)
     mesh = Mesh(np.array(jax.devices()[:8]).reshape(8), ("z",))
-    got = wilson_z_fused_halo(psi, uz, mesh, interpret=True)
+    got = _run_fused(wilson_z_fused_halo, psi, uz, mesh)
     want = wilson_z_composed(psi, uz)
     err = float(jnp.max(jnp.abs(got - want)))
     scale = float(jnp.max(jnp.abs(want)))
@@ -75,7 +81,7 @@ def test_bidir_fused_halo_t_axis_matches_composed():
     psi = jax.random.normal(k1, (4, 3, 2, T, Z, YX), jnp.float32)
     ut = jax.random.normal(k2, (3, 3, 2, T, Z, YX), jnp.float32)
     mesh = Mesh(np.array(jax.devices()[:8]).reshape(8), ("t",))
-    got = wilson_t_fused_halo(psi, ut, mesh, interpret=True)
+    got = _run_fused(wilson_t_fused_halo, psi, ut, mesh)
     want = wilson_t_composed(psi, ut)
     err = float(jnp.max(jnp.abs(got - want)))
     scale = float(jnp.max(jnp.abs(want)))

@@ -436,6 +436,8 @@ def test_sharded_wilson_eo_v2_fused_halo_matches_facefix(parity):
     assert err < 1e-6
 
 
+# 168 s alone (PR 25): ~16 interpreted applications, at 4^4 already
+@pytest.mark.slow
 def test_sharded_operator_defaults_to_v2_and_races_policy(tmp_path,
                                                           monkeypatch):
     """The model-layer dispatch: a multi-device mesh operator now

@@ -206,7 +206,7 @@ def test_psum_free_on_size1_mesh_axes():
 
     def compiled_allreduce_groups(body):
         fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(spec,),
-                                   out_specs=P(None, None, None, None),
+                                   out_specs=P(),  # the psum is a scalar
                                    check_vma=False))
         txt = fn.lower(x).compile().as_text()
         groups = [ln.split("replica_groups=")[1].split(",")[0]

@@ -163,11 +163,16 @@ def test_staggered_fused_precision_forms_match_full(pform, parity):
     ref_op = dpc.pairs(jnp.float32, use_pallas=True,
                        pallas_interpret=True, form="fused",
                        precision_form="full")
-    ref = np.asarray(ref_op.D_to_pairs(psi, parity, jnp.float32))
     op = dpc.pairs(jnp.float32, use_pallas=True, pallas_interpret=True,
                    form="fused", precision_form=pform)
     assert op._precision_form == pform
-    out = np.asarray(op.D_to_pairs(psi, parity, jnp.float32))
+    # XLA:CPU's fusion emitters (jax 0.9.0) round a multiply-add chain
+    # by where the fusion boundary falls, which the fold layout moves;
+    # the bit-match is about the kernels' adds, so compile without.
+    ref, out = (np.asarray(jax.jit(
+        lambda p, o=o: o.D_to_pairs(p, parity, jnp.float32),
+        compiler_options={"xla_cpu_use_fusion_emitters": False})(psi))
+        for o in (ref_op, op))
     if pform == "fold":
         assert np.array_equal(out, ref)
     else:

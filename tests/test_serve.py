@@ -418,6 +418,9 @@ def test_resident_mg_state_never_serves_stale_hierarchy():
 
 # -- cross-process warm start ------------------------------------------------
 
+# 73 s alone, 93 s in a full run (PR 25): two service sessions, each
+# compiling its MRHS solve; L=4, 2 gauges, 2 batches already
+@pytest.mark.slow
 def test_acceptance_two_workers_warm_start(tmp_path, monkeypatch):
     """The ISSUE-12 acceptance drill end to end.  Worker session A
     serves coalesced MRHS batches against 2 resident gauges under a

@@ -319,12 +319,18 @@ def test_fused_bitmatches_two_pass_sum_folded_links():
 
     ref = spk.dslash_staggered_packed_pairs(fat_pp, psi_pp, X, Y,
                                             long_pp)
-    two_pass = spl.dslash_staggered_pallas_v3(fat_pp, psi_pp, X,
-                                              long_pl=long_pp,
-                                              interpret=True, block_z=Z)
-    fused = spl.dslash_staggered_pallas_fused(fat_pp, psi_pp, X,
-                                              long_pl=long_pp,
-                                              interpret=True, block_z=Z)
+    # XLA:CPU's fusion emitters (jax 0.9.0) round a multiply-add chain
+    # by where the fusion boundary falls, which differs between the two
+    # forms; the claim is about the kernels' adds, so compile without.
+    exact = {"xla_cpu_use_fusion_emitters": False}
+    two_pass = jax.jit(
+        lambda f, p, l: spl.dslash_staggered_pallas_v3(
+            f, p, X, long_pl=l, interpret=True, block_z=Z),
+        compiler_options=exact)(fat_pp, psi_pp, long_pp)
+    fused = jax.jit(
+        lambda f, p, l: spl.dslash_staggered_pallas_fused(
+            f, p, X, long_pl=l, interpret=True, block_z=Z),
+        compiler_options=exact)(fat_pp, psi_pp, long_pp)
     # bit-identical to the two-pass sum (same adds, same order)
     assert bool(jnp.all(fused == two_pass))
     err = float(jnp.sqrt(blas.norm2(ref - fused) / blas.norm2(ref)))

@@ -156,3 +156,31 @@ def test_rng_deterministic_and_checkpointable():
     # full determinism from the seed
     r3 = LatticeRNG(7, GEOM)
     assert np.array_equal(np.asarray(r3.gaussian((4, 3))), np.asarray(a))
+
+
+def test_per_test_limit_ends_a_test_blocked_in_c(tmp_path):
+    """tests/conftest.py's per-test limit, driven for real: a pytest
+    run whose only test blocks in a C wait (no Python signal handler
+    would ever run) must die by itself, non-zero, with the traceback of
+    the blocked test on stderr despite pytest's capture."""
+    import subprocess
+    import sys
+    conftest = os.path.join(os.path.dirname(__file__), "conftest.py")
+    (tmp_path / "conftest.py").write_text(
+        "import importlib.util\n"
+        f"spec = importlib.util.spec_from_file_location('tier1', {conftest!r})\n"
+        "tier1 = importlib.util.module_from_spec(spec)\n"
+        "spec.loader.exec_module(tier1)\n"
+        "tier1.LIMIT_S = 5\n"
+        "pytest_runtest_call = tier1.pytest_runtest_call\n")
+    (tmp_path / "test_hang.py").write_text(
+        "import threading\n\n\n"
+        "def test_hang():\n"
+        "    threading.Event().wait()\n")
+    run = subprocess.run(      # TimeoutExpired: the limit did not fire
+        [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
+         "--rootdir", str(tmp_path), str(tmp_path)],
+        capture_output=True, text=True, timeout=30, cwd=str(tmp_path))
+    assert run.returncode != 0, run.stdout
+    assert "Timeout (0:00:05)!" in run.stderr, run.stderr
+    assert "in test_hang" in run.stderr, run.stderr
