@@ -1047,6 +1047,17 @@ def _record_solve_metrics(api: str, form: str, solver: str,
     omet.observe("solve_seconds", secs, api=api, family=family)
 
 
+def _note_solve_program(span, api: str, form: str, solver: str,
+                        hit: bool):
+    """A call went through a cached solve program (solvers/program.py):
+    hit or miss onto the solve span and the ``solve_program_total``
+    counter."""
+    from ..obs import metrics as omet
+    outcome = "hit" if hit else "miss"
+    span.set(program=outcome)
+    omet.record_solve_program(api, form, solver, outcome)
+
+
 def _invert_quda_body(source, param: InvertParam):
     from .. import solvers
     from ..obs import convergence as oconv
@@ -1228,8 +1239,8 @@ def _invert_quda_body(source, param: InvertParam):
     t_solve0 = time.perf_counter()
     with otr.phase("compute", "invert_quda"), \
             otr.span(f"solve:{inv}", cat="solver", mesh=_op_mesh(d),
-                     tol=param.tol, maxiter=param.maxiter):
-        # keyword-only at the call site: four adjacent bools among 17
+                     tol=param.tol, maxiter=param.maxiter) as solve_span:
+        # keyword-only at the call site: four adjacent bools among 18
         # parameters — a positional transposition would type-check and
         # silently pick the wrong solve route
         res = _invert_dispatch(param=param, d=d, d_full=d_full, b=b,
@@ -1238,7 +1249,12 @@ def _invert_quda_body(source, param: InvertParam):
                                mixed=mixed, pair_sloppy=pair_sloppy,
                                hermitian_pc=hermitian_pc, normop=normop,
                                sloppy_prec=sloppy_prec, dtype=dtype,
-                               pc=pc, t0=t0, recording=recording)
+                               pc=pc, t0=t0, recording=recording,
+                               solve_span=solve_span)
+        # the cached solve program returns at dispatch: wait here, so
+        # the phase, t_solve and the span hold the solve's device time
+        # and not the epilogue's first host read
+        jax.block_until_ready(res)
     if not isinstance(res, tuple):
         return res             # gcr-mg handled everything itself
     res, publish_sys_rhs = res
@@ -1322,29 +1338,44 @@ def _invert_quda_body(source, param: InvertParam):
 
 def _invert_dispatch(param, d, d_full, b, rhs, sys_rhs, mv, mv_applies,
                      inv, mixed, pair_sloppy, hermitian_pc, normop,
-                     sloppy_prec, dtype, pc, t0, recording):
+                     sloppy_prec, dtype, pc, t0, recording, solve_span):
     """The solver dispatch chain of invert_quda.  Returns
     ``(SolverResult, system_rhs_for_history)`` — or the finished
     solution array for the gcr-mg route, which completes its own
     epilogue/accounting."""
     from .. import solvers
+    from ..solvers import program as sprog
 
     if mixed and inv == "cg":
         if pair_sloppy:
             sl = d.sloppy(sloppy_prec)
-            # each operator representation (canonical / packed) supplies
-            # the codec matching its sloppy storage layout; the storage
-            # dtype comes from the BUILT sloppy operator so the two can
-            # never desynchronise
-            codec = (d.codec(dtype, sl.store_dtype)
-                     if hasattr(d, "codec")
-                     else solvers.pair_codec(sl.store_dtype, dtype))
-            # staggered PC is already the (Hermitian) normal operator
-            mv_lo = sl.M_pairs if hermitian_pc else sl.MdagM_pairs
-            res = solvers.cg_reliable(
-                mv, mv_lo, sys_rhs, tol=param.tol,
-                maxiter=param.maxiter, delta=param.reliable_delta,
-                codec=codec, record=recording)
+            if isinstance(d, _WilsonPairsSolve) and sprog.presents(d.op,
+                                                                   sl):
+                # the loop traced once per process: here mv IS
+                # d.op.MdagM_pairs (cg on this adapter always runs the
+                # normal equations) and d.codec the in-place pair
+                # codec, which the program rebuilds inside its trace
+                res, hit = sprog.cg_reliable(
+                    d.op, sl, sys_rhs, tol=param.tol,
+                    maxiter=param.maxiter, delta=param.reliable_delta,
+                    record=recording)
+                _note_solve_program(solve_span, "invert_quda",
+                                    _solve_form(d), inv, hit)
+            else:
+                # each operator representation (canonical / packed)
+                # supplies the codec matching its sloppy storage
+                # layout; the storage dtype comes from the BUILT sloppy
+                # operator so the two can never desynchronise
+                codec = (d.codec(dtype, sl.store_dtype)
+                         if hasattr(d, "codec")
+                         else solvers.pair_codec(sl.store_dtype, dtype))
+                # staggered PC is already the (Hermitian) normal
+                # operator
+                mv_lo = sl.M_pairs if hermitian_pc else sl.MdagM_pairs
+                res = solvers.cg_reliable(
+                    mv, mv_lo, sys_rhs, tol=param.tol,
+                    maxiter=param.maxiter, delta=param.reliable_delta,
+                    codec=codec, record=recording)
         else:
             sl = _build_sloppy(param, pc, sloppy_prec)
             if hermitian_pc:
@@ -1683,6 +1714,7 @@ def _invert_multi_src_body(sources, param: InvertParam):
                            breakdown=bk)
 
     if route == "batched":
+        from ..solvers import program as sprog
         from ..solvers.block import (_per_rhs_dot, batched_cg_pairs,
                                      block_cg_pairs)
         with otr.phase("setup", "invert_multi_src_quda"):
@@ -1719,16 +1751,29 @@ def _invert_multi_src_body(sources, param: InvertParam):
                                       fresh=True)) == "1"
         solver_name = "block-cg-pairs" if use_block else \
             "batched-cg-pairs"
+        form_b = ("staggered" if stag_family
+                  else param.dslash_type.replace("-", "_") if zoo_family
+                  else "wilson") + "_batched_pairs"
         t_solve0 = time.perf_counter()
         with otr.phase("compute", "invert_multi_src_quda"), \
                 otr.span(f"solve:{solver_name}", cat="solver",
-                         nrhs=n_src, tol=param.tol):
+                         nrhs=n_src, tol=param.tol) as solve_span:
             if use_block:
                 res = block_cg_pairs(mv_b, nrm_b,
                                      tol=param.tol,
                                      maxiter=param.maxiter,
                                      record=recording)
                 iters_rhs = np.full(n_src, int(res.iters))
+            elif sprog.presents(op):
+                # the loop traced once per process (an operator that
+                # presents is non-Hermitian: mv_b IS its
+                # MdagM_pairs_mrhs)
+                res, hit = sprog.batched_cg_pairs(
+                    op, nrm_b, tol=param.tol, maxiter=param.maxiter,
+                    record=recording)
+                _note_solve_program(solve_span, "invert_multi_src_quda",
+                                    form_b, solver_name, hit)
+                iters_rhs = np.asarray(res.iters)
             else:
                 res = batched_cg_pairs(mv_b, nrm_b,
                                        tol=param.tol,
@@ -1737,10 +1782,7 @@ def _invert_multi_src_body(sources, param: InvertParam):
                 iters_rhs = np.asarray(res.iters)
         t_solve = time.perf_counter() - t_solve0
         _record_solve_metrics(
-            "invert_multi_src_quda",
-            ("staggered" if stag_family
-             else param.dslash_type.replace("-", "_") if zoo_family
-             else "wilson") + "_batched_pairs",
+            "invert_multi_src_quda", form_b,
             solver_name, t_solve, param.dslash_type, param.cuda_prec)
         conv = np.asarray(res.converged)
         if not conv.all():

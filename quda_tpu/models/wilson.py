@@ -1118,6 +1118,47 @@ class DiracWilsonPCPackedSloppy(_PackedHopMixin, _PairSloppyBase):
         self.kappa = float(dpk.kappa)
         self.matpc = dpk.matpc
 
+    # -- solve-program operand (solvers/program.py) ---------------------
+    # The operator crosses a jit boundary as a pytree: the resident
+    # links and kappa are the LEAVES (operands of the compiled solve: a
+    # new configuration or a new mass reuses the executable, and no
+    # field is baked into it), everything that changes the traced
+    # computation is the static aux (part of jit's cache key).  Every
+    # attribute the stencil dispatch reads is in one list or the other.
+    _PROGRAM_ARRAYS = ("gauge_eo_pp", "_u_bw", "_gauge_q", "_gauge_s",
+                       "kappa")
+    _PROGRAM_STATIC = ("geom", "dims", "matpc", "store_dtype",
+                       "use_pallas", "_pallas_interpret", "_tb_sign",
+                       "_pallas_version", "_precision_form", "_block_z")
+
+    @property
+    def program_signature(self):
+        """The hashable static half of this operator as a solve-program
+        operand, or None when it cannot be one: a mesh operator races
+        its halo policy on concrete operands and memoises the shard_map
+        on the instance, so it keeps the eager solve."""
+        if self._mesh is not None:
+            return None
+        static = {n: getattr(self, n) for n in self._PROGRAM_STATIC}
+        static["store_dtype"] = jnp.dtype(self.store_dtype)
+        return tuple(static.values())
+
+    def tree_flatten(self):
+        sig = self.program_signature
+        if sig is None:
+            raise TypeError("a mesh-sharded packed pair operator is not "
+                            "a solve-program operand")
+        return (tuple(getattr(self, n, None)
+                      for n in self._PROGRAM_ARRAYS), sig)
+
+    @classmethod
+    def tree_unflatten(cls, sig, arrays):
+        op = object.__new__(cls)
+        vars(op).update(zip(cls._PROGRAM_STATIC, sig),
+                        _mesh=None, _mesh_yx=None)
+        vars(op).update(zip(cls._PROGRAM_ARRAYS, arrays))
+        return op
+
     def _to_pairs(self, x):
         from ..ops import wilson_packed as wpk
         return wpk.to_packed_pairs(x, self.store_dtype)
@@ -1193,6 +1234,9 @@ class DiracWilsonPCPackedSloppy(_PackedHopMixin, _PairSloppyBase):
         x_p = self.solution_from_pairs_mrhs(x_b, b_q.dtype)
         x_q = self.solution_from_pairs_mrhs(xq_b, b_q.dtype)
         return (x_p, x_q) if p == EVEN else (x_q, x_p)
+
+
+jax.tree_util.register_pytree_node_class(DiracWilsonPCPackedSloppy)
 
 
 class DiracWilsonPCSloppy(_PairSloppyBase):

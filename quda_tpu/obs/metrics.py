@@ -241,6 +241,18 @@ def record_execution(api: str, form: str, shape, dtype: str,
     return first
 
 
+def record_solve_program(api: str, form: str, solver: str, outcome: str):
+    """One call through a cached solve program (solvers/program.py):
+    ``outcome`` 'miss' traced the loop program in this call, 'hit'
+    found its executable in the process (no-op when metrics are off)."""
+    r = _session
+    if r is None:
+        return
+    r.inc("solve_program_total", 1.0,
+          {"api": api, "form": form, "solver": solver,
+           "outcome": outcome})
+
+
 def executable_keys() -> set:
     """Snapshot of the (api, form, shape, dtype, solver) keys executed
     this session (the rendered-string form ``record_execution`` keys

@@ -10,7 +10,10 @@ module is the same discipline for the telemetry surface:
   belongs to and a one-line meaning;
 * ``METRICS``      — every metric the registry (obs/metrics.py) may
   record, with its type (counter | gauge | histogram) and help string
-  (exported verbatim into the Prometheus ``# HELP`` lines).
+  (exported verbatim into the Prometheus ``# HELP`` lines);
+* ``SPAN_ATTRS``   — every attribute a span gains after its work has
+  run (obs/trace ``span.set``, validated there), with the spans that
+  carry it.
 
 ``tests/test_obs_schema_lint.py`` AST-harvests every emission site in
 the package and asserts BOTH directions: no emitted name missing here,
@@ -126,6 +129,16 @@ TRACE_EVENTS: dict[str, dict] = {
                               "reported at session stop)"},
 }
 
+# -- span attributes set after the fact (obs/trace span.set) ----------------
+
+SPAN_ATTRS: dict[str, dict] = {
+    "program": {"spans": ("solve:cg", "solve:batched-cg-pairs"),
+                "doc": "'hit' | 'miss': whether the cached solve "
+                       "program (solvers/program.py) served the call "
+                       "from the in-process executable cache or traced "
+                       "anew; absent on an eager solve"},
+}
+
 # -- metrics (obs/metrics.py registry) --------------------------------------
 
 COUNTER, GAUGE, HISTOGRAM = "counter", "gauge", "histogram"
@@ -159,6 +172,13 @@ METRICS: dict[str, dict] = {
         "type": COUNTER,
         "help": "compute-phase executions per api/form (warm "
                 "executable after the first)"},
+    "solve_program_total": {
+        "type": COUNTER,
+        "help": "calls through a cached solve program "
+                "(solvers/program.py), by api/form/solver/outcome: "
+                "'miss' traced (and lowered, compiled or fetched) the "
+                "loop program, 'hit' was an in-process executable "
+                "lookup"},
     # tuner warm-cache accounting (utils/tune.py)
     "tune_cache_hits_total": {
         "type": COUNTER,

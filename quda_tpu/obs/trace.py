@@ -36,6 +36,7 @@ from typing import Optional
 # the flight recorder taps this module's event stream (obs/flight.py
 # imports nothing from here at module level, so the edge is acyclic)
 from . import flight as _flight
+from . import schema
 
 
 class _NoopSpan:
@@ -47,6 +48,9 @@ class _NoopSpan:
 
     def __exit__(self, *exc):
         return False
+
+    def set(self, **attrs):
+        pass
 
 
 _NOOP = _NoopSpan()
@@ -215,6 +219,16 @@ class _Span:
         self._depth = 0
         self._tid = 0
         self._mesh = mesh
+
+    def set(self, **attrs):
+        """Attributes known only once the spanned work has run (every
+        name registered in obs/schema.SPAN_ATTRS)."""
+        unknown = set(attrs) - set(schema.SPAN_ATTRS)
+        if unknown:
+            raise KeyError(
+                f"unregistered span attribute(s) {sorted(unknown)}; "
+                "register them in quda_tpu/obs/schema.py SPAN_ATTRS")
+        self.args.update(attrs)
 
     def __enter__(self):
         s = _session

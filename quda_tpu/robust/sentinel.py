@@ -113,6 +113,15 @@ class Sentinel:
     def __init__(self, stagnation_checks: int = 0):
         self.stagnation_checks = int(stagnation_checks)
 
+    # value semantics: a sentinel is part of a cached solve program's
+    # key (solvers/program.py), and two with one count trace alike
+    def __eq__(self, other):
+        return (isinstance(other, Sentinel)
+                and self.stagnation_checks == other.stagnation_checks)
+
+    def __hash__(self):
+        return hash((Sentinel, self.stagnation_checks))
+
     def init(self, r2):
         r2 = jnp.asarray(r2)
         return (jnp.int32(NONE), r2, jnp.int32(0))

@@ -171,11 +171,25 @@ def batched_cg_pairs(matvec_batch: Callable, B: jnp.ndarray,
     from ..robust import faultinject as finj
     from ..robust import sentinel as rsent
     from .fused_iter import _resolve_check_every
+    return batched_cg_pairs_loop(
+        matvec_batch, B, tol, maxiter, _resolve_check_every(check_every),
+        record, rsent.make(), finj.iteration_fault("dslash"))
+
+
+def batched_cg_pairs_loop(matvec_batch: Callable, B: jnp.ndarray, tol,
+                          maxiter, check_every: int, record: bool, sent,
+                          fault_k: Optional[int]) -> BatchedCGResult:
+    """``batched_cg_pairs`` with every knob already resolved by the
+    caller (the cadence, ``sent``: robust/sentinel.Sentinel or None,
+    ``fault_k``: the armed dslash fault iteration or None), so nothing
+    here reads host state: the body the cached solve program
+    (solvers/program.py) traces once per key.  ``tol`` and ``maxiter``
+    may be traced scalars, except that ``record`` sizes the history by
+    a concrete ``maxiter``."""
+    from ..robust import faultinject as finj
+    from ..robust import sentinel as rsent
     n = B.shape[0]
     _check_nrhs(n)
-    check_every = _resolve_check_every(check_every)
-    sent = rsent.make()
-    fault_k = finj.iteration_fault("dslash")
     rdt = jnp.float32 if B.dtype == jnp.bfloat16 else B.dtype
     # scalar-lane dtype: the real counterpart of rdt, so complex
     # batches (the MG setup's null-vector solves on the complex

@@ -119,6 +119,22 @@ def packed_pair_codec(store_dtype, precise_dtype) -> StorageCodec:
         lambda x: wpk.from_packed_pairs(x, precise_dtype), store_dtype)
 
 
+def pair_inplace_config(store_dtype, use_pallas_tail: Optional[bool] = None,
+                        pallas_interpret: Optional[bool] = None) -> tuple:
+    """``pair_inplace_codec``'s arguments with every ``None`` resolved
+    (knob and backend read HERE, outside any trace): the hashable triple
+    the cached solve program (solvers/program.py) keys on and rebuilds
+    the codec from inside its trace."""
+    if use_pallas_tail is None:
+        from ..utils import config as qconf
+        use_pallas_tail = str(qconf.get("QUDA_TPU_FUSED_TAIL",
+                                        fresh=True)) == "1"
+    if pallas_interpret is None:
+        pallas_interpret = jax.default_backend() != "tpu"
+    return (jnp.dtype(store_dtype), bool(use_pallas_tail),
+            bool(pallas_interpret))
+
+
 def pair_inplace_codec(store_dtype, use_pallas_tail: Optional[bool] = None,
                        pallas_interpret: Optional[bool] = None
                        ) -> StorageCodec:
@@ -133,12 +149,8 @@ def pair_inplace_codec(store_dtype, use_pallas_tail: Optional[bool] = None,
     silently doing nothing is the failure mode utils/config.py exists
     to kill).  ``pallas_interpret=None`` resolves to interpret mode on
     non-TPU backends."""
-    if use_pallas_tail is None:
-        from ..utils import config as qconf
-        use_pallas_tail = str(qconf.get("QUDA_TPU_FUSED_TAIL",
-                                        fresh=True)) == "1"
-    if pallas_interpret is None:
-        pallas_interpret = jax.default_backend() != "tpu"
+    store_dtype, use_pallas_tail, pallas_interpret = pair_inplace_config(
+        store_dtype, use_pallas_tail, pallas_interpret)
     return _make_pair_codec(
         lambda x: x.astype(store_dtype),
         lambda x: x.astype(jnp.float32), store_dtype,
@@ -173,8 +185,23 @@ def cg_reliable(matvec_hi: Callable, matvec_lo: Callable, b: jnp.ndarray,
     # exact unguarded computation
     from ..robust import faultinject as finj
     from ..robust import sentinel as rsent
-    sent = rsent.make()
-    fault_k = finj.iteration_fault("dslash")
+    return cg_reliable_loop(matvec_hi, matvec_lo, b, tol, maxiter, delta,
+                            codec, record, rsent.make(),
+                            finj.iteration_fault("dslash"))
+
+
+def cg_reliable_loop(matvec_hi: Callable, matvec_lo: Callable,
+                     b: jnp.ndarray, tol, maxiter, delta: float,
+                     codec: StorageCodec, record: bool, sent,
+                     fault_k: Optional[int]) -> SolverResult:
+    """``cg_reliable`` with every knob already resolved by the caller
+    (``sent``: robust/sentinel.Sentinel or None; ``fault_k``: the armed
+    dslash fault iteration or None), so nothing here reads host state:
+    the body the cached solve program (solvers/program.py) traces once
+    per key.  ``tol`` and ``maxiter`` may be traced scalars, except
+    that ``record`` sizes the history by a concrete ``maxiter``."""
+    from ..robust import faultinject as finj
+    from ..robust import sentinel as rsent
     b2 = blas.norm2(b)
     stop = (tol ** 2) * b2
 

@@ -1,0 +1,116 @@
+"""The solver loop as ONE cached, jitted program per key.
+
+``mixed.cg_reliable`` and ``block.batched_cg_pairs`` run
+``lax.while_loop`` over closures; called eagerly from the API with
+operators rebuilt per call, every call re-traced the loop (a dozen
+pallas_calls), re-lowered it through Mosaic and hashed the module before
+the persistent cache could serve the executable: 1.2-1.4 s of host time
+per call at 24^4 with the device idle (PERF_LEDGER, PR 25 lines).  Here
+the same loop bodies (``cg_reliable_loop`` / ``batched_cg_pairs_loop``,
+no solver logic copied) sit under one module-level ``jax.jit`` each, so
+a later call with the same key is an in-process executable lookup.
+
+* **Operands** (new values reuse the executable; nothing the size of a
+  field is closed over): the source, the operators' resident links and
+  kappa (the operator is a pytree: models/wilson
+  ``DiracWilsonPCPackedSloppy.tree_flatten``), ``tol``, and ``maxiter``
+  unless ``record`` sizes the history by it.
+* **Key** (a change gives a new program, never a stale one), all
+  resolved OUTSIDE the trace on every call: the operators' static
+  signature (class, dims, matpc, tb_sign, pallas version, block_z,
+  precision form, storage dtype, pallas/interpret flags: the treedef),
+  the operand avals, and the loop's own knobs below (``delta``, the
+  codec's fused-tail choice, ``check_every``, ``record``, the sentinel,
+  the armed fault iteration).  The key holds no array and no operator
+  identity.
+
+An operator goes through a program when it ``presents``: its class is a
+registered pytree with a ``program_signature``.  Everything else (a
+mesh operator, an MG closure, a bare lambda, the staggered and zoo pair
+operators) keeps the eager solver call.  Both programs solve the NORMAL
+equations of a non-Hermitian PC operator (``MdagM_pairs`` /
+``MdagM_pairs_mrhs``), the only shape a presenting operator has today.
+"""
+
+from __future__ import annotations
+
+from functools import partial
+from typing import NamedTuple, Optional
+
+import jax
+
+from . import block, mixed
+
+# program bodies run only while jax traces them: a call that leaves
+# this count unchanged was served by the in-process executable cache
+_traces = [0]
+
+
+class _LoopKnobs(NamedTuple):
+    """What the loops read from host state, resolved per call."""
+    record: bool
+    maxiter: Optional[int]       # only when ``record`` sizes by it
+    sentinel: Optional[object]   # robust/sentinel.Sentinel (hashable)
+    fault_k: Optional[int]       # armed dslash fault iteration
+
+
+def _loop_knobs(record: bool, maxiter: int) -> _LoopKnobs:
+    from ..robust import faultinject as finj
+    from ..robust import sentinel as rsent
+    return _LoopKnobs(bool(record), int(maxiter) if record else None,
+                      rsent.make(), finj.iteration_fault("dslash"))
+
+
+def presents(*ops) -> bool:
+    """Whether every operator can cross the jit boundary as (static
+    signature, array operands)."""
+    return all(getattr(op, "program_signature", None) is not None
+               for op in ops)
+
+
+def _run(program, *operands, key):
+    n0 = _traces[0]
+    out = program(*operands, key=key)
+    return out, _traces[0] == n0
+
+
+@partial(jax.jit, static_argnames=("key",))
+def _cg_reliable_program(op_hi, op_lo, b, tol, maxiter, key):
+    _traces[0] += 1
+    delta, codec_cfg, knobs = key
+    return mixed.cg_reliable_loop(
+        op_hi.MdagM_pairs, op_lo.MdagM_pairs, b, tol,
+        knobs.maxiter if knobs.record else maxiter, delta,
+        mixed.pair_inplace_codec(*codec_cfg), knobs.record,
+        knobs.sentinel, knobs.fault_k)
+
+
+def cg_reliable(op_hi, op_lo, b, tol: float, maxiter: int, delta: float,
+                record: bool = False):
+    """``mixed.cg_reliable`` on ``op_hi.MdagM_pairs`` (precise) and
+    ``op_lo.MdagM_pairs`` (sloppy storage, the in-place pair codec)
+    through the cached program.  Returns ``(SolverResult, hit)``."""
+    key = (float(delta), mixed.pair_inplace_config(op_lo.store_dtype),
+           _loop_knobs(record, maxiter))
+    return _run(_cg_reliable_program, op_hi, op_lo, b, float(tol),
+                int(maxiter), key=key)
+
+
+@partial(jax.jit, static_argnames=("key",))
+def _batched_cg_pairs_program(op, B, tol, maxiter, key):
+    _traces[0] += 1
+    check_every, knobs = key
+    return block.batched_cg_pairs_loop(
+        op.MdagM_pairs_mrhs, B, tol,
+        knobs.maxiter if knobs.record else maxiter, check_every,
+        knobs.record, knobs.sentinel, knobs.fault_k)
+
+
+def batched_cg_pairs(op, B, tol: float, maxiter: int,
+                     record: bool = False):
+    """``block.batched_cg_pairs`` on ``op.MdagM_pairs_mrhs`` through
+    the cached program.  Returns ``(BatchedCGResult, hit)``."""
+    from .fused_iter import _resolve_check_every
+    key = (_resolve_check_every(None), _loop_knobs(record, maxiter))
+    return _run(_batched_cg_pairs_program, op, B, float(tol),
+                int(maxiter), key=key)

@@ -163,3 +163,69 @@ def test_kernel_compiles_for_v5e(case, one_chip):
         jax.config.update("jax_enable_compilation_cache", cache_was)
         cc.reset_cache()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+_HLO_BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2,
+              "u16": 2, "f32": 4, "s32": 4, "u32": 4, "f64": 8, "s64": 8,
+              "u64": 8, "c64": 8, "c128": 16}
+
+
+def _hlo_values(hlo, op):
+    """(bytes, dtype, dims) of every ``op(`` instruction of an HLO text."""
+    import math
+    import re
+    pat = re.compile(r"= (\w+)\[([\d,]*)\](?:\{[^}]*\})? " + op + r"\(")
+    return [(_HLO_BYTES[dt] * math.prod(int(d) for d in dims.split(",")
+                                        if d), dt, dims)
+            for dt, dims in pat.findall(hlo)]
+
+
+def test_solve_program_compiles_for_v5e_with_links_as_parameters(one_chip):
+    """The single-source solve program (solvers/program.py: mixed
+    f32/bf16 reliable CG, prologue + while_loop + final fold) at 24^4:
+    lowers and compiles for the described chip with the resident links
+    of both operators as PARAMETERS, and bakes no field into the
+    executable (PR 22 cause 8: a closed-over gauge was a 353 MB
+    constant)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from quda_tpu.fields.geometry import LatticeGeometry
+    from quda_tpu.models.wilson import DiracWilsonPC
+    from quda_tpu.solvers import mixed
+    from quda_tpu.solvers import program as sprog
+    geom = LatticeGeometry(DIMS)
+
+    def operators(gauge):
+        dpk = DiracWilsonPC(gauge, geom, 0.124).packed()
+        return tuple(dpk.pairs(dt, use_pallas=True, pallas_interpret=False,
+                               pallas_version=2, precision_form="full")
+                     for dt in (F32, BF16))
+
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        with jax.enable_x64(False):
+            ops = jax.eval_shape(operators, jax.ShapeDtypeStruct(
+                (4,) + DIMS + (3, 3), jnp.complex64))
+            hi, lo = jax.tree_util.tree_map(
+                lambda s: jax.ShapeDtypeStruct(
+                    s.shape, s.dtype, sharding=one_chip,
+                    weak_type=s.weak_type), ops)
+            b = jax.ShapeDtypeStruct(*_psi(F32), sharding=one_chip)
+            key = (0.1, mixed.pair_inplace_config(BF16, False, False),
+                   sprog._LoopKnobs(False, None, None, None))
+            compiled = sprog._cg_reliable_program.lower(
+                hi, lo, b, 1e-6, 10000, key=key).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        cc.reset_cache()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    params = _hlo_values(hlo, "parameter")
+    links = ",".join(str(d) for d in _links(F32)[0])
+    # forward + pre-shifted backward links of two parities, per operator
+    assert sum(p[1:] == ("f32", links) for p in params) == 4
+    assert sum(p[1:] == ("bf16", links) for p in params) == 4
+    consts = _hlo_values(hlo, "constant")
+    big = [c for c in consts if c[0] > 2 ** 20]
+    assert consts and not big, f"fields baked into the executable: {big}"

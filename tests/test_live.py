@@ -408,15 +408,19 @@ def test_periodic_flusher_rewrites_on_interval(monkeypatch, tmp_path):
     olive.start(port=0)
     assert olive._session.flusher is not None
     prom = os.path.join(tmp_path, "metrics.prom")
+
+    def flushes():
+        return sum(v for (n, _), v in omet.snapshot()["counters"].items()
+                   if n == "live_flushes_total")
+    # a window writes metrics.prom first and counts itself last, after
+    # the flight and roofline legs: wait for the whole window
     deadline = time.time() + 15.0
-    while time.time() < deadline and not os.path.exists(prom):
+    while time.time() < deadline and not (os.path.exists(prom)
+                                          and flushes()):
         time.sleep(0.05)
     assert os.path.exists(prom), "flusher never wrote metrics.prom"
     from quda_tpu.obs import schema as osch
-    snap = omet.snapshot()
-    flushes = sum(v for (n, _), v in snap["counters"].items()
-                  if n == "live_flushes_total")
-    assert flushes >= 1
+    assert flushes() >= 1
     assert osch.METRICS["live_flushes_total"]["type"] == osch.COUNTER
 
 

@@ -222,6 +222,48 @@ def test_pick_bz_feeds_vmem_audit(tmp_path):
     assert "vmem_budget_bytes" in gauges
 
 
+def test_solve_program_counts_one_miss_then_hits(tmp_path):
+    """Three calls through the cached solve program (solvers/program.py;
+    here on the cheap XLA pair stencil at 4^4, new links every call):
+    ``solve_program_total`` reads one miss then two hits, and the solve
+    span carries ``program`` likewise."""
+    from quda_tpu.fields.gauge import GaugeField
+    from quda_tpu.fields.geometry import LatticeGeometry
+    from quda_tpu.interfaces.quda_api import _note_solve_program
+    from quda_tpu.models.wilson import DiracWilsonPC
+    from quda_tpu.solvers import program as sprog
+    omet.start(str(tmp_path))
+    otr.start(str(tmp_path))
+    geom = LatticeGeometry((4, 4, 4, 4))
+    rng = np.random.default_rng(5)
+    for i in range(3):
+        g = GaugeField.random(jax.random.PRNGKey(40 + i), geom)
+        dpk = DiracWilsonPC(g.data.astype(jnp.complex64), geom,
+                            0.11 + 0.005 * i).packed()
+        hi, lo = dpk.pairs(jnp.float32), dpk.pairs(jnp.bfloat16)
+        b = jnp.asarray(rng.standard_normal((4, 3, 2, 4, 4, 8)),
+                        jnp.float32)
+        with otr.span("solve:cg", cat="solver") as sp:
+            res, hit = sprog.cg_reliable(hi, lo, b, tol=1e-5, maxiter=200,
+                                         delta=0.1)
+            _note_solve_program(sp, "invert_quda", "wilson_xla", "cg",
+                                hit)
+        assert bool(res.converged)
+        r = b - hi.MdagM_pairs(res.x)
+        assert float(jnp.linalg.norm(r) / jnp.linalg.norm(b)) < 2e-5
+    by_outcome = {dict(lab)["outcome"]: v
+                  for (n, lab), v in omet.snapshot()["counters"].items()
+                  if n == "solve_program_total"}
+    assert by_outcome == {"miss": 1.0, "hit": 2.0}
+    prom = omet.render_prometheus()
+    assert ('quda_tpu_solve_program_total{api="invert_quda",'
+            'form="wilson_xla",outcome="hit",solver="cg"} 2') in prom
+    paths = otr.stop()
+    spans = [json.loads(ln) for ln in open(paths["jsonl"])]
+    assert [s["program"] for s in spans
+            if s.get("name") == "solve:cg"] == ["miss", "hit", "hit"]
+
+
 # -- acceptance: metrics-on session end to end ------------------------------
 
 def _unit_gauge(L):

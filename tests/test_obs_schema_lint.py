@@ -13,6 +13,8 @@ metrics registry also validates names at RECORD time
 (obs/metrics._Registry._check), so the dynamic half is covered even
 off-CI."""
 
+import pytest
+
 from quda_tpu import analysis
 from quda_tpu.obs import schema as osch
 
@@ -63,3 +65,27 @@ def test_schema_entries_carry_docs():
         assert meta["type"] in (osch.COUNTER, osch.GAUGE,
                                 osch.HISTOGRAM), name
         assert len(meta["help"]) > 10, name
+    for name, meta in osch.SPAN_ATTRS.items():
+        assert meta["spans"] and len(meta["doc"]) > 10, name
+
+
+def test_solve_program_counter_and_span_attribute_are_registered(tmp_path):
+    """The cached solve program's two signals (solvers/program.py):
+    the hit/miss counter and the ``program`` attribute of the solve
+    span.  A span attribute set after the fact is validated at record
+    time like a metric name."""
+    from quda_tpu.obs import trace as otr
+    assert osch.metric_type("solve_program_total") == osch.COUNTER
+    assert set(osch.SPAN_ATTRS["program"]["spans"]) == {
+        "solve:cg", "solve:batched-cg-pairs"}
+    otr.stop(flush_files=False)
+    otr.span("solve:cg").set(anything="ignored: tracing is off")
+    otr.start(str(tmp_path))
+    try:
+        with otr.span("solve:cg", cat="solver") as sp:
+            sp.set(program="hit")
+            with pytest.raises(KeyError, match="unregistered span"):
+                sp.set(progam="hit")
+        assert otr._session.jsonl[-1]["program"] == "hit"
+    finally:
+        otr.stop(flush_files=False)

@@ -1,0 +1,333 @@
+"""The cached solve program (solvers/program.py): the solver loop is
+traced once per process per key, and everything that differs from call
+to call is an operand.
+
+Every count is read from the ``solve_program_total`` counter the
+mechanism itself reports (obs/metrics).  8^4, interpret-mode kernels,
+through ``invert_quda`` (mixed f32/bf16 reliable CG on the Wilson packed
+pair operator) and ``invert_multi_src_quda`` (the f32 batched-pairs
+route).  A miss costs the CPU a 20-40 s compile of interpreted kernels,
+so the first call of each route is made once per worker, in a fixture,
+and what does not need the kernels (the comparison with the eager
+solver, the operand's own tests) runs on the XLA pair stencil at 4^4.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from quda_tpu.interfaces import quda_api as api
+from quda_tpu.interfaces.params import GaugeParam, InvertParam
+from quda_tpu.obs import metrics as omet
+from quda_tpu.obs import trace as otr
+from quda_tpu.robust import faultinject as finj
+from quda_tpu.solvers import program as sprog
+from quda_tpu.utils import config as qconf
+from tests.host_reference.wilson_ref import wilson_mat_ref
+
+L = 8
+KAPPA = 0.12
+ROUTES = ("single", "multi")
+KEY = {"single": dict(api="invert_quda", form="wilson_v2", solver="cg"),
+       "multi": dict(api="invert_multi_src_quda",
+                     form="wilson_batched_pairs",
+                     solver="batched-cg-pairs")}
+
+
+def _gauge(seed):
+    from quda_tpu.fields.gauge import GaugeField
+    from quda_tpu.fields.geometry import LatticeGeometry
+    g = GaugeField.random(jax.random.PRNGKey(seed),
+                          LatticeGeometry((L,) * 4))
+    return np.asarray(g.data.astype(jnp.complex64))
+
+
+def _sources(seed, n):
+    rng = np.random.default_rng(seed)
+    shape = (n, L, L, L, L, 4, 3)
+    return (rng.standard_normal(shape)
+            + 1j * rng.standard_normal(shape)).astype(np.complex64)
+
+
+def _param(kappa=KAPPA):
+    return InvertParam(dslash_type="wilson", inv_type="cg",
+                       solve_type="normop-pc", kappa=kappa, tol=1e-6,
+                       maxiter=500, cuda_prec="single",
+                       cuda_prec_sloppy="half")
+
+
+def _solve(route, seed, kappa=KAPPA):
+    """One API call on sources drawn from ``seed``: (sources, solutions,
+    param), each with a leading source axis."""
+    p = _param(kappa)
+    if route == "single":
+        b = _sources(seed, 1)
+        return b, np.asarray(api.invert_quda(b[0], p))[None], p
+    b = _sources(seed, 2)
+    return b, np.asarray(api.invert_multi_src_quda(b, p)), p
+
+
+def _counts(route):
+    """(misses, hits) of the route's solve program so far."""
+    want = KEY[route]
+    out = {"miss": 0, "hit": 0}
+    for (name, labels), v in omet.snapshot()["counters"].items():
+        lab = dict(labels)
+        if name == "solve_program_total" and all(
+                lab[k] == w for k, w in want.items()):
+            out[lab["outcome"]] += int(v)
+    return out["miss"], out["hit"]
+
+
+def _delta(route, before):
+    m, h = _counts(route)
+    return m - before[0], h - before[1]
+
+
+def _host_residual(gauge, b, x, kappa):
+    r = b - wilson_mat_ref(gauge.astype(np.complex128),
+                           x.astype(np.complex128), kappa)
+    return float(np.linalg.norm(r) / np.linalg.norm(b))
+
+
+@pytest.fixture(scope="module")
+def gauges():
+    return {"A": _gauge(11), "B": _gauge(12)}
+
+
+@pytest.fixture(scope="module")
+def quda(gauges, tmp_path_factory):
+    """init + resident gauge A + a metrics session, with the
+    interpret-mode pallas pair route selected (the TPU default)."""
+    mp = pytest.MonkeyPatch()
+    mp.setenv("QUDA_TPU_PACKED", "1")
+    mp.setenv("QUDA_TPU_PALLAS", "1")
+    # conftest's eight virtual devices would take a batch of two to the
+    # split-grid route: the batched route is the one-chip route
+    mp.setenv("QUDA_TPU_MULTI_SRC_SPLIT", "0")
+    for knob in ("QUDA_TPU_ROBUST", "QUDA_TPU_FAULT", "QUDA_TPU_TRACE",
+                 "QUDA_TPU_FUSED_TAIL", "QUDA_TPU_CG_CHECK_EVERY"):
+        mp.delenv(knob, raising=False)
+    qconf.reset_cache()
+    finj.reset()
+    otr.stop(flush_files=False)
+    api.init_quda()
+    omet.start(str(tmp_path_factory.mktemp("solve_program")))
+    api.load_gauge_quda(gauges["A"], GaugeParam(X=(L,) * 4,
+                                                cuda_prec="single"))
+    yield
+    omet.stop(flush_files=False)
+    api.end_quda()
+    mp.undo()
+    qconf.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def first_calls(quda):
+    return {}
+
+
+@pytest.fixture
+def warm(route, first_calls):
+    """The (misses, hits) that the worker's first call of ``route``
+    added; made here, in set-up, once per worker.  Later calls with the
+    same key must all be hits."""
+    if route not in first_calls:
+        before = _counts(route)
+        _solve(route, seed=1)
+        first_calls[route] = _delta(route, before)
+    return first_calls[route]
+
+
+@pytest.fixture
+def knobs(monkeypatch):
+    """Set or clear knobs for one test; everything is put back (and the
+    fault registry disarmed) after it."""
+    def set_(**env):
+        for k, v in env.items():
+            if v is None:
+                monkeypatch.delenv(k, raising=False)
+            else:
+                monkeypatch.setenv(k, v)
+        qconf.reset_cache()
+        finj.reset()
+    yield set_
+    monkeypatch.undo()
+    qconf.reset_cache()
+    finj.reset()
+    otr.stop(flush_files=False)
+
+
+# (a), (d): sources and links are operands ------------------------------------
+
+@pytest.mark.parametrize("route", ROUTES)
+def test_new_source_and_new_gauge_reuse_the_program(route, warm, gauges):
+    assert sum(warm) == 1         # at most the process's one trace
+    before = _counts(route)
+    b2, x2, p2 = _solve(route, seed=2)
+    try:
+        api.load_gauge_quda(gauges["B"], GaugeParam(X=(L,) * 4,
+                                                    cuda_prec="single"))
+        b3, x3, p3 = _solve(route, seed=3)
+    finally:
+        api.load_gauge_quda(gauges["A"], GaugeParam(X=(L,) * 4,
+                                                    cuda_prec="single"))
+    assert _delta(route, before) == (0, 2)
+    # each call returned the solution of ITS gauge, not of the links the
+    # program was traced with
+    for i in range(len(b2)):
+        assert _host_residual(gauges["A"], b2[i], x2[i], KAPPA) < 5e-6
+        assert _host_residual(gauges["B"], b3[i], x3[i], KAPPA) < 5e-6
+    assert _host_residual(gauges["A"], b3[0], x3[0], KAPPA) > 1e-2
+    assert p2.converged and p3.converged
+
+
+# (b), (d): kappa is an operand ----------------------------------------------
+
+@pytest.mark.parametrize("route", ROUTES)
+def test_second_kappa_reuses_the_program(route, warm, gauges):
+    before = _counts(route)
+    b, x, p = _solve(route, seed=4, kappa=0.105)
+    assert _delta(route, before) == (0, 1)
+    assert p.converged
+    assert _host_residual(gauges["A"], b[0], x[0], 0.105) < 5e-6
+    assert _host_residual(gauges["A"], b[0], x[0], KAPPA) > 1e-3
+
+
+# (c), (d): what changes the traced loop is in the key ---------------------
+
+FLIPS = {
+    # what the loop reads -> the environment that flips it ("record" is
+    # a trace session, started in the test)
+    "fused_tail": {"QUDA_TPU_FUSED_TAIL": "1"},
+    "check_every": {"QUDA_TPU_CG_CHECK_EVERY": "2"},
+    "robust": {"QUDA_TPU_ROBUST": "verify"},
+    "fault": {"QUDA_TPU_FAULT": "dslash:3"},
+    "robust+fault": {"QUDA_TPU_ROBUST": "verify",
+                     "QUDA_TPU_FAULT": "dslash:3"},
+    "record": {},
+}
+
+
+# Sentinel, fault and record are resolved by one function for both
+# programs (program._loop_knobs), and a miss on the single-source route
+# compiles twice as long: there the sentinel and the fault flip
+# together, and one at a time on the batched route.
+@pytest.mark.parametrize("route,flip", [
+    ("single", "fused_tail"), ("single", "robust+fault"),
+    ("single", "record"),
+    ("multi", "check_every"), ("multi", "robust"), ("multi", "fault"),
+    ("multi", "record")])
+def test_a_flipped_knob_is_a_miss_and_back_a_hit(route, flip, warm, knobs,
+                                                 tmp_path):
+    env = FLIPS[flip]
+    before = _counts(route)
+    knobs(**env)
+    if flip == "record":
+        otr.start(str(tmp_path))
+    _, _, p = _solve(route, seed=5)
+    assert _delta(route, before) == (1, 0), "a stale program served it"
+    if "fault" in flip:
+        # the program that was traced holds the fault: the solve broke
+        assert finj.fired("dslash") and not p.converged
+        if "robust" in flip:     # ... and its sentinel said so
+            assert p.solve_status == "breakdown:nonfinite"
+    elif flip == "record":
+        assert len(p.res_history) > 0
+        spans = [e for e in otr._session.jsonl
+                 if e.get("kind") == "span"
+                 and e["name"].startswith("solve:")]
+        assert spans[-1]["program"] == "miss"
+        otr.stop(flush_files=False)
+    else:
+        assert p.converged
+    knobs(**{k: None for k in env})
+    _, _, p = _solve(route, seed=6)
+    assert _delta(route, before) == (1, 1)
+    assert p.converged
+
+
+# (e): the cached program is the eager solver ------------------------------
+
+@pytest.mark.parametrize("route", ROUTES)
+def test_cached_program_equals_the_eager_solver(route):
+    """Same operators, same right-hand side, through the program and
+    through the eager solver function (on the XLA pair stencil at 4^4:
+    what is compared is the program, not the kernels)."""
+    from quda_tpu.solvers import batched_cg_pairs, cg_reliable
+    from quda_tpu.solvers.mixed import pair_inplace_codec
+    dpk = _packed(3, KAPPA)
+    hi, lo = dpk.pairs(jnp.float32), dpk.pairs(jnp.bfloat16)
+    rng = np.random.default_rng(8)
+    kw = dict(tol=1e-5, maxiter=300)
+    if route == "single":
+        b = jnp.asarray(rng.standard_normal((4, 3, 2, 4, 4, 8)),
+                        jnp.float32)
+        cached, _ = sprog.cg_reliable(hi, lo, b, delta=0.1, **kw)
+        eager = cg_reliable(hi.MdagM_pairs, lo.MdagM_pairs, b, delta=0.1,
+                            codec=pair_inplace_codec(jnp.bfloat16), **kw)
+    else:
+        b = jnp.asarray(rng.standard_normal((2, 4, 3, 2, 4, 4, 8)),
+                        jnp.float32)
+        cached, _ = sprog.batched_cg_pairs(hi, b, **kw)
+        eager = batched_cg_pairs(hi.MdagM_pairs_mrhs, b, **kw)
+    assert np.all(np.asarray(cached.converged))
+    np.testing.assert_array_equal(np.asarray(cached.iters),
+                                  np.asarray(eager.iters))
+    np.testing.assert_allclose(np.asarray(cached.r2),
+                               np.asarray(eager.r2), rtol=1e-3)
+    np.testing.assert_allclose(np.asarray(cached.x), np.asarray(eager.x),
+                               rtol=0, atol=1e-5 * float(
+                                   jnp.max(jnp.abs(eager.x))))
+
+
+# the operand itself ------------------------------------------------------
+
+def _packed(seed, kappa, lat=4):
+    from quda_tpu.fields.gauge import GaugeField
+    from quda_tpu.fields.geometry import LatticeGeometry
+    from quda_tpu.models.wilson import DiracWilsonPC
+    geom = LatticeGeometry((lat,) * 4)
+    g = GaugeField.random(jax.random.PRNGKey(seed), geom)
+    return DiracWilsonPC(g.data.astype(jnp.complex64), geom,
+                         kappa).packed()
+
+
+@pytest.mark.parametrize("store,pallas,form", [
+    (jnp.float32, True, None), (jnp.bfloat16, False, None),
+    (jnp.float32, True, "r12f"), (jnp.bfloat16, True, "int8")])
+def test_operator_is_arrays_plus_a_small_static_key(store, pallas, form):
+    kw = dict(use_pallas=pallas, pallas_interpret=True,
+              precision_form=form)
+    op = _packed(1, 0.12).pairs(store, **kw)
+    leaves, treedef = jax.tree_util.tree_flatten(op)
+    # every field-sized thing is a leaf (the links of both parities at
+    # the least), the key holds no array, and an operator rebuilt on
+    # other links with another kappa has the same key
+    assert sum(getattr(x, "size", 1) for x in leaves) >= 36 * 4 ** 4
+    sig = op.program_signature
+    assert not any(isinstance(v, (jax.Array, np.ndarray)) for v in sig)
+    other = _packed(2, 0.1).pairs(store, **kw)
+    assert jax.tree_util.tree_structure(other) == treedef
+    assert hash(other.program_signature) == hash(sig)
+    # the round trip keeps everything the stencil dispatch reads
+    back = jax.tree_util.tree_unflatten(treedef, leaves)
+    assert back.program_signature == sig
+    for name in type(op)._PROGRAM_ARRAYS:
+        was, now = getattr(op, name, None), getattr(back, name)
+        assert jax.tree_util.tree_all(jax.tree_util.tree_map(
+            lambda a, b: a is b, was, now))
+
+
+def test_a_mesh_operator_does_not_present():
+    from quda_tpu.parallel.mesh import make_lattice_mesh
+    mesh = make_lattice_mesh(grid=(2, 1, 1, 1), n_src=1,
+                             devices=jax.devices()[:2])
+    op = _packed(1, 0.12, lat=8).pairs(
+        jnp.float32, use_pallas=True, pallas_interpret=True, mesh=mesh,
+        sharded_policy="xla_facefix")
+    assert op.program_signature is None and not sprog.presents(op)
+    with pytest.raises(TypeError, match="mesh"):
+        jax.tree_util.tree_flatten(op)
+    assert not sprog.presents(lambda v: v)
