@@ -33,6 +33,7 @@ _ctx = {
     "long": None,
     "mg": None,
     "mg_epoch": -1,         # gauge_epoch the resident MG was built against
+    "clover": None,         # resident clover term (load_clover_quda)
     "gauge_epoch": 0,       # bumped whenever the resident gauge changes
 }
 
@@ -269,6 +270,7 @@ def _set_resident_gauge(g):
     _ctx["gauge_epoch"] += 1
     from ..obs import memory as omem
     omem.track("gauge", "resident_gauge", g)
+    _drop_resident_clover()     # built from the links that just went
 
 
 @_pm_api("load_gauge_quda", payload="gauge")
@@ -390,6 +392,134 @@ def free_gauge_quda():
 
 def _antiperiodic():
     return _ctx["gauge_param"].t_boundary == "antiperiodic"
+
+
+# -- the resident clover term (loadCloverQuda) ------------------------------
+
+_CLOVER_FIELD = "resident_clover"     # its row in the HBM ledger
+
+
+def _pair_store(prec: str):
+    """Storage dtype of a pair operator at an API precision name."""
+    return jnp.bfloat16 if prec in ("half", "quarter") else jnp.float32
+
+
+def _drop_resident_clover():
+    if _ctx.get("clover") is not None:
+        _ctx["clover"] = None
+        from ..obs import memory as omem
+        omem.release("clover", _CLOVER_FIELD)
+
+
+def _clover_term_key(param: InvertParam, on_tpu: bool) -> tuple:
+    """What a resident clover term depends on: the gauge generation,
+    kappa*csw and matpc (the blocks), the fermion boundary and the
+    kernel route (the hop arrays the pair operators hold beside them).
+    kappa alone is NOT part of it: it is a leaf of the operators."""
+    matpc = EVEN if param.matpc_type == "even-even" else ODD
+    return (_ctx["gauge_epoch"], float(param.kappa) * float(param.csw),
+            matpc, _antiperiodic(), _pallas_enabled(on_tpu),
+            _pallas_interpret(on_tpu))
+
+
+def _clover_pair_ops(term: dict, stores, blocks=None) -> None:
+    """Build the pair operators of ``stores`` (dtypes) a term lacks
+    from the resident links and the packed complex blocks (A_p,
+    A_q^-1): ``blocks`` at construction, later read back from the f32
+    operator's pairs (exact)."""
+    from ..models.clover import DiracCloverPCPairs
+    from ..ops import wilson as wops
+    from ..ops import wilson_packed as wpk
+    from ..ops.boundary import apply_t_boundary
+    missing = [st for st in dict.fromkeys(jnp.dtype(s) for s in stores)
+               if st not in term["ops"]]
+    if not missing:
+        return
+    _, _, matpc, ap, use_pallas, interpret = term["key"]
+    geom = _ctx["geom"]
+    if blocks is None:
+        hi = term["ops"][jnp.dtype(jnp.float32)]
+        blocks = tuple(wpk.from_packed_pairs(b) for b in
+                       (hi.clover_p_pp, hi.clover_inv_q_pp))
+    links = wpk.pack_gauge_eo(wops.split_gauge_eo(
+        apply_t_boundary(_ctx["gauge"], geom, -1 if ap else 1), geom))
+    for st in missing:
+        term["ops"][st] = DiracCloverPCPairs.from_packed(
+            geom, links, 0.0, matpc, *blocks, st, use_pallas=use_pallas,
+            pallas_interpret=interpret, tb_sign=ap)
+
+
+def _resident_clover(param: InvertParam, stores) -> dict:
+    """The clover term of (resident gauge, kappa*csw, matpc) with pair
+    operators at the storage dtypes ``stores`` (f32 always): the
+    resident one when its key matches (``reused``), else constructed
+    lattice-minor (ops/clover_packed) from the PHYSICAL links, before
+    the fermion boundary phase, and kept in ``_ctx``: ``built`` when
+    nothing was resident, ``rebuilt`` when the coefficient, gauge or
+    route differs.  Phases land on the ``load_clover_quda`` profile
+    whoever calls."""
+    from ..obs import memory as omem
+    from ..obs import metrics as omet
+    from ..obs import trace as otr
+    from ..ops import clover_packed as cpk
+    from ..ops import wilson_packed as wpk
+    on_tpu = jax.default_backend() == "tpu"
+    key = _clover_term_key(param, on_tpu)
+    term = _ctx.get("clover")
+    outcome = ("reused" if term is not None and term["key"] == key
+               else "built" if term is None else "rebuilt")
+    prof = "load_clover_quda"
+    stores = (jnp.float32,) + tuple(stores)
+    with otr.span("clover_term", cat="setup", outcome=outcome,
+                  kappa_csw=key[1]):
+        blocks = None
+        if outcome != "reused":
+            _drop_resident_clover()
+            dims, p = _ctx["geom"].lattice_shape, key[2]
+            wait = jax.block_until_ready
+            with otr.phase("field_strength", prof):
+                f = wait(cpk.field_strength_eo(_ctx["gauge"], dims))
+            with otr.phase("blocks", prof):
+                a = wait(tuple(cpk.clover_blocks_packed(fp, key[1] / 2.0)
+                               for fp in f))
+            del f
+            with otr.phase("invert", prof):
+                blocks = (a[p], wait(cpk.invert_blocks_packed(a[1 - p])))
+            term = {"key": key, "ops": {},
+                    "a_q_pp": wpk.to_packed_pairs(a[1 - p], jnp.float32)}
+        with otr.phase("pack", prof):
+            _clover_pair_ops(term, stores, blocks)
+            jax.block_until_ready(
+                ([jax.tree_util.tree_leaves(op)
+                  for op in term["ops"].values()], term["a_q_pp"]))
+        _ctx["clover"] = term
+        omem.track("clover", _CLOVER_FIELD, term)
+    omet.inc("clover_term_total", outcome=outcome)
+    return term
+
+
+def load_clover_quda(param: InvertParam):
+    """loadCloverQuda with ``compute_clover`` and
+    ``compute_clover_inverse``: the clover term A = 1 + (kappa csw / 2)
+    sum sigma F and the inverse of the other parity, computed once from
+    the resident gauge and kept on the device as the packed pair blocks
+    of the solve operators (precise f32, and the sloppy storage
+    ``param`` resolves to).  ``invert_quda`` with
+    ``dslash_type="clover"`` uses them; a solve whose kappa*csw, matpc
+    or gauge differs rebuilds; ``load_gauge_quda`` invalidates."""
+    _require_init()
+    param.validate()
+    if _ctx["gauge"] is None:
+        qlog.errorq("load_clover_quda: load_gauge_quda first")
+    from ..obs import trace as otr
+    with otr.api_span("load_clover_quda", csw=param.csw,
+                      kappa=param.kappa):
+        _resident_clover(param, (_pair_store(_resolve_sloppy(param)),))
+
+
+def free_clover_quda():
+    """freeCloverQuda: drop the resident clover term."""
+    _drop_resident_clover()
 
 
 def _build_dirac(p: InvertParam, pc: bool):
@@ -611,6 +741,29 @@ class _PairOpSolve(_StaggeredPairsSolve):
         if name in ("split5", "join5"):
             return getattr(self.op, name)
         raise AttributeError(name)
+
+
+class _CloverResidentSolve(_PairOpSolve):
+    """The clover solve on the resident term (``_resident_clover``):
+    the pair operators are the resident ones under this call's kappa,
+    nothing is built from the canonical ``DiracCloverPC``."""
+
+    def __init__(self, term: dict, kappa: float):
+        self._term = term
+        self._kappa = kappa
+        self.op = term["ops"][jnp.dtype(jnp.float32)].with_kappa(kappa)
+
+    def sloppy(self, prec: str = "half"):
+        return self._term["ops"][jnp.dtype(_pair_store(prec))].with_kappa(
+            self._kappa)
+
+    def full(self):
+        """The full M = A - kappa D of the verified-exit check."""
+        from ..models.clover import DiracCloverFullPairs
+        return DiracCloverFullPairs(self.op, self._term["a_q_pp"])
+
+    def flops_per_site_M(self) -> int:
+        return 2 * 1320 + 2 * 504 + 48      # DiracCloverPC's count
 
 
 class _WilsonPairsSolve:
@@ -985,11 +1138,13 @@ def invert_quda(source, param: InvertParam):
 import contextlib
 
 # ledger families whose fields live only for the duration of one API
-# call (clover terms rebuilt per _build_dirac; eig workspaces handed to
-# the caller at return) — released when the call exits so "resident
-# now" stays honest while the family HIGH-WATER keeps the peak signal.
-# gauge/fat_naik/mg are genuinely resident (_ctx) and are NOT listed.
-_TRANSIENT_FAMILIES = ("clover", "eig")
+# call (eig workspaces handed to the caller at return) — released when
+# the call exits so "resident now" stays honest while the family
+# HIGH-WATER keeps the peak signal.  gauge/fat_naik/mg are genuinely
+# resident (_ctx) and are NOT listed; of the clover family the resident
+# term's row (load_clover_quda) stays and the rows of operators built
+# per call (twisted clover, the canonical classes) go.
+_TRANSIENT_FAMILIES = ("eig",)
 
 
 @contextlib.contextmanager
@@ -1008,6 +1163,7 @@ def _hbm_sampled(api: str):
     finally:
         for fam in _TRANSIENT_FAMILIES:
             omem.release_family(fam)
+        omem.release_family("clover", keep=(_CLOVER_FIELD,))
         if omet.enabled():
             omem.sample(f"{api}:exit")
 
@@ -1070,9 +1226,6 @@ def _invert_quda_body(source, param: InvertParam):
     pc = param.solve_type.endswith("-pc")
     inv = param.inv_type
     with otr.phase("setup", "invert_quda"):
-        d = _build_dirac(param, pc)
-        d_full = _build_dirac(param, False)
-
         # Mixed-precision gate (computed early: the layout choice below
         # must not apply to representation combinations it cannot serve).
         # QUDA threads matSloppy through every solver
@@ -1127,6 +1280,19 @@ def _invert_quda_body(source, param: InvertParam):
         pair_op = pair_op and not pair_excluded
         wil_pairs = wil_pairs and not pair_excluded
 
+        # the clover pair route solves on the resident term
+        # (load_clover_quda; built on first use): no canonical operator
+        # is constructed, for the solve or for the verified-exit check
+        clover_resident = pair_op and param.dslash_type == "clover"
+        if clover_resident:
+            d = _CloverResidentSolve(_resident_clover(
+                param, (_pair_store(sloppy_prec),) if mixed else ()),
+                param.kappa)
+            d_full = d.full()
+        else:
+            d = _build_dirac(param, pc)
+            d_full = _build_dirac(param, False)
+
         # TPU-native packed device order for the Wilson PC solve path
         # (QUDA keeps solver fields in native FloatN order the same way);
         # default on TPU, opt-in/out anywhere via QUDA_TPU_PACKED=1/0.
@@ -1166,7 +1332,7 @@ def _invert_quda_body(source, param: InvertParam):
                 # sloppy op falls back to bf16.
                 d = _StaggeredPairsSolve(d, _pallas_enabled(on_tpu),
                                          _pallas_interpret(on_tpu))
-            elif pair_op:
+            elif pair_op and not clover_resident:
                 d = _PairOpSolve(d, _pallas_enabled(on_tpu),
                                  _pallas_interpret(on_tpu))
             elif wil_pairs:
@@ -1349,12 +1515,15 @@ def _invert_dispatch(param, d, d_full, b, rhs, sys_rhs, mv, mv_applies,
     if mixed and inv == "cg":
         if pair_sloppy:
             sl = d.sloppy(sloppy_prec)
-            if isinstance(d, _WilsonPairsSolve) and sprog.presents(d.op,
-                                                                   sl):
-                # the loop traced once per process: here mv IS
-                # d.op.MdagM_pairs (cg on this adapter always runs the
-                # normal equations) and d.codec the in-place pair
-                # codec, which the program rebuilds inside its trace
+            if (not hermitian_pc and hasattr(d, "op")
+                    and sprog.presents(d.op, sl)):
+                # the loop traced once per process: the operators
+                # present (registered pytrees with a program_signature:
+                # the Wilson and clover packed pair operators off a
+                # mesh); here mv IS d.op.MdagM_pairs (cg on a
+                # non-Hermitian pair adapter always runs the normal
+                # equations) and d.codec the in-place pair codec, which
+                # the program rebuilds inside its trace
                 res, hit = sprog.cg_reliable(
                     d.op, sl, sys_rhs, tol=param.tol,
                     maxiter=param.maxiter, delta=param.reliable_delta,

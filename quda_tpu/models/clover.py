@@ -16,15 +16,18 @@ so csw=0 reduces exactly to Wilson.  PC operator on parity p:
 
 from __future__ import annotations
 
+import copy
+
+import jax
 import jax.numpy as jnp
 
 from ..fields.geometry import EVEN, LatticeGeometry
-from ..fields.spinor import even_odd_split
+from ..fields.spinor import even_odd_join, even_odd_split
 from ..ops import wilson as wops
 from ..ops.boundary import apply_t_boundary
 from ..ops.clover import apply_clover, clover_blocks, invert_clover
 from .dirac import Dirac, DiracPC, MATPC_EVEN_EVEN
-from .wilson import _SchurPairOpBase
+from .wilson import _ProgramOperand, _SchurPairOpBase
 
 
 class DiracClover(Dirac):
@@ -160,7 +163,7 @@ def apply_clover_pairs(blk_pp: jnp.ndarray, x_pp: jnp.ndarray,
     return out.reshape(x_pp.shape).astype(odt)
 
 
-class DiracCloverPCPairs(_SchurPairOpBase):
+class DiracCloverPCPairs(_ProgramOperand, _SchurPairOpBase):
     """Complex-free packed pair-form of DiracCloverPC — Wilson-clover
     solves on TPU runtimes without complex64 execution, and (bf16
     storage) the sloppy clover operator of mixed solves.
@@ -171,36 +174,79 @@ class DiracCloverPCPairs(_SchurPairOpBase):
     blocks applied as real einsums (MXU).  The PC operator is
     gamma5-hermitian, so the template's sign argument is ignored.
 
+    A solve-program operand (_ProgramOperand): links, blocks and kappa
+    are the leaves; the fused-or-staged form, resolved when the
+    operator is built, is part of the static signature.
+
     Reference behavior: QUDA runs clover solves in native FloatN orders
     with the clover field in its own packed order
     (include/clover_field_order.h); this is that representation.
     """
+
+    _PROGRAM_ARRAYS = _ProgramOperand._PROGRAM_ARRAYS + (
+        "clover_p_pp", "clover_inv_q_pp")
+    _PROGRAM_STATIC = _ProgramOperand._PROGRAM_STATIC + ("_op_form",)
 
     def __init__(self, dpc: "DiracCloverPC", store_dtype=jnp.float32,
                  use_pallas: bool = False, pallas_interpret: bool = False,
                  pallas_version: int | None = None,
                  form: str | None = None):
         from ..ops import wilson_packed as wpk
-        self._setup_hop(dpc.geom, wpk.pack_gauge_eo(dpc.gauge_eo),
-                        store_dtype, use_pallas, pallas_interpret,
-                        pallas_version=pallas_version,
-                        tb_sign=getattr(dpc, 'antiperiodic_t',
-                                        True))
-        self.kappa = float(dpc.kappa)
-        self.matpc = dpc.matpc
-        self.clover_p_pp = pack_clover_pairs(dpc.clover[dpc.matpc],
-                                             store_dtype)
-        self.clover_inv_q_pp = pack_clover_pairs(dpc.clover_inv_q,
-                                                 store_dtype)
+        self._assemble(
+            dpc.geom, wpk.pack_gauge_eo(dpc.gauge_eo), dpc.kappa,
+            dpc.matpc, pack_clover_pairs(dpc.clover[dpc.matpc],
+                                         store_dtype),
+            pack_clover_pairs(dpc.clover_inv_q, store_dtype),
+            store_dtype, use_pallas, pallas_interpret,
+            getattr(dpc, 'antiperiodic_t', True), pallas_version, form)
         from ..obs import memory as omem
         omem.track("clover", "clover_pair_blocks",
                    (self.clover_p_pp, self.clover_inv_q_pp))
+
+    @classmethod
+    def from_packed(cls, geom, gauge_eo_packed, kappa, matpc, clover_p,
+                    clover_inv_q, store_dtype=jnp.float32,
+                    use_pallas: bool = False,
+                    pallas_interpret: bool = False, tb_sign: bool = True,
+                    pallas_version: int | None = None,
+                    form: str | None = None) -> "DiracCloverPCPairs":
+        """From what a resident clover term holds: the boundary-folded
+        packed links (wilson_packed.pack_gauge_eo) and the packed
+        complex blocks of ops/clover_packed, (2,6,6,T,Z,Y*Xh) — no
+        canonical DiracCloverPC in between.  The owner of the term keeps
+        its row in the HBM ledger."""
+        from ..ops import wilson_packed as wpk
+        op = object.__new__(cls)
+        op._assemble(geom, gauge_eo_packed, kappa, matpc,
+                     wpk.to_packed_pairs(clover_p, store_dtype),
+                     wpk.to_packed_pairs(clover_inv_q, store_dtype),
+                     store_dtype, use_pallas, pallas_interpret, tb_sign,
+                     pallas_version, form)
+        return op
+
+    def _assemble(self, geom, gauge_eo_packed, kappa, matpc, clover_p_pp,
+                  clover_inv_q_pp, store_dtype, use_pallas,
+                  pallas_interpret, tb_sign, pallas_version, form):
+        self._setup_hop(geom, gauge_eo_packed, store_dtype, use_pallas,
+                        pallas_interpret, pallas_version=pallas_version,
+                        tb_sign=tb_sign)
+        self.kappa = float(kappa)
+        self.matpc = matpc
+        self.clover_p_pp = clover_p_pp
+        self.clover_inv_q_pp = clover_inv_q_pp
         from . import formsel
         aux = jnp.dtype(store_dtype).name
         self._op_form = formsel.resolve_form(
             "clover", form, self,
             race=lambda: formsel.race_schur("clover", self, aux=aux),
             aux=aux)
+
+    def with_kappa(self, kappa: float) -> "DiracCloverPCPairs":
+        """The same resident arrays under another hopping parameter (a
+        leaf of the pytree: the solve program's executable is shared)."""
+        op = copy.copy(self)
+        op.kappa = float(kappa)
+        return op
 
     def _diag_sign_pairs(self, x, sign, out_dtype):
         return apply_clover_pairs(self.clover_p_pp, x, out_dtype)
@@ -216,3 +262,43 @@ class DiracCloverPCPairs(_SchurPairOpBase):
 
     def _fused_k2_params(self, sign):
         return self.clover_p_pp, None
+
+
+jax.tree_util.register_pytree_node_class(DiracCloverPCPairs)
+
+
+@jax.jit
+def _full_m_pairs(op: DiracCloverPCPairs, clover_q_pp, psi):
+    """M psi = A psi - kappa D psi on the full lattice through the f32
+    pair operator: canonical complex in and out, pair arithmetic in
+    between (the hop is the operator's own stencil, the diagonal its
+    resident blocks plus those of the other parity)."""
+    p = op.matpc
+    pe, po = even_odd_split(psi, op.geom)
+    x = [op._to_pairs(v) for v in ((pe, po) if p == EVEN else (po, pe))]
+    blk = (op.clover_p_pp, clover_q_pp)
+    out = [op._from_pairs(
+        apply_clover_pairs(blk[i], x[i], jnp.float32)
+        - op.kappa * op._d_to(x[1 - i], p if i == 0 else 1 - p,
+                              jnp.float32), psi.dtype)
+        for i in (0, 1)]
+    oe, oo = out if p == EVEN else out[::-1]
+    return even_odd_join(oe, oo, op.geom)
+
+
+class DiracCloverFullPairs:
+    """The full operator M = A - kappa D of a verified-exit check,
+    applied with a resident f32 ``DiracCloverPCPairs`` and the A blocks
+    of its other parity: no second clover_blocks, no canonical
+    (...,2,6,6) einsum (interfaces/quda_api builds it from the
+    resident clover term)."""
+
+    def __init__(self, op: DiracCloverPCPairs, clover_q_pp):
+        self.op = op
+        self.clover_q_pp = clover_q_pp
+
+    def M(self, psi):
+        return _full_m_pairs(self.op, self.clover_q_pp, psi)
+
+    def flops_per_site_M(self) -> int:
+        return 1320 + 504 + 48

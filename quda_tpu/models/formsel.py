@@ -11,7 +11,8 @@ knob or auto decision takes effect silently).
 
 Knobs (utils/config.py): QUDA_TPU_CLOVER_FORM / QUDA_TPU_TWISTED_FORM /
 QUDA_TPU_DWF_FORM ∈ {'', auto, pallas, xla}.  Resolution precedence:
-explicit ``form=`` kwarg > env knob > auto.  'auto' races the two
+explicit ``form=`` kwarg > env knob > auto ('' is auto, except in a
+family whose forms have been measured on the chip: ``MEASURED``).  'auto' races the two
 compositions at operator construction and caches the winner per
 (volume, family, dtype[, Ls]); with tuning disabled it resolves
 statically to pallas with a notice — the expected chip winner (the
@@ -33,6 +34,13 @@ KNOBS = {
 }
 
 FORMS = ("", "auto", "pallas", "xla")
+
+# Families whose two forms have been read on the chip: with the knob
+# unset they serve the winner WITHOUT a race.  Clover, one v5e, 24^4
+# (PERF.md, PR 28): fused 953 / 515 us an M (f32 / bf16) against staged
+# 1,308 / 796, and the race itself lowered eight kernels through Mosaic
+# in every process, 90-130 s of load_clover_quda.  'auto' still races.
+MEASURED = {"clover": "pallas"}
 
 _NOTICED: set = set()
 
@@ -90,7 +98,7 @@ def resolve_form(family: str, requested: Optional[str], op,
     if req not in FORMS:
         raise ValueError(
             f"{knob}={req!r}: expected one of {FORMS}")
-    if not req:
+    if not req and family not in MEASURED:
         req = "auto"
 
     blocker = fused_capable(op)
@@ -105,7 +113,7 @@ def resolve_form(family: str, requested: Optional[str], op,
         _notice(family, "pallas", "pinned")
         return "pallas"
 
-    # auto
+    # auto, or unset with a measured winner
     from ..utils import tune as qtune
     if getattr(op, "_pallas_interpret", False):
         # interpret mode: a race would time the interpreter, and the
@@ -115,6 +123,11 @@ def resolve_form(family: str, requested: Optional[str], op,
         _notice(family, "xla",
                 "auto default (interpret mode: fused form is opt-in)")
         return "xla"
+    if not req:
+        _notice(family, MEASURED[family],
+                "default: the chip's measured winner, no race "
+                f"({knob}=auto races)")
+        return MEASURED[family]
     if not qtune.tuning_enabled():
         _notice(family, "pallas",
                 "auto default (tuning disabled: no chip race)")
@@ -156,6 +169,14 @@ def race_schur(family: str, op, aux: str = "") -> str:
     T, Z, _, _ = op.dims
     yxh = op.gauge_eo_pp[0].shape[-1]
     psi0 = jnp.zeros((4, 3, 2, T, Z, yxh), op.store_dtype)
+    if getattr(op, "program_signature", None) is not None:
+        # a pytree operator goes in as an ARGUMENT: closed over, its
+        # links and blocks are constants of each candidate (1 GB
+        # executables at 24^4 that no cache holds: PERF.md, PR 28)
+        cands = {form: jax.jit(
+            lambda o, v, form=form: o._M_sign_pairs(v, +1, form=form))
+            for form in ("pallas", "xla")}
+        return race_forms(family, op, cands, (op, psi0), aux=aux)
     cands = {
         "pallas": jax.jit(
             lambda v: op._M_sign_pairs(v, +1, form="pallas")),

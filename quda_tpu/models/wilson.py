@@ -814,6 +814,54 @@ class _PackedHopMixin:
                                   dtype), (T, Z, Y, X // 2))
 
 
+class _ProgramOperand:
+    """A packed pair operator as a solve-program operand
+    (solvers/program.py).  The operator crosses a jit boundary as a
+    pytree: the resident arrays and kappa are the LEAVES (operands of
+    the compiled solve: a new configuration or a new mass reuses the
+    executable, and no field is baked into it), everything that changes
+    the traced computation is the static aux (part of jit's cache key).
+    A class lists every attribute its dispatch reads in one tuple or
+    the other (below: what the packed hop of _PackedHopMixin reads; a
+    class with more state extends them) and registers itself as a
+    pytree node."""
+
+    _PROGRAM_ARRAYS: tuple = ("gauge_eo_pp", "_u_bw", "_gauge_q",
+                              "_gauge_s", "kappa")
+    _PROGRAM_STATIC: tuple = ("geom", "dims", "matpc", "store_dtype",
+                              "use_pallas", "_pallas_interpret",
+                              "_tb_sign", "_pallas_version",
+                              "_precision_form", "_block_z")
+
+    @property
+    def program_signature(self):
+        """The hashable static half of this operator as a solve-program
+        operand, or None when it cannot be one: a mesh operator races
+        its halo policy on concrete operands and memoises the shard_map
+        on the instance, so it keeps the eager solve."""
+        if getattr(self, "_mesh", None) is not None:
+            return None
+        static = {n: getattr(self, n) for n in self._PROGRAM_STATIC}
+        static["store_dtype"] = jnp.dtype(self.store_dtype)
+        return tuple(static.values())
+
+    def tree_flatten(self):
+        sig = self.program_signature
+        if sig is None:
+            raise TypeError("a mesh-sharded packed pair operator is not "
+                            "a solve-program operand")
+        return (tuple(getattr(self, n, None)
+                      for n in self._PROGRAM_ARRAYS), sig)
+
+    @classmethod
+    def tree_unflatten(cls, sig, arrays):
+        op = object.__new__(cls)
+        vars(op).update(zip(cls._PROGRAM_STATIC, sig),
+                        _mesh=None, _mesh_yx=None)
+        vars(op).update(zip(cls._PROGRAM_ARRAYS, arrays))
+        return op
+
+
 class _SchurPairOpBase(_PackedHopMixin, _PairSloppyBase):
     """Template for clover-type Schur pair operators
 
@@ -1097,7 +1145,8 @@ class DiracWilsonPCPacked:
                                  precise_dtype)
 
 
-class DiracWilsonPCPackedSloppy(_PackedHopMixin, _PairSloppyBase):
+class DiracWilsonPCPackedSloppy(_ProgramOperand, _PackedHopMixin,
+                                _PairSloppyBase):
     """bf16 pair-storage PC Wilson operator on the PACKED layout:
     spinors (4,3,2,T,Z,Y*Xh) bf16, gauge likewise — the sloppy stencil
     of the packed solve path (ops/wilson_packed.dslash_eo_packed_pairs).
@@ -1117,47 +1166,6 @@ class DiracWilsonPCPackedSloppy(_PackedHopMixin, _PairSloppyBase):
                         precision_form=precision_form)
         self.kappa = float(dpk.kappa)
         self.matpc = dpk.matpc
-
-    # -- solve-program operand (solvers/program.py) ---------------------
-    # The operator crosses a jit boundary as a pytree: the resident
-    # links and kappa are the LEAVES (operands of the compiled solve: a
-    # new configuration or a new mass reuses the executable, and no
-    # field is baked into it), everything that changes the traced
-    # computation is the static aux (part of jit's cache key).  Every
-    # attribute the stencil dispatch reads is in one list or the other.
-    _PROGRAM_ARRAYS = ("gauge_eo_pp", "_u_bw", "_gauge_q", "_gauge_s",
-                       "kappa")
-    _PROGRAM_STATIC = ("geom", "dims", "matpc", "store_dtype",
-                       "use_pallas", "_pallas_interpret", "_tb_sign",
-                       "_pallas_version", "_precision_form", "_block_z")
-
-    @property
-    def program_signature(self):
-        """The hashable static half of this operator as a solve-program
-        operand, or None when it cannot be one: a mesh operator races
-        its halo policy on concrete operands and memoises the shard_map
-        on the instance, so it keeps the eager solve."""
-        if self._mesh is not None:
-            return None
-        static = {n: getattr(self, n) for n in self._PROGRAM_STATIC}
-        static["store_dtype"] = jnp.dtype(self.store_dtype)
-        return tuple(static.values())
-
-    def tree_flatten(self):
-        sig = self.program_signature
-        if sig is None:
-            raise TypeError("a mesh-sharded packed pair operator is not "
-                            "a solve-program operand")
-        return (tuple(getattr(self, n, None)
-                      for n in self._PROGRAM_ARRAYS), sig)
-
-    @classmethod
-    def tree_unflatten(cls, sig, arrays):
-        op = object.__new__(cls)
-        vars(op).update(zip(cls._PROGRAM_STATIC, sig),
-                        _mesh=None, _mesh_yx=None)
-        vars(op).update(zip(cls._PROGRAM_ARRAYS, arrays))
-        return op
 
     def _to_pairs(self, x):
         from ..ops import wilson_packed as wpk

@@ -125,13 +125,15 @@ def track(family: str, name: str, obj) -> int:
     return int(nbytes)
 
 
-def release_family(family: str) -> int:
-    """Release every field of a family (the per-API-call transient
-    families — clover terms, eig workspaces — whose arrays die with the
-    call; family high-water is retained as the peak signal).  Returns
-    the number of entries released."""
+def release_family(family: str, keep: tuple = ()) -> int:
+    """Release every field of a family but those named in ``keep``
+    (the per-API-call transient rows — per-call clover operators, eig
+    workspaces — whose arrays die with the call; family high-water is
+    retained as the peak signal).  Returns the number of entries
+    released."""
     with _lock:
-        names = [n for (f, n) in _fields if f == family]
+        names = [n for (f, n) in _fields if f == family
+                 and n not in keep]
     for n in names:
         release(family, n)
     return len(names)

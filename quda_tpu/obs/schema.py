@@ -179,6 +179,13 @@ METRICS: dict[str, dict] = {
                 "'miss' traced (and lowered, compiled or fetched) the "
                 "loop program, 'hit' was an in-process executable "
                 "lookup"},
+    "clover_term_total": {
+        "type": COUNTER,
+        "help": "uses of the resident clover term (load_clover_quda, "
+                "clover invert_quda) by outcome: 'built' nothing was "
+                "resident, 'reused' the resident term served, "
+                "'rebuilt' another kappa*csw, matpc, gauge or kernel "
+                "route replaced it"},
     # tuner warm-cache accounting (utils/tune.py)
     "tune_cache_hits_total": {
         "type": COUNTER,

@@ -23,9 +23,9 @@ from . import gamma as g
 from .fmunu import PLANES, field_strength
 
 
-def _sigma_blocks(dtype):
-    """sigma_{mu nu} chiral blocks for the 6 planes: (6, 2, 2, 2) —
-    [plane, chirality, s, s']."""
+def sigma_blocks_np() -> np.ndarray:
+    """sigma_{mu nu} chiral blocks for the 6 planes as host constants:
+    (6, 2, 2, 2) — [plane, chirality, s, s']."""
     blocks = np.zeros((6, 2, 2, 2), dtype=np.complex128)
     for p, (mu, nu) in enumerate(PLANES):
         s = g.SIGMA[mu, nu]
@@ -33,7 +33,11 @@ def _sigma_blocks(dtype):
             "sigma must be chiral-block-diagonal in this basis"
         blocks[p, 0] = s[:2, :2]
         blocks[p, 1] = s[2:, 2:]
-    return jnp.asarray(blocks, dtype)
+    return blocks
+
+
+def _sigma_blocks(dtype):
+    return jnp.asarray(sigma_blocks_np(), dtype)
 
 
 def clover_blocks(gauge: jnp.ndarray, coeff: float,

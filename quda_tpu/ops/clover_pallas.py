@@ -20,7 +20,15 @@ or the twist in the kernel epilogue, never as a second pass):
   the K2 stage: the second hop plus the p-parity diagonal (A_p blocks
   and/or the +i a g5 twist of the ORIGINAL x) and the -kappa^2 combine,
   one pass.  The extra center operand x rides a sixth psi-layout input
-  whose BlockSpec matches the center spinor block.
+  whose BlockSpec matches the center spinor block; ``hop_coeff`` is a
+  one-element f32 OPERAND in SMEM, not a compiled-in constant, so a
+  solve program that takes kappa as an operand (solvers/program.py)
+  serves every mass with one executable.
+
+Each of the two is its own jitted function, so a profiler trace names
+the kernel event after it (``dslash_eo_pallas_post.N`` /
+``dslash_eo_pallas_diag_hop.N``, with the element types of result,
+spinor and blocks), as it does the Wilson kernel.
 
 The clover term enters as the resident packed pair blocks of
 models/clover.pack_clover_pairs — (2,6,6,2,T,Z,YXh), 576 B/site at f32
@@ -102,12 +110,12 @@ def _add_sc(a, b):
             for s in range(4)]
 
 
-def _scale_sc(vals, k: float):
+def _scale_sc(vals, k):
     return [[(k * v[0], k * v[1]) for v in row] for row in vals]
 
 
 def _epilogue_kernel(X, bz, eo, T, tb_sign, *, xc_mode, with_blk,
-                     twist, diag_twist, hop_coeff):
+                     twist, diag_twist, with_coeff):
     """v2 hop kernel + family epilogue over the out tile.
 
     xc_mode: None (no diagonal operand), 'input' (sixth psi-layout
@@ -116,7 +124,11 @@ def _epilogue_kernel(X, bz, eo, T, tb_sign, *, xc_mode, with_blk,
     twist: (c, scale) post-rotation scale*(v + i c g5 v) applied to the
     hop result (the twisted-mass A^{-1}); diag_twist: c of the +i c g5
     rotation of the ORIGINAL x added to the diagonal term.
-    hop_coeff: None = E(hop) only; float = diag(x) + hop_coeff * hop.
+    with_coeff: False = E(hop) only; True = diag(x) + hop_coeff * hop,
+    hop_coeff read from a one-element f32 SMEM ref that follows the
+    spinor refs (links, then blocks, stay the last inputs: the
+    benchmark's trace reduction names a kernel event by the element
+    types of its result, first and LAST operand).
     """
     base = wpp._make_kernel(X, bz, eo=eo, T=T, tb_sign=tb_sign)
 
@@ -128,6 +140,10 @@ def _epilogue_kernel(X, bz, eo, T, tb_sign, *, xc_mode, with_blk,
             k = 6
         elif xc_mode == "center":
             xc_ref = refs[0]
+        coeff_ref = None
+        if with_coeff:
+            coeff_ref = refs[k]
+            k += 1
         g_c, g_m = refs[k], refs[k + 1]
         blk_ref = refs[k + 2] if with_blk else None
         out_ref = refs[-1]
@@ -137,7 +153,7 @@ def _epilogue_kernel(X, bz, eo, T, tb_sign, *, xc_mode, with_blk,
         # staged rounding of the XLA composition it replaces
         base(*refs[:5], g_c, g_m, out_ref)
         hop = _load_sc(out_ref)
-        if hop_coeff is None:
+        if not with_coeff:
             v = _blk_mul(blk_ref, hop) if with_blk else hop
             if twist is not None:
                 c, scale = twist
@@ -149,7 +165,7 @@ def _epilogue_kernel(X, bz, eo, T, tb_sign, *, xc_mode, with_blk,
             d = _blk_mul(blk_ref, x) if with_blk else x
             if diag_twist is not None:
                 d = _add_sc(d, _ig5_rot(x, diag_twist))
-            v = _add_sc(d, _scale_sc(hop, hop_coeff))
+            v = _add_sc(d, _scale_sc(hop, coeff_ref[0]))
         _store_sc(out_ref, v)
 
     return kernel
@@ -161,14 +177,23 @@ def _planes(R: int, xc_mode, with_blk: bool) -> int:
             + (_XC_PLANES if xc_mode == "input" else 0))
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "dims", "target_parity", "twist", "diag_twist", "hop_coeff",
-    "interpret", "block_z", "out_dtype", "tb_sign"))
-def _fused_eo_call(u_here_pl, u_bw_pl, psi_pl, xc_pl, blk_pl, dims,
-                   target_parity, twist=None, diag_twist=None,
-                   hop_coeff=None, interpret=False, block_z=None,
+def _coeff_operand(hop_coeff):
+    """The K2 combine coefficient as the kernel takes it: (1,) f32."""
+    return jnp.asarray(hop_coeff, F32).reshape(1)
+
+
+def _fused_eo_call(u_here_pl, u_bw_pl, psi_pl, xc_pl, blk_pl, coeff, dims,
+                   target_parity, *, name, mrhs=False, twist=None,
+                   diag_twist=None, interpret=False, block_z=None,
                    out_dtype=None, tb_sign=True):
+    """The one pallas_call behind the four eo entry points.  ``coeff``
+    (a (1,) f32 array) makes it the K2 stage; ``mrhs`` gives every
+    spinor operand a leading RHS axis, streamed innermost: gauge AND
+    block index maps ignore the RHS index, so both stay tile-resident
+    across the RHS stream (the MRHS amortisation carries over to the
+    576 B/site clover blocks, not just the links)."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     T, Z, Y, X = dims
     Xh = X // 2
@@ -181,22 +206,23 @@ def _fused_eo_call(u_here_pl, u_bw_pl, psi_pl, xc_pl, blk_pl, dims,
     if Z % bz != 0:
         raise ValueError(f"block_z={bz} does not divide Z={Z}")
     nzb = Z // bz
+    lead = (1,) if mrhs else ()
 
     def psi_spec(dt, dz):
         return pl.BlockSpec(
-            (4, 3, 2, 1, bz, YXh),
-            lambda t, zb, dt=dt, dz=dz: (0, 0, 0, (t + dt) % T,
-                                         (zb + dz) % nzb, 0))
+            lead + (4, 3, 2, 1, bz, YXh),
+            lambda t, zb, *n: n + (0, 0, 0, (t + dt) % T,
+                                   (zb + dz) % nzb, 0))
 
-    gauge_spec = pl.BlockSpec(
-        (4, R, 3, 2, 1, bz, YXh), lambda t, zb: (0, 0, 0, 0, t, zb, 0))
-    blk_spec = pl.BlockSpec(
-        (2, 6, 6, 2, 1, bz, YXh), lambda t, zb: (0, 0, 0, 0, t, zb, 0))
+    def site_spec(*idx):
+        return pl.BlockSpec(
+            idx + (1, bz, YXh),
+            lambda t, zb, *n: (0,) * len(idx) + (t, zb, 0))
 
     kernel = _epilogue_kernel(X, bz, (target_parity, Xh), T, tb_sign,
                               xc_mode=xc_mode, with_blk=with_blk,
                               twist=twist, diag_twist=diag_twist,
-                              hop_coeff=hop_coeff)
+                              with_coeff=coeff is not None)
 
     in_specs = [psi_spec(0, 0), psi_spec(+1, 0), psi_spec(-1, 0),
                 psi_spec(0, +1), psi_spec(0, -1)]
@@ -204,96 +230,40 @@ def _fused_eo_call(u_here_pl, u_bw_pl, psi_pl, xc_pl, blk_pl, dims,
     if xc_mode == "input":
         in_specs.append(psi_spec(0, 0))
         operands.append(xc_pl)
-    in_specs += [gauge_spec, gauge_spec]
+    if mrhs:
+        kernel = wpp._mrhs_wrap(kernel, n_psi=len(operands))
+    if coeff is not None:
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+        operands.append(coeff)
+    in_specs += [site_spec(4, R, 3, 2)] * 2
     operands += [u_here_pl, u_bw_pl]
     if with_blk:
-        in_specs.append(blk_spec)
+        in_specs.append(site_spec(2, 6, 6, 2))
         operands.append(blk_pl)
 
     return pl.pallas_call(
         kernel,
-        grid=(T, nzb),
+        grid=(T, nzb) + ((psi_pl.shape[0],) if mrhs else ()),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((4, 3, 2, 1, bz, YXh),
-                               lambda t, zb: (0, 0, 0, t, zb, 0)),
+        out_specs=psi_spec(0, 0),
         out_shape=jax.ShapeDtypeStruct(psi_pl.shape,
                                        out_dtype or psi_pl.dtype),
         interpret=interpret,
-    )(*operands)
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "dims", "target_parity", "twist", "diag_twist", "hop_coeff",
-    "interpret", "block_z", "out_dtype", "tb_sign"))
-def _fused_eo_call_mrhs(u_here_pl, u_bw_pl, psi_pl, xc_pl, blk_pl, dims,
-                        target_parity, twist=None, diag_twist=None,
-                        hop_coeff=None, interpret=False, block_z=None,
-                        out_dtype=None, tb_sign=True):
-    from jax.experimental import pallas as pl
-
-    T, Z, Y, X = dims
-    Xh = X // 2
-    N = psi_pl.shape[0]
-    R = u_here_pl.shape[1]
-    YXh = psi_pl.shape[-1]
-    with_blk = blk_pl is not None
-    xc_mode = "input" if xc_pl is not None else None
-    bz = block_z if block_z is not None else wpp._pick_bz(
-        Z, YXh, psi_pl.dtype, planes=_planes(R, xc_mode, with_blk))
-    if Z % bz != 0:
-        raise ValueError(f"block_z={bz} does not divide Z={Z}")
-    nzb = Z // bz
-
-    def psi_spec(dt, dz):
-        return pl.BlockSpec(
-            (1, 4, 3, 2, 1, bz, YXh),
-            lambda t, zb, n, dt=dt, dz=dz: (n, 0, 0, 0, (t + dt) % T,
-                                            (zb + dz) % nzb, 0))
-
-    # gauge AND block index maps ignore n: both stay tile-resident
-    # across the innermost RHS stream (the MRHS amortisation carries
-    # over to the 576 B/site clover blocks, not just the links)
-    gauge_spec = pl.BlockSpec(
-        (4, R, 3, 2, 1, bz, YXh),
-        lambda t, zb, n: (0, 0, 0, 0, t, zb, 0))
-    blk_spec = pl.BlockSpec(
-        (2, 6, 6, 2, 1, bz, YXh),
-        lambda t, zb, n: (0, 0, 0, 0, t, zb, 0))
-
-    n_psi = 6 if xc_mode == "input" else 5
-    kernel = wpp._mrhs_wrap(
-        _epilogue_kernel(X, bz, (target_parity, Xh), T, tb_sign,
-                         xc_mode=xc_mode, with_blk=with_blk,
-                         twist=twist, diag_twist=diag_twist,
-                         hop_coeff=hop_coeff),
-        n_psi=n_psi)
-
-    in_specs = [psi_spec(0, 0), psi_spec(+1, 0), psi_spec(-1, 0),
-                psi_spec(0, +1), psi_spec(0, -1)]
-    operands = [psi_pl, psi_pl, psi_pl, psi_pl, psi_pl]
-    if xc_mode == "input":
-        in_specs.append(psi_spec(0, 0))
-        operands.append(xc_pl)
-    in_specs += [gauge_spec, gauge_spec]
-    operands += [u_here_pl, u_bw_pl]
-    if with_blk:
-        in_specs.append(blk_spec)
-        operands.append(blk_pl)
-
-    return pl.pallas_call(
-        kernel,
-        grid=(T, nzb, N),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 4, 3, 2, 1, bz, YXh),
-                               lambda t, zb, n: (n, 0, 0, 0, t, zb, 0)),
-        out_shape=jax.ShapeDtypeStruct(psi_pl.shape,
-                                       out_dtype or psi_pl.dtype),
-        interpret=interpret,
+        name=name,
     )(*operands)
 
 
 # -- public entry points ----------------------------------------------------
+# Each is its own jitted function: the trace names a kernel event after
+# the function that wraps its pallas_call.
 
+_POST_STATIC = ("dims", "target_parity", "twist", "interpret", "block_z",
+                "out_dtype", "tb_sign")
+_DIAG_HOP_STATIC = ("dims", "target_parity", "diag_twist", "interpret",
+                    "block_z", "out_dtype", "tb_sign")
+
+
+@functools.partial(jax.jit, static_argnames=_POST_STATIC)
 def dslash_eo_pallas_post(u_here_pl, u_bw_pl, psi_pl, dims,
                           target_parity, *, blk_pl=None, twist=None,
                           interpret=False, block_z=None, out_dtype=None,
@@ -303,12 +273,14 @@ def dslash_eo_pallas_post(u_here_pl, u_bw_pl, psi_pl, dims,
     clover inverse or the dense twisted-clover inverse) and/or the
     static twist rotation ``twist=(c, scale)`` mapping
     v -> scale*(v + i c g5 v)."""
-    return _fused_eo_call(u_here_pl, u_bw_pl, psi_pl, None, blk_pl,
-                          tuple(dims), target_parity, twist=twist,
+    return _fused_eo_call(u_here_pl, u_bw_pl, psi_pl, None, blk_pl, None,
+                          tuple(dims), target_parity,
+                          name="dslash_eo_pallas_post", twist=twist,
                           interpret=interpret, block_z=block_z,
                           out_dtype=out_dtype, tb_sign=tb_sign)
 
 
+@functools.partial(jax.jit, static_argnames=_DIAG_HOP_STATIC)
 def dslash_eo_pallas_diag_hop(u_here_pl, u_bw_pl, psi_pl, xc_pl, dims,
                               target_parity, *, hop_coeff, blk_pl=None,
                               diag_twist=None, interpret=False,
@@ -316,16 +288,20 @@ def dslash_eo_pallas_diag_hop(u_here_pl, u_bw_pl, psi_pl, xc_pl, dims,
                               tb_sign=True):
     """diag(x) + hop_coeff * D_{p<-q} psi in one VMEM pass — the K2
     stage: diag(x) = blk x (+ i c g5 x with ``diag_twist=c``), x riding
-    a sixth psi-layout operand whose BlockSpec is the center block.
-    Pass out_dtype=f32 so the hop read-back loses nothing before the
-    f32 combine (the caller casts the final result to storage)."""
+    a sixth psi-layout operand whose BlockSpec is the center block;
+    ``hop_coeff`` a float or an f32 scalar array (an operand either
+    way).  Pass out_dtype=f32 so the hop read-back loses nothing before
+    the f32 combine (the caller casts the final result to storage)."""
     return _fused_eo_call(u_here_pl, u_bw_pl, psi_pl, xc_pl, blk_pl,
-                          tuple(dims), target_parity,
-                          diag_twist=diag_twist, hop_coeff=hop_coeff,
-                          interpret=interpret, block_z=block_z,
-                          out_dtype=out_dtype, tb_sign=tb_sign)
+                          _coeff_operand(hop_coeff), tuple(dims),
+                          target_parity,
+                          name="dslash_eo_pallas_diag_hop",
+                          diag_twist=diag_twist, interpret=interpret,
+                          block_z=block_z, out_dtype=out_dtype,
+                          tb_sign=tb_sign)
 
 
+@functools.partial(jax.jit, static_argnames=_POST_STATIC)
 def dslash_eo_pallas_post_mrhs(u_here_pl, u_bw_pl, psi_pl, dims,
                                target_parity, *, blk_pl=None,
                                twist=None, interpret=False,
@@ -333,24 +309,28 @@ def dslash_eo_pallas_post_mrhs(u_here_pl, u_bw_pl, psi_pl, dims,
                                tb_sign=True):
     """MRHS ``dslash_eo_pallas_post``: psi (N,4,3,2,T,Z,YXh), RHS
     innermost, gauge and block tiles fetched once per (t, z-block)."""
-    return _fused_eo_call_mrhs(u_here_pl, u_bw_pl, psi_pl, None, blk_pl,
-                               tuple(dims), target_parity, twist=twist,
-                               interpret=interpret, block_z=block_z,
-                               out_dtype=out_dtype, tb_sign=tb_sign)
+    return _fused_eo_call(u_here_pl, u_bw_pl, psi_pl, None, blk_pl, None,
+                          tuple(dims), target_parity,
+                          name="dslash_eo_pallas_post_mrhs", mrhs=True,
+                          twist=twist, interpret=interpret,
+                          block_z=block_z, out_dtype=out_dtype,
+                          tb_sign=tb_sign)
 
 
+@functools.partial(jax.jit, static_argnames=_DIAG_HOP_STATIC)
 def dslash_eo_pallas_diag_hop_mrhs(u_here_pl, u_bw_pl, psi_pl, xc_pl,
                                    dims, target_parity, *, hop_coeff,
                                    blk_pl=None, diag_twist=None,
                                    interpret=False, block_z=None,
                                    out_dtype=None, tb_sign=True):
     """MRHS ``dslash_eo_pallas_diag_hop`` (x batched like psi)."""
-    return _fused_eo_call_mrhs(u_here_pl, u_bw_pl, psi_pl, xc_pl,
-                               blk_pl, tuple(dims), target_parity,
-                               diag_twist=diag_twist,
-                               hop_coeff=hop_coeff, interpret=interpret,
-                               block_z=block_z, out_dtype=out_dtype,
-                               tb_sign=tb_sign)
+    return _fused_eo_call(u_here_pl, u_bw_pl, psi_pl, xc_pl, blk_pl,
+                          _coeff_operand(hop_coeff), tuple(dims),
+                          target_parity,
+                          name="dslash_eo_pallas_diag_hop_mrhs",
+                          mrhs=True, diag_twist=diag_twist,
+                          interpret=interpret, block_z=block_z,
+                          out_dtype=out_dtype, tb_sign=tb_sign)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -364,6 +344,7 @@ def clover_pallas_packed(gauge_pl, blk_pl, psi_pl, X, kappa,
     gauge_pl (4,R,3,2,T,Z,YX), blk_pl (2,6,6,2,T,Z,YX), psi_pl
     (4,3,2,T,Z,YX); layouts as ops/wilson_pallas_packed."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     _, _, _, T, Z, YX = psi_pl.shape
     R = gauge_pl.shape[1]
@@ -389,17 +370,18 @@ def clover_pallas_packed(gauge_pl, blk_pl, psi_pl, X, kappa,
     kernel = _epilogue_kernel(X, bz, None, T, tb_sign,
                               xc_mode="center", with_blk=True,
                               twist=None, diag_twist=diag_twist,
-                              hop_coeff=-float(kappa))
+                              with_coeff=True)
 
     return pl.pallas_call(
         kernel,
         grid=(T, nzb),
         in_specs=[psi_spec(0, 0), psi_spec(+1, 0), psi_spec(-1, 0),
-                  psi_spec(0, +1), psi_spec(0, -1), gauge_spec,
+                  psi_spec(0, +1), psi_spec(0, -1),
+                  pl.BlockSpec(memory_space=pltpu.SMEM), gauge_spec,
                   gauge_spec, blk_spec],
         out_specs=pl.BlockSpec((4, 3, 2, 1, bz, YX),
                                lambda t, zb: (0, 0, 0, t, zb, 0)),
         out_shape=jax.ShapeDtypeStruct(psi_pl.shape, psi_pl.dtype),
         interpret=interpret,
-    )(psi_pl, psi_pl, psi_pl, psi_pl, psi_pl, gauge_pl, gauge_bw,
-      blk_pl)
+    )(psi_pl, psi_pl, psi_pl, psi_pl, psi_pl,
+      _coeff_operand(-float(kappa)), gauge_pl, gauge_bw, blk_pl)

@@ -22,8 +22,12 @@ from .su3 import dagger, is_pairs, mat_i, mat_mul, trace
 PLANES = ((0, 1), (0, 2), (0, 3), (1, 2), (1, 3), (2, 3))
 
 
-def _leaf_sum(gauge, mu: int, nu: int, shift_fn=shift):
-    """Sum of the four clover leaves Q_{mu nu}(x) (3,3 per site)."""
+def _leaf_sum(gauge, mu: int, nu: int, shift_fn=shift, mat_mul=mat_mul,
+              dagger=dagger):
+    """Sum of the four clover leaves Q_{mu nu}(x) (3,3 per site).
+    ``mat_mul`` / ``dagger`` follow the layout of ``gauge[mu]``: the
+    defaults take the colour indices trailing, ops/clover_packed passes
+    its leading-index forms (lattice minor)."""
     u_mu = gauge[mu]
     u_nu = gauge[nu]
 

@@ -236,14 +236,16 @@ _register("QUDA_TPU_STAGGERED_FORM", "choice", "auto",
           ("", "auto", "fused", "two_pass", "v3"),
           reference="dslash policy selection; tune.cpp:862 — policies "
                     "are timed, never assumed")
-_register("QUDA_TPU_CLOVER_FORM", "choice", "auto",
+_register("QUDA_TPU_CLOVER_FORM", "choice", "",
           "clover PC pair-operator form: 'pallas' = the fused v2 "
           "kernel with the resident 2x6x6 chiral clover blocks applied "
           "in the kernel epilogue (ops/clover_pallas — diag+hop one "
           "VMEM pass), 'xla' = the staged hop + einsum composition, "
           "'auto' = race both via utils.tune at operator construction "
-          "and cache the winner per (volume, dtype).  Read at operator "
-          "construction only, hence NOT trace-safe",
+          "and cache the winner per (volume, dtype), '' (default) = "
+          "the form measured faster on the chip, fused, without a race "
+          "(models/formsel.MEASURED; interpret mode: staged).  Read at "
+          "operator construction only, hence NOT trace-safe",
           ("", "auto", "pallas", "xla"),
           reference="dslash policy selection; tune.cpp:862 — policies "
                     "are timed, never assumed "

@@ -229,3 +229,96 @@ def test_solve_program_compiles_for_v5e_with_links_as_parameters(one_chip):
     consts = _hlo_values(hlo, "constant")
     big = [c for c in consts if c[0] > 2 ** 20]
     assert consts and not big, f"fields baked into the executable: {big}"
+
+
+def _aot(lower):
+    """Compile for the described chip with x64 and the persistent
+    cache off (module docstring); ``lower()`` returns the Lowered."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        with jax.enable_x64(False):
+            return lower().compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        cc.reset_cache()
+
+
+def test_clover_term_construction_compiles_for_v5e_under_8_gib(one_chip):
+    """The lattice-minor clover construction (ops/clover_packed: field
+    strength, chiral blocks of both parities, the inverse) as ONE
+    jitted program at 24^4 from the canonical resident gauge: it fits
+    (the canonical construction's F_munu alone is 16 GB of tile-padded
+    temporaries, PERF.md) and bakes no field into the executable."""
+    from quda_tpu.ops import clover_packed as cpk
+
+    def lower():
+        g = jax.ShapeDtypeStruct((4,) + DIMS + (3, 3), jnp.complex64,
+                                 sharding=one_chip)
+        c = jax.ShapeDtypeStruct((), F32, sharding=one_chip)
+        return jax.jit(lambda g, c: cpk.clover_term_packed(
+            g, c, DIMS, 0)).lower(g, c)
+    compiled = _aot(lower)
+    ma = compiled.memory_analysis()
+    peak = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes)
+    assert peak < 8 * 2 ** 30, ma
+    big = [c for c in _hlo_values(compiled.as_text(), "constant")
+           if c[0] > 2 ** 20]
+    assert not big, f"fields baked into the executable: {big}"
+
+
+def test_clover_solve_program_compiles_for_v5e_with_blocks_as_parameters(
+        one_chip):
+    """The clover single-source solve program (solvers/program.py on
+    DiracCloverPCPairs, fused form) at 24^4: compiles for the described
+    chip with the links AND the clover blocks of both operators as
+    parameters (PR 22 cause 8: nothing the size of a field is a
+    constant), and both fused kernels are there in f32 and in bf16."""
+    import re
+    from quda_tpu.fields.geometry import LatticeGeometry
+    from quda_tpu.models.clover import DiracCloverPCPairs
+    from quda_tpu.solvers import mixed
+    from quda_tpu.solvers import program as sprog
+    geom = LatticeGeometry(DIMS)
+    half = (L, L, YXH)
+
+    def operators(links_e, links_o, a_p, ainv_q):
+        return tuple(DiracCloverPCPairs.from_packed(
+            geom, (links_e, links_o), 0.124, 0, a_p, ainv_q, dt,
+            use_pallas=True, pallas_interpret=False, pallas_version=2,
+            form="pallas") for dt in (F32, BF16))
+
+    def lower():
+        lk = jax.ShapeDtypeStruct((4, 3, 3) + half, jnp.complex64)
+        bk = jax.ShapeDtypeStruct((2, 6, 6) + half, jnp.complex64)
+        ops = jax.eval_shape(operators, lk, lk, bk, bk)
+        hi, lo = jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(
+                s.shape, s.dtype, sharding=one_chip,
+                weak_type=s.weak_type), ops)
+        b = jax.ShapeDtypeStruct(*_psi(F32), sharding=one_chip)
+        key = (0.1, mixed.pair_inplace_config(BF16, False, False),
+               sprog._LoopKnobs(False, None, None, None))
+        return sprog._cg_reliable_program.lower(hi, lo, b, 1e-6, 10000,
+                                                key=key)
+    hlo = _aot(lower).as_text()
+    # every M of the loop is one post + one diag_hop kernel; the post
+    # kernel's result carries the operator's storage type (diag_hop
+    # always returns f32, the caller rounds)
+    calls = re.findall(r"%(dslash_eo_pallas_post|dslash_eo_pallas_diag_hop)"
+                       r"[.\d]* = (\w+)\[[^\n]*tpu_custom_call", hlo)
+    post = [dt for k, dt in calls if k == "dslash_eo_pallas_post"]
+    assert set(post) == {"f32", "bf16"}, calls
+    assert len(calls) == 2 * len(post) and post.count("bf16") >= 2
+    params = _hlo_values(hlo, "parameter")
+    links = ",".join(str(d) for d in _links(F32)[0])
+    blocks = ",".join(str(d) for d in (2, 6, 6, 2, L, L, YXH))
+    for dt in ("f32", "bf16"):
+        assert sum(p[1:] == (dt, links) for p in params) == 4
+        assert sum(p[1:] == (dt, blocks) for p in params) == 2
+    consts = _hlo_values(hlo, "constant")
+    big = [c for c in consts if c[0] > 2 ** 20]
+    assert consts and not big, f"fields baked into the executable: {big}"
