@@ -247,12 +247,12 @@ def phase_kernels(api):
     ip = invert_param()
     check(api._pallas_enabled(on_tpu) and api._packed_enabled(on_tpu),
           "the packed pallas route is not enabled on this backend")
-    ws = api._WilsonPairsSolve(api._build_dirac(ip, True).packed(),
-                               api._pallas_interpret(on_tpu))
+    sloppy = api._resolve_sloppy(ip)       # "half" (bf16) on a TPU
+    ws = api._WilsonPairsSolve(
+        api._resident_wilson(ip, (api._pair_store(sloppy),)), ip.kappa)
     check(not ws.op._pallas_interpret,
           "the solve operator is in pallas INTERPRET mode")
     found = {}
-    sloppy = api._resolve_sloppy(ip)       # "half" (bf16) on a TPU
     for name, op in (("precise_f32", ws.op),
                      ("sloppy_" + sloppy, ws.sloppy(sloppy))):
         x = jax.ShapeDtypeStruct((4, 3, 2, L, L, L * L // 2),

@@ -34,6 +34,7 @@ _ctx = {
     "mg": None,
     "mg_epoch": -1,         # gauge_epoch the resident MG was built against
     "clover": None,         # resident clover term (load_clover_quda)
+    "wilson": None,         # resident Wilson pair operators
     "gauge_epoch": 0,       # bumped whenever the resident gauge changes
 }
 
@@ -270,7 +271,7 @@ def _set_resident_gauge(g):
     _ctx["gauge_epoch"] += 1
     from ..obs import memory as omem
     omem.track("gauge", "resident_gauge", g)
-    _drop_resident_clover()     # built from the links that just went
+    _drop_resident_terms()      # built from the links that just went
 
 
 @_pm_api("load_gauge_quda", payload="gauge")
@@ -388,15 +389,19 @@ def free_gauge_quda():
     _ctx["gauge"] = None
     from ..obs import memory as omem
     omem.release("gauge", "resident_gauge")
+    _drop_resident_terms()      # or a later solve would run on them
 
 
 def _antiperiodic():
     return _ctx["gauge_param"].t_boundary == "antiperiodic"
 
 
-# -- the resident clover term (loadCloverQuda) ------------------------------
+# -- what is built from the resident gauge and kept: the clover term --------
+# -- (loadCloverQuda) and the Wilson pair operators --------------------------
 
-_CLOVER_FIELD = "resident_clover"     # its row in the HBM ledger
+_CLOVER_FIELD = "resident_clover"     # their rows in the HBM ledger
+_WILSON_FIELD = "resident_wilson"
+_RESIDENT_FIELDS = {"clover": _CLOVER_FIELD, "wilson": _WILSON_FIELD}
 
 
 def _pair_store(prec: str):
@@ -404,11 +409,80 @@ def _pair_store(prec: str):
     return jnp.bfloat16 if prec in ("half", "quarter") else jnp.float32
 
 
-def _drop_resident_clover():
-    if _ctx.get("clover") is not None:
-        _ctx["clover"] = None
+def _drop_resident(family: str):
+    """Forget the term kept under ``_ctx[family]`` and its ledger row."""
+    if _ctx.get(family) is not None:
+        _ctx[family] = None
         from ..obs import memory as omem
-        omem.release("clover", _CLOVER_FIELD)
+        omem.release(family, _RESIDENT_FIELDS[family])
+
+
+def _drop_resident_terms():
+    for family in _RESIDENT_FIELDS:
+        _drop_resident(family)
+
+
+def _resident_packed_links(antiperiodic: bool):
+    """(even, odd) packed complex links of the resident gauge with the
+    fermion boundary folded in: what the packed pair operators are
+    assembled from."""
+    from ..ops import wilson as wops
+    from ..ops import wilson_packed as wpk
+    from ..ops.boundary import apply_t_boundary
+    geom = _ctx["geom"]
+    return wpk.pack_gauge_eo(wops.split_gauge_eo(apply_t_boundary(
+        _ctx["gauge"], geom, -1 if antiperiodic else 1), geom))
+
+
+def _wilson_term_key(param: InvertParam, on_tpu: bool) -> tuple:
+    """What the resident Wilson pair operators depend on: the gauge
+    generation, matpc, the fermion boundary and the kernel route
+    (pallas or not, interpreted or not, and what the hop set-up reads
+    from the environment).  kappa is NOT part of it: it is a leaf of
+    the operators."""
+    from ..models.wilson import hop_route_knobs
+    matpc = EVEN if param.matpc_type == "even-even" else ODD
+    return (_ctx["gauge_epoch"], matpc, _antiperiodic(),
+            _pallas_enabled(on_tpu), _pallas_interpret(on_tpu),
+            hop_route_knobs())
+
+
+def _resident_wilson(param: InvertParam, stores=()) -> dict:
+    """The Wilson packed pair operators of (resident gauge, matpc,
+    boundary, kernel route) at the storage dtypes ``stores`` (f32
+    always), under kappa 0: callers take them ``with_kappa``.  The
+    resident ones when their key matches (``reused``), else assembled
+    from the resident gauge and kept in ``_ctx``: ``built`` when
+    nothing was resident, ``rebuilt`` when matpc, boundary or route
+    differ.  No canonical Dirac object is constructed."""
+    from ..models.wilson import DiracWilsonPCPackedSloppy
+    from ..obs import memory as omem
+    from ..obs import metrics as omet
+    from ..obs import trace as otr
+    on_tpu = jax.default_backend() == "tpu"
+    key = _wilson_term_key(param, on_tpu)
+    term = _ctx.get("wilson")
+    outcome = ("reused" if term is not None and term["key"] == key
+               else "built" if term is None else "rebuilt")
+    with otr.span("wilson_term", cat="setup", outcome=outcome):
+        if outcome != "reused":
+            _drop_resident("wilson")
+            term = {"key": key, "ops": {}}
+        missing = [st for st in dict.fromkeys(
+            jnp.dtype(s) for s in (jnp.float32,) + tuple(stores))
+            if st not in term["ops"]]
+        if missing:
+            _, matpc, ap, use_pallas, interpret, _ = key
+            links = _resident_packed_links(ap)
+            for st in missing:
+                term["ops"][st] = DiracWilsonPCPackedSloppy.from_packed(
+                    _ctx["geom"], links, 0.0, matpc, st,
+                    use_pallas=use_pallas, pallas_interpret=interpret,
+                    tb_sign=ap)
+            _ctx["wilson"] = term
+            omem.track("wilson", _WILSON_FIELD, term["ops"])
+    omet.inc("wilson_term_total", outcome=outcome)
+    return term
 
 
 def _clover_term_key(param: InvertParam, on_tpu: bool) -> tuple:
@@ -428,9 +502,7 @@ def _clover_pair_ops(term: dict, stores, blocks=None) -> None:
     A_q^-1): ``blocks`` at construction, later read back from the f32
     operator's pairs (exact)."""
     from ..models.clover import DiracCloverPCPairs
-    from ..ops import wilson as wops
     from ..ops import wilson_packed as wpk
-    from ..ops.boundary import apply_t_boundary
     missing = [st for st in dict.fromkeys(jnp.dtype(s) for s in stores)
                if st not in term["ops"]]
     if not missing:
@@ -441,8 +513,7 @@ def _clover_pair_ops(term: dict, stores, blocks=None) -> None:
         hi = term["ops"][jnp.dtype(jnp.float32)]
         blocks = tuple(wpk.from_packed_pairs(b) for b in
                        (hi.clover_p_pp, hi.clover_inv_q_pp))
-    links = wpk.pack_gauge_eo(wops.split_gauge_eo(
-        apply_t_boundary(_ctx["gauge"], geom, -1 if ap else 1), geom))
+    links = _resident_packed_links(ap)
     for st in missing:
         term["ops"][st] = DiracCloverPCPairs.from_packed(
             geom, links, 0.0, matpc, *blocks, st, use_pallas=use_pallas,
@@ -474,7 +545,7 @@ def _resident_clover(param: InvertParam, stores) -> dict:
                   kappa_csw=key[1]):
         blocks = None
         if outcome != "reused":
-            _drop_resident_clover()
+            _drop_resident("clover")
             dims, p = _ctx["geom"].lattice_shape, key[2]
             wait = jax.block_until_ready
             with otr.phase("field_strength", prof):
@@ -519,7 +590,7 @@ def load_clover_quda(param: InvertParam):
 
 def free_clover_quda():
     """freeCloverQuda: drop the resident clover term."""
-    _drop_resident_clover()
+    _drop_resident("clover")
 
 
 def _build_dirac(p: InvertParam, pc: bool):
@@ -743,10 +814,11 @@ class _PairOpSolve(_StaggeredPairsSolve):
         raise AttributeError(name)
 
 
-class _CloverResidentSolve(_PairOpSolve):
-    """The clover solve on the resident term (``_resident_clover``):
-    the pair operators are the resident ones under this call's kappa,
-    nothing is built from the canonical ``DiracCloverPC``."""
+class _ResidentPairSolve(_PairOpSolve):
+    """A solve on operators kept in ``_ctx`` (``_resident_clover``,
+    ``_resident_wilson``): the pair operators are the resident ones
+    under this call's kappa, nothing is built from a canonical
+    operator."""
 
     def __init__(self, term: dict, kappa: float):
         self._term = term
@@ -757,6 +829,10 @@ class _CloverResidentSolve(_PairOpSolve):
         return self._term["ops"][jnp.dtype(_pair_store(prec))].with_kappa(
             self._kappa)
 
+
+class _CloverResidentSolve(_ResidentPairSolve):
+    """The clover solve on the resident term (``_resident_clover``)."""
+
     def full(self):
         """The full M = A - kappa D of the verified-exit check."""
         from ..models.clover import DiracCloverFullPairs
@@ -766,7 +842,7 @@ class _CloverResidentSolve(_PairOpSolve):
         return 2 * 1320 + 2 * 504 + 48      # DiracCloverPC's count
 
 
-class _WilsonPairsSolve:
+class _WilsonPairsSolve(_ResidentPairSolve):
     """Pallas-dslash-in-solver routing for the Wilson PC family: the
     whole Krylov loop (prepare, MdagM, reconstruct) runs on the packed
     pair representation with the measured-winner pallas eo stencil
@@ -777,48 +853,16 @@ class _WilsonPairsSolve:
     inside the CG hot loop, lib/inv_cg_quda.cpp + dslash_policy.hpp).
 
     CG routes through the normal equations (coefficients real — exact
-    on pairs), mirroring _PairOpSolve; the mixed-precision hooks hand
-    back the bf16 pair operator + the in-place pair codec on the SAME
-    layout, so reliable updates stay complex-free too."""
+    on pairs), as _PairOpSolve; the mixed-precision hooks hand back the
+    bf16 pair operator + the in-place pair codec on the SAME layout, so
+    reliable updates stay complex-free too.
 
-    hermitian = False
-
-    def __init__(self, dpk, pallas_interpret: bool = False,
-                 pallas_version: Optional[int] = None):
-        self._dpk = dpk
-        self._pallas_interpret = pallas_interpret
-        self.op = dpk.pairs(jnp.float32, use_pallas=True,
-                            pallas_interpret=pallas_interpret,
-                            pallas_version=pallas_version)
-
-    def prepare(self, b_even, b_odd):
-        return self.op.prepare_pairs(b_even, b_odd)
-
-    def M(self, x_pp):
-        return self.op.M_pairs(x_pp)
-
-    def Mdag(self, x_pp):
-        return self.op.Mdag_pairs(x_pp)
-
-    def MdagM(self, x_pp):
-        return self.op.MdagM_pairs(x_pp)
-
-    def reconstruct(self, x_pp, b_even, b_odd):
-        return self.op.reconstruct_pairs(x_pp, b_even, b_odd)
-
-    def sloppy(self, prec: str = "half"):
-        store = jnp.bfloat16 if prec in ("half", "quarter") \
-            else jnp.float32
-        return self._dpk.pairs(store, use_pallas=True,
-                               pallas_interpret=self._pallas_interpret,
-                               pallas_version=self.op._pallas_version)
-
-    def codec(self, precise_dtype, store_dtype):
-        from ..solvers.mixed import pair_inplace_codec
-        return pair_inplace_codec(store_dtype)
+    The operators are the resident ones (``_resident_wilson``), and the
+    verified exit runs on ``op`` itself (solvers/program.verified_exit):
+    no canonical Wilson operator is built on this route."""
 
     def flops_per_site_M(self) -> int:
-        return getattr(self._dpk, "flops_per_site_M", lambda: 0)()
+        return 2 * 1320 + 48                # DiracWilsonPC's count
 
 
 def _invert_wilson_df64(b, param: InvertParam, d, sloppy_prec: str,
@@ -1214,6 +1258,22 @@ def _note_solve_program(span, api: str, form: str, solver: str,
     omet.record_solve_program(api, form, solver, outcome)
 
 
+def _verified_exit(api: str, form: str, op, b, x_pp):
+    """The verified exit of a Wilson pair route, one cached program on
+    the resident f32 pair operator ``op`` (solvers/program.py): the
+    canonical solution(s) and, on the host, the true residual(s) of
+    what is returned.  The one host read ends the epilogue phase on the
+    program's device time.  Hit or miss as ``_note_solve_program``."""
+    import numpy as np
+
+    from ..obs import trace as otr
+    from ..solvers import program as sprog
+    with otr.span("verified_exit", cat="epilogue") as span:
+        (x_full, true_res), hit = sprog.verified_exit(op, b, x_pp)
+        _note_solve_program(span, api, form, "verified-exit", hit)
+        return x_full, np.asarray(true_res)
+
+
 def _invert_quda_body(source, param: InvertParam):
     from .. import solvers
     from ..obs import convergence as oconv
@@ -1280,31 +1340,6 @@ def _invert_quda_body(source, param: InvertParam):
         pair_op = pair_op and not pair_excluded
         wil_pairs = wil_pairs and not pair_excluded
 
-        # the clover pair route solves on the resident term
-        # (load_clover_quda; built on first use): no canonical operator
-        # is constructed, for the solve or for the verified-exit check
-        clover_resident = pair_op and param.dslash_type == "clover"
-        if clover_resident:
-            d = _CloverResidentSolve(_resident_clover(
-                param, (_pair_store(sloppy_prec),) if mixed else ()),
-                param.kappa)
-            d_full = d.full()
-        else:
-            d = _build_dirac(param, pc)
-            d_full = _build_dirac(param, False)
-
-        # TPU-native packed device order for the Wilson PC solve path
-        # (QUDA keeps solver fields in native FloatN order the same way);
-        # default on TPU, opt-in/out anywhere via QUDA_TPU_PACKED=1/0.
-        # Skipped for the dtype-sloppy mixed path (its canonical sloppy
-        # operator cannot consume packed iterates) and for 'quarter'
-        # (the int8 gauge codec lives on the canonical layout).
-        if (param.dslash_type == "wilson" and pc
-                and _packed_enabled(on_tpu)
-                and not (mixed and dtype_sloppy and not pair_sloppy)
-                and sloppy_prec != "quarter"):
-            d = d.packed()
-
         # Extended-precision (df64) route: deep-tolerance Wilson CG where
         # no f64 backend serves (TPU always; CPU when the precise dtype
         # is f32).  The fp64-matPrecise + dbldbl-reduction analog
@@ -1324,6 +1359,41 @@ def _invert_quda_body(source, param: InvertParam):
                      and _packed_enabled(on_tpu))
         df64_route = df64_able and df64_mode != "0" and (
             df64_mode == "1" or param.tol < 5e-8)
+
+        # the clover and Wilson pair routes solve on what is resident
+        # (load_clover_quda / built on first use): no canonical operator
+        # is constructed, for the solve or for the verified-exit check
+        clover_resident = pair_op and param.dslash_type == "clover"
+        wilson_resident = wil_pairs and not df64_route
+        stores = (_pair_store(sloppy_prec),) if mixed else ()
+        if clover_resident:
+            d = _CloverResidentSolve(_resident_clover(param, stores),
+                                     param.kappa)
+            d_full = d.full()
+        elif wilson_resident:
+            # the hand-tuned eo kernel runs inside the compiled Krylov
+            # loop (interpret-mode off TPU so the routing is testable
+            # on CPU hosts); the verified exit is d.op's own program
+            d = _WilsonPairsSolve(_resident_wilson(param, stores),
+                                  param.kappa)
+            d_full = None
+        else:
+            d = _build_dirac(param, pc)
+            d_full = _build_dirac(param, False)
+
+        # TPU-native packed device order for the Wilson PC solve path
+        # (QUDA keeps solver fields in native FloatN order the same way);
+        # default on TPU, opt-in/out anywhere via QUDA_TPU_PACKED=1/0.
+        # Skipped for the dtype-sloppy mixed path (its canonical sloppy
+        # operator cannot consume packed iterates) and for 'quarter'
+        # (the int8 gauge codec lives on the canonical layout).
+        if (param.dslash_type == "wilson" and pc
+                and not wilson_resident
+                and _packed_enabled(on_tpu)
+                and not (mixed and dtype_sloppy and not pair_sloppy)
+                and sloppy_prec != "quarter"):
+            d = d.packed()
+
         if not df64_route:
             if stag_pairs:
                 # complex-free staggered solve loop (pair representation
@@ -1335,13 +1405,6 @@ def _invert_quda_body(source, param: InvertParam):
             elif pair_op and not clover_resident:
                 d = _PairOpSolve(d, _pallas_enabled(on_tpu),
                                  _pallas_interpret(on_tpu))
-            elif wil_pairs:
-                from ..models.wilson import DiracWilsonPCPacked
-                if isinstance(d, DiracWilsonPCPacked):
-                    # the hand-tuned eo kernel runs inside the compiled
-                    # Krylov loop (interpret-mode off TPU so the routing
-                    # is testable on CPU hosts)
-                    d = _WilsonPairsSolve(d, _pallas_interpret(on_tpu))
 
             if pc:
                 be, bo = _split(b, param, d)
@@ -1434,16 +1497,21 @@ def _invert_quda_body(source, param: InvertParam):
 
     with otr.phase("epilogue", "invert_quda"):
         x_sys = back(res.x)
-        if pc:
-            xe, xo = d.reconstruct(x_sys, be, bo)
-            x_full = _join(xe, xo, param, d)
+        if wilson_resident:
+            x_full, true_res = _verified_exit(
+                "invert_quda", _solve_form(d), d.op, b, x_sys)
         else:
-            x_full = x_sys
+            if pc:
+                xe, xo = d.reconstruct(x_sys, be, bo)
+                x_full = _join(xe, xo, param, d)
+            else:
+                x_full = x_sys
+            r = b - d_full.M(x_full)
+            true_res = jnp.sqrt(blas.norm2(r) / blas.norm2(b))
 
         param.iter_count = int(res.iters)
+        param.true_res = float(true_res)
         param.secs = time.perf_counter() - t0
-        r = b - d_full.M(x_full)
-        param.true_res = float(jnp.sqrt(blas.norm2(r) / blas.norm2(b)))
         flops = getattr(d, "flops_per_site_M", lambda: 0)()
         # GFLOPS convention: flops_per_site_M counts flops per site the
         # operator UPDATES, and an even/odd-preconditioned operator
@@ -1457,8 +1525,9 @@ def _invert_quda_body(source, param: InvertParam):
         sites = _ctx["geom"].volume // 2 if pc else _ctx["geom"].volume
         param.gflops = (param.iter_count * mv_applies * flops
                         * sites) / 1e9
-        # verified exit: param.true_res above IS the hi-precision XLA
-        # reference recomputation (d_full.M on the full lattice) — the
+        # verified exit: param.true_res above IS the recomputed
+        # full-lattice residual of x_full at the precise dtype (d_full.M,
+        # or the pair program on the resident f32 operator) — the
         # supervision epilogue records it as verified_res and
         # classifies the exit (robust/), and ALWAYS maintains
         # param.converged + the one-time unconverged warning
@@ -1851,31 +1920,29 @@ def _invert_multi_src_body(sources, param: InvertParam):
             rhs = op.prepare_pairs(be, bo)
             res = fused_cg(op.MdagM_pairs, op.Mdag_pairs(rhs), tol=tol,
                            maxiter=maxiter)
-            xe, xo = op.reconstruct_pairs(res.x, be, bo)
+            # the pair-form verified exit in the lane's own trace, on
+            # its own device: reconstruction, join and the true residual
+            # of what is returned (no canonical full operator)
+            x, true_res = op.verified_exit_pairs(b, res.x)
             # thread the solver's OWN convergence claim (and sentinel
             # code) out of the vmapped lane: the maxiter heuristic
             # cannot see a mid-solve breakdown exit, whose iters <
             # maxiter would otherwise read as converged
-            return (even_odd_join(xe, xo, geom), res.iters,
-                    res.converged, res.breakdown)
+            return x, true_res, res.iters, res.converged, res.breakdown
 
         # pass the RAW resident gauge; each sub-grid folds the boundary
         # phase inside its own trace (DiracWilsonPC does it)
         t_solve0 = time.perf_counter()
         with otr.phase("compute", "invert_multi_src_quda", mesh=mesh,
                        route="split_grid"):
-            x_full, iters, conv_l, bk_l = split_grid_solve(
+            x_full, res_rhs, iters, conv_l, bk_l = split_grid_solve(
                 solve_one, _ctx["gauge"], B, mesh)
         _record_solve_metrics("invert_multi_src_quda",
                               "wilson_split_grid", param.inv_type,
                               time.perf_counter() - t_solve0,
                               param.dslash_type, param.cuda_prec)
         with otr.phase("epilogue", "invert_multi_src_quda"):
-            d_chk = _build_dirac(param, False)
-            res_rhs = [float(jnp.sqrt(blas.norm2(B[i]
-                                                 - d_chk.M(x_full[i]))
-                                      / blas.norm2(B[i])))
-                       for i in range(n_src)]
+            res_rhs = np.asarray(res_rhs)
             bk = (None if bk_l is None
                   else int(np.max(np.asarray(bk_l))))
             return _finish(x_full, np.asarray(iters), res_rhs, 2.0,
@@ -1887,21 +1954,30 @@ def _invert_multi_src_body(sources, param: InvertParam):
         from ..solvers.block import (_per_rhs_dot, batched_cg_pairs,
                                      block_cg_pairs)
         with otr.phase("setup", "invert_multi_src_quda"):
-            d = _build_dirac(param, True)
             if param.dslash_type == "wilson":
-                d = d.packed()
-            # staggered: pin the two_pass form — this route only ever
-            # runs the gather MRHS kernel (_d_to_mrhs), so 'auto' would
-            # race single-RHS kernels whose winner is never used
-            kw = ({"form": "two_pass"} if stag_family else {})
-            op = d.pairs(jnp.float32,
-                         use_pallas=_pallas_enabled(on_tpu),
-                         pallas_interpret=_pallas_interpret(on_tpu),
-                         **kw)
+                # the resident f32 pair operator, as invert_quda's
+                # wil_pairs route; it presents off a mesh, and then the
+                # verified exit is its own program
+                op = _WilsonPairsSolve(_resident_wilson(param),
+                                       param.kappa).op
+            else:
+                # staggered: pin the two_pass form — this route only
+                # ever runs the gather MRHS kernel (_d_to_mrhs), so
+                # 'auto' would race single-RHS kernels whose winner is
+                # never used
+                kw = ({"form": "two_pass"} if stag_family else {})
+                op = _build_dirac(param, True).pairs(
+                    jnp.float32, use_pallas=_pallas_enabled(on_tpu),
+                    pallas_interpret=_pallas_interpret(on_tpu), **kw)
+            pair_exit = (param.dslash_type == "wilson"
+                         and sprog.presents(op))
             halves = [even_odd_split(B[i], geom) for i in range(n_src)]
             be = jnp.stack([h[0] for h in halves])
             bo = jnp.stack([h[1] for h in halves])
+            del halves
             rhs_b = op.prepare_pairs_mrhs(be, bo)
+            if pair_exit:
+                del be, bo      # the verified exit splits B itself
             if stag_family:
                 # the staggered PC operator is already the (Hermitian
                 # positive definite) normal operator — the batched CG
@@ -1962,14 +2038,18 @@ def _invert_multi_src_body(sources, param: InvertParam):
                 "breakdown reports lanes unconverged too); per-RHS "
                 "true_res_multi holds the achieved residuals")
         with otr.phase("epilogue", "invert_multi_src_quda"):
-            xe_b, xo_b = op.reconstruct_pairs_mrhs(res.x, be, bo)
-            x_full = jax.vmap(
-                lambda e, o: even_odd_join(e, o, geom))(xe_b, xo_b)
-            d_chk = _build_dirac(param, False)
-            res_rhs = [float(jnp.sqrt(blas.norm2(B[i]
-                                                 - d_chk.M(x_full[i]))
-                                      / blas.norm2(B[i])))
-                       for i in range(n_src)]
+            if pair_exit:
+                x_full, res_rhs = _verified_exit(
+                    "invert_multi_src_quda", form_b, op, B, res.x)
+            else:
+                xe_b, xo_b = op.reconstruct_pairs_mrhs(res.x, be, bo)
+                x_full = jax.vmap(
+                    lambda e, o: even_odd_join(e, o, geom))(xe_b, xo_b)
+                d_chk = _build_dirac(param, False)
+                res_rhs = [float(jnp.sqrt(blas.norm2(B[i]
+                                                     - d_chk.M(x_full[i]))
+                                          / blas.norm2(B[i])))
+                           for i in range(n_src)]
             x_out = _finish(x_full, iters_rhs, res_rhs, mv_applies,
                             converged_rhs=conv,
                             breakdown=getattr(res, "breakdown", None))

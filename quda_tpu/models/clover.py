@@ -16,8 +16,6 @@ so csw=0 reduces exactly to Wilson.  PC operator on parity p:
 
 from __future__ import annotations
 
-import copy
-
 import jax
 import jax.numpy as jnp
 
@@ -240,13 +238,6 @@ class DiracCloverPCPairs(_ProgramOperand, _SchurPairOpBase):
             "clover", form, self,
             race=lambda: formsel.race_schur("clover", self, aux=aux),
             aux=aux)
-
-    def with_kappa(self, kappa: float) -> "DiracCloverPCPairs":
-        """The same resident arrays under another hopping parameter (a
-        leaf of the pytree: the solve program's executable is shared)."""
-        op = copy.copy(self)
-        op.kappa = float(kappa)
-        return op
 
     def _diag_sign_pairs(self, x, sign, out_dtype):
         return apply_clover_pairs(self.clover_p_pp, x, out_dtype)

@@ -13,6 +13,8 @@ reconstruction x_{1-p} = b_{1-p} + kappa D_{1-p,p} x_p
 
 from __future__ import annotations
 
+import copy
+
 import jax
 import jax.numpy as jnp
 
@@ -259,6 +261,17 @@ def _notice_precision_form(requested: str, served: str, why: str):
         f"({why}); pin via QUDA_TPU_PRECISION_FORM", qlog.SUMMARIZE)
 
 
+def hop_route_knobs() -> tuple:
+    """What ``_PackedHopMixin._setup_hop`` resolves from the environment
+    when its caller pins nothing: (pallas version, precision form,
+    legacy reconstruct-12).  An operator kept across calls is keyed by
+    it, so that a flipped knob gives a new operator, never a stale one."""
+    from ..utils import config as qconf
+    return (qconf.get("QUDA_TPU_PALLAS_VERSION", fresh=True),
+            str(qconf.get("QUDA_TPU_PRECISION_FORM", fresh=True)),
+            str(qconf.get("QUDA_TPU_RECONSTRUCT", fresh=True)) == "12")
+
+
 class _PackedHopMixin:
     """The packed eo Wilson hop on pair arrays, shared by every
     packed-layout pair operator (Wilson, clover, twisted, Möbius hops):
@@ -303,6 +316,7 @@ class _PackedHopMixin:
         self._pallas_interpret = pallas_interpret
         self._tb_sign = tb_sign
         from ..utils import config as qconf
+        env_version, env_form, legacy_r12 = hop_route_knobs()
         if mesh is not None and getattr(mesh, "size", 2) == 1:
             # single-chip escape: a 1-device mesh shards nothing — drop
             # it and resolve the kernel form exactly like the unsharded
@@ -313,8 +327,7 @@ class _PackedHopMixin:
             # sharded eo policy exists in both kernel forms: the
             # measured-best v2 default (PERF.md round 5) finally serves
             # multi-chip too, and env/kwarg can still pin v3
-            pallas_version = qconf.get("QUDA_TPU_PALLAS_VERSION",
-                                       fresh=True)
+            pallas_version = env_version
         if pallas_version not in (2, 3):
             raise ValueError(f"pallas_version must be 2 or 3, got "
                              f"{pallas_version}")
@@ -337,11 +350,7 @@ class _PackedHopMixin:
         # (QUDA_TPU_RECONSTRUCT=12 -> r12, else full); 'auto' races the
         # numerics-preserving forms via utils.tune.  int8 is NEVER part
         # of a race: block-float links change the operator's floats.
-        legacy_r12 = str(qconf.get("QUDA_TPU_RECONSTRUCT",
-                                   fresh=True)) == "12"
-        form = precision_form
-        if form is None:
-            form = str(qconf.get("QUDA_TPU_PRECISION_FORM", fresh=True))
+        form = env_form if precision_form is None else precision_form
         requested = form or ("r12" if legacy_r12 else "full")
         form = self._downgrade_precision_form(requested, use_pallas,
                                               mesh, legacy_r12)
@@ -861,6 +870,13 @@ class _ProgramOperand:
         vars(op).update(zip(cls._PROGRAM_ARRAYS, arrays))
         return op
 
+    def with_kappa(self, kappa: float):
+        """The same resident arrays under another hopping parameter (a
+        leaf of the pytree: a program's executable is shared)."""
+        op = copy.copy(self)
+        op.kappa = float(kappa)
+        return op
+
 
 class _SchurPairOpBase(_PackedHopMixin, _PairSloppyBase):
     """Template for clover-type Schur pair operators
@@ -1167,6 +1183,23 @@ class DiracWilsonPCPackedSloppy(_ProgramOperand, _PackedHopMixin,
         self.kappa = float(dpk.kappa)
         self.matpc = dpk.matpc
 
+    @classmethod
+    def from_packed(cls, geom, gauge_eo_packed, kappa, matpc,
+                    store_dtype=jnp.float32, use_pallas: bool = False,
+                    pallas_interpret: bool = False, tb_sign: bool = True
+                    ) -> "DiracWilsonPCPackedSloppy":
+        """From the boundary-folded packed links alone
+        (wilson_packed.pack_gauge_eo): what a resident Wilson term is
+        built from, no canonical DiracWilsonPC or DiracWilsonPCPacked
+        in between.  The kernel form is resolved from the environment
+        as ``pairs()`` does without pins."""
+        op = object.__new__(cls)
+        op._setup_hop(geom, gauge_eo_packed, store_dtype, use_pallas,
+                      pallas_interpret, tb_sign=tb_sign)
+        op.kappa = float(kappa)
+        op.matpc = matpc
+        return op
+
     def _to_pairs(self, x):
         from ..ops import wilson_packed as wpk
         return wpk.to_packed_pairs(x, self.store_dtype)
@@ -1209,6 +1242,44 @@ class DiracWilsonPCPackedSloppy(_ProgramOperand, _PackedHopMixin,
         x_p = _PackedHopMixin._from_pairs(self, x_pp, b_q.dtype)
         x_q = _PackedHopMixin._from_pairs(self, xq_pp, b_q.dtype)
         return (x_p, x_q) if p == EVEN else (x_q, x_p)
+
+    def verified_exit_pairs(self, b, x_pp):
+        """The API's verified exit on the pair representation: the
+        canonical full-lattice source ``b`` and the pair-form PC
+        solution -> (canonical full-lattice solution, |b - M x| / |b|).
+        x_q = b_q + kappa D x_p is the reconstruction; the residual is
+        that of the RETURNED solution under the full M = 1 - kappa D,
+        applied parity by parity with this operator's own hop in f32:
+        no canonical (...,4,3) temporary beyond the two boundaries.
+        With a leading source axis on both, the MRHS hop and one
+        residual per source.  Meant to be traced (solvers/program.py)
+        on the f32 operator."""
+        from ..fields.geometry import EVEN
+        from ..fields.spinor import even_odd_join, even_odd_split
+        f32, p, kappa = jnp.float32, self.matpc, self.kappa
+        batched = b.ndim == 7
+        per_src = jax.vmap if batched else (lambda f: f)
+        hop = self._d_to_mrhs if batched else self._d_to
+        to_pp = per_src(
+            lambda v: _PackedHopMixin._to_pairs(self, v).astype(f32))
+        from_pp = per_src(
+            lambda v: _PackedHopMixin._from_pairs(self, v, b.dtype))
+        norm2 = per_src(lambda v: jnp.sum(v * v))
+        halves = per_src(lambda v: even_odd_split(v, self.geom))(b)
+        b_p, b_q = (to_pp(h)
+                    for h in (halves if p == EVEN else halves[::-1]))
+        x_p = x_pp.astype(f32)
+        # D x_p serves the reconstruction and the q half of M x (the
+        # compiler merges a second identical hop anyway)
+        d_xp = hop(x_p, 1 - p, f32)
+        x_q = b_q + kappa * d_xp
+        r_p = b_p - (x_p - kappa * hop(x_q, p, f32))
+        r_q = b_q - (x_q - kappa * d_xp)
+        x_e, x_o = (from_pp(v)
+                    for v in ((x_p, x_q) if p == EVEN else (x_q, x_p)))
+        x = per_src(lambda e, o: even_odd_join(e, o, self.geom))(x_e, x_o)
+        return x, jnp.sqrt((norm2(r_p) + norm2(r_q))
+                           / (norm2(b_p) + norm2(b_q)))
 
     # -- multi-RHS boundary helpers (the invert_multi_src_quda route) --
     def prepare_pairs_mrhs(self, b_even_b, b_odd_b):

@@ -174,11 +174,12 @@ METRICS: dict[str, dict] = {
                 "executable after the first)"},
     "solve_program_total": {
         "type": COUNTER,
-        "help": "calls through a cached solve program "
-                "(solvers/program.py), by api/form/solver/outcome: "
-                "'miss' traced (and lowered, compiled or fetched) the "
-                "loop program, 'hit' was an in-process executable "
-                "lookup"},
+        "help": "calls through a cached program (solvers/program.py: "
+                "the solve loops, and solver='verified-exit' the "
+                "Wilson pair routes' verified exit), by "
+                "api/form/solver/outcome: 'miss' traced (and lowered, "
+                "compiled or fetched) the program, 'hit' was an "
+                "in-process executable lookup"},
     "clover_term_total": {
         "type": COUNTER,
         "help": "uses of the resident clover term (load_clover_quda, "
@@ -186,6 +187,13 @@ METRICS: dict[str, dict] = {
                 "resident, 'reused' the resident term served, "
                 "'rebuilt' another kappa*csw, matpc, gauge or kernel "
                 "route replaced it"},
+    "wilson_term_total": {
+        "type": COUNTER,
+        "help": "uses of the resident Wilson pair operators (invert_quda "
+                "and invert_multi_src_quda on the packed pair routes) by "
+                "outcome: 'built' nothing was resident, 'reused' the "
+                "resident operators served, 'rebuilt' another matpc, "
+                "boundary or kernel route replaced them"},
     # tuner warm-cache accounting (utils/tune.py)
     "tune_cache_hits_total": {
         "type": COUNTER,

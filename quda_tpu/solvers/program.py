@@ -24,6 +24,11 @@ a later call with the same key is an in-process executable lookup.
   the armed fault iteration).  The key holds no array and no operator
   identity.
 
+The verified exit of the Wilson pair routes is a program of the same
+kind (``verified_exit``): from the canonical source and the pair-form
+solution to the canonical solution and its true residual, the resident
+f32 pair operator an operand.
+
 An operator goes through a program when it ``presents``: its class is a
 registered pytree with a ``program_signature``.  Everything else (a
 mesh operator, an MG closure, a bare lambda, the staggered and zoo pair
@@ -68,9 +73,9 @@ def presents(*ops) -> bool:
                for op in ops)
 
 
-def _run(program, *operands, key):
+def _run(program, *operands, **static):
     n0 = _traces[0]
-    out = program(*operands, key=key)
+    out = program(*operands, **static)
     return out, _traces[0] == n0
 
 
@@ -114,3 +119,19 @@ def batched_cg_pairs(op, B, tol: float, maxiter: int,
     key = (_resolve_check_every(None), _loop_knobs(record, maxiter))
     return _run(_batched_cg_pairs_program, op, B, float(tol),
                 int(maxiter), key=key)
+
+
+@jax.jit
+def _verified_exit_program(op, b, x_pp):
+    _traces[0] += 1
+    return op.verified_exit_pairs(b, x_pp)
+
+
+def verified_exit(op, b, x_pp):
+    """The verified exit of a solve on the f32 Wilson packed pair
+    operator ``op`` (``verified_exit_pairs``) through the cached
+    program: canonical source(s) and pair-form PC solution(s) ->
+    ``((canonical full-lattice solution, true residual), hit)``.  With
+    a leading source axis on ``b`` and ``x_pp`` the N residuals come
+    back in one array."""
+    return _run(_verified_exit_program, op, b, x_pp)

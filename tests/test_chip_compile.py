@@ -322,3 +322,45 @@ def test_clover_solve_program_compiles_for_v5e_with_blocks_as_parameters(
     consts = _hlo_values(hlo, "constant")
     big = [c for c in consts if c[0] > 2 ** 20]
     assert consts and not big, f"fields baked into the executable: {big}"
+
+
+@pytest.mark.parametrize("n_src", [1, 8])
+def test_verified_exit_program_compiles_for_v5e(one_chip, n_src):
+    """The Wilson pair routes' verified exit (solvers/program.py) at
+    24^4, one source and the eight of a batched call: compiles for the
+    described chip with the resident links as parameters (nothing the
+    size of a field is a constant), one kernel call per parity (the
+    hop the reconstruction and M x share is not run twice), and what it
+    holds besides its arguments and results stays far under the
+    canonical M's tile-padded temporaries it replaced (6.9 GiB peak for
+    one source, 13 GiB for eight: PERF_LEDGER, PR 28)."""
+    from quda_tpu.fields.geometry import LatticeGeometry
+    from quda_tpu.models.wilson import DiracWilsonPCPackedSloppy
+    from quda_tpu.solvers import program as sprog
+    geom = LatticeGeometry(DIMS)
+    lead = () if n_src == 1 else (n_src,)
+
+    def lower():
+        lk = jax.ShapeDtypeStruct((4, 3, 3, L, L, YXH), jnp.complex64)
+        op = jax.eval_shape(
+            lambda e, o: DiracWilsonPCPackedSloppy.from_packed(
+                geom, (e, o), 0.124, 0, F32, use_pallas=True,
+                pallas_interpret=False), lk, lk)
+        op = jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(
+                s.shape, s.dtype, sharding=one_chip,
+                weak_type=s.weak_type), op)
+        b = jax.ShapeDtypeStruct(lead + DIMS + (4, 3), jnp.complex64,
+                                 sharding=one_chip)
+        x = jax.ShapeDtypeStruct(*_psi(F32, lead), sharding=one_chip)
+        return sprog._verified_exit_program.lower(op, b, x)
+    compiled = _aot(lower)
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") == 2
+    params = _hlo_values(hlo, "parameter")
+    links = ",".join(str(d) for d in _links(F32)[0])
+    assert sum(p[1:] == ("f32", links) for p in params) == 4
+    big = [c for c in _hlo_values(hlo, "constant") if c[0] > 2 ** 20]
+    assert not big, f"fields baked into the executable: {big}"
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < n_src * 0.4 * 2 ** 30

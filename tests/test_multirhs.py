@@ -410,6 +410,16 @@ def test_invert_multi_src_quda_split_grid(api_ctx, monkeypatch):
         rel = float(np.max(np.abs(np.asarray(X[i]) - np.asarray(X_b[i])))
                     / np.max(np.abs(np.asarray(X_b[i]))))
         assert rel < 1e-4, (i, rel)
+    # each lane's own pair-form exit reported the residual of what it
+    # returned: the canonical operator on the host's copy reads the same
+    from quda_tpu.models.wilson import DiracWilson
+    d = DiracWilson(api._ctx["gauge"], GEOM_SMALL, p.kappa,
+                    api._antiperiodic())
+    for i in range(NRHS):
+        r = jnp.asarray(B[i]) - d.M(jnp.asarray(X[i]))
+        want = float(jnp.linalg.norm(r.ravel())
+                     / np.linalg.norm(B[i].ravel()))
+        assert abs(p.true_res_multi[i] - want) < 0.1 * want
 
 
 def test_invert_multi_src_quda_fallback_non_wilson(api_ctx):

@@ -10,6 +10,12 @@ route).  A miss costs the CPU a 20-40 s compile of interpreted kernels,
 so the first call of each route is made once per worker, in a fixture,
 and what does not need the kernels (the comparison with the eager
 solver, the operand's own tests) runs on the XLA pair stencil at 4^4.
+
+Both routes solve on the resident Wilson pair operators
+(``wilson_term_total``) and leave through the verified-exit program
+(``solve_program_total`` with solver ``verified-exit``): their API
+cases are here because they need the same warm-up (the program and the
+term by themselves: tests/test_wilson_resident.py).
 """
 
 import jax
@@ -68,9 +74,10 @@ def _solve(route, seed, kappa=KAPPA):
     return b, np.asarray(api.invert_multi_src_quda(b, p)), p
 
 
-def _counts(route):
-    """(misses, hits) of the route's solve program so far."""
-    want = KEY[route]
+def _counts(route, solver=None):
+    """(misses, hits) of the route's solve program so far, or of its
+    program ``solver`` (the verified exit)."""
+    want = dict(KEY[route], **({"solver": solver} if solver else {}))
     out = {"miss": 0, "hit": 0}
     for (name, labels), v in omet.snapshot()["counters"].items():
         lab = dict(labels)
@@ -80,9 +87,26 @@ def _counts(route):
     return out["miss"], out["hit"]
 
 
-def _delta(route, before):
-    m, h = _counts(route)
+def _delta(route, before, solver=None):
+    m, h = _counts(route, solver)
     return m - before[0], h - before[1]
+
+
+EXIT = "verified-exit"
+
+
+def _term_counts():
+    """{outcome: count} of the resident Wilson term so far."""
+    out = {"built": 0, "reused": 0, "rebuilt": 0}
+    for (name, labels), v in omet.snapshot()["counters"].items():
+        if name == "wilson_term_total":
+            out[dict(labels)["outcome"]] += int(v)
+    return out
+
+
+def _term_delta(before):
+    return {k: v - before[k] for k, v in _term_counts().items()
+            if v != before[k]}
 
 
 def _host_residual(gauge, b, x, kappa):
@@ -134,9 +158,10 @@ def warm(route, first_calls):
     added; made here, in set-up, once per worker.  Later calls with the
     same key must all be hits."""
     if route not in first_calls:
-        before = _counts(route)
+        before, before_exit = _counts(route), _counts(route, EXIT)
         _solve(route, seed=1)
         first_calls[route] = _delta(route, before)
+        first_calls[route, EXIT] = _delta(route, before_exit, EXIT)
     return first_calls[route]
 
 
@@ -164,18 +189,22 @@ def knobs(monkeypatch):
 @pytest.mark.parametrize("route", ROUTES)
 def test_new_source_and_new_gauge_reuse_the_program(route, warm, gauges):
     assert sum(warm) == 1         # at most the process's one trace
-    before = _counts(route)
+    before, before_exit = _counts(route), _counts(route, EXIT)
     b2, x2, p2 = _solve(route, seed=2)
     try:
         api.load_gauge_quda(gauges["B"], GaugeParam(X=(L,) * 4,
                                                     cuda_prec="single"))
+        terms = _term_counts()
         b3, x3, p3 = _solve(route, seed=3)
     finally:
         api.load_gauge_quda(gauges["A"], GaugeParam(X=(L,) * 4,
                                                     cuda_prec="single"))
     assert _delta(route, before) == (0, 2)
+    assert _delta(route, before_exit, EXIT) == (0, 2)
+    # the resident term went with gauge A: the second call built its own
+    assert _term_delta(terms) == {"built": 1}
     # each call returned the solution of ITS gauge, not of the links the
-    # program was traced with
+    # program was traced with or of a term that outlived its gauge
     for i in range(len(b2)):
         assert _host_residual(gauges["A"], b2[i], x2[i], KAPPA) < 5e-6
         assert _host_residual(gauges["B"], b3[i], x3[i], KAPPA) < 5e-6
@@ -187,12 +216,93 @@ def test_new_source_and_new_gauge_reuse_the_program(route, warm, gauges):
 
 @pytest.mark.parametrize("route", ROUTES)
 def test_second_kappa_reuses_the_program(route, warm, gauges):
-    before = _counts(route)
+    before, before_exit = _counts(route), _counts(route, EXIT)
+    api._resident_wilson(_param())  # KAPPA's, if the last test loaded anew
+    terms = _term_counts()
     b, x, p = _solve(route, seed=4, kappa=0.105)
     assert _delta(route, before) == (0, 1)
+    assert _delta(route, before_exit, EXIT) == (0, 1)
+    assert _term_delta(terms) == {"reused": 1}      # kappa: a leaf
     assert p.converged
     assert _host_residual(gauges["A"], b[0], x[0], 0.105) < 5e-6
     assert _host_residual(gauges["A"], b[0], x[0], KAPPA) > 1e-3
+
+
+# the resident term and the verified exit, through the API -----------------
+
+def _load(gauge):
+    api.load_gauge_quda(gauge, GaugeParam(X=(L,) * 4, cuda_prec="single"))
+
+
+@pytest.mark.parametrize("route", ROUTES)
+def test_load_then_two_solves_build_once_and_trace_the_exit_once(
+        route, warm, first_calls, gauges):
+    assert first_calls[route, EXIT] == (1, 0)
+    _load(gauges["A"])
+    assert api._ctx["wilson"] is None
+    before_exit, terms = _counts(route, EXIT), _term_counts()
+    for seed in (7, 8):
+        b, x, p = _solve(route, seed=seed)
+        assert p.converged
+        for i in range(len(b)):
+            assert _host_residual(gauges["A"], b[i], x[i], KAPPA) < 5e-6
+    assert _term_delta(terms) == {"built": 1, "reused": 1}
+    assert _delta(route, before_exit, EXIT) == (0, 2)
+
+
+@pytest.mark.parametrize("route", ROUTES)
+def test_free_gauge_then_a_new_one_solves_the_new_system(route, warm,
+                                                         gauges):
+    terms = _term_counts()
+    try:
+        api.free_gauge_quda()
+        assert api._ctx["wilson"] is None
+        _load(gauges["B"])
+        b, x, p = _solve(route, seed=9)
+    finally:
+        _load(gauges["A"])
+    assert _term_delta(terms) == {"built": 1}
+    assert p.converged
+    assert _host_residual(gauges["B"], b[0], x[0], KAPPA) < 5e-6
+    assert _host_residual(gauges["A"], b[0], x[0], KAPPA) > 1e-2
+
+
+@pytest.mark.parametrize("route", ROUTES)
+def test_no_canonical_wilson_operator_is_built_on_the_route(
+        route, warm, gauges, monkeypatch):
+    from quda_tpu.models import wilson as mwil
+
+    def refuse(self, *a, **k):
+        raise AssertionError(f"{type(self).__name__} built on the route")
+    for cls in (mwil.DiracWilson, mwil.DiracWilsonPC,
+                mwil.DiracWilsonPCPacked):
+        monkeypatch.setattr(cls, "__init__", refuse)
+    _load(gauges["A"])          # the term too is built without them
+    b, x, p = _solve(route, seed=10)
+    monkeypatch.undo()
+    assert p.converged
+    assert _host_residual(gauges["A"], b[0], x[0], KAPPA) < 5e-6
+
+
+@pytest.mark.parametrize("route", ROUTES)
+def test_true_res_is_that_of_what_is_returned(route, warm, gauges):
+    """A solve cut short returns a poor solution and says so: the
+    reported residual is the one the host computes of the returned
+    field, whatever the solver's recurrence believed."""
+    p = _param()
+    p.maxiter = 3
+    b = _sources(11, 1 if route == "single" else 2)
+    if route == "single":
+        x = np.asarray(api.invert_quda(b[0], p))[None]
+        reported = [p.true_res]
+    else:
+        x = np.asarray(api.invert_multi_src_quda(b, p))
+        reported = p.true_res_multi
+    assert not p.converged
+    for i in range(len(b)):
+        want = _host_residual(gauges["A"], b[i], x[i], KAPPA)
+        assert want > 1e-3
+        assert abs(reported[i] - want) < 1e-4 * want
 
 
 # (c), (d): what changes the traced loop is in the key ---------------------
