@@ -448,8 +448,8 @@ def main():
     pallas_rel_err = None
     if platform == "tpu":
         # most-important-first: if the deadline watchdog fires mid-run,
-        # the v3-vs-v2 answer (the round's open question) must already be
-        # in the record; stencil + bf16 variants follow
+        # the f32 pallas kernel must already be in the record; stencil
+        # + bf16 variants follow
         from quda_tpu.ops import wilson_pallas_packed as wpp
         # gate the pallas kernel ON DEVICE against the (CPU-gated) pair
         # stencil at the headline size — this exercises the multi-z-block
@@ -479,70 +479,7 @@ def main():
                     f"gate failed: rel err {pallas_rel_err:.3e}")
         except Exception as e:
             paths["pallas_packed_error"] = str(e)[:160]
-        # v3 kernel: scatter-form backward hops, no backward-gauge copy
-        try:
-            @jax.jit
-            def _gate3(g, p):
-                a = wpp.dslash_pallas_packed_v3(g, p, X)
-                b = wpk.dslash_packed_pairs(g, p, X, Y)
-                return (jnp.max(jnp.abs(a - b)), jnp.max(jnp.abs(b)))
-            d3, m3 = _gate3(g_d, p_d)
-            v3_rel_err = _fetch(d3) / _fetch(m3)
-            if v3_rel_err < 1e-4:
-                run_path("pallas_v3",
-                         lambda g, v: wpp.dslash_pallas_packed_v3(g, v, X),
-                         (g_d, p_d))
-            else:
-                paths["pallas_v3_error"] = (
-                    f"gate failed: rel err {v3_rel_err:.3e}")
-        except Exception as e:
-            paths["pallas_v3_error"] = str(e)[:160]
-        # reconstruct-12 v3: in-kernel third-row reconstruction needs
-        # genuine SU(3) links, so gate + time on a projected gauge
-        # (det-fixed QR) with the antiperiodic-t phase folded the same
-        # way the solve path folds it
-        try:
-            graw = (rng.standard_normal((4, T, Z, Y, X, 3, 3))
-                    + 1j * rng.standard_normal((4, T, Z, Y, X, 3, 3))
-                    ).astype(np.complex64)
-            qm, rm = np.linalg.qr(graw)
-            dg = np.diagonal(rm, axis1=-2, axis2=-1)
-            qm = qm * (dg / np.abs(dg))[..., None, :]
-            qm = qm * np.linalg.det(qm)[..., None, None] ** (-1.0 / 3.0)
-            qm[3, -1] *= -1.0
-            gsu = np.transpose(qm, (0, 5, 6, 1, 2, 3, 4)).reshape(
-                4, 3, 3, T, Z, Y * X)
-            gsu_d = jax.device_put(jnp.asarray(
-                np.stack([gsu.real, gsu.imag], axis=3).astype(np.float32)))
-            gsu_d.block_until_ready()
-            g12 = jax.jit(wpp.to_recon12)(gsu_d)
-            g12.block_until_ready()
-
-            @jax.jit
-            def _gate12(gf, gc, p):
-                a = wpp.dslash_pallas_packed_v3(gc, p, X)
-                b = wpp.dslash_pallas_packed_v3(gf, p, X)
-                return (jnp.max(jnp.abs(a - b)), jnp.max(jnp.abs(b)))
-            d12, m12 = _gate12(gsu_d, g12, p_d)
-            r12_rel_err = _fetch(d12) / _fetch(m12)
-            if r12_rel_err < 1e-4:
-                run_path("pallas_v3_r12",
-                         lambda g, v: wpp.dslash_pallas_packed_v3(
-                             g, v, X),
-                         (g12, p_d))
-                g12_bf = g12.astype(jnp.bfloat16)
-                p_bf0 = p_d.astype(jnp.bfloat16)
-                g12_bf.block_until_ready(), p_bf0.block_until_ready()
-                run_path("pallas_v3_r12_bf16",
-                         lambda g, v: wpp.dslash_pallas_packed_v3(
-                             g, v, X),
-                         (g12_bf, p_bf0))
-            else:
-                paths["pallas_v3_r12_error"] = (
-                    f"gate failed: rel err {r12_rel_err:.3e}")
-        except Exception as e:
-            paths["pallas_v3_r12_error"] = str(e)[:160]
-        # f32 stencil next: if both pallas gates failed, the record still
+        # f32 stencil next: if the pallas gate failed, the record still
         # gets a headline-eligible f32 number before the bf16 variants
         run_path("xla_pairs",
                  lambda g, v: wpk.dslash_packed_pairs(g, v, X, Y),
@@ -552,9 +489,6 @@ def main():
         g_bf = g_d.astype(jnp.bfloat16)
         p_bf = p_d.astype(jnp.bfloat16)
         g_bf.block_until_ready(), p_bf.block_until_ready()
-        run_path("pallas_v3_bf16",
-                 lambda g, v: wpp.dslash_pallas_packed_v3(g, v, X),
-                 (g_bf, p_bf))
         gbw_bf = jax.jit(lambda g: wpp.backward_gauge(g, X))(g_bf)
         gbw_bf.block_until_ready()
         run_path("pallas_bf16",

@@ -359,7 +359,7 @@ def main(argv):
             # two-pass scatter form (984 B/site, no backward copies) and
             # (iii) the FUSED single-pass fat+Naik kernel (864 B/site,
             # one launch, one psi read, no XLA sum pass) — raced, not
-            # assumed, since v3 LOST for Wilson on this chip
+            # assumed
             cases.append(
                 ("improved_staggered_v3",
                  lambda g, p: stp.dslash_staggered_pallas_v3(
@@ -1206,9 +1206,8 @@ def main(argv):
 
     if "sharded" in suites:
         # Multi-chip dslash policy A/B at 24^4 (round-8 tentpole): the
-        # rows the next multi-chip window needs to settle (a) v2-sharded
-        # vs v3-sharded kernel form and (b) fused-halo vs xla-facefix
-        # halo transport with NUMBERS (VERDICT r7 #5/#7).  GATED: these
+        # rows the next multi-chip window needs to settle fused-halo vs
+        # xla-facefix halo transport with NUMBERS.  GATED: these
         # are only meaningful compiled on >= 2 real chips — a 1-device
         # mesh exchanges nothing and an interpret-mode timing is noise —
         # so anything else logs a loud SKIPPED row instead of silence.
@@ -1227,7 +1226,7 @@ def main(argv):
             from quda_tpu.parallel.mesh import (factor_devices,
                                                 make_lattice_mesh)
             from quda_tpu.parallel.pallas_dslash import (
-                dslash_eo_pallas_sharded, dslash_eo_pallas_sharded_v3)
+                dslash_eo_pallas_sharded)
 
             Lsh = _conf("QUDA_TPU_BENCH_SOLVER_L_CHIP") or 24
             # t/z device grid whose product is GUARANTEED to be n_dev
@@ -1301,46 +1300,33 @@ def main(argv):
                 ici_gb_sh = round(qcomms.wilson_eo_halo_model(
                     dims_sh, (n_t, n_z))["total"] / 1e9, 6)
 
-                def sharded_case(name, form, policy):
-                    if form == "v2":
-                        def local(a, b, p):
-                            return dslash_eo_pallas_sharded(
-                                a, b, p, dims_sh, 0, mesh_sh,
-                                policy=policy)
-                        args = (uh, u_bw)
-                    else:
-                        def local(a, b, p):
-                            return dslash_eo_pallas_sharded_v3(
-                                a, b, p, dims_sh, 0, mesh_sh,
-                                policy=policy)
-                        args = (uh, ut)
+                def sharded_case(name, policy):
+                    def local(a, b, p):
+                        return dslash_eo_pallas_sharded(
+                            a, b, p, dims_sh, 0, mesh_sh,
+                            policy=policy)
                     fn = jax.shard_map(
                         local, mesh=mesh_sh,
                         in_specs=(gspec_p, gspec_p, pspec_p),
                         out_specs=pspec_p, check_vma=False)
                     try:
                         secs = _bench_op(lambda a, b, p: fn(a, b, p),
-                                         psi_sh, consts=args, n1=4, n2=40)
+                                         psi_sh, consts=(uh, u_bw), n1=4, n2=40)
                         _emit("sharded", name, secs, fl_sh, bts_sh,
                               platform, (Lsh,) * 4, banner=banner,
-                              mesh=[n_t, n_z], form=form, policy=policy,
+                              mesh=[n_t, n_z], form="v2", policy=policy,
                               devices=n_dev, ici_gb=ici_gb_sh)
                     except Exception as e:
                         print(json.dumps({
                             "suite": "sharded", "name": name,
                             "error": str(e)[:140]}), flush=True)
 
-                # A/B 1: kernel form at fixed (facefix) transport
-                sharded_case("wilson_eo_sharded_v2_facefix_24", "v2",
+                # A/B: halo transport — fused_halo needs real multi-chip
+                # RDMA, and a failure here is a loud error row, not
+                # silence
+                sharded_case("wilson_eo_sharded_v2_facefix_24",
                              "xla_facefix")
-                sharded_case("wilson_eo_sharded_v3_facefix_24", "v3",
-                             "xla_facefix")
-                # A/B 2: halo transport at fixed (v2, the expected winner)
-                # kernel form — fused_halo needs real multi-chip RDMA, and
-                # a failure here is a loud error row, not silence
-                sharded_case("wilson_eo_sharded_v2_fused_halo_24", "v2",
-                             "fused_halo")
-                sharded_case("wilson_eo_sharded_v3_fused_halo_24", "v3",
+                sharded_case("wilson_eo_sharded_v2_fused_halo_24",
                              "fused_halo")
 
                 # A/B 3 (round 18): mesh SHAPE at fixed (v2, facefix)

@@ -264,7 +264,6 @@ def phase_kernels(api):
               f"no tpu_custom_call in the compiled {name} MdagM_pairs")
     failed = list(qtune._failed)
     emit(phase="C", solve_form=api._solve_form(ws),
-         pallas_version=ws.op._pallas_version,
          precision_form=ws.op._precision_form,
          tpu_custom_call_mentions=found,
          tuner_failed_candidates=failed,

@@ -845,12 +845,10 @@ class _CloverResidentSolve(_ResidentPairSolve):
 class _WilsonPairsSolve(_ResidentPairSolve):
     """Pallas-dslash-in-solver routing for the Wilson PC family: the
     whole Krylov loop (prepare, MdagM, reconstruct) runs on the packed
-    pair representation with the measured-winner pallas eo stencil
-    (QUDA_TPU_PALLAS_VERSION, default v2 by the round-5 chip verdict) —
-    so the 5,673-GFLOPS kernel actually executes INSIDE the compiled
-    solve instead of only in standalone benchmarks (the solver/kernel
-    chasm, VERDICT round 5 weak #1; QUDA analog: the policy-tuned dslash
-    inside the CG hot loop, lib/inv_cg_quda.cpp + dslash_policy.hpp).
+    pair representation with the pallas eo stencil, so the kernel
+    executes INSIDE the compiled solve (QUDA analog: the policy-tuned
+    dslash inside the CG hot loop, lib/inv_cg_quda.cpp +
+    dslash_policy.hpp).
 
     CG routes through the normal equations (coefficients real — exact
     on pairs), as _PairOpSolve; the mixed-precision hooks hand back the
@@ -1063,7 +1061,6 @@ def _solve_form(d) -> str:
     op = getattr(d, "op", d)
     name = type(op).__name__.lower()
     if "wilson" in name and getattr(op, "use_pallas", False):
-        v = getattr(op, "_pallas_version", None)
         # reconstruct-12 storage is visible in the resident link shape
         # (rows kept: 2 instead of 3 — models/wilson.to_recon12), which
         # is authoritative even if QUDA_TPU_RECONSTRUCT changed after
@@ -1073,8 +1070,8 @@ def _solve_form(d) -> str:
         r12 = (gpp is not None and len(gpp) > 0
                and gpp[0].shape[1] == 2)
         suffix = "_r12" if r12 else ""
-        if getattr(op, "_mesh", None) is not None and v in (2, 3):
-            return f"wilson_sharded_v{v}{suffix}"
+        if getattr(op, "_mesh", None) is not None:
+            return f"wilson_sharded_v2{suffix}"
         # precision storage forms (PERF.md round 16) carry their own
         # traffic models; the label is read off the authoritative
         # operator attribute, with bf16 storage distinguished where the
@@ -1093,8 +1090,7 @@ def _solve_form(d) -> str:
             return "wilson_v2_bf16_bzfull"
         # f32 bzfull moves the same bytes as the baseline v2 block
         # schedule — same model row, no separate label
-        if v in (2, 3):
-            return f"wilson_v{v}{suffix}"
+        return f"wilson_v2{suffix}"
     if "wilson" in name:
         return "wilson_xla"
     if "staggered" in name:
@@ -1318,11 +1314,9 @@ def _invert_quda_body(source, param: InvertParam):
             "domain-wall", "domain-wall-4d", "mobius", "mobius-eofa",
             "clover", "twisted-mass", "twisted-clover",
             "ndeg-twisted-mass", "ndeg-twisted-clover")
-        # pallas-dslash-in-solver routing for Wilson PC (kernel-form
-        # selection threaded from utils/config.py: QUDA_TPU_PALLAS gates
-        # it on/off, QUDA_TPU_PALLAS_VERSION picks the kernel generation
-        # — v2 by chip measurement).  'quarter' keeps the canonical
-        # int8-codec path.
+        # pallas-dslash-in-solver routing for Wilson PC (QUDA_TPU_PALLAS
+        # gates it on/off).  'quarter' keeps the canonical int8-codec
+        # path.
         wil_pairs = (pairs_ok and param.dslash_type == "wilson"
                      and _pallas_enabled(on_tpu)
                      and sloppy_prec != "quarter")

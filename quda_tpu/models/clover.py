@@ -117,16 +117,13 @@ class DiracCloverPC(DiracPC):
 
     def pairs(self, store_dtype=jnp.float32, use_pallas: bool = False,
               pallas_interpret: bool = False,
-              pallas_version: int | None = None,
               form: str | None = None) -> "DiracCloverPCPairs":
         """Complex-free packed companion (f32 = the precise TPU solve
         path; bf16 = the sloppy clover operator of mixed solves).
         ``form`` / QUDA_TPU_CLOVER_FORM picks fused-pallas vs staged-XLA
-        (models/formsel); the legacy ``pallas_version`` kwarg maps
-        through it (v!=2 has no fused form)."""
+        (models/formsel)."""
         return DiracCloverPCPairs(self, store_dtype, use_pallas,
                                   pallas_interpret,
-                                  pallas_version=pallas_version,
                                   form=form)
 
 
@@ -187,7 +184,6 @@ class DiracCloverPCPairs(_ProgramOperand, _SchurPairOpBase):
 
     def __init__(self, dpc: "DiracCloverPC", store_dtype=jnp.float32,
                  use_pallas: bool = False, pallas_interpret: bool = False,
-                 pallas_version: int | None = None,
                  form: str | None = None):
         from ..ops import wilson_packed as wpk
         self._assemble(
@@ -196,7 +192,7 @@ class DiracCloverPCPairs(_ProgramOperand, _SchurPairOpBase):
                                          store_dtype),
             pack_clover_pairs(dpc.clover_inv_q, store_dtype),
             store_dtype, use_pallas, pallas_interpret,
-            getattr(dpc, 'antiperiodic_t', True), pallas_version, form)
+            getattr(dpc, 'antiperiodic_t', True), form)
         from ..obs import memory as omem
         omem.track("clover", "clover_pair_blocks",
                    (self.clover_p_pp, self.clover_inv_q_pp))
@@ -206,7 +202,6 @@ class DiracCloverPCPairs(_ProgramOperand, _SchurPairOpBase):
                     clover_inv_q, store_dtype=jnp.float32,
                     use_pallas: bool = False,
                     pallas_interpret: bool = False, tb_sign: bool = True,
-                    pallas_version: int | None = None,
                     form: str | None = None) -> "DiracCloverPCPairs":
         """From what a resident clover term holds: the boundary-folded
         packed links (wilson_packed.pack_gauge_eo) and the packed
@@ -219,15 +214,14 @@ class DiracCloverPCPairs(_ProgramOperand, _SchurPairOpBase):
                      wpk.to_packed_pairs(clover_p, store_dtype),
                      wpk.to_packed_pairs(clover_inv_q, store_dtype),
                      store_dtype, use_pallas, pallas_interpret, tb_sign,
-                     pallas_version, form)
+                     form)
         return op
 
     def _assemble(self, geom, gauge_eo_packed, kappa, matpc, clover_p_pp,
                   clover_inv_q_pp, store_dtype, use_pallas,
-                  pallas_interpret, tb_sign, pallas_version, form):
+                  pallas_interpret, tb_sign, form):
         self._setup_hop(geom, gauge_eo_packed, store_dtype, use_pallas,
-                        pallas_interpret, pallas_version=pallas_version,
-                        tb_sign=tb_sign)
+                        pallas_interpret, tb_sign=tb_sign)
         self.kappa = float(kappa)
         self.matpc = matpc
         self.clover_p_pp = clover_p_pp

@@ -154,7 +154,6 @@ class DiracMobiusPC(DiracPC):
 
     def pairs(self, store_dtype=jnp.float32, use_pallas: bool = False,
               pallas_interpret: bool = False,
-              pallas_version: int | None = None,
               form: str | None = None) -> "DiracMobiusPCPairs":
         """Complex-free packed companion (f32 = the precise TPU solve
         path; bf16 = the sloppy operator) — also serves the EOFA
@@ -163,7 +162,6 @@ class DiracMobiusPC(DiracPC):
         vmap-over-s stencil (models/formsel)."""
         return DiracMobiusPCPairs(self, store_dtype, use_pallas,
                                   pallas_interpret,
-                                  pallas_version=pallas_version,
                                   form=form)
 
 
@@ -201,7 +199,7 @@ class DiracMobiusPCPairs(_LsPairIOMixin, _PackedHopMixin):
     links (4, 3, 3, 2, T, Z, Y*Xh); compute f32.
 
     The 4-d hop is the packed eo Wilson stencil vmapped over the Ls axis
-    (optionally the pallas v3 kernel — jax.vmap turns its grid into
+    (optionally the pallas kernel — jax.vmap turns its grid into
     (Ls, T, Z/bz)); the s-operators are the REAL dense (Ls, Ls)
     chirality blocks of ops/dwf.py applied as f32 einsums (MXU), so no
     complex arithmetic remains anywhere.
@@ -215,13 +213,11 @@ class DiracMobiusPCPairs(_LsPairIOMixin, _PackedHopMixin):
 
     def __init__(self, dpc: DiracMobiusPC, store_dtype=jnp.float32,
                  use_pallas: bool = False, pallas_interpret: bool = False,
-                 pallas_version: int | None = None,
                  form: str | None = None):
         import numpy as np
         from ..ops import wilson_packed as wpk
         self._setup_hop(dpc.geom, wpk.pack_gauge_eo(dpc.gauge_eo),
                         store_dtype, use_pallas, pallas_interpret,
-                        pallas_version=pallas_version,
                         tb_sign=getattr(dpc, 'antiperiodic_t',
                                         True))
         self.ls = dpc.ls
@@ -580,7 +576,6 @@ class DiracDomainWall5DPC(DiracPC):
 
     def pairs(self, store_dtype=jnp.float32, use_pallas: bool = False,
               pallas_interpret: bool = False,
-              pallas_version: int | None = None,
               form: str | None = None
               ) -> "DiracDomainWall5DPCPairs":
         """Complex-free packed companion (the TPU solve path).
@@ -588,7 +583,6 @@ class DiracDomainWall5DPC(DiracPC):
         kernel vs the vmap-over-s stencil (models/formsel)."""
         return DiracDomainWall5DPCPairs(self, store_dtype, use_pallas,
                                         pallas_interpret,
-                                        pallas_version=pallas_version,
                                         form=form)
 
 
@@ -608,12 +602,10 @@ class DiracDomainWall5DPCPairs(_LsPairIOMixin, _PackedHopMixin):
 
     def __init__(self, dpc: DiracDomainWall5DPC, store_dtype=jnp.float32,
                  use_pallas: bool = False, pallas_interpret: bool = False,
-                 pallas_version: int | None = None,
                  form: str | None = None):
         from ..ops import wilson_packed as wpk
         self._setup_hop(dpc.geom, wpk.pack_gauge_eo(dpc.gauge_eo),
                         store_dtype, use_pallas, pallas_interpret,
-                        pallas_version=pallas_version,
                         tb_sign=getattr(dpc, 'antiperiodic_t',
                                         True))
         self.ls = dpc.ls

@@ -64,13 +64,11 @@ def _reset_notices():
 def fused_capable(op) -> Optional[str]:
     """None when ``op`` (a _PackedHopMixin pair operator) can host the
     fused epilogue kernels; otherwise the reason it cannot.  The fused
-    forms are built on the v2 full-tile gather kernel: scatter (v3),
-    folded/r12f/int8 precision storage, multi-chip meshes, and plain
-    XLA stencils all keep the staged composition."""
+    forms are built on the full-tile gather kernel: folded/r12f/int8
+    precision storage, multi-chip meshes, and plain XLA stencils all
+    keep the staged composition."""
     if not getattr(op, "use_pallas", False):
         return "use_pallas=False (XLA stencil path)"
-    if getattr(op, "_pallas_version", 2) != 2:
-        return f"pallas v{getattr(op, '_pallas_version', 2)} (fused forms are v2-only)"
     if getattr(op, "_mesh", None) is not None:
         return "multi-chip mesh (sharded hop keeps staged diagonal)"
     pf = getattr(op, "_precision_form", None)

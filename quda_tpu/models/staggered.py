@@ -129,7 +129,6 @@ class DiracStaggeredPC(DiracPC):
 
     def pairs(self, store_dtype=jnp.float32, use_pallas: bool = False,
               pallas_interpret: bool = False,
-              pallas_version: int | None = None,
               form: str | None = None, mesh=None,
               sharded_policy: str | None = None,
               precision_form: str | None = None
@@ -137,7 +136,7 @@ class DiracStaggeredPC(DiracPC):
         """Complex-free packed companion (f32 = the precise TPU solve
         path; bf16 = the sloppy operator); see DiracStaggeredPCPairs."""
         return DiracStaggeredPCPairs(self, store_dtype, use_pallas,
-                                     pallas_interpret, pallas_version,
+                                     pallas_interpret,
                                      form=form, mesh=mesh,
                                      sharded_policy=sharded_policy,
                                      precision_form=precision_form)
@@ -189,14 +188,14 @@ class DiracStaggeredPCPairs:
     * ``fused``    — single-pass fat+Naik (one launch, one psi read, no
                      XLA sum pass; ~864 vs 1512 B/site) — improved only;
     * ``two_pass`` — separate fat/long gather launches with resident
-                     pre-shifted backward links (the pre-round-10 form,
-                     = the old pallas_version=2);
-    * ``v3``       — two-pass scatter form (= pallas_version=3);
+                     pre-shifted backward links (the pre-round-10
+                     form);
+    * ``v3``       — two-pass scatter form;
     * ``auto``     — race the applicable forms via utils.tune at
                      construction and cache the winner — A/B'd, not
-                     assumed (the scatter form LOST for Wilson on chip,
-                     PERF.md round 5, so no staggered form is presumed
-                     either).  Off-chip (interpret mode) the race would
+                     assumed (the staggered forms have no chip reading
+                     yet; PERF.md section 6, PR 30, has the Wilson
+                     one).  Off-chip (interpret mode) the race would
                      time the interpreter, not the hardware, so auto
                      resolves statically to the projected winner (fused
                      for improved, two_pass for fat-only) with a notice.
@@ -219,7 +218,6 @@ class DiracStaggeredPCPairs:
 
     def __init__(self, dpc: DiracStaggeredPC, store_dtype=jnp.float32,
                  use_pallas: bool = False, pallas_interpret: bool = False,
-                 pallas_version: int | None = None,
                  form: str | None = None, mesh=None,
                  sharded_policy: str | None = None,
                  precision_form: str | None = None):
@@ -249,25 +247,10 @@ class DiracStaggeredPCPairs:
         self._fat_bw = self._long_bw = None
         improved = self.long_eo_pp is not None
 
-        # -- kernel-form resolution (explicit kwarg > legacy
-        # pallas_version kwarg > QUDA_TPU_STAGGERED_FORM knob, whose
-        # empty value falls back to QUDA_TPU_PALLAS_VERSION) ----------
+        # -- kernel-form resolution (explicit kwarg >
+        # QUDA_TPU_STAGGERED_FORM knob) -------------------------------
         if form is None:
-            if pallas_version is not None:
-                if pallas_version not in (2, 3):
-                    raise ValueError(f"pallas_version must be 2 or 3, "
-                                     f"got {pallas_version}")
-                form = "two_pass" if pallas_version == 2 else "v3"
-            else:
-                form = str(qconf.get("QUDA_TPU_STAGGERED_FORM",
-                                     fresh=True))
-                if not form:
-                    pv = qconf.get("QUDA_TPU_PALLAS_VERSION", fresh=True)
-                    if pv not in (2, 3):
-                        raise ValueError(
-                            f"QUDA_TPU_PALLAS_VERSION must be 2 or 3, "
-                            f"got {pv}")
-                    form = "two_pass" if pv == 2 else "v3"
+            form = str(qconf.get("QUDA_TPU_STAGGERED_FORM", fresh=True))
         if form not in STAGGERED_FORMS + ("auto",):
             raise ValueError(f"staggered form must be one of "
                              f"{STAGGERED_FORMS + ('auto',)}, got "
@@ -342,9 +325,6 @@ class DiracStaggeredPCPairs:
             # XLA stencil path: the form knob has no kernel to pick
             form = "two_pass"
         self._pallas_form = form
-        # legacy attribute (callers/benches keyed on the wilson-style
-        # generation number): gather forms report 2, scatter 3
-        self._pallas_version = 3 if form == "v3" else 2
 
         # -- precision storage form (PERF.md round 16), fused kernel
         # only: 'r12' compresses the NAIK hop set (long links are

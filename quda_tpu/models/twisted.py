@@ -123,7 +123,6 @@ class DiracTwistedMassPC(DiracPC):
 
     def pairs(self, store_dtype=jnp.float32, use_pallas: bool = False,
               pallas_interpret: bool = False,
-              pallas_version: int | None = None,
               form: str | None = None) -> "DiracTwistedMassPCPairs":
         """Complex-free packed companion (f32 = the precise TPU solve
         path; bf16 = the sloppy operator).  ``form`` /
@@ -131,7 +130,6 @@ class DiracTwistedMassPC(DiracPC):
         the staged XLA composition (models/formsel)."""
         return DiracTwistedMassPCPairs(self, store_dtype, use_pallas,
                                        pallas_interpret,
-                                       pallas_version=pallas_version,
                                        form=form)
 
 
@@ -168,12 +166,10 @@ class DiracTwistedMassPCPairs(_SchurPairOpBase):
 
     def __init__(self, dpc: "DiracTwistedMassPC", store_dtype=jnp.float32,
                  use_pallas: bool = False, pallas_interpret: bool = False,
-                 pallas_version: int | None = None,
                  form: str | None = None):
         from ..ops import wilson_packed as wpk
         self._setup_hop(dpc.geom, wpk.pack_gauge_eo(dpc.gauge_eo),
                         store_dtype, use_pallas, pallas_interpret,
-                        pallas_version=pallas_version,
                         tb_sign=getattr(dpc, 'antiperiodic_t',
                                         True))
         self.kappa = float(dpc.kappa)
@@ -211,13 +207,11 @@ class DiracTwistedCloverPCPairs(_SchurPairOpBase):
     def __init__(self, dpc: "DiracTwistedCloverPC",
                  store_dtype=jnp.float32, use_pallas: bool = False,
                  pallas_interpret: bool = False,
-                 pallas_version: int | None = None,
                  form: str | None = None):
         from ..ops import wilson_packed as wpk
         from .clover import pack_clover_pairs
         self._setup_hop(dpc.geom, wpk.pack_gauge_eo(dpc.gauge_eo),
                         store_dtype, use_pallas, pallas_interpret,
-                        pallas_version=pallas_version,
                         tb_sign=getattr(dpc, 'antiperiodic_t',
                                         True))
         self.kappa = float(dpc.kappa)
@@ -540,7 +534,6 @@ class DiracTwistedCloverPC(DiracPC):
 
     def pairs(self, store_dtype=jnp.float32, use_pallas: bool = False,
               pallas_interpret: bool = False,
-              pallas_version: int | None = None,
               form: str | None = None) -> "DiracTwistedCloverPCPairs":
         """Complex-free packed companion (f32 = the precise TPU solve
         path; bf16 = the sloppy operator).  ``form`` /
@@ -548,7 +541,6 @@ class DiracTwistedCloverPC(DiracPC):
         kernel vs the staged XLA composition (models/formsel)."""
         return DiracTwistedCloverPCPairs(self, store_dtype, use_pallas,
                                          pallas_interpret,
-                                         pallas_version=pallas_version,
                                          form=form)
 
 

@@ -219,16 +219,13 @@ class _PairSloppyBase:
 _SHARDED_NOTICED = False
 
 
-def _notice_sharded_policy(version: int, policy: str, src: str,
+def _notice_sharded_policy(policy: str, src: str,
                            ici_bytes: int | None = None):
     """One-time provenance notice naming the mesh dslash configuration
-    actually selected (kernel form + halo policy + how it was chosen:
-    pinned, raced, or served from the chip-keyed tunecache warm cache)
-    — a policy must never take effect without a trace (utils/config.py
-    fail-fast model; successor of the retired _notice_mesh_forces_v3,
-    which existed because the sharded path could only run the v3
-    scatter form — round 8 ported the measured-best v2 form, so the
-    override it reported is gone)."""
+    actually selected (halo policy + how it was chosen: pinned, raced,
+    or served from the chip-keyed tunecache warm cache) — a policy must
+    never take effect without a trace (utils/config.py fail-fast
+    model)."""
     global _SHARDED_NOTICED
     if _SHARDED_NOTICED:
         return
@@ -239,9 +236,9 @@ def _notice_sharded_policy(version: int, policy: str, src: str,
     comms = ("" if not ici_bytes
              else f"; ICI {ici_bytes / 1024:.1f} KB/device per dslash")
     qlog.printq(
-        f"mesh dslash: pallas v{version} eo interior, halo policy "
-        f"{policy} ({src}){comms}; pin via QUDA_TPU_PALLAS_VERSION / "
-        "QUDA_TPU_SHARDED_POLICY", qlog.SUMMARIZE)
+        f"mesh dslash: pallas eo interior, halo policy "
+        f"{policy} ({src}){comms}; pin via QUDA_TPU_SHARDED_POLICY",
+        qlog.SUMMARIZE)
 
 
 _PRECISION_NOTICED: set = set()
@@ -263,25 +260,24 @@ def _notice_precision_form(requested: str, served: str, why: str):
 
 def hop_route_knobs() -> tuple:
     """What ``_PackedHopMixin._setup_hop`` resolves from the environment
-    when its caller pins nothing: (pallas version, precision form,
-    legacy reconstruct-12).  An operator kept across calls is keyed by
-    it, so that a flipped knob gives a new operator, never a stale one."""
+    when its caller pins nothing: (precision form, legacy
+    reconstruct-12).  An operator kept across calls is keyed by it, so
+    that a flipped knob gives a new operator, never a stale one."""
     from ..utils import config as qconf
-    return (qconf.get("QUDA_TPU_PALLAS_VERSION", fresh=True),
-            str(qconf.get("QUDA_TPU_PRECISION_FORM", fresh=True)),
+    return (str(qconf.get("QUDA_TPU_PRECISION_FORM", fresh=True)),
             str(qconf.get("QUDA_TPU_RECONSTRUCT", fresh=True)) == "12")
 
 
 class _PackedHopMixin:
     """The packed eo Wilson hop on pair arrays, shared by every
     packed-layout pair operator (Wilson, clover, twisted, Möbius hops):
-    gauge setup, the pallas-version-aware stencil dispatch, and the
+    gauge setup, the stencil dispatch by precision form, and the
     canonical<->packed spinor converters live ONCE here."""
 
     _spin_axis = 0
 
     def _setup_hop(self, geom, gauge_eo_packed, store_dtype,
-                   use_pallas, pallas_interpret, pallas_version=None,
+                   use_pallas, pallas_interpret,
                    tb_sign: bool = True, mesh=None,
                    sharded_policy: str | None = None,
                    precision_form: str | None = None):
@@ -316,35 +312,12 @@ class _PackedHopMixin:
         self._pallas_interpret = pallas_interpret
         self._tb_sign = tb_sign
         from ..utils import config as qconf
-        env_version, env_form, legacy_r12 = hop_route_knobs()
+        env_form, legacy_r12 = hop_route_knobs()
         if mesh is not None and getattr(mesh, "size", 2) == 1:
             # single-chip escape: a 1-device mesh shards nothing — drop
             # it and resolve the kernel form exactly like the unsharded
             # path (no exterior fix passes on a trivial mesh)
             mesh = None
-        if pallas_version is None:
-            # mesh and single-chip resolve the SAME way now that the
-            # sharded eo policy exists in both kernel forms: the
-            # measured-best v2 default (PERF.md round 5) finally serves
-            # multi-chip too, and env/kwarg can still pin v3
-            pallas_version = env_version
-        if pallas_version not in (2, 3):
-            raise ValueError(f"pallas_version must be 2 or 3, got "
-                             f"{pallas_version}")
-        if mesh is not None and pallas_version == 3:
-            ms = dict(mesh.shape)
-            if int(ms.get("y", 1)) > 1 or int(ms.get("x", 1)) > 1:
-                # the v3 scatter exterior shards t/z only; a y/x-
-                # partitioned mesh clamps to the v2 gather form (the
-                # measured-best default anyway, PERF.md round 5)
-                from ..utils import logging as qlog
-                qlog.printq(
-                    "mesh dslash: pallas v3 exterior shards t/z only "
-                    "— y/x-partitioned mesh clamps to the v2 gather "
-                    "form (pin QUDA_TPU_PALLAS_VERSION=2 to silence)",
-                    qlog.SUMMARIZE)
-                pallas_version = 2
-        self._pallas_version = pallas_version
         # -- precision storage form (PERF.md round 16) ------------------
         # explicit kwarg > QUDA_TPU_PRECISION_FORM > legacy resolution
         # (QUDA_TPU_RECONSTRUCT=12 -> r12, else full); 'auto' races the
@@ -361,10 +334,10 @@ class _PackedHopMixin:
         if use_pallas:
             from ..ops import wilson_pallas_packed as wpp
             # in-kernel gauge compression (QUDA reconstruct-12 analog),
-            # both kernel generations and the sharded path: resident
-            # link arrays shrink 288 -> 192 B/site.  r12f shares the
-            # R=2 storage; its scatter backward reads the unshifted
-            # opposite-parity links, so no backward copy exists.
+            # single chip and sharded: resident link arrays shrink
+            # 288 -> 192 B/site.  r12f shares the R=2 storage; its
+            # scatter backward reads the unshifted opposite-parity
+            # links, so no backward copy exists.
             if form in ("r12", "r12f"):
                 self.gauge_eo_pp = tuple(wpp.to_recon12(g)
                                          for g in self.gauge_eo_pp)
@@ -396,14 +369,14 @@ class _PackedHopMixin:
                 qbf.from_int8_links(*qbf.to_int8_links(
                     g.astype(jnp.float32)))
                 for g in self.gauge_eo_pp)
-        # v2-family gather forms: resident pre-shifted backward links
-        # (the v3/r12f scatter kernels read the unshifted opposite-
-        # parity links directly — no resident copy).  Computed on the
-        # GLOBAL arrays: under a mesh the shifts then already carry the
-        # cross-shard links, so the sharded exterior exchanges only psi
-        # slabs (parallel/pallas_dslash.dslash_eo_pallas_sharded).
-        if use_pallas and form not in ("r12f", "int8") and (
-                pallas_version == 2 or form in ("fold", "bzfull")):
+        # gather forms: resident pre-shifted backward links (the r12f
+        # scatter kernel reads the unshifted opposite-parity links
+        # directly, the int8 kernel its own mantissa and scale planes —
+        # no resident copy).  Computed on the GLOBAL arrays: under a
+        # mesh the shifts then already carry the cross-shard links, so
+        # the sharded exterior exchanges only psi slabs
+        # (parallel/pallas_dslash.dslash_eo_pallas_sharded).
+        if use_pallas and form not in ("r12f", "int8"):
             from ..ops import wilson_pallas_packed as wpp
             self._u_bw = tuple(
                 wpp.backward_gauge_eo(self.gauge_eo_pp[1 - p],
@@ -474,8 +447,7 @@ class _PackedHopMixin:
                 self._sharded_policy = pols
                 live = [a for a, n in zip(("t", "z", "y", "x"),
                                           _mesh_counts(mesh)) if n > 1]
-                _notice_sharded_policy(self._pallas_version,
-                                       _policy_label(pols, live),
+                _notice_sharded_policy(_policy_label(pols, live),
                                        "pinned",
                                        ici_bytes=self._ici_model_bytes())
 
@@ -554,8 +526,7 @@ class _PackedHopMixin:
         except ValueError:
             pass  # full-Z block busts even the scoped window: not a form
         psi0 = jnp.zeros((4, 3, 2, T, Z, YXh), store_dtype)
-        aux = (f"v{self._pallas_version}|"
-               f"{jnp.dtype(store_dtype).name}")
+        aux = jnp.dtype(store_dtype).name
         warm = qtune.cached_param("wilson_eo_precision_form", dims,
                                   aux=aux)
         won = qtune.tune("wilson_eo_precision_form", dims, cands,
@@ -572,11 +543,8 @@ class _PackedHopMixin:
             from ..ops import wilson_pallas_packed as wpp
             if getattr(self, "_mesh", None) is not None:
                 fn = self._sharded_d_to(target_parity, out_dtype)
-                if self._pallas_version == 2:
-                    return fn(self.gauge_eo_pp[target_parity],
-                              self._u_bw[target_parity], psi_pp)
                 return fn(self.gauge_eo_pp[target_parity],
-                          self.gauge_eo_pp[1 - target_parity], psi_pp)
+                          self._u_bw[target_parity], psi_pp)
             form = getattr(self, "_precision_form", None)
             if form == "r12f":
                 return wpp.dslash_eo_pallas_packed_r12f(
@@ -602,13 +570,6 @@ class _PackedHopMixin:
                     tuple(self.dims), target_parity,
                     interpret=self._pallas_interpret,
                     out_dtype=out_dtype)
-            if self._pallas_version == 3:
-                return wpp.dslash_eo_pallas_packed_v3(
-                    self.gauge_eo_pp[target_parity],
-                    self.gauge_eo_pp[1 - target_parity], psi_pp,
-                    tuple(self.dims), target_parity,
-                    interpret=self._pallas_interpret,
-                    out_dtype=out_dtype, tb_sign=self._tb_sign)
             return wpp.dslash_eo_pallas_packed(
                 self.gauge_eo_pp[target_parity],
                 self._u_bw[target_parity], psi_pp, tuple(self.dims),
@@ -620,10 +581,10 @@ class _PackedHopMixin:
                                           out_dtype=out_dtype)
 
     def _d_to_mrhs(self, psi_b, target_parity, out_dtype):
-        """Batched packed eo hop: psi_b (N,4,3,2,T,Z,Y*Xh).  The v2
+        """Batched packed eo hop: psi_b (N,4,3,2,T,Z,Y*Xh).  The
         pallas path routes the MRHS kernel (one gauge-tile fetch per
         (t, z-block), N spinor tiles streamed through it); r12f and
-        fold route their own MRHS kernels; everything else (int8, v3,
+        fold route their own MRHS kernels; everything else (int8,
         mesh, XLA) falls back to the vmapped single-RHS stencil."""
         if self.use_pallas and getattr(self, "_mesh", None) is None:
             from ..ops import wilson_pallas_packed as wpp
@@ -643,7 +604,7 @@ class _PackedHopMixin:
                     interpret=self._pallas_interpret,
                     out_dtype=out_dtype, tb_sign=self._tb_sign)
                 return wpp.from_fold(out)
-            if form != "int8" and self._pallas_version == 2:
+            if form != "int8":
                 return wpp.dslash_eo_pallas_packed_mrhs(
                     self.gauge_eo_pp[target_parity],
                     self._u_bw[target_parity], psi_b, tuple(self.dims),
@@ -673,24 +634,16 @@ class _PackedHopMixin:
         spec string, or {axis: policy} dict)."""
         from jax.sharding import PartitionSpec as P
 
-        from ..parallel.pallas_dslash import (dslash_eo_pallas_sharded,
-                                              dslash_eo_pallas_sharded_v3)
+        from ..parallel.pallas_dslash import dslash_eo_pallas_sharded
         pspec = P(None, None, None, "t", "z", ("y", "x"))
         gspec = P(None, None, None, None, "t", "z", ("y", "x"))
-        if self._pallas_version == 2:
-            def local(uh, ub, p):
-                return dslash_eo_pallas_sharded(
-                    uh, ub, p, tuple(self.dims), target_parity,
-                    self._mesh, interpret=self._pallas_interpret,
-                    out_dtype=out_dtype, tb_sign=self._tb_sign,
-                    policy=policy)
-        else:
-            def local(uh, ut, p):
-                return dslash_eo_pallas_sharded_v3(
-                    uh, ut, p, tuple(self.dims), target_parity,
-                    self._mesh, interpret=self._pallas_interpret,
-                    out_dtype=out_dtype, tb_sign=self._tb_sign,
-                    policy=policy)
+
+        def local(uh, ub, p):
+            return dslash_eo_pallas_sharded(
+                uh, ub, p, tuple(self.dims), target_parity,
+                self._mesh, interpret=self._pallas_interpret,
+                out_dtype=out_dtype, tb_sign=self._tb_sign,
+                policy=policy)
         return jax.jit(jax.shard_map(
             local, mesh=self._mesh, in_specs=(gspec, gspec, pspec),
             out_specs=pspec, check_vma=False))
@@ -726,8 +679,7 @@ class _PackedHopMixin:
         # a tracer — the links are resident concrete arrays already)
         from jax.sharding import NamedSharding, PartitionSpec as P
         uh = self.gauge_eo_pp[target_parity]
-        ub = (self._u_bw[target_parity] if self._pallas_version == 2
-              else self.gauge_eo_pp[1 - target_parity])
+        ub = self._u_bw[target_parity]
         T, Z, _, _ = self.dims
         psi0 = jax.device_put(
             jnp.zeros((4, 3, 2, T, Z, uh.shape[-1]), self.store_dtype),
@@ -735,8 +687,7 @@ class _PackedHopMixin:
                           P(None, None, None, "t", "z", ("y", "x"))))
         mesh_shape = tuple(int(self._mesh.shape[a])
                            for a in self._mesh.axis_names)
-        aux = (f"v{self._pallas_version}|mesh{mesh_shape}|"
-               f"{jnp.dtype(self.store_dtype).name}")
+        aux = f"mesh{mesh_shape}|{jnp.dtype(self.store_dtype).name}"
         pols = {a: "xla_facefix" for a in AXIS_NAMES}
         # warm-cache provenance: winners already raced on THIS chip
         # (tune_key carries the platform component) for EVERY live axis
@@ -771,7 +722,7 @@ class _PackedHopMixin:
                                             dict(pols))
         self.__dict__.setdefault("_sharded_fns", {})[key] = seeded
         _notice_sharded_policy(
-            self._pallas_version, _policy_label(pols, live),
+            _policy_label(pols, live),
             "warm cache (chip-keyed tunecache)" if warm
             else "raced+cached (QUDA_TPU_SHARDED_POLICY=auto)",
             ici_bytes=self._ici_model_bytes())
@@ -839,8 +790,8 @@ class _ProgramOperand:
                               "_gauge_s", "kappa")
     _PROGRAM_STATIC: tuple = ("geom", "dims", "matpc", "store_dtype",
                               "use_pallas", "_pallas_interpret",
-                              "_tb_sign", "_pallas_version",
-                              "_precision_form", "_block_z")
+                              "_tb_sign", "_precision_form",
+                              "_block_z")
 
     @property
     def program_signature(self):
@@ -1125,7 +1076,6 @@ class DiracWilsonPCPacked:
 
     def pairs(self, store_dtype=jnp.bfloat16, use_pallas: bool = False,
               pallas_interpret: bool = False,
-              pallas_version: int | None = None,
               mesh=None,
               sharded_policy: str | None = None,
               precision_form: str | None = None
@@ -1137,19 +1087,16 @@ class DiracWilsonPCPacked:
         and the native-order analog of QUDA keeping solver fields in
         float2/float4 orders (no complex type on the device either).
         ``use_pallas`` swaps the stencil for the hand-tuned pallas eo
-        kernel; ``pallas_version`` 2 (the measured single-chip winner,
-        PERF.md round 5 — the env default) uses the gather kernel with
-        resident pre-shifted backward links, 3 the scatter-form kernel
-        that needs none.  ``mesh``: a jax.sharding.Mesh with t/z axes
-        partitioning the lattice T/Z — the stencil then runs the
-        sharded eo pallas policy under shard_map in the SAME kernel
-        form (multi-chip CG hot loop, lib/dslash_policy.hpp:522
+        kernel (the gather kernel with resident pre-shifted backward
+        links).  ``mesh``: a jax.sharding.Mesh with t/z/y/x axes
+        partitioning the lattice — the stencil then runs the sharded
+        eo pallas policy under shard_map on the same kernel
+        (multi-chip CG hot loop, lib/dslash_policy.hpp:522
         analog), with ``sharded_policy`` (or QUDA_TPU_SHARDED_POLICY)
         selecting the halo transport: xla_facefix, fused_halo, or auto
         (raced via utils.tune)."""
         return DiracWilsonPCPackedSloppy(self, store_dtype, use_pallas,
-                                         pallas_interpret, pallas_version,
-                                         mesh=mesh,
+                                         pallas_interpret, mesh=mesh,
                                          sharded_policy=sharded_policy,
                                          precision_form=precision_form)
 
@@ -1172,11 +1119,10 @@ class DiracWilsonPCPackedSloppy(_ProgramOperand, _PackedHopMixin,
 
     def __init__(self, dpk: "DiracWilsonPCPacked", store_dtype=jnp.bfloat16,
                  use_pallas: bool = False, pallas_interpret: bool = False,
-                 pallas_version: int | None = None, mesh=None,
-                 sharded_policy: str | None = None,
+                 mesh=None, sharded_policy: str | None = None,
                  precision_form: str | None = None):
         self._setup_hop(dpk.geom, dpk.gauge_eo_p, store_dtype,
-                        use_pallas, pallas_interpret, pallas_version,
+                        use_pallas, pallas_interpret,
                         tb_sign=getattr(dpk._dpc, "antiperiodic_t", True),
                         mesh=mesh, sharded_policy=sharded_policy,
                         precision_form=precision_form)

@@ -429,10 +429,9 @@ def wilson_eo_halo_model(dims, mesh_shape, itemsize: int = 4) -> dict:
     from first principles — the number the ledger must reproduce from
     the seams, and what the QUDA_TPU_SHARDED_POLICY race notice quotes
     next to its timing winner.  ``dims`` = global (T, Z, Y, X),
-    ``mesh_shape`` = (n_t, n_z) or the full (n_t, n_z, n_y, n_x).  Both
-    v2 and v3 exchange exactly two psi-shaped faces per partitioned
-    direction (one ``exchange`` call), so the model is form-independent:
-    2 x face bytes per axis.  t/z faces are whole planes, the y face is
+    ``mesh_shape`` = (n_t, n_z) or the full (n_t, n_z, n_y, n_x).  The
+    policy exchanges exactly two psi-shaped faces per partitioned
+    direction (one ``exchange`` call): 2 x face bytes per axis.  t/z faces are whole planes, the y face is
     one local row strip, and the x face is one local COLUMN stack of xh
     slots (the eo slot-select reaches one column, w=1) — strided, which
     is why x is the cheapest axis per device but ppermute-only."""

@@ -217,11 +217,6 @@ _FOOTPRINTS: Dict[str, dict] = {
                   "floor": lambda n: 2 * _G + 2 * _PSI},
     "wilson_v2_r12": {"family": "wilson",
                       "floor": lambda n: 2 * _G12 + 2 * _PSI},
-    # v3 scatter: one link array, no backward copy
-    "wilson_v3": {"family": "wilson",
-                  "floor": lambda n: _G + 2 * _PSI},
-    "wilson_v3_r12": {"family": "wilson",
-                      "floor": lambda n: _G12 + 2 * _PSI},
     "wilson_mrhs": {"family": "wilson",
                     "floor": lambda n: 2 * _G / n + 2 * _PSI},
     # precision storage forms (PERF.md round 16).  Floors are the
@@ -244,8 +239,6 @@ _FOOTPRINTS: Dict[str, dict] = {
                        + 2 * _PSI},
     "wilson_sharded_v2": {"alias": "wilson_v2"},
     "wilson_sharded_v2_r12": {"alias": "wilson_v2_r12"},
-    "wilson_sharded_v3": {"alias": "wilson_v3"},
-    "wilson_sharded_v3_r12": {"alias": "wilson_v3_r12"},
     "staggered_fat": {"family": "staggered_fat",
                       "floor": lambda n: 2 * _G + 2 * _SPSI},
     "staggered_fat_naik": {"family": "staggered_fat_naik",

@@ -50,8 +50,8 @@ def device_peaks(device_kind: Optional[str] = None) -> Optional[dict]:
 
 # Per-site flops / bytes models (f32 pairs, per UPDATED site, one
 # operator application).  Sources: PERF.md round 2 (v2 traffic table),
-# round 3 (v3 scatter table), round 4 (reconstruct-12), round 7 (MRHS
-# 576 + 576/N), round 8 (staggered fat+Naik 1512 B).  ``bytes_per_site``
+# round 4 (reconstruct-12), round 7 (MRHS 576 + 576/N), round 8
+# (staggered fat+Naik 1512 B).  ``bytes_per_site``
 # None = no credible traffic model for the form (no BW attribution).
 KERNEL_MODELS: Dict[str, dict] = {
     # gather-form v2: psi 5x96 + out 96 + gauge 288 fwd + 288 bw copy
@@ -60,10 +60,6 @@ KERNEL_MODELS: Dict[str, dict] = {
     # and the pre-shifted backward copy, built from the compressed
     # arrays) shrink 288 -> 192 B/site, so 1152 - 2*96
     "wilson_v2_r12": {"flops_per_site": 1320, "bytes_per_site": 960},
-    # scatter-form v3: psi ~312 + gauge 288 + U_t plane ~81 + out 96
-    "wilson_v3": {"flops_per_site": 1320, "bytes_per_site": 777},
-    # v3 + in-kernel reconstruct-12 link decompression
-    "wilson_v3_r12": {"flops_per_site": 1320, "bytes_per_site": 684},
     # MRHS v2: psi 480 + out 96 + gauge 576/N per RHS (nrhs-dependent)
     "wilson_mrhs": {"flops_per_site": 1320,
                     "bytes_per_site": lambda nrhs: 576.0 + 576.0 / nrhs},
@@ -72,7 +68,7 @@ KERNEL_MODELS: Dict[str, dict] = {
     # are g_here 192 + g_there xyz 144 + g_t plane 48 = 384 — exactly
     # the r12 forward+backward-copy 2x192, so traffic EQUALS wilson_v2
     # _r12; the win is residency (no 192 B/site backward array), not
-    # bandwidth.  684 B/site remains wilson_v3_r12's number.
+    # bandwidth.
     "wilson_v2_r12f": {"flops_per_site": 1320, "bytes_per_site": 960},
     # fold: re/im interleaved into sublane rows — same logical bytes as
     # v2 at f32 (the fold changes tile SHAPE, not byte count)...
@@ -100,9 +96,6 @@ KERNEL_MODELS: Dict[str, dict] = {
     "wilson_sharded_v2": {"flops_per_site": 1320, "bytes_per_site": 1152},
     "wilson_sharded_v2_r12": {"flops_per_site": 1320,
                               "bytes_per_site": 960},
-    "wilson_sharded_v3": {"flops_per_site": 1320, "bytes_per_site": 777},
-    "wilson_sharded_v3_r12": {"flops_per_site": 1320,
-                              "bytes_per_site": 684},
     # XLA pair stencil: flop model only (XLA's fusion choices make a
     # static traffic model dishonest)
     "wilson_xla": {"flops_per_site": 1320, "bytes_per_site": None},

@@ -27,8 +27,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from . import df64 as dfm
-from .wilson_pallas import TABLES
-from .wilson_packed import shift_eo_packed
+from .wilson_packed import TABLES, shift_eo_packed
 
 
 # -- complex df64 helpers ----------------------------------------------------

@@ -26,10 +26,12 @@ Shifts on the packed layout:
          roll by (1-X) and a lane mask select fix the wrap (branch-free,
          same trick as ops/shift.py's checkerboard masks).
 
-The spin algebra uses the derived projection tables of ops/wilson_pallas
-(project to 2 half-spinors, one 3x3 color multiply each, reconstruct) —
-1320 flops/site, matching Dslash::flops() (include/dslash.h:475; kernel
-reference include/kernels/dslash_wilson.cuh:84-162).
+The spin algebra uses the projection tables ``TABLES`` below (project to
+2 half-spinors, one 3x3 color multiply each, reconstruct) — 1320
+flops/site, matching Dslash::flops() (include/dslash.h:475; kernel
+reference include/kernels/dslash_wilson.cuh:84-162).  Every Wilson-type
+kernel (the pallas kernels, the df64 stencil, the sharded face fixes)
+reads the tables from here.
 """
 
 from __future__ import annotations
@@ -39,7 +41,49 @@ from functools import lru_cache
 import jax.numpy as jnp
 import numpy as np
 
-from .wilson_pallas import TABLES
+from .gamma import GAMMAS
+
+# -- spin projection tables (derived, then trusted) ------------------------
+# DERIVED from ops/gamma.py at import and asserted, not hand-copied: for
+# each (mu, sign), P = 1 -+ gamma_mu has rank 2 with rows 2,3 proportional
+# to rows 0,1.  Half-spinor h_a = psi_a + c_a * psi_{j_a} (a=0,1);
+# reconstruction rows: out_2 = d_2 * h_{k_2}, out_3 = d_3 * h_{k_3}.
+
+
+def _derive_tables():
+    tables = {}
+    for mu in range(4):
+        for sign in (+1, -1):
+            P = np.eye(4) - sign * np.asarray(GAMMAS[mu])
+            entry = {}
+            for a in (0, 1):
+                row = P[a]
+                assert row[a] == 1.0
+                nz = [j for j in range(4) if j != a and abs(row[j]) > 1e-12]
+                assert len(nz) == 1, (mu, sign, a, row)
+                entry[f"j{a}"] = nz[0]
+                entry[f"c{a}"] = complex(row[nz[0]])
+            for b in (2, 3):
+                row = P[b]
+                # row b = d * row a for exactly one a in (0,1)
+                found = False
+                for a in (0, 1):
+                    ra = P[a]
+                    nz_b = np.nonzero(np.abs(row) > 1e-12)[0]
+                    nz_a = np.nonzero(np.abs(ra) > 1e-12)[0]
+                    if set(nz_b) == set(nz_a):
+                        d = row[nz_b[0]] / ra[nz_b[0]]
+                        assert np.allclose(row, d * ra), (mu, sign, b)
+                        entry[f"k{b}"] = a
+                        entry[f"d{b}"] = complex(d)
+                        found = True
+                        break
+                assert found, (mu, sign, b)
+            tables[(mu, sign)] = entry
+    return tables
+
+
+TABLES = _derive_tables()
 
 
 # -- pack / unpack (host order <-> native order) ---------------------------

@@ -1,10 +1,8 @@
 """Pallas TPU Wilson dslash on the packed device layout — the hand-tuned
-hot path, round 2.
+hot path.
 
-Replaces ops/wilson_pallas.py's canonical-layout kernel, which fetched the
-full spinor five times per application and fought the (8,128) tiling with
-trailing (4,3,2) axes.  This kernel works on the PACKED order of
-ops/wilson_packed.py, split into float re/im planes:
+It works on the PACKED order of ops/wilson_packed.py, split into float
+re/im planes (trailing (4,3,2) axes would fight the (8,128) tiling):
 
     psi   (4, 3, 2, T, Z, Y*X)   float32
     gauge (4, 3, 3, 2, T, Z, Y*X) float32
@@ -15,7 +13,7 @@ BlockSpec index maps deliver psi at (t, zb), its t+-1 and zb+-1
 neighbour tiles, the forward gauge tile at (t, zb) and the PRE-SHIFTED
 backward gauge tile (see below).  The spin algebra is the derived
 projection-table project -> 3x3 color multiply -> reconstruct of
-ops/wilson_pallas (reference include/kernels/dslash_wilson.cuh:84-162),
+ops/wilson_packed.TABLES (reference include/kernels/dslash_wilson.cuh:84-162),
 in explicit re/im-pair arithmetic on (BZ, Y*X) tiles.
 
 Two design points keep the kernel off the VPU-issue wall (the first
@@ -55,7 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .wilson_pallas import TABLES
+from .wilson_packed import TABLES
 
 F32 = jnp.float32
 
@@ -225,7 +223,7 @@ def _make_kernel(X: int, bz: int, eo: tuple | None = None,
                     ref[s, c, 1, 0][rows].astype(F32))
 
         # reconstruct-12 t-boundary sign planes (None for full storage /
-        # periodic t; see _make_kernel_v3 for the v3 analog)
+        # periodic t)
         if g_c.shape[1] == 2 and tb_sign:
             t_idx = pl.program_id(0)
             s_t_fwd = jnp.where(t_idx == T - 1, -1.0, 1.0).astype(F32)
@@ -624,28 +622,13 @@ def dslash_eo_pallas_packed_mrhs(u_here_pl: jnp.ndarray,
     )(psi_pl, psi_pl, psi_pl, psi_pl, psi_pl, u_here_pl, u_bw_pl)
 
 
-# -- v3: scatter-form backward hops (no backward-gauge copy) ----------------
+# -- hop algebra shared by the kernel bodies ---------------------------------
 #
-# The v2 kernel above reads 1152 B/site: psi five times (center + two full
-# t tiles + two full z tiles) and the gauge twice (forward links + the
-# pre-shifted backward copy).  v3 restructures the backward hops into
-# SCATTER form: the backward-mu contribution to out(x) is
-#     U_mu(x-mu)^dag h^-(x-mu)  =  m(x-mu),   m(y) := U_mu(y)^dag h^-(y),
-# so computing m pointwise with the ALREADY-LOADED forward links and then
-# shifting the 6-pair product by -mu gives the same term with ZERO extra
-# gauge traffic for x/y/z — the pre-shifted backward-gauge array (288
-# B/site of HBM reads and a full resident gauge copy) disappears.  The
-# shift count is unchanged (6 complex planes per direction either way).
-# Boundary data comes from tiny BlockSpec inputs instead of full tiles:
-#   * z+ / z- neighbours: single (1, YX) boundary ROWS of psi (the v2
-#     kernel fetched whole (bz, YX) tiles for one row each),
-#   * backward-t: the U_t plane at t-1 via an index-mapped single-mu
-#     slice of the same gauge array (plus the psi t-1 plane, as before).
-# Net per-site traffic: 96 (psi) + 2x96 (psi t planes) + ~0 (z rows)
-# + 288 (gauge) + 72 (U_t plane) + ~0 (U_z row) + 96 (out) ~= 780 B/site
-# — 1.48x less than v2, same VPU instruction mix (measured v2 was
-# HBM-bound: the v1->v2 3.7x speedup exceeded its 1.67x max VPU-bound
-# speedup).
+# Projection, colour multiply, spin reconstruction and the link accessors
+# (full 18-real rows or in-kernel reconstruct-12) as functions of (re, im)
+# tile pairs: the r12f, fold and int8 bodies below, the clover epilogue
+# kernels (ops/clover_pallas.py) and the fused halo (parallel/pallas_halo)
+# are built from them.
 
 
 def _project(get_psi, table):
@@ -734,219 +717,12 @@ def _link_getter(ref, mu, row2_sign=None):
     return _recon12_wrap(stored, ref.shape[1], row2_sign)
 
 
-def _make_kernel_v3(X: int, bz: int, eo: tuple | None = None,
-                    T: int | None = None, tb_sign: bool = True):
-    """v3 kernel over one (t, z-block) tile.  Ref shapes (R = 3 rows for
-    full storage, 2 for reconstruct-12):
-      psi_c/tp/tm:      (4, 3, 2, 1, bz, YX)
-      psi_zp/zm rows:   (4, 3, 2, 1, 1, YX)
-      g_c:              (4, R, 3, 2, 1, bz, YX)   forward links
-      g_t_tm:           (1, R, 3, 2, 1, bz, YX)   U_t plane at t-1
-      g_z_zm:           (1, R, 3, 2, 1, 1, YX)    U_z row at z-1
-    With ``eo = (target_parity, Xh)`` the backward links live on the
-    OPPOSITE parity, so three extra refs carry them (see
-    dslash_eo_pallas_packed_v3): g_there_xyz (3,R,3,2,1,bz,YX) replaces
-    g_c for backward x/y/z and g_t_tm/g_z_zm slice the opposite-parity
-    gauge array.  ``T``/``tb_sign`` drive the reconstruct-12 t-boundary
-    row-2 sign (see _link_getter).
-    """
-    from jax.experimental import pallas as pl
-
-    def kernel(*refs):
-        if eo is None:
-            (psi_c, psi_tp, psi_tm, psi_zp, psi_zm,
-             g_c, g_t_tm, g_z_zm, out_ref) = refs
-            g_bwd_xyz = g_c
-        else:
-            (psi_c, psi_tp, psi_tm, psi_zp, psi_zm,
-             g_c, g_there_xyz, g_t_tm, g_z_zm, out_ref) = refs
-            g_bwd_xyz = g_there_xyz
-            parity, Xh = eo
-            t_id = pl.program_id(0)
-            zb_id = pl.program_id(1)
-            shape = psi_c.shape[-2:]
-            z = (jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-                 + zb_id * bz)
-            y = jax.lax.broadcasted_iota(jnp.int32, shape, 1) // Xh
-            mask_r0 = ((t_id + z + y + parity) % 2) == 0
-
-        def shift_x(v, sign):
-            if eo is None:
-                return _shift_xy(v, 0, sign, X)
-            return _shift_x_eo(v, sign, eo[1], mask_r0)
-
-        def psi_at(ref, s, c):
-            # center blocks are (4,3,2,1,bz,YX); boundary-ROW inputs
-            # carry one extra singleton z axis (…,1,1,YX) because a
-            # 1-extent block on the sublane axis of a Z-extent array is
-            # illegal on hardware — index the extra axis away
-            pad = (0,) * (len(ref.shape) - 6)
-            return (ref[(s, c, 0, 0) + pad].astype(F32),
-                    ref[(s, c, 1, 0) + pad].astype(F32))
-
-        # reconstruct-12 t-boundary sign planes (None for full storage /
-        # periodic t): forward t-link lives on plane t, backward on t-1
-        if g_c.shape[1] == 2 and tb_sign:
-            t_idx = pl.program_id(0)
-            s_fwd = jnp.where(t_idx == T - 1, -1.0, 1.0).astype(F32)
-            s_bwd = jnp.where(t_idx == 0, -1.0, 1.0).astype(F32)
-        else:
-            s_fwd = s_bwd = None
-
-        def link_of(ref, mu, row2_sign=None):
-            return _link_getter(ref, mu, row2_sign)
-
-        acc = [[(jnp.zeros(psi_c.shape[-2:], F32),
-                 jnp.zeros(psi_c.shape[-2:], F32))
-                for _ in range(3)] for _ in range(4)]
-
-        # x, y: forward = project center, shift h, multiply U(x);
-        # backward = multiply U^dag(x) pointwise, shift the product
-        for mu in (0, 1):
-            tf = TABLES[(mu, +1)]
-            h = _project(lambda s, c: psi_at(psi_c, s, c), tf)
-            if mu == 0:
-                h = [[shift_x(h[a][c], +1) for c in range(3)]
-                     for a in (0, 1)]
-            else:
-                h = [[_shift_xy(h[a][c], 1, +1,
-                                X if eo is None else eo[1])
-                      for c in range(3)] for a in (0, 1)]
-            _recon_acc(acc, _color_mul(h, link_of(g_c, mu), False), tf)
-
-            tb = TABLES[(mu, -1)]
-            h = _project(lambda s, c: psi_at(psi_c, s, c), tb)
-            uh = _color_mul(h, link_of(g_bwd_xyz, mu), True)
-            if mu == 0:
-                uh = [[shift_x(uh[a][c], -1) for c in range(3)]
-                      for a in (0, 1)]
-            else:
-                uh = [[_shift_xy(uh[a][c], 1, -1,
-                                 X if eo is None else eo[1])
-                       for c in range(3)] for a in (0, 1)]
-            _recon_acc(acc, uh, tb)
-
-        # z forward: splice the projected boundary row of the z+ block
-        tf = TABLES[(2, +1)]
-        h = _project(lambda s, c: psi_at(psi_c, s, c), tf)
-        h_row = _project(lambda s, c: psi_at(psi_zp, s, c), tf)
-        h = [[_shift_z(h[a][c], h_row[a][c], +1) for c in range(3)]
-             for a in (0, 1)]
-        _recon_acc(acc, _color_mul(h, link_of(g_c, 2), False), tf)
-
-        # z backward: product with local U_z, shifted down one row; the
-        # incoming row is the z-1 product built from the row inputs
-        tb = TABLES[(2, -1)]
-        h = _project(lambda s, c: psi_at(psi_c, s, c), tb)
-        uh = _color_mul(h, link_of(g_bwd_xyz, 2), True)
-        h_b = _project(lambda s, c: psi_at(psi_zm, s, c), tb)
-        uh_b = _color_mul(h_b, link_of(g_z_zm, 0), True)
-        uh = [[_shift_z(uh[a][c], uh_b[a][c], -1) for c in range(3)]
-              for a in (0, 1)]
-        _recon_acc(acc, uh, tb)
-
-        # t forward: whole neighbour plane, local U_t, no shift
-        tf = TABLES[(3, +1)]
-        h = _project(lambda s, c: psi_at(psi_tp, s, c), tf)
-        _recon_acc(acc, _color_mul(h, link_of(g_c, 3, s_fwd), False), tf)
-
-        # t backward: U_t(t-1)^dag psi(t-1), both read at t-1 directly
-        tb = TABLES[(3, -1)]
-        h = _project(lambda s, c: psi_at(psi_tm, s, c), tb)
-        _recon_acc(acc, _color_mul(h, link_of(g_t_tm, 0, s_bwd), True),
-                   tb)
-
-        odt = out_ref.dtype
-        for s in range(4):
-            for c in range(3):
-                out_ref[s, c, 0, 0] = acc[s][c][0].astype(odt)
-                out_ref[s, c, 1, 0] = acc[s][c][1].astype(odt)
-
-    return kernel
-
-
 def to_recon12(gauge_pl: jnp.ndarray) -> jnp.ndarray:
     """Packed links -> reconstruct-12 storage: keep rows 0-1 only.
     (4, 3, 3, 2, T, Z, YX) -> (4, 2, 3, 2, T, Z, YX); 192 B/site f32
     instead of 288.  Valid for SU(3) links (incl. folded antiperiodic-t:
     the kernels re-apply the boundary sign to the reconstructed row)."""
     return gauge_pl[:, :2]
-
-
-@functools.partial(jax.jit, static_argnames=("X", "interpret", "block_z",
-                                             "tb_sign"))
-def dslash_pallas_packed_v3(gauge_pl: jnp.ndarray, psi_pl: jnp.ndarray,
-                            X: int, interpret: bool = False,
-                            block_z: int | None = None,
-                            tb_sign: bool = True) -> jnp.ndarray:
-    """Wilson hop sum, v3: no backward-gauge copy, row-sized z inputs.
-
-    Same layouts and semantics as ``dslash_pallas_packed`` but reads
-    ~780 B/site instead of ~1150 and needs no ``backward_gauge``
-    precompute or resident copy.  A gauge array with ROW extent 2 (see
-    ``to_recon12``) selects in-kernel reconstruct-12: gauge traffic
-    drops another 96 B/site for ~66 extra VPU flops/site
-    (gauge_field_order.h Reconstruct<12>); ``tb_sign`` re-applies the
-    folded antiperiodic-t phase to the reconstructed row.
-    """
-    from jax.experimental import pallas as pl
-
-    _, _, _, T, Z, YX = psi_pl.shape
-    R = gauge_pl.shape[1]
-    bz = block_z if block_z is not None else _pick_bz(
-        Z, YX, psi_pl.dtype, planes=280 if R == 3 else 232)
-    if Z % bz != 0:
-        raise ValueError(f"block_z={bz} does not divide Z={Z}")
-    nzb = Z // bz
-
-    def psi_spec(dt):
-        return pl.BlockSpec(
-            (4, 3, 2, 1, bz, YX),
-            lambda t, zb, dt=dt: (0, 0, 0, (t + dt) % T, zb, 0))
-
-    # Boundary z-ROWS as separate pre-gathered arrays with a SINGLETON z
-    # axis: a 1-extent block on the sublane axis of a Z-extent array is
-    # rejected by the hardware lowering (block second-to-minor extent
-    # must divide by 8 or equal the array's), so the rows are sliced out
-    # ahead of the kernel — O(Z/bz) of the field, fused by XLA — and the
-    # block extent 1 legally equals the array extent 1.
-    psi_r = psi_pl.reshape(4, 3, 2, T, nzb, bz, YX)
-    rows_zp = jnp.roll(psi_r[:, :, :, :, :, 0, :], -1,
-                       axis=4)[:, :, :, :, :, None, :]
-    rows_zm = jnp.roll(psi_r[:, :, :, :, :, bz - 1, :], 1,
-                       axis=4)[:, :, :, :, :, None, :]
-    g_r = gauge_pl[2:3].reshape(1, R, 3, 2, T, nzb, bz, YX)
-    g_rows_zm = jnp.roll(g_r[:, :, :, :, :, :, bz - 1, :], 1,
-                         axis=5)[:, :, :, :, :, :, None, :]
-
-    def psi_row_spec():
-        return pl.BlockSpec(
-            (4, 3, 2, 1, 1, 1, YX),
-            lambda t, zb: (0, 0, 0, t, zb, 0, 0))
-
-    gauge_spec = pl.BlockSpec(
-        (4, R, 3, 2, 1, bz, YX), lambda t, zb: (0, 0, 0, 0, t, zb, 0))
-    g_t_spec = pl.BlockSpec(
-        (1, R, 3, 2, 1, bz, YX),
-        lambda t, zb: (3, 0, 0, 0, (t - 1) % T, zb, 0))
-    g_z_spec = pl.BlockSpec(
-        (1, R, 3, 2, 1, 1, 1, YX),
-        lambda t, zb: (0, 0, 0, 0, t, zb, 0, 0))
-
-    kernel = _make_kernel_v3(X, bz, T=T, tb_sign=tb_sign)
-
-    return pl.pallas_call(
-        kernel,
-        grid=(T, nzb),
-        in_specs=[psi_spec(0), psi_spec(+1), psi_spec(-1),
-                  psi_row_spec(), psi_row_spec(),
-                  gauge_spec, g_t_spec, g_z_spec],
-        out_specs=pl.BlockSpec((4, 3, 2, 1, bz, YX),
-                               lambda t, zb: (0, 0, 0, t, zb, 0)),
-        out_shape=jax.ShapeDtypeStruct(psi_pl.shape, psi_pl.dtype),
-        interpret=interpret,
-    )(psi_pl, psi_pl, psi_pl, rows_zp, rows_zm, gauge_pl, gauge_pl,
-      g_rows_zm)
 
 
 # -- even/odd (checkerboarded) kernel: the solver hot path ------------------
@@ -1020,93 +796,6 @@ def dslash_eo_pallas_packed(u_here_pl: jnp.ndarray, u_bw_pl: jnp.ndarray,
                                        out_dtype or psi_pl.dtype),
         interpret=interpret,
     )(psi_pl, psi_pl, psi_pl, psi_pl, psi_pl, u_here_pl, u_bw_pl)
-
-
-@functools.partial(jax.jit, static_argnames=("dims", "target_parity",
-                                             "interpret", "block_z",
-                                             "out_dtype", "tb_sign"))
-def dslash_eo_pallas_packed_v3(u_here_pl: jnp.ndarray,
-                               u_there_pl: jnp.ndarray,
-                               psi_pl: jnp.ndarray, dims,
-                               target_parity: int, interpret: bool = False,
-                               block_z: int | None = None,
-                               out_dtype=None,
-                               tb_sign: bool = True) -> jnp.ndarray:
-    """Checkerboarded Wilson hop, v3: scatter-form backward hops read
-    the UNSHIFTED opposite-parity links directly — no
-    ``backward_gauge_eo`` precompute or resident pre-shifted copy, and
-    the z neighbours arrive as single boundary rows instead of whole
-    tiles (~160 B/site less HBM traffic than the v2 kernel).
-
-    u_here_pl: (4,R,3,2,T,Z,Y*Xh) forward links at target-parity sites;
-    u_there_pl: links at the OPPOSITE parity (the source parity of
-    psi_pl), same layout; psi_pl: (4,3,2,T,Z,Y*Xh) parity-(1-p) spinor.
-    ROW extent R = 2 selects in-kernel reconstruct-12 (see to_recon12);
-    ``tb_sign`` re-applies the folded antiperiodic-t phase to the
-    reconstructed row.
-    """
-    from jax.experimental import pallas as pl
-
-    T, Z, Y, X = dims
-    Xh = X // 2
-    _, _, _, _, _, YXh = psi_pl.shape
-    R = u_here_pl.shape[1]
-    # working set: 3 psi tiles (72 planes) + u_here (144) + u_there_xyz
-    # (108) + U_t plane (36) + out (24) = 384 bz-row planes (R=3)
-    bz = block_z if block_z is not None else _pick_bz(
-        Z, YXh, psi_pl.dtype, planes=390 if R == 3 else 294)
-    if Z % bz != 0:
-        raise ValueError(f"block_z={bz} does not divide Z={Z}")
-    nzb = Z // bz
-
-    def psi_spec(dt):
-        return pl.BlockSpec(
-            (4, 3, 2, 1, bz, YXh),
-            lambda t, zb, dt=dt: (0, 0, 0, (t + dt) % T, zb, 0))
-
-    # boundary z-rows as singleton-z-axis arrays (hardware-legal block
-    # extent 1; see dslash_pallas_packed_v3)
-    psi_r = psi_pl.reshape(4, 3, 2, T, nzb, bz, YXh)
-    rows_zp = jnp.roll(psi_r[:, :, :, :, :, 0, :], -1,
-                       axis=4)[:, :, :, :, :, None, :]
-    rows_zm = jnp.roll(psi_r[:, :, :, :, :, bz - 1, :], 1,
-                       axis=4)[:, :, :, :, :, None, :]
-    g_r = u_there_pl[2:3].reshape(1, R, 3, 2, T, nzb, bz, YXh)
-    g_rows_zm = jnp.roll(g_r[:, :, :, :, :, :, bz - 1, :], 1,
-                         axis=5)[:, :, :, :, :, :, None, :]
-
-    def psi_row_spec():
-        return pl.BlockSpec(
-            (4, 3, 2, 1, 1, 1, YXh),
-            lambda t, zb: (0, 0, 0, t, zb, 0, 0))
-
-    g_here_spec = pl.BlockSpec(
-        (4, R, 3, 2, 1, bz, YXh), lambda t, zb: (0, 0, 0, 0, t, zb, 0))
-    g_there_xyz_spec = pl.BlockSpec(
-        (3, R, 3, 2, 1, bz, YXh), lambda t, zb: (0, 0, 0, 0, t, zb, 0))
-    g_t_spec = pl.BlockSpec(
-        (1, R, 3, 2, 1, bz, YXh),
-        lambda t, zb: (3, 0, 0, 0, (t - 1) % T, zb, 0))
-    g_z_spec = pl.BlockSpec(
-        (1, R, 3, 2, 1, 1, 1, YXh),
-        lambda t, zb: (0, 0, 0, 0, t, zb, 0, 0))
-
-    kernel = _make_kernel_v3(X, bz, eo=(target_parity, Xh), T=T,
-                             tb_sign=tb_sign)
-
-    return pl.pallas_call(
-        kernel,
-        grid=(T, nzb),
-        in_specs=[psi_spec(0), psi_spec(+1), psi_spec(-1),
-                  psi_row_spec(), psi_row_spec(),
-                  g_here_spec, g_there_xyz_spec, g_t_spec, g_z_spec],
-        out_specs=pl.BlockSpec((4, 3, 2, 1, bz, YXh),
-                               lambda t, zb: (0, 0, 0, t, zb, 0)),
-        out_shape=jax.ShapeDtypeStruct(psi_pl.shape,
-                                       out_dtype or psi_pl.dtype),
-        interpret=interpret,
-    )(psi_pl, psi_pl, psi_pl, rows_zp, rows_zm, u_here_pl, u_there_pl,
-      u_there_pl, g_rows_zm)
 
 
 # -- folded re/im storage: full bf16 sublane tiles --------------------------
@@ -1400,12 +1089,12 @@ def dslash_eo_pallas_packed_fold_mrhs(u_here_f: jnp.ndarray,
 # backward link copy (backward_gauge_eo) — half the gauge HBM footprint
 # again, and the array the sharded gauge-residency budget feels most.
 # r12f keeps the v2 GATHER psi pipeline (whole z-neighbour tiles — the
-# form that won on chip; PERF.md round 5) but takes the v3 kernels'
-# copy-free backward structure: backward x/y/z multiply the UNSHIFTED
-# opposite-parity links pointwise and shift the product (scatter form —
-# recon commutes with the shift, so reconstructing the local rows is
-# bitwise identical to reconstructing pre-shifted rows), backward-t
-# reads the U_t plane at t-1 via its index map.  HBM traffic equals
+# form the cells run) with a copy-free backward structure: backward
+# x/y/z multiply the UNSHIFTED opposite-parity links pointwise and
+# shift the product (scatter form — recon commutes with the shift, so
+# reconstructing the local rows is bitwise identical to reconstructing
+# pre-shifted rows), backward-t reads the U_t plane at t-1 via its
+# index map.  HBM traffic equals
 # wilson_v2_r12 (960 B/site: the backward links cost the same bytes
 # read directly or via a copy) — what disappears is the resident copy
 # itself and its backward_gauge_eo precompute.
@@ -1523,7 +1212,10 @@ def _make_kernel_r12f(X: int, bz: int, eo: tuple, T: int | None = None,
 def _r12f_gz_rows(u_there_pl, R, T, nzb, bz, YXh):
     """Pre-gathered U_z boundary rows at z-1 (the previous block's last
     row of the mu=2 plane), shaped (1,R,3,2,T,nzb,1,YXh) so the block
-    extent 1 legally equals the array extent (see _make_kernel_v3)."""
+    extent 1 legally equals the array extent (a 1-extent block on the
+    sublane axis of a Z-extent array is refused by the hardware
+    lowering: the second-to-minor block extent must divide by 8 or
+    equal the array's)."""
     g_r = u_there_pl[2:3].reshape(1, R, 3, 2, T, nzb, bz, YXh)
     return jnp.roll(g_r[:, :, :, :, :, :, bz - 1, :], 1,
                     axis=5)[:, :, :, :, :, :, None, :]
@@ -1891,7 +1583,7 @@ def dslash_eo_pallas_packed_int8(q_here, s_here, q_there, s_there,
         (1, 1, 1, 1, YXh), lambda t, zb: (0, t, zb, 0, 0))
 
     # pre-gathered z-1 boundary rows of the opposite-parity U_z mantissa
-    # and scale planes (block extent 1 == array extent; see v3)
+    # and scale planes (block extent 1 == array extent; _r12f_gz_rows)
     q_r = q_there[2:3].reshape(1, 3, 3, 2, T, nzb, bz, YXh)
     q_rows_zm = jnp.roll(q_r[:, :, :, :, :, :, bz - 1, :], 1,
                          axis=5)[:, :, :, :, :, :, None, :]

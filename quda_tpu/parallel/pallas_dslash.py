@@ -26,10 +26,10 @@ shared).  x-partitioned blocks must be laid out block-contiguous
 (parallel/mesh.fuse_block_layout) so one shard holds a (Y_loc, X_loc)
 rectangle with the LOCAL row width as its fused minor.
 
-Round 8 brought the t/z policies to both kernel forms — v2 (gather,
-globally pre-shifted backward links; the measured single-chip winner)
-and v3 (scatter) — with reconstruct-12 storage (face slabs rebuilt by
-``_full_rows``).  Round 18 generalizes the exchange seam per axis:
+The Wilson policies run the v2 gather kernel (globally pre-shifted
+backward links), the staggered ones the gather and the scatter (v3)
+form; reconstruct-12 storage has its face slabs rebuilt by
+``_full_rows``.  The exchange seam is per axis:
 ``QUDA_TPU_SHARDED_POLICY`` accepts a per-axis spec
 (``t=fused_halo,z=fused_halo,y=xla_facefix``) resolved by
 ``resolve_axis_policies``; every partitioned direction routes its face
@@ -52,7 +52,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..ops.wilson_pallas import TABLES
+from ..ops.wilson_packed import TABLES
 from ..ops.wilson_packed import (_hop_packed_pairs, _planes_psi, _planes_u,
                                  _stack_pairs)
 from .halo import _permute_slice as _nbr
@@ -143,19 +143,6 @@ def _axis_plan(counts, xcols: int):
 def _mesh_counts(mesh):
     s = dict(mesh.shape)
     return tuple(int(s.get(a, 1)) for a in AXIS_NAMES)
-
-
-def _fix_hi_face_n(out, gauge_pl, psi_pl, axis, name, n, mu):
-    """Forward-hop fix on the HIGH face (ppermute form, kept for the
-    staggered policies): psi(x+mu) must come from the next shard's first
-    plane — the kernel used the local first plane."""
-    u_fwd_hi = _face_n(gauge_pl[mu], axis, lo=False)
-    halo_hi = _nbr(_face_n(psi_pl, axis, lo=True), name,
-                   towards_lower=True, n=n)
-    wrong_hi = _face_n(psi_pl, axis, lo=True)
-    corr_hi = (_hop_term(halo_hi, u_fwd_hi, TABLES[(mu, +1)], False)
-               - _hop_term(wrong_hi, u_fwd_hi, TABLES[(mu, +1)], False))
-    return _add_face_n(out, corr_hi, axis, lo=False)
 
 
 # -- halo-exchange policies (QUDA_TPU_SHARDED_POLICY) -----------------------
@@ -380,40 +367,6 @@ def _wilson_fix_faces_v2(out, links_fwd, links_bwd_sh, psi_pl, fio,
     return fio.add(out, corr_lo, lo=True)
 
 
-def _wilson_fix_faces_v3(out, links_fwd, links_bwd, psi_pl, fio, name,
-                         n, mu, exchange=_exchange_xla, sign_hi=None):
-    """Both face fixes for one partitioned direction, v3 scatter-form
-    conventions (one home for the full-lattice AND eo policies):
-
-    * forward hop, HIGH face: psi(x+mu) from the next shard's first
-      face against ``links_fwd`` (the links the forward hop reads);
-    * backward hop, LOW face: the kernel wrapped the locally-computed
-      product U^dag psi of the last face (built from ``links_bwd``);
-      permute the product itself — linear in the face, no link exchange.
-
-    Both transfers ride ONE ``exchange`` call (the policy seam)."""
-    lo_first = fio.face(psi_pl, lo=True)
-    hi_last = fio.face(psi_pl, lo=False)
-    u_bwd_true, u_bwd_kern = _face_links(fio.face(links_bwd[mu],
-                                                  lo=False), sign_hi)
-    tb = TABLES[(mu, -1)]
-    # the face SENT upward must be the physically correct product (the
-    # receiver splices it in as-is); the face SUBTRACTED locally must be
-    # the interior kernel's own wrong-wrap product
-    prod_true = _hop_term(hi_last, u_bwd_true, tb, True)
-    prod_kern = (prod_true if u_bwd_kern is u_bwd_true
-                 else _hop_term(hi_last, u_bwd_kern, tb, True))
-    halo_hi, prod_in = exchange(lo_first, prod_true, name, n)
-
-    u_fwd_true, u_fwd_kern = _face_links(fio.face(links_fwd[mu],
-                                                  lo=False), sign_hi)
-    tf = TABLES[(mu, +1)]
-    corr_hi = (_hop_term(halo_hi, u_fwd_true, tf, False)
-               - _hop_term(lo_first, u_fwd_kern, tf, False))
-    out = fio.add(out, corr_hi, lo=False)
-    return fio.add(out, prod_in - prod_kern, lo=True)
-
-
 def _check_sharded_mesh(name: str, psi_pl, X: int, mesh):
     """Shared guards of the full-lattice sharded policies: the x mesh
     axis must split X evenly and the local fused extent must be whole
@@ -515,7 +468,7 @@ def _stag_fix_faces(out, links_fwd, links_bwd, psi_pl, nhop: int, fio,
     Both transfers ride ONE ``exchange`` call per hop set (the
     QUDA_TPU_SHARDED_POLICY seam — the psi face and the product face
     have identical shapes, so the fused-RDMA bidirectional kernel
-    serves them like the Wilson v3 fixes on any contiguous-strip axis).
+    serves them on any contiguous-strip axis).
 
     ``links_fwd``/``links_bwd``: the link arrays each hop reads — the
     same full-lattice array, or (checkerboarded) the target-parity and
@@ -969,9 +922,8 @@ def dslash_eo_pallas_sharded(u_here_pl, u_bw_pl, psi_pl, dims,
                              out_dtype=None, tb_sign: bool = True,
                              policy="xla_facefix"):
     """Checkerboarded Wilson hop under shard_map on the v2 (gather)
-    kernel form — the MEASURED-BEST interior (PERF.md round 5: v2 f32
-    5673 GFLOPS vs v3 1768 single-chip) driving the multi-chip CG hot
-    loop, all four directions partitionable (reference:
+    kernel — the interior every cell runs — driving the multi-chip CG
+    hot loop, all four directions partitionable (reference:
     lib/dslash_policy.hpp:365-560; full 4-d decomposition with
     per-dimension policies is QUDA's production story).
 
@@ -1024,106 +976,4 @@ def dslash_eo_pallas_sharded(u_here_pl, u_bw_pl, psi_pl, dims,
             out = _wilson_fix_faces_v2(out, u_here_pl, u_bw_pl, psi_pl,
                                        fio, name, n, mu, exchange,
                                        sign_hi, sign_lo)
-    return out
-
-
-def dslash_eo_pallas_sharded_v3(u_here_pl, u_there_pl, psi_pl, dims,
-                                target_parity: int, mesh,
-                                interpret: bool = False,
-                                out_dtype=None, tb_sign: bool = True,
-                                policy="xla_facefix"):
-    """Checkerboarded Wilson hop under shard_map on the v3 scatter
-    kernel form — t/z mesh axes only (the scatter exterior permutes
-    products, which have no slot-select column fix; the v2 gather form
-    is the all-axes production path and what the models pin under a
-    mesh).  Reference: the eo interior/exterior policies of
-    lib/dslash_policy.hpp:365-560 driving dslash_wilson.cuh.
-
-    Interior: the single-chip v3 scatter-form eo kernel
-    (ops/wilson_pallas_packed.dslash_eo_pallas_packed_v3) on the LOCAL
-    block.  Exterior: the same slab algebra as the full-lattice v3
-    policy — forward hops read the target-parity links (u_here) against
-    the next shard's first psi plane; the backward hop permutes the
-    locally computed product U^dag psi built from the opposite-parity
-    links (u_there).  Both link arrays are already shard-resident: only
-    psi slabs and product slabs ride the exchange (the policy seam);
-    row extent 2 selects reconstruct-12.
-
-    t/z hops flip parity but keep the checkerboarded x-slot layout, so
-    slab alignment matches the full-lattice case; partitioned axes need
-    EVEN local extents (the in-kernel x-slot parity masks use local
-    coordinates).  ``dims`` is the GLOBAL (T, Z, Y, X).
-    """
-    from ..ops.wilson_pallas_packed import dslash_eo_pallas_packed_v3
-
-    counts, dims_local, xh_loc = _check_eo_mesh(
-        "dslash_eo_pallas_sharded_v3", mesh, psi_pl, dims, False,
-        tz_only=True)
-    n_t = counts[0]
-    R = u_here_pl.shape[1]
-    pols = resolve_axis_policies(policy)
-    exchange = _make_exchange(pols, mesh, interpret)
-
-    out = dslash_eo_pallas_packed_v3(
-        u_here_pl, u_there_pl, psi_pl, dims_local, target_parity,
-        interpret=interpret, out_dtype=out_dtype,
-        tb_sign=tb_sign and n_t == 1)
-
-    plan = _axis_plan(counts, xh_loc)
-    live = [nm for _, nm, nn, _ in plan if nn > 1]
-    from ..obs import comms as ocomms
-    with ocomms.scope(f"wilson_eo_sharded_v3:p{target_parity}",
-                      _policy_label(pols, live), mesh_axes=counts):
-        for fio, name, n, mu in plan:
-            if n == 1:
-                continue
-            sign_hi, _ = _t_edge_signs(name, n, mu, R, tb_sign)
-            out = _wilson_fix_faces_v3(out, u_here_pl, u_there_pl,
-                                       psi_pl, fio, name, n, mu,
-                                       exchange, sign_hi)
-    return out
-
-
-def dslash_pallas_sharded_v3(gauge_pl, psi_pl, X: int, mesh,
-                             interpret: bool = False,
-                             tb_sign: bool = True,
-                             policy="xla_facefix"):
-    """v3 of the fused manual policy: the scatter-form interior kernel
-    needs NO backward-gauge copy anywhere — not per shard, not global.
-
-    The v3 kernel's backward hop wraps the locally-computed product
-    m = U_mu^dag psi into the low face.  Since that product is
-    elementwise per face site and the exchange is linear, the fix sends
-    the PRODUCT once — corr = recv(m_last) - m_last — one f32 spinor
-    face per partitioned direction, half the exterior compute, and no
-    gauge exchange or resident pre-shifted copy anywhere.  All four
-    directions partition (full-lattice hop-to-face alignment is 1:1 on
-    every axis); row extent 2 selects reconstruct-12; ``policy`` the
-    per-axis halo transport.  ``X`` is the GLOBAL x extent.
-    """
-    from ..ops.wilson_pallas_packed import dslash_pallas_packed_v3
-
-    counts, x_loc = _check_sharded_mesh("dslash_pallas_sharded_v3",
-                                        psi_pl, X, mesh)
-    n_t = counts[0]
-    R = gauge_pl.shape[1]
-    pols = resolve_axis_policies(policy)
-    exchange = _make_exchange(pols, mesh, interpret)
-
-    out = dslash_pallas_packed_v3(gauge_pl, psi_pl, x_loc,
-                                  interpret=interpret,
-                                  tb_sign=tb_sign and n_t == 1)
-
-    plan = _axis_plan(counts, x_loc)
-    live = [nm for _, nm, nn, _ in plan if nn > 1]
-    from ..obs import comms as ocomms
-    with ocomms.scope("wilson_sharded_v3", _policy_label(pols, live),
-                      mesh_axes=counts):
-        for fio, name, n, mu in plan:
-            if n == 1:
-                continue
-            sign_hi, _ = _t_edge_signs(name, n, mu, R, tb_sign)
-            out = _wilson_fix_faces_v3(out, gauge_pl, gauge_pl, psi_pl,
-                                       fio, name, n, mu, exchange,
-                                       sign_hi)
     return out

@@ -20,7 +20,7 @@ sharded dslash policies select via QUDA_TPU_SHARDED_POLICY=fused_halo
 (parallel/pallas_dslash.py).  The original kernel:
 
   1. computes m(y) = U_z(y)^dag P^{+z} psi(y) for every LOCAL site
-     (the scatter-form backward product, as in the v3 kernels),
+     (the scatter-form backward product),
   2. copies its top boundary row of m into a VMEM send buffer and
      STARTS the async remote copy to the +z neighbour's receive buffer,
   3. (the interior rows of the output are assembled while the DMA is in

@@ -145,7 +145,7 @@ _register("QUDA_TPU_MG_COARSE_FORM", "choice", "auto",
           reference="coarse-dslash MMA/policy selection "
                     "(lib/dslash_coarse.cu + tune.cpp:862)")
 _register("QUDA_TPU_RECONSTRUCT", "choice", "18",
-          "gauge link storage for v3 pallas kernels: '18' = full, "
+          "gauge link storage for the pallas kernels: '18' = full, "
           "'12' = two rows + in-kernel third-row reconstruction "
           "(192 B/site instead of 288; SU(3) links only)",
           ("18", "12"),
@@ -155,10 +155,10 @@ _register("QUDA_TPU_PRECISION_FORM", "choice", "",
           "link storage / precision form for the packed pallas Wilson "
           "operator (PERF.md round 16): 'full' = resident 18-real "
           "links; 'r12' = two rows + in-kernel third-row recon "
-          "(192 B/site, both kernel generations and the sharded path); "
+          "(192 B/site, single chip and the sharded path); "
           "'r12f' = r12 storage + scatter backward (no resident "
-          "backward-link copy — the v3 trick on the v2 gather psi "
-          "path); 'fold' = re/im interleaved into sublanes "
+          "backward-link copy) on the gather psi "
+          "path; 'fold' = re/im interleaved into sublanes "
           "((...,2,T,Z,YX) -> (...,T,2Z,YX)) so bf16 (16,128) tiles "
           "fill exactly; 'bzfull' = full-Z block admission (single-"
           "buffered under the 16 MB scoped window when the budget knob "
@@ -176,16 +176,6 @@ _register("QUDA_TPU_PRECISION_FORM", "choice", "",
                     "matrix (gauge_field_order.h Reconstruct<12> + "
                     "quarter-precision block-float norm arrays)",
           trace_safe=False)
-_register("QUDA_TPU_PALLAS_VERSION", "int", 2,
-          "pallas kernel generation: 2 = gather kernels with "
-          "pre-shifted backward links, 3 = scatter-form backward hops "
-          "(no backward-link copies).  Default 2 BY MEASUREMENT "
-          "(2026-07-31, TPU v5 lite, 24^4 Wilson full: v2 f32 5673 "
-          "GFLOPS vs v3 1768 / v3+recon-12 1919 — the scatter shifts "
-          "cost more VPU work than the saved HBM traffic buys; the "
-          "autotuner can still select v3 per-shape when it wins)",
-          reference="dslash policy selection; tune.cpp:862 — policies "
-                    "are timed, never assumed")
 _register("QUDA_TPU_SHARDED_POLICY", "str", "auto",
           "multi-chip dslash halo policy, PER MESH AXIS since round "
           "18: 'xla_facefix' = lax.ppermute face fixes around the "
@@ -231,9 +221,9 @@ _register("QUDA_TPU_STAGGERED_FORM", "choice", "auto",
           "pre-shifted backward links (the pre-round-10 form), 'v3' = "
           "two-pass scatter, 'auto' = race all forms via utils.tune at "
           "operator construction and cache the winner per (volume, "
-          "dtype, improved) — A/B'd, not assumed: v3 LOST for Wilson "
-          "on chip, so no staggered form is presumed either",
-          ("", "auto", "fused", "two_pass", "v3"),
+          "dtype, improved) — A/B'd, not assumed: no staggered form "
+          "has a chip reading yet",
+          ("auto", "fused", "two_pass", "v3"),
           reference="dslash policy selection; tune.cpp:862 — policies "
                     "are timed, never assumed")
 _register("QUDA_TPU_CLOVER_FORM", "choice", "",
@@ -622,8 +612,9 @@ _register("QUDA_TPU_SERVE_SLO_BUCKETS", "str", "",
           "as the bucket grid",
           reference="pull-based Prometheus scrape discipline")
 
-# CUDA-runtime knobs deliberately not carried over: the replacing
-# subsystem answers "where did it go".
+# Knobs deliberately not accepted (CUDA-runtime ones never carried over,
+# and this package's own retired ones): what replaces each answers
+# "where did it go".
 SUBSUMED = {
     "QUDA_ENABLE_DEVICE_MEMORY_POOL": "XLA/PJRT allocator",
     "QUDA_ENABLE_PINNED_MEMORY_POOL": "XLA/PJRT allocator",
@@ -638,6 +629,9 @@ SUBSUMED = {
     "QUDA_ENABLE_ZERO_COPY": "device_put / donation semantics",
     "QUDA_REORDER_LOCATION": "host<->device packing in fields/",
     "QUDA_ENABLE_DSLASH_POLICY": "QUDA_TPU_PALLAS + utils.tune",
+    "QUDA_TPU_PALLAS_VERSION":  # quda-lint: disable=env-knob  reason=the retired knob's own entry: named so that a user who still sets it is told
+        "the one Wilson pallas kernel generation (the v2 gather kernel; "
+        "v1 and v3 were deleted in PR 30)",
     "QUDA_ALLOW_JIT": "jit is the only execution model",
     "QUDA_DEVICE_RESET": "PJRT owns device lifetime",
 }
@@ -774,7 +768,7 @@ def snapshot_values() -> dict:
 
 def describe() -> str:
     """Human-readable table of every knob (value, default, doc) plus the
-    subsumed CUDA-era knobs — the analog of the reference's documented
+    subsumed knobs — the analog of the reference's documented
     environment-variable list."""
     lines = ["# quda_tpu environment configuration"]
     for name in sorted(_REGISTRY):
@@ -784,7 +778,7 @@ def describe() -> str:
         ref = f"  [ref: {k.reference}]" if k.reference else ""
         lines.append(f"{name} = {cur!r} ({src}; default {k.default!r}) "
                      f"— {k.doc}{ref}")
-    lines.append("# subsumed CUDA-era knobs")
+    lines.append("# subsumed knobs (CUDA-era and retired)")
     for name in sorted(SUBSUMED):
         lines.append(f"{name} -> {SUBSUMED[name]}")
     return "\n".join(lines)
@@ -797,13 +791,14 @@ def check_environment(warn=None) -> list:
     from . import logging as qlog
     warn = warn or qlog.warningq
     unknown = [v for v in os.environ
-               if v.startswith(_PREFIX) and v not in _REGISTRY]
+               if v.startswith(_PREFIX) and v not in _REGISTRY
+               and v not in SUBSUMED]
     for v in unknown:
         warn(f"warning: unrecognised environment variable {v} "
              "(see quda_tpu.utils.config.describe())")
     legacy = [v for v in os.environ if v in SUBSUMED]
     for v in legacy:
-        warn(f"warning: {v} has no effect on TPU — subsumed by "
+        warn(f"warning: {v} has no effect — subsumed by "
              f"{SUBSUMED[v]}")
     bad = []
     for name in _REGISTRY:

@@ -197,7 +197,7 @@ def test_solve_program_compiles_for_v5e_with_links_as_parameters(one_chip):
     def operators(gauge):
         dpk = DiracWilsonPC(gauge, geom, 0.124).packed()
         return tuple(dpk.pairs(dt, use_pallas=True, pallas_interpret=False,
-                               pallas_version=2, precision_form="full")
+                               precision_form="full")
                      for dt in (F32, BF16))
 
     cache_was = jax.config.jax_enable_compilation_cache
@@ -288,7 +288,7 @@ def test_clover_solve_program_compiles_for_v5e_with_blocks_as_parameters(
     def operators(links_e, links_o, a_p, ainv_q):
         return tuple(DiracCloverPCPairs.from_packed(
             geom, (links_e, links_o), 0.124, 0, a_p, ainv_q, dt,
-            use_pallas=True, pallas_interpret=False, pallas_version=2,
+            use_pallas=True, pallas_interpret=False,
             form="pallas") for dt in (F32, BF16))
 
     def lower():

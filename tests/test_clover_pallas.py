@@ -191,10 +191,6 @@ def test_formsel_capability_gates(cfg):
     # no pallas at all -> xla even when pallas is requested
     op = dpc.pairs(jnp.float32, use_pallas=False, form="pallas")
     assert op._op_form == "xla"
-    # legacy pallas_version mapping: v3 has no fused form
-    op3 = dpc.pairs(jnp.float32, use_pallas=True, pallas_interpret=True,
-                    pallas_version=3, form="pallas")
-    assert op3._op_form == "xla"
 
 
 def test_form_knob_validation(cfg):

@@ -107,26 +107,21 @@ def test_packed_and_pallas_switches(monkeypatch):
     assert _pallas_enabled(False)
 
 
-def test_pallas_version_knob(monkeypatch):
-    from quda_tpu.fields.geometry import LatticeGeometry
-    from quda_tpu.fields.gauge import GaugeField
-    from quda_tpu.models.wilson import DiracWilsonPC
-    import jax
-    geom = LatticeGeometry((4, 4, 4, 4))
-    g = GaugeField.random(jax.random.PRNGKey(0), geom).data.astype(
-        jnp.complex64)
-    dpk = DiracWilsonPC(g, geom, 0.1).packed()
+def test_retired_pallas_version_knob_is_flagged_once(monkeypatch):
+    """QUDA_TPU_PALLAS_VERSION went with the v1 and v3 Wilson kernels:
+    it is unregistered (``get`` raises), and a user who still sets it is
+    told so by ``check_environment``: once, as retired, not as a typo."""
+    assert "QUDA_TPU_PALLAS_VERSION" not in qconf.knobs()
+    assert "QUDA_TPU_PALLAS_VERSION" in qconf.SUBSUMED
+    with pytest.raises(KeyError, match="unregistered"):
+        qconf.get("QUDA_TPU_PALLAS_VERSION")
     monkeypatch.setenv("QUDA_TPU_PALLAS_VERSION", "3")
-    qconf.reset_cache()
-    sl3 = dpk.pairs(jnp.float32, use_pallas=True, pallas_interpret=True)
-    assert sl3._pallas_version == 3 and not hasattr(sl3, "_u_bw")
-    monkeypatch.delenv("QUDA_TPU_PALLAS_VERSION")
-    qconf.reset_cache()
-    # default is v2 BY MEASUREMENT (utils/config.py: chip A/B 2026-07-31)
-    sl = dpk.pairs(jnp.float32, use_pallas=True, pallas_interpret=True)
-    assert sl._pallas_version == 2 and sl._u_bw is not None
-    with pytest.raises(ValueError, match="pallas_version"):
-        dpk.pairs(jnp.float32, use_pallas=True, pallas_version=1)
+    msgs = []
+    assert qconf.check_environment(msgs.append) == [
+        "QUDA_TPU_PALLAS_VERSION"]
+    (msg,) = msgs
+    assert "no effect" in msg and "unrecognised" not in msg
+    assert "QUDA_TPU_PALLAS_VERSION" in qconf.describe()
 
 
 def test_force_monitor_logs(monkeypatch, capsys):

@@ -223,7 +223,7 @@ def test_reliable_codec_pallas_tail(pair_problem):
 
 @pytest.mark.slow
 def test_invert_quda_routes_pallas_v2_inside_solve(monkeypatch):
-    """invert_quda routes the measured-winner v2 pallas eo dslash INSIDE
+    """invert_quda routes the pallas eo dslash INSIDE
     the compiled solve via config (CPU: interpreter mode), and the PC
     GFLOPS accounting charges volume/2."""
     from quda_tpu.interfaces import quda_api as api
@@ -233,7 +233,6 @@ def test_invert_quda_routes_pallas_v2_inside_solve(monkeypatch):
 
     monkeypatch.setenv("QUDA_TPU_PALLAS", "1")
     monkeypatch.setenv("QUDA_TPU_PACKED", "1")
-    monkeypatch.setenv("QUDA_TPU_PALLAS_VERSION", "2")
     qconf.reset_cache()
 
     calls = {"n": 0}
@@ -259,7 +258,7 @@ def test_invert_quda_routes_pallas_v2_inside_solve(monkeypatch):
                         maxiter=500, cuda_prec="single",
                         cuda_prec_sloppy="single")
         api.invert_quda(b, p)
-        # the v2 kernel actually executed inside the compiled solve
+        # the kernel actually executed inside the compiled solve
         assert calls["n"] > 0
         assert p.true_res < 5e-4
         # PC accounting: flops charged per UPDATED (half-lattice) site
@@ -273,13 +272,10 @@ def test_invert_quda_routes_pallas_v2_inside_solve(monkeypatch):
 
 
 @pytest.mark.slow
-def test_single_device_mesh_escapes_to_measured_winner(monkeypatch):
-    """The sharded path no longer hardcodes v3: a 1-device mesh shards
-    nothing and now honors the measured-winner default (v2)."""
+def test_single_device_mesh_escapes_to_measured_winner():
+    """A 1-device mesh shards nothing: it is dropped, and the operator
+    is the unsharded one."""
     from jax.sharding import Mesh
-    from quda_tpu.utils import config as qconf
-    monkeypatch.delenv("QUDA_TPU_PALLAS_VERSION", raising=False)
-    qconf.reset_cache()
     geom = GEOM_PAIR
     gauge = GaugeField.random(jax.random.PRNGKey(9), geom).data.astype(
         jnp.complex64)
@@ -288,7 +284,6 @@ def test_single_device_mesh_escapes_to_measured_winner(monkeypatch):
     op = dpk.pairs(jnp.float32, use_pallas=True, pallas_interpret=True,
                    mesh=mesh1)
     assert op._mesh is None            # trivial mesh dropped
-    assert op._pallas_version == 2     # the measured winner, not v3
     # reference: the XLA pair stencil (avoids a second interpret compile)
     ref = dpk.pairs(jnp.float32)
     T, Z, Y, X = geom.lattice_shape
@@ -297,24 +292,17 @@ def test_single_device_mesh_escapes_to_measured_winner(monkeypatch):
     np.testing.assert_allclose(np.asarray(op.M_pairs(x)),
                                np.asarray(ref.M_pairs(x)),
                                rtol=1e-5, atol=1e-5)
-    # an EXPLICIT v3 request on a 1-device mesh is still honored
-    op3 = dpk.pairs(jnp.float32, use_pallas=True, pallas_interpret=True,
-                    pallas_version=3, mesh=mesh1)
-    assert op3._pallas_version == 3
 
 
 def test_mesh_policy_emits_one_time_provenance_notice(monkeypatch,
                                                       capsys):
-    """The mesh dispatch no longer overrides the kernel form: v2 (the
-    measured winner) is honored under a multi-device mesh, and a
-    one-time provenance notice names the selected kernel form + halo
-    policy — a policy must never take effect silently (successor of the
-    retired forced-v3 override notice)."""
+    """Under a multi-device mesh a one-time provenance notice names
+    the selected halo policy and how it was chosen — a policy must
+    never take effect silently."""
     import quda_tpu.models.wilson as mwil
     from quda_tpu.parallel.mesh import make_lattice_mesh
     if len(jax.devices()) != 8:
         pytest.skip("needs the 8-device virtual mesh")
-    monkeypatch.setenv("QUDA_TPU_PALLAS_VERSION", "2")
     monkeypatch.setattr(mwil, "_SHARDED_NOTICED", False)
     geom = LatticeGeometry((4, 4, 8, 16))
     gauge = GaugeField.random(jax.random.PRNGKey(11), geom).data.astype(
@@ -323,9 +311,9 @@ def test_mesh_policy_emits_one_time_provenance_notice(monkeypatch,
     mesh = make_lattice_mesh(grid=(4, 2, 1, 1), n_src=1)
     op = dpk.pairs(jnp.float32, use_pallas=True, pallas_interpret=True,
                    mesh=mesh, sharded_policy="xla_facefix")
-    assert op._pallas_version == 2     # the env knob is honored on mesh
+    assert op._u_bw is not None        # the gather kernel's links
     err = capsys.readouterr().err       # qlog emits on stderr
-    assert "pallas v2 eo interior" in err
+    assert "pallas eo interior" in err
     assert "halo policy xla_facefix" in err
     # one-time: a second construction stays quiet
     dpk.pairs(jnp.float32, use_pallas=True, pallas_interpret=True,

@@ -498,7 +498,6 @@ def test_invert_multi_src_routes_mrhs_pallas_kernel(api_ctx,
     from quda_tpu.utils import config as qconf
     api, B = api_ctx
     monkeypatch.setenv("QUDA_TPU_PALLAS", "1")
-    monkeypatch.setenv("QUDA_TPU_PALLAS_VERSION", "2")
     qconf.reset_cache()
 
     calls = {"n": 0}

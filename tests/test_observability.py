@@ -385,7 +385,6 @@ def test_solve_form_labels_recon12():
 
     class _FakeWilsonOp:
         use_pallas = True
-        _pallas_version = 2
         _mesh = None
 
     op18, op12 = _FakeWilsonOp(), _FakeWilsonOp()
@@ -396,8 +395,6 @@ def test_solve_form_labels_recon12():
     # every r12 label resolves to a model with the subtracted traffic
     assert orf.model("wilson_v2_r12")[1] == 960
     assert orf.model("wilson_sharded_v2_r12")[1] == 960
-    assert orf.model("wilson_v3_r12")[1] == 684
-    assert orf.model("wilson_sharded_v3_r12")[1] == 684
 
 
 def test_publish_multishift_sloppy_stage_tol():

@@ -96,7 +96,7 @@ def test_axis_composed_references_match_packed_stencil():
     from quda_tpu.ops.wilson_packed import (_hop_packed_pairs,
                                             _planes_psi, _planes_u,
                                             _stack_pairs, shift_packed)
-    from quda_tpu.ops.wilson_pallas import TABLES
+    from quda_tpu.ops.wilson_packed import TABLES
     from quda_tpu.parallel.pallas_halo import (wilson_t_composed,
                                                wilson_z_composed)
     key = jax.random.PRNGKey(11)

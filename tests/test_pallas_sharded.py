@@ -56,44 +56,6 @@ def test_sharded_pallas_matches_single_device(grid):
     assert err < 1e-6
 
 
-@pytest.mark.slow
-@pytest.mark.parametrize("grid", [(4, 2, 1, 1), (2, 4, 1, 1),
-                                  (8, 1, 1, 1)])
-def test_sharded_pallas_v3_matches_single_device(grid):
-    """v3 fused policy: no backward-gauge copy at all — face fixes
-    exchange the neighbour's psi AND U planes; must bit-match the
-    single-device stencil on the virtual mesh."""
-    from quda_tpu.parallel.pallas_dslash import dslash_pallas_sharded_v3
-    if len(jax.devices()) != 8:
-        pytest.skip("needs the 8-device virtual mesh")
-    geom = LatticeGeometry((4, 4, 8, 8))
-    T, Z, Y, X = geom.lattice_shape
-    gauge = GaugeField.random(jax.random.PRNGKey(13), geom).data.astype(
-        jnp.complex64)
-    psi = ColorSpinorField.gaussian(jax.random.PRNGKey(14), geom
-                                    ).data.astype(jnp.complex64)
-    gp = wpp.to_pallas_layout(wpk.pack_gauge(gauge))
-    pp = wpp.to_pallas_layout(wpk.pack_spinor(psi))
-    ref = wpk.dslash_packed_pairs(gp, pp, X, Y)
-
-    mesh = make_lattice_mesh(grid=grid, n_src=1)
-    psi_spec = P(None, None, None, "t", "z", None)
-    g_spec = P(None, None, None, None, "t", "z", None)
-
-    fn = jax.shard_map(
-        lambda g, p: dslash_pallas_sharded_v3(g, p, X, mesh,
-                                              interpret=True),
-        mesh=mesh, in_specs=(g_spec, psi_spec),
-        out_specs=psi_spec, check_vma=False)
-
-    gp_s = jax.device_put(gp, NamedSharding(mesh, g_spec))
-    pp_s = jax.device_put(pp, NamedSharding(mesh, psi_spec))
-    out = jax.jit(fn)(gp_s, pp_s)
-
-    err = float(jnp.sqrt(blas.norm2(ref - out) / blas.norm2(ref)))
-    assert err < 1e-6
-
-
 @pytest.mark.parametrize("grid", [(4, 2, 1, 1), (2, 4, 1, 1),
                                   (8, 1, 1, 1)])
 def test_sharded_staggered_v3_matches_single_device(grid):
@@ -165,49 +127,6 @@ def test_sharded_improved_staggered_v3_matches_single_device():
     long_s = jax.device_put(long_pp, NamedSharding(mesh, g_spec))
     psi_s = jax.device_put(psi_pp, NamedSharding(mesh, psi_spec))
     out = jax.jit(fn)(fat_s, long_s, psi_s)
-    err = float(jnp.sqrt(blas.norm2(ref - out) / blas.norm2(ref)))
-    assert err < 1e-6
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("parity", [0, 1])
-def test_sharded_wilson_eo_v3_matches_single_device(parity):
-    """Checkerboarded Wilson hop (the CG hot loop) under shard_map == the
-    single-device eo pair stencil, both parities (the policy the
-    reference's engine exists to serve, lib/dslash_policy.hpp:522)."""
-    from quda_tpu.fields.spinor import even_odd_split
-    from quda_tpu.ops.wilson import split_gauge_eo
-    from quda_tpu.parallel.pallas_dslash import dslash_eo_pallas_sharded_v3
-    if len(jax.devices()) != 8:
-        pytest.skip("needs the 8-device virtual mesh")
-    # partitioned local extents must be EVEN (local-coordinate masks)
-    geom = LatticeGeometry((4, 4, 8, 16))
-    T, Z, Y, X = geom.lattice_shape
-    dims = (T, Z, Y, X)
-    gauge = GaugeField.random(jax.random.PRNGKey(41), geom).data.astype(
-        jnp.complex64)
-    psi = ColorSpinorField.gaussian(jax.random.PRNGKey(42), geom
-                                    ).data.astype(jnp.complex64)
-    g_eo = split_gauge_eo(gauge, geom)
-    pe, po = even_odd_split(psi, geom)
-    src = pe if parity == 1 else po
-    g_eo_pp = tuple(wpk.to_packed_pairs(wpk.pack_gauge(g), jnp.float32)
-                    for g in g_eo)
-    src_pp = wpk.to_packed_pairs(wpk.pack_spinor(src), jnp.float32)
-    ref = wpk.dslash_eo_packed_pairs(g_eo_pp, src_pp, dims, parity)
-
-    mesh = make_lattice_mesh(grid=(4, 2, 1, 1), n_src=1)
-    psi_spec = P(None, None, None, "t", "z", None)
-    g_spec = P(None, None, None, None, "t", "z", None)
-    fn = jax.shard_map(
-        lambda uh, ut, p: dslash_eo_pallas_sharded_v3(
-            uh, ut, p, dims, parity, mesh, interpret=True),
-        mesh=mesh, in_specs=(g_spec, g_spec, psi_spec),
-        out_specs=psi_spec, check_vma=False)
-    uh_s = jax.device_put(g_eo_pp[parity], NamedSharding(mesh, g_spec))
-    ut_s = jax.device_put(g_eo_pp[1 - parity], NamedSharding(mesh, g_spec))
-    src_s = jax.device_put(src_pp, NamedSharding(mesh, psi_spec))
-    out = jax.jit(fn)(uh_s, ut_s, src_s)
     err = float(jnp.sqrt(blas.norm2(ref - out) / blas.norm2(ref)))
     assert err < 1e-6
 
@@ -390,35 +309,6 @@ def test_sharded_wilson_eo_v2_recon12_matches_single_device(parity):
 
 
 @pytest.mark.slow
-def test_sharded_wilson_eo_v3_recon12_matches_single_device():
-    """The v3 sharded form accepts reconstruct-12 too (the restriction
-    was on the sharded path as a whole, not one kernel form)."""
-    from quda_tpu.parallel.pallas_dslash import dslash_eo_pallas_sharded_v3
-    if len(jax.devices()) != 8:
-        pytest.skip("needs the 8-device virtual mesh")
-    parity = 0
-    dims, g_eo_pp, (pe, po) = _eo_fixture()
-    src_pp = wpk.to_packed_pairs(wpk.pack_spinor(po), jnp.float32)
-    ref = wpk.dslash_eo_packed_pairs(g_eo_pp, src_pp, dims, parity)
-    mesh = make_lattice_mesh(grid=(4, 2, 1, 1), n_src=1)
-    psi_spec = P(None, None, None, "t", "z", None)
-    g_spec = P(None, None, None, None, "t", "z", None)
-    uh = wpp.to_recon12(g_eo_pp[parity])
-    ut = wpp.to_recon12(g_eo_pp[1 - parity])
-    fn = jax.shard_map(
-        lambda a, b, p: dslash_eo_pallas_sharded_v3(
-            a, b, p, dims, parity, mesh, interpret=True),
-        mesh=mesh, in_specs=(g_spec, g_spec, psi_spec),
-        out_specs=psi_spec, check_vma=False)
-    out = jax.jit(fn)(jax.device_put(uh, NamedSharding(mesh, g_spec)),
-                      jax.device_put(ut, NamedSharding(mesh, g_spec)),
-                      jax.device_put(src_pp,
-                                     NamedSharding(mesh, psi_spec)))
-    err = float(jnp.sqrt(blas.norm2(ref - out) / blas.norm2(ref)))
-    assert err < 1e-5
-
-
-@pytest.mark.slow
 @pytest.mark.parametrize("parity", [0, 1])
 def test_sharded_wilson_eo_v2_fused_halo_matches_facefix(parity):
     """Policy A/B: the fused in-kernel RDMA slab exchange must be
@@ -453,7 +343,6 @@ def test_sharded_operator_defaults_to_v2_and_races_policy(tmp_path,
     from quda_tpu.utils import tune as qtune
     if len(jax.devices()) != 8:
         pytest.skip("needs the 8-device virtual mesh")
-    monkeypatch.delenv("QUDA_TPU_PALLAS_VERSION", raising=False)
     monkeypatch.delenv("QUDA_TPU_SHARDED_POLICY", raising=False)
     monkeypatch.setenv("QUDA_TPU_RESOURCE_PATH", str(tmp_path))
     qconf.reset_cache()
@@ -471,7 +360,6 @@ def test_sharded_operator_defaults_to_v2_and_races_policy(tmp_path,
                              devices=jax.devices()[:4])
     op = dpk.pairs(jnp.float32, use_pallas=True, pallas_interpret=True,
                    mesh=mesh)
-    assert op._pallas_version == 2          # measured winner, not v3
     won = op._sharded_policy_winner
     # round 18: the engine races PER AXIS — the winner is a full
     # {axis: policy} map with every partitioned axis raced and the

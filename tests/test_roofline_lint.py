@@ -38,10 +38,9 @@ def _wilson_ops():
     # the resident link row extent (3 vs 2) drives the _r12 suffix
     g18 = (np.zeros((4, 3, 3, 2, 2, 2, 4), np.float32),)
     g12 = (np.zeros((4, 2, 3, 2, 2, 2, 4), np.float32),)
-    for v, g, mesh in itertools.product((2, 3), (g18, g12),
-                                        (None, object())):
+    for g, mesh in itertools.product((g18, g12), (None, object())):
         yield _mk("DiracWilsonPCPackedPairs", use_pallas=True,
-                  _pallas_version=v, gauge_eo_pp=g, _mesh=mesh)
+                  gauge_eo_pp=g, _mesh=mesh)
     # precision storage forms (round 16): every (_precision_form,
     # store_dtype) pair the operator can serve single-chip must label
     # to a modeled row (int8 has gauge_eo_pp=None — the label path
@@ -53,7 +52,7 @@ def _wilson_ops():
         g = None if pform == "int8" else (
             g12[0:1] if pform in ("r12", "r12f") else g18)
         yield _mk("DiracWilsonPCPackedPairs", use_pallas=True,
-                  _pallas_version=2, gauge_eo_pp=g, _mesh=None,
+                  gauge_eo_pp=g, _mesh=None,
                   _precision_form=pform, store_dtype=store)
     yield _mk("DiracWilsonPCPackedPairs", use_pallas=False)
 

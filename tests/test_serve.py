@@ -50,8 +50,13 @@ def _serve_isolation(monkeypatch, tmp_path):
     monkeypatch.setenv("QUDA_TPU_METRICS", "1")
     monkeypatch.setenv("QUDA_TPU_PACKED", "1")
     omet.stop(flush_files=False)
-    omem.reset()
     otr.stop(flush_files=False)
+    # a session another file's test left open on this xdist worker would
+    # keep SolveService.start() from calling init_quda, and with it from
+    # opening the metrics registry these tests read: end it here
+    if api._ctx["initialized"]:
+        api.end_quda()
+    omem.reset()
     qconf.reset_cache()
     yield
     try:

@@ -502,16 +502,11 @@ def test_staggered_form_auto_resolves_without_race_off_chip():
     op2 = dpc_fat.pairs(jnp.float32, use_pallas=True,
                         pallas_interpret=True, form="auto")
     assert op2._pallas_form == "two_pass"
-    # legacy pallas_version kwarg still pins the generation
-    op3 = dpc.pairs(jnp.float32, use_pallas=True, pallas_interpret=True,
-                    pallas_version=3)
-    assert op3._pallas_form == "v3"
-    assert op3._pallas_version == 3
 
 
 def test_staggered_form_auto_races_via_tune(monkeypatch):
     """'auto' on chip goes through utils.tune over ALL applicable forms
-    (A/B'd, not assumed — v3 lost for Wilson) and honors the winner."""
+    (A/B'd, not assumed) and honors the winner."""
     from quda_tpu.utils import tune as qtune
     seen = {}
 

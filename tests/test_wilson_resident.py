@@ -20,7 +20,8 @@ from quda_tpu.fields.spinor import even_odd_join, even_odd_split
 from quda_tpu.interfaces import quda_api as api
 from quda_tpu.interfaces.params import GaugeParam, InvertParam
 from quda_tpu.models.wilson import (DiracWilson, DiracWilsonPC,
-                                    DiracWilsonPCPackedSloppy)
+                                    DiracWilsonPCPackedSloppy,
+                                    hop_route_knobs)
 from quda_tpu.obs import memory as omem
 from quda_tpu.obs import metrics as omet
 from quda_tpu.ops import wilson as wops
@@ -177,8 +178,8 @@ def quda(tmp_path_factory):
     asked for directly, so no kernel is interpreted and nothing solves."""
     mp = pytest.MonkeyPatch()
     mp.setenv("QUDA_TPU_PACKED", "1")
-    for knob in ("QUDA_TPU_PALLAS", "QUDA_TPU_PALLAS_VERSION",
-                 "QUDA_TPU_PRECISION_FORM", "QUDA_TPU_RECONSTRUCT"):
+    for knob in ("QUDA_TPU_PALLAS", "QUDA_TPU_PRECISION_FORM",
+                 "QUDA_TPU_RECONSTRUCT"):
         mp.delenv(knob, raising=False)
     qconf.reset_cache()
     api.init_quda()
@@ -223,8 +224,6 @@ CHANGES = {
     "pallas_route": ({}, {"QUDA_TPU_PALLAS": "1"}),
     "precision_form": ({}, {"QUDA_TPU_PALLAS": "1",
                             "QUDA_TPU_PRECISION_FORM": "r12"}),
-    "pallas_version": ({}, {"QUDA_TPU_PALLAS": "1",
-                            "QUDA_TPU_PALLAS_VERSION": "3"}),
 }
 
 
@@ -259,10 +258,34 @@ def test_term_is_kept_until_what_it_depends_on_changes(quda, what,
             assert op.matpc == ODD
         elif what == "pallas_route":
             assert op.use_pallas and op._pallas_interpret
-        elif what == "precision_form":
-            assert op._precision_form == "r12"
         else:
-            assert op._pallas_version == 3
+            assert op._precision_form == "r12"
+    finally:
+        monkeypatch.undo()
+        qconf.reset_cache()
+        api._drop_resident("wilson")
+
+
+def test_the_retired_version_knob_changes_nothing(quda, monkeypatch):
+    """There is one Wilson kernel generation: QUDA_TPU_PALLAS_VERSION is
+    not read any more, so setting it keeps the resident term, and no
+    version is part of the operator's program signature."""
+    api._drop_resident("wilson")
+    monkeypatch.setenv("QUDA_TPU_PALLAS", "1")
+    qconf.reset_cache()
+    try:
+        seen = _outcomes()
+        term, outcome = _ask(seen, _param())
+        assert outcome == "built"
+        monkeypatch.setenv("QUDA_TPU_PALLAS_VERSION", "3")
+        qconf.reset_cache()
+        again, outcome = _ask(seen, _param())
+        assert outcome == "reused" and again is term
+        op = term["ops"][jnp.dtype(jnp.float32)]
+        assert op.use_pallas and op._u_bw is not None
+        assert not hasattr(op, "_pallas_version")
+        assert len(hop_route_knobs()) == 2
+        assert "_pallas_version" not in op._PROGRAM_STATIC
     finally:
         monkeypatch.undo()
         qconf.reset_cache()
