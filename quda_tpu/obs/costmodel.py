@@ -21,7 +21,8 @@ checkable against two independent witnesses:
   than the data touched once (impossible); one above
   ``BYTES_REREAD_MAX`` x the floor claims more re-reading than any
   kernel form in this codebase performs (measured worst case: the
-  wilson MRHS model at 2.14x the floor at the n=4 probe point; the
+  twisted-mass MRHS model, five psi reads, at 2.14x the floor at the
+  n=4 probe point; the Wilson MRHS model, two, is at 1.29x; the
   deliberate-mistake fixtures in tests/test_costmodel.py pin that a
   factor-2 slip in either direction fails).
 
@@ -54,7 +55,7 @@ FLOPS_RTOL = 0.5
 # analytic bytes_per_site vs the operand-footprint floor: must be >= 1x
 # (cannot move less than the data once) and <= this re-read factor.
 # Measured ratios across the registered forms: 1.15 (staggered two-pass)
-# to 2.14 (wilson MRHS at the n=4 probe point); 2.5 leaves headroom
+# to 2.14 (twisted-mass MRHS at the n=4 probe point); 2.5 leaves headroom
 # while a factor-2 slip in either direction still fails (the
 # tests/test_costmodel.py fixtures pin both directions)
 BYTES_REREAD_MAX = 2.5
@@ -217,6 +218,7 @@ _FOOTPRINTS: Dict[str, dict] = {
                   "floor": lambda n: 2 * _G + 2 * _PSI},
     "wilson_v2_r12": {"family": "wilson",
                       "floor": lambda n: 2 * _G12 + 2 * _PSI},
+    # either route of the MRHS kernel: the floor counts each operand once
     "wilson_mrhs": {"family": "wilson",
                     "floor": lambda n: 2 * _G / n + 2 * _PSI},
     # precision storage forms (PERF.md round 16).  Floors are the

@@ -231,7 +231,8 @@ def sample(phase: str = "") -> List[dict]:
 # -- VMEM budget audit ------------------------------------------------------
 
 def vmem_audit(knob: str, block_bytes: int, budget_bytes: int,
-               bz: Optional[int] = None, single_buffered: bool = False):
+               bz: Optional[int] = None, single_buffered: bool = False,
+               route: Optional[str] = None):
     """Record one ``_pick_bz`` decision: selected single-buffer working
     set vs the knob's budget (ops/wilson_pallas_packed.py call sites).
     ``block_bytes`` is the PADDED tile working set — sublane rows at the
@@ -239,14 +240,19 @@ def vmem_audit(knob: str, block_bytes: int, budget_bytes: int,
     128 — so the audit charges what the block really occupies.
     ``single_buffered`` marks a full-block admission that only fits the
     scoped window once (the bf16/int8 bz=Z fallback): Mosaic cannot
-    double-buffer it, so the pipeline serialises."""
+    double-buffer it, so the pipeline serialises.  ``route`` names a
+    call that sets its own ``vmem_limit_bytes`` (the multi-RHS Wilson
+    kernel's full-Z route): ``budget_bytes`` is then that limit, which
+    holds the blocks twice, and the decision is kept beside the knob's,
+    under ``knob[route]``."""
+    key = knob if route is None else f"{knob}[{route}]"
     with _lock:
-        _vmem_last[knob] = {"block_bytes": int(block_bytes),
-                            "budget_bytes": int(budget_bytes), "bz": bz,
-                            "single_buffered": bool(single_buffered)}
+        _vmem_last[key] = {"block_bytes": int(block_bytes),
+                           "budget_bytes": int(budget_bytes), "bz": bz,
+                           "single_buffered": bool(single_buffered)}
     from . import metrics as omet
-    omet.set_gauge("vmem_block_bytes", block_bytes, knob=knob)
-    omet.set_gauge("vmem_budget_bytes", budget_bytes, knob=knob)
+    omet.set_gauge("vmem_block_bytes", block_bytes, knob=key)
+    omet.set_gauge("vmem_budget_bytes", budget_bytes, knob=key)
 
 
 def audit_vmem_budgets() -> List[dict]:
@@ -266,5 +272,17 @@ def audit_vmem_budgets() -> List[dict]:
             "last_block_bytes": last.get("block_bytes"),
             "last_bz": last.get("bz"),
             "last_single_buffered": last.get("single_buffered", False),
+        })
+    with _lock:
+        routed = {k: dict(v) for k, v in _vmem_last.items()
+                  if k not in VMEM_KNOBS}
+    for key in sorted(routed):
+        last = routed[key]
+        out.append({
+            "knob": key, "budget_mb": last["budget_bytes"] / 2 ** 20,
+            "double_buffer_ok": not last["single_buffered"],
+            "last_block_bytes": last["block_bytes"],
+            "last_bz": last["bz"],
+            "last_single_buffered": last["single_buffered"],
         })
     return out

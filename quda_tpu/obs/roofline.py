@@ -50,7 +50,7 @@ def device_peaks(device_kind: Optional[str] = None) -> Optional[dict]:
 
 # Per-site flops / bytes models (f32 pairs, per UPDATED site, one
 # operator application).  Sources: PERF.md round 2 (v2 traffic table),
-# round 4 (reconstruct-12), round 7 (MRHS 576 + 576/N), round 8
+# round 4 (reconstruct-12), PR 31 (MRHS 288 + 576/N), round 8
 # (staggered fat+Naik 1512 B).  ``bytes_per_site``
 # None = no credible traffic model for the form (no BW attribution).
 KERNEL_MODELS: Dict[str, dict] = {
@@ -60,9 +60,13 @@ KERNEL_MODELS: Dict[str, dict] = {
     # and the pre-shifted backward copy, built from the compressed
     # arrays) shrink 288 -> 192 B/site, so 1152 - 2*96
     "wilson_v2_r12": {"flops_per_site": 1320, "bytes_per_site": 960},
-    # MRHS v2: psi 480 + out 96 + gauge 576/N per RHS (nrhs-dependent)
+    # MRHS, the full-Z route with two time-slices a step
+    # (ops/wilson_pallas_packed._mrhs_route: what one chip's 24^4 runs):
+    # psi 2x96 + out 96 + gauge 576/N per RHS.  One slice a step reads
+    # psi three times (384 + 576/N), the z-blocked fallback of larger
+    # local volumes five (576 + 576/N); neither has a row of its own
     "wilson_mrhs": {"flops_per_site": 1320,
-                    "bytes_per_site": lambda nrhs: 576.0 + 576.0 / nrhs},
+                    "bytes_per_site": lambda nrhs: 288.0 + 576.0 / nrhs},
     # precision storage forms (PERF.md round 16).  r12f = r12 storage
     # + copy-free scatter backward on the gather psi path: gauge reads
     # are g_here 192 + g_there xyz 144 + g_t plane 48 = 384 — exactly
@@ -194,14 +198,14 @@ KERNEL_MODELS: Dict[str, dict] = {
         "bytes_per_site": lambda nrhs: 576.0 + 1152.0 / nrhs},
     # Ls-batched DWF/Möbius 4d hop (ops/dwf_pallas): per UPDATED 4d
     # site per dslash invocation with Ls baked in — Ls spinor planes
-    # (Ls x 576) stream through ONE gauge-tile fetch (576), i.e.
-    # 576 + 576/Ls per plane.  flops Ls x 1320.  Only Ls in {4, 8} get
-    # traffic rows: at Ls >= 12 the honest model (psi still read 5x per
-    # plane) exceeds the BYTES_REREAD_MAX re-read ceiling over the
-    # operand floor, so larger Ls report flops-only via 'dwf_pallas'
-    "dwf_ls4_pallas": {"flops_per_site": 5280, "bytes_per_site": 2880},
+    # stream through ONE gauge-tile fetch (576) on the MRHS kernel's
+    # full-Z route (two time-slices a step: psi read twice, 288 a
+    # plane), i.e. 288 + 576/Ls per plane.  flops Ls x 1320.  Only Ls
+    # in {4, 8} get traffic rows; other Ls report flops-only via
+    # 'dwf_pallas'
+    "dwf_ls4_pallas": {"flops_per_site": 5280, "bytes_per_site": 1728},
     "dwf_ls8_pallas": {"flops_per_site": 10560,
-                       "bytes_per_site": 5184},
+                       "bytes_per_site": 2880},
     # Ls outside the registered set: flops come from the operator
     # (flops_per_site override), no static traffic claim
     "dwf_pallas": {"flops_per_site": None, "bytes_per_site": None},

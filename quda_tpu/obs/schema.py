@@ -194,6 +194,13 @@ METRICS: dict[str, dict] = {
                 "outcome: 'built' nothing was resident, 'reused' the "
                 "resident operators served, 'rebuilt' another matpc, "
                 "boundary or kernel route replaced them"},
+    "wilson_mrhs_route_total": {
+        "type": COUNTER,
+        "help": "traced calls of the multi-RHS Wilson kernel "
+                "(ops/wilson_pallas_packed._mrhs_route) by route: "
+                "'fullz' whole-Z tiles, bt time-slices a step, each "
+                "spinor tile read (bt + 2) / bt times, 'zblock' z-blocks "
+                "with two z-neighbour tiles besides (five reads)"},
     # tuner warm-cache accounting (utils/tune.py)
     "tune_cache_hits_total": {
         "type": COUNTER,

@@ -8,9 +8,19 @@ models/domain_wall._LsPairIOMixin IS the (N, ...) MRHS layout of
 ops/wilson_pallas_packed.dslash_eo_pallas_packed_mrhs, whose gauge
 BlockSpec index maps ignore the batch index — so each gauge tile is
 fetched once per (t, z-block) while all Ls spinor planes stream
-through it: 576 + 576/Ls bytes per site per plane instead of the
-576 + 576 of a vmap-over-s launch (batch OUTERMOST, links re-fetched
-for every s plane).
+through it, instead of the links re-fetched for every s plane of a
+vmap-over-s launch (batch OUTERMOST).
+
+What a plane moves is the MRHS kernel's account (the comment above
+``_LeadAxisRef`` there): where two time-slices of whole (Z, YXh) tiles
+fit VMEM the full-Z route reads each spinor plane twice, 288 + 576/Ls
+bytes per site per plane (three times, 384 + 576/Ls, where only one
+slice fits); larger local volumes fall back to z-blocks and five
+reads, 576 + 576/Ls.  The route follows the shapes, Ls included
+nowhere: these wrappers pass ``block_z`` through and nothing else.  No
+cell runs a 5d operator: the chip reading of this seam is the Wilson
+batch's (PERF.md section 6, PR 31: eight planes 1,070 us at 24^4,
+twelve 1,518).
 
 The dense (Ls, Ls) m5 algebra (ops/dwf.py SOp blocks, applied as
 einsum GEMMs in models/domain_wall) stays in XLA: it is

@@ -179,14 +179,72 @@ def _shift_z(v, v_row, sign: int):
                  for c, n in zip(v, v_row))
 
 
+class _TileRef:
+    """Trace-time view of one time-slice ``t`` and rows [z0, z0 + rows)
+    of a pallas Ref whose block holds several time-slices of whole-Z
+    tiles, (..., BT, Z, YX): the kernel body indexes it like a block of
+    one slice and that many rows (its last index, the in-block time
+    index 0, becomes ``t``).  The full-Z route walks its block so."""
+
+    def __init__(self, ref, t: int, z0, rows: int):
+        from jax.experimental import pallas as pl
+        self._ref, self._rows = ref, rows
+        self._tz = (t, pl.ds(z0, rows), slice(None))
+
+    @property
+    def shape(self):
+        sh = tuple(self._ref.shape)
+        return sh[:-3] + (1, self._rows, sh[-1])
+
+    @property
+    def dtype(self):
+        return self._ref.dtype
+
+    def __getitem__(self, idx):
+        return self._ref[tuple(idx)[:-1] + self._tz]
+
+    def __setitem__(self, idx, val):
+        self._ref[tuple(idx)[:-1] + self._tz] = val
+
+
+class _EitherRef:
+    """Trace-time view that reads ``a`` where the scalar ``pred`` holds
+    and ``b`` where it does not (two views of one shape; both are
+    loaded, the value is selected): the full-Z body's t neighbour, a
+    slice of its own block or the single-slice operand, by the slice
+    the loop is at."""
+
+    def __init__(self, pred, a, b):
+        self._pred, self._a, self._b = pred, a, b
+        self.shape, self.dtype = a.shape, a.dtype
+
+    def __getitem__(self, idx):
+        return jnp.where(self._pred, self._a[idx], self._b[idx])
+
+
 def _make_kernel(X: int, bz: int, eo: tuple | None = None,
-                 T: int | None = None, tb_sign: bool = True):
+                 T: int | None = None, tb_sign: bool = True,
+                 z_rows: str = "tiles"):
     """Kernel over one (t, z-block) tile.  Ref shapes (leading block dims
     of 1 squeezed by indexing; R = 3 link rows for full storage, 2 for
     reconstruct-12):
       psi refs:            (4, 3, 2, 1, BZ, YX) x5 (c, t+1, t-1, z+1, z-1)
       g_c / g_m refs:      (4, R, 3, 2, 1, BZ, YX)  (forward / pre-shifted
                            backward links)
+    ``z_rows`` says where the z-boundary rows come from: ``"tiles"``,
+    the two z-neighbour tiles above; ``"centre"``, the centre block
+    itself: the refs then span the whole Z extent, the centre, link and
+    out blocks hold BT time-slices, (.., BT, Z, YX), and the kernel
+    takes three psi refs: c, and the single slices after and before
+    the block's (t+BT, t-1).  It walks the block slice by slice in
+    chunks of BZ rows, each chunk the body of a z-block whose z
+    neighbours are the wrapped neighbouring chunks and whose t
+    neighbours are the block's own slices where it has them.  The body
+    is traced once, in one loop over (slice, chunk): with BT > 1 a t
+    neighbour is loaded from both places and selected by the slice
+    (``_EitherRef``); unrolling the slices instead doubles what every
+    process lowers for 5 % of the kernel's time (PERF.md section 6,
+    PR 31).  Same values per chunk, same hop algebra: the two bit-match.
     With ``eo = (target_parity, Xh)`` the tile is a checkerboarded half
     lattice (fused axis Y*Xh) and x shifts use the slot-parity select of
     wilson_packed.shift_eo_packed; g_c/g_m are then the target-parity
@@ -197,16 +255,19 @@ def _make_kernel(X: int, bz: int, eo: tuple | None = None,
     """
     from jax.experimental import pallas as pl
 
-    def kernel(psi_c, psi_tp, psi_tm, psi_zp, psi_zm, g_c, g_m, out_ref):
+    def kernel(psi_c, psi_tp, psi_tm, psi_zp, psi_zm, g_c, g_m, out_ref,
+               z0=None, t_id=None):
+        # z0 / t_id: the tile's first z row and its time-slice, where the
+        # caller knows them better than the grid does (centre_kernel)
         if eo is not None:
             parity, Xh = eo
-            t_id = pl.program_id(0)
-            zb_id = pl.program_id(1)
+            t_eo = pl.program_id(0) if t_id is None else t_id
+            zb_id = pl.program_id(1) if z0 is None else None
             shape = psi_c.shape[-2:]
             z = (jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-                 + zb_id * bz)
+                 + (zb_id * bz if z0 is None else z0))
             y = jax.lax.broadcasted_iota(jnp.int32, shape, 1) // Xh
-            mask_r0 = ((t_id + z + y + parity) % 2) == 0
+            mask_r0 = ((t_eo + z + y + parity) % 2) == 0
 
         def shift_x(v, sign):
             if eo is None:
@@ -225,7 +286,7 @@ def _make_kernel(X: int, bz: int, eo: tuple | None = None,
         # reconstruct-12 t-boundary sign planes (None for full storage /
         # periodic t)
         if g_c.shape[1] == 2 and tb_sign:
-            t_idx = pl.program_id(0)
+            t_idx = pl.program_id(0) if t_id is None else t_id
             s_t_fwd = jnp.where(t_idx == T - 1, -1.0, 1.0).astype(F32)
             s_t_bwd = jnp.where(t_idx == 0, -1.0, 1.0).astype(F32)
         else:
@@ -303,7 +364,44 @@ def _make_kernel(X: int, bz: int, eo: tuple | None = None,
                 out_ref[s, c, 0, 0] = acc[s][c][0].astype(odt)
                 out_ref[s, c, 1, 0] = acc[s][c][1].astype(odt)
 
-    return kernel
+    def centre_kernel(psi_c, psi_tp, psi_tm, g_c, g_m, out_ref):
+        bt, Z = psi_c.shape[-3:-1]
+        nzc = Z // bz
+        t0 = pl.program_id(0) * bt    # not inside the loop's body
+
+        def chunk(k, carry):
+            # one trace of the body serves every slice and chunk
+            i = k if nzc == 1 else jax.lax.div(k, nzc)
+            zc = 0 if nzc == 1 else jax.lax.rem(k, nzc)
+
+            def at(ref, t, dz=0):
+                z0 = 0 if nzc == 1 else pl.multiple_of(
+                    jax.lax.rem(zc + dz + nzc, nzc) * bz, bz)
+                return _TileRef(ref, t, z0, bz)
+            if bt == 1:
+                up, dn = at(psi_tp, 0), at(psi_tm, 0)
+            else:
+                up = _EitherRef(i + 1 < bt,
+                                at(psi_c, jnp.minimum(i + 1, bt - 1)),
+                                at(psi_tp, 0))
+                dn = _EitherRef(i > 0, at(psi_c, jnp.maximum(i - 1, 0)),
+                                at(psi_tm, 0))
+            kernel(at(psi_c, i), up, dn, at(psi_c, i, +1), at(psi_c, i, -1),
+                   at(g_c, i), at(g_m, i), at(out_ref, i),
+                   z0=zc * bz, t_id=t0 + i)
+            return carry
+        if bt * nzc == 1:
+            chunk(0, 0)
+        else:
+            jax.lax.fori_loop(0, bt * nzc, chunk, 0)
+
+    return centre_kernel if z_rows == "centre" else kernel
+
+
+def _sublane_rows(dtype) -> int:
+    """Rows of the dtype's (sublane, 128) tile: (8,128) f32, (16,128)
+    bf16, (32,128) int8."""
+    return {4: 8, 2: 16, 1: 32}[jnp.dtype(dtype).itemsize]
 
 
 def _pick_bz(Z: int, YX: int, dtype=jnp.float32, planes: int = 288,
@@ -350,7 +448,7 @@ def _pick_bz(Z: int, YX: int, dtype=jnp.float32, planes: int = 288,
     # sublane tile rows by itemsize: (8,128) f32, (16,128) bf16,
     # (32,128) int8 — the audit must charge the PADDED tile, not the
     # logical rows (a bf16 bz=24 block really holds 32 sublanes)
-    sub = {4: 8, 2: 16, 1: 32}[jnp.dtype(dtype).itemsize]
+    sub = _sublane_rows(dtype)
     nbytes = jnp.dtype(dtype).itemsize
     yx_pad = -(-YX // 128) * 128
     from ..utils import config as qconf
@@ -452,23 +550,39 @@ def dslash_pallas_packed(gauge_pl: jnp.ndarray, psi_pl: jnp.ndarray,
 # -- multi-RHS (MRHS) variants of the v2 kernels ---------------------------
 #
 # Production workloads (propagator inversions, RHMC pseudofermions, MG
-# setup solves) apply the SAME gauge field to many right-hand sides; the
-# single-RHS v2 kernel re-reads 576 B/site of links per RHS — half its
-# ~1,152 B/site traffic (QUDA's multi-RHS batching motivation,
-# arXiv:1408.5925 §5 / the src_idx kernel dimension).  The MRHS form
-# keeps the v2 kernel body BIT-IDENTICAL per RHS and changes only the
-# pipeline: grid (T, Z/bz, N) with the RHS axis INNERMOST, psi/out
-# BlockSpecs carrying a leading size-1 RHS block, and gauge BlockSpecs
-# whose index map ignores n — consecutive grid steps then present the
-# same gauge block index, so the Mosaic pipeline keeps the tile resident
-# instead of re-fetching it, and N spinor tiles stream through one gauge
-# load.  Projected per-RHS traffic: psi 480 + out 96 + gauge 1152/(2N)
-# B/site -> ~648 B/site at N=8, ~1.7x per-RHS throughput if the HBM
-# bound holds (measure on chip: bench_suite MRHS rows).
+# setup solves) apply the SAME gauge field to many right-hand sides.  The
+# MRHS form keeps the kernel body BIT-IDENTICAL per RHS and changes only
+# the pipeline: the RHS axis is the INNERMOST grid axis, psi/out
+# BlockSpecs carry a leading size-1 RHS block, and the gauge BlockSpecs'
+# index maps ignore n, so consecutive grid steps present the same gauge
+# block index and Mosaic keeps the tile resident: N spinor tiles stream
+# through one gauge load (576 B a site, once).
 #
-# The per-step VMEM working set is UNCHANGED (one RHS's tiles + the two
-# gauge tiles), so _pick_bz and the z-block legality rules carry over
-# as-is.
+# A batch of spinors does not sit on chip the way XLA keeps a single
+# source's in a CG loop (8 x 16 MB at 24^4), and with N innermost no two
+# consecutive steps share a psi block index: every psi operand of every
+# step is a fresh DMA from HBM, and the kernel's time follows the bytes
+# it moves (PERF.md section 5, "bytes moved against time").  Per output
+# site and call, f32, N sources:
+#
+#   route         psi operands            moved                N=8    needed
+#   zblock        c, t+1, t-1, z+1, z-1   576 + N (480 + 96)   5,184  2,112
+#   fullz, bt 1   c, t+1, t-1             576 + N (288 + 96)   3,648  2,112
+#   fullz, bt 2   c (2 slices), t+2, t-1  576 + N (192 + 96)   2,880  2,112
+#
+# The z-blocked route DMAs a whole z-neighbour tile for the one row the
+# body splices from it; at 24^4 (bz 8, 576 grid steps of 9 KB planes)
+# the cell wilson24_mrhs8.light read 1,643 us a call, 26 % of the
+# needed-bytes roofline (ledger, PR 30).  The full-Z route holds whole
+# (Z, YX) tiles in VMEM, takes its z rows from the centre tile, its t
+# neighbours from the block's own slices where it has them, and walks
+# the block in chunks of one sublane tile, so the body's values stay
+# the z-blocked body's size (the compiler's schedule for the described
+# v5e: 1,937 bundles a chunk against 2,170 a z-block step).  With one
+# time-slice a step the cell read 1,200 us, 35.6 % (PERF.md section 6,
+# PR 31, which has the reading with two as well).  The planes' 288
+# lanes are stored as 384 in HBM and in VMEM, so 75 % of the
+# needed-bytes roofline is the most this layout can read.
 
 
 class _LeadAxisRef:
@@ -511,6 +625,139 @@ def _mrhs_wrap(kernel, n_psi: int = 5):
     return wrapped
 
 
+# What the full-Z route may ask of a core's VMEM: three eighths of a
+# v5e core's 128 MiB (Mosaic's scoped default is
+# obs/memory.SCOPED_VMEM_MB = 16).  The call sets ``vmem_limit_bytes``
+# to what it needs and runs only where that stays under this cap; 24^4
+# in f32 needs 21.9 MiB with one time-slice a step and 35.2 with two.
+_MRHS_FULLZ_VMEM_CAP = 48 * 2 ** 20
+
+
+def _mrhs_fullz_vmem(Z: int, YX: int, dtype, out_dtype, R: int,
+                     bt: int = 1):
+    """(block_bytes, need_bytes) of one full-Z MRHS step of ``bt``
+    time-slices.  Blocks: bt + 2 psi tiles (24 planes each: the block's
+    slices and the one after and before them), bt forward and backward
+    link tiles (24 R each) and bt out tiles (24), every (Z, YX) plane
+    padded to its dtype's (sublane, 128) tile as ``_pick_bz`` pads it.
+    Need: the blocks double-buffered by the pipeline plus the body's
+    own f32 tiles, which live in VMEM, not in vregs (accumulators, the
+    loaded spinor, the hop's temporaries): six spinors' worth of full-Z
+    planes.  The described-v5e compiles of 24^4 f32 ask for five where
+    the body works on the whole tile (17.72 MiB with 13.5 of blocks at
+    R = 2, bt = 1) and for 2.0 MiB where it walks 8-row chunks (18.85
+    MiB with 16.9 of blocks at R = 3, bt = 1)."""
+    yx_pad = -(-YX // 128) * 128
+
+    def plane(dt):
+        sub = _sublane_rows(dt)
+        return -(-Z // sub) * sub * yx_pad * jnp.dtype(dt).itemsize
+
+    blocks = (((bt + 2) * 24 + 2 * 24 * R * bt) * plane(dtype)
+              + bt * 24 * plane(out_dtype))
+    return blocks, 2 * blocks + 6 * 24 * plane(F32)
+
+
+def _fullz_chunk(Z: int, dtype) -> int:
+    """Rows the full-Z body works on at a time: one sublane tile of the
+    storage dtype (8 of f32, 16 of bf16), so that its values are the
+    z-blocked body's three vregs a plane and not Z/8 times as many (24
+    accumulator planes alone are 72 of the 64 vregs at one tile);
+    the whole tile where Z is no multiple of it (24 rows of bf16)."""
+    sub = _sublane_rows(dtype)
+    return sub if Z % sub == 0 else Z
+
+
+def _mrhs_route(T: int, Z: int, YX: int, dtype, out_dtype, R: int,
+                block_z: int | None):
+    """(route, bz, bt, vmem_limit_bytes) of an MRHS call, from its
+    shapes.
+
+    ``"fullz"``: one tile spans Z, the z shift wraps inside it and the
+    call has three psi operands; taken where its VMEM need fits
+    ``_MRHS_FULLZ_VMEM_CAP``, with two time-slices a step (``bt``)
+    where they fit too and T is even: the block's own slices are then
+    each other's t neighbours, and a spinor tile is read (bt + 2) / bt
+    times.  ``"zblock"``: ``_pick_bz``'s z-block and the five psi
+    operands of the single-RHS kernel, within the scoped default; taken
+    where full-Z does not fit or a caller's ``block_z`` asks for
+    z-blocks.  Recorded at trace time: the VMEM audit gets the full-Z
+    route's blocks and limit (``_pick_bz`` records the z-block's),
+    ``wilson_mrhs_route_total`` counts the call by route."""
+    from ..obs import memory as omem
+    from ..obs import metrics as omet
+    fits = [(bt,) + _mrhs_fullz_vmem(Z, YX, dtype, out_dtype, R, bt)
+            for bt in (2, 1) if T % bt == 0]
+    fits = [f for f in fits if f[2] <= _MRHS_FULLZ_VMEM_CAP]
+    if block_z in (None, Z) and fits:
+        route, bz = "fullz", Z
+        bt, blocks, need = fits[0]
+        limit = max(need, int(omem.SCOPED_VMEM_MB * 2 ** 20))
+        omem.vmem_audit("QUDA_TPU_PALLAS_VMEM_MB", blocks, limit, bz=Z,
+                        route="fullz")
+    else:
+        route, bt, limit = "zblock", 1, None
+        bz = block_z if block_z is not None else _pick_bz(
+            Z, YX, dtype, planes=288 if R == 3 else 240)
+        if Z % bz != 0:
+            raise ValueError(f"block_z={bz} does not divide Z={Z}")
+    omet.inc("wilson_mrhs_route_total", route=route)
+    return route, bz, bt, limit
+
+
+def _mrhs_hop(g_c, g_m, psi_pl, X: int, eo, tb_sign: bool,
+              block_z, out_dtype, interpret: bool):
+    """The MRHS pallas_call shared by the full-lattice and the eo
+    wrapper: grid (T/bt, Z/bz, N), RHS innermost, links indexed by
+    (t, zb) alone."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    N, _, _, _, T, Z, YX = psi_pl.shape
+    R = g_c.shape[1]
+    out_dtype = out_dtype or psi_pl.dtype
+    route, bz, bt, vmem_limit = _mrhs_route(T, Z, YX, psi_pl.dtype,
+                                            out_dtype, R, block_z)
+    nzb = Z // bz
+
+    def psi_block(tb, zb, n):
+        return (n, 0, 0, 0, tb, zb, 0)
+
+    def psi_slice(dt, dz=0):
+        # one time-slice, dt slices off the block's first: its block
+        # index on the t axis is the slice itself
+        return pl.BlockSpec(
+            (1, 4, 3, 2, 1, bz, YX),
+            lambda tb, zb, n: (n, 0, 0, 0, (tb * bt + dt) % T,
+                               (zb + dz) % nzb, 0))
+
+    # gauge index maps ignore n: the block index repeats across the
+    # innermost RHS loop, so the pipeline re-uses the resident tile
+    gauge_spec = pl.BlockSpec(
+        (4, R, 3, 2, bt, bz, YX), lambda tb, zb, n: (0, 0, 0, 0, tb, zb, 0))
+
+    psi_specs = [pl.BlockSpec((1, 4, 3, 2, bt, bz, YX), psi_block),
+                 psi_slice(bt), psi_slice(T - 1)]
+    if route == "fullz":
+        body_rows, z_rows = _fullz_chunk(Z, psi_pl.dtype), "centre"
+    else:
+        body_rows, z_rows = bz, "tiles"
+        psi_specs += [psi_slice(0, +1), psi_slice(0, -1)]
+    kernel = _mrhs_wrap(
+        _make_kernel(X, body_rows, eo=eo, T=T, tb_sign=tb_sign,
+                     z_rows=z_rows), n_psi=len(psi_specs))
+
+    return pl.pallas_call(
+        kernel,
+        grid=(T // bt, nzb, N),
+        in_specs=psi_specs + [gauge_spec, gauge_spec],
+        out_specs=pl.BlockSpec((1, 4, 3, 2, bt, bz, YX), psi_block),
+        out_shape=jax.ShapeDtypeStruct(psi_pl.shape, out_dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
+        interpret=interpret,
+    )(*([psi_pl] * len(psi_specs)), g_c, g_m)
+
+
 @functools.partial(jax.jit,
                    static_argnames=("X", "interpret", "block_z",
                                     "tb_sign"))
@@ -527,42 +774,10 @@ def dslash_pallas_packed_mrhs(gauge_pl: jnp.ndarray, psi_pl: jnp.ndarray,
     (same kernel body per grid step); the gauge tiles are loaded once
     per (t, z-block) and amortised over all N RHS by grid ordering.
     """
-    from jax.experimental import pallas as pl
-
-    N, _, _, _, T, Z, YX = psi_pl.shape
-    R = gauge_pl.shape[1]
-    bz = block_z if block_z is not None else _pick_bz(
-        Z, YX, psi_pl.dtype, planes=288 if R == 3 else 240)
-    if Z % bz != 0:
-        raise ValueError(f"block_z={bz} does not divide Z={Z}")
-    nzb = Z // bz
     if gauge_bw is None:
         gauge_bw = backward_gauge(gauge_pl, X)
-
-    def psi_spec(dt, dz):
-        return pl.BlockSpec(
-            (1, 4, 3, 2, 1, bz, YX),
-            lambda t, zb, n, dt=dt, dz=dz: (n, 0, 0, 0, (t + dt) % T,
-                                            (zb + dz) % nzb, 0))
-
-    # gauge index maps ignore n: the block index repeats across the
-    # innermost RHS loop, so the pipeline re-uses the resident tile
-    gauge_spec = pl.BlockSpec(
-        (4, R, 3, 2, 1, bz, YX), lambda t, zb, n: (0, 0, 0, 0, t, zb, 0))
-
-    kernel = _mrhs_wrap(_make_kernel(X, bz, T=T, tb_sign=tb_sign))
-
-    return pl.pallas_call(
-        kernel,
-        grid=(T, nzb, N),
-        in_specs=[psi_spec(0, 0), psi_spec(+1, 0), psi_spec(-1, 0),
-                  psi_spec(0, +1), psi_spec(0, -1), gauge_spec,
-                  gauge_spec],
-        out_specs=pl.BlockSpec((1, 4, 3, 2, 1, bz, YX),
-                               lambda t, zb, n: (n, 0, 0, 0, t, zb, 0)),
-        out_shape=jax.ShapeDtypeStruct(psi_pl.shape, psi_pl.dtype),
-        interpret=interpret,
-    )(psi_pl, psi_pl, psi_pl, psi_pl, psi_pl, gauge_pl, gauge_bw)
+    return _mrhs_hop(gauge_pl, gauge_bw, psi_pl, X, None, tb_sign,
+                     block_z, None, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("dims", "target_parity",
@@ -581,45 +796,12 @@ def dslash_eo_pallas_packed_mrhs(u_here_pl: jnp.ndarray,
 
     u_here_pl/u_bw_pl as in the single-RHS eo kernel; psi_pl:
     (N,4,3,2,T,Z,Y*Xh) of parity 1-p.  Gauge tiles are fetched once per
-    (t, z-block) and shared by all N RHS (RHS-innermost grid)."""
-    from jax.experimental import pallas as pl
-
-    T, Z, Y, X = dims
-    Xh = X // 2
-    N = psi_pl.shape[0]
-    R = u_here_pl.shape[1]
-    YXh = psi_pl.shape[-1]
-    bz = block_z if block_z is not None else _pick_bz(
-        Z, YXh, psi_pl.dtype, planes=288 if R == 3 else 240)
-    if Z % bz != 0:
-        raise ValueError(f"block_z={bz} does not divide Z={Z}")
-    nzb = Z // bz
-
-    def psi_spec(dt, dz):
-        return pl.BlockSpec(
-            (1, 4, 3, 2, 1, bz, YXh),
-            lambda t, zb, n, dt=dt, dz=dz: (n, 0, 0, 0, (t + dt) % T,
-                                            (zb + dz) % nzb, 0))
-
-    gauge_spec = pl.BlockSpec(
-        (4, R, 3, 2, 1, bz, YXh),
-        lambda t, zb, n: (0, 0, 0, 0, t, zb, 0))
-
-    kernel = _mrhs_wrap(_make_kernel(X, bz, eo=(target_parity, Xh),
-                                     T=T, tb_sign=tb_sign))
-
-    return pl.pallas_call(
-        kernel,
-        grid=(T, nzb, N),
-        in_specs=[psi_spec(0, 0), psi_spec(+1, 0), psi_spec(-1, 0),
-                  psi_spec(0, +1), psi_spec(0, -1), gauge_spec,
-                  gauge_spec],
-        out_specs=pl.BlockSpec((1, 4, 3, 2, 1, bz, YXh),
-                               lambda t, zb, n: (n, 0, 0, 0, t, zb, 0)),
-        out_shape=jax.ShapeDtypeStruct(psi_pl.shape,
-                                       out_dtype or psi_pl.dtype),
-        interpret=interpret,
-    )(psi_pl, psi_pl, psi_pl, psi_pl, psi_pl, u_here_pl, u_bw_pl)
+    (t, z-block) and shared by all N RHS (RHS-innermost grid); the
+    route (full-Z tiles or z-blocks) follows the shapes, ``_mrhs_route``.
+    """
+    X = dims[3]
+    return _mrhs_hop(u_here_pl, u_bw_pl, psi_pl, X, (target_parity, X // 2),
+                     tb_sign, block_z, out_dtype, interpret)
 
 
 # -- hop algebra shared by the kernel bodies ---------------------------------

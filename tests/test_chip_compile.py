@@ -61,11 +61,18 @@ def _eo(dt, rows=3):
             [_links(dt, rows), _links(dt, rows), _psi(dt)])
 
 
-def _eo_mrhs(n):
+def _eo_mrhs(n, dt=F32, block_z=None):
+    """The MRHS kernel as the shapes route it: full-Z tiles at 24^4
+    (three psi operands, two time-slices a step, its own
+    ``vmem_limit_bytes``; 24 rows of bf16 pad to 32 sublanes, so that
+    VMEM sum is another); ``block_z = 8`` keeps the z-blocked fallback
+    compiled for the chip."""
     from quda_tpu.ops import wilson_pallas_packed as wpp
+    want = "zblock" if block_z else "fullz"
+    assert wpp._mrhs_route(L, L, YXH, dt, dt, 3, block_z)[0] == want
     return (lambda u, ub, p: wpp.dslash_eo_pallas_packed_mrhs(
-                u, ub, p, DIMS, 0),
-            [_links(F32), _links(F32), _psi(F32, (n,))])
+                u, ub, p, DIMS, 0, block_z=block_z),
+            [_links(dt), _links(dt), _psi(dt, (n,))])
 
 
 def _cg_update(dt):
@@ -117,6 +124,8 @@ CASES = {
     "wilson_eo_v2_bf16": lambda: _eo(BF16),
     "wilson_eo_v2_recon12": lambda: _eo(F32, rows=2),
     "wilson_eo_mrhs_n8": lambda: _eo_mrhs(8),
+    "wilson_eo_mrhs_n8_bf16": lambda: _eo_mrhs(8, BF16),
+    "wilson_eo_mrhs_n8_zblock": lambda: _eo_mrhs(8, block_z=8),
     "cg_update_norm2_f32": lambda: _cg_update(F32),
     "cg_update_norm2_bf16": lambda: _cg_update(BF16),
     "axpy_norm2_f32": _axpy_norm2,

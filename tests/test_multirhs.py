@@ -488,6 +488,136 @@ def test_mrhs_eo_kernel_bitmatches_all_parities(parity):
         assert bool(jnp.all(got == want)), (parity, nrhs)
 
 
+# -- the MRHS kernel's two routes (ops/wilson_pallas_packed._mrhs_route) ---
+#
+# Full-Z tiles (three psi operands, the z shift wraps inside the tile,
+# two time-slices a step) where their VMEM set fits, z-blocks (five)
+# where it does not; both bitwise the single-source kernel per RHS.
+# The single-source side is held to block_z = 8, so it really splices
+# rows of its z-neighbour tiles; T = 4 so that a block's t neighbours
+# are its own slice on one side and another block's on the other.
+
+def _fz_dims(dtype, z_tiles=2):
+    """(T, Z, Y, X) with Z = ``z_tiles`` sublane tiles of the storage
+    dtype: two, and the full-Z body walks two chunks; one, and it works
+    on the whole tile (as 24 rows of bf16 make it at 24^4)."""
+    return (4, z_tiles * (8 if dtype == jnp.float32 else 16), 2, 4)
+
+
+def _eo_mrhs_problem(dims, parity, nrhs, dtype, seed=8):
+    from quda_tpu.ops import wilson_pallas_packed as wpp
+    T, Z, Y, X = dims
+    rng = np.random.default_rng(seed)
+
+    def draw(shape):
+        return jnp.asarray(rng.standard_normal(shape),
+                           jnp.float32).astype(dtype)
+    half = (T, Z, Y * X // 2)
+    u_here = draw((4, 3, 3, 2) + half)
+    u_bw = wpp.backward_gauge_eo(draw((4, 3, 3, 2) + half), dims, parity)
+    return u_here, u_bw, draw((nrhs, 4, 3, 2) + half)
+
+
+@pytest.fixture
+def route_counts(tmp_path):
+    """A metrics session of the test's own; calling the fixture reads
+    ``wilson_mrhs_route_total`` as {route: count}."""
+    from quda_tpu.obs import memory as omem
+    from quda_tpu.obs import metrics as omet
+    omet.stop(flush_files=False)
+    omem.reset()
+    omet.start(str(tmp_path))
+    yield lambda: {dict(lab)["route"]: v for (n, lab), v in
+                   omet.snapshot()["counters"].items()
+                   if n == "wilson_mrhs_route_total"}
+    omet.stop(flush_files=False)
+    omem.reset()
+
+
+@pytest.mark.parametrize("parity,nrhs,dtype,z_tiles", [
+    (0, 2, jnp.float32, 2), (1, 8, jnp.float32, 2),
+    (0, 8, jnp.bfloat16, 2), (1, 2, jnp.bfloat16, 2),
+    pytest.param(1, 2, jnp.float32, 2, marks=pytest.mark.slow),
+    pytest.param(0, 8, jnp.float32, 1, marks=pytest.mark.slow),
+    pytest.param(1, 8, jnp.bfloat16, 1, marks=pytest.mark.slow),
+    pytest.param(0, 2, jnp.bfloat16, 2, marks=pytest.mark.slow)])
+def test_mrhs_fullz_route_bitmatches_single_source(parity, nrhs, dtype,
+                                                   z_tiles, route_counts):
+    """The full-Z route is bitwise ``jax.vmap`` of the z-blocked
+    single-source kernel, and ``wilson_mrhs_route_total`` counts it once
+    per traced call (a second call of the same shapes traces nothing)."""
+    from quda_tpu.ops import wilson_pallas_packed as wpp
+    dims = _fz_dims(dtype, z_tiles)
+    u_here, u_bw, psi_b = _eo_mrhs_problem(dims, parity, nrhs, dtype)
+    want = jax.vmap(lambda p: wpp.dslash_eo_pallas_packed(
+        u_here, u_bw, p, dims, parity, interpret=True,
+        block_z=8))(psi_b)
+    got = wpp.dslash_eo_pallas_packed_mrhs(
+        u_here, u_bw, psi_b, dims, parity, interpret=True)
+    again = wpp.dslash_eo_pallas_packed_mrhs(
+        u_here, u_bw, psi_b, dims, parity, interpret=True)
+    assert got.dtype == dtype
+    assert bool(jnp.all(got == want)) and bool(jnp.all(again == want))
+    assert route_counts() == {"fullz": 1.0}
+
+
+def test_mrhs_zblock_route_runs_where_fullz_does_not_fit(route_counts):
+    """A large tile (Z = 40, Y*Xh = 640: 60.9 MiB of full-Z blocks,
+    one time-slice a step, against the 48 MiB the route may ask for)
+    sends the call to the z-blocked five-operand route, from its shapes
+    alone; it is still bitwise the single-source kernel and counted as
+    ``zblock``."""
+    from quda_tpu.ops import wilson_pallas_packed as wpp
+    dims = (2, 40, 32, 40)
+    assert wpp._mrhs_fullz_vmem(40, 640, jnp.float32, jnp.float32, 3)[1] \
+        > wpp._MRHS_FULLZ_VMEM_CAP
+    u_here, u_bw, psi_b = _eo_mrhs_problem(dims, 0, 2, jnp.float32)
+    want = jax.vmap(lambda p: wpp.dslash_eo_pallas_packed(
+        u_here, u_bw, p, dims, 0, interpret=True))(psi_b)
+    got = wpp.dslash_eo_pallas_packed_mrhs(
+        u_here, u_bw, psi_b, dims, 0, interpret=True)
+    assert bool(jnp.all(got == want))
+    assert route_counts() == {"zblock": 1.0}
+
+
+@pytest.mark.parametrize("case,want", [
+    # (T, Z, YX, storage, out, link rows R, block_z) -> (route, bz, bt)
+    ((24, 24, 288, jnp.float32, jnp.float32, 3, None), ("fullz", 24, 2)),
+    ((24, 24, 288, jnp.bfloat16, jnp.bfloat16, 3, None), ("fullz", 24, 2)),
+    ((24, 24, 288, jnp.bfloat16, jnp.float32, 3, None), ("fullz", 24, 2)),
+    ((24, 24, 288, jnp.float32, jnp.float32, 2, None), ("fullz", 24, 2)),
+    ((24, 24, 288, jnp.float32, jnp.float32, 3, 24), ("fullz", 24, 2)),
+    ((24, 24, 288, jnp.float32, jnp.float32, 3, 8), ("zblock", 8, 1)),
+    ((9, 24, 288, jnp.float32, jnp.float32, 3, None), ("fullz", 24, 1)),
+    ((32, 32, 512, jnp.float32, jnp.float32, 3, None), ("fullz", 32, 1)),
+    ((32, 32, 512, jnp.bfloat16, jnp.bfloat16, 3, None), ("fullz", 32, 2)),
+    ((40, 40, 640, jnp.float32, jnp.float32, 3, None), ("zblock", 8, 1)),
+    ((8, 8, 8, jnp.float32, jnp.float32, 3, None), ("fullz", 8, 2))])
+def test_mrhs_route_follows_the_shapes(case, want, route_counts):
+    """The route is arithmetic on (T, Z, YX, dtypes, R): padded planes x
+    bytes, twice for the pipeline's buffers, plus the body's tiles,
+    against the limit the call sets: two time-slices a step where they
+    fit and T is even, one where only that fits, z-blocks where neither
+    does; a caller's ``block_z`` wins.  The full-Z call's
+    ``vmem_limit_bytes`` holds what it computed, and the VMEM audit
+    keeps the route's blocks beside the knob's own row."""
+    from quda_tpu.obs import memory as omem
+    from quda_tpu.ops import wilson_pallas_packed as wpp
+    T, Z, YX, dt, odt, R, block_z = case
+    route, bz, bt, limit = wpp._mrhs_route(T, Z, YX, dt, odt, R, block_z)
+    rows = {r["knob"]: r for r in omem.audit_vmem_budgets()}
+    assert (route, bz, bt) == want and route_counts() == {route: 1.0}
+    blocks, need = wpp._mrhs_fullz_vmem(Z, YX, dt, odt, R, bt)
+    if route == "fullz":
+        assert need <= limit <= wpp._MRHS_FULLZ_VMEM_CAP
+        row = rows["QUDA_TPU_PALLAS_VMEM_MB[fullz]"]
+        assert row["last_bz"] == Z and row["last_block_bytes"] == blocks
+        assert row["double_buffer_ok"]
+    else:
+        assert limit is None
+        assert "QUDA_TPU_PALLAS_VMEM_MB[fullz]" not in rows
+
+
 @pytest.mark.slow
 def test_invert_multi_src_routes_mrhs_pallas_kernel(api_ctx,
                                                     monkeypatch):

@@ -252,11 +252,13 @@ def test_roofline_attribute_wilson_v2_fixture():
 
 
 def test_roofline_mrhs_model_amortises_gauge():
-    # the round-7 traffic model: per-RHS bytes 576 + 576/N
+    # the full-Z route's traffic model: per-RHS bytes 288 + 576/N
+    # (two time-slices a step, psi read twice; at N=8, 8 x 360 = the
+    # 2,880 B a site of PERF.md section 5)
     _, b1 = orf.model("wilson_mrhs", nrhs=1)
     _, b8 = orf.model("wilson_mrhs", nrhs=8)
-    assert b1 == pytest.approx(1152.0)
-    assert b8 == pytest.approx(648.0)
+    assert b1 == pytest.approx(864.0)
+    assert b8 == pytest.approx(360.0)
     # generic form carries no traffic model -> no bandwidth claim
     row = orf.attribute("generic", 100, 1, 1.0, flops_per_site=10)
     assert row["gbps"] is None and row["pct_peak_bw"] is None
