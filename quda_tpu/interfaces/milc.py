@@ -192,12 +192,11 @@ def qudaSaveGaugeField(path: str, precision: int = 64):
 def qudaLoadUnitarizedLink(ulink):
     """qudaLoadUnitarizedLink: MILC supplies the unitarized W links (used
     as the fat links of the HISQ level-2 smearing input)."""
-    api._ctx["fat"] = jnp.asarray(ulink)
+    api._set_resident_ks(jnp.asarray(ulink), api._ctx["long"])
 
 
 def qudaFreeKSLink():
-    api._ctx["fat"] = None
-    api._ctx["long"] = None
+    api._set_resident_ks(None, None)
 
 
 def qudaLoadCloverField(clover_blocks):

@@ -35,7 +35,9 @@ _ctx = {
     "mg_epoch": -1,         # gauge_epoch the resident MG was built against
     "clover": None,         # resident clover term (load_clover_quda)
     "wilson": None,         # resident Wilson pair operators
+    "ks": None,             # resident KS pair operators (fat + long)
     "gauge_epoch": 0,       # bumped whenever the resident gauge changes
+    "ks_epoch": 0,          # bumped whenever the fat / long links change
 }
 
 
@@ -101,10 +103,10 @@ def end_quda():
     # caches elsewhere (interfaces/milc.py) key on it, and a reset would
     # let a post-reinit epoch collide with a pre-reset one, reviving
     # stale operators built against the old gauge.
-    keep_epoch = _ctx["gauge_epoch"]
+    keep = {k: _ctx[k] for k in ("gauge_epoch", "ks_epoch")}
     for k in list(_ctx):
         _ctx[k] = None if k != "initialized" else False
-    _ctx["gauge_epoch"] = keep_epoch
+    _ctx.update(keep)
     _ctx["mg_epoch"] = -1
     # shutdown telemetry flush (endQuda summary semantics): the timer
     # summary + profile.tsv, the tuner's profiler half (profile_0.tsv),
@@ -396,12 +398,15 @@ def _antiperiodic():
     return _ctx["gauge_param"].t_boundary == "antiperiodic"
 
 
-# -- what is built from the resident gauge and kept: the clover term --------
-# -- (loadCloverQuda) and the Wilson pair operators --------------------------
+# -- what is built from the resident links and kept: the clover term --------
+# -- (loadCloverQuda), the Wilson pair operators and the KS pair -------------
+# -- operators of the fat and long links (load_fat_long_quda) ----------------
 
 _CLOVER_FIELD = "resident_clover"     # their rows in the HBM ledger
 _WILSON_FIELD = "resident_wilson"
-_RESIDENT_FIELDS = {"clover": _CLOVER_FIELD, "wilson": _WILSON_FIELD}
+_KS_FIELD = "resident_ks"
+_RESIDENT_FIELDS = {"clover": _CLOVER_FIELD, "wilson": _WILSON_FIELD,
+                    "ks": _KS_FIELD}
 
 
 def _pair_store(prec: str):
@@ -482,6 +487,88 @@ def _resident_wilson(param: InvertParam, stores=()) -> dict:
             _ctx["wilson"] = term
             omem.track("wilson", _WILSON_FIELD, term["ops"])
     omet.inc("wilson_term_total", outcome=outcome)
+    return term
+
+
+def _set_resident_ks(fat, long_links):
+    """Every change of the resident fat / long links goes through here:
+    the pair operators built from the old ones no longer match their
+    key (``rebuilt`` at their next use) and go at once where the links
+    are freed."""
+    from ..obs import memory as omem
+    _ctx["fat"], _ctx["long"] = fat, long_links
+    _ctx["ks_epoch"] += 1
+    if fat is None or long_links is None:
+        _drop_resident("ks")
+    for field, g in (("fat_links", fat), ("long_links", long_links)):
+        if g is None:
+            omem.release("fat_naik", field)
+        else:
+            omem.track("fat_naik", field, g)
+
+
+def _ks_term_key(param: InvertParam, on_tpu: bool) -> tuple:
+    """What the resident KS pair operators depend on: the generation of
+    the fat / long pair, matpc, the fermion boundary and the kernel
+    route (pallas or not, interpreted or not, and the two form knobs
+    the operator's set-up reads).  The mass is NOT part of it: it is a
+    leaf of the operators."""
+    from ..utils import config as qconf
+    matpc = EVEN if param.matpc_type == "even-even" else ODD
+    return (_ctx["ks_epoch"], matpc, _antiperiodic(),
+            _pallas_enabled(on_tpu), _pallas_interpret(on_tpu),
+            str(qconf.get("QUDA_TPU_STAGGERED_FORM", fresh=True)),
+            str(qconf.get("QUDA_TPU_PRECISION_FORM", fresh=True)))
+
+
+def _resident_staggered(param: InvertParam, stores=()) -> dict:
+    """The improved-staggered pair operators of (resident fat and long
+    links, matpc, boundary, kernel route) at the storage dtypes
+    ``stores`` (f32 always), under mass 0: callers take them
+    ``with_mass``.  The resident ones when their key matches
+    (``reused``), else built lattice-minor from the resident links
+    (ops/staggered_packed.ks_links_eo_pairs: phases and boundary
+    folded, even-odd split, pairs) and kept in ``_ctx``: ``built`` when
+    nothing was resident, ``rebuilt`` when matpc, boundary or route
+    differ.  No canonical DiracStaggered* is constructed.  Phases land
+    on the ``load_fat_long_quda`` profile whoever calls."""
+    from ..models.staggered import DiracStaggeredPCPairs
+    from ..obs import memory as omem
+    from ..obs import metrics as omet
+    from ..obs import trace as otr
+    from ..ops import staggered_packed as spk
+    on_tpu = jax.default_backend() == "tpu"
+    key = _ks_term_key(param, on_tpu)
+    term = _ctx.get("ks")
+    outcome = ("reused" if term is not None and term["key"] == key
+               else "built" if term is None else "rebuilt")
+    prof = "load_fat_long_quda"
+    with otr.span("ks_term", cat="setup", outcome=outcome):
+        if outcome != "reused":
+            _drop_resident("ks")
+            term = {"key": key, "ops": {}}
+        missing = [st for st in dict.fromkeys(
+            jnp.dtype(s) for s in (jnp.float32,) + tuple(stores))
+            if st not in term["ops"]]
+        if missing:
+            _, matpc, ap, use_pallas, interpret, _, _ = key
+            geom = _ctx["geom"]
+            dims = tuple(geom.lattice_shape)
+            with otr.phase("fold_split", prof):
+                fat, lng = jax.block_until_ready(tuple(
+                    spk.ks_links_eo_pairs(_ctx[name], dims, ap, nhop)
+                    for name, nhop in (("fat", 1), ("long", 3))))
+            with otr.phase("pack", prof):
+                for st in missing:
+                    term["ops"][st] = DiracStaggeredPCPairs.from_packed(
+                        geom, tuple(g.astype(st) for g in fat),
+                        tuple(g.astype(st) for g in lng), 0.0, matpc,
+                        st, use_pallas=use_pallas,
+                        pallas_interpret=interpret)
+                jax.block_until_ready([term["ops"][st] for st in missing])
+            _ctx["ks"] = term
+            omem.track("ks", _KS_FIELD, term["ops"])
+    omet.inc("ks_term_total", outcome=outcome)
     return term
 
 
@@ -861,6 +948,32 @@ class _WilsonPairsSolve(_ResidentPairSolve):
 
     def flops_per_site_M(self) -> int:
         return 2 * 1320 + 48                # DiracWilsonPC's count
+
+
+class _StaggeredResidentSolve(_StaggeredPairsSolve):
+    """The improved-staggered solve on the resident KS term
+    (``_resident_staggered``): the pair operators are the resident ones
+    under this call's mass; prepare, the Hermitian PC solve and the
+    verified exit are cached programs on them (solvers/program.py), and
+    no canonical staggered operator is built on this route."""
+
+    def __init__(self, term: dict, mass: float):
+        self._term = term
+        self._mass = mass
+        self.op = term["ops"][jnp.dtype(jnp.float32)].with_mass(mass)
+
+    def prepare_source(self, b):
+        """Parity split and ``prepare`` of the full-lattice source, one
+        program."""
+        from ..solvers import program as sprog
+        return sprog.prepare(self.op, b)[0]
+
+    def sloppy(self, prec: str = "half"):
+        return self._term["ops"][jnp.dtype(_pair_store(prec))].with_mass(
+            self._mass)
+
+    def flops_per_site_M(self) -> int:
+        return 2 * 1146 + 24                # DiracStaggeredPC's count
 
 
 def _invert_wilson_df64(b, param: InvertParam, d, sloppy_prec: str,
@@ -1255,7 +1368,7 @@ def _note_solve_program(span, api: str, form: str, solver: str,
 
 
 def _verified_exit(api: str, form: str, op, b, x_pp):
-    """The verified exit of a Wilson pair route, one cached program on
+    """The verified exit of a resident pair route, one cached program on
     the resident f32 pair operator ``op`` (solvers/program.py): the
     canonical solution(s) and, on the host, the true residual(s) of
     what is returned.  The one host read ends the epilogue phase on the
@@ -1359,8 +1472,17 @@ def _invert_quda_body(source, param: InvertParam):
         # is constructed, for the solve or for the verified-exit check
         clover_resident = pair_op and param.dslash_type == "clover"
         wilson_resident = wil_pairs and not df64_route
+        # so does the improved-staggered pair route, on the fat and
+        # long links as loaded (load_fat_long_quda builds the term)
+        ks_resident = (stag_pairs and param.dslash_type != "staggered"
+                       and _ctx["fat"] is not None
+                       and _ctx["long"] is not None)
         stores = (_pair_store(sloppy_prec),) if mixed else ()
-        if clover_resident:
+        if ks_resident:
+            d = _StaggeredResidentSolve(
+                _resident_staggered(param, stores), param.mass)
+            d_full = None
+        elif clover_resident:
             d = _CloverResidentSolve(_resident_clover(param, stores),
                                      param.kappa)
             d_full = d.full()
@@ -1389,7 +1511,7 @@ def _invert_quda_body(source, param: InvertParam):
             d = d.packed()
 
         if not df64_route:
-            if stag_pairs:
+            if stag_pairs and not ks_resident:
                 # complex-free staggered solve loop (pair representation
                 # end to end; the pallas eo stencil on real TPU).
                 # 'quarter' storage has no staggered int8 codec — the
@@ -1400,7 +1522,9 @@ def _invert_quda_body(source, param: InvertParam):
                 d = _PairOpSolve(d, _pallas_enabled(on_tpu),
                                  _pallas_interpret(on_tpu))
 
-            if pc:
+            if ks_resident:
+                rhs = d.prepare_source(b)
+            elif pc:
                 be, bo = _split(b, param, d)
                 rhs = d.prepare(be, bo)
             else:
@@ -1491,7 +1615,7 @@ def _invert_quda_body(source, param: InvertParam):
 
     with otr.phase("epilogue", "invert_quda"):
         x_sys = back(res.x)
-        if wilson_resident:
+        if wilson_resident or ks_resident:
             x_full, true_res = _verified_exit(
                 "invert_quda", _solve_form(d), d.op, b, x_sys)
         else:
@@ -1578,15 +1702,15 @@ def _invert_dispatch(param, d, d_full, b, rhs, sys_rhs, mv, mv_applies,
     if mixed and inv == "cg":
         if pair_sloppy:
             sl = d.sloppy(sloppy_prec)
-            if (not hermitian_pc and hasattr(d, "op")
-                    and sprog.presents(d.op, sl)):
+            if hasattr(d, "op") and sprog.presents(d.op, sl):
                 # the loop traced once per process: the operators
                 # present (registered pytrees with a program_signature:
-                # the Wilson and clover packed pair operators off a
-                # mesh); here mv IS d.op.MdagM_pairs (cg on a
+                # the Wilson, clover and staggered packed pair operators
+                # off a mesh); here mv IS d.op.MdagM_pairs (cg on a
                 # non-Hermitian pair adapter always runs the normal
-                # equations) and d.codec the in-place pair codec, which
-                # the program rebuilds inside its trace
+                # equations) or, where the operators are ``hermitian``,
+                # d.op.M_pairs, and d.codec the in-place pair codec,
+                # which the program rebuilds inside its trace
                 res, hit = sprog.cg_reliable(
                     d.op, sl, sys_rhs, tol=param.tol,
                     maxiter=param.maxiter, delta=param.reliable_delta,
@@ -2003,10 +2127,12 @@ def _invert_multi_src_body(sources, param: InvertParam):
                                      maxiter=param.maxiter,
                                      record=recording)
                 iters_rhs = np.full(n_src, int(res.iters))
-            elif sprog.presents(op):
-                # the loop traced once per process (an operator that
-                # presents is non-Hermitian: mv_b IS its
-                # MdagM_pairs_mrhs)
+            elif sprog.presents(op) and not getattr(op, "hermitian",
+                                                    False):
+                # the loop traced once per process (the batched program
+                # has the normal equations only: mv_b IS the operator's
+                # MdagM_pairs_mrhs; a Hermitian operator, the staggered
+                # one, keeps the eager loop on its M_pairs_mrhs)
                 res, hit = sprog.batched_cg_pairs(
                     op, nrm_b, tol=param.tol, maxiter=param.maxiter,
                     record=recording)
@@ -2692,24 +2818,33 @@ def compute_ks_link_quda(naik_eps: float = 0.0):
     """computeKSLinkQuda: HISQ fatten the resident gauge; keep fat/long
     resident for staggered inverts."""
     from ..gauge.hisq import hisq_fattening
-    from ..obs import memory as omem
     _require_init()
     links = hisq_fattening(_ctx["gauge"], naik_eps)
-    _ctx["fat"] = links.fat
-    _ctx["long"] = links.long
-    omem.track("fat_naik", "fat_links", links.fat)
-    omem.track("fat_naik", "long_links", links.long)
+    _set_resident_ks(links.fat, links.long)
     return links
 
 
 def load_fat_long_quda(fat, long_links):
-    from ..obs import memory as omem
+    """loadGaugeQuda with QUDA_ASQTAD_FAT_LINKS / QUDA_ASQTAD_LONG_LINKS
+    (MILC's qudaLoadKSLink): the application's fat and long links,
+    canonical (4,T,Z,Y,X,3,3), become the resident ones, and where the
+    pair route is the platform's solve path (``_packed_enabled``) the
+    pair operators ``invert_quda`` solves on are built from them now,
+    once (``_resident_staggered`` for a single-precision HISQ solve,
+    even-even, sloppy ``auto``: precise f32 and the sloppy storage that
+    resolves to).  A solve whose matpc or kernel route differs, or new
+    links, rebuild; ``load_gauge_quda`` drops the operators (not the
+    links)."""
     _require_init()
-    dtype = _ctx["gauge"].dtype if _ctx["gauge"] is not None else None
-    _ctx["fat"] = jnp.asarray(fat, dtype)
-    _ctx["long"] = jnp.asarray(long_links, dtype)
-    omem.track("fat_naik", "fat_links", _ctx["fat"])
-    omem.track("fat_naik", "long_links", _ctx["long"])
+    from ..obs import trace as otr
+    with otr.api_span("load_fat_long_quda"):
+        dtype = _ctx["gauge"].dtype if _ctx["gauge"] is not None else None
+        _set_resident_ks(jnp.asarray(fat, dtype),
+                         jnp.asarray(long_links, dtype))
+        if (_ctx["gauge_param"] is not None
+                and _packed_enabled(jax.default_backend() == "tpu")):
+            p = InvertParam(dslash_type="hisq", cuda_prec="single")
+            _resident_staggered(p, (_pair_store(_resolve_sloppy(p)),))
 
 
 def save_gauge_field_quda(path: str, precision: int = 64):

@@ -13,6 +13,8 @@ prepare/reconstruct for the PC solve of M x = b:
 
 from __future__ import annotations
 
+import copy
+
 import jax
 import jax.numpy as jnp
 
@@ -21,6 +23,7 @@ from ..ops import staggered as sops
 from ..ops.boundary import apply_staggered_phases
 from ..ops.wilson import split_gauge_eo
 from .dirac import Dirac, DiracPC, MATPC_EVEN_EVEN
+from .wilson import _ProgramOperand
 
 
 class DiracStaggered(Dirac):
@@ -173,8 +176,17 @@ def _notice_precision_form(requested: str, served: str, why: str):
 STAGGERED_FORMS = ("fused", "two_pass", "v3")
 STAGGERED_PRECISION_FORMS = ("full", "r12", "fold")
 
+# Hop sets whose forms have been read on the chip: with the knob unset
+# they serve the winner WITHOUT a race (models/formsel.MEASURED is the
+# same rule for clover).  Fat + Naik, one v5e, 24^4 (PERF.md, PR 32):
+# as one M the forms are within 5 % of each other (bf16 v3 480 us,
+# two_pass 495, fused 503; f32 fused 743, v3 773, two_pass 797), and in
+# the CG loop of the cell v3 reads call_s 0.0883 s against two_pass
+# 0.0964 and fused 0.1027.  'auto' still races.
+MEASURED_FORMS = {"fat_naik": "v3"}
 
-class DiracStaggeredPCPairs:
+
+class DiracStaggeredPCPairs(_ProgramOperand):
     """Complex-free packed pair-form of DiracStaggeredPC — the staggered
     solver operator for TPU runtimes without complex64 execution, and
     (with bf16 storage) the sloppy staggered operator of mixed solves.
@@ -186,16 +198,26 @@ class DiracStaggeredPCPairs:
     selected by ``form`` / QUDA_TPU_STAGGERED_FORM:
 
     * ``fused``    — single-pass fat+Naik (one launch, one psi read, no
-                     XLA sum pass; ~864 vs 1512 B/site) — improved only;
+                     XLA sum pass) — improved only.  The eo kernel reads
+                     all 16 link matrices of an output site once (8
+                     forward of its own parity, 8 backward of the other:
+                     1,152 B in f32) + 5 psi tiles + out, ~1,300 B/site
+                     against a needed 1,200; the "864 B" of
+                     ops/staggered_pallas.py counts the FULL-lattice
+                     kernel, whose backward x/y/z hops re-use the forward
+                     tiles (it leaves out the other parity's 6 x/y/z
+                     backward matrices, 432 B, that the eo form must
+                     fetch);
     * ``two_pass`` — separate fat/long gather launches with resident
                      pre-shifted backward links (the pre-round-10
                      form);
     * ``v3``       — two-pass scatter form;
+    * unset        — ``MEASURED_FORMS``: the winner of the chip reading
+                     (fat+Naik: v3; PERF.md section 6, PR 32), no
+                     race; ``auto`` where the hop set has none;
     * ``auto``     — race the applicable forms via utils.tune at
-                     construction and cache the winner — A/B'd, not
-                     assumed (the staggered forms have no chip reading
-                     yet; PERF.md section 6, PR 30, has the Wilson
-                     one).  Off-chip (interpret mode) the race would
+                     construction and cache the winner.
+                     Off-chip (interpret mode) the race would
                      time the interpreter, not the hardware, so auto
                      resolves statically to the projected winner (fused
                      for improved, two_pass for fat-only) with a notice.
@@ -216,6 +238,15 @@ class DiracStaggeredPCPairs:
 
     hermitian = True
 
+    # the solve-program operand (solvers/program.py): resident links,
+    # the gather forms' pre-shifted backward links, the r12 sign planes
+    # and the mass are leaves; what picks the traced hop is static
+    _PROGRAM_ARRAYS = ("fat_eo_pp", "long_eo_pp", "_fat_bw", "_long_bw",
+                       "_long_sign", "mass")
+    _PROGRAM_STATIC = ("geom", "dims", "matpc", "store_dtype",
+                       "use_pallas", "_pallas_interpret", "_pallas_form",
+                       "_precision_form")
+
     def __init__(self, dpc: DiracStaggeredPC, store_dtype=jnp.float32,
                  use_pallas: bool = False, pallas_interpret: bool = False,
                  form: str | None = None, mesh=None,
@@ -223,18 +254,47 @@ class DiracStaggeredPCPairs:
                  precision_form: str | None = None):
         from ..ops import staggered_packed as spk
         from ..ops.wilson_packed import to_packed_pairs
+        pack = lambda gs: tuple(
+            to_packed_pairs(spk.pack_links(g), store_dtype) for g in gs)
+        self._setup(dpc.geom, dpc.mass, dpc.matpc, pack(dpc.fat_eo),
+                    pack(dpc.long_eo) if dpc.long_eo is not None
+                    else None, store_dtype, use_pallas, pallas_interpret,
+                    form, mesh, sharded_policy, precision_form)
+
+    @classmethod
+    def from_packed(cls, geom, fat_eo_pp, long_eo_pp, mass, matpc,
+                    store_dtype=jnp.float32, use_pallas: bool = False,
+                    pallas_interpret: bool = False,
+                    form: str | None = None) -> "DiracStaggeredPCPairs":
+        """From the phase- and boundary-folded (even, odd) pair links
+        alone (ops/staggered_packed.ks_links_eo_pairs, at
+        ``store_dtype``): what a resident KS term is built from, no
+        canonical DiracStaggeredPC in between.  Kernel and precision
+        form resolve as ``pairs()`` does without pins."""
+        op = object.__new__(cls)
+        op._setup(geom, mass, matpc, fat_eo_pp, long_eo_pp, store_dtype,
+                  use_pallas, pallas_interpret, form, None, None, None)
+        return op
+
+    def with_mass(self, mass: float):
+        """The same resident arrays under another mass (a leaf of the
+        pytree: a program's executable is shared)."""
+        op = copy.copy(self)
+        op.mass = float(mass)
+        return op
+
+    def _setup(self, geom, mass, matpc, fat_eo_pp, long_eo_pp,
+               store_dtype, use_pallas, pallas_interpret, form, mesh,
+               sharded_policy, precision_form):
         from ..utils import config as qconf
-        self.geom = dpc.geom
-        self.mass = float(dpc.mass)
-        self.matpc = dpc.matpc
-        self.dims = tuple(dpc.geom.lattice_shape)
+        self.geom = geom
+        self.mass = float(mass)
+        self.matpc = matpc
+        self.dims = tuple(geom.lattice_shape)
         self.store_dtype = store_dtype
-        self.fat_eo_pp = tuple(
-            to_packed_pairs(spk.pack_links(g), store_dtype)
-            for g in dpc.fat_eo)
-        self.long_eo_pp = (tuple(
-            to_packed_pairs(spk.pack_links(g), store_dtype)
-            for g in dpc.long_eo) if dpc.long_eo is not None else None)
+        self.fat_eo_pp = tuple(fat_eo_pp)
+        self.long_eo_pp = (tuple(long_eo_pp) if long_eo_pp is not None
+                           else None)
         self.use_pallas = use_pallas
         if use_pallas:
             # pallas-construction fault seam (robust/faultinject.py) —
@@ -251,6 +311,16 @@ class DiracStaggeredPCPairs:
         # QUDA_TPU_STAGGERED_FORM knob) -------------------------------
         if form is None:
             form = str(qconf.get("QUDA_TPU_STAGGERED_FORM", fresh=True))
+        if not form:
+            # knob unset: the chip's measured winner where one was
+            # read, no race; else as 'auto'
+            form = (MEASURED_FORMS.get(
+                "fat_naik" if improved else "fat", "auto")
+                if use_pallas and mesh is None else "auto")
+            if form != "auto":
+                _notice_staggered_form(
+                    form, None, "default: the chip's measured winner, "
+                    "no race (QUDA_TPU_STAGGERED_FORM=auto races)")
         if form not in STAGGERED_FORMS + ("auto",):
             raise ValueError(f"staggered form must be one of "
                              f"{STAGGERED_FORMS + ('auto',)}, got "
@@ -876,6 +946,34 @@ class DiracStaggeredPCPairs:
         x_q = self._from_pairs(x_q_pp, b_q.dtype)
         return (x_p, x_q) if p == EVEN else (x_q, x_p)
 
+    def verified_exit_pairs(self, b, x_pp):
+        """The API's verified exit on the pair representation: the
+        canonical full-lattice source ``b`` (T,Z,Y,X,1,3) and the
+        pair-form PC solution -> (canonical full-lattice solution,
+        |b - (2m + D) x| / |b|).  x_q = (b_q - D x_p) / 2m is the
+        reconstruction; the residual is that of the RETURNED solution
+        under the full M = 2m + D, parity by parity with this
+        operator's own hop in f32 (on the q sites it is what rounding
+        leaves of the reconstruction): no canonical (...,1,3) temporary
+        beyond the two boundaries.  Meant to be traced
+        (solvers/program.py) on the f32 operator."""
+        from ..fields.spinor import even_odd_join, even_odd_split
+        f32, p, m2 = jnp.float32, self.matpc, 2.0 * self.mass
+        halves = even_odd_split(b, self.geom)
+        b_p, b_q = (self._to_pairs(h).astype(f32)
+                    for h in (halves if p == EVEN else halves[::-1]))
+        x_p = x_pp.astype(f32)
+        d_xp = self.D_to_pairs(x_p, 1 - p, out_dtype=f32)
+        x_q = (b_q - d_xp) / m2
+        r_p = b_p - (m2 * x_p + self.D_to_pairs(x_q, p, out_dtype=f32))
+        r_q = b_q - (m2 * x_q + d_xp)
+        norm2 = lambda v: jnp.sum(v * v)
+        x_e, x_o = (self._from_pairs(v, b.dtype)
+                    for v in ((x_p, x_q) if p == EVEN else (x_q, x_p)))
+        return (even_odd_join(x_e, x_o, self.geom),
+                jnp.sqrt((norm2(r_p) + norm2(r_q))
+                         / (norm2(b_p) + norm2(b_q))))
+
     # -- multi-RHS boundary helpers (the invert_multi_src_quda route) ---
     def prepare_pairs_mrhs(self, b_even_b, b_odd_b):
         """Batched canonical complex parity sources (N, T,Z,Y,Xh,1,3) ->
@@ -904,3 +1002,6 @@ class DiracStaggeredPCPairs:
         x_p = self.solution_from_pairs_mrhs(x_b, b_q.dtype)
         x_q = self.solution_from_pairs_mrhs(xq_b, b_q.dtype)
         return (x_p, x_q) if p == EVEN else (x_q, x_p)
+
+
+jax.tree_util.register_pytree_node_class(DiracStaggeredPCPairs)

@@ -132,7 +132,8 @@ TRACE_EVENTS: dict[str, dict] = {
 # -- span attributes set after the fact (obs/trace span.set) ----------------
 
 SPAN_ATTRS: dict[str, dict] = {
-    "program": {"spans": ("solve:cg", "solve:batched-cg-pairs"),
+    "program": {"spans": ("solve:cg", "solve:batched-cg-pairs",
+                          "verified_exit"),
                 "doc": "'hit' | 'miss': whether the cached solve "
                        "program (solvers/program.py) served the call "
                        "from the in-process executable cache or traced "
@@ -176,7 +177,7 @@ METRICS: dict[str, dict] = {
         "type": COUNTER,
         "help": "calls through a cached program (solvers/program.py: "
                 "the solve loops, and solver='verified-exit' the "
-                "Wilson pair routes' verified exit), by "
+                "Wilson and staggered pair routes' verified exit), by "
                 "api/form/solver/outcome: 'miss' traced (and lowered, "
                 "compiled or fetched) the program, 'hit' was an "
                 "in-process executable lookup"},
@@ -194,6 +195,14 @@ METRICS: dict[str, dict] = {
                 "outcome: 'built' nothing was resident, 'reused' the "
                 "resident operators served, 'rebuilt' another matpc, "
                 "boundary or kernel route replaced them"},
+    "ks_term_total": {
+        "type": COUNTER,
+        "help": "uses of the resident KS pair operators "
+                "(load_fat_long_quda, asqtad / hisq invert_quda on the "
+                "pair route) by outcome: 'built' nothing was resident, "
+                "'reused' the resident operators served, 'rebuilt' "
+                "another matpc, boundary or kernel route replaced them; "
+                "new fat / long links or a new gauge drop them"},
     "wilson_mrhs_route_total": {
         "type": COUNTER,
         "help": "traced calls of the multi-RHS Wilson kernel "

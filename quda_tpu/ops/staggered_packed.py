@@ -14,6 +14,9 @@ is unrolled 3x3 elementwise work on full vector tiles.
 
 from __future__ import annotations
 
+import functools
+
+import jax
 import jax.numpy as jnp
 
 from .wilson_packed import pack_gauge as pack_links  # (4,3,3,T,Z,Y*X)
@@ -182,3 +185,38 @@ def dslash_staggered_eo_packed_pairs(fat_eo_pp, psi_pp: jnp.ndarray, dims,
                                             for a, t in zip(acc, term)]
     return jnp.stack([jnp.stack([re, im]) for re, im in acc]).astype(
         out_dtype)
+
+
+# ---------------------------------------------------------------------------
+# resident KS links: canonical fat / long links -> the (even, odd) pair
+# arrays of the solve operators, built lattice-minor
+# ---------------------------------------------------------------------------
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("dims", "antiperiodic_t", "nhop"))
+def ks_links_eo_pairs(links, dims, antiperiodic_t: bool, nhop: int = 1):
+    """Canonical (4,T,Z,Y,X,3,3) fat (``nhop`` 1) or long (``nhop`` 3)
+    links -> (even, odd) f32 pair arrays (4,3,3,2,T,Z,Y*Xh) with the
+    MILC staggered phases and the antiperiodic t boundary (the last
+    ``nhop`` time slices) folded in: what
+    ``apply_staggered_phases`` + ``split_gauge_eo`` + ``pack_links`` +
+    ``to_packed_pairs`` give, as ONE program whose every intermediate
+    keeps the lattice minor (a canonical (...,3,3) temporary tile-pads
+    ~57x on a TPU: PERF.md, PR 22 cause 3).  A lower storage dtype is a
+    cast of the result."""
+    from .clover_packed import split_eo_packed
+    T, Z, Y, X = dims
+    shape = (T, Z, Y * X)
+    t, z, yx = (jax.lax.broadcasted_iota(jnp.int32, shape, a)
+                for a in range(3))
+    x, y = yx % X, yx // X
+    sign = lambda n: (1 - 2 * (n % 2)).astype(jnp.float32)
+    eta_t = sign(x + y + z)
+    if antiperiodic_t:
+        eta_t = jnp.where(t >= T - nhop, -eta_t, eta_t)
+    eta = jnp.stack([jnp.ones(shape, jnp.float32), sign(x), sign(x + y),
+                     eta_t])
+    gp = pack_links(links) * eta[:, None, None].astype(links.dtype)
+    return tuple(to_packed_pairs(h, jnp.float32)
+                 for h in split_eo_packed(gp, dims))

@@ -24,17 +24,20 @@ a later call with the same key is an in-process executable lookup.
   the armed fault iteration).  The key holds no array and no operator
   identity.
 
-The verified exit of the Wilson pair routes is a program of the same
-kind (``verified_exit``): from the canonical source and the pair-form
-solution to the canonical solution and its true residual, the resident
-f32 pair operator an operand.
+The verified exit of the Wilson and staggered pair routes is a program
+of the same kind (``verified_exit``): from the canonical source and the
+pair-form solution to the canonical solution and its true residual, the
+resident f32 pair operator an operand.  ``prepare`` is the entry's: the
+canonical source, split by parity, to the pair-form PC right-hand side.
 
 An operator goes through a program when it ``presents``: its class is a
 registered pytree with a ``program_signature``.  Everything else (a
-mesh operator, an MG closure, a bare lambda, the staggered and zoo pair
-operators) keeps the eager solver call.  Both programs solve the NORMAL
-equations of a non-Hermitian PC operator (``MdagM_pairs`` /
-``MdagM_pairs_mrhs``), the only shape a presenting operator has today.
+mesh operator, an MG closure, a bare lambda, the zoo pair operators)
+keeps the eager solver call.  ``cg_reliable`` solves the NORMAL
+equations of a non-Hermitian PC operator (``MdagM_pairs``: Wilson,
+clover) and applies a ``hermitian`` one once an iteration (``M_pairs``:
+the staggered PC operator is already 4m^2 - D D); the batched program
+has the normal equations only.
 """
 
 from __future__ import annotations
@@ -82,9 +85,10 @@ def _run(program, *operands, **static):
 @partial(jax.jit, static_argnames=("key",))
 def _cg_reliable_program(op_hi, op_lo, b, tol, maxiter, key):
     _traces[0] += 1
-    delta, codec_cfg, knobs = key
+    delta, codec_cfg, knobs, hermitian = key
+    mv = "M_pairs" if hermitian else "MdagM_pairs"
     return mixed.cg_reliable_loop(
-        op_hi.MdagM_pairs, op_lo.MdagM_pairs, b, tol,
+        getattr(op_hi, mv), getattr(op_lo, mv), b, tol,
         knobs.maxiter if knobs.record else maxiter, delta,
         mixed.pair_inplace_codec(*codec_cfg), knobs.record,
         knobs.sentinel, knobs.fault_k)
@@ -94,9 +98,11 @@ def cg_reliable(op_hi, op_lo, b, tol: float, maxiter: int, delta: float,
                 record: bool = False):
     """``mixed.cg_reliable`` on ``op_hi.MdagM_pairs`` (precise) and
     ``op_lo.MdagM_pairs`` (sloppy storage, the in-place pair codec)
-    through the cached program.  Returns ``(SolverResult, hit)``."""
+    through the cached program; on ``M_pairs`` where the operators say
+    they are ``hermitian``.  Returns ``(SolverResult, hit)``."""
     key = (float(delta), mixed.pair_inplace_config(op_lo.store_dtype),
-           _loop_knobs(record, maxiter))
+           _loop_knobs(record, maxiter),
+           bool(getattr(op_hi, "hermitian", False)))
     return _run(_cg_reliable_program, op_hi, op_lo, b, float(tol),
                 int(maxiter), key=key)
 
@@ -128,10 +134,25 @@ def _verified_exit_program(op, b, x_pp):
 
 
 def verified_exit(op, b, x_pp):
-    """The verified exit of a solve on the f32 Wilson packed pair
-    operator ``op`` (``verified_exit_pairs``) through the cached
+    """The verified exit of a solve on the f32 packed pair operator
+    ``op`` (``verified_exit_pairs``: Wilson, staggered) through the cached
     program: canonical source(s) and pair-form PC solution(s) ->
     ``((canonical full-lattice solution, true residual), hit)``.  With
     a leading source axis on ``b`` and ``x_pp`` the N residuals come
     back in one array."""
     return _run(_verified_exit_program, op, b, x_pp)
+
+
+@jax.jit
+def _prepare_program(op, b):
+    _traces[0] += 1
+    from ..fields.spinor import even_odd_split
+    return op.prepare_pairs(*even_odd_split(b, op.geom))
+
+
+def prepare(op, b):
+    """The entry of a solve on the pair operator ``op``: the canonical
+    full-lattice source, split by parity and through
+    ``op.prepare_pairs``, to the pair-form PC right-hand side, as one
+    cached program.  Returns ``(rhs, hit)``."""
+    return _run(_prepare_program, op, b)

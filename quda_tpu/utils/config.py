@@ -214,16 +214,17 @@ _register("QUDA_TPU_PALLAS_VMEM_MB_STAGGERED", "float", 9.0,
           "PERF.md round 8 lever (a)); the raised default admits it "
           "while the Wilson kernels keep the measured-proven 6 MB",
           reference="tune.cpp shared-bytes tuning axis (per-kernel)")
-_register("QUDA_TPU_STAGGERED_FORM", "choice", "auto",
+_register("QUDA_TPU_STAGGERED_FORM", "choice", "",
           "staggered/HISQ pallas kernel form: 'fused' = single-pass "
           "fat+Naik (one launch, one psi read, no XLA sum pass), "
           "'two_pass' = separate fat/long gather launches with "
           "pre-shifted backward links (the pre-round-10 form), 'v3' = "
           "two-pass scatter, 'auto' = race all forms via utils.tune at "
           "operator construction and cache the winner per (volume, "
-          "dtype, improved) — A/B'd, not assumed: no staggered form "
-          "has a chip reading yet",
-          ("auto", "fused", "two_pass", "v3"),
+          "dtype, improved); '' = the winner of the chip reading where "
+          "the hop set has one (models/staggered.MEASURED_FORMS: "
+          "fat+Naik serves v3, PERF.md PR 32), no race, else as 'auto'",
+          ("", "auto", "fused", "two_pass", "v3"),
           reference="dslash policy selection; tune.cpp:862 — policies "
                     "are timed, never assumed")
 _register("QUDA_TPU_CLOVER_FORM", "choice", "",

@@ -222,7 +222,7 @@ def test_solve_program_compiles_for_v5e_with_links_as_parameters(one_chip):
                     weak_type=s.weak_type), ops)
             b = jax.ShapeDtypeStruct(*_psi(F32), sharding=one_chip)
             key = (0.1, mixed.pair_inplace_config(BF16, False, False),
-                   sprog._LoopKnobs(False, None, None, None))
+                   sprog._LoopKnobs(False, None, None, None), False)
             compiled = sprog._cg_reliable_program.lower(
                 hi, lo, b, 1e-6, 10000, key=key).compile()
     finally:
@@ -310,7 +310,7 @@ def test_clover_solve_program_compiles_for_v5e_with_blocks_as_parameters(
                 weak_type=s.weak_type), ops)
         b = jax.ShapeDtypeStruct(*_psi(F32), sharding=one_chip)
         key = (0.1, mixed.pair_inplace_config(BF16, False, False),
-               sprog._LoopKnobs(False, None, None, None))
+               sprog._LoopKnobs(False, None, None, None), False)
         return sprog._cg_reliable_program.lower(hi, lo, b, 1e-6, 10000,
                                                 key=key)
     hlo = _aot(lower).as_text()
@@ -373,3 +373,82 @@ def test_verified_exit_program_compiles_for_v5e(one_chip, n_src):
     assert not big, f"fields baked into the executable: {big}"
     assert compiled.memory_analysis().temp_size_in_bytes \
         < n_src * 0.4 * 2 ** 30
+
+
+def test_ks_links_construction_compiles_for_v5e_lattice_minor(one_chip):
+    """The resident KS term's links (ops/staggered_packed
+    .ks_links_eo_pairs: phases and boundary folded, even-odd split,
+    pairs) as ONE program at 24^4 from the canonical long links: what it
+    holds beside its argument and result stays under 1 GiB (a
+    canonical (...,3,3) temporary tile-pads ~57x: 5.4 GB each) and no
+    field is baked into the executable.  The result's 95.6 MB are
+    127.4 MB on the device: a 288-lane plane is held in 384 lanes."""
+    from quda_tpu.ops import staggered_packed as spk
+
+    def lower():
+        g = jax.ShapeDtypeStruct((4,) + DIMS + (3, 3), jnp.complex64,
+                                 sharding=one_chip)
+        return spk.ks_links_eo_pairs.lower(g, DIMS, True, 3)
+    compiled = _aot(lower)
+    ma = compiled.memory_analysis()
+    logical = 2 * 4 * 18 * (L ** 4 // 2) * 4
+    assert logical <= ma.output_size_in_bytes <= (
+        logical * 384 // 288 + 4096), (ma.output_size_in_bytes, logical)
+    assert ma.temp_size_in_bytes < 2 ** 30, ma
+    big = [c for c in _hlo_values(compiled.as_text(), "constant")
+           if c[0] > 2 ** 20]
+    assert not big, f"fields baked into the executable: {big}"
+
+
+def test_hisq_solve_program_compiles_for_v5e_with_links_as_parameters(
+        one_chip):
+    """The improved-staggered single-source solve program
+    (solvers/program.py on DiracStaggeredPCPairs, ``hermitian``: the
+    operator applied once an iteration) at 24^4: compiles for the
+    described chip with the fat and long links of both operators as
+    parameters, and the served kernel form (MEASURED_FORMS: the
+    two-pass scatter form v3) is there for both, under the name the
+    benchmark's metrics read."""
+    import re
+    from quda_tpu.fields.geometry import LatticeGeometry
+    from quda_tpu.models.staggered import DiracStaggeredPCPairs
+    from quda_tpu.solvers import mixed
+    from quda_tpu.solvers import program as sprog
+    geom = LatticeGeometry(DIMS)
+    lshape = (4, 3, 3, 2, L, L, YXH)
+
+    def operators(fe, fo, le, lo):
+        return tuple(DiracStaggeredPCPairs.from_packed(
+            geom, (fe.astype(dt), fo.astype(dt)),
+            (le.astype(dt), lo.astype(dt)), 0.04, 0, dt, use_pallas=True,
+            pallas_interpret=False) for dt in (F32, BF16))
+
+    def lower():
+        lk = jax.ShapeDtypeStruct(lshape, F32)
+        ops = jax.eval_shape(operators, lk, lk, lk, lk)
+        hi, lo = jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(
+                s.shape, s.dtype, sharding=one_chip,
+                weak_type=s.weak_type), ops)
+        assert hi.hermitian and sprog.presents(hi, lo)
+        assert hi._pallas_form == lo._pallas_form == "v3"
+        b = jax.ShapeDtypeStruct((3, 2, L, L, YXH), F32,
+                                 sharding=one_chip)
+        key = (0.1, mixed.pair_inplace_config(BF16, False, False),
+               sprog._LoopKnobs(False, None, None, None), True)
+        return sprog._cg_reliable_program.lower(hi, lo, b, 1e-6, 10000,
+                                                key=key)
+    hlo = _aot(lower).as_text()
+    # one M = two hops = four passes; the precise and the sloppy operator
+    # each apply it (a pass returns f32 whatever it reads; the bf16
+    # links among the parameters below say what the sloppy calls read)
+    calls = re.findall(r"%dslash_staggered_eo_pallas_v3[.\d]* = f32"
+                       r"\[[^\n]*tpu_custom_call", hlo)
+    assert len(calls) >= 8, calls
+    params = _hlo_values(hlo, "parameter")
+    links = ",".join(str(d) for d in lshape)
+    for dt in ("f32", "bf16"):      # fat and long, two parities (the
+        # nested computations that slice their z rows list them again)
+        assert sum(p[1:] == (dt, links) for p in params) >= 4
+    big = [c for c in _hlo_values(hlo, "constant") if c[0] > 2 ** 20]
+    assert not big, f"fields baked into the executable: {big}"
