@@ -580,12 +580,32 @@ class _PackedHopMixin:
                                           self.dims, target_parity,
                                           out_dtype=out_dtype)
 
+    def _plain_mrhs(self) -> bool:
+        """Whether the batched hop is the plain pallas MRHS kernel
+        (``_hop_mrhs``): the pallas route off a mesh, in a storage form
+        without a batched kernel or a vmapped fallback of its own."""
+        return (self.use_pallas and getattr(self, "_mesh", None) is None
+                and getattr(self, "_precision_form", None)
+                not in ("r12f", "fold", "int8"))
+
+    def _hop_mrhs(self, psi_b, target_parity, out_dtype, **epilogue):
+        """The plain pallas MRHS kernel on this operator's links;
+        ``epilogue``: its combine operands (``xc``, ``coeff``, ``g5``)."""
+        from ..ops import wilson_pallas_packed as wpp
+        return wpp.dslash_eo_pallas_packed_mrhs(
+            self.gauge_eo_pp[target_parity], self._u_bw[target_parity],
+            psi_b, tuple(self.dims), target_parity,
+            interpret=self._pallas_interpret, out_dtype=out_dtype,
+            tb_sign=self._tb_sign, **epilogue)
+
     def _d_to_mrhs(self, psi_b, target_parity, out_dtype):
         """Batched packed eo hop: psi_b (N,4,3,2,T,Z,Y*Xh).  The
         pallas path routes the MRHS kernel (one gauge-tile fetch per
         (t, z-block), N spinor tiles streamed through it); r12f and
         fold route their own MRHS kernels; everything else (int8,
         mesh, XLA) falls back to the vmapped single-RHS stencil."""
+        if self._plain_mrhs():
+            return self._hop_mrhs(psi_b, target_parity, out_dtype)
         if self.use_pallas and getattr(self, "_mesh", None) is None:
             from ..ops import wilson_pallas_packed as wpp
             form = getattr(self, "_precision_form", None)
@@ -604,12 +624,6 @@ class _PackedHopMixin:
                     interpret=self._pallas_interpret,
                     out_dtype=out_dtype, tb_sign=self._tb_sign)
                 return wpp.from_fold(out)
-            if form != "int8":
-                return wpp.dslash_eo_pallas_packed_mrhs(
-                    self.gauge_eo_pp[target_parity],
-                    self._u_bw[target_parity], psi_b, tuple(self.dims),
-                    target_parity, interpret=self._pallas_interpret,
-                    out_dtype=out_dtype, tb_sign=self._tb_sign)
         return jax.vmap(
             lambda p: self._d_to(p, target_parity, out_dtype))(psi_b)
 
@@ -1226,6 +1240,36 @@ class DiracWilsonPCPackedSloppy(_ProgramOperand, _PackedHopMixin,
         x = per_src(lambda e, o: even_odd_join(e, o, self.geom))(x_e, x_o)
         return x, jnp.sqrt((norm2(r_p) + norm2(r_q))
                            / (norm2(b_p) + norm2(b_q)))
+
+    # -- the batched operator, its combine in the second hop's epilogue --
+    # Where the batched hop is the plain pallas MRHS kernel
+    # (``_plain_mrhs``: decided by the operator's own route, no knob)
+    # the second hop writes [g5] (x - kappa^2 D D x) itself
+    # (ops/wilson_pallas_packed: the combine epilogue, kappa an SMEM
+    # operand), so the CG tail reads one array where the base
+    # composition hands XLA the bare hop sum and x (PERF.md section 6,
+    # PR 33).  Every other representation keeps _PairSloppyBase's
+    # composition, operation for operation; prepare / reconstruct / the
+    # verified exit apply bare hops either way.
+    def _M_g5_pairs_mrhs(self, x, g5: bool):
+        """``M x``, or ``g5 M x``."""
+        if not self._plain_mrhs():
+            out = super().M_pairs_mrhs(x)
+            return self._g5_pairs_mrhs(out) if g5 else out
+        p = self.matpc
+        tmp = self._hop_mrhs(x, 1 - p, self.store_dtype)
+        return self._hop_mrhs(tmp, p, self.store_dtype, xc=x,
+                              coeff=-(self.kappa ** 2), g5=g5)
+
+    def M_pairs_mrhs(self, x):
+        return self._M_g5_pairs_mrhs(x, False)
+
+    def Mdag_pairs_mrhs(self, x):
+        return self._M_g5_pairs_mrhs(self._g5_pairs_mrhs(x), True)
+
+    def MdagM_pairs_mrhs(self, x):
+        # g5 M g5 M x: each M's second hop applies the g5 in front of it
+        return self._M_g5_pairs_mrhs(self._M_g5_pairs_mrhs(x, True), True)
 
     # -- multi-RHS boundary helpers (the invert_multi_src_quda route) --
     def prepare_pairs_mrhs(self, b_even_b, b_odd_b):

@@ -209,7 +209,10 @@ METRICS: dict[str, dict] = {
                 "(ops/wilson_pallas_packed._mrhs_route) by route: "
                 "'fullz' whole-Z tiles, bt time-slices a step, each "
                 "spinor tile read (bt + 2) / bt times, 'zblock' z-blocks "
-                "with two z-neighbour tiles besides (five reads)"},
+                "with two z-neighbour tiles besides (five reads); and by "
+                "epilogue: 'combine' the store writes [g5] (xc + coeff * "
+                "hop) (the second hop of the batched PC operator), 'none' "
+                "the bare hop sum"},
     # tuner warm-cache accounting (utils/tune.py)
     "tune_cache_hits_total": {
         "type": COUNTER,

@@ -224,7 +224,8 @@ class _EitherRef:
 
 def _make_kernel(X: int, bz: int, eo: tuple | None = None,
                  T: int | None = None, tb_sign: bool = True,
-                 z_rows: str = "tiles"):
+                 z_rows: str = "tiles", combine: bool = False,
+                 g5: bool = False):
     """Kernel over one (t, z-block) tile.  Ref shapes (leading block dims
     of 1 squeezed by indexing; R = 3 link rows for full storage, 2 for
     reconstruct-12):
@@ -252,13 +253,21 @@ def _make_kernel(X: int, bz: int, eo: tuple | None = None,
     ``T``/``tb_sign`` drive the reconstruct-12 t-boundary row-2 sign
     (see _link_getter): the forward t-link boundary plane is t = T-1 on
     g_c, the PRE-SHIFTED backward one is t = 0 on g_m.
+    ``combine`` gives the kernel a combine epilogue: two more refs
+    follow the psi refs, ``xc`` (a spinor block of the out block's
+    shape) and ``coeff`` (one f32 in SMEM), and the store writes
+    ``xc + coeff[0] * hop`` from the f32 accumulators, under gamma5
+    (the sign (+,+,-,-) by spin row) with ``g5``: rounded to the out
+    dtype once, as the XLA pass it replaces rounds.  Without it the
+    body is the plain hop's, trace for trace.
     """
     from jax.experimental import pallas as pl
 
     def kernel(psi_c, psi_tp, psi_tm, psi_zp, psi_zm, g_c, g_m, out_ref,
-               z0=None, t_id=None):
+               z0=None, t_id=None, xc=None, coeff=None):
         # z0 / t_id: the tile's first z row and its time-slice, where the
-        # caller knows them better than the grid does (centre_kernel)
+        # caller knows them better than the grid does (centre_kernel);
+        # xc / coeff: the combine epilogue's operands
         if eo is not None:
             parity, Xh = eo
             t_eo = pl.program_id(0) if t_id is None else t_id
@@ -359,12 +368,21 @@ def _make_kernel(X: int, bz: int, eo: tuple | None = None,
             color_acc(h, _link_getter(gref, 3, r2s), t, adjoint)
 
         odt = out_ref.dtype
+        if xc is not None:
+            k = coeff[0]
+            nk = -k
         for s in range(4):
             for c in range(3):
-                out_ref[s, c, 0, 0] = acc[s][c][0].astype(odt)
-                out_ref[s, c, 1, 0] = acc[s][c][1].astype(odt)
+                for ri in (0, 1):
+                    v = acc[s][c][ri]
+                    if xc is not None:
+                        x = xc[s, c, ri, 0].astype(F32)
+                        # -(x + k v) is (-k) v - x to the bit
+                        v = nk * v - x if g5 and s >= 2 else x + k * v
+                    out_ref[s, c, ri, 0] = v.astype(odt)
 
-    def centre_kernel(psi_c, psi_tp, psi_tm, g_c, g_m, out_ref):
+    def centre_kernel(psi_c, psi_tp, psi_tm, g_c, g_m, out_ref,
+                      xc=None, coeff=None):
         bt, Z = psi_c.shape[-3:-1]
         nzc = Z // bz
         t0 = pl.program_id(0) * bt    # not inside the loop's body
@@ -388,14 +406,26 @@ def _make_kernel(X: int, bz: int, eo: tuple | None = None,
                                 at(psi_tm, 0))
             kernel(at(psi_c, i), up, dn, at(psi_c, i, +1), at(psi_c, i, -1),
                    at(g_c, i), at(g_m, i), at(out_ref, i),
-                   z0=zc * bz, t_id=t0 + i)
+                   z0=zc * bz, t_id=t0 + i,
+                   xc=None if xc is None else at(xc, i), coeff=coeff)
             return carry
         if bt * nzc == 1:
             chunk(0, 0)
         else:
             jax.lax.fori_loop(0, bt * nzc, chunk, 0)
 
-    return centre_kernel if z_rows == "centre" else kernel
+    body = centre_kernel if z_rows == "centre" else kernel
+    if not combine:
+        return body
+
+    def combine_kernel(*refs):
+        # operand order: psi refs, xc, coeff, then the links LAST (the
+        # benchmark's trace reduction names a kernel event by the element
+        # types of its result, first and last operand)
+        *psi, xc, coeff, g_c, g_m, out_ref = refs
+        body(*psi, g_c, g_m, out_ref, xc=xc, coeff=coeff)
+
+    return combine_kernel
 
 
 def _sublane_rows(dtype) -> int:
@@ -634,12 +664,14 @@ _MRHS_FULLZ_VMEM_CAP = 48 * 2 ** 20
 
 
 def _mrhs_fullz_vmem(Z: int, YX: int, dtype, out_dtype, R: int,
-                     bt: int = 1):
+                     bt: int = 1, xc_dtype=None):
     """(block_bytes, need_bytes) of one full-Z MRHS step of ``bt``
     time-slices.  Blocks: bt + 2 psi tiles (24 planes each: the block's
     slices and the one after and before them), bt forward and backward
-    link tiles (24 R each) and bt out tiles (24), every (Z, YX) plane
-    padded to its dtype's (sublane, 128) tile as ``_pick_bz`` pads it.
+    link tiles (24 R each), bt out tiles (24) and, with the combine
+    epilogue, bt tiles of its ``xc`` operand (24, of ``xc_dtype``),
+    every (Z, YX) plane padded to its dtype's (sublane, 128) tile as
+    ``_pick_bz`` pads it.
     Need: the blocks double-buffered by the pipeline plus the body's
     own f32 tiles, which live in VMEM, not in vregs (accumulators, the
     loaded spinor, the hop's temporaries): six spinors' worth of full-Z
@@ -655,6 +687,8 @@ def _mrhs_fullz_vmem(Z: int, YX: int, dtype, out_dtype, R: int,
 
     blocks = (((bt + 2) * 24 + 2 * 24 * R * bt) * plane(dtype)
               + bt * 24 * plane(out_dtype))
+    if xc_dtype is not None:
+        blocks += bt * 24 * plane(xc_dtype)
     return blocks, 2 * blocks + 6 * 24 * plane(F32)
 
 
@@ -669,7 +703,7 @@ def _fullz_chunk(Z: int, dtype) -> int:
 
 
 def _mrhs_route(T: int, Z: int, YX: int, dtype, out_dtype, R: int,
-                block_z: int | None):
+                block_z: int | None, xc_dtype=None):
     """(route, bz, bt, vmem_limit_bytes) of an MRHS call, from its
     shapes.
 
@@ -681,12 +715,16 @@ def _mrhs_route(T: int, Z: int, YX: int, dtype, out_dtype, R: int,
     times.  ``"zblock"``: ``_pick_bz``'s z-block and the five psi
     operands of the single-RHS kernel, within the scoped default; taken
     where full-Z does not fit or a caller's ``block_z`` asks for
-    z-blocks.  Recorded at trace time: the VMEM audit gets the full-Z
-    route's blocks and limit (``_pick_bz`` records the z-block's),
-    ``wilson_mrhs_route_total`` counts the call by route."""
+    z-blocks.  ``xc_dtype``: the call has the combine epilogue, whose
+    ``xc`` operand is one more spinor block on either route.  Recorded
+    at trace time: the VMEM audit gets the full-Z route's blocks and
+    limit (``_pick_bz`` records the z-block's),
+    ``wilson_mrhs_route_total`` counts the call by route and
+    epilogue."""
     from ..obs import memory as omem
     from ..obs import metrics as omet
-    fits = [(bt,) + _mrhs_fullz_vmem(Z, YX, dtype, out_dtype, R, bt)
+    fits = [(bt,) + _mrhs_fullz_vmem(Z, YX, dtype, out_dtype, R, bt,
+                                     xc_dtype)
             for bt in (2, 1) if T % bt == 0]
     fits = [f for f in fits if f[2] <= _MRHS_FULLZ_VMEM_CAP]
     if block_z in (None, Z) and fits:
@@ -698,26 +736,48 @@ def _mrhs_route(T: int, Z: int, YX: int, dtype, out_dtype, R: int,
     else:
         route, bt, limit = "zblock", 1, None
         bz = block_z if block_z is not None else _pick_bz(
-            Z, YX, dtype, planes=288 if R == 3 else 240)
+            Z, YX, dtype, planes=(288 if R == 3 else 240)
+            + (24 if xc_dtype is not None else 0))
         if Z % bz != 0:
             raise ValueError(f"block_z={bz} does not divide Z={Z}")
-    omet.inc("wilson_mrhs_route_total", route=route)
+    omet.inc("wilson_mrhs_route_total", route=route,
+             epilogue="none" if xc_dtype is None else "combine")
     return route, bz, bt, limit
 
 
 def _mrhs_hop(g_c, g_m, psi_pl, X: int, eo, tb_sign: bool,
-              block_z, out_dtype, interpret: bool):
+              block_z, out_dtype, interpret: bool, xc=None, coeff=None,
+              g5: bool = False):
     """The MRHS pallas_call shared by the full-lattice and the eo
     wrapper: grid (T/bt, Z/bz, N), RHS innermost, links indexed by
-    (t, zb) alone."""
+    (t, zb) alone.  With ``xc`` (an array of the result's shape) and
+    ``coeff`` (a (1,) f32 array, in SMEM: an operand, so that a solve
+    program serves every kappa) the call writes
+    ``[g5] (xc + coeff * hop)``: ``_make_kernel``'s combine epilogue."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     N, _, _, _, T, Z, YX = psi_pl.shape
     R = g_c.shape[1]
     out_dtype = out_dtype or psi_pl.dtype
-    route, bz, bt, vmem_limit = _mrhs_route(T, Z, YX, psi_pl.dtype,
-                                            out_dtype, R, block_z)
+    combine = xc is not None
+    try:
+        route, bz, bt, vmem_limit = _mrhs_route(
+            T, Z, YX, psi_pl.dtype, out_dtype, R, block_z,
+            xc.dtype if combine else None)
+    except ValueError:
+        if not combine:
+            raise
+        # no route holds the xc block besides the hop's own (a z-block
+        # at _pick_bz's budget): the bare hop, if that fits, and XLA's
+        # pass over the batch, as without the epilogue
+        hop = _mrhs_hop(g_c, g_m, psi_pl, X, eo, tb_sign, block_z, F32,
+                        interpret)
+        v = xc.astype(F32) + coeff[0] * hop
+        if g5:
+            v = v * jnp.asarray([1, 1, -1, -1], F32).reshape(
+                (4,) + (1,) * 5)
+        return v.astype(out_dtype)
     nzb = Z // bz
 
     def psi_block(tb, zb, n):
@@ -736,26 +796,37 @@ def _mrhs_hop(g_c, g_m, psi_pl, X: int, eo, tb_sign: bool,
     gauge_spec = pl.BlockSpec(
         (4, R, 3, 2, bt, bz, YX), lambda tb, zb, n: (0, 0, 0, 0, tb, zb, 0))
 
-    psi_specs = [pl.BlockSpec((1, 4, 3, 2, bt, bz, YX), psi_block),
-                 psi_slice(bt), psi_slice(T - 1)]
+    def centre_spec():
+        return pl.BlockSpec((1, 4, 3, 2, bt, bz, YX), psi_block)
+
+    psi_specs = [centre_spec(), psi_slice(bt), psi_slice(T - 1)]
     if route == "fullz":
         body_rows, z_rows = _fullz_chunk(Z, psi_pl.dtype), "centre"
     else:
         body_rows, z_rows = bz, "tiles"
         psi_specs += [psi_slice(0, +1), psi_slice(0, -1)]
+    operands = [psi_pl] * len(psi_specs)
+    rest_specs = [gauge_spec, gauge_spec]
+    if combine:
+        # xc rides the out block's spec; the coefficient precedes the
+        # links (combine_kernel's operand order)
+        psi_specs.append(centre_spec())
+        operands.append(xc)
+        rest_specs.insert(0, pl.BlockSpec(memory_space=pltpu.SMEM))
     kernel = _mrhs_wrap(
         _make_kernel(X, body_rows, eo=eo, T=T, tb_sign=tb_sign,
-                     z_rows=z_rows), n_psi=len(psi_specs))
+                     z_rows=z_rows, combine=combine, g5=g5),
+        n_psi=len(psi_specs))
 
     return pl.pallas_call(
         kernel,
         grid=(T // bt, nzb, N),
-        in_specs=psi_specs + [gauge_spec, gauge_spec],
-        out_specs=pl.BlockSpec((1, 4, 3, 2, bt, bz, YX), psi_block),
+        in_specs=psi_specs + rest_specs,
+        out_specs=centre_spec(),
         out_shape=jax.ShapeDtypeStruct(psi_pl.shape, out_dtype),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
         interpret=interpret,
-    )(*([psi_pl] * len(psi_specs)), g_c, g_m)
+    )(*operands, *([coeff] if combine else []), g_c, g_m)
 
 
 @functools.partial(jax.jit,
@@ -782,7 +853,8 @@ def dslash_pallas_packed_mrhs(gauge_pl: jnp.ndarray, psi_pl: jnp.ndarray,
 
 @functools.partial(jax.jit, static_argnames=("dims", "target_parity",
                                              "interpret", "block_z",
-                                             "out_dtype", "tb_sign"))
+                                             "out_dtype", "tb_sign",
+                                             "g5"))
 def dslash_eo_pallas_packed_mrhs(u_here_pl: jnp.ndarray,
                                  u_bw_pl: jnp.ndarray,
                                  psi_pl: jnp.ndarray, dims,
@@ -790,7 +862,10 @@ def dslash_eo_pallas_packed_mrhs(u_here_pl: jnp.ndarray,
                                  interpret: bool = False,
                                  block_z: int | None = None,
                                  out_dtype=None,
-                                 tb_sign: bool = True) -> jnp.ndarray:
+                                 tb_sign: bool = True,
+                                 xc: jnp.ndarray | None = None,
+                                 coeff=None,
+                                 g5: bool = False) -> jnp.ndarray:
     """Multi-RHS checkerboarded Wilson hop — the batched-solver hot path
     (``dslash_eo_pallas_packed`` with a leading RHS axis on psi).
 
@@ -798,10 +873,19 @@ def dslash_eo_pallas_packed_mrhs(u_here_pl: jnp.ndarray,
     (N,4,3,2,T,Z,Y*Xh) of parity 1-p.  Gauge tiles are fetched once per
     (t, z-block) and shared by all N RHS (RHS-innermost grid); the
     route (full-Z tiles or z-blocks) follows the shapes, ``_mrhs_route``.
+
+    With ``xc`` (a batch of parity p, the result's shape) and ``coeff``
+    (a float or an f32 scalar array: an operand either way) the kernel's
+    epilogue writes ``xc + coeff * hop``, and ``g5`` puts gamma5 in
+    front of it: the second hop of the preconditioned operator then
+    hands back ``M x`` (or ``g5 M x``) itself, and no XLA pass over the
+    batch builds it from the bare hop sum.
     """
     X = dims[3]
+    if xc is not None:
+        coeff = jnp.asarray(coeff, F32).reshape(1)
     return _mrhs_hop(u_here_pl, u_bw_pl, psi_pl, X, (target_parity, X // 2),
-                     tb_sign, block_z, out_dtype, interpret)
+                     tb_sign, block_z, out_dtype, interpret, xc, coeff, g5)
 
 
 # -- hop algebra shared by the kernel bodies ---------------------------------

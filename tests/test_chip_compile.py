@@ -61,18 +61,30 @@ def _eo(dt, rows=3):
             [_links(dt, rows), _links(dt, rows), _psi(dt)])
 
 
-def _eo_mrhs(n, dt=F32, block_z=None):
+def _eo_mrhs(n, dt=F32, block_z=None, combine=False, lat=L, bt=2):
     """The MRHS kernel as the shapes route it: full-Z tiles at 24^4
     (three psi operands, two time-slices a step, its own
     ``vmem_limit_bytes``; 24 rows of bf16 pad to 32 sublanes, so that
-    VMEM sum is another); ``block_z = 8`` keeps the z-blocked fallback
-    compiled for the chip."""
+    VMEM sum is another), one slice a step at 32^4; ``block_z = 8``
+    keeps the z-blocked fallback compiled for the chip.  ``combine``:
+    with its combine epilogue (the second hop of the batched PC
+    operator: one more spinor block, the coefficient in SMEM, gamma5 in
+    registers), on each of those routes."""
     from quda_tpu.ops import wilson_pallas_packed as wpp
-    want = "zblock" if block_z else "fullz"
-    assert wpp._mrhs_route(L, L, YXH, dt, dt, 3, block_z)[0] == want
-    return (lambda u, ub, p: wpp.dslash_eo_pallas_packed_mrhs(
-                u, ub, p, DIMS, 0, block_z=block_z),
-            [_links(dt), _links(dt), _psi(dt, (n,))])
+    dims, yxh = (lat,) * 4, lat * lat // 2
+    want = ("zblock", block_z, 1) if block_z else ("fullz", lat, bt)
+    assert wpp._mrhs_route(lat, lat, yxh, dt, dt, 3, block_z,
+                           dt if combine else None)[:3] == want
+    links = ((4, 3, 3, 2, lat, lat, yxh), dt)
+    psi = ((n, 4, 3, 2, lat, lat, yxh), dt)
+    if not combine:
+        return (lambda u, ub, p: wpp.dslash_eo_pallas_packed_mrhs(
+                    u, ub, p, dims, 0, block_z=block_z),
+                [links, links, psi])
+    return (lambda u, ub, p, xc, k: wpp.dslash_eo_pallas_packed_mrhs(
+                u, ub, p, dims, 0, block_z=block_z, xc=xc, coeff=k,
+                g5=True),
+            [links, links, psi, psi, ((), F32)])
 
 
 def _cg_update(dt):
@@ -126,6 +138,13 @@ CASES = {
     "wilson_eo_mrhs_n8": lambda: _eo_mrhs(8),
     "wilson_eo_mrhs_n8_bf16": lambda: _eo_mrhs(8, BF16),
     "wilson_eo_mrhs_n8_zblock": lambda: _eo_mrhs(8, block_z=8),
+    "wilson_eo_mrhs_n8_combine": lambda: _eo_mrhs(8, combine=True),
+    "wilson_eo_mrhs_n8_combine_bf16": lambda: _eo_mrhs(
+        8, BF16, combine=True),
+    "wilson_eo_mrhs_n8_combine_32": lambda: _eo_mrhs(
+        8, combine=True, lat=32, bt=1),
+    "wilson_eo_mrhs_n8_combine_zblock": lambda: _eo_mrhs(
+        8, block_z=8, combine=True),
     "cg_update_norm2_f32": lambda: _cg_update(F32),
     "cg_update_norm2_bf16": lambda: _cg_update(BF16),
     "axpy_norm2_f32": _axpy_norm2,
@@ -373,6 +392,48 @@ def test_verified_exit_program_compiles_for_v5e(one_chip, n_src):
     assert not big, f"fields baked into the executable: {big}"
     assert compiled.memory_analysis().temp_size_in_bytes \
         < n_src * 0.4 * 2 ** 30
+
+
+def test_batched_solve_program_compiles_for_v5e_combining_in_the_kernel(
+        one_chip):
+    """The eight-source solve program (solvers/program.py over
+    ``block.batched_cg_pairs_loop``) at 24^4: the loop's ``MdagM`` is
+    four MRHS kernels, the second and the fourth with the combine
+    epilogue (seven operands: three spinor blocks, ``xc``, the
+    coefficient, the links), so what XLA is left with is the solver's
+    own updates; kappa and the links are parameters."""
+    import re
+    from quda_tpu.fields.geometry import LatticeGeometry
+    from quda_tpu.models.wilson import DiracWilsonPCPackedSloppy
+    from quda_tpu.solvers import program as sprog
+    from quda_tpu.solvers.fused_iter import _resolve_check_every
+    geom = LatticeGeometry(DIMS)
+
+    def lower():
+        lk = jax.ShapeDtypeStruct((4, 3, 3, L, L, YXH), jnp.complex64)
+        op = jax.eval_shape(
+            lambda e, o: DiracWilsonPCPackedSloppy.from_packed(
+                geom, (e, o), 0.124, 0, F32, use_pallas=True,
+                pallas_interpret=False), lk, lk)
+        op = jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(
+                s.shape, s.dtype, sharding=one_chip,
+                weak_type=s.weak_type), op)
+        b = jax.ShapeDtypeStruct(*_psi(F32, (8,)), sharding=one_chip)
+        key = (_resolve_check_every(None),
+               sprog._LoopKnobs(False, None, None, None))
+        return sprog._batched_cg_pairs_program.lower(op, b, 1e-6, 10000,
+                                                     key=key)
+    hlo = _aot(lower).as_text()
+    calls = re.findall(r"%dslash_eo_pallas_packed_mrhs[.\d]* = f32\["
+                       r"[^\n]*custom-call\(([^\n]*?)\), custom_call_"
+                       r"target=\"tpu_custom_call\"", hlo)
+    assert sorted(c.count("%") for c in calls) == [5, 5, 7, 7], calls
+    links = ",".join(str(d) for d in _links(F32)[0])
+    assert sum(p[1:] == ("f32", links)
+               for p in _hlo_values(hlo, "parameter")) == 4
+    big = [c for c in _hlo_values(hlo, "constant") if c[0] > 2 ** 20]
+    assert not big, f"fields baked into the executable: {big}"
 
 
 def test_ks_links_construction_compiles_for_v5e_lattice_minor(one_chip):

@@ -99,13 +99,18 @@ def test_split_grid_solve_matches_serial(problem):
 # ---------------------------------------------------------------------------
 
 @pytest.fixture(scope="module")
-def pair_problem():
+def pair_dpk():
+    gauge = GaugeField.random(jax.random.PRNGKey(23),
+                              GEOM_SMALL).data.astype(jnp.complex64)
+    return DiracWilsonPC(gauge, GEOM_SMALL, 0.12, matpc=EVEN).packed()
+
+
+@pytest.fixture(scope="module")
+def pair_problem(pair_dpk):
     """Complex-free packed pair-form PC batch problem (XLA stencil — the
     vmap-fallback MRHS path, exact vs the pallas route's math)."""
     k = jax.random.PRNGKey(23)
-    gauge = GaugeField.random(k, GEOM_SMALL).data.astype(jnp.complex64)
-    dpk = DiracWilsonPC(gauge, GEOM_SMALL, 0.12, matpc=EVEN).packed()
-    op = dpk.pairs(jnp.float32)
+    op = pair_dpk.pairs(jnp.float32)
     bs = [ColorSpinorField.gaussian(jax.random.fold_in(k, i),
                                     GEOM_SMALL).data.astype(jnp.complex64)
           for i in range(NRHS)]
@@ -130,6 +135,33 @@ def test_mrhs_operator_composition_matches_per_rhs(pair_problem):
     mm_b = op.MdagM_pairs_mrhs(nrm_b)
     mm_i = jnp.stack([op.MdagM_pairs(nrm_b[i]) for i in range(NRHS)])
     assert bool(jnp.all(mm_b == mm_i))
+
+
+@pytest.mark.parametrize("store", [
+    jnp.float32, pytest.param(jnp.bfloat16, marks=pytest.mark.slow)])
+def test_mrhs_operator_on_the_pallas_route_combines_in_the_second_hop(
+        pair_dpk, pair_problem, store, route_counts):
+    """On the pallas route the batched operator's second hop writes
+    ``[g5] (x - kappa^2 D D x)`` itself (the combine epilogue), and the
+    result is still the per-source composition stacked: against
+    ``MdagM_pairs`` / ``Mdag_pairs`` of the XLA stencil operator, whose
+    hop differs from the kernel's in the order of its sums (a few ulp;
+    in bf16 storage the intermediate roundings may fall either way)."""
+    xla = pair_dpk.pairs(store)
+    op = pair_dpk.pairs(store, use_pallas=True, pallas_interpret=True)
+    x = pair_problem[4].astype(store)
+    tol = 8 * float(jnp.finfo(store).eps)
+    for name in ("MdagM_pairs", "Mdag_pairs"):
+        got = getattr(op, name + "_mrhs")(x).astype(jnp.float32)
+        want = jnp.stack([getattr(xla, name)(x[i]) for i in range(NRHS)]
+                         ).astype(jnp.float32)
+        assert got.shape == want.shape
+        assert float(jnp.max(jnp.abs(got - want))) \
+            <= tol * float(jnp.max(jnp.abs(want)))
+    # two kernels traced, both full-Z: the first hop bare, the second
+    # with the epilogue (Mdag's are MdagM's own, not traced again)
+    assert route_counts("epilogue") == {"none": 1.0, "combine": 1.0}
+    assert route_counts() == {"fullz": 2.0}
 
 
 BATCH_TOL = 1e-7
@@ -521,15 +553,21 @@ def _eo_mrhs_problem(dims, parity, nrhs, dtype, seed=8):
 @pytest.fixture
 def route_counts(tmp_path):
     """A metrics session of the test's own; calling the fixture reads
-    ``wilson_mrhs_route_total`` as {route: count}."""
+    ``wilson_mrhs_route_total`` as {route: count}, or by its other
+    label, ``epilogue``."""
     from quda_tpu.obs import memory as omem
     from quda_tpu.obs import metrics as omet
     omet.stop(flush_files=False)
     omem.reset()
     omet.start(str(tmp_path))
-    yield lambda: {dict(lab)["route"]: v for (n, lab), v in
-                   omet.snapshot()["counters"].items()
-                   if n == "wilson_mrhs_route_total"}
+
+    def read(label="route"):
+        out = {}
+        for (n, lab), v in omet.snapshot()["counters"].items():
+            if n == "wilson_mrhs_route_total":
+                out[dict(lab)[label]] = out.get(dict(lab)[label], 0.0) + v
+        return out
+    yield read
     omet.stop(flush_files=False)
     omem.reset()
 
@@ -580,8 +618,88 @@ def test_mrhs_zblock_route_runs_where_fullz_does_not_fit(route_counts):
     assert route_counts() == {"zblock": 1.0}
 
 
+def _one_slice(monkeypatch, wpp, dims, dtype):
+    # a cap that one time-slice a step passes with the xc block and two
+    # do not
+    T, Z, Y, X = dims
+    one, two = (wpp._mrhs_fullz_vmem(Z, Y * X // 2, dtype, dtype, 3, bt,
+                                     dtype)[1] for bt in (1, 2))
+    monkeypatch.setattr(wpp, "_MRHS_FULLZ_VMEM_CAP", (one + two) // 2)
+
+
+def _no_room_for_xc(monkeypatch, wpp, dims, dtype):
+    # no full-Z tiles, and a z-block budget that the hop's 288 planes
+    # pass and the 312 with the xc block do not
+    monkeypatch.setattr(wpp, "_MRHS_FULLZ_VMEM_CAP", 0)
+    plane = wpp._sublane_rows(dtype) * 128 * jnp.dtype(dtype).itemsize
+    monkeypatch.setenv("QUDA_TPU_PALLAS_VMEM_MB",
+                       str(300 * plane / 2 ** 20))
+
+
+# route -> (what bends the shapes' own choice, the route counted, the
+# epilogue counted).  A bent call has a batch of its own size: the cap
+# and the knob are no part of the jitted call's key.
+_COMBINE_ROUTES = {"fullz2": (None, "fullz", "combine"),
+                   "fullz1": (_one_slice, "fullz", "combine"),
+                   "zblock": (None, "zblock", "combine"),
+                   "xla": (_no_room_for_xc, "zblock", "none")}
+
+
+@pytest.mark.parametrize("parity,g5,route,dtype", [
+    (0, True, "fullz2", jnp.float32),
+    pytest.param(1, False, "zblock", jnp.float32, marks=pytest.mark.slow),
+    pytest.param(1, True, "fullz1", jnp.float32, marks=pytest.mark.slow),
+    pytest.param(0, True, "xla", jnp.float32, marks=pytest.mark.slow),
+    pytest.param(1, True, "fullz2", jnp.bfloat16, marks=pytest.mark.slow),
+    pytest.param(0, False, "fullz1", jnp.bfloat16,
+                 marks=pytest.mark.slow),
+    pytest.param(0, True, "zblock", jnp.bfloat16,
+                 marks=pytest.mark.slow)])
+def test_mrhs_combine_epilogue_is_xla_on_the_plain_hop(
+        parity, g5, route, dtype, route_counts, monkeypatch):
+    """``xc``, ``coeff``, ``g5``: the call writes ``[g5] (xc + coeff *
+    hop)`` from its f32 accumulators, rounded to the storage dtype once,
+    on either route, and is counted with ``epilogue="combine"``; where
+    no route holds the xc block (``xla``) XLA combines the bare hop, as
+    without the epilogue.  Against the plain call's f32 hop combined by
+    XLA: the last bit may differ (one contracts the multiply-add, one
+    does not), and with it now and then the bf16 a sum rounds to."""
+    from quda_tpu.ops import wilson_pallas_packed as wpp
+    bend, counted, epilogue = _COMBINE_ROUTES[route]
+    dims, nrhs = _fz_dims(dtype), 2 if bend is None else 3
+    kw = ({"block_z": wpp._sublane_rows(dtype)} if route == "zblock"
+          else {})
+    if bend is not None:
+        bend(monkeypatch, wpp, dims, dtype)
+    u_here, u_bw, psi_b = _eo_mrhs_problem(dims, parity, nrhs, dtype)
+    xc = _eo_mrhs_problem(dims, parity, nrhs, dtype, seed=9)[2]
+    coeff = -0.12 ** 2
+    got = wpp.dslash_eo_pallas_packed_mrhs(
+        u_here, u_bw, psi_b, dims, parity, interpret=True, xc=xc,
+        coeff=coeff, g5=g5, **kw)
+    hop = wpp.dslash_eo_pallas_packed_mrhs(
+        u_here, u_bw, psi_b, dims, parity, interpret=True,
+        out_dtype=jnp.float32, **kw)
+    want = xc.astype(jnp.float32) + jnp.float32(coeff) * hop
+    if g5:
+        want = want * jnp.asarray([1, 1, -1, -1], jnp.float32).reshape(
+            1, 4, 1, 1, 1, 1, 1)
+    want = want.astype(dtype).astype(jnp.float32)
+    assert got.dtype == dtype and got.shape == psi_b.shape
+    diff = jnp.abs(got.astype(jnp.float32) - want)
+    ulp = float(jnp.finfo(dtype).eps) * float(jnp.max(jnp.abs(want)))
+    assert float(jnp.max(diff)) <= ulp
+    if dtype == jnp.bfloat16:
+        assert float(jnp.mean(diff == 0)) > 0.99
+    assert route_counts() == {counted: 2.0}
+    assert route_counts("epilogue") == (
+        {"none": 2.0} if epilogue == "none"
+        else {"none": 1.0, "combine": 1.0})
+
+
 @pytest.mark.parametrize("case,want", [
-    # (T, Z, YX, storage, out, link rows R, block_z) -> (route, bz, bt)
+    # (T, Z, YX, storage, out, link rows R, block_z[, xc storage])
+    # -> (route, bz, bt)
     ((24, 24, 288, jnp.float32, jnp.float32, 3, None), ("fullz", 24, 2)),
     ((24, 24, 288, jnp.bfloat16, jnp.bfloat16, 3, None), ("fullz", 24, 2)),
     ((24, 24, 288, jnp.bfloat16, jnp.float32, 3, None), ("fullz", 24, 2)),
@@ -592,7 +710,22 @@ def test_mrhs_zblock_route_runs_where_fullz_does_not_fit(route_counts):
     ((32, 32, 512, jnp.float32, jnp.float32, 3, None), ("fullz", 32, 1)),
     ((32, 32, 512, jnp.bfloat16, jnp.bfloat16, 3, None), ("fullz", 32, 2)),
     ((40, 40, 640, jnp.float32, jnp.float32, 3, None), ("zblock", 8, 1)),
-    ((8, 8, 8, jnp.float32, jnp.float32, 3, None), ("fullz", 8, 2))])
+    ((8, 8, 8, jnp.float32, jnp.float32, 3, None), ("fullz", 8, 2)),
+    # the combine epilogue's xc block is one more operand: 24^4 still
+    # takes two slices a step (38.8 MiB of the 48), a 32 x 32 plane at
+    # Z = 24 takes two without it and one with it, and where the hop's
+    # own z-block is the largest that fits no route holds it
+    ((24, 24, 288, jnp.float32, jnp.float32, 3, None, jnp.float32),
+     ("fullz", 24, 2)),
+    ((24, 24, 288, jnp.bfloat16, jnp.bfloat16, 3, None, jnp.bfloat16),
+     ("fullz", 24, 2)),
+    ((24, 24, 512, jnp.float32, jnp.float32, 3, None), ("fullz", 24, 2)),
+    ((24, 24, 512, jnp.float32, jnp.float32, 3, None, jnp.float32),
+     ("fullz", 24, 1)),
+    ((24, 24, 288, jnp.float32, jnp.float32, 3, 8, jnp.float32),
+     ("zblock", 8, 1)),
+    ((40, 40, 640, jnp.float32, jnp.float32, 3, None, jnp.float32),
+     ValueError)])
 def test_mrhs_route_follows_the_shapes(case, want, route_counts):
     """The route is arithmetic on (T, Z, YX, dtypes, R): padded planes x
     bytes, twice for the pipeline's buffers, plus the body's tiles,
@@ -603,11 +736,19 @@ def test_mrhs_route_follows_the_shapes(case, want, route_counts):
     keeps the route's blocks beside the knob's own row."""
     from quda_tpu.obs import memory as omem
     from quda_tpu.ops import wilson_pallas_packed as wpp
-    T, Z, YX, dt, odt, R, block_z = case
-    route, bz, bt, limit = wpp._mrhs_route(T, Z, YX, dt, odt, R, block_z)
+    T, Z, YX, dt, odt, R, block_z, xc_dt = (case + (None,))[:8]
+    if want is ValueError:
+        with pytest.raises(ValueError, match="fits the VMEM budget"):
+            wpp._mrhs_route(T, Z, YX, dt, odt, R, block_z, xc_dt)
+        assert route_counts() == {}
+        return
+    route, bz, bt, limit = wpp._mrhs_route(T, Z, YX, dt, odt, R, block_z,
+                                           xc_dt)
     rows = {r["knob"]: r for r in omem.audit_vmem_budgets()}
     assert (route, bz, bt) == want and route_counts() == {route: 1.0}
-    blocks, need = wpp._mrhs_fullz_vmem(Z, YX, dt, odt, R, bt)
+    assert route_counts("epilogue") == {
+        "none" if xc_dt is None else "combine": 1.0}
+    blocks, need = wpp._mrhs_fullz_vmem(Z, YX, dt, odt, R, bt, xc_dt)
     if route == "fullz":
         assert need <= limit <= wpp._MRHS_FULLZ_VMEM_CAP
         row = rows["QUDA_TPU_PALLAS_VMEM_MB[fullz]"]

@@ -228,6 +228,32 @@ def test_second_kappa_reuses_the_program(route, warm, gauges):
     assert _host_residual(gauges["A"], b[0], x[0], KAPPA) > 1e-3
 
 
+def test_batched_route_operator_combines_in_its_second_hops(quda):
+    """The batched route's resident operator hands its program ``Ap``
+    and not the bare hop sums: tracing ``MdagM`` on a batch of a size
+    this process has not traced traces two kernels, the first hop bare
+    and the second with the combine epilogue
+    (``wilson_mrhs_route_total``); kappa is an operand of that epilogue,
+    which is why the second kappa above is a hit."""
+    def counts():
+        out = {}
+        for (name, labels), v in omet.snapshot()["counters"].items():
+            if name == "wilson_mrhs_route_total":
+                lab = dict(labels)
+                out[lab["route"], lab["epilogue"]] = int(v)
+        return out
+    op = api._resident_wilson(_param())["ops"][jnp.dtype(jnp.float32)]
+    before = counts()
+    batch = jax.ShapeDtypeStruct((5, 4, 3, 2, L, L, L * L // 2),
+                                 jnp.float32)
+    out = jax.eval_shape(op.with_kappa(KAPPA).MdagM_pairs_mrhs, batch)
+    assert (out.shape, out.dtype) == (batch.shape, batch.dtype)
+    after = counts()
+    assert {k: after[k] - before.get(k, 0) for k in after
+            if after[k] != before.get(k, 0)} == {
+        ("fullz", "combine"): 1, ("fullz", "none"): 1}
+
+
 # the resident term and the verified exit, through the API -----------------
 
 def _load(gauge):
