@@ -429,29 +429,22 @@ def qudaMultigridDestroy():
 
 
 def qudaInvertMsrc(mass: float, sources, tol: float = 1e-10,
-                   maxiter: int = 10000, improved: bool = True):
-    """qudaInvertMsrc (quda_milc_interface.h:443): multi-source solve,
-    batched over the leading axis (solvers/block.py)."""
-    from ..fields.spinor import even_odd_join, even_odd_split
-    from ..models.staggered import DiracStaggeredPC
-    from ..solvers.block import batched_cg
-    geom = api._ctx["geom"]
-    fat = api._ctx["fat"] if improved else api._ctx["gauge"]
-    lng = api._ctx["long"] if improved else None
-    dpc = DiracStaggeredPC(fat, geom, mass, improved, lng)
-    B = jnp.asarray(sources)
-    be = jnp.stack([even_odd_split(B[i], geom)[0]
-                    for i in range(B.shape[0])])
-    bo = jnp.stack([even_odd_split(B[i], geom)[1]
-                    for i in range(B.shape[0])])
-    rhs = jnp.stack([dpc.prepare(be[i], bo[i]) for i in range(B.shape[0])])
-    res = batched_cg(dpc.M, rhs, tol=tol, maxiter=maxiter)
-    outs = []
-    for i in range(B.shape[0]):
-        xe, xo = dpc.reconstruct(res.x[i], be[i], bo[i])
-        outs.append(even_odd_join(xe, xo, geom))
-    return jnp.stack(outs), {
-        "iters": [int(i) for i in np.asarray(res.iters).reshape(-1)]}
+                   maxiter: int = 10000, improved: bool = True,
+                   prec="double", sloppy_prec="single"):
+    """qudaInvertMsrc (quda_milc_interface.h:443): one mass, num_src
+    sources (leading axis) against the loaded links, through
+    ``invert_multi_src_quda`` as ``qudaInvert`` goes through
+    ``invert_quda``: in single precision on the packed route the batch
+    solves on the resident KS term (``qudaLoadKSLink``).  Returns
+    (solutions, info) with per-source iterations and verified
+    residuals."""
+    p = InvertParam(
+        dslash_type="hisq" if improved else "staggered",
+        inv_type="cg", solve_type="normop-pc", mass=mass, tol=tol,
+        maxiter=maxiter, cuda_prec=prec, cuda_prec_sloppy=sloppy_prec)
+    x = api.invert_multi_src_quda(sources, p)
+    return x, {"iters": list(p.iter_count_multi),
+               "true_res": list(p.true_res_multi), "secs": p.secs}
 
 
 def qudaEigCGInvert(mass: float, source, n_ev: int = 8, m: int = 32,

@@ -507,6 +507,13 @@ def _set_resident_ks(fat, long_links):
             omem.track("fat_naik", field, g)
 
 
+def _ks_links_loaded(param: InvertParam) -> bool:
+    """An improved-staggered solve with both link fields resident: what
+    the pair routes need to solve on ``_resident_staggered``."""
+    return (param.dslash_type in ("asqtad", "hisq")
+            and _ctx["fat"] is not None and _ctx["long"] is not None)
+
+
 def _ks_term_key(param: InvertParam, on_tpu: bool) -> tuple:
     """What the resident KS pair operators depend on: the generation of
     the fat / long pair, matpc, the fermion boundary and the kernel
@@ -1474,9 +1481,7 @@ def _invert_quda_body(source, param: InvertParam):
         wilson_resident = wil_pairs and not df64_route
         # so does the improved-staggered pair route, on the fat and
         # long links as loaded (load_fat_long_quda builds the term)
-        ks_resident = (stag_pairs and param.dslash_type != "staggered"
-                       and _ctx["fat"] is not None
-                       and _ctx["long"] is not None)
+        ks_resident = stag_pairs and _ks_links_loaded(param)
         stores = (_pair_store(sloppy_prec),) if mixed else ()
         if ks_resident:
             d = _StaggeredResidentSolve(
@@ -2072,30 +2077,45 @@ def _invert_multi_src_body(sources, param: InvertParam):
         from ..solvers.block import (_per_rhs_dot, batched_cg_pairs,
                                      block_cg_pairs)
         with otr.phase("setup", "invert_multi_src_quda"):
+            # the improved-staggered batch solves on the resident KS
+            # term, as invert_quda's ks_resident route
+            ks_resident = _ks_links_loaded(param)
             if param.dslash_type == "wilson":
                 # the resident f32 pair operator, as invert_quda's
                 # wil_pairs route; it presents off a mesh, and then the
                 # verified exit is its own program
                 op = _WilsonPairsSolve(_resident_wilson(param),
                                        param.kappa).op
+            elif ks_resident:
+                op = _StaggeredResidentSolve(_resident_staggered(param),
+                                             param.mass).op
             else:
-                # staggered: pin the two_pass form — this route only
-                # ever runs the gather MRHS kernel (_d_to_mrhs), so
-                # 'auto' would race single-RHS kernels whose winner is
-                # never used
+                # plain staggered has no resident term: built per call,
+                # on the gather form its MRHS kernel reads
                 kw = ({"form": "two_pass"} if stag_family else {})
                 op = _build_dirac(param, True).pairs(
                     jnp.float32, use_pallas=_pallas_enabled(on_tpu),
                     pallas_interpret=_pallas_interpret(on_tpu), **kw)
-            pair_exit = (param.dslash_type == "wilson"
+            form_b = ("staggered" if stag_family
+                      else param.dslash_type.replace("-", "_")
+                      if zoo_family else "wilson") + "_batched_pairs"
+            pair_exit = ((param.dslash_type == "wilson" or ks_resident)
                          and sprog.presents(op))
-            halves = [even_odd_split(B[i], geom) for i in range(n_src)]
-            be = jnp.stack([h[0] for h in halves])
-            bo = jnp.stack([h[1] for h in halves])
-            del halves
-            rhs_b = op.prepare_pairs_mrhs(be, bo)
-            if pair_exit:
-                del be, bo      # the verified exit splits B itself
+            if ks_resident and pair_exit:
+                # parity split and prepare of the batch: one program
+                with otr.span("prepare", cat="setup") as span:
+                    rhs_b, hit = sprog.prepare(op, B)
+                    _note_solve_program(span, "invert_multi_src_quda",
+                                        form_b, "prepare", hit)
+            else:
+                halves = [even_odd_split(B[i], geom)
+                          for i in range(n_src)]
+                be = jnp.stack([h[0] for h in halves])
+                bo = jnp.stack([h[1] for h in halves])
+                del halves
+                rhs_b = op.prepare_pairs_mrhs(be, bo)
+                if pair_exit:
+                    del be, bo      # the verified exit splits B itself
             if stag_family:
                 # the staggered PC operator is already the (Hermitian
                 # positive definite) normal operator — the batched CG
@@ -2114,9 +2134,6 @@ def _invert_multi_src_body(sources, param: InvertParam):
                                       fresh=True)) == "1"
         solver_name = "block-cg-pairs" if use_block else \
             "batched-cg-pairs"
-        form_b = ("staggered" if stag_family
-                  else param.dslash_type.replace("-", "_") if zoo_family
-                  else "wilson") + "_batched_pairs"
         t_solve0 = time.perf_counter()
         with otr.phase("compute", "invert_multi_src_quda"), \
                 otr.span(f"solve:{solver_name}", cat="solver",
@@ -2127,12 +2144,10 @@ def _invert_multi_src_body(sources, param: InvertParam):
                                      maxiter=param.maxiter,
                                      record=recording)
                 iters_rhs = np.full(n_src, int(res.iters))
-            elif sprog.presents(op) and not getattr(op, "hermitian",
-                                                    False):
-                # the loop traced once per process (the batched program
-                # has the normal equations only: mv_b IS the operator's
-                # MdagM_pairs_mrhs; a Hermitian operator, the staggered
-                # one, keeps the eager loop on its M_pairs_mrhs)
+            elif sprog.presents(op):
+                # the loop traced once per process: the program applies
+                # what mv_b is, the operator's MdagM_pairs_mrhs, or its
+                # M_pairs_mrhs where it is Hermitian (staggered)
                 res, hit = sprog.batched_cg_pairs(
                     op, nrm_b, tol=param.tol, maxiter=param.maxiter,
                     record=recording)

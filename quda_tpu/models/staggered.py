@@ -185,6 +185,19 @@ STAGGERED_PRECISION_FORMS = ("full", "r12", "fold")
 # 0.0964 and fused 0.1027.  'auto' still races.
 MEASURED_FORMS = {"fat_naik": "v3"}
 
+# The batched hop (``_d_to_mrhs``) of a single-chip pallas operator on
+# full-storage links, by hop set: ``gather_two_pass`` the gather MRHS
+# kernel on pre-shifted backward links (five psi tiles a source),
+# ``scatter_two_pass`` the v3 scatter pass under the same RHS-innermost
+# wrap (three psi tiles, no backward links), ``vmap`` jax.vmap of the
+# single-source hop (the source axis outermost: links read once per
+# source).  Fat + Naik is served from the chip's reading, no race: one
+# v5e, 24^4, 8 sources, f32 (PERF.md section 6, PR 35), eagerly a hop
+# reads scatter 1,336 us, gather 1,406, vmap of v3 3,132, and in the
+# cell hisq24_mrhs8.strange call_s 0.636 / 0.656 / 1.304 s.  The
+# fat-only hop set, never read on the chip, keeps the gather kernel.
+MEASURED_MRHS_FORMS = {"fat_naik": "scatter_two_pass"}
+
 
 class DiracStaggeredPCPairs(_ProgramOperand):
     """Complex-free packed pair-form of DiracStaggeredPC — the staggered
@@ -245,7 +258,7 @@ class DiracStaggeredPCPairs(_ProgramOperand):
                        "_long_sign", "mass")
     _PROGRAM_STATIC = ("geom", "dims", "matpc", "store_dtype",
                        "use_pallas", "_pallas_interpret", "_pallas_form",
-                       "_precision_form")
+                       "_precision_form", "_mrhs_form")
 
     def __init__(self, dpc: DiracStaggeredPC, store_dtype=jnp.float32,
                  use_pallas: bool = False, pallas_interpret: bool = False,
@@ -439,6 +452,15 @@ class DiracStaggeredPCPairs(_ProgramOperand):
         # scatter/fused forms read the opposite-parity links as-is)
         if use_pallas and mesh is None and form == "two_pass":
             self._ensure_bw()
+
+        # the batched hop: an MRHS kernel streams full R=3 link tiles
+        # on one chip; r12 / fold storage, a mesh and the XLA stencil
+        # vmap the single-source hop
+        self._mrhs_form = (
+            MEASURED_MRHS_FORMS.get("fat_naik" if improved else "fat",
+                                    "gather_two_pass")
+            if use_pallas and mesh is None and pform == "full"
+            else "vmap")
 
         # multi-chip: move the resident links (and the globally
         # pre-shifted backward links the gather form needs) onto the
@@ -827,28 +849,37 @@ class DiracStaggeredPCPairs(_ProgramOperand):
             self.long_eo_pp, out_dtype=out_dtype)
 
     def _d_to_mrhs(self, psi_b, target_parity, out_dtype=None):
-        """Batched eo hop: psi_b (N,3,2,T,Z,Y*Xh).  The single-chip
-        pallas path routes the MRHS kernel (fat/long tiles fetched once
-        per (t, z-block), N spinor tiles streamed through them — the
-        round-7 Wilson move on the second headline family); everything
-        else falls back to the vmapped single-RHS stencil."""
+        """Batched eo hop: psi_b (N,3,2,T,Z,Y*Xh), by ``_mrhs_form``
+        (see ``MEASURED_MRHS_FORMS``).  The MRHS kernels fetch the
+        fat/long tiles once per (t, z-block) and stream the N spinor
+        tiles through them; ``vmap`` is the single-RHS stencil per
+        source.  Counted per traced call in
+        ``staggered_mrhs_route_total``."""
+        from ..obs import metrics as omet
         out_dtype = out_dtype or self.store_dtype
-        if (self.use_pallas and self._mesh is None
-                and getattr(self, "_precision_form", "full") == "full"):
-            # the gather MRHS kernel streams full R=3 fat/long tiles;
-            # r12/fold storage vmaps the single-RHS fused form instead
-            from ..ops import staggered_pallas as spl
-            self._ensure_bw()
-            p = target_parity
-            return spl.dslash_staggered_eo_pallas_mrhs(
-                self.fat_eo_pp[p], self._fat_bw[p], psi_b, self.dims, p,
-                long_here_pl=(self.long_eo_pp[p]
-                              if self.long_eo_pp is not None else None),
-                long_bw_pl=(self._long_bw[p]
-                            if self._long_bw is not None else None),
+        form, p = self._mrhs_form, target_parity
+        omet.inc("staggered_mrhs_route_total",
+                 form=form if form != "vmap" else "vmap_" + (
+                     self._pallas_form if self.use_pallas else "xla"))
+        if form == "vmap":
+            return jax.vmap(
+                lambda q: self.D_to_pairs(q, p, out_dtype))(psi_b)
+        from ..ops import staggered_pallas as spl
+        lng = self.long_eo_pp
+        if form == "scatter_two_pass":
+            return spl.dslash_staggered_eo_pallas_v3_mrhs(
+                self.fat_eo_pp[p], self.fat_eo_pp[1 - p], psi_b,
+                self.dims, p,
+                long_here_pl=lng[p] if lng is not None else None,
+                long_there_pl=lng[1 - p] if lng is not None else None,
                 interpret=self._pallas_interpret, out_dtype=out_dtype)
-        return jax.vmap(
-            lambda q: self.D_to_pairs(q, target_parity, out_dtype))(psi_b)
+        self._ensure_bw()
+        return spl.dslash_staggered_eo_pallas_mrhs(
+            self.fat_eo_pp[p], self._fat_bw[p], psi_b, self.dims, p,
+            long_here_pl=lng[p] if lng is not None else None,
+            long_bw_pl=(self._long_bw[p]
+                        if self._long_bw is not None else None),
+            interpret=self._pallas_interpret, out_dtype=out_dtype)
 
     def M_pairs(self, x_pp):
         """(4m^2 - D_pq D_qp) on pair arrays — Hermitian positive
@@ -955,22 +986,28 @@ class DiracStaggeredPCPairs(_ProgramOperand):
         under the full M = 2m + D, parity by parity with this
         operator's own hop in f32 (on the q sites it is what rounding
         leaves of the reconstruction): no canonical (...,1,3) temporary
-        beyond the two boundaries.  Meant to be traced
-        (solvers/program.py) on the f32 operator."""
+        beyond the two boundaries.  With a leading source axis on both,
+        the batched hop and one residual per source.  Meant to be
+        traced (solvers/program.py) on the f32 operator."""
         from ..fields.spinor import even_odd_join, even_odd_split
         f32, p, m2 = jnp.float32, self.matpc, 2.0 * self.mass
-        halves = even_odd_split(b, self.geom)
-        b_p, b_q = (self._to_pairs(h).astype(f32)
+        batched = b.ndim == 7
+        per_src = jax.vmap if batched else (lambda f: f)
+        hop = ((lambda v, par: self._d_to_mrhs(v, par, f32)) if batched
+               else (lambda v, par: self.D_to_pairs(v, par, out_dtype=f32)))
+        halves = per_src(lambda v: even_odd_split(v, self.geom))(b)
+        b_p, b_q = (per_src(self._to_pairs)(h).astype(f32)
                     for h in (halves if p == EVEN else halves[::-1]))
         x_p = x_pp.astype(f32)
-        d_xp = self.D_to_pairs(x_p, 1 - p, out_dtype=f32)
+        d_xp = hop(x_p, 1 - p)
         x_q = (b_q - d_xp) / m2
-        r_p = b_p - (m2 * x_p + self.D_to_pairs(x_q, p, out_dtype=f32))
+        r_p = b_p - (m2 * x_p + hop(x_q, p))
         r_q = b_q - (m2 * x_q + d_xp)
-        norm2 = lambda v: jnp.sum(v * v)
-        x_e, x_o = (self._from_pairs(v, b.dtype)
+        norm2 = per_src(lambda v: jnp.sum(v * v))
+        x_e, x_o = (per_src(lambda w: self._from_pairs(w, b.dtype))(v)
                     for v in ((x_p, x_q) if p == EVEN else (x_q, x_p)))
-        return (even_odd_join(x_e, x_o, self.geom),
+        join = per_src(lambda e, o: even_odd_join(e, o, self.geom))
+        return (join(x_e, x_o),
                 jnp.sqrt((norm2(r_p) + norm2(r_q))
                          / (norm2(b_p) + norm2(b_q))))
 

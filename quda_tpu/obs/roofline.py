@@ -137,11 +137,12 @@ KERNEL_MODELS: Dict[str, dict] = {
     # unfolded fused attribution)
     "staggered_fat_naik_fused_fold": {"flops_per_site": 1146,
                                       "bytes_per_site": 864},
-    # MRHS staggered (gather two-pass body, links amortized over N):
-    # improved = 2 passes x (psi 120 + out 24) + sum 72 + 1152/N links;
-    # fat-only = one pass, no sum
+    # MRHS staggered, links amortized over N.  improved: the served
+    # scatter two-pass body (models/staggered.MEASURED_MRHS_FORMS) =
+    # 2 passes x (psi 72 + out 24) + sum 72 + 1152/N links; fat-only:
+    # one gather pass (psi 120 + out 24), no sum
     "staggered_mrhs": {"flops_per_site": 1146,
-                       "bytes_per_site": lambda nrhs: 360.0
+                       "bytes_per_site": lambda nrhs: 264.0
                        + 1152.0 / nrhs},
     "staggered_fat_mrhs": {"flops_per_site": 570,
                            "bytes_per_site": lambda nrhs: 144.0

@@ -133,7 +133,7 @@ TRACE_EVENTS: dict[str, dict] = {
 
 SPAN_ATTRS: dict[str, dict] = {
     "program": {"spans": ("solve:cg", "solve:batched-cg-pairs",
-                          "verified_exit"),
+                          "verified_exit", "prepare"),
                 "doc": "'hit' | 'miss': whether the cached solve "
                        "program (solvers/program.py) served the call "
                        "from the in-process executable cache or traced "
@@ -176,9 +176,10 @@ METRICS: dict[str, dict] = {
     "solve_program_total": {
         "type": COUNTER,
         "help": "calls through a cached program (solvers/program.py: "
-                "the solve loops, and solver='verified-exit' the "
-                "Wilson and staggered pair routes' verified exit), by "
-                "api/form/solver/outcome: 'miss' traced (and lowered, "
+                "the solve loops, solver='verified-exit' the Wilson "
+                "and staggered pair routes' verified exit, and "
+                "solver='prepare' the batched staggered route's entry), "
+                "by api/form/solver/outcome: 'miss' traced (and lowered, "
                 "compiled or fetched) the program, 'hit' was an "
                 "in-process executable lookup"},
     "clover_term_total": {
@@ -198,8 +199,9 @@ METRICS: dict[str, dict] = {
     "ks_term_total": {
         "type": COUNTER,
         "help": "uses of the resident KS pair operators "
-                "(load_fat_long_quda, asqtad / hisq invert_quda on the "
-                "pair route) by outcome: 'built' nothing was resident, "
+                "(load_fat_long_quda, asqtad / hisq invert_quda and "
+                "invert_multi_src_quda on the pair routes) by outcome: "
+                "'built' nothing was resident, "
                 "'reused' the resident operators served, 'rebuilt' "
                 "another matpc, boundary or kernel route replaced them; "
                 "new fat / long links or a new gauge drop them"},
@@ -213,6 +215,15 @@ METRICS: dict[str, dict] = {
                 "epilogue: 'combine' the store writes [g5] (xc + coeff * "
                 "hop) (the second hop of the batched PC operator), 'none' "
                 "the bare hop sum"},
+    "staggered_mrhs_route_total": {
+        "type": COUNTER,
+        "help": "traced calls of the batched staggered hop "
+                "(models/staggered.DiracStaggeredPCPairs._d_to_mrhs) by "
+                "form: 'gather_two_pass' the gather MRHS kernel on "
+                "pre-shifted backward links, 'scatter_two_pass' the v3 "
+                "scatter pass with the RHS axis innermost, 'vmap_<form>' "
+                "jax.vmap of the single-source hop (the pallas form, or "
+                "'xla')"},
     # tuner warm-cache accounting (utils/tune.py)
     "tune_cache_hits_total": {
         "type": COUNTER,

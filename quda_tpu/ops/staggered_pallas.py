@@ -1186,6 +1186,115 @@ def dslash_staggered_eo_pallas_mrhs(fat_here_pl, fat_bw_pl, psi_pl, dims,
     return out.astype(odt)
 
 
+# The scatter (v3) pass under the same wrap: no pre-shifted backward
+# links (the backward hops read the opposite parity's links as they
+# are), three full psi tiles a source instead of five.  Per pass and
+# output site, N sources: links 576 B in f32 once (u 288, u_there_xyz
+# 216, the U_t plane 72), psi 3 x 24 N in, 24 N out.
+
+
+def _stag_pass_v3_mrhs(links_pl, links_there_pl, psi_pl, X, nhop, bz,
+                       interpret, eo):
+    """``_stag_pass_v3`` (checkerboarded) with a leading RHS axis on psi
+    and out: grid (T, Z/bz, N), RHS innermost, link index maps ignore
+    n."""
+    from jax.experimental import pallas as pl
+
+    from .wilson_pallas_packed import _mrhs_wrap
+
+    N, _, _, T, Z, YX = psi_pl.shape
+    nzb = Z // bz
+    if nzb > 1 and bz % nhop != 0:
+        raise ValueError(
+            f"block_z={bz} not a multiple of nhop={nhop}: the nhop-row "
+            "z boundary inputs must align to row-block boundaries")
+
+    def psi_spec(dt):
+        return pl.BlockSpec(
+            (1, 3, 2, 1, bz, YX),
+            lambda t, zb, n, dt=dt: (n, 0, 0, (t + dt) % T, zb, 0))
+
+    # boundary z-rows pre-gathered as in _stag_pass_v3 (unread dummies
+    # with a single z-block: the kernel rolls inside its tile)
+    if nzb == 1:
+        rows_zp = rows_zm = jnp.zeros((N, 3, 2, T, 1, nhop, YX),
+                                      psi_pl.dtype)
+        u_rows_zm = jnp.zeros((1, 3, 3, 2, T, 1, nhop, YX),
+                              links_there_pl.dtype)
+    else:
+        q = bz // nhop
+        psi_q = psi_pl.reshape(N, 3, 2, T, nzb, q, nhop, YX)
+        rows_zp = jnp.roll(psi_q[:, :, :, :, :, 0], -1, axis=4)
+        rows_zm = jnp.roll(psi_q[:, :, :, :, :, q - 1], 1, axis=4)
+        u_q = links_there_pl[2:3].reshape(1, 3, 3, 2, T, nzb, q, nhop, YX)
+        u_rows_zm = jnp.roll(u_q[:, :, :, :, :, :, q - 1], 1, axis=5)
+
+    psi_row_spec = pl.BlockSpec((1, 3, 2, 1, 1, nhop, YX),
+                                lambda t, zb, n: (n, 0, 0, t, zb, 0, 0))
+    links_spec = pl.BlockSpec(
+        (4, 3, 3, 2, 1, bz, YX), lambda t, zb, n: (0, 0, 0, 0, t, zb, 0))
+    links_xyz_spec = pl.BlockSpec(
+        (3, 3, 3, 2, 1, bz, YX), lambda t, zb, n: (0, 0, 0, 0, t, zb, 0))
+    u_t_spec = pl.BlockSpec(
+        (1, 3, 3, 2, 1, bz, YX),
+        lambda t, zb, n: (3, 0, 0, 0, (t - nhop) % T, zb, 0))
+    u_z_spec = pl.BlockSpec(
+        (1, 3, 3, 2, 1, 1, nhop, YX),
+        lambda t, zb, n: (0, 0, 0, 0, t, zb, 0, 0))
+
+    kernel = _mrhs_wrap(
+        _make_stag_kernel_v3(X, nhop, bz, eo, single_zb=(nzb == 1)),
+        n_psi=5)
+    return pl.pallas_call(
+        kernel,
+        grid=(T, nzb, N),
+        in_specs=[psi_spec(0), psi_spec(+nhop), psi_spec(-nhop),
+                  psi_row_spec, psi_row_spec, links_spec,
+                  links_xyz_spec, u_t_spec, u_z_spec],
+        out_specs=pl.BlockSpec((1, 3, 2, 1, bz, YX),
+                               lambda t, zb, n: (n, 0, 0, t, zb, 0)),
+        out_shape=jax.ShapeDtypeStruct(psi_pl.shape, jnp.float32),
+        interpret=interpret,
+    )(psi_pl, psi_pl, psi_pl, rows_zp, rows_zm, links_pl, links_there_pl,
+      links_there_pl, u_rows_zm)
+
+
+@functools.partial(jax.jit, static_argnames=("dims", "target_parity",
+                                             "interpret", "block_z",
+                                             "out_dtype"))
+def dslash_staggered_eo_pallas_v3_mrhs(fat_here_pl, fat_there_pl, psi_pl,
+                                       dims, target_parity: int,
+                                       long_here_pl=None,
+                                       long_there_pl=None,
+                                       interpret: bool = False,
+                                       block_z: int | None = None,
+                                       out_dtype=None) -> jnp.ndarray:
+    """Multi-RHS checkerboarded scatter hop:
+    ``dslash_staggered_eo_pallas_v3`` with a leading RHS axis on psi
+    ((N,3,2,T,Z,Y*Xh) of parity 1-p), bit for bit the single-RHS kernel
+    per source; link tiles fetched once per (t, z-block) for all N."""
+    T, Z, Y, X = dims
+    Xh = X // 2
+    YXh = psi_pl.shape[-1]
+    _require_naik_z(Z, long_here_pl is not None)
+    if block_z is not None:
+        bz = block_z
+        if Z % bz != 0:
+            raise ValueError(f"block_z={bz} does not divide Z={Z}")
+    else:
+        bz = _pick_bz_v3(Z, YXh, psi_pl.dtype, long_here_pl is not None,
+                         eo=True)
+
+    eo = (target_parity, Xh)
+    out = _stag_pass_v3_mrhs(fat_here_pl, fat_there_pl, psi_pl, X, 1, bz,
+                             interpret, eo)
+    if long_here_pl is not None:
+        out = out + _stag_pass_v3_mrhs(long_here_pl, long_there_pl, psi_pl,
+                                       X, 3, bz, interpret, eo)
+    odt = out_dtype or psi_pl.dtype
+    return out.astype(odt)
+
+
 # -- full-tile fold variant of the fused kernel -----------------------------
 #
 # bf16 tiles are (16, 128): a bz-row re plane and its im plane each pad

@@ -37,7 +37,9 @@ keeps the eager solver call.  ``cg_reliable`` solves the NORMAL
 equations of a non-Hermitian PC operator (``MdagM_pairs``: Wilson,
 clover) and applies a ``hermitian`` one once an iteration (``M_pairs``:
 the staggered PC operator is already 4m^2 - D D); the batched program
-has the normal equations only.
+does the same on ``MdagM_pairs_mrhs`` / ``M_pairs_mrhs``.  With a
+leading source axis ``verified_exit`` and ``prepare`` are the batched
+route's.
 """
 
 from __future__ import annotations
@@ -110,9 +112,9 @@ def cg_reliable(op_hi, op_lo, b, tol: float, maxiter: int, delta: float,
 @partial(jax.jit, static_argnames=("key",))
 def _batched_cg_pairs_program(op, B, tol, maxiter, key):
     _traces[0] += 1
-    check_every, knobs = key
+    check_every, knobs, hermitian = key
     return block.batched_cg_pairs_loop(
-        op.MdagM_pairs_mrhs, B, tol,
+        op.M_pairs_mrhs if hermitian else op.MdagM_pairs_mrhs, B, tol,
         knobs.maxiter if knobs.record else maxiter, check_every,
         knobs.record, knobs.sentinel, knobs.fault_k)
 
@@ -120,9 +122,12 @@ def _batched_cg_pairs_program(op, B, tol, maxiter, key):
 def batched_cg_pairs(op, B, tol: float, maxiter: int,
                      record: bool = False):
     """``block.batched_cg_pairs`` on ``op.MdagM_pairs_mrhs`` through
-    the cached program.  Returns ``(BatchedCGResult, hit)``."""
+    the cached program; on ``M_pairs_mrhs``, once an iteration, where
+    the operator says it is ``hermitian``.  Returns
+    ``(BatchedCGResult, hit)``."""
     from .fused_iter import _resolve_check_every
-    key = (_resolve_check_every(None), _loop_knobs(record, maxiter))
+    key = (_resolve_check_every(None), _loop_knobs(record, maxiter),
+           bool(getattr(op, "hermitian", False)))
     return _run(_batched_cg_pairs_program, op, B, float(tol),
                 int(maxiter), key=key)
 
@@ -147,6 +152,9 @@ def verified_exit(op, b, x_pp):
 def _prepare_program(op, b):
     _traces[0] += 1
     from ..fields.spinor import even_odd_split
+    if b.ndim == 7:
+        return op.prepare_pairs_mrhs(
+            *jax.vmap(lambda v: even_odd_split(v, op.geom))(b))
     return op.prepare_pairs(*even_odd_split(b, op.geom))
 
 
@@ -154,5 +162,6 @@ def prepare(op, b):
     """The entry of a solve on the pair operator ``op``: the canonical
     full-lattice source, split by parity and through
     ``op.prepare_pairs``, to the pair-form PC right-hand side, as one
-    cached program.  Returns ``(rhs, hit)``."""
+    cached program; a batch of sources (a leading axis) through
+    ``op.prepare_pairs_mrhs``.  Returns ``(rhs, hit)``."""
     return _run(_prepare_program, op, b)

@@ -123,6 +123,54 @@ def pytest_collection_modifyitems(config, items):
             item.add_marker(pytest.mark.mid)
 
 
+# -- one file, one worker ----------------------------------------------------
+# Tier-1 runs `-n 6 --dist load`, which deals consecutive tests out in
+# ever smaller hands: a module's tests end up on every worker, and each
+# of them pays the module's fixtures (test_solve_program.py's warm-up
+# is two 80 s compiles of interpreted kernels) and traces and compiles
+# the module's programs again.  The five heaviest files took 2,595 s of
+# test time dealt out and 1,744 s each in a process of its own, and the
+# whole run 1,407 s dealt out and 1,105 s file by file (same machine,
+# same hour).  So under `--dist load` the scheduler is xdist's own
+# loadfile one, made to hand out the heaviest file that is left: longest
+# first keeps the last worker's tail short.  FILE_SECONDS is each
+# file's test time in that 1,105 s run, to the nearest ten, for files
+# of 50 s and more.  It only orders the hand-out: a stale or missing
+# entry costs balance and nothing else.
+FILE_SECONDS = {
+    "test_solve_program.py": 760, "test_multirhs.py": 600,
+    "test_staggered_pallas.py": 380, "test_pallas.py": 380,
+    "test_pair_mg.py": 300, "test_precision_forms.py": 290,
+    "test_domain_wall.py": 240, "test_clover_resident.py": 240,
+    "test_mixed.py": 220, "test_wilson_resident.py": 200,
+    "test_twisted.py": 180, "test_interface.py": 180,
+    "test_pair_gauge.py": 180, "test_chip_compile.py": 170,
+    "test_pair_eig.py": 140, "test_serve.py": 140,
+    "test_pallas_sharded.py": 120, "test_ks_resident.py": 110,
+    "test_staggered_mg.py": 90, "test_packed.py": 80, "test_madwf.py": 80,
+    "test_eig.py": 80, "test_milc_rhmc.py": 80, "test_mg_3level.py": 80,
+    "test_mg_gemm_coarse.py": 70, "test_clover.py": 70, "test_live.py": 70,
+    "test_heatbath.py": 60, "test_schwarz.py": 60, "test_solvers.py": 60,
+    "test_smear_force.py": 50, "test_mg.py": 50, "test_parallel.py": 50,
+}
+OTHER_FILE_SECONDS = 20
+
+
+@pytest.hookimpl(optionalhook=True)
+def pytest_xdist_make_scheduler(config, log):
+    if config.getvalue("dist") != "load":
+        return None
+    from xdist.scheduler import LoadFileScheduling
+
+    class HeaviestFileFirst(LoadFileScheduling):
+        def _assign_work_unit(self, node):
+            heaviest = max(self.workqueue, key=lambda scope: FILE_SECONDS.get(
+                os.path.basename(scope), OTHER_FILE_SECONDS))
+            self.workqueue.move_to_end(heaviest, last=False)
+            super()._assign_work_unit(node)
+    return HeaviestFileFirst(config, log)
+
+
 # -- per-test limit -----------------------------------------------------------
 # Tier-1 runs under the driver's wall clock, and under `--dist load` a
 # worker that hangs keeps the tests already handed to it until that

@@ -107,6 +107,23 @@ def _staggered_eo_fused():
             [lk, lk, ((3, 2, L, L, YXH), F32), lk, lk])
 
 
+def _staggered_eo_mrhs(form, parity, n=8):
+    """The batched fat + Naik hop at 24^4, N sources, f32: the served
+    scatter form (models/staggered.MEASURED_MRHS_FORMS) and the gather
+    form it was read against; the fourth and fifth operand are the
+    other parity's links (scatter) or the pre-shifted backward links
+    (gather)."""
+    from quda_tpu.ops import staggered_pallas as sp
+    lk = ((4, 3, 3, 2, L, L, YXH), F32)
+    if form == "scatter":
+        fn = lambda fh, ft, p, lh, lt: sp.dslash_staggered_eo_pallas_v3_mrhs(
+            fh, ft, p, DIMS, parity, long_here_pl=lh, long_there_pl=lt)
+    else:
+        fn = lambda fh, fb, p, lh, lb: sp.dslash_staggered_eo_pallas_mrhs(
+            fh, fb, p, DIMS, parity, long_here_pl=lh, long_bw_pl=lb)
+    return fn, [lk, lk, ((n, 3, 2, L, L, YXH), F32), lk, lk]
+
+
 def _clover_pc_k1():
     from quda_tpu.ops import clover_pallas as cp
     blk = ((2, 6, 6, 2, L, L, YXH), F32)
@@ -150,6 +167,14 @@ CASES = {
     "axpy_norm2_f32": _axpy_norm2,
     # one case per other operator family the solve API routes to a kernel
     "staggered_eo_fused": _staggered_eo_fused,
+    "staggered_eo_mrhs_n8_scatter_even": lambda: _staggered_eo_mrhs(
+        "scatter", 0),
+    "staggered_eo_mrhs_n8_scatter_odd": lambda: _staggered_eo_mrhs(
+        "scatter", 1),
+    "staggered_eo_mrhs_n8_gather_even": lambda: _staggered_eo_mrhs(
+        "gather", 0),
+    "staggered_eo_mrhs_n8_gather_odd": lambda: _staggered_eo_mrhs(
+        "gather", 1),
     "clover_pc_k1": _clover_pc_k1,
     "dwf_eo_ls8": _dwf_ls8,
     "mg_coarse_1296x48": _coarse,
@@ -421,7 +446,7 @@ def test_batched_solve_program_compiles_for_v5e_combining_in_the_kernel(
                 weak_type=s.weak_type), op)
         b = jax.ShapeDtypeStruct(*_psi(F32, (8,)), sharding=one_chip)
         key = (_resolve_check_every(None),
-               sprog._LoopKnobs(False, None, None, None))
+               sprog._LoopKnobs(False, None, None, None), False)
         return sprog._batched_cg_pairs_program.lower(op, b, 1e-6, 10000,
                                                      key=key)
     hlo = _aot(lower).as_text()
@@ -513,3 +538,61 @@ def test_hisq_solve_program_compiles_for_v5e_with_links_as_parameters(
         assert sum(p[1:] == (dt, links) for p in params) >= 4
     big = [c for c in _hlo_values(hlo, "constant") if c[0] > 2 ** 20]
     assert not big, f"fields baked into the executable: {big}"
+
+
+@pytest.mark.parametrize("program", ["prepare", "solve", "verified-exit"])
+def test_hisq_batched_programs_compile_for_v5e(one_chip, program):
+    """The three programs of a batched improved-staggered call
+    (solvers/program.py on the resident f32 DiracStaggeredPCPairs, 8
+    sources at 24^4) compile for the described chip on abstract
+    operands: the links are parameters, the served MRHS form
+    (MEASURED_MRHS_FORMS) is the kernel in them, and the Hermitian
+    solve applies it four times an iteration (one M = two hops = four
+    passes), not eight."""
+    import re
+    from quda_tpu.fields.geometry import LatticeGeometry
+    from quda_tpu.models.staggered import DiracStaggeredPCPairs
+    from quda_tpu.solvers import program as sprog
+    from quda_tpu.solvers.fused_iter import _resolve_check_every
+    geom = LatticeGeometry(DIMS)
+    lshape = (4, 3, 3, 2, L, L, YXH)
+    n = 8
+
+    def operator(fe, fo, le, lo):
+        return DiracStaggeredPCPairs.from_packed(
+            geom, (fe, fo), (le, lo), 0.04, 0, F32, use_pallas=True,
+            pallas_interpret=False)
+
+    def lower():
+        lk = jax.ShapeDtypeStruct(lshape, F32)
+        op = jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(
+                s.shape, s.dtype, sharding=one_chip,
+                weak_type=s.weak_type),
+            jax.eval_shape(operator, lk, lk, lk, lk))
+        assert op.hermitian and sprog.presents(op)
+        assert op._mrhs_form == "scatter_two_pass"
+        x = jax.ShapeDtypeStruct((n, 3, 2, L, L, YXH), F32,
+                                 sharding=one_chip)
+        b = jax.ShapeDtypeStruct((n,) + DIMS + (1, 3), jnp.complex64,
+                                 sharding=one_chip)
+        if program == "prepare":
+            return sprog._prepare_program.lower(op, b)
+        if program == "verified-exit":
+            return sprog._verified_exit_program.lower(op, b, x)
+        key = (_resolve_check_every(None),
+               sprog._LoopKnobs(False, None, None, None), True)
+        return sprog._batched_cg_pairs_program.lower(op, x, 1e-6, 10000,
+                                                     key=key)
+    compiled = _aot(lower)
+    hlo = compiled.as_text()
+    calls = re.findall(r"%dslash_staggered_eo_pallas_v3_mrhs[.\d]* = f32"
+                       r"\[[^\n]*tpu_custom_call", hlo)
+    assert len(calls) == {"prepare": 2, "solve": 4,
+                          "verified-exit": 4}[program], calls
+    params = _hlo_values(hlo, "parameter")
+    links = ",".join(str(d) for d in lshape)
+    assert sum(p[1:] == ("f32", links) for p in params) >= 4
+    big = [c for c in _hlo_values(hlo, "constant") if c[0] > 2 ** 20]
+    assert not big, f"fields baked into the executable: {big}"
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30

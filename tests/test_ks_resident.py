@@ -287,3 +287,153 @@ def test_milc_two_calls_reach_the_resident_route(quda):
         ("cg", "hit"): 1, ("verified-exit", "hit"): 1}
     assert abs(_true_residual(fat, lng, b, x) - info["true_res"]) < 1e-5
     assert info["true_res"] < 2e-5
+
+
+# (d) a batch of sources on the same term (invert_multi_src_quda) ------------
+
+N_SRC = 8
+
+
+def _batch(seed, n=N_SRC):
+    return _field(seed, (n, L, L, L, L, 1, 3))
+
+
+@pytest.fixture
+def boundary(quda, request):
+    """The resident gauge under the requested fermion t boundary (the
+    fat and long links stay loaded); antiperiodic again afterwards."""
+    fat, lng = quda
+    ap = request.param
+
+    def load(tb):
+        api.load_gauge_quda(np.asarray(fat), GaugeParam(
+            X=(L,) * 4, cuda_prec="single", t_boundary=tb))
+        api.load_fat_long_quda(fat, lng)
+    if not ap:
+        load("periodic")
+    else:
+        api.load_fat_long_quda(fat, lng)
+    yield ap
+    if not ap:
+        load("antiperiodic")
+
+
+@pytest.mark.parametrize("boundary", [True, False], indirect=True,
+                         ids=["antiperiodic", "periodic"])
+@pytest.mark.parametrize("matpc", ["even-even", "odd-odd"])
+def test_batch_equals_single_calls_and_the_canonical_residual(
+        quda, boundary, matpc):
+    fat, lng = quda
+    geom = LatticeGeometry((L,) * 4)
+    B = _batch(21)
+    p = _param(matpc_type=matpc)
+    X = api.invert_multi_src_quda(B, p)
+    assert X.shape == B.shape and all(p.converged_multi)
+    assert len(p.true_res_multi) == len(p.iter_count_multi) == N_SRC
+    d = DiracStaggered(fat, geom, MASS, improved=True, long_links=lng,
+                       antiperiodic_t=boundary)
+    for i in range(N_SRC):
+        want = _rel(d.M(X[i]), B[i])
+        assert abs(want - p.true_res_multi[i]) < 1e-5 * max(1.0, want / 1e-5)
+        assert p.true_res_multi[i] < 2e-5
+    # one at a time through invert_quda: the same systems to the
+    # solver's tolerance (tol / 2m on the full system, from either
+    # side); on the parity whose single-source programs this module has
+    for i in (0, N_SRC - 1) if matpc == "even-even" else ():
+        pi = _param(matpc_type=matpc)
+        xi = api.invert_quda(B[i], pi)
+        assert pi.converged and _rel(X[i], xi) < 4e-5
+
+
+def test_batch_builds_once_then_reuses_and_hits(quda):
+    """Two calls against the same loaded links: the first builds the
+    term and traces the three programs, the second builds nothing and
+    traces nothing; another mass reuses them; new links rebuild; the
+    single-source route afterwards finds the same term."""
+    fat, lng = quda
+    n = 2                   # a batch size no other test traces
+    api.load_fat_long_quda(fat, lng)
+    api._drop_resident("ks")
+    progs = lambda: _counts("solve_program_total",
+                            ("api", "form", "solver", "outcome"))
+    key = lambda solver, outcome: ("invert_multi_src_quda",
+                                   "staggered_batched_pairs", solver,
+                                   outcome)
+    three = ("prepare", "batched-cg-pairs", "verified-exit")
+    t0, p0 = _counts("ks_term_total", ("outcome",)), progs()
+    p = _param()
+    api.invert_multi_src_quda(_batch(31, n), p)
+    assert all(p.converged_multi)
+    assert _delta(t0, _counts("ks_term_total", ("outcome",))) == {
+        ("built",): 1}
+    assert _delta(p0, progs()) == {key(s, "miss"): 1 for s in three}
+    term = api._ctx["ks"]
+    # the second call, another mass and other sources
+    t1, p1, n1 = _counts("ks_term_total", ("outcome",)), progs(), \
+        sprog._traces[0]
+    routes = _counts("staggered_mrhs_route_total", ("form",))
+    p = _param(mass=0.2)
+    B = _batch(32, n)
+    X = api.invert_multi_src_quda(B, p)
+    assert sprog._traces[0] == n1
+    assert _counts("staggered_mrhs_route_total", ("form",)) == routes
+    assert routes == {("vmap_xla",): routes[("vmap_xla",)]}
+    assert _delta(t1, _counts("ks_term_total", ("outcome",))) == {
+        ("reused",): 1}
+    assert _delta(p1, progs()) == {key(s, "hit"): 1 for s in three}
+    assert api._ctx["ks"] is term
+    for i in range(n):
+        assert abs(_true_residual(fat, lng, B[i], X[i], 0.2)
+                   - p.true_res_multi[i]) < 1e-5
+    # the single-source route on the term the batch left
+    t2 = _counts("ks_term_total", ("outcome",))
+    api.invert_quda(B[0], _param())
+    assert _delta(t2, _counts("ks_term_total", ("outcome",))) == {
+        ("reused",): 1}
+    assert api._ctx["ks"] is term
+    # new links: another term, the programs stay
+    t3, p3 = _counts("ks_term_total", ("outcome",)), progs()
+    api.load_fat_long_quda(fat, 0.5 * lng)
+    p = _param()
+    api.invert_multi_src_quda(B, p)
+    assert _delta(t3, _counts("ks_term_total", ("outcome",))) == {
+        ("rebuilt",): 1, ("reused",): 1}
+    assert api._ctx["ks"] is not term
+    assert _delta(p3, progs()) == {key(s, "hit"): 1 for s in three}
+
+
+def test_no_canonical_staggered_operator_on_the_batched_route(
+        quda, monkeypatch):
+    fat, lng = quda
+    api.load_fat_long_quda(fat, lng)
+
+    def refuse(*a, **k):
+        raise AssertionError("a canonical operator was built")
+    monkeypatch.setattr(api, "_build_dirac", refuse)
+    p = _param()
+    api.invert_multi_src_quda(_batch(41), p)
+    assert all(p.converged_multi)
+
+
+def test_milc_msrc_reaches_the_resident_batched_route(quda):
+    """qudaLoadKSLink + qudaInvertMsrc: the second call builds nothing
+    and traces nothing, and returns the verified residuals."""
+    fat, lng = quda
+    milc.qudaLoadKSLink(fat, lng)
+    kw = dict(tol=1e-6, maxiter=2000, prec="single", sloppy_prec="auto")
+    milc.qudaInvertMsrc(MASS, _batch(51), **kw)
+    t0 = _counts("ks_term_total", ("outcome",))
+    p0 = _counts("solve_program_total", ("api", "solver", "outcome"))
+    B = _batch(52)
+    X, info = milc.qudaInvertMsrc(MASS, B, **kw)
+    assert _delta(t0, _counts("ks_term_total", ("outcome",))) == {
+        ("reused",): 1}
+    assert _delta(p0, _counts("solve_program_total",
+                              ("api", "solver", "outcome"))) == {
+        ("invert_multi_src_quda", s, "hit"): 1
+        for s in ("prepare", "batched-cg-pairs", "verified-exit")}
+    assert len(info["iters"]) == len(info["true_res"]) == N_SRC
+    for i in (0, N_SRC - 1):
+        assert abs(_true_residual(fat, lng, B[i], X[i])
+                   - info["true_res"][i]) < 1e-5
+        assert info["true_res"][i] < 2e-5

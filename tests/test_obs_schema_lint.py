@@ -77,7 +77,7 @@ def test_solve_program_counter_and_span_attribute_are_registered(tmp_path):
     from quda_tpu.obs import trace as otr
     assert osch.metric_type("solve_program_total") == osch.COUNTER
     assert set(osch.SPAN_ATTRS["program"]["spans"]) == {
-        "solve:cg", "solve:batched-cg-pairs", "verified_exit"}
+        "solve:cg", "solve:batched-cg-pairs", "verified_exit", "prepare"}
     otr.stop(flush_files=False)
     otr.span("solve:cg").set(anything="ignored: tracing is off")
     otr.start(str(tmp_path))

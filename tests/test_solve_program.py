@@ -418,6 +418,45 @@ def test_cached_program_equals_the_eager_solver(route):
                                    jnp.max(jnp.abs(eager.x))))
 
 
+def test_hermitian_batched_program_applies_m_once_an_iteration(
+        monkeypatch):
+    """The batched program on an operator that says it is ``hermitian``
+    (the improved-staggered PC operator): ``M_pairs_mrhs`` in the loop,
+    two hops an iteration and no normal equations, iteration for
+    iteration the eager ``batched_cg_pairs`` on the same operator."""
+    from quda_tpu.fields.gauge import GaugeField
+    from quda_tpu.fields.geometry import LatticeGeometry
+    from quda_tpu.models.staggered import (DiracStaggeredPC,
+                                           DiracStaggeredPCPairs)
+    from quda_tpu.solvers import batched_cg_pairs
+    geom = LatticeGeometry((4,) * 4)
+    k1, k2 = jax.random.split(jax.random.PRNGKey(5))
+    fat = GaugeField.random(k1, geom).data.astype(jnp.complex64)
+    lng = (0.1 * GaugeField.random(k2, geom).data).astype(jnp.complex64)
+    op = DiracStaggeredPC(fat, geom, 0.1, improved=True,
+                          long_links=lng).pairs(jnp.float32)
+    assert op.hermitian and sprog.presents(op)
+    hops = []
+    d_to = DiracStaggeredPCPairs._d_to_mrhs
+    monkeypatch.setattr(
+        DiracStaggeredPCPairs, "_d_to_mrhs",
+        lambda self, *a, **k: hops.append(1) or d_to(self, *a, **k))
+    b = jnp.asarray(np.random.default_rng(9).standard_normal(
+        (3, 3, 2, 4, 4, 8)), jnp.float32)
+    kw = dict(tol=1e-6, maxiter=500)
+    cached, hit = sprog.batched_cg_pairs(op, b, **kw)
+    assert not hit and len(hops) == 2       # one M in the traced loop
+    _, hit = sprog.batched_cg_pairs(op.with_mass(0.2), 2.0 * b, **kw)
+    assert hit and len(hops) == 2
+    eager = batched_cg_pairs(op.M_pairs_mrhs, b, **kw)
+    assert np.all(np.asarray(cached.converged))
+    np.testing.assert_array_equal(np.asarray(cached.iters),
+                                  np.asarray(eager.iters))
+    np.testing.assert_allclose(np.asarray(cached.x), np.asarray(eager.x),
+                               rtol=0, atol=1e-5 * float(
+                                   jnp.max(jnp.abs(eager.x))))
+
+
 # the operand itself ------------------------------------------------------
 
 def _packed(seed, kappa, lat=4):
