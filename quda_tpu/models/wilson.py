@@ -590,7 +590,9 @@ class _PackedHopMixin:
 
     def _hop_mrhs(self, psi_b, target_parity, out_dtype, **epilogue):
         """The plain pallas MRHS kernel on this operator's links;
-        ``epilogue``: its combine operands (``xc``, ``coeff``, ``g5``)."""
+        ``epilogue``: its combine operands (``xc``, ``coeff``, ``g5``);
+        with them the result is ``(batch, its squared norms per
+        source)``."""
         from ..ops import wilson_pallas_packed as wpp
         return wpp.dslash_eo_pallas_packed_mrhs(
             self.gauge_eo_pp[target_parity], self._u_bw[target_parity],
@@ -1250,16 +1252,23 @@ class DiracWilsonPCPackedSloppy(_ProgramOperand, _PackedHopMixin,
     # composition hands XLA the bare hop sum and x (PERF.md section 6,
     # PR 33).  Every other representation keeps _PairSloppyBase's
     # composition, operation for operation; prepare / reconstruct / the
-    # verified exit apply bare hops either way.
+    # verified exit apply bare hops either way.  The same epilogue sums
+    # the squares of what it stores, per source: |g5 M x|^2 is the
+    # batched CG's pAp (MdagM_dot_pairs_mrhs; PR 37).
+    def _M_g5_norm2_pairs_mrhs(self, x, g5: bool):
+        """``([g5] M x, its squared norms per source)`` on the plain
+        kernel route: both from the second hop's epilogue."""
+        p = self.matpc
+        tmp = self._hop_mrhs(x, 1 - p, self.store_dtype)
+        return self._hop_mrhs(tmp, p, self.store_dtype, xc=x,
+                              coeff=-(self.kappa ** 2), g5=g5)
+
     def _M_g5_pairs_mrhs(self, x, g5: bool):
         """``M x``, or ``g5 M x``."""
         if not self._plain_mrhs():
             out = super().M_pairs_mrhs(x)
             return self._g5_pairs_mrhs(out) if g5 else out
-        p = self.matpc
-        tmp = self._hop_mrhs(x, 1 - p, self.store_dtype)
-        return self._hop_mrhs(tmp, p, self.store_dtype, xc=x,
-                              coeff=-(self.kappa ** 2), g5=g5)
+        return self._M_g5_norm2_pairs_mrhs(x, g5)[0]
 
     def M_pairs_mrhs(self, x):
         return self._M_g5_pairs_mrhs(x, False)
@@ -1270,6 +1279,20 @@ class DiracWilsonPCPackedSloppy(_ProgramOperand, _PackedHopMixin,
     def MdagM_pairs_mrhs(self, x):
         # g5 M g5 M x: each M's second hop applies the g5 in front of it
         return self._M_g5_pairs_mrhs(self._M_g5_pairs_mrhs(x, True), True)
+
+    def MdagM_dot_pairs_mrhs(self, x):
+        """``(MdagM x, x . MdagM x per source)``, what the batched CG
+        applies (solvers/block.batched_cg_pairs_loop).  MdagM is
+        g5 M g5 M, so ``x . MdagM x = |q|^2`` with ``q = g5 M x``, and
+        on the plain kernel route the hop that stores ``q`` sums its
+        squares as it stores them (the combine epilogue): no pass over
+        the batch for the CG's ``pAp``.  Every other route: XLA's dot
+        of ``x`` and ``MdagM_pairs_mrhs(x)``."""
+        if not self._plain_mrhs():
+            from ..solvers.block import with_dot
+            return with_dot(self.MdagM_pairs_mrhs)(x)
+        q, q2 = self._M_g5_norm2_pairs_mrhs(x, True)
+        return self._M_g5_pairs_mrhs(q, True), q2
 
     # -- multi-RHS boundary helpers (the invert_multi_src_quda route) --
     def prepare_pairs_mrhs(self, b_even_b, b_odd_b):

@@ -214,7 +214,11 @@ METRICS: dict[str, dict] = {
                 "with two z-neighbour tiles besides (five reads); and by "
                 "epilogue: 'combine' the store writes [g5] (xc + coeff * "
                 "hop) (the second hop of the batched PC operator), 'none' "
-                "the bare hop sum"},
+                "the bare hop sum; and by reduce: 'norm2' the epilogue "
+                "also sums the squares of what it stores, per source "
+                "(every combine call; from the first M's second hop it "
+                "is the batched CG's pAp = |g5 M p|^2), 'none' the bare "
+                "hop"},
     "staggered_mrhs_route_total": {
         "type": COUNTER,
         "help": "traced calls of the batched staggered hop "

@@ -260,14 +260,26 @@ def _make_kernel(X: int, bz: int, eo: tuple | None = None,
     (the sign (+,+,-,-) by spin row) with ``g5``: rounded to the out
     dtype once, as the XLA pass it replaces rounds.  Without it the
     body is the plain hop's, trace for trace.
+    The epilogue has a second, small f32 output after ``out_ref``, one
+    (BZ, YX) block a grid step: the sum over the step's planes,
+    time-slices and chunks of the squares of what it stores, taken
+    after the rounding to the out dtype (the norm of the array the
+    next hop reads).  Summed per source by the caller it is
+    ``|g5 M p|^2``, which is the ``p . MdagM p`` of the batched CG
+    (solvers/block.batched_cg_pairs_loop): no pass over the batch
+    reads ``p`` and ``Ap`` only to sum them.  Every combine call
+    carries it, used or not (48 flop a site on a 1,320-flop body): a
+    variant without it is one more Mosaic lowering in every process
+    for 0.02 % of a call (PERF.md section 6, PR 37).
     """
     from jax.experimental import pallas as pl
 
     def kernel(psi_c, psi_tp, psi_tm, psi_zp, psi_zm, g_c, g_m, out_ref,
-               z0=None, t_id=None, xc=None, coeff=None):
+               z0=None, t_id=None, xc=None, coeff=None, nrm=None):
         # z0 / t_id: the tile's first z row and its time-slice, where the
         # caller knows them better than the grid does (centre_kernel);
-        # xc / coeff: the combine epilogue's operands
+        # xc / coeff: the combine epilogue's operands; nrm: its (BZ, YX)
+        # f32 block of partial sums of squares, zeroed by the caller
         if eo is not None:
             parity, Xh = eo
             t_eo = pl.program_id(0) if t_id is None else t_id
@@ -371,6 +383,7 @@ def _make_kernel(X: int, bz: int, eo: tuple | None = None,
         if xc is not None:
             k = coeff[0]
             nk = -k
+        sq = None
         for s in range(4):
             for c in range(3):
                 for ri in (0, 1):
@@ -379,10 +392,16 @@ def _make_kernel(X: int, bz: int, eo: tuple | None = None,
                         x = xc[s, c, ri, 0].astype(F32)
                         # -(x + k v) is (-k) v - x to the bit
                         v = nk * v - x if g5 and s >= 2 else x + k * v
-                    out_ref[s, c, ri, 0] = v.astype(odt)
+                    v = v.astype(odt)
+                    out_ref[s, c, ri, 0] = v
+                    if nrm is not None:
+                        v = v.astype(F32)
+                        sq = v * v if sq is None else sq + v * v
+        if nrm is not None:
+            nrm[...] += sq
 
     def centre_kernel(psi_c, psi_tp, psi_tm, g_c, g_m, out_ref,
-                      xc=None, coeff=None):
+                      xc=None, coeff=None, nrm=None):
         bt, Z = psi_c.shape[-3:-1]
         nzc = Z // bz
         t0 = pl.program_id(0) * bt    # not inside the loop's body
@@ -407,7 +426,8 @@ def _make_kernel(X: int, bz: int, eo: tuple | None = None,
             kernel(at(psi_c, i), up, dn, at(psi_c, i, +1), at(psi_c, i, -1),
                    at(g_c, i), at(g_m, i), at(out_ref, i),
                    z0=zc * bz, t_id=t0 + i,
-                   xc=None if xc is None else at(xc, i), coeff=coeff)
+                   xc=None if xc is None else at(xc, i), coeff=coeff,
+                   nrm=nrm)
             return carry
         if bt * nzc == 1:
             chunk(0, 0)
@@ -422,8 +442,9 @@ def _make_kernel(X: int, bz: int, eo: tuple | None = None,
         # operand order: psi refs, xc, coeff, then the links LAST (the
         # benchmark's trace reduction names a kernel event by the element
         # types of its result, first and last operand)
-        *psi, xc, coeff, g_c, g_m, out_ref = refs
-        body(*psi, g_c, g_m, out_ref, xc=xc, coeff=coeff)
+        *psi, xc, coeff, g_c, g_m, out_ref, nrm = refs
+        nrm[...] = jnp.zeros(nrm.shape, F32)
+        body(*psi, g_c, g_m, out_ref, xc=xc, coeff=coeff, nrm=nrm)
 
     return combine_kernel
 
@@ -643,15 +664,16 @@ class _LeadAxisRef:
         self._ref[(0,) + idx] = val
 
 
-def _mrhs_wrap(kernel, n_psi: int = 5):
+def _mrhs_wrap(kernel, n_psi: int = 5, n_out: int = 1):
     """Adapt a single-RHS kernel to MRHS blocks: the first ``n_psi`` refs
-    and the output ref carry a leading size-1 RHS axis; gauge refs pass
+    and the first of the ``n_out`` output refs (the spinor) carry a
+    leading size-1 RHS axis; gauge refs and any further output pass
     through untouched."""
     def wrapped(*refs):
+        n_in = len(refs) - n_out
         psi = [_LeadAxisRef(r) for r in refs[:n_psi]]
-        rest = list(refs[n_psi:-1])
-        out = _LeadAxisRef(refs[-1])
-        kernel(*psi, *rest, out)
+        kernel(*psi, *refs[n_psi:n_in], _LeadAxisRef(refs[n_in]),
+               *refs[n_in + 1:])
     return wrapped
 
 
@@ -671,7 +693,8 @@ def _mrhs_fullz_vmem(Z: int, YX: int, dtype, out_dtype, R: int,
     link tiles (24 R each), bt out tiles (24) and, with the combine
     epilogue, bt tiles of its ``xc`` operand (24, of ``xc_dtype``),
     every (Z, YX) plane padded to its dtype's (sublane, 128) tile as
-    ``_pick_bz`` pads it.
+    ``_pick_bz`` pads it, and the f32 block of the epilogue's sums of
+    squares, one chunk of the body's rows.
     Need: the blocks double-buffered by the pipeline plus the body's
     own f32 tiles, which live in VMEM, not in vregs (accumulators, the
     loaded spinor, the hop's temporaries): six spinors' worth of full-Z
@@ -688,7 +711,8 @@ def _mrhs_fullz_vmem(Z: int, YX: int, dtype, out_dtype, R: int,
     blocks = (((bt + 2) * 24 + 2 * 24 * R * bt) * plane(dtype)
               + bt * 24 * plane(out_dtype))
     if xc_dtype is not None:
-        blocks += bt * 24 * plane(xc_dtype)
+        rows = -(-_fullz_chunk(Z, dtype) // 8) * 8
+        blocks += bt * 24 * plane(xc_dtype) + rows * yx_pad * 4
     return blocks, 2 * blocks + 6 * 24 * plane(F32)
 
 
@@ -716,11 +740,12 @@ def _mrhs_route(T: int, Z: int, YX: int, dtype, out_dtype, R: int,
     operands of the single-RHS kernel, within the scoped default; taken
     where full-Z does not fit or a caller's ``block_z`` asks for
     z-blocks.  ``xc_dtype``: the call has the combine epilogue, whose
-    ``xc`` operand is one more spinor block on either route.  Recorded
-    at trace time: the VMEM audit gets the full-Z route's blocks and
-    limit (``_pick_bz`` records the z-block's),
-    ``wilson_mrhs_route_total`` counts the call by route and
-    epilogue."""
+    ``xc`` operand is one more spinor block on either route, and its
+    f32 block of sums of squares at most two planes of the storage
+    dtype.  Recorded at trace time: the VMEM audit gets the full-Z
+    route's blocks and limit (``_pick_bz`` records the z-block's),
+    ``wilson_mrhs_route_total`` counts the call by route, epilogue and
+    reduce (``norm2``: the epilogue's sums)."""
     from ..obs import memory as omem
     from ..obs import metrics as omet
     fits = [(bt,) + _mrhs_fullz_vmem(Z, YX, dtype, out_dtype, R, bt,
@@ -737,11 +762,13 @@ def _mrhs_route(T: int, Z: int, YX: int, dtype, out_dtype, R: int,
         route, bt, limit = "zblock", 1, None
         bz = block_z if block_z is not None else _pick_bz(
             Z, YX, dtype, planes=(288 if R == 3 else 240)
-            + (24 if xc_dtype is not None else 0))
+            + (24 + 4 // jnp.dtype(dtype).itemsize
+               if xc_dtype is not None else 0))
         if Z % bz != 0:
             raise ValueError(f"block_z={bz} does not divide Z={Z}")
     omet.inc("wilson_mrhs_route_total", route=route,
-             epilogue="none" if xc_dtype is None else "combine")
+             epilogue="none" if xc_dtype is None else "combine",
+             reduce="none" if xc_dtype is None else "norm2")
     return route, bz, bt, limit
 
 
@@ -753,7 +780,12 @@ def _mrhs_hop(g_c, g_m, psi_pl, X: int, eo, tb_sign: bool,
     (t, zb) alone.  With ``xc`` (an array of the result's shape) and
     ``coeff`` (a (1,) f32 array, in SMEM: an operand, so that a solve
     program serves every kappa) the call writes
-    ``[g5] (xc + coeff * hop)``: ``_make_kernel``'s combine epilogue."""
+    ``[g5] (xc + coeff * hop)``, ``_make_kernel``'s combine epilogue,
+    and returns ``(v, |v|^2 per source)``, v what it wrote: the
+    kernel's second output holds one block of f32 partial sums a grid
+    step, (T/bt, Z/bz, N, rows, YX), and XLA sums those few KB a
+    source to (N,) f32.  This is where the batched CG's ``pAp`` comes
+    from (models/wilson.MdagM_dot_pairs_mrhs)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -770,14 +802,16 @@ def _mrhs_hop(g_c, g_m, psi_pl, X: int, eo, tb_sign: bool,
             raise
         # no route holds the xc block besides the hop's own (a z-block
         # at _pick_bz's budget): the bare hop, if that fits, and XLA's
-        # pass over the batch, as without the epilogue
+        # passes over the batch, as without the epilogue
         hop = _mrhs_hop(g_c, g_m, psi_pl, X, eo, tb_sign, block_z, F32,
                         interpret)
         v = xc.astype(F32) + coeff[0] * hop
         if g5:
             v = v * jnp.asarray([1, 1, -1, -1], F32).reshape(
                 (4,) + (1,) * 5)
-        return v.astype(out_dtype)
+        v = v.astype(out_dtype)
+        w = v.astype(F32)
+        return v, jnp.sum((w * w).reshape(N, -1), axis=1)
     nzb = Z // bz
 
     def psi_block(tb, zb, n):
@@ -813,20 +847,32 @@ def _mrhs_hop(g_c, g_m, psi_pl, X: int, eo, tb_sign: bool,
         psi_specs.append(centre_spec())
         operands.append(xc)
         rest_specs.insert(0, pl.BlockSpec(memory_space=pltpu.SMEM))
+    out_specs = centre_spec()
+    out_shape = jax.ShapeDtypeStruct(psi_pl.shape, out_dtype)
+    if combine:
+        # one (rows, YX) block of partial sums a grid step
+        out_specs = [out_specs, pl.BlockSpec(
+            (None, None, None, body_rows, YX),
+            lambda tb, zb, n: (tb, zb, n, 0, 0))]
+        out_shape = [out_shape, jax.ShapeDtypeStruct(
+            (T // bt, nzb, N, body_rows, YX), F32)]
     kernel = _mrhs_wrap(
         _make_kernel(X, body_rows, eo=eo, T=T, tb_sign=tb_sign,
                      z_rows=z_rows, combine=combine, g5=g5),
-        n_psi=len(psi_specs))
+        n_psi=len(psi_specs), n_out=1 + combine)
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=(T // bt, nzb, N),
         in_specs=psi_specs + rest_specs,
-        out_specs=centre_spec(),
-        out_shape=jax.ShapeDtypeStruct(psi_pl.shape, out_dtype),
+        out_specs=out_specs,
+        out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
         interpret=interpret,
     )(*operands, *([coeff] if combine else []), g_c, g_m)
+    if not combine:
+        return out
+    return out[0], jnp.sum(out[1], axis=(0, 1, 3, 4))
 
 
 @functools.partial(jax.jit,
@@ -865,7 +911,7 @@ def dslash_eo_pallas_packed_mrhs(u_here_pl: jnp.ndarray,
                                  tb_sign: bool = True,
                                  xc: jnp.ndarray | None = None,
                                  coeff=None,
-                                 g5: bool = False) -> jnp.ndarray:
+                                 g5: bool = False):
     """Multi-RHS checkerboarded Wilson hop — the batched-solver hot path
     (``dslash_eo_pallas_packed`` with a leading RHS axis on psi).
 
@@ -879,7 +925,9 @@ def dslash_eo_pallas_packed_mrhs(u_here_pl: jnp.ndarray,
     epilogue writes ``xc + coeff * hop``, and ``g5`` puts gamma5 in
     front of it: the second hop of the preconditioned operator then
     hands back ``M x`` (or ``g5 M x``) itself, and no XLA pass over the
-    batch builds it from the bare hop sum.
+    batch builds it from the bare hop sum.  The result is then the
+    pair (that batch, its (N,) f32 squared norms per source), the
+    norms summed by the same epilogue from what it stores.
     """
     X = dims[3]
     if xc is not None:

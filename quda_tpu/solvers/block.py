@@ -146,6 +146,25 @@ def _per_rhs_dot(u: jnp.ndarray, v: jnp.ndarray) -> jnp.ndarray:
     return jnp.sum(jnp.real(jnp.conjugate(u) * v).reshape(n, -1), axis=1)
 
 
+def _wide_dot(u: jnp.ndarray, v: jnp.ndarray) -> jnp.ndarray:
+    """``_per_rhs_dot`` in the batch's reduction dtype: its own, f32
+    for a bf16 batch."""
+    rdt = jnp.float32 if u.dtype == jnp.bfloat16 else u.dtype
+    return _per_rhs_dot(u.astype(rdt), v.astype(rdt))
+
+
+def with_dot(matvec_batch: Callable) -> Callable:
+    """Lift a batched matvec ``A`` to what ``batched_cg_pairs_loop``
+    applies, ``x -> (A x, per-source x . A x)``: XLA's dot over the
+    batch, operation for operation the ``pAp`` the loop took itself
+    before an operator could hand it over.  For every operator that has
+    nothing better (models/wilson.MdagM_dot_pairs_mrhs has)."""
+    def apply(x):
+        Ax = matvec_batch(x)
+        return Ax, _wide_dot(x, Ax)
+    return apply
+
+
 def _bcast(s: jnp.ndarray, like: jnp.ndarray) -> jnp.ndarray:
     """(N,) scalars broadcast over the per-RHS field axes."""
     return s.reshape((s.shape[0],) + (1,) * (like.ndim - 1))
@@ -172,11 +191,12 @@ def batched_cg_pairs(matvec_batch: Callable, B: jnp.ndarray,
     from ..robust import sentinel as rsent
     from .fused_iter import _resolve_check_every
     return batched_cg_pairs_loop(
-        matvec_batch, B, tol, maxiter, _resolve_check_every(check_every),
-        record, rsent.make(), finj.iteration_fault("dslash"))
+        with_dot(matvec_batch), B, tol, maxiter,
+        _resolve_check_every(check_every), record, rsent.make(),
+        finj.iteration_fault("dslash"))
 
 
-def batched_cg_pairs_loop(matvec_batch: Callable, B: jnp.ndarray, tol,
+def batched_cg_pairs_loop(apply_batch: Callable, B: jnp.ndarray, tol,
                           maxiter, check_every: int, record: bool, sent,
                           fault_k: Optional[int]) -> BatchedCGResult:
     """``batched_cg_pairs`` with every knob already resolved by the
@@ -185,7 +205,15 @@ def batched_cg_pairs_loop(matvec_batch: Callable, B: jnp.ndarray, tol,
     here reads host state: the body the cached solve program
     (solvers/program.py) traces once per key.  ``tol`` and ``maxiter``
     may be traced scalars, except that ``record`` sizes the history by
-    a concrete ``maxiter``."""
+    a concrete ``maxiter``.
+
+    ``apply_batch`` is ``p -> (A p, per-source p . A p)``: the loop
+    makes no pass over the batch for ``pAp``.  It comes from the
+    operator where that has it for nothing (the Wilson pair operator's
+    ``MdagM_dot_pairs_mrhs``: ``|g5 M p|^2``, summed by the kernel
+    that stores ``g5 M p``) and from ``with_dot`` everywhere else.
+    With a dslash fault armed the dot is taken here, from the corrupted
+    ``Ap``, so a fault reaches ``alpha`` and the sentinel's pivot."""
     from ..robust import faultinject as finj
     from ..robust import sentinel as rsent
     n = B.shape[0]
@@ -196,7 +224,7 @@ def batched_cg_pairs_loop(matvec_batch: Callable, B: jnp.ndarray, tol,
     # hierarchy) carry real residual lanes; identical to rdt for the
     # real pair arrays
     sdt = jnp.zeros((), rdt).real.dtype
-    b2 = _per_rhs_dot(B.astype(rdt), B.astype(rdt))
+    b2 = _wide_dot(B, B)
     stop = (tol ** 2) * b2
     tiny = jnp.asarray(jnp.finfo(sdt).tiny, sdt)
 
@@ -206,15 +234,15 @@ def batched_cg_pairs_loop(matvec_batch: Callable, B: jnp.ndarray, tol,
     rz = b2
 
     def one_iter(x, r, p, rz, k):
-        Ap = matvec_batch(p)
+        Ap, pAp = apply_batch(p)
         if fault_k is not None:
             Ap = finj.corrupt(Ap, k, fault_k)
-        pAp = _per_rhs_dot(p.astype(rdt), Ap.astype(rdt))
+            pAp = _wide_dot(p, Ap)
         alpha = rz / jnp.maximum(pAp, tiny)
         a = _bcast(alpha, x).astype(x.dtype)
         x = x + a * p
         r = r - a * Ap
-        r2 = _per_rhs_dot(r.astype(rdt), r.astype(rdt))
+        r2 = _wide_dot(r, r)
         beta = r2 / jnp.maximum(rz, tiny)
         p = r + _bcast(beta, p).astype(p.dtype) * p
         return x, r, p, r2, pAp

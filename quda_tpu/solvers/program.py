@@ -37,9 +37,15 @@ keeps the eager solver call.  ``cg_reliable`` solves the NORMAL
 equations of a non-Hermitian PC operator (``MdagM_pairs``: Wilson,
 clover) and applies a ``hermitian`` one once an iteration (``M_pairs``:
 the staggered PC operator is already 4m^2 - D D); the batched program
-does the same on ``MdagM_pairs_mrhs`` / ``M_pairs_mrhs``.  With a
-leading source axis ``verified_exit`` and ``prepare`` are the batched
-route's.
+does the same on ``MdagM_pairs_mrhs`` / ``M_pairs_mrhs``.  Its loop
+takes ``(A p, p . A p)`` from one callable: the operator's own
+``MdagM_dot_pairs_mrhs`` / ``M_dot_pairs_mrhs`` where its class has one
+(the Wilson pair operator: ``pAp`` is ``|g5 M p|^2``, summed in the
+epilogue of the kernel that stores ``g5 M p``), else
+``block.with_dot`` of the matvec (XLA's dot over the batch).  Which of
+the two is the operand's class and static signature, so the key has no
+field for it.  With a leading source axis ``verified_exit`` and
+``prepare`` are the batched route's.
 """
 
 from __future__ import annotations
@@ -113,17 +119,20 @@ def cg_reliable(op_hi, op_lo, b, tol: float, maxiter: int, delta: float,
 def _batched_cg_pairs_program(op, B, tol, maxiter, key):
     _traces[0] += 1
     check_every, knobs, hermitian = key
+    mv = "M" if hermitian else "MdagM"
+    apply_batch = (getattr(op, mv + "_dot_pairs_mrhs", None)
+                   or block.with_dot(getattr(op, mv + "_pairs_mrhs")))
     return block.batched_cg_pairs_loop(
-        op.M_pairs_mrhs if hermitian else op.MdagM_pairs_mrhs, B, tol,
-        knobs.maxiter if knobs.record else maxiter, check_every,
-        knobs.record, knobs.sentinel, knobs.fault_k)
+        apply_batch, B, tol, knobs.maxiter if knobs.record else maxiter,
+        check_every, knobs.record, knobs.sentinel, knobs.fault_k)
 
 
 def batched_cg_pairs(op, B, tol: float, maxiter: int,
                      record: bool = False):
     """``block.batched_cg_pairs`` on ``op.MdagM_pairs_mrhs`` through
     the cached program; on ``M_pairs_mrhs``, once an iteration, where
-    the operator says it is ``hermitian``.  Returns
+    the operator says it is ``hermitian``; with ``pAp`` from the
+    operator where it has a ``*_dot_pairs_mrhs``.  Returns
     ``(BatchedCGResult, hit)``."""
     from .fused_iter import _resolve_check_every
     key = (_resolve_check_every(None), _loop_knobs(record, maxiter),

@@ -69,12 +69,19 @@ def _eo_mrhs(n, dt=F32, block_z=None, combine=False, lat=L, bt=2):
     keeps the z-blocked fallback compiled for the chip.  ``combine``:
     with its combine epilogue (the second hop of the batched PC
     operator: one more spinor block, the coefficient in SMEM, gamma5 in
-    registers), on each of those routes."""
+    registers, and the per-source sums of squares of what it stores,
+    the batched CG's ``pAp``: a second, small f32 output block counted
+    in the route's VMEM sum), on each of those routes."""
     from quda_tpu.ops import wilson_pallas_packed as wpp
     dims, yxh = (lat,) * 4, lat * lat // 2
     want = ("zblock", block_z, 1) if block_z else ("fullz", lat, bt)
-    assert wpp._mrhs_route(lat, lat, yxh, dt, dt, 3, block_z,
-                           dt if combine else None)[:3] == want
+    route = wpp._mrhs_route(lat, lat, yxh, dt, dt, 3, block_z,
+                            dt if combine else None)
+    assert route[:3] == want
+    if not block_z:
+        assert wpp._mrhs_fullz_vmem(
+            lat, yxh, dt, dt, 3, bt, dt if combine else None
+        )[1] <= route[3] <= wpp._MRHS_FULLZ_VMEM_CAP
     links = ((4, 3, 3, 2, lat, lat, yxh), dt)
     psi = ((n, 4, 3, 2, lat, lat, yxh), dt)
     if not combine:
@@ -425,8 +432,10 @@ def test_batched_solve_program_compiles_for_v5e_combining_in_the_kernel(
     ``block.batched_cg_pairs_loop``) at 24^4: the loop's ``MdagM`` is
     four MRHS kernels, the second and the fourth with the combine
     epilogue (seven operands: three spinor blocks, ``xc``, the
-    coefficient, the links), so what XLA is left with is the solver's
-    own updates; kappa and the links are parameters."""
+    coefficient, the links; two results, the second the per-source
+    sums of squares that are the loop's ``pAp``), so what XLA is left
+    with is the solver's own updates; kappa and the links are
+    parameters."""
     import re
     from quda_tpu.fields.geometry import LatticeGeometry
     from quda_tpu.models.wilson import DiracWilsonPCPackedSloppy
@@ -450,10 +459,13 @@ def test_batched_solve_program_compiles_for_v5e_combining_in_the_kernel(
         return sprog._batched_cg_pairs_program.lower(op, b, 1e-6, 10000,
                                                      key=key)
     hlo = _aot(lower).as_text()
-    calls = re.findall(r"%dslash_eo_pallas_packed_mrhs[.\d]* = f32\["
+    calls = re.findall(r"%dslash_eo_pallas_packed_mrhs[.\d]* = (\(?)f32\["
                        r"[^\n]*custom-call\(([^\n]*?)\), custom_call_"
                        r"target=\"tpu_custom_call\"", hlo)
-    assert sorted(c.count("%") for c in calls) == [5, 5, 7, 7], calls
+    # a fused hop has a second, small result, the sums of squares of
+    # what it stores: the first M's are the loop's pAp = |g5 M p|^2
+    assert sorted((t, c.count("%")) for t, c in calls) == [
+        ("", 5), ("", 5), ("(", 7), ("(", 7)], calls
     links = ",".join(str(d) for d in _links(F32)[0])
     assert sum(p[1:] == ("f32", links)
                for p in _hlo_values(hlo, "parameter")) == 4

@@ -161,6 +161,27 @@ def test_mrhs_operator_on_the_pallas_route_combines_in_the_second_hop(
     # two kernels traced, both full-Z: the first hop bare, the second
     # with the epilogue (Mdag's are MdagM's own, not traced again)
     assert route_counts("epilogue") == {"none": 1.0, "combine": 1.0}
+    assert route_counts("reduce") == {"none": 1.0, "norm2": 1.0}
+    # what the batched CG applies: the same MdagM x, and x . MdagM x as
+    # |g5 M x|^2 summed by the epilogue that stores g5 M x (the same
+    # two kernels: nothing more is traced)
+    from quda_tpu.solvers.block import _per_rhs_dot
+    ax, dot = op.MdagM_dot_pairs_mrhs(x)
+    assert bool(jnp.all(ax == op.MdagM_pairs_mrhs(x)))
+    want = _per_rhs_dot(x.astype(jnp.float32), ax.astype(jnp.float32))
+    assert dot.shape == (NRHS,) and dot.dtype == jnp.float32
+    # in bf16 storage q is rounded before its squares are summed and
+    # before the second M reads it: <q, q_r> against <q_r, q_r>
+    np.testing.assert_allclose(
+        np.asarray(dot), np.asarray(want),
+        rtol=1e-6 if store == jnp.float32 else 1e-2)
+    assert route_counts() == {"fullz": 2.0}
+    # off the kernel route the same method is XLA's dot of the lifted
+    # composition
+    ax, dot = xla.MdagM_dot_pairs_mrhs(x)
+    assert bool(jnp.all(ax == xla.MdagM_pairs_mrhs(x)))
+    assert bool(jnp.all(dot == _per_rhs_dot(
+        x.astype(jnp.float32), ax.astype(jnp.float32))))
     assert route_counts() == {"fullz": 2.0}
 
 
@@ -198,6 +219,41 @@ def test_batched_cg_pairs_matches_single_trajectory(pair_problem,
     # same trajectory up to reduction-order ulps (the per-RHS
     # reductions sum in a different shape than blas.norm2)
     assert abs(int(res.iters[0]) - int(single.iters)) <= 1
+
+
+@pytest.mark.parametrize("fault_k", [None, 10 ** 6])
+def test_batched_cg_pairs_loop_takes_pAp_from_its_operator(fault_k):
+    """The loop applies ``p -> (A p, per-source p . A p)`` and makes no
+    dot of its own: with ``with_dot`` it is the solver it was, and an
+    operator that hands over NaN for ``pAp`` breaks the solve.  With a
+    dslash fault armed (here at an iteration never reached) the dot is
+    the loop's own again, taken from the ``Ap`` the fault corrupts: the
+    operator's is ignored."""
+    from quda_tpu.solvers.block import batched_cg_pairs_loop, with_dot
+    rng = np.random.default_rng(6)
+    n, dim = 3, 128
+    d = jnp.stack([jnp.linspace(1.0, 2.0 + i, dim).astype(jnp.float32)
+                   for i in range(n)])
+    B = jnp.asarray(rng.standard_normal((n, dim)), jnp.float32)
+    mv = lambda V: d * V
+
+    def solve(apply_batch):
+        return batched_cg_pairs_loop(apply_batch, B, 1e-6, 200, 1, False,
+                                     None, fault_k)
+    want = solve(with_dot(mv))
+    assert bool(jnp.all(want.converged))
+    np.testing.assert_allclose(np.asarray(want.x), np.asarray(B / d),
+                               rtol=1e-4, atol=1e-5)
+    got = solve(lambda V: (mv(V), jnp.full((n,), jnp.nan, jnp.float32)))
+    if fault_k is None:
+        assert not bool(jnp.any(got.converged))
+        assert not bool(jnp.any(jnp.isfinite(got.x)))
+    else:
+        assert bool(jnp.all(got.converged))
+        np.testing.assert_array_equal(np.asarray(got.iters),
+                                      np.asarray(want.iters))
+        np.testing.assert_array_equal(np.asarray(got.x),
+                                      np.asarray(want.x))
 
 
 def test_batched_cg_pairs_check_cadence():
@@ -654,7 +710,12 @@ _COMBINE_ROUTES = {"fullz2": (None, "fullz", "combine"),
     pytest.param(0, False, "fullz1", jnp.bfloat16,
                  marks=pytest.mark.slow),
     pytest.param(0, True, "zblock", jnp.bfloat16,
-                 marks=pytest.mark.slow)])
+                 marks=pytest.mark.slow),
+    # every route in tier 1 since the epilogue also sums (PR 37), with
+    # and without g5
+    (1, False, "fullz1", jnp.float32),
+    (1, True, "zblock", jnp.float32),
+    (0, False, "xla", jnp.float32)])
 def test_mrhs_combine_epilogue_is_xla_on_the_plain_hop(
         parity, g5, route, dtype, route_counts, monkeypatch):
     """``xc``, ``coeff``, ``g5``: the call writes ``[g5] (xc + coeff *
@@ -663,7 +724,11 @@ def test_mrhs_combine_epilogue_is_xla_on_the_plain_hop(
     no route holds the xc block (``xla``) XLA combines the bare hop, as
     without the epilogue.  Against the plain call's f32 hop combined by
     XLA: the last bit may differ (one contracts the multiply-add, one
-    does not), and with it now and then the bf16 a sum rounds to."""
+    does not), and with it now and then the bf16 a sum rounds to.
+    Besides, the call returns the per-source sums of squares of what
+    it stored (f32, to f32 rounding of a sum taken in f64: the kernel's
+    own partial sums, XLA's on the fallback), counted with
+    ``reduce="norm2"``."""
     from quda_tpu.ops import wilson_pallas_packed as wpp
     bend, counted, epilogue = _COMBINE_ROUTES[route]
     dims, nrhs = _fz_dims(dtype), 2 if bend is None else 3
@@ -674,9 +739,14 @@ def test_mrhs_combine_epilogue_is_xla_on_the_plain_hop(
     u_here, u_bw, psi_b = _eo_mrhs_problem(dims, parity, nrhs, dtype)
     xc = _eo_mrhs_problem(dims, parity, nrhs, dtype, seed=9)[2]
     coeff = -0.12 ** 2
-    got = wpp.dslash_eo_pallas_packed_mrhs(
+    got, sums = wpp.dslash_eo_pallas_packed_mrhs(
         u_here, u_bw, psi_b, dims, parity, interpret=True, xc=xc,
         coeff=coeff, g5=g5, **kw)
+    assert sums.shape == (nrhs,) and sums.dtype == jnp.float32
+    np.testing.assert_allclose(
+        np.asarray(sums), np.asarray(jnp.sum(
+            got.astype(jnp.float64).reshape(nrhs, -1) ** 2, axis=1)),
+        rtol=2e-6)
     hop = wpp.dslash_eo_pallas_packed_mrhs(
         u_here, u_bw, psi_b, dims, parity, interpret=True,
         out_dtype=jnp.float32, **kw)
@@ -695,6 +765,10 @@ def test_mrhs_combine_epilogue_is_xla_on_the_plain_hop(
     assert route_counts("epilogue") == (
         {"none": 2.0} if epilogue == "none"
         else {"none": 1.0, "combine": 1.0})
+    # the fallback's sums are XLA's: no kernel is counted with them
+    assert route_counts("reduce") == (
+        {"none": 2.0} if epilogue == "none"
+        else {"none": 1.0, "norm2": 1.0})
 
 
 @pytest.mark.parametrize("case,want", [
@@ -711,8 +785,9 @@ def test_mrhs_combine_epilogue_is_xla_on_the_plain_hop(
     ((32, 32, 512, jnp.bfloat16, jnp.bfloat16, 3, None), ("fullz", 32, 2)),
     ((40, 40, 640, jnp.float32, jnp.float32, 3, None), ("zblock", 8, 1)),
     ((8, 8, 8, jnp.float32, jnp.float32, 3, None), ("fullz", 8, 2)),
-    # the combine epilogue's xc block is one more operand: 24^4 still
-    # takes two slices a step (38.8 MiB of the 48), a 32 x 32 plane at
+    # the combine epilogue's xc block is one more operand, and its
+    # block of sums one chunk of f32 rows more: 24^4 still takes two
+    # slices a step (38.8 MiB of the 48), a 32 x 32 plane at
     # Z = 24 takes two without it and one with it, and where the hop's
     # own z-block is the largest that fits no route holds it
     ((24, 24, 288, jnp.float32, jnp.float32, 3, None, jnp.float32),
@@ -748,7 +823,19 @@ def test_mrhs_route_follows_the_shapes(case, want, route_counts):
     assert (route, bz, bt) == want and route_counts() == {route: 1.0}
     assert route_counts("epilogue") == {
         "none" if xc_dt is None else "combine": 1.0}
+    assert route_counts("reduce") == {
+        "none" if xc_dt is None else "norm2": 1.0}
     blocks, need = wpp._mrhs_fullz_vmem(Z, YX, dt, odt, R, bt, xc_dt)
+    if xc_dt is not None:
+        # bt spinor tiles and one chunk of the body's rows in f32 (the
+        # epilogue's sums), every plane padded to 128 lanes
+        def padded(n, d):
+            sub = wpp._sublane_rows(d)
+            return -(-n // sub) * sub
+        lanes = -(-YX // 128) * 128
+        assert blocks - wpp._mrhs_fullz_vmem(Z, YX, dt, odt, R, bt)[0] == (
+            bt * 24 * padded(Z, xc_dt) * lanes * jnp.dtype(xc_dt).itemsize
+            + padded(wpp._fullz_chunk(Z, dt), jnp.float32) * lanes * 4)
     if route == "fullz":
         assert need <= limit <= wpp._MRHS_FULLZ_VMEM_CAP
         row = rows["QUDA_TPU_PALLAS_VMEM_MB[fullz]"]

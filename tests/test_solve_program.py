@@ -228,30 +228,66 @@ def test_second_kappa_reuses_the_program(route, warm, gauges):
     assert _host_residual(gauges["A"], b[0], x[0], KAPPA) > 1e-3
 
 
-def test_batched_route_operator_combines_in_its_second_hops(quda):
+@pytest.mark.parametrize("method,n", [("MdagM_pairs_mrhs", 5),
+                                      ("MdagM_dot_pairs_mrhs", 6)])
+def test_batched_route_operator_combines_in_its_second_hops(quda, method,
+                                                            n):
     """The batched route's resident operator hands its program ``Ap``
     and not the bare hop sums: tracing ``MdagM`` on a batch of a size
     this process has not traced traces two kernels, the first hop bare
     and the second with the combine epilogue
     (``wilson_mrhs_route_total``); kappa is an operand of that epilogue,
-    which is why the second kappa above is a hit."""
+    which is why the second kappa above is a hit.  That epilogue sums
+    the squares of what it stores (``reduce="norm2"``, once per traced
+    fused hop), so what the program's loop applies, ``MdagM_dot``, is
+    the same two kernels and hands it ``pAp`` too."""
     def counts():
         out = {}
         for (name, labels), v in omet.snapshot()["counters"].items():
             if name == "wilson_mrhs_route_total":
                 lab = dict(labels)
-                out[lab["route"], lab["epilogue"]] = int(v)
+                out[lab["route"], lab["epilogue"], lab["reduce"]] = int(v)
         return out
     op = api._resident_wilson(_param())["ops"][jnp.dtype(jnp.float32)]
     before = counts()
-    batch = jax.ShapeDtypeStruct((5, 4, 3, 2, L, L, L * L // 2),
+    batch = jax.ShapeDtypeStruct((n, 4, 3, 2, L, L, L * L // 2),
                                  jnp.float32)
-    out = jax.eval_shape(op.with_kappa(KAPPA).MdagM_pairs_mrhs, batch)
+    out = jax.eval_shape(getattr(op.with_kappa(KAPPA), method), batch)
+    if method == "MdagM_dot_pairs_mrhs":
+        out, dot = out
+        assert (dot.shape, dot.dtype) == ((n,), jnp.float32)
     assert (out.shape, out.dtype) == (batch.shape, batch.dtype)
     after = counts()
     assert {k: after[k] - before.get(k, 0) for k in after
             if after[k] != before.get(k, 0)} == {
-        ("fullz", "combine"): 1, ("fullz", "none"): 1}
+        ("fullz", "none", "none"): 1, ("fullz", "combine", "norm2"): 1}
+
+
+@pytest.mark.parametrize("route", ["multi"])
+def test_kernel_pAp_stops_the_batched_solve_where_the_dot_does(
+        route, warm, knobs, gauges):
+    """The batched API call on the kernel route (``pAp`` out of the
+    second hop's epilogue) against the same call on the XLA stencil
+    (``block.with_dot``: XLA's dot over the batch): both converge to
+    tol, source by source within two iterations of each other, and the
+    kernel route's call is a hit of the program the worker's first call
+    traced."""
+    before = _counts(route)
+    b, x, p = _solve(route, seed=12)
+    assert _delta(route, before) == (0, 1) and p.converged
+    iters = list(p.iter_count_multi)
+    try:
+        knobs(QUDA_TPU_PALLAS="0")
+        _, x_dot, p_dot = _solve(route, seed=12)
+    finally:
+        knobs(QUDA_TPU_PALLAS="1")
+        api._resident_wilson(_param())      # the kernel route's, again
+    assert p_dot.converged
+    assert all(abs(i - j) <= 2
+               for i, j in zip(iters, p_dot.iter_count_multi))
+    for i in range(len(b)):
+        assert _host_residual(gauges["A"], b[i], x[i], KAPPA) < 5e-6
+        assert _host_residual(gauges["A"], b[i], x_dot[i], KAPPA) < 5e-6
 
 
 # the resident term and the verified exit, through the API -----------------
@@ -436,6 +472,8 @@ def test_hermitian_batched_program_applies_m_once_an_iteration(
     op = DiracStaggeredPC(fat, geom, 0.1, improved=True,
                           long_links=lng).pairs(jnp.float32)
     assert op.hermitian and sprog.presents(op)
+    # no dot of its own: the program lifts M_pairs_mrhs (block.with_dot)
+    assert not hasattr(op, "M_dot_pairs_mrhs")
     hops = []
     d_to = DiracStaggeredPCPairs._d_to_mrhs
     monkeypatch.setattr(
