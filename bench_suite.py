@@ -45,20 +45,15 @@ def _emit(suite, name, secs, flops, bytes_, platform, lattice,
     # HERE, loudly.  secs is rounded to 9 digits so a genuine ~1 us
     # marginal cannot quantize DOWN to the gate's 1e-6 floor and be
     # rejected as noise.
-    from quda_tpu.obs import metrics as qmet
     from quda_tpu.obs.roofline import achieved
     th = achieved(flops, bytes_, secs)
-    ok = record_row(suite, {
+    record_row(suite, {
         "name": name,
         "gflops": th["gflops"],
         "gbps": th["gbps"],
         "secs_per_call": round(secs, 9),
         "platform": platform, "lattice": list(lattice), **extra,
     }, banner_platform=banner)
-    # count only rows the gate actually recorded — a rejected row in
-    # the counter would overstate a partially-failing suite's output
-    if ok:
-        qmet.inc("bench_rows_total", suite=suite)
 
 
 def _bench_op(fn, arg, consts=(), n1=8, n2=200, reps=3):

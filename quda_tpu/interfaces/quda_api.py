@@ -54,6 +54,8 @@ def init_quda(device: int = 0):
     qmon.start_default()       # QUDA_TPU_ENABLE_MONITOR sampling thread
     otr.maybe_start()          # QUDA_TPU_TRACE span/event session
     omet.maybe_start()         # QUDA_TPU_METRICS counter/gauge registry
+    from ..obs import build as obuild
+    obuild.install()           # what jax builds, by program and span
     from ..obs import comms as ocomms
     ocomms.maybe_start()       # ICI comms ledger (rides both knobs)
     from ..obs import flight as ofl
@@ -1367,11 +1369,18 @@ def _note_solve_program(span, api: str, form: str, solver: str,
                         hit: bool):
     """A call went through a cached solve program (solvers/program.py):
     hit or miss onto the solve span and the ``solve_program_total``
-    counter."""
+    counter.  A miss's span also says what the build took: the seconds
+    of the build records (obs/build.py) under ``span``, which the caller
+    still holds open, in this call; the counter that owns those seconds
+    is ``program_build_seconds{program, stage}``."""
+    from ..obs import build as obuild
     from ..obs import metrics as omet
-    outcome = "hit" if hit else "miss"
-    span.set(program=outcome)
-    omet.record_solve_program(api, form, solver, outcome)
+    if hit:
+        span.set(program="hit")
+    else:
+        span.set(program="miss",
+                 build_seconds=round(obuild.seconds_here(), 6))
+    omet.record_solve_program(api, form, solver, "hit" if hit else "miss")
 
 
 def _verified_exit(api: str, form: str, op, b, x_pp):
@@ -1387,7 +1396,8 @@ def _verified_exit(api: str, form: str, op, b, x_pp):
     with otr.span("verified_exit", cat="epilogue") as span:
         (x_full, true_res), hit = sprog.verified_exit(op, b, x_pp)
         _note_solve_program(span, api, form, "verified-exit", hit)
-        return x_full, np.asarray(true_res)
+        with otr.span("exit_read", cat="epilogue"):
+            return x_full, np.asarray(true_res)
 
 
 def _invert_quda_body(source, param: InvertParam):
@@ -1528,10 +1538,13 @@ def _invert_quda_body(source, param: InvertParam):
                                  _pallas_interpret(on_tpu))
 
             if ks_resident:
-                rhs = d.prepare_source(b)
+                with otr.span("prepare", cat="setup"):
+                    rhs = d.prepare_source(b)
             elif pc:
-                be, bo = _split(b, param, d)
-                rhs = d.prepare(be, bo)
+                with otr.span("source_split", cat="setup"):
+                    be, bo = _split(b, param, d)
+                with otr.span("prepare", cat="setup"):
+                    rhs = d.prepare(be, bo)
             else:
                 rhs = b
 
@@ -1549,7 +1562,8 @@ def _invert_quda_body(source, param: InvertParam):
                 mv_applies = 1.0
             elif normop:
                 mv = lambda v: d.Mdag(d.M(v))
-                sys_rhs = d.Mdag(rhs)
+                with otr.span("mdag", cat="setup"):
+                    sys_rhs = d.Mdag(rhs)
                 back = lambda x: x
                 mv_applies = 2.0
             else:
@@ -1566,7 +1580,8 @@ def _invert_quda_body(source, param: InvertParam):
                 qlog.warningq("cg on a non-normal system; using CGNR "
                               "(normal-residual) semantics")
                 mv = lambda v: d.Mdag(d.M(v))
-                sys_rhs = d.Mdag(rhs)
+                with otr.span("mdag", cat="setup"):
+                    sys_rhs = d.Mdag(rhs)
                 mv_applies = 2.0
 
             # direct-route solvers that internally apply the operator
@@ -1595,18 +1610,21 @@ def _invert_quda_body(source, param: InvertParam):
         # keyword-only at the call site: four adjacent bools among 18
         # parameters — a positional transposition would type-check and
         # silently pick the wrong solve route
-        res = _invert_dispatch(param=param, d=d, d_full=d_full, b=b,
-                               rhs=rhs, sys_rhs=sys_rhs, mv=mv,
-                               mv_applies=mv_applies, inv=inv,
-                               mixed=mixed, pair_sloppy=pair_sloppy,
-                               hermitian_pc=hermitian_pc, normop=normop,
-                               sloppy_prec=sloppy_prec, dtype=dtype,
-                               pc=pc, t0=t0, recording=recording,
-                               solve_span=solve_span)
+        with otr.span("dispatch", cat="solver"):
+            res = _invert_dispatch(param=param, d=d, d_full=d_full, b=b,
+                                   rhs=rhs, sys_rhs=sys_rhs, mv=mv,
+                                   mv_applies=mv_applies, inv=inv,
+                                   mixed=mixed, pair_sloppy=pair_sloppy,
+                                   hermitian_pc=hermitian_pc,
+                                   normop=normop, sloppy_prec=sloppy_prec,
+                                   dtype=dtype, pc=pc, t0=t0,
+                                   recording=recording,
+                                   solve_span=solve_span)
         # the cached solve program returns at dispatch: wait here, so
         # the phase, t_solve and the span hold the solve's device time
         # and not the epilogue's first host read
-        jax.block_until_ready(res)
+        with otr.span("wait", cat="solver"):
+            jax.block_until_ready(res)
     if not isinstance(res, tuple):
         return res             # gcr-mg handled everything itself
     res, publish_sys_rhs = res
@@ -2108,12 +2126,14 @@ def _invert_multi_src_body(sources, param: InvertParam):
                     _note_solve_program(span, "invert_multi_src_quda",
                                         form_b, "prepare", hit)
             else:
-                halves = [even_odd_split(B[i], geom)
-                          for i in range(n_src)]
-                be = jnp.stack([h[0] for h in halves])
-                bo = jnp.stack([h[1] for h in halves])
-                del halves
-                rhs_b = op.prepare_pairs_mrhs(be, bo)
+                with otr.span("source_split", cat="setup"):
+                    halves = [even_odd_split(B[i], geom)
+                              for i in range(n_src)]
+                    be = jnp.stack([h[0] for h in halves])
+                    bo = jnp.stack([h[1] for h in halves])
+                    del halves
+                with otr.span("prepare", cat="setup"):
+                    rhs_b = op.prepare_pairs_mrhs(be, bo)
                 if pair_exit:
                     del be, bo      # the verified exit splits B itself
             if stag_family:
@@ -2127,7 +2147,8 @@ def _invert_multi_src_body(sources, param: InvertParam):
                 # CGNR on the batched normal equations (coefficients
                 # real — exact on pairs; same route as the
                 # single-source wil_pairs cg)
-                nrm_b = op.Mdag_pairs_mrhs(rhs_b)
+                with otr.span("mdag", cat="setup"):
+                    nrm_b = op.Mdag_pairs_mrhs(rhs_b)
                 mv_b = op.MdagM_pairs_mrhs
                 mv_applies = 2.0
             use_block = str(qconf.get("QUDA_TPU_MULTI_SRC_BLOCK",
@@ -2150,12 +2171,16 @@ def _invert_multi_src_body(sources, param: InvertParam):
                 # M_pairs_mrhs where it is Hermitian (staggered), and
                 # takes pAp from the operator's own *_dot_pairs_mrhs
                 # where it has one (Wilson: the kernel's |g5 M p|^2)
-                res, hit = sprog.batched_cg_pairs(
-                    op, nrm_b, tol=param.tol, maxiter=param.maxiter,
-                    record=recording)
+                with otr.span("dispatch", cat="solver"):
+                    res, hit = sprog.batched_cg_pairs(
+                        op, nrm_b, tol=param.tol, maxiter=param.maxiter,
+                        record=recording)
                 _note_solve_program(solve_span, "invert_multi_src_quda",
                                     form_b, solver_name, hit)
-                iters_rhs = np.asarray(res.iters)
+                # the first host read of the program's result: the wait
+                # for its device time
+                with otr.span("wait", cat="solver"):
+                    iters_rhs = np.asarray(res.iters)
             else:
                 res = batched_cg_pairs(mv_b, nrm_b,
                                        tol=param.tol,

@@ -594,7 +594,9 @@ class _PackedHopMixin:
         with them the result is ``(batch, its squared norms per
         source)``."""
         from ..ops import wilson_pallas_packed as wpp
-        return wpp.dslash_eo_pallas_packed_mrhs(
+        hop = (wpp.dslash_eo_pallas_packed_mrhs_combine if epilogue
+               else wpp.dslash_eo_pallas_packed_mrhs)
+        return hop(
             self.gauge_eo_pp[target_parity], self._u_bw[target_parity],
             psi_b, tuple(self.dims), target_parity,
             interpret=self._pallas_interpret, out_dtype=out_dtype,

@@ -36,6 +36,12 @@ home for that surface:
                         vs warm-executable accounting, tuner warm-cache
                         hit/miss, retry-ladder counters; exported as
                         Prometheus text + metrics.tsv by end_quda.
+* ``obs.build``       — build accounting: every program jax traces,
+                        lowers and compiles (``jax.monitoring``), with
+                        its seconds by stage, the persistent cache's
+                        answer and the span and API call it was built
+                        under; always on, read by the benchmark's
+                        first-call metrics.
 * ``obs.memory``      — HBM field ledger (every resident field tracked
                         at load/free with per-family bytes + high-water),
                         all-local-device memory_stats sampling around
@@ -84,6 +90,6 @@ home for that surface:
 # obs.replay is deliberately NOT imported eagerly: it is the
 # ``python -m quda_tpu.obs.replay`` entry point, and runpy warns when a
 # -m target is already resident from its package import
-from . import (comms, convergence, costmodel, flight,  # noqa: F401
-               history, memory, metrics, postmortem, regress,
+from . import (build, comms, convergence, costmodel,  # noqa: F401
+               flight, history, memory, metrics, postmortem, regress,
                report, roofline, schema, trace)

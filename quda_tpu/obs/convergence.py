@@ -193,7 +193,8 @@ def harvest(solver: str, res, tol: float, b2
 def publish(rec: Optional[ConvergenceRecord], param=None):
     """Surface a record on an InvertParam (res_history/events) and emit
     per-iteration ``residual`` events into the trace stream (one per
-    history entry; per-lane entries carry their lane label)."""
+    history entry of the headline lane; the per-lane histories stay on
+    the record)."""
     if rec is None:
         return None
     if param is not None:
@@ -204,13 +205,6 @@ def publish(rec: Optional[ConvergenceRecord], param=None):
         for e in rec.history:
             otr.event("residual", cat="convergence", solver=rec.solver,
                       iter=e["iter"], r2=e["r2"], relres=e["relres"])
-        if rec.lanes:
-            for label, lane in rec.lanes.items():
-                for e in lane:
-                    otr.event("residual_lane", cat="convergence",
-                              solver=rec.solver, lane=label,
-                              iter=e["iter"], r2=e["r2"],
-                              relres=e["relres"])
         for ev in rec.events:
             otr.event(ev.get("type", "solver_event"), cat="convergence",
                       solver=rec.solver,

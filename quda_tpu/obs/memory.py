@@ -15,8 +15,8 @@ Three surfaces:
 * the **field ledger** — :func:`track` / :func:`release` called at every
   resident-field load/free site (interfaces/quda_api.py, models/).
   Host-side dict bookkeeping (nanoseconds, no device ops), ALWAYS
-  maintained; mirrored into the metrics registry (``hbm_field_bytes``,
-  family totals, high-water gauges) and the trace stream only when
+  maintained; mirrored into the metrics registry (family totals and
+  high-water gauges) and the trace stream only when
   those sessions are active.
 * **device snapshots** — :func:`device_snapshot` reads
   ``memory_stats()`` from **all** local devices (not just device 0 —
@@ -116,9 +116,7 @@ def track(family: str, name: str, obj) -> int:
         if fam_total > _family_high.get(family, 0):
             _family_high[family] = fam_total
         high = _family_high.get(family, 0)
-    from . import metrics as omet
     from . import trace as otr
-    omet.set_gauge("hbm_field_bytes", nbytes, family=family, field=name)
     _mirror_family(family, fam_total, high)
     otr.event("hbm_field_tracked", cat="memory", family=family,
               field=name, bytes=int(nbytes))
@@ -148,9 +146,7 @@ def release(family: str, name: str) -> bool:
             return False
         fam_total = _family_total_locked(family)
         high = _family_high.get(family, 0)
-    from . import metrics as omet
     from . import trace as otr
-    omet.set_gauge("hbm_field_bytes", 0, family=family, field=name)
     _mirror_family(family, fam_total, high)
     otr.event("hbm_field_released", cat="memory", family=family,
               field=name, bytes=entry["bytes"])
@@ -215,17 +211,10 @@ def device_snapshot() -> List[dict]:
 
 def sample(phase: str = "") -> List[dict]:
     """Solve-phase device sampling hook (quda_api, metrics-gated at the
-    call sites): snapshot all local devices and mirror the per-device
-    gauges.  ``phase`` is advisory (kept for call-site readability)."""
-    rows = device_snapshot()
-    from . import metrics as omet
-    for r in rows:
-        omet.set_gauge("hbm_device_bytes_in_use", r["bytes_in_use"],
-                       device=r["device"])
-        omet.set_gauge("hbm_device_high_water_bytes",
-                       _device_high.get(r["device"], 0),
-                       device=r["device"])
-    return rows
+    call sites): snapshot all local devices into the ledger's per-device
+    high-water marks (the fleet report reads them).  ``phase`` is
+    advisory (kept for call-site readability)."""
+    return device_snapshot()
 
 
 # -- VMEM budget audit ------------------------------------------------------

@@ -30,8 +30,6 @@ TRACE_EVENTS: dict[str, dict] = {
     # convergence recording (obs/convergence.py)
     "residual": {"cat": "residual",
                  "doc": "per-iteration solver residual (headline lane)"},
-    "residual_lane": {"cat": "residual",
-                      "doc": "per-RHS/per-shift lane residual"},
     # roofline attribution (obs/roofline.py)
     "roofline": {"cat": "roofline",
                  "doc": "one achieved-GFLOPS/BW attribution row"},
@@ -138,6 +136,14 @@ SPAN_ATTRS: dict[str, dict] = {
                        "program (solvers/program.py) served the call "
                        "from the in-process executable cache or traced "
                        "anew; absent on an eager solve"},
+    "build_seconds": {"spans": ("solve:cg", "solve:batched-cg-pairs",
+                                "verified_exit", "prepare"),
+                      "doc": "on a 'miss': the seconds jax spent tracing, "
+                             "lowering and compiling (or fetching) under "
+                             "this span in this call, summed from the "
+                             "build accounting's records (obs/build.py), "
+                             "which program_build_seconds counts by "
+                             "program"},
 }
 
 # -- metrics (obs/metrics.py registry) --------------------------------------
@@ -182,6 +188,16 @@ METRICS: dict[str, dict] = {
                 "by api/form/solver/outcome: 'miss' traced (and lowered, "
                 "compiled or fetched) the program, 'hit' was an "
                 "in-process executable lookup"},
+    "program_build_seconds": {
+        "type": COUNTER,
+        "help": "seconds jax spent building programs (obs/build.py, from "
+                "jax.monitoring), by program (the jitted function's name; "
+                "eager operations are jit(<primitive>) programs of their "
+                "own) and stage: 'trace' Python to jaxpr, 'lower' jaxpr "
+                "to MLIR (a pallas kernel's Mosaic lowering included), "
+                "'compile' XLA's compile or the fetch from the "
+                "persistent cache; a trace nested in another program's "
+                "is part of that program's and not counted twice"},
     "clover_term_total": {
         "type": COUNTER,
         "help": "uses of the resident clover term (load_clover_quda, "
@@ -258,21 +274,12 @@ METRICS: dict[str, dict] = {
         "type": COUNTER,
         "help": "breakdown-sentinel exits, by api/reason"},
     # HBM field ledger (obs/memory.py)
-    "hbm_field_bytes": {
-        "type": GAUGE,
-        "help": "resident bytes of one registered field, by family/field"},
     "hbm_family_bytes": {
         "type": GAUGE,
         "help": "resident bytes per field family"},
     "hbm_family_high_water_bytes": {
         "type": GAUGE,
         "help": "session high-water resident bytes per field family"},
-    "hbm_device_bytes_in_use": {
-        "type": GAUGE,
-        "help": "backend bytes_in_use per local device (last sample)"},
-    "hbm_device_high_water_bytes": {
-        "type": GAUGE,
-        "help": "session high-water bytes_in_use per local device"},
     # VMEM budget audit (obs/memory.py vs QUDA_TPU_PALLAS_VMEM_MB*)
     "vmem_budget_bytes": {
         "type": GAUGE,
@@ -363,10 +370,6 @@ METRICS: dict[str, dict] = {
                 "family pooled): (1 - compliance) / "
                 "(1 - QUDA_TPU_SLO_OBJECTIVE) against "
                 "QUDA_TPU_SLO_TARGET_MS"},
-    # bench harness (bench_suite.py)
-    "bench_rows_total": {
-        "type": COUNTER,
-        "help": "bench rows emitted, by suite"},
     # static analysis (quda_tpu/analysis; bench_suite --artifacts-dir
     # runs the engine and mirrors per-rule counts here for the fleet
     # report's Static analysis section)

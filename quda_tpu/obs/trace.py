@@ -10,18 +10,19 @@ the resource path) when tracing is active.
 
 Activation: ``QUDA_TPU_TRACE=1`` (read by init_quda via
 ``maybe_start``) or an explicit ``start()`` (the bench harness's
-``--trace``).  **Off means off**: ``span()`` returns a module-level
-no-op singleton whose __enter__/__exit__ do nothing and ``event()``
-returns after one global load — no buffers, no clocks, no allocation —
-so instrumented code is safe to leave in hot host paths and around jit
-boundaries.  (Spans time HOST regions; device work inside a span is
-attributed to it only up to XLA's async dispatch, so callers that need
-device-accurate spans must pass a fetched/blocked result the way the
-bench harness does.)
-
-When jax.profiler.TraceAnnotation is available each span also opens a
-matching annotation, so quda_tpu spans show up inside a jax/XLA
-profiler capture (StartTraceRegion analog).
+``--trace``).  With no session open ``event()`` returns after one
+global load and a span keeps no buffer and reads no clock: it opens a
+``jax.profiler.TraceAnnotation`` of its name (StartTraceRegion analog:
+nanoseconds while no profiler captures) and puts the name on the build
+accounting's stack (obs/build.py), so ANY ``jax.profiler`` capture holds
+the program's spans on the profiler's own clock, beside the device's
+operations, and everything jax builds is charged to the span it was
+built under.  ``QUDA_TPU_DO_NOT_PROFILE`` turns that off with the phase
+timers: ``span()`` then returns a module-level no-op singleton.  The
+call sites are at the API layer, never inside a solver iteration.
+(The session's spans time HOST regions; device work inside a span is
+attributed to it only up to XLA's async dispatch.  In a profiler
+capture the device's own timeline says what ran under which span.)
 """
 
 from __future__ import annotations
@@ -33,6 +34,10 @@ import time
 from contextlib import contextmanager
 from typing import Optional
 
+from jax.profiler import TraceAnnotation
+
+from ..utils import timer as _qtimer
+from . import build as _build
 # the flight recorder taps this module's event stream (obs/flight.py
 # imports nothing from here at module level, so the edge is acyclic)
 from . import flight as _flight
@@ -40,7 +45,8 @@ from . import schema
 
 
 class _NoopSpan:
-    """Zero-overhead disabled span (the QUDA_DO_NOT_PROFILE analog)."""
+    """Zero-overhead disabled span (QUDA_TPU_DO_NOT_PROFILE: the
+    QUDA_DO_NOT_PROFILE analog)."""
     __slots__ = ()
 
     def __enter__(self):
@@ -56,6 +62,30 @@ class _NoopSpan:
 _NOOP = _NoopSpan()
 
 
+class _BareSpan:
+    """A span with no session open: its name on the build accounting's
+    stack and as a profiler annotation, nothing recorded here."""
+    __slots__ = ("name", "_api", "_ann")
+
+    def __init__(self, name: str, api: bool = False):
+        self.name = name
+        self._api = api
+
+    def __enter__(self):
+        _build.push(self.name, self._api)
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        _build.pop()
+        return False
+
+    def set(self, **attrs):
+        pass
+
+
 class _Session:
     def __init__(self, path: str, prefix: str, max_events: int):
         self.path = path
@@ -69,12 +99,6 @@ class _Session:
         self.lock = threading.Lock()
         self.depth: dict = {}      # thread ident -> current span depth
         self.device_pids: dict = {}  # device label -> chrome pid
-        try:
-            import jax.profiler
-            self.annotation_cls = getattr(jax.profiler, "TraceAnnotation",
-                                          None)
-        except Exception:
-            self.annotation_cls = None
 
 
 _session: Optional[_Session] = None
@@ -208,12 +232,14 @@ def _mirror_span_per_device(s: _Session, name: str, cat: str, ts: float,
 
 class _Span:
     __slots__ = ("name", "cat", "args", "_ts", "_ann", "_depth", "_tid",
-                 "_mesh")
+                 "_mesh", "_api")
 
-    def __init__(self, name: str, cat: str, args: dict, mesh=None):
+    def __init__(self, name: str, cat: str, args: dict, mesh=None,
+                 api: bool = False):
         self.name = name
         self.cat = cat
         self.args = args
+        self._api = api
         self._ann = None
         self._ts = 0.0
         self._depth = 0
@@ -237,21 +263,16 @@ class _Span:
         self._tid = threading.get_ident()
         self._depth = s.depth.get(self._tid, 0) + 1
         s.depth[self._tid] = self._depth
-        if s.annotation_cls is not None:
-            try:
-                self._ann = s.annotation_cls(self.name)
-                self._ann.__enter__()
-            except Exception:
-                self._ann = None
+        _build.push(self.name, self._api)
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
         self._ts = _now_us(s)
         return self
 
     def __exit__(self, *exc):
         if self._ann is not None:
-            try:
-                self._ann.__exit__(*exc)
-            except Exception:
-                pass
+            self._ann.__exit__(*exc)
+            _build.pop()
         s = _session
         if s is None or self._depth == 0:
             return False
@@ -274,15 +295,26 @@ class _Span:
         return False
 
 
+def _bare(name: str, annotate: Optional[bool] = None, api: bool = False):
+    """The span of a process with no session open.  ``annotate``: whether
+    the profile is on, where the caller has asked already; a span inside
+    an open one follows it and does not ask again."""
+    if annotate is None:
+        annotate = _build.inside() or _qtimer._profiling_enabled()
+    return _BareSpan(name, api) if annotate else _NOOP
+
+
 def span(name: str, cat: str = "api", mesh=None, **args):
-    """A nestable named span; the module no-op singleton when tracing is
-    off (so call sites stay branch-cheap on the disabled path).  With
-    ``mesh`` (a jax.sharding.Mesh) the span is additionally mirrored
-    onto one chrome track per local mesh device, mesh coordinates in
-    the track names — a sharded solve renders as parallel device rows
-    in perfetto instead of one collapsed host track."""
+    """A nestable named span.  In a session it is recorded; with none
+    open it is a profiler annotation and a frame of the build
+    accounting's stack (module docstring), and under
+    QUDA_TPU_DO_NOT_PROFILE the module's no-op singleton.  With
+    ``mesh`` (a jax.sharding.Mesh) a session's span is additionally
+    mirrored onto one chrome track per local mesh device, mesh
+    coordinates in the track names — a sharded solve renders as parallel
+    device rows in perfetto instead of one collapsed host track."""
     if _session is None:
-        return _NOOP
+        return _bare(name)
     return _Span(name, cat, args, mesh=mesh)
 
 
@@ -342,11 +374,12 @@ def api_span(name: str, **args):
     entries/exits are also marked into the flight-recorder ring
     (host-side, no-op when QUDA_TPU_FLIGHT is off) so a postmortem
     bundle's tail shows what the worker was serving when it failed."""
-    from ..utils.timer import push_profile
     _flight.record("api_enter", cat="api", api=name, **args)
     try:
-        with push_profile(name):
-            with span(name, cat="api", **args):
+        with _qtimer.push_profile(name) as prof:
+            with (_bare(name, prof is not None, api=True)
+                  if _session is None
+                  else _Span(name, "api", args, api=True)):
                 yield
     finally:
         _flight.record("api_exit", cat="api", api=name)
@@ -359,14 +392,14 @@ def phase(category: str, profile: Optional[str] = None, mesh=None,
     — the setup/compute/comms/epilogue breakdown inside an api_span.
     ``mesh`` mirrors the span onto per-device chrome tracks (see
     :func:`span`)."""
-    from ..utils import timer as qtimer
-    prof = (qtimer.get_profile(profile)
-            if profile is not None and qtimer._profiling_enabled()
-            else None)
+    profiling = _qtimer._profiling_enabled()
+    prof = (_qtimer.get_profile(profile)
+            if profile is not None and profiling else None)
     if prof is not None:
         prof.start(category)
     try:
-        with span(category, cat=category, mesh=mesh, **args):
+        with (_bare(category, profiling) if _session is None
+              else _Span(category, category, args, mesh=mesh)):
             yield
     finally:
         if prof is not None:

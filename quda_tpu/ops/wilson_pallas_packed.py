@@ -897,22 +897,35 @@ def dslash_pallas_packed_mrhs(gauge_pl: jnp.ndarray, psi_pl: jnp.ndarray,
                      block_z, None, interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("dims", "target_parity",
-                                             "interpret", "block_z",
-                                             "out_dtype", "tb_sign",
-                                             "g5"))
-def dslash_eo_pallas_packed_mrhs(u_here_pl: jnp.ndarray,
-                                 u_bw_pl: jnp.ndarray,
-                                 psi_pl: jnp.ndarray, dims,
-                                 target_parity: int,
-                                 interpret: bool = False,
-                                 block_z: int | None = None,
-                                 out_dtype=None,
-                                 tb_sign: bool = True,
-                                 xc: jnp.ndarray | None = None,
-                                 coeff=None,
-                                 g5: bool = False):
-    """Multi-RHS checkerboarded Wilson hop — the batched-solver hot path
+def _eo_mrhs_jit(name: str, combine: bool):
+    """The checkerboarded MRHS hop under a ``jax.jit`` of that name, the
+    callers' own entry (no dispatching frame between them and the
+    jit: the Python stack above a kernel is paid for in its tracing
+    and lowering, PERF.md section 7 (22)).  A kernel event in a profiler
+    capture is named after the jitted function that wraps its
+    ``pallas_call``."""
+    def hop(u_here_pl, u_bw_pl, psi_pl, dims, target_parity,
+            interpret=False, block_z=None, out_dtype=None, tb_sign=True,
+            xc=None, coeff=None, g5=False):
+        if (xc is not None) != combine:
+            raise ValueError(
+                f"{name}: the hop with the combine epilogue (xc, coeff) is "
+                "dslash_eo_pallas_packed_mrhs_combine, the bare hop "
+                "dslash_eo_pallas_packed_mrhs")
+        X = dims[3]
+        if combine:
+            coeff = jnp.asarray(coeff, F32).reshape(1)
+        return _mrhs_hop(u_here_pl, u_bw_pl, psi_pl, X,
+                         (target_parity, X // 2), tb_sign, block_z,
+                         out_dtype, interpret, xc, coeff, g5)
+    hop.__name__ = hop.__qualname__ = name
+    hop.__doc__ = _EO_MRHS_DOC
+    return jax.jit(hop, static_argnames=("dims", "target_parity",
+                                         "interpret", "block_z",
+                                         "out_dtype", "tb_sign", "g5"))
+
+
+_EO_MRHS_DOC = """Multi-RHS checkerboarded Wilson hop — the batched-solver hot path
     (``dslash_eo_pallas_packed`` with a leading RHS axis on psi).
 
     u_here_pl/u_bw_pl as in the single-RHS eo kernel; psi_pl:
@@ -920,20 +933,23 @@ def dslash_eo_pallas_packed_mrhs(u_here_pl: jnp.ndarray,
     (t, z-block) and shared by all N RHS (RHS-innermost grid); the
     route (full-Z tiles or z-blocks) follows the shapes, ``_mrhs_route``.
 
-    With ``xc`` (a batch of parity p, the result's shape) and ``coeff``
-    (a float or an f32 scalar array: an operand either way) the kernel's
-    epilogue writes ``xc + coeff * hop``, and ``g5`` puts gamma5 in
-    front of it: the second hop of the preconditioned operator then
-    hands back ``M x`` (or ``g5 M x``) itself, and no XLA pass over the
-    batch builds it from the bare hop sum.  The result is then the
-    pair (that batch, its (N,) f32 squared norms per source), the
-    norms summed by the same epilogue from what it stores.
+    ``dslash_eo_pallas_packed_mrhs`` is the bare hop sum.
+    ``dslash_eo_pallas_packed_mrhs_combine`` takes ``xc`` (a batch of
+    parity p, the result's shape) and ``coeff`` (a float or an f32
+    scalar array: an operand either way) besides: the kernel's epilogue
+    writes ``xc + coeff * hop``, and ``g5`` puts gamma5 in front of it:
+    the second hop of the preconditioned operator then hands back ``M x``
+    (or ``g5 M x``) itself, and no XLA pass over the batch builds it
+    from the bare hop sum.  Its result is the pair (that batch, its (N,)
+    f32 squared norms per source), the norms summed by the same epilogue
+    from what it stores.  Same body, two names: the two are told apart
+    in a capture, and the needed bytes of one are not charged to the
+    other (the two argument structures traced and lowered apart before).
     """
-    X = dims[3]
-    if xc is not None:
-        coeff = jnp.asarray(coeff, F32).reshape(1)
-    return _mrhs_hop(u_here_pl, u_bw_pl, psi_pl, X, (target_parity, X // 2),
-                     tb_sign, block_z, out_dtype, interpret, xc, coeff, g5)
+dslash_eo_pallas_packed_mrhs = _eo_mrhs_jit(
+    "dslash_eo_pallas_packed_mrhs", combine=False)
+dslash_eo_pallas_packed_mrhs_combine = _eo_mrhs_jit(
+    "dslash_eo_pallas_packed_mrhs_combine", combine=True)
 
 
 # -- hop algebra shared by the kernel bodies ---------------------------------

@@ -100,6 +100,10 @@ def print_summary():
     from .logging import printq
     for prof in _profiles.values():
         printq(prof.summary())
+    from ..obs import build as obuild
+    built = obuild.summary()
+    if built:
+        printq(built)
     save_profiles()
 
 

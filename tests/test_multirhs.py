@@ -739,7 +739,7 @@ def test_mrhs_combine_epilogue_is_xla_on_the_plain_hop(
     u_here, u_bw, psi_b = _eo_mrhs_problem(dims, parity, nrhs, dtype)
     xc = _eo_mrhs_problem(dims, parity, nrhs, dtype, seed=9)[2]
     coeff = -0.12 ** 2
-    got, sums = wpp.dslash_eo_pallas_packed_mrhs(
+    got, sums = wpp.dslash_eo_pallas_packed_mrhs_combine(
         u_here, u_bw, psi_b, dims, parity, interpret=True, xc=xc,
         coeff=coeff, g5=g5, **kw)
     assert sums.shape == (nrhs,) and sums.dtype == jnp.float32

@@ -41,14 +41,24 @@ def _obs_isolation():
 
 # -- span tracer ------------------------------------------------------------
 
-def test_noop_spans_when_off():
-    """Off means off: span() hands back the module singleton (no
-    allocation), event() is a single-global-load early return, and no
-    buffers exist anywhere."""
+@pytest.mark.parametrize("do_not_profile", [False, True])
+def test_spans_buffer_nothing_when_off(monkeypatch, do_not_profile):
+    """With no session open nothing is buffered: event() is a
+    single-global-load early return, and a span is a profiler
+    annotation of its name (tests/test_build_accounting.py) or, under
+    QUDA_TPU_DO_NOT_PROFILE, the module's no-op singleton (no
+    allocation)."""
+    if do_not_profile:
+        monkeypatch.setenv("QUDA_TPU_DO_NOT_PROFILE", "1")
+    else:
+        monkeypatch.delenv("QUDA_TPU_DO_NOT_PROFILE", raising=False)
+    qconf.reset_cache()
     assert not otr.enabled()
-    assert otr.span("a") is otr.span("b", cat="x", k=1) is otr._NOOP
+    if do_not_profile:
+        assert otr.span("a") is otr.span("b", cat="x", k=1) is otr._NOOP
     with otr.span("nested") as s:
-        assert s is otr._NOOP
+        assert (s is otr._NOOP) is do_not_profile
+        s.set(anything="ignored: no session records it")
     otr.event("dropped", value=1)         # must not raise, must not buffer
     assert otr._session is None
 
