@@ -2168,9 +2168,9 @@ def _invert_multi_src_body(sources, param: InvertParam):
             elif sprog.presents(op):
                 # the loop traced once per process: the program applies
                 # what mv_b is, the operator's MdagM_pairs_mrhs, or its
-                # M_pairs_mrhs where it is Hermitian (staggered), and
-                # takes pAp from the operator's own *_dot_pairs_mrhs
-                # where it has one (Wilson: the kernel's |g5 M p|^2)
+                # M_pairs_mrhs where it is Hermitian (staggered), or the
+                # operator's own *_cg_step_pairs_mrhs where it has one
+                # (Wilson: pAp, the new r and |r|^2 out of the kernels)
                 with otr.span("dispatch", cat="solver"):
                     res, hit = sprog.batched_cg_pairs(
                         op, nrm_b, tol=param.tol, maxiter=param.maxiter,

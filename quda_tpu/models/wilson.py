@@ -590,11 +590,13 @@ class _PackedHopMixin:
 
     def _hop_mrhs(self, psi_b, target_parity, out_dtype, **epilogue):
         """The plain pallas MRHS kernel on this operator's links;
-        ``epilogue``: its combine operands (``xc``, ``coeff``, ``g5``);
-        with them the result is ``(batch, its squared norms per
-        source)``."""
+        ``epilogue``: its combine operands (``xc``, ``coeff``, ``g5``),
+        with ``rc`` and ``alpha`` the residual form's; with them the
+        result is ``(batch, its squared norms per source)``."""
         from ..ops import wilson_pallas_packed as wpp
-        hop = (wpp.dslash_eo_pallas_packed_mrhs_combine if epilogue
+        hop = (wpp.dslash_eo_pallas_packed_mrhs_residual
+               if "rc" in epilogue
+               else wpp.dslash_eo_pallas_packed_mrhs_combine if epilogue
                else wpp.dslash_eo_pallas_packed_mrhs)
         return hop(
             self.gauge_eo_pp[target_parity], self._u_bw[target_parity],
@@ -1256,7 +1258,9 @@ class DiracWilsonPCPackedSloppy(_ProgramOperand, _PackedHopMixin,
     # composition, operation for operation; prepare / reconstruct / the
     # verified exit apply bare hops either way.  The same epilogue sums
     # the squares of what it stores, per source: |g5 M x|^2 is the
-    # batched CG's pAp (MdagM_dot_pairs_mrhs; PR 37).
+    # batched CG's pAp (PR 37), and with alpha known from it the last
+    # hop of an iteration writes r - alpha MdagM p and sums the new
+    # |r|^2 (MdagM_cg_step_pairs_mrhs; PR 39).
     def _M_g5_norm2_pairs_mrhs(self, x, g5: bool):
         """``([g5] M x, its squared norms per source)`` on the plain
         kernel route: both from the second hop's epilogue."""
@@ -1282,19 +1286,31 @@ class DiracWilsonPCPackedSloppy(_ProgramOperand, _PackedHopMixin,
         # g5 M g5 M x: each M's second hop applies the g5 in front of it
         return self._M_g5_pairs_mrhs(self._M_g5_pairs_mrhs(x, True), True)
 
-    def MdagM_dot_pairs_mrhs(self, x):
-        """``(MdagM x, x . MdagM x per source)``, what the batched CG
-        applies (solvers/block.batched_cg_pairs_loop).  MdagM is
-        g5 M g5 M, so ``x . MdagM x = |q|^2`` with ``q = g5 M x``, and
-        on the plain kernel route the hop that stores ``q`` sums its
-        squares as it stores them (the combine epilogue): no pass over
-        the batch for the CG's ``pAp``.  Every other route: XLA's dot
-        of ``x`` and ``MdagM_pairs_mrhs(x)``."""
+    def MdagM_cg_step_pairs_mrhs(self, p, r, rz, k=None):
+        """The first half of a batched CG iteration on MdagM, what
+        solvers/block.batched_cg_pairs_loop applies: from the search
+        directions ``p``, the residuals ``r`` and their ``|r|^2``
+        ``rz`` to ``(r - alpha MdagM p, its squared norms per source,
+        alpha, pAp)``.  MdagM is g5 M g5 M, so ``p . MdagM p = |q|^2``
+        with ``q = g5 M p``, which the hop that stores ``q`` sums as it
+        stores (the combine epilogue): ``alpha`` is known before the
+        second ``M``, whose last hop then writes ``r - alpha g5 M q``
+        in ``r``'s place and sums that (the residual form), and
+        ``MdagM p`` is never stored.  No pass over the batch for
+        ``pAp``, the update of ``r`` or ``|r|^2``.  ``k``, the
+        iteration, is the generic step's (an armed fault).  Off the
+        plain kernel route: solvers/block.cg_step of
+        ``MdagM_pairs_mrhs``, XLA's dot, update and sum."""
+        from ..solvers import block
         if not self._plain_mrhs():
-            from ..solvers.block import with_dot
-            return with_dot(self.MdagM_pairs_mrhs)(x)
-        q, q2 = self._M_g5_norm2_pairs_mrhs(x, True)
-        return self._M_g5_pairs_mrhs(q, True), q2
+            return block.cg_step(self.MdagM_pairs_mrhs)(p, r, rz, k)
+        q, pAp = self._M_g5_norm2_pairs_mrhs(p, True)
+        alpha = block.cg_alpha(rz, pAp)
+        tmp = self._hop_mrhs(q, 1 - self.matpc, self.store_dtype)
+        r, r2 = self._hop_mrhs(tmp, self.matpc, self.store_dtype, xc=q,
+                               coeff=-(self.kappa ** 2), g5=True, rc=r,
+                               alpha=alpha)
+        return r, r2, alpha, pAp
 
     # -- multi-RHS boundary helpers (the invert_multi_src_quda route) --
     def prepare_pairs_mrhs(self, b_even_b, b_odd_b):

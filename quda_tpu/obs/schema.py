@@ -229,12 +229,15 @@ METRICS: dict[str, dict] = {
                 "spinor tile read (bt + 2) / bt times, 'zblock' z-blocks "
                 "with two z-neighbour tiles besides (five reads); and by "
                 "epilogue: 'combine' the store writes [g5] (xc + coeff * "
-                "hop) (the second hop of the batched PC operator), 'none' "
-                "the bare hop sum; and by reduce: 'norm2' the epilogue "
-                "also sums the squares of what it stores, per source "
-                "(every combine call; from the first M's second hop it "
-                "is the batched CG's pAp = |g5 M p|^2), 'none' the bare "
-                "hop"},
+                "hop) (the second hop of the batched PC operator), "
+                "'residual' it writes rc - alpha * that, alpha per "
+                "source (the last hop of a batched CG iteration: the "
+                "new r), 'none' the bare hop sum; and by reduce: 'norm2' "
+                "the epilogue also sums the squares of what it stores, "
+                "per source (every combine and residual call; from the "
+                "first M's second hop it is the batched CG's pAp = "
+                "|g5 M p|^2, from the residual hop its new |r|^2), "
+                "'none' the bare hop"},
     "staggered_mrhs_route_total": {
         "type": COUNTER,
         "help": "traced calls of the batched staggered hop "

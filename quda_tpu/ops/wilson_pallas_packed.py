@@ -225,7 +225,7 @@ class _EitherRef:
 def _make_kernel(X: int, bz: int, eo: tuple | None = None,
                  T: int | None = None, tb_sign: bool = True,
                  z_rows: str = "tiles", combine: bool = False,
-                 g5: bool = False):
+                 g5: bool = False, residual: bool = False):
     """Kernel over one (t, z-block) tile.  Ref shapes (leading block dims
     of 1 squeezed by indexing; R = 3 link rows for full storage, 2 for
     reconstruct-12):
@@ -271,15 +271,24 @@ def _make_kernel(X: int, bz: int, eo: tuple | None = None,
     carries it, used or not (48 flop a site on a 1,320-flop body): a
     variant without it is one more Mosaic lowering in every process
     for 0.02 % of a call (PERF.md section 6, PR 37).
+    ``residual`` (with ``combine``) is the epilogue's third form, the
+    batched CG's ``r - alpha A p``: two refs more, ``rc`` (a spinor
+    block like ``xc``, after it) and ``alpha`` (one f32 a source in
+    SMEM, after ``coeff``; the source is the grid's axis 2), and the
+    store writes ``rc - alpha[n] * v``, v the combine form's value
+    before its rounding, so that ``nrm`` sums the new ``|r|^2`` and
+    ``A p`` never reaches HBM (PERF.md section 6, PR 39).
     """
     from jax.experimental import pallas as pl
 
     def kernel(psi_c, psi_tp, psi_tm, psi_zp, psi_zm, g_c, g_m, out_ref,
-               z0=None, t_id=None, xc=None, coeff=None, nrm=None):
+               z0=None, t_id=None, xc=None, coeff=None, nrm=None,
+               rc=None, alpha=None):
         # z0 / t_id: the tile's first z row and its time-slice, where the
         # caller knows them better than the grid does (centre_kernel);
         # xc / coeff: the combine epilogue's operands; nrm: its (BZ, YX)
-        # f32 block of partial sums of squares, zeroed by the caller
+        # f32 block of partial sums of squares, zeroed by the caller;
+        # rc / alpha: the residual form's block and its source's scalar
         if eo is not None:
             parity, Xh = eo
             t_eo = pl.program_id(0) if t_id is None else t_id
@@ -392,6 +401,8 @@ def _make_kernel(X: int, bz: int, eo: tuple | None = None,
                         x = xc[s, c, ri, 0].astype(F32)
                         # -(x + k v) is (-k) v - x to the bit
                         v = nk * v - x if g5 and s >= 2 else x + k * v
+                    if rc is not None:
+                        v = rc[s, c, ri, 0].astype(F32) - alpha * v
                     v = v.astype(odt)
                     out_ref[s, c, ri, 0] = v
                     if nrm is not None:
@@ -401,7 +412,7 @@ def _make_kernel(X: int, bz: int, eo: tuple | None = None,
             nrm[...] += sq
 
     def centre_kernel(psi_c, psi_tp, psi_tm, g_c, g_m, out_ref,
-                      xc=None, coeff=None, nrm=None):
+                      xc=None, coeff=None, nrm=None, rc=None, alpha=None):
         bt, Z = psi_c.shape[-3:-1]
         nzc = Z // bz
         t0 = pl.program_id(0) * bt    # not inside the loop's body
@@ -427,7 +438,8 @@ def _make_kernel(X: int, bz: int, eo: tuple | None = None,
                    at(g_c, i), at(g_m, i), at(out_ref, i),
                    z0=zc * bz, t_id=t0 + i,
                    xc=None if xc is None else at(xc, i), coeff=coeff,
-                   nrm=nrm)
+                   nrm=nrm, rc=None if rc is None else at(rc, i),
+                   alpha=alpha)
             return carry
         if bt * nzc == 1:
             chunk(0, 0)
@@ -446,7 +458,15 @@ def _make_kernel(X: int, bz: int, eo: tuple | None = None,
         nrm[...] = jnp.zeros(nrm.shape, F32)
         body(*psi, g_c, g_m, out_ref, xc=xc, coeff=coeff, nrm=nrm)
 
-    return combine_kernel
+    def residual_kernel(*refs):
+        # psi refs, xc, rc, coeff, alpha, then the links LAST
+        *psi, xc, rc, coeff, alpha, g_c, g_m, out_ref, nrm = refs
+        nrm[...] = jnp.zeros(nrm.shape, F32)
+        # the source's alpha, read outside the body's loop
+        body(*psi, g_c, g_m, out_ref, xc=xc, coeff=coeff, nrm=nrm, rc=rc,
+             alpha=alpha[pl.program_id(2)])
+
+    return residual_kernel if residual else combine_kernel
 
 
 def _sublane_rows(dtype) -> int:
@@ -677,6 +697,30 @@ def _mrhs_wrap(kernel, n_psi: int = 5, n_out: int = 1):
     return wrapped
 
 
+def _big_frame_caller(n_locals: int = 4200):
+    """``call(fn) -> fn()`` from a frame of ``n_locals`` locals.
+
+    CPython (3.11 on) keeps a thread's interpreter frames on a stack of
+    16 KiB chunks and gives a chunk back to the allocator when its
+    first frame returns.  A loop whose calls straddle a chunk boundary
+    maps and unmaps a chunk a call, and tracing a kernel body is such a
+    loop (some 10^4 equations, each bound a dozen frames down): the
+    same trace takes 0.7 s or 1.8 here, 2.2 or 4.9 s on the chip's host
+    (PERF.md section 7 (22)), by where on that stack the caller stands,
+    which any local variable more in any frame above it moves.  A
+    frame too large for what is left of any 16 KiB chunk always opens a
+    chunk of its own (64 KiB for 4,200 locals, 30 of them free below
+    it), so what runs under it stands at the same place whoever
+    calls."""
+    names = " = ".join(f"_{i}" for i in range(n_locals))
+    scope = {}
+    exec(f"def call(fn):\n    {names} = None\n    return fn()\n", scope)
+    return scope["call"]
+
+
+_on_a_stack_chunk_of_its_own = _big_frame_caller()
+
+
 # What the full-Z route may ask of a core's VMEM: three eighths of a
 # v5e core's 128 MiB (Mosaic's scoped default is
 # obs/memory.SCOPED_VMEM_MB = 16).  The call sets ``vmem_limit_bytes``
@@ -686,12 +730,13 @@ _MRHS_FULLZ_VMEM_CAP = 48 * 2 ** 20
 
 
 def _mrhs_fullz_vmem(Z: int, YX: int, dtype, out_dtype, R: int,
-                     bt: int = 1, xc_dtype=None):
+                     bt: int = 1, xc_dtype=None, rc_dtype=None):
     """(block_bytes, need_bytes) of one full-Z MRHS step of ``bt``
     time-slices.  Blocks: bt + 2 psi tiles (24 planes each: the block's
     slices and the one after and before them), bt forward and backward
     link tiles (24 R each), bt out tiles (24) and, with the combine
-    epilogue, bt tiles of its ``xc`` operand (24, of ``xc_dtype``),
+    epilogue, bt tiles of its ``xc`` operand (24, of ``xc_dtype``) and,
+    in its residual form, bt more of ``rc`` (``rc_dtype``),
     every (Z, YX) plane padded to its dtype's (sublane, 128) tile as
     ``_pick_bz`` pads it, and the f32 block of the epilogue's sums of
     squares, one chunk of the body's rows.
@@ -713,6 +758,8 @@ def _mrhs_fullz_vmem(Z: int, YX: int, dtype, out_dtype, R: int,
     if xc_dtype is not None:
         rows = -(-_fullz_chunk(Z, dtype) // 8) * 8
         blocks += bt * 24 * plane(xc_dtype) + rows * yx_pad * 4
+    if rc_dtype is not None:
+        blocks += bt * 24 * plane(rc_dtype)
     return blocks, 2 * blocks + 6 * 24 * plane(F32)
 
 
@@ -727,7 +774,7 @@ def _fullz_chunk(Z: int, dtype) -> int:
 
 
 def _mrhs_route(T: int, Z: int, YX: int, dtype, out_dtype, R: int,
-                block_z: int | None, xc_dtype=None):
+                block_z: int | None, xc_dtype=None, rc_dtype=None):
     """(route, bz, bt, vmem_limit_bytes) of an MRHS call, from its
     shapes.
 
@@ -742,14 +789,16 @@ def _mrhs_route(T: int, Z: int, YX: int, dtype, out_dtype, R: int,
     z-blocks.  ``xc_dtype``: the call has the combine epilogue, whose
     ``xc`` operand is one more spinor block on either route, and its
     f32 block of sums of squares at most two planes of the storage
-    dtype.  Recorded at trace time: the VMEM audit gets the full-Z
-    route's blocks and limit (``_pick_bz`` records the z-block's),
-    ``wilson_mrhs_route_total`` counts the call by route, epilogue and
-    reduce (``norm2``: the epilogue's sums)."""
+    dtype; ``rc_dtype``: the epilogue is the residual form, with its
+    ``rc`` block besides.  Recorded at trace time: the VMEM audit gets
+    the full-Z route's blocks and limit (``_pick_bz`` records the
+    z-block's), ``wilson_mrhs_route_total`` counts the call by route,
+    epilogue (``none``, ``combine``, ``residual``) and reduce
+    (``norm2``: the epilogue's sums)."""
     from ..obs import memory as omem
     from ..obs import metrics as omet
     fits = [(bt,) + _mrhs_fullz_vmem(Z, YX, dtype, out_dtype, R, bt,
-                                     xc_dtype)
+                                     xc_dtype, rc_dtype)
             for bt in (2, 1) if T % bt == 0]
     fits = [f for f in fits if f[2] <= _MRHS_FULLZ_VMEM_CAP]
     if block_z in (None, Z) and fits:
@@ -763,18 +812,20 @@ def _mrhs_route(T: int, Z: int, YX: int, dtype, out_dtype, R: int,
         bz = block_z if block_z is not None else _pick_bz(
             Z, YX, dtype, planes=(288 if R == 3 else 240)
             + (24 + 4 // jnp.dtype(dtype).itemsize
-               if xc_dtype is not None else 0))
+               if xc_dtype is not None else 0)
+            + (24 if rc_dtype is not None else 0))
         if Z % bz != 0:
             raise ValueError(f"block_z={bz} does not divide Z={Z}")
     omet.inc("wilson_mrhs_route_total", route=route,
-             epilogue="none" if xc_dtype is None else "combine",
+             epilogue=("none" if xc_dtype is None else
+                       "combine" if rc_dtype is None else "residual"),
              reduce="none" if xc_dtype is None else "norm2")
     return route, bz, bt, limit
 
 
 def _mrhs_hop(g_c, g_m, psi_pl, X: int, eo, tb_sign: bool,
               block_z, out_dtype, interpret: bool, xc=None, coeff=None,
-              g5: bool = False):
+              g5: bool = False, rc=None, alpha=None):
     """The MRHS pallas_call shared by the full-lattice and the eo
     wrapper: grid (T/bt, Z/bz, N), RHS innermost, links indexed by
     (t, zb) alone.  With ``xc`` (an array of the result's shape) and
@@ -785,30 +836,42 @@ def _mrhs_hop(g_c, g_m, psi_pl, X: int, eo, tb_sign: bool,
     kernel's second output holds one block of f32 partial sums a grid
     step, (T/bt, Z/bz, N, rows, YX), and XLA sums those few KB a
     source to (N,) f32.  This is where the batched CG's ``pAp`` comes
-    from (models/wilson.MdagM_dot_pairs_mrhs)."""
+    from.  With ``rc`` (as ``xc``) and ``alpha`` ((N,) f32, in SMEM)
+    besides, the residual form: it writes ``rc - alpha[n] * [g5] (xc +
+    coeff * hop)`` over ``rc``'s own buffer (each step reads and writes
+    the same centre block of it) and sums that: the batched CG's new
+    ``r`` and ``|r|^2`` (models/wilson.MdagM_cg_step_pairs_mrhs)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     N, _, _, _, T, Z, YX = psi_pl.shape
     R = g_c.shape[1]
     out_dtype = out_dtype or psi_pl.dtype
-    combine = xc is not None
+    combine, residual = xc is not None, rc is not None
     try:
         route, bz, bt, vmem_limit = _mrhs_route(
             T, Z, YX, psi_pl.dtype, out_dtype, R, block_z,
-            xc.dtype if combine else None)
+            xc.dtype if combine else None, rc.dtype if residual else None)
     except ValueError:
         if not combine:
             raise
-        # no route holds the xc block besides the hop's own (a z-block
-        # at _pick_bz's budget): the bare hop, if that fits, and XLA's
-        # passes over the batch, as without the epilogue
-        hop = _mrhs_hop(g_c, g_m, psi_pl, X, eo, tb_sign, block_z, F32,
-                        interpret)
-        v = xc.astype(F32) + coeff[0] * hop
-        if g5:
-            v = v * jnp.asarray([1, 1, -1, -1], F32).reshape(
-                (4,) + (1,) * 5)
+        if residual:
+            # no route holds the xc and the rc block: the combine hop
+            # (or what it falls back to) and XLA's update and sum
+            v, _ = _mrhs_hop(g_c, g_m, psi_pl, X, eo, tb_sign, block_z,
+                             F32, interpret, xc, coeff, g5)
+            v = rc.astype(F32) - alpha.reshape((N,) + (1,) * 6) * v
+        else:
+            # no route holds the xc block besides the hop's own (a
+            # z-block at _pick_bz's budget): the bare hop, if that
+            # fits, and XLA's passes over the batch, as without the
+            # epilogue
+            hop = _mrhs_hop(g_c, g_m, psi_pl, X, eo, tb_sign, block_z,
+                            F32, interpret)
+            v = xc.astype(F32) + coeff[0] * hop
+            if g5:
+                v = v * jnp.asarray([1, 1, -1, -1], F32).reshape(
+                    (4,) + (1,) * 5)
         v = v.astype(out_dtype)
         w = v.astype(F32)
         return v, jnp.sum((w * w).reshape(N, -1), axis=1)
@@ -839,14 +902,15 @@ def _mrhs_hop(g_c, g_m, psi_pl, X: int, eo, tb_sign: bool,
     else:
         body_rows, z_rows = bz, "tiles"
         psi_specs += [psi_slice(0, +1), psi_slice(0, -1)]
-    operands = [psi_pl] * len(psi_specs)
-    rest_specs = [gauge_spec, gauge_spec]
-    if combine:
-        # xc rides the out block's spec; the coefficient precedes the
-        # links (combine_kernel's operand order)
-        psi_specs.append(centre_spec())
-        operands.append(xc)
-        rest_specs.insert(0, pl.BlockSpec(memory_space=pltpu.SMEM))
+    # the epilogue's blocks (xc, rc) ride the out block's spec after
+    # the psi operands; its scalars (the coefficient, alpha) precede
+    # the links, in SMEM (combine_kernel's operand order)
+    blocks = [v for v in (xc, rc) if v is not None]
+    scalars = [k for k in (coeff, alpha) if k is not None]
+    operands = [psi_pl] * len(psi_specs) + blocks
+    psi_specs += [centre_spec() for _ in blocks]
+    rest_specs = ([pl.BlockSpec(memory_space=pltpu.SMEM)] * len(scalars)
+                  + [gauge_spec, gauge_spec])
     out_specs = centre_spec()
     out_shape = jax.ShapeDtypeStruct(psi_pl.shape, out_dtype)
     if combine:
@@ -858,18 +922,28 @@ def _mrhs_hop(g_c, g_m, psi_pl, X: int, eo, tb_sign: bool,
             (T // bt, nzb, N, body_rows, YX), F32)]
     kernel = _mrhs_wrap(
         _make_kernel(X, body_rows, eo=eo, T=T, tb_sign=tb_sign,
-                     z_rows=z_rows, combine=combine, g5=g5),
+                     z_rows=z_rows, combine=combine, g5=g5,
+                     residual=residual),
         n_psi=len(psi_specs), n_out=1 + combine)
+    # the new r takes the old one's buffer where their types agree:
+    # without the alias XLA copies the batch once an iteration to
+    # carry it (call_s 8.07 against 7.43 s, PERF.md section 6, PR 39)
+    aliases = ({len(operands) - 1: 0}
+               if residual and rc.dtype == out_dtype else {})
 
-    out = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=(T // bt, nzb, N),
         in_specs=psi_specs + rest_specs,
         out_specs=out_specs,
         out_shape=out_shape,
+        input_output_aliases=aliases,
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
         interpret=interpret,
-    )(*operands, *([coeff] if combine else []), g_c, g_m)
+    )
+    # the body's trace, wherever on the frame stack this call stands
+    out = _on_a_stack_chunk_of_its_own(
+        lambda: call(*operands, *scalars, g_c, g_m))
     if not combine:
         return out
     return out[0], jnp.sum(out[1], axis=(0, 1, 3, 4))
@@ -897,27 +971,35 @@ def dslash_pallas_packed_mrhs(gauge_pl: jnp.ndarray, psi_pl: jnp.ndarray,
                      block_z, None, interpret)
 
 
-def _eo_mrhs_jit(name: str, combine: bool):
+def _eo_mrhs_jit(name: str, epilogue: str):
     """The checkerboarded MRHS hop under a ``jax.jit`` of that name, the
     callers' own entry (no dispatching frame between them and the
     jit: the Python stack above a kernel is paid for in its tracing
     and lowering, PERF.md section 7 (22)).  A kernel event in a profiler
     capture is named after the jitted function that wraps its
-    ``pallas_call``."""
+    ``pallas_call``.  ``epilogue``: the operands the entry takes,
+    ``"none"``, ``"combine"`` (xc, coeff) or ``"residual"`` (rc, alpha
+    besides)."""
     def hop(u_here_pl, u_bw_pl, psi_pl, dims, target_parity,
             interpret=False, block_z=None, out_dtype=None, tb_sign=True,
-            xc=None, coeff=None, g5=False):
-        if (xc is not None) != combine:
+            xc=None, coeff=None, g5=False, rc=None, alpha=None):
+        given = ("none" if xc is None and rc is None else
+                 "combine" if rc is None else "residual")
+        if given != epilogue:
             raise ValueError(
                 f"{name}: the hop with the combine epilogue (xc, coeff) is "
-                "dslash_eo_pallas_packed_mrhs_combine, the bare hop "
+                "dslash_eo_pallas_packed_mrhs_combine, with the residual "
+                "form (rc, alpha besides) "
+                "dslash_eo_pallas_packed_mrhs_residual, the bare hop "
                 "dslash_eo_pallas_packed_mrhs")
         X = dims[3]
-        if combine:
+        if xc is not None:
             coeff = jnp.asarray(coeff, F32).reshape(1)
+        if rc is not None:
+            alpha = jnp.asarray(alpha, F32).reshape(psi_pl.shape[0])
         return _mrhs_hop(u_here_pl, u_bw_pl, psi_pl, X,
                          (target_parity, X // 2), tb_sign, block_z,
-                         out_dtype, interpret, xc, coeff, g5)
+                         out_dtype, interpret, xc, coeff, g5, rc, alpha)
     hop.__name__ = hop.__qualname__ = name
     hop.__doc__ = _EO_MRHS_DOC
     return jax.jit(hop, static_argnames=("dims", "target_parity",
@@ -942,14 +1024,23 @@ _EO_MRHS_DOC = """Multi-RHS checkerboarded Wilson hop — the batched-solver hot
     (or ``g5 M x``) itself, and no XLA pass over the batch builds it
     from the bare hop sum.  Its result is the pair (that batch, its (N,)
     f32 squared norms per source), the norms summed by the same epilogue
-    from what it stores.  Same body, two names: the two are told apart
-    in a capture, and the needed bytes of one are not charged to the
-    other (the two argument structures traced and lowered apart before).
+    from what it stores.
+    ``dslash_eo_pallas_packed_mrhs_residual`` takes ``rc`` (a batch as
+    ``xc``) and ``alpha`` ((N,) f32, one a source) besides those: the
+    epilogue writes ``rc - alpha * [g5] (xc + coeff * hop)`` and sums
+    the squares of that.  With ``xc = q = g5 M p``, ``rc = r`` and
+    ``coeff = -kappa^2`` it is the last hop of a batched CG iteration on
+    ``MdagM = g5 M g5 M`` and hands back the new ``r`` and ``|r|^2``;
+    ``A p`` is never stored.  Same body, three names: they are told
+    apart in a capture, and the needed bytes of one are not charged to
+    another (the argument structures traced and lowered apart before).
     """
 dslash_eo_pallas_packed_mrhs = _eo_mrhs_jit(
-    "dslash_eo_pallas_packed_mrhs", combine=False)
+    "dslash_eo_pallas_packed_mrhs", "none")
 dslash_eo_pallas_packed_mrhs_combine = _eo_mrhs_jit(
-    "dslash_eo_pallas_packed_mrhs_combine", combine=True)
+    "dslash_eo_pallas_packed_mrhs_combine", "combine")
+dslash_eo_pallas_packed_mrhs_residual = _eo_mrhs_jit(
+    "dslash_eo_pallas_packed_mrhs_residual", "residual")
 
 
 # -- hop algebra shared by the kernel bodies ---------------------------------

@@ -153,16 +153,35 @@ def _wide_dot(u: jnp.ndarray, v: jnp.ndarray) -> jnp.ndarray:
     return _per_rhs_dot(u.astype(rdt), v.astype(rdt))
 
 
-def with_dot(matvec_batch: Callable) -> Callable:
+def cg_alpha(rz: jnp.ndarray, pAp: jnp.ndarray) -> jnp.ndarray:
+    """The batched CG's per-source step length ``|r|^2 / p . A p``, the
+    pivot clamped away from zero: the one definition, for ``cg_step``
+    and for an operator's own step."""
+    return rz / jnp.maximum(pAp, jnp.finfo(pAp.dtype).tiny)
+
+
+def cg_step(matvec_batch: Callable, fault_k: Optional[int] = None
+            ) -> Callable:
     """Lift a batched matvec ``A`` to what ``batched_cg_pairs_loop``
-    applies, ``x -> (A x, per-source x . A x)``: XLA's dot over the
-    batch, operation for operation the ``pAp`` the loop took itself
-    before an operator could hand it over.  For every operator that has
-    nothing better (models/wilson.MdagM_dot_pairs_mrhs has)."""
-    def apply(x):
-        Ax = matvec_batch(x)
-        return Ax, _wide_dot(x, Ax)
-    return apply
+    applies, the first half of an iteration: ``(p, r, rz, k) ->
+    (r - alpha A p, its squared norms per source, alpha, p . A p)``
+    with XLA's dot, update and sum over the batch, operation for
+    operation what the loop did itself before an operator could hand
+    them over.  For every operator that has nothing better
+    (models/wilson.MdagM_cg_step_pairs_mrhs has).  ``fault_k``: the
+    armed dslash fault iteration, which corrupts ``A p`` at iteration
+    ``k`` before anything reads it."""
+    from ..robust import faultinject as finj
+
+    def step(p, r, rz, k):
+        Ap = matvec_batch(p)
+        if fault_k is not None:
+            Ap = finj.corrupt(Ap, k, fault_k)
+        pAp = _wide_dot(p, Ap)
+        alpha = cg_alpha(rz, pAp)
+        r = r - _bcast(alpha, r).astype(r.dtype) * Ap
+        return r, _wide_dot(r, r), alpha, pAp
+    return step
 
 
 def _bcast(s: jnp.ndarray, like: jnp.ndarray) -> jnp.ndarray:
@@ -191,30 +210,31 @@ def batched_cg_pairs(matvec_batch: Callable, B: jnp.ndarray,
     from ..robust import sentinel as rsent
     from .fused_iter import _resolve_check_every
     return batched_cg_pairs_loop(
-        with_dot(matvec_batch), B, tol, maxiter,
-        _resolve_check_every(check_every), record, rsent.make(),
-        finj.iteration_fault("dslash"))
+        cg_step(matvec_batch, finj.iteration_fault("dslash")), B, tol,
+        maxiter, _resolve_check_every(check_every), record, rsent.make())
 
 
-def batched_cg_pairs_loop(apply_batch: Callable, B: jnp.ndarray, tol,
-                          maxiter, check_every: int, record: bool, sent,
-                          fault_k: Optional[int]) -> BatchedCGResult:
+def batched_cg_pairs_loop(step: Callable, B: jnp.ndarray, tol, maxiter,
+                          check_every: int, record: bool, sent
+                          ) -> BatchedCGResult:
     """``batched_cg_pairs`` with every knob already resolved by the
-    caller (the cadence, ``sent``: robust/sentinel.Sentinel or None,
-    ``fault_k``: the armed dslash fault iteration or None), so nothing
-    here reads host state: the body the cached solve program
+    caller (the cadence, ``sent``: robust/sentinel.Sentinel or None),
+    so nothing here reads host state: the body the cached solve program
     (solvers/program.py) traces once per key.  ``tol`` and ``maxiter``
     may be traced scalars, except that ``record`` sizes the history by
     a concrete ``maxiter``.
 
-    ``apply_batch`` is ``p -> (A p, per-source p . A p)``: the loop
-    makes no pass over the batch for ``pAp``.  It comes from the
-    operator where that has it for nothing (the Wilson pair operator's
-    ``MdagM_dot_pairs_mrhs``: ``|g5 M p|^2``, summed by the kernel
-    that stores ``g5 M p``) and from ``with_dot`` everywhere else.
-    With a dslash fault armed the dot is taken here, from the corrupted
-    ``Ap``, so a fault reaches ``alpha`` and the sentinel's pivot."""
-    from ..robust import faultinject as finj
+    ``step`` is the first half of an iteration, ``(p, r, rz, k) ->
+    (r - alpha A p, its squared norms per source, alpha, p . A p)``
+    (``k``: the iteration): the loop makes no pass over the batch for
+    ``pAp``, for ``r`` or for ``|r|^2``, and keeps ``x += alpha p``,
+    ``beta`` and ``p = r + beta p``.  It comes from the operator where
+    that has the whole of it for one more tile read (the Wilson pair
+    operator's ``MdagM_cg_step_pairs_mrhs``: ``pAp = |g5 M p|^2`` out
+    of the first ``M``, so the last hop's epilogue writes the new ``r``
+    and sums it) and from ``cg_step`` of the batched matvec everywhere
+    else, which is also where an armed dslash fault corrupts ``A p``:
+    it reaches ``alpha``, ``r`` and the sentinel's pivot."""
     from ..robust import sentinel as rsent
     n = B.shape[0]
     _check_nrhs(n)
@@ -234,15 +254,8 @@ def batched_cg_pairs_loop(apply_batch: Callable, B: jnp.ndarray, tol,
     rz = b2
 
     def one_iter(x, r, p, rz, k):
-        Ap, pAp = apply_batch(p)
-        if fault_k is not None:
-            Ap = finj.corrupt(Ap, k, fault_k)
-            pAp = _wide_dot(p, Ap)
-        alpha = rz / jnp.maximum(pAp, tiny)
-        a = _bcast(alpha, x).astype(x.dtype)
-        x = x + a * p
-        r = r - a * Ap
-        r2 = _wide_dot(r, r)
+        r, r2, alpha, pAp = step(p, r, rz, k)
+        x = x + _bcast(alpha, x).astype(x.dtype) * p
         beta = r2 / jnp.maximum(rz, tiny)
         p = r + _bcast(beta, p).astype(p.dtype) * p
         return x, r, p, r2, pAp

@@ -38,14 +38,20 @@ equations of a non-Hermitian PC operator (``MdagM_pairs``: Wilson,
 clover) and applies a ``hermitian`` one once an iteration (``M_pairs``:
 the staggered PC operator is already 4m^2 - D D); the batched program
 does the same on ``MdagM_pairs_mrhs`` / ``M_pairs_mrhs``.  Its loop
-takes ``(A p, p . A p)`` from one callable: the operator's own
-``MdagM_dot_pairs_mrhs`` / ``M_dot_pairs_mrhs`` where its class has one
-(the Wilson pair operator: ``pAp`` is ``|g5 M p|^2``, summed in the
-epilogue of the kernel that stores ``g5 M p``), else
-``block.with_dot`` of the matvec (XLA's dot over the batch).  Which of
-the two is the operand's class and static signature, so the key has no
-field for it.  With a leading source axis ``verified_exit`` and
-``prepare`` are the batched route's.
+takes the first half of an iteration from one callable, ``(p, r, rz,
+k) -> (r - alpha A p, its squared norms, alpha, p . A p)``, and keeps
+the updates of ``x`` and ``p``: the operator's own
+``MdagM_cg_step_pairs_mrhs`` / ``M_cg_step_pairs_mrhs`` where its class
+has one (the Wilson pair operator: ``pAp`` is ``|g5 M p|^2``, summed in
+the epilogue of the kernel that stores ``g5 M p``, so ``alpha`` is
+known before the last hop, whose epilogue writes ``r - alpha A p`` in
+``r``'s place and sums it: ``A p`` is never stored), else
+``block.cg_step`` of the matvec (XLA's dot, update and sum over the
+batch).  With a dslash fault armed it is ``block.cg_step`` whatever the
+operator offers: the fault corrupts ``A p``, which only that step has.
+Which of the two is the operand's class and static signature and
+``knobs.fault_k``, so the key has no field for it.  With a leading
+source axis ``verified_exit`` and ``prepare`` are the batched route's.
 """
 
 from __future__ import annotations
@@ -120,20 +126,22 @@ def _batched_cg_pairs_program(op, B, tol, maxiter, key):
     _traces[0] += 1
     check_every, knobs, hermitian = key
     mv = "M" if hermitian else "MdagM"
-    apply_batch = (getattr(op, mv + "_dot_pairs_mrhs", None)
-                   or block.with_dot(getattr(op, mv + "_pairs_mrhs")))
+    step = getattr(op, mv + "_cg_step_pairs_mrhs", None)
+    if step is None or knobs.fault_k is not None:
+        step = block.cg_step(getattr(op, mv + "_pairs_mrhs"),
+                             knobs.fault_k)
     return block.batched_cg_pairs_loop(
-        apply_batch, B, tol, knobs.maxiter if knobs.record else maxiter,
-        check_every, knobs.record, knobs.sentinel, knobs.fault_k)
+        step, B, tol, knobs.maxiter if knobs.record else maxiter,
+        check_every, knobs.record, knobs.sentinel)
 
 
 def batched_cg_pairs(op, B, tol: float, maxiter: int,
                      record: bool = False):
     """``block.batched_cg_pairs`` on ``op.MdagM_pairs_mrhs`` through
     the cached program; on ``M_pairs_mrhs``, once an iteration, where
-    the operator says it is ``hermitian``; with ``pAp`` from the
-    operator where it has a ``*_dot_pairs_mrhs``.  Returns
-    ``(BatchedCGResult, hit)``."""
+    the operator says it is ``hermitian``; with the operator's own
+    ``*_cg_step_pairs_mrhs`` where it has one and no dslash fault is
+    armed.  Returns ``(BatchedCGResult, hit)``."""
     from .fused_iter import _resolve_check_every
     key = (_resolve_check_every(None), _loop_knobs(record, maxiter),
            bool(getattr(op, "hermitian", False)))

@@ -138,7 +138,7 @@ def pytest_collection_modifyitems(config, items):
 # of 50 s and more.  It only orders the hand-out: a stale or missing
 # entry costs balance and nothing else.
 FILE_SECONDS = {
-    "test_solve_program.py": 760, "test_multirhs.py": 660,
+    "test_solve_program.py": 770, "test_multirhs.py": 810,
     "test_staggered_pallas.py": 380, "test_pallas.py": 380,
     "test_pair_mg.py": 300, "test_precision_forms.py": 290,
     "test_domain_wall.py": 240, "test_clover_resident.py": 240,

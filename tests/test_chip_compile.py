@@ -61,7 +61,8 @@ def _eo(dt, rows=3):
             [_links(dt, rows), _links(dt, rows), _psi(dt)])
 
 
-def _eo_mrhs(n, dt=F32, block_z=None, combine=False, lat=L, bt=2):
+def _eo_mrhs(n, dt=F32, block_z=None, combine=False, lat=L, bt=2,
+             residual=False):
     """The MRHS kernel as the shapes route it: full-Z tiles at 24^4
     (three psi operands, two time-slices a step, its own
     ``vmem_limit_bytes``; 24 rows of bf16 pad to 32 sublanes, so that
@@ -71,19 +72,28 @@ def _eo_mrhs(n, dt=F32, block_z=None, combine=False, lat=L, bt=2):
     operator: one more spinor block, the coefficient in SMEM, gamma5 in
     registers, and the per-source sums of squares of what it stores,
     the batched CG's ``pAp``: a second, small f32 output block counted
-    in the route's VMEM sum), on each of those routes."""
+    in the route's VMEM sum), on each of those routes.  ``residual``:
+    that epilogue's residual form (the last hop of a batched CG
+    iteration: the ``rc`` block and one ``alpha`` a source in SMEM
+    besides, the result over ``rc``'s buffer)."""
     from quda_tpu.ops import wilson_pallas_packed as wpp
     dims, yxh = (lat,) * 4, lat * lat // 2
     want = ("zblock", block_z, 1) if block_z else ("fullz", lat, bt)
-    route = wpp._mrhs_route(lat, lat, yxh, dt, dt, 3, block_z,
-                            dt if combine else None)
+    epilogue = (dt if combine or residual else None,
+                dt if residual else None)
+    route = wpp._mrhs_route(lat, lat, yxh, dt, dt, 3, block_z, *epilogue)
     assert route[:3] == want
     if not block_z:
-        assert wpp._mrhs_fullz_vmem(
-            lat, yxh, dt, dt, 3, bt, dt if combine else None
-        )[1] <= route[3] <= wpp._MRHS_FULLZ_VMEM_CAP
+        assert wpp._mrhs_fullz_vmem(lat, yxh, dt, dt, 3, bt, *epilogue
+                                    )[1] <= route[3] <= wpp._MRHS_FULLZ_VMEM_CAP
     links = ((4, 3, 3, 2, lat, lat, yxh), dt)
     psi = ((n, 4, 3, 2, lat, lat, yxh), dt)
+    if residual:
+        return (lambda u, ub, p, xc, k, rc, a:
+                wpp.dslash_eo_pallas_packed_mrhs_residual(
+                    u, ub, p, dims, 0, block_z=block_z, xc=xc, coeff=k,
+                    g5=True, rc=rc, alpha=a),
+                [links, links, psi, psi, ((), F32), psi, ((n,), F32)])
     if not combine:
         return (lambda u, ub, p: wpp.dslash_eo_pallas_packed_mrhs(
                     u, ub, p, dims, 0, block_z=block_z),
@@ -169,6 +179,11 @@ CASES = {
         8, combine=True, lat=32, bt=1),
     "wilson_eo_mrhs_n8_combine_zblock": lambda: _eo_mrhs(
         8, block_z=8, combine=True),
+    "wilson_eo_mrhs_n8_residual": lambda: _eo_mrhs(8, residual=True),
+    "wilson_eo_mrhs_n8_residual_32": lambda: _eo_mrhs(
+        8, residual=True, lat=32, bt=1),
+    "wilson_eo_mrhs_n8_residual_zblock": lambda: _eo_mrhs(
+        8, block_z=8, residual=True),
     "cg_update_norm2_f32": lambda: _cg_update(F32),
     "cg_update_norm2_bf16": lambda: _cg_update(BF16),
     "axpy_norm2_f32": _axpy_norm2,
@@ -429,13 +444,15 @@ def test_verified_exit_program_compiles_for_v5e(one_chip, n_src):
 def test_batched_solve_program_compiles_for_v5e_combining_in_the_kernel(
         one_chip):
     """The eight-source solve program (solvers/program.py over
-    ``block.batched_cg_pairs_loop``) at 24^4: the loop's ``MdagM`` is
-    four MRHS kernels, the second and the fourth with the combine
-    epilogue (seven operands: three spinor blocks, ``xc``, the
-    coefficient, the links; two results, the second the per-source
-    sums of squares that are the loop's ``pAp``), so what XLA is left
-    with is the solver's own updates; kappa and the links are
-    parameters."""
+    ``block.batched_cg_pairs_loop``) at 24^4: an iteration is four
+    MRHS kernels, the second with the combine epilogue (seven
+    operands: three spinor blocks, ``xc``, the coefficient, the links;
+    two results, the second the per-source sums of squares that are
+    the loop's ``pAp``) and the fourth with its residual form (nine:
+    ``rc`` and the per-source ``alpha`` besides; its first result the
+    new ``r`` in the old one's buffer, its second the new ``|r|^2``),
+    so what XLA is left with is the update of ``x`` and ``p``, one
+    fusion; kappa and the links are parameters."""
     import re
     from quda_tpu.fields.geometry import LatticeGeometry
     from quda_tpu.models.wilson import DiracWilsonPCPackedSloppy
@@ -459,16 +476,24 @@ def test_batched_solve_program_compiles_for_v5e_combining_in_the_kernel(
         return sprog._batched_cg_pairs_program.lower(op, b, 1e-6, 10000,
                                                      key=key)
     hlo = _aot(lower).as_text()
-    calls = re.findall(r"%dslash_eo_pallas_packed_mrhs(_combine)?[.\d]* = "
-                       r"(\(?)f32\[[^\n]*custom-call\(([^\n]*?)\), "
+    calls = re.findall(r"%dslash_eo_pallas_packed_mrhs(_combine|_residual)?"
+                       r"[.\d]* = (\(?)f32\[[^\n]*custom-call\(([^\n]*?)\), "
                        r"custom_call_target=\"tpu_custom_call\"", hlo)
     # a fused hop has a second, small result, the sums of squares of
-    # what it stores: the first M's are the loop's pAp = |g5 M p|^2;
-    # it runs under a kernel name of its own, which the benchmark's
-    # patterns tell from the bare hop's
+    # what it stores: the first M's are the loop's pAp = |g5 M p|^2,
+    # the last hop's the new |r|^2; each form runs under a kernel name
+    # of its own, which the benchmark's patterns tell apart
     assert sorted((n, t, c.count("%")) for n, t, c in calls) == [
         ("", "", 5), ("", "", 5), ("_combine", "(", 7),
-        ("_combine", "(", 7)], calls
+        ("_residual", "(", 9)], calls
+    # the r update and its |r|^2 are the kernel's: the one XLA fusion
+    # over the batch left in the loop is the update of x and p
+    body = hlo[hlo.index("dslash_eo_pallas_packed_mrhs_residual"):]
+    body = body[:body.index("ROOT")]
+    batch = "f32[" + ",".join(str(d) for d in _psi(F32, (8,))[0]) + "]"
+    fused = re.findall(r"(%[\w.]+) = \(?" + re.escape(batch)
+                       + r"[^\n]* fusion\(", body)
+    assert len(fused) == 1, fused
     links = ",".join(str(d) for d in _links(F32)[0])
     assert sum(p[1:] == ("f32", links)
                for p in _hlo_values(hlo, "parameter")) == 4

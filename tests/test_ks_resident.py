@@ -367,6 +367,9 @@ def test_batch_builds_once_then_reuses_and_hits(quda):
     assert _delta(t0, _counts("ks_term_total", ("outcome",))) == {
         ("built",): 1}
     assert _delta(p0, progs()) == {key(s, "miss"): 1 for s in three}
+    # the batched program took the generic CG step (block.cg_step): no
+    # Wilson MRHS kernel, in any epilogue form, was traced for it
+    assert _counts("wilson_mrhs_route_total", ("epilogue",)) == {}
     term = api._ctx["ks"]
     # the second call, another mass and other sources
     t1, p1, n1 = _counts("ks_term_total", ("outcome",)), progs(), \

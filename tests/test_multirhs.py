@@ -9,6 +9,8 @@ test_fused_iter.py); tier-1 covers the MRHS math through the vmap-
 fallback operator forms and the solver/API tests, which are exact against
 the same composition."""
 
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -162,27 +164,37 @@ def test_mrhs_operator_on_the_pallas_route_combines_in_the_second_hop(
     # with the epilogue (Mdag's are MdagM's own, not traced again)
     assert route_counts("epilogue") == {"none": 1.0, "combine": 1.0}
     assert route_counts("reduce") == {"none": 1.0, "norm2": 1.0}
-    # what the batched CG applies: the same MdagM x, and x . MdagM x as
-    # |g5 M x|^2 summed by the epilogue that stores g5 M x (the same
-    # two kernels: nothing more is traced)
-    from quda_tpu.solvers.block import _per_rhs_dot
-    ax, dot = op.MdagM_dot_pairs_mrhs(x)
-    assert bool(jnp.all(ax == op.MdagM_pairs_mrhs(x)))
-    want = _per_rhs_dot(x.astype(jnp.float32), ax.astype(jnp.float32))
-    assert dot.shape == (NRHS,) and dot.dtype == jnp.float32
+    # what the batched CG applies, the first half of an iteration: pAp
+    # as |g5 M p|^2 summed by the epilogue that stores g5 M p, then
+    # r - alpha MdagM p and its squares out of the last hop's epilogue
+    # (one more kernel traced: the residual form).  Against XLA's dot,
+    # update and sum on the XLA stencil, with another alpha per source
+    from quda_tpu.solvers.block import cg_step
+    r = pair_problem[3].astype(store)
+    rz = jnp.asarray([0.7, 1.9, 4.3], jnp.float32)
+    got = op.MdagM_cg_step_pairs_mrhs(x, r, rz)
+    want = cg_step(xla.MdagM_pairs_mrhs)(x, r, rz, 0)
+    assert [(v.shape, v.dtype) for v in got] == [
+        (x.shape, store)] + [((NRHS,), jnp.float32)] * 3
     # in bf16 storage q is rounded before its squares are summed and
-    # before the second M reads it: <q, q_r> against <q_r, q_r>
-    np.testing.assert_allclose(
-        np.asarray(dot), np.asarray(want),
-        rtol=1e-6 if store == jnp.float32 else 1e-2)
-    assert route_counts() == {"fullz": 2.0}
-    # off the kernel route the same method is XLA's dot of the lifted
-    # composition
-    ax, dot = xla.MdagM_dot_pairs_mrhs(x)
-    assert bool(jnp.all(ax == xla.MdagM_pairs_mrhs(x)))
-    assert bool(jnp.all(dot == _per_rhs_dot(
-        x.astype(jnp.float32), ax.astype(jnp.float32))))
-    assert route_counts() == {"fullz": 2.0}
+    # before the second M reads it, and A p is not rounded at all
+    # before r takes it
+    rtol = 1e-5 if store == jnp.float32 else 2e-2
+    for g, w in zip(got[1:], want[1:]):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=rtol)
+    assert len({float(a) for a in got[2]}) == NRHS
+    w = want[0].astype(jnp.float32)
+    assert float(jnp.max(jnp.abs(got[0].astype(jnp.float32) - w))) \
+        <= (tol if store == jnp.float32 else 2e-2) \
+        * float(jnp.max(jnp.abs(w)))
+    assert route_counts("epilogue") == {"none": 1.0, "combine": 1.0,
+                                        "residual": 1.0}
+    assert route_counts("reduce") == {"none": 1.0, "norm2": 2.0}
+    assert route_counts() == {"fullz": 3.0}
+    # off the kernel route the same method is that generic step
+    for g, w in zip(xla.MdagM_cg_step_pairs_mrhs(x, r, rz, 0), want):
+        assert bool(jnp.all(g == w))
+    assert route_counts() == {"fullz": 3.0}
 
 
 BATCH_TOL = 1e-7
@@ -221,30 +233,89 @@ def test_batched_cg_pairs_matches_single_trajectory(pair_problem,
     assert abs(int(res.iters[0]) - int(single.iters)) <= 1
 
 
-@pytest.mark.parametrize("fault_k", [None, 10 ** 6])
-def test_batched_cg_pairs_loop_takes_pAp_from_its_operator(fault_k):
-    """The loop applies ``p -> (A p, per-source p . A p)`` and makes no
-    dot of its own: with ``with_dot`` it is the solver it was, and an
-    operator that hands over NaN for ``pAp`` breaks the solve.  With a
-    dslash fault armed (here at an iteration never reached) the dot is
-    the loop's own again, taken from the ``Ap`` the fault corrupts: the
-    operator's is ignored."""
-    from quda_tpu.solvers.block import batched_cg_pairs_loop, with_dot
+def test_batched_cg_pairs_loop_on_the_operators_own_step(
+        pair_dpk, pair_problem, batched_solution):
+    """The loop on the kernel route's own step (``pAp``, the new ``r``
+    and ``|r|^2`` out of the hops' epilogues, ``A p`` never stored)
+    against the loop on the generic step of the XLA stencil operator
+    (``batched_solution``): the same iterations to a source, the same
+    solution to the solver's tolerance."""
+    from quda_tpu.solvers.block import batched_cg_pairs_loop
+    op = pair_dpk.pairs(jnp.float32, use_pallas=True,
+                        pallas_interpret=True)
+    nrm_b = pair_problem[4]
+    got = jax.jit(lambda b: batched_cg_pairs_loop(
+        op.MdagM_cg_step_pairs_mrhs, b, BATCH_TOL, 800, 1, False, None)
+    )(nrm_b)
+    want = batched_solution
+    assert bool(jnp.all(got.converged))
+    assert np.all(np.abs(np.asarray(got.iters)
+                         - np.asarray(want.iters)) <= 1)
+    for i in range(NRHS):
+        assert float(jnp.sqrt(blas.norm2(got.x[i] - want.x[i])
+                              / blas.norm2(want.x[i]))) < 10 * BATCH_TOL
+        rel = float(jnp.sqrt(
+            blas.norm2(nrm_b[i] - pair_problem[0].MdagM_pairs(got.x[i]))
+            / blas.norm2(nrm_b[i])))
+        assert rel < 5 * BATCH_TOL, (i, rel)
+
+
+def _diagonal_batch():
     rng = np.random.default_rng(6)
     n, dim = 3, 128
     d = jnp.stack([jnp.linspace(1.0, 2.0 + i, dim).astype(jnp.float32)
                    for i in range(n)])
-    B = jnp.asarray(rng.standard_normal((n, dim)), jnp.float32)
-    mv = lambda V: d * V
+    return d, jnp.asarray(rng.standard_normal((n, dim)), jnp.float32)
 
-    def solve(apply_batch):
-        return batched_cg_pairs_loop(apply_batch, B, 1e-6, 200, 1, False,
-                                     None, fault_k)
-    want = solve(with_dot(mv))
+
+@jax.tree_util.register_pytree_node_class
+class _Diagonal:
+    """A batch of diagonal operators as a solve program's operand."""
+    program_signature = ("diagonal",)
+
+    def __init__(self, d):
+        self.d = d
+
+    def tree_flatten(self):
+        return (self.d,), None
+
+    @classmethod
+    def tree_unflatten(cls, _, leaves):
+        return cls(*leaves)
+
+    def MdagM_pairs_mrhs(self, V):
+        return self.d * V
+
+
+@jax.tree_util.register_pytree_node_class
+class _DiagonalNaNStep(_Diagonal):
+    """The same with a CG step of its own, which hands back NaN."""
+
+    def MdagM_cg_step_pairs_mrhs(self, p, r, rz, k=None):
+        return (r, rz) + (jnp.full_like(rz, jnp.nan),) * 2
+
+
+@pytest.mark.parametrize("fault_k", [None, 10 ** 6])
+def test_batched_cg_pairs_loop_takes_its_step_from_its_operator(fault_k):
+    """The loop applies ``(p, r, rz, k) -> (new r, its squares, alpha,
+    pAp)`` and makes no pass of its own for them: with ``cg_step`` it is
+    the solver it was, and an operator whose own step hands back NaN
+    breaks the solve.  With a dslash fault armed (here at an iteration
+    never reached) the program takes ``cg_step`` of the operator's
+    matvec, whose ``A p`` the fault corrupts: the operator's step is
+    ignored, bit for bit."""
+    from quda_tpu.solvers import program as sprog
+    d, B = _diagonal_batch()
+    n = B.shape[0]
+
+    def solve(op):
+        key = (1, sprog._LoopKnobs(False, None, None, fault_k), False)
+        return sprog._batched_cg_pairs_program(op, B, 1e-6, 200, key=key)
+    want = solve(_Diagonal(d))
     assert bool(jnp.all(want.converged))
     np.testing.assert_allclose(np.asarray(want.x), np.asarray(B / d),
                                rtol=1e-4, atol=1e-5)
-    got = solve(lambda V: (mv(V), jnp.full((n,), jnp.nan, jnp.float32)))
+    got = solve(_DiagonalNaNStep(d))
     if fault_k is None:
         assert not bool(jnp.any(got.converged))
         assert not bool(jnp.any(jnp.isfinite(got.x)))
@@ -254,6 +325,30 @@ def test_batched_cg_pairs_loop_takes_pAp_from_its_operator(fault_k):
                                       np.asarray(want.iters))
         np.testing.assert_array_equal(np.asarray(got.x),
                                       np.asarray(want.x))
+
+
+def test_generic_cg_step_solves_the_diagonal_batch_as_the_parent_did():
+    """``cg_step`` is operation for operation what the loop did itself
+    before PR 39 (dot, clamp, update, sum): the solve of the synthetic
+    diagonal batch is the parent's to the bit (values pinned from the
+    parent commit's ``batched_cg_pairs_loop(with_dot(mv), ...)`` on this
+    CPU backend)."""
+    import hashlib
+    from quda_tpu.solvers.block import batched_cg_pairs_loop, cg_step
+    d, B = _diagonal_batch()
+    res = batched_cg_pairs_loop(cg_step(lambda V: d * V), B, 1e-6, 200, 1,
+                                False, None)
+    np.testing.assert_array_equal(np.asarray(res.iters), [8, 11, 13])
+    np.testing.assert_array_equal(
+        np.asarray(res.r2).view(np.uint32),
+        [574408242, 711960263, 774210360])
+    x = np.asarray(res.x)
+    np.testing.assert_array_equal(x[:, ::31].view(np.uint32), [
+        [1065798783, 1043042583, 1053140022, 1062365434, 1059093241],
+        [3205742788, 3148764080, 980381189, 1048709162, 1044638461],
+        [3207082431, 1055123836, 1065060131, 1043839724, 3172677981]])
+    assert hashlib.sha256(x.tobytes()).hexdigest() == (
+        "af655ae0df771b7b005dbe320d6b8a03a9ae0f24d67592b86b8d05c14ce5eddc")
 
 
 def test_batched_cg_pairs_check_cadence():
@@ -701,6 +796,22 @@ _COMBINE_ROUTES = {"fullz2": (None, "fullz", "combine"),
                    "xla": (_no_room_for_xc, "zblock", "none")}
 
 
+def _epilogue_case(route, parity, dtype, monkeypatch):
+    """One of ``_COMBINE_ROUTES`` bent into place and a problem on it:
+    (dims, nrhs, the call's keywords, links, backward links, psi, xc,
+    the route counted, the epilogue counted)."""
+    from quda_tpu.ops import wilson_pallas_packed as wpp
+    bend, counted, epilogue = _COMBINE_ROUTES[route]
+    dims, nrhs = _fz_dims(dtype), 2 if bend is None else 3
+    kw = ({"block_z": wpp._sublane_rows(dtype)} if route == "zblock"
+          else {})
+    if bend is not None:
+        bend(monkeypatch, wpp, dims, dtype)
+    u_here, u_bw, psi_b = _eo_mrhs_problem(dims, parity, nrhs, dtype)
+    xc = _eo_mrhs_problem(dims, parity, nrhs, dtype, seed=9)[2]
+    return dims, nrhs, kw, u_here, u_bw, psi_b, xc, counted, epilogue
+
+
 @pytest.mark.parametrize("parity,g5,route,dtype", [
     (0, True, "fullz2", jnp.float32),
     pytest.param(1, False, "zblock", jnp.float32, marks=pytest.mark.slow),
@@ -730,14 +841,8 @@ def test_mrhs_combine_epilogue_is_xla_on_the_plain_hop(
     own partial sums, XLA's on the fallback), counted with
     ``reduce="norm2"``."""
     from quda_tpu.ops import wilson_pallas_packed as wpp
-    bend, counted, epilogue = _COMBINE_ROUTES[route]
-    dims, nrhs = _fz_dims(dtype), 2 if bend is None else 3
-    kw = ({"block_z": wpp._sublane_rows(dtype)} if route == "zblock"
-          else {})
-    if bend is not None:
-        bend(monkeypatch, wpp, dims, dtype)
-    u_here, u_bw, psi_b = _eo_mrhs_problem(dims, parity, nrhs, dtype)
-    xc = _eo_mrhs_problem(dims, parity, nrhs, dtype, seed=9)[2]
+    (dims, nrhs, kw, u_here, u_bw, psi_b, xc, counted,
+     epilogue) = _epilogue_case(route, parity, dtype, monkeypatch)
     coeff = -0.12 ** 2
     got, sums = wpp.dslash_eo_pallas_packed_mrhs_combine(
         u_here, u_bw, psi_b, dims, parity, interpret=True, xc=xc,
@@ -771,9 +876,78 @@ def test_mrhs_combine_epilogue_is_xla_on_the_plain_hop(
         else {"none": 1.0, "norm2": 1.0})
 
 
+def test_mrhs_kernel_is_traced_from_a_frame_too_large_for_a_chunk():
+    """The MRHS ``pallas_call`` is made from a frame that no 16 KiB
+    chunk of CPython's frame stack has room left for, so that the
+    kernel body's trace does not straddle a chunk boundary by the luck
+    of its caller's depth (PERF.md section 7 (22)); it hands through
+    what it calls."""
+    from quda_tpu.ops import wilson_pallas_packed as wpp
+    call = wpp._on_a_stack_chunk_of_its_own
+    assert call.__code__.co_nlocals * 8 > 2 * 16 * 1024
+    assert call(lambda: sys._getframe(1).f_code) is call.__code__
+
+
+@pytest.mark.parametrize("parity,g5,route,dtype", [
+    (0, True, "fullz2", jnp.float32),
+    (1, True, "xla", jnp.float32),
+    pytest.param(1, False, "fullz1", jnp.float32, marks=pytest.mark.slow),
+    pytest.param(0, True, "zblock", jnp.float32, marks=pytest.mark.slow),
+    pytest.param(1, True, "fullz2", jnp.bfloat16, marks=pytest.mark.slow)])
+def test_mrhs_residual_epilogue_is_xla_on_the_combine_hop(
+        parity, g5, route, dtype, route_counts, monkeypatch):
+    """``rc`` and ``alpha`` besides ``xc`` and ``coeff``: the call
+    writes ``rc - alpha[n] * [g5] (xc + coeff * hop)``, source n with
+    ITS alpha (every source has another: a swapped index is far off),
+    rounded to the storage dtype once, and returns the per-source sums
+    of squares of what it stored; counted with ``epilogue="residual"``.
+    Where no route holds the two blocks (``xla``) it is the combine
+    call (here in turn the bare hop and XLA's combine) and XLA's
+    update and sum.  Against the combine call's f32 result updated by
+    XLA."""
+    from quda_tpu.ops import wilson_pallas_packed as wpp
+    (dims, nrhs, kw, u_here, u_bw, psi_b, xc, counted,
+     epilogue) = _epilogue_case(route, parity, dtype, monkeypatch)
+    rc = _eo_mrhs_problem(dims, parity, nrhs, dtype, seed=10)[2]
+    coeff = -0.12 ** 2
+    alpha = jnp.asarray([0.37, -1.9, 2.6][:nrhs], jnp.float32)
+    got, sums = wpp.dslash_eo_pallas_packed_mrhs_residual(
+        u_here, u_bw, psi_b, dims, parity, interpret=True, xc=xc,
+        coeff=coeff, g5=g5, rc=rc, alpha=alpha, **kw)
+    assert sums.shape == (nrhs,) and sums.dtype == jnp.float32
+    np.testing.assert_allclose(
+        np.asarray(sums), np.asarray(jnp.sum(
+            got.astype(jnp.float64).reshape(nrhs, -1) ** 2, axis=1)),
+        rtol=2e-6)
+    v = wpp.dslash_eo_pallas_packed_mrhs_combine(
+        u_here, u_bw, psi_b, dims, parity, interpret=True, xc=xc,
+        coeff=coeff, g5=g5, out_dtype=jnp.float32, **kw)[0]
+
+    def update(a):
+        w = rc.astype(jnp.float32) - a.reshape((nrhs,) + (1,) * 6) * v
+        return w.astype(dtype).astype(jnp.float32)
+    want = update(alpha)
+    assert got.dtype == dtype and got.shape == psi_b.shape
+    diff = jnp.abs(got.astype(jnp.float32) - want)
+    ulp = float(jnp.finfo(dtype).eps) * float(jnp.max(jnp.abs(want)))
+    assert float(jnp.max(diff)) <= ulp
+    assert float(jnp.max(jnp.abs(got.astype(jnp.float32)
+                                 - update(alpha[::-1])))) > 1e3 * ulp
+    assert route_counts() == {counted: 2.0}
+    assert route_counts("epilogue") == (
+        {"none": 2.0} if epilogue == "none"
+        else {"combine": 1.0, "residual": 1.0})
+    assert route_counts("reduce") == (
+        {"none": 2.0} if epilogue == "none" else {"norm2": 2.0})
+    with pytest.raises(ValueError, match="mrhs_residual"):
+        wpp.dslash_eo_pallas_packed_mrhs_combine(
+            u_here, u_bw, psi_b, dims, parity, interpret=True, xc=xc,
+            coeff=coeff, rc=rc, alpha=alpha)
+
+
 @pytest.mark.parametrize("case,want", [
-    # (T, Z, YX, storage, out, link rows R, block_z[, xc storage])
-    # -> (route, bz, bt)
+    # (T, Z, YX, storage, out, link rows R, block_z[, xc storage
+    # [, rc storage]]) -> (route, bz, bt)
     ((24, 24, 288, jnp.float32, jnp.float32, 3, None), ("fullz", 24, 2)),
     ((24, 24, 288, jnp.bfloat16, jnp.bfloat16, 3, None), ("fullz", 24, 2)),
     ((24, 24, 288, jnp.bfloat16, jnp.float32, 3, None), ("fullz", 24, 2)),
@@ -800,7 +974,22 @@ def test_mrhs_combine_epilogue_is_xla_on_the_plain_hop(
     ((24, 24, 288, jnp.float32, jnp.float32, 3, 8, jnp.float32),
      ("zblock", 8, 1)),
     ((40, 40, 640, jnp.float32, jnp.float32, 3, None, jnp.float32),
-     ValueError)])
+     ValueError),
+    # the residual form's rc block is one more again: 24^4 still takes
+    # two slices a step (42.2 MiB of the 48), eight rows of 1,408
+    # lanes take two with the xc block and one with both
+    ((24, 24, 288, jnp.float32, jnp.float32, 3, None, jnp.float32,
+      jnp.float32), ("fullz", 24, 2)),
+    ((24, 24, 288, jnp.bfloat16, jnp.bfloat16, 3, None, jnp.bfloat16,
+      jnp.bfloat16), ("fullz", 24, 2)),
+    ((24, 8, 1408, jnp.float32, jnp.float32, 3, None, jnp.float32),
+     ("fullz", 8, 2)),
+    ((24, 8, 1408, jnp.float32, jnp.float32, 3, None, jnp.float32,
+      jnp.float32), ("fullz", 8, 1)),
+    ((24, 24, 288, jnp.float32, jnp.float32, 3, 8, jnp.float32,
+      jnp.float32), ("zblock", 8, 1)),
+    ((40, 40, 640, jnp.float32, jnp.float32, 3, None, jnp.float32,
+      jnp.float32), ValueError)])
 def test_mrhs_route_follows_the_shapes(case, want, route_counts):
     """The route is arithmetic on (T, Z, YX, dtypes, R): padded planes x
     bytes, twice for the pipeline's buffers, plus the body's tiles,
@@ -811,22 +1000,31 @@ def test_mrhs_route_follows_the_shapes(case, want, route_counts):
     keeps the route's blocks beside the knob's own row."""
     from quda_tpu.obs import memory as omem
     from quda_tpu.ops import wilson_pallas_packed as wpp
-    T, Z, YX, dt, odt, R, block_z, xc_dt = (case + (None,))[:8]
+    T, Z, YX, dt, odt, R, block_z, xc_dt, rc_dt = (case + (None,) * 2)[:9]
     if want is ValueError:
         with pytest.raises(ValueError, match="fits the VMEM budget"):
-            wpp._mrhs_route(T, Z, YX, dt, odt, R, block_z, xc_dt)
+            wpp._mrhs_route(T, Z, YX, dt, odt, R, block_z, xc_dt, rc_dt)
         assert route_counts() == {}
         return
     route, bz, bt, limit = wpp._mrhs_route(T, Z, YX, dt, odt, R, block_z,
-                                           xc_dt)
+                                           xc_dt, rc_dt)
     rows = {r["knob"]: r for r in omem.audit_vmem_budgets()}
     assert (route, bz, bt) == want and route_counts() == {route: 1.0}
     assert route_counts("epilogue") == {
-        "none" if xc_dt is None else "combine": 1.0}
+        "none" if xc_dt is None else
+        "combine" if rc_dt is None else "residual": 1.0}
     assert route_counts("reduce") == {
         "none" if xc_dt is None else "norm2": 1.0}
-    blocks, need = wpp._mrhs_fullz_vmem(Z, YX, dt, odt, R, bt, xc_dt)
-    if xc_dt is not None:
+    blocks, need = wpp._mrhs_fullz_vmem(Z, YX, dt, odt, R, bt, xc_dt,
+                                        rc_dt)
+    if rc_dt is not None:
+        # bt spinor tiles more than with the xc block alone
+        with_xc = wpp._mrhs_fullz_vmem(Z, YX, dt, odt, R, bt, xc_dt)[0]
+        sub = wpp._sublane_rows(rc_dt)
+        assert blocks - with_xc == (
+            bt * 24 * -(-Z // sub) * sub * -(-YX // 128) * 128
+            * jnp.dtype(rc_dt).itemsize)
+    elif xc_dt is not None:
         # bt spinor tiles and one chunk of the body's rows in f32 (the
         # epilogue's sums), every plane padded to 128 lanes
         def padded(n, d):

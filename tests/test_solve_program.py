@@ -228,47 +228,95 @@ def test_second_kappa_reuses_the_program(route, warm, gauges):
     assert _host_residual(gauges["A"], b[0], x[0], KAPPA) > 1e-3
 
 
+def _mrhs_route_counts():
+    """{(route, epilogue, reduce): traced MRHS kernels so far}."""
+    out = {}
+    for (name, labels), v in omet.snapshot()["counters"].items():
+        if name == "wilson_mrhs_route_total":
+            lab = dict(labels)
+            out[lab["route"], lab["epilogue"], lab["reduce"]] = int(v)
+    return out
+
+
+def _mrhs_route_delta(before):
+    after = _mrhs_route_counts()
+    return {k: after[k] - before.get(k, 0) for k in after
+            if after[k] != before.get(k, 0)}
+
+
 @pytest.mark.parametrize("method,n", [("MdagM_pairs_mrhs", 5),
-                                      ("MdagM_dot_pairs_mrhs", 6)])
+                                      ("MdagM_cg_step_pairs_mrhs", 6),
+                                      ("program", 7)])
 def test_batched_route_operator_combines_in_its_second_hops(quda, method,
                                                             n):
     """The batched route's resident operator hands its program ``Ap``
     and not the bare hop sums: tracing ``MdagM`` on a batch of a size
     this process has not traced traces two kernels, the first hop bare
     and the second with the combine epilogue
-    (``wilson_mrhs_route_total``); kappa is an operand of that epilogue,
-    which is why the second kappa above is a hit.  That epilogue sums
-    the squares of what it stores (``reduce="norm2"``, once per traced
-    fused hop), so what the program's loop applies, ``MdagM_dot``, is
-    the same two kernels and hands it ``pAp`` too."""
-    def counts():
-        out = {}
-        for (name, labels), v in omet.snapshot()["counters"].items():
-            if name == "wilson_mrhs_route_total":
-                lab = dict(labels)
-                out[lab["route"], lab["epilogue"], lab["reduce"]] = int(v)
-        return out
+    (``wilson_mrhs_route_total``; the counter counts a kernel where it
+    is traced, and the second ``M``'s hops are the first's traces);
+    kappa is an operand of that epilogue, which is why the second kappa
+    above is a hit.  That epilogue sums the squares of what it stores
+    (``reduce="norm2"``, once per traced fused hop).  What the
+    program's loop applies, ``MdagM_cg_step``, takes ``pAp`` from those
+    sums and makes the LAST of its four hops (two bare: one trace, one
+    combine, one residual) write ``r - alpha A p`` and sum it: one
+    kernel more, the residual form, and that is all a whole solve
+    program traces."""
     op = api._resident_wilson(_param())["ops"][jnp.dtype(jnp.float32)]
-    before = counts()
+    op = op.with_kappa(KAPPA)
+    before = _mrhs_route_counts()
     batch = jax.ShapeDtypeStruct((n, 4, 3, 2, L, L, L * L // 2),
                                  jnp.float32)
-    out = jax.eval_shape(getattr(op.with_kappa(KAPPA), method), batch)
-    if method == "MdagM_dot_pairs_mrhs":
-        out, dot = out
-        assert (dot.shape, dot.dtype) == ((n,), jnp.float32)
+    lanes = jax.ShapeDtypeStruct((n,), jnp.float32)
+    want = {("fullz", "none", "none"): 1, ("fullz", "combine", "norm2"): 1}
+    if method == "MdagM_pairs_mrhs":
+        out = jax.eval_shape(op.MdagM_pairs_mrhs, batch)
+    elif method == "MdagM_cg_step_pairs_mrhs":
+        out, *scalars = jax.eval_shape(op.MdagM_cg_step_pairs_mrhs, batch,
+                                       batch, lanes)
+        assert [(v.shape, v.dtype) for v in scalars] == [
+            ((n,), jnp.float32)] * 3
+        want["fullz", "residual", "norm2"] = 1
+    else:
+        key = (1, sprog._LoopKnobs(False, None, None, None), False)
+        out = jax.eval_shape(
+            lambda o, b: sprog._batched_cg_pairs_program(
+                o, b, 1e-6, 500, key=key), op, batch).x
+        want["fullz", "residual", "norm2"] = 1
     assert (out.shape, out.dtype) == (batch.shape, batch.dtype)
-    after = counts()
-    assert {k: after[k] - before.get(k, 0) for k in after
-            if after[k] != before.get(k, 0)} == {
-        ("fullz", "none", "none"): 1, ("fullz", "combine", "norm2"): 1}
+    assert _mrhs_route_delta(before) == want
+
+
+@pytest.mark.parametrize("route", ["multi"])
+def test_second_kappa_and_batch_are_a_hit_of_the_batched_program(
+        route, warm):
+    """``solvers/program.batched_cg_pairs`` itself, on the resident
+    operator at another kappa and on another batch than the worker's
+    first call of the route: a hit (``alpha`` and ``coeff`` are operands
+    of the residual hop, not constants of the program), and the solution
+    of THAT system."""
+    op = api._resident_wilson(_param())["ops"][jnp.dtype(jnp.float32)]
+    other = op.with_kappa(0.105)
+    b = jnp.asarray(np.random.default_rng(21).standard_normal(
+        (2, 4, 3, 2, L, L, L * L // 2)), jnp.float32)
+    before = _mrhs_route_counts()
+    res, hit = sprog.batched_cg_pairs(other, b, tol=1e-6, maxiter=500)
+    assert hit and _mrhs_route_delta(before) == {}
+    assert bool(jnp.all(res.converged))
+    for kappa, small in ((0.105, True), (KAPPA, False)):
+        r = b - op.with_kappa(kappa).MdagM_pairs_mrhs(res.x)
+        rel = float(jnp.sqrt(jnp.sum(r * r) / jnp.sum(b * b)))
+        assert (rel < 5e-6) == small, (kappa, rel)
 
 
 @pytest.mark.parametrize("route", ["multi"])
 def test_kernel_pAp_stops_the_batched_solve_where_the_dot_does(
         route, warm, knobs, gauges):
     """The batched API call on the kernel route (``pAp`` out of the
-    second hop's epilogue) against the same call on the XLA stencil
-    (``block.with_dot``: XLA's dot over the batch): both converge to
+    second hop's epilogue, ``r`` and ``|r|^2`` out of the last's)
+    against the same call on the XLA stencil (``block.cg_step``: XLA's
+    dot, update and sum over the batch): both converge to
     tol, source by source within two iterations of each other, and the
     kernel route's call is a hit of the program the worker's first call
     traced."""
@@ -472,8 +520,8 @@ def test_hermitian_batched_program_applies_m_once_an_iteration(
     op = DiracStaggeredPC(fat, geom, 0.1, improved=True,
                           long_links=lng).pairs(jnp.float32)
     assert op.hermitian and sprog.presents(op)
-    # no dot of its own: the program lifts M_pairs_mrhs (block.with_dot)
-    assert not hasattr(op, "M_dot_pairs_mrhs")
+    # no step of its own: the program lifts M_pairs_mrhs (block.cg_step)
+    assert not hasattr(op, "M_cg_step_pairs_mrhs")
     hops = []
     d_to = DiracStaggeredPCPairs._d_to_mrhs
     monkeypatch.setattr(
