@@ -62,15 +62,28 @@ def qudaInvert(mass: float, source, tol: float = 1e-10,
 
 def qudaMultishiftInvert(mass: float, offsets: Sequence[float], source,
                          tol: float = 1e-10, maxiter: int = 10000,
-                         improved: bool = True):
+                         improved: bool = True, prec="double",
+                         tol_offset: Sequence[float] = (),
+                         info: Optional[dict] = None):
     """qudaMultishiftInvert: the RHMC rational-fraction solve
-    ((4m^2 - D_eo D_oe) + offset_i) x_i = b."""
+    ((4m^2 - D_eo D_oe) + offset_i) x_i = b.  ``tol_offset`` (MILC's
+    per-shift ``target_residual[]``) is accepted where every entry
+    equals ``tol``.  A dict given as ``info`` is filled with what
+    MILC's ``final_residual[]`` carries: ``true_res_offset`` and
+    ``iter_res_offset`` (per shift), ``converged_multi``, ``iters``."""
     p = InvertParam(
         dslash_type="hisq" if improved else "staggered",
         inv_type="multi-shift-cg", solve_type="normop-pc", mass=mass,
-        tol=tol, maxiter=maxiter, num_offset=len(offsets),
-        offset=tuple(offsets))
-    return api.invert_multishift_quda(source, p)
+        tol=tol, maxiter=maxiter, cuda_prec=prec,
+        num_offset=len(offsets), offset=tuple(offsets),
+        tol_offset=tuple(tol_offset))
+    xs = api.invert_multishift_quda(source, p)
+    if info is not None:
+        info.update(true_res_offset=list(p.true_res_offset),
+                    iter_res_offset=list(p.iter_res_offset),
+                    converged_multi=list(p.converged_multi),
+                    iters=p.iter_count)
+    return xs
 
 
 def qudaDslash(source, parity: int, mass: float = 0.0,

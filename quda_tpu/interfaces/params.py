@@ -94,6 +94,9 @@ class InvertParam:
     pipeline: int = 0
     num_offset: int = 0               # multi-shift
     offset: Sequence[float] = ()
+    # per-shift tolerances (QUDA's tol_offset[]): accepted only empty
+    # or equal to ``tol`` for every shift; anything else is refused
+    tol_offset: Sequence[float] = ()
     cuda_prec: str = "double"
     # "auto" resolves at solve time: bf16 ("half") on TPU, = cuda_prec on
     # CPU.  Pinning any explicit value opts out of the TPU default.
@@ -112,6 +115,13 @@ class InvertParam:
     # the per-RHS sums with the volume/2 PC flop convention
     true_res_multi: Sequence[float] = ()
     iter_count_multi: Sequence[int] = ()
+    # multi-shift results (invert_multishift_quda; QUDA's
+    # true_res_offset[] / iter_res_offset[]): per shift the true
+    # residual |b - (A + offset_i) x_i| / |b| recomputed at the exit,
+    # and the loop's own analytic zeta_i |r| / |b|; ``true_res`` stays
+    # shift 0's as in QUDA, ``converged_multi`` is per shift
+    true_res_offset: Sequence[float] = ()
+    iter_res_offset: Sequence[float] = ()
     # convergence trace (populated when QUDA_TPU_TRACE is on —
     # obs/convergence.py): res_history = per-check-point entries
     # [{"iter", "r2", "relres"}, ...] (every iteration at cadence 1),
@@ -148,6 +158,17 @@ class InvertParam:
         _check(self.tol > 0 and self.maxiter > 0, "bad tol/maxiter")
         if self.num_offset:
             _check(len(self.offset) == self.num_offset, "offset mismatch")
+        if self.offset:
+            _check(min(self.offset) >= self.offset[0],
+                   f"offset[0] must be the smallest shift (QUDA takes "
+                   f"them ascending), got {tuple(self.offset)}")
+        if len(self.tol_offset):
+            _check(len(self.tol_offset) == len(self.offset)
+                   and all(float(t) == float(self.tol)
+                           for t in self.tol_offset),
+                   f"tol_offset {tuple(self.tol_offset)}: per-shift "
+                   f"tolerances other than tol ({self.tol:g}) are not "
+                   "served")
         return self
 
     def describe(self) -> str:

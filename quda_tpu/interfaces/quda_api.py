@@ -2465,38 +2465,127 @@ def _publish_multishift(res, rhs, param, tol=None, stage_note=None):
     oconv.publish(rec, param)
 
 
+def _account_multishift(param: InvertParam, d):
+    """Populate param.gflops like invert_quda does (monitor parity,
+    lib/monitor.cpp solver fields).  Hermitian PC (staggered): the
+    shifted solves apply M once per iteration; otherwise the normal
+    equations cost MdagM = 2 applies.  PC convention: flops_per_site_M
+    is per UPDATED site, so the PC operator charges volume/2 (see
+    invert_quda's accounting note)."""
+    flops = getattr(d, "flops_per_site_M", lambda: 0)()
+    sites = _ctx["geom"].volume // 2
+    mv_per_iter = 1.0 if getattr(d, "hermitian", False) else 2.0
+    param.gflops = (param.iter_count * mv_per_iter * flops * sites) / 1e9
+    _record_solve_metrics("invert_multishift_quda", _solve_form(d),
+                          "multishift-cg", param.secs,
+                          param.dslash_type, param.cuda_prec)
+
+
+def _shift_residuals(param: InvertParam, mv, rhs, xs, res=None):
+    """The eager routes' per-shift exit: ``true_res_offset[i]`` =
+    |rhs - (mv + offset_i) x_i| / |rhs| for EVERY shift, one more
+    application of ``mv`` each; ``true_res`` stays shift 0's, as in
+    QUDA; ``iter_res_offset`` the loop's analytic zeta_i |r| where
+    ``res`` (a MultiShiftResult) carries it."""
+    import numpy as np
+    b2 = blas.norm2(rhs)
+    param.true_res_offset = [
+        float(jnp.sqrt(blas.norm2(rhs - (mv(xs[i]) + s * xs[i])) / b2))
+        for i, s in enumerate(param.offset)]
+    param.true_res = param.true_res_offset[0]
+    param.iter_res_offset = (
+        [] if res is None else
+        [float(v) for v in np.sqrt(np.asarray(res.shift_r2)
+                                   / float(b2))])
+
+
+def _invert_multishift_resident(b, param: InvertParam, recording: bool):
+    """The improved-staggered multi-shift solve on the resident KS term
+    (``_resident_staggered``, as invert_quda's ks_resident route):
+    prepare, the shared-Krylov loop and the exit are one cached program
+    each (solvers/program.py), the offsets operands of the last two.
+    The exit verifies EVERY shift: the N solutions are a batch for the
+    operator's batched hop, and a shift counts as converged when the
+    loop claimed it and its true residual is within the verified-exit
+    margin (``QUDA_TPU_ROBUST_VERIFY_MARGIN`` x tol), never on the
+    loop's zeta |r| alone."""
+    import numpy as np
+
+    from ..obs import metrics as omet
+    from ..obs import trace as otr
+    from ..solvers import program as sprog
+    from ..utils import config as qconf
+    api = "invert_multishift_quda"
+    t0 = time.perf_counter()
+    with otr.phase("setup", api):
+        d = _StaggeredResidentSolve(_resident_staggered(param),
+                                    param.mass)
+        form = _solve_form(d)
+        # a host array: an operand of the two programs, no eager op
+        shifts = np.asarray(param.offset, np.float32)
+        with otr.span("prepare", cat="setup") as span:
+            rhs, hit = sprog.prepare(d.op, b)
+            _note_solve_program(span, api, form, "prepare", hit)
+    with otr.phase("compute", api), \
+            otr.span("solve:multishift-cg", cat="solver",
+                     n_shifts=len(param.offset), tol=param.tol,
+                     maxiter=param.maxiter) as solve_span:
+        with otr.span("dispatch", cat="solver"):
+            res, hit = sprog.multishift_cg(
+                d.op, rhs, shifts, tol=param.tol, maxiter=param.maxiter,
+                record=recording)
+        _note_solve_program(solve_span, api, form, "multishift-cg", hit)
+        # the first host read of the program's result: the wait for
+        # its device time
+        with otr.span("wait", cat="solver"):
+            param.iter_count = int(res.iters)
+    param.secs = time.perf_counter() - t0
+    _account_multishift(param, d)
+    with otr.phase("epilogue", api):
+        margin = float(qconf.get("QUDA_TPU_ROBUST_VERIFY_MARGIN",
+                                 fresh=True))
+        with otr.span("verified_exit", cat="epilogue") as span:
+            (xs, *numbers), hit = sprog.verified_exit_shifts(
+                d.op, rhs, res, shifts, margin * param.tol)
+            _note_solve_program(span, api, form, "verified-exit", hit)
+            with otr.span("exit_read", cat="epilogue"):
+                true_res, iter_res, ok = jax.device_get(numbers)
+        param.true_res_offset = [float(r) for r in true_res]
+        param.iter_res_offset = [float(r) for r in iter_res]
+        param.true_res = param.true_res_offset[0]
+        for outcome, n in (("converged", int(ok.sum())),
+                           ("failed", int((~ok).sum()))):
+            if n:
+                omet.inc("multishift_shift_total", float(n),
+                         outcome=outcome)
+        _solve_supervision(param, api,
+                           breakdown=getattr(res, "breakdown", None),
+                           converged_multi=ok)
+    _publish_multishift(res, rhs, param)
+    return xs.astype(b.dtype)
+
+
 def _invert_multishift_body(source, param: InvertParam):
     from ..obs import trace as otr
     from ..solvers.multishift import multishift_cg
     recording = otr.enabled()
     b = jnp.asarray(source, complex_dtype(param.cuda_prec))
+    on_tpu = jax.default_backend() == "tpu"
+    pairs_ok = ((param.cuda_prec == "single" or on_tpu)
+                and _packed_enabled(on_tpu))
+    if pairs_ok and _ks_links_loaded(param):
+        return _invert_multishift_resident(b, param, recording)
     d = _build_dirac(param, True)
     be, bo = _split(b, param, d)
 
-    def _account(n_extra_mv: int = 0):
-        """Populate param.gflops like invert_quda does (monitor parity,
-        lib/monitor.cpp solver fields).  Hermitian PC (staggered): the
-        shifted solves apply M once per iteration; otherwise the normal
-        equations cost MdagM = 2 applies.  Polish solves add their own.
-        PC convention: flops_per_site_M is per UPDATED site, so the PC
-        operator charges volume/2 (see invert_quda's accounting note)."""
-        flops = getattr(d, "flops_per_site_M", lambda: 0)()
-        sites = _ctx["geom"].volume // 2
-        mv_per_iter = 1.0 if getattr(d, "hermitian", False) else 2.0
-        param.gflops = ((param.iter_count * mv_per_iter + n_extra_mv)
-                        * flops * sites) / 1e9
-        _record_solve_metrics("invert_multishift_quda", _solve_form(d),
-                              "multishift-cg", param.secs,
-                              param.dslash_type, param.cuda_prec)
-
-    on_tpu = jax.default_backend() == "tpu"
     if (param.dslash_type in ("staggered", "asqtad", "hisq")
-            and (param.cuda_prec == "single" or on_tpu)
-            and _packed_enabled(on_tpu)):
-        # complex-free multishift (the RHMC rational-force hot path):
-        # shared-Krylov solve entirely on pair arrays (CG coefficients
-        # on the Hermitian PC operator are real, so the pair
-        # representation is exact), pallas eo stencil on real TPU
+            and pairs_ok):
+        # complex-free multishift without a resident KS term (plain
+        # staggered, or improved links that were never loaded): the
+        # operator is built per call and the shared-Krylov loop runs
+        # eagerly on pair arrays (CG coefficients on the Hermitian PC
+        # operator are real, so the pair representation is exact),
+        # pallas eo stencil on real TPU
         t0 = time.perf_counter()
         ad = _StaggeredPairsSolve(d, _pallas_enabled(on_tpu),
                                   _pallas_interpret(on_tpu))
@@ -2507,21 +2596,17 @@ def _invert_multishift_body(source, param: InvertParam):
                                 record=recording)
         param.iter_count = int(res.iters)
         param.secs = time.perf_counter() - t0
-        _account()
+        _account_multishift(param, d)
         _publish_multishift(res, rhs_pp, param)
-        r0 = rhs_pp - (ad.M(res.x[0])
-                       + param.offset[0] * res.x[0].astype(jnp.float32))
-        param.true_res = float(jnp.sqrt(blas.norm2(r0)
-                                        / blas.norm2(rhs_pp)))
+        _shift_residuals(param, ad.M, rhs_pp,
+                         res.x.astype(jnp.float32), res)
         _solve_supervision(param, "invert_multishift_quda",
                            breakdown=getattr(res, "breakdown", None),
                            converged_multi=res.converged)
         return jnp.stack([ad.op._from_pairs(res.x[i], b.dtype)
                           for i in range(len(param.offset))])
 
-    if (param.dslash_type == "wilson"
-            and (param.cuda_prec == "single" or on_tpu)
-            and _packed_enabled(on_tpu)):
+    if param.dslash_type == "wilson" and pairs_ok:
         # complex-free Wilson multishift: shared-Krylov CGNR on the
         # packed pair representation end to end (coefficients of the
         # shifted normal-equation solves are real — exact on pairs)
@@ -2545,12 +2630,10 @@ def _invert_multishift_body(source, param: InvertParam):
                                 maxiter=param.maxiter, record=recording)
         param.iter_count = int(res.iters)
         param.secs = time.perf_counter() - t0
-        _account()
+        _account_multishift(param, d)
         _publish_multishift(res, nrm_rhs, param)
-        r0 = nrm_rhs - (sl.MdagM_pairs(res.x[0])
-                        + param.offset[0] * res.x[0].astype(jnp.float32))
-        param.true_res = float(jnp.sqrt(blas.norm2(r0)
-                                        / blas.norm2(nrm_rhs)))
+        _shift_residuals(param, sl.MdagM_pairs, nrm_rhs,
+                         res.x.astype(jnp.float32), res)
         _solve_supervision(param, "invert_multishift_quda",
                            breakdown=getattr(res, "breakdown", None),
                            converged_multi=res.converged)
@@ -2595,9 +2678,8 @@ def _invert_multishift_body(source, param: InvertParam):
             conv_s.append(bool(ref.converged))
         param.iter_count = iters
         param.secs = time.perf_counter() - t0
-        _account()
-        r0 = rhs - (mv(xs[0]) + shifts[0] * xs[0])
-        param.true_res = float(jnp.sqrt(blas.norm2(r0) / blas.norm2(rhs)))
+        _account_multishift(param, d)
+        _shift_residuals(param, mv, rhs, xs)
         # convergence judged on the precise-level per-shift polish CGs
         _solve_supervision(param, "invert_multishift_quda",
                            converged_multi=conv_s)
@@ -2607,10 +2689,9 @@ def _invert_multishift_body(source, param: InvertParam):
                             maxiter=param.maxiter, record=recording)
     param.iter_count = int(res.iters)
     param.secs = time.perf_counter() - t0
-    _account()
+    _account_multishift(param, d)
     _publish_multishift(res, rhs, param)
-    r0 = rhs - (mv(res.x[0]) + shifts[0] * res.x[0])
-    param.true_res = float(jnp.sqrt(blas.norm2(r0) / blas.norm2(rhs)))
+    _shift_residuals(param, mv, rhs, res.x, res)
     _solve_supervision(param, "invert_multishift_quda",
                        breakdown=getattr(res, "breakdown", None),
                        converged_multi=res.converged)

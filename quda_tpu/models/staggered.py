@@ -1011,6 +1011,31 @@ class DiracStaggeredPCPairs(_ProgramOperand):
                 jnp.sqrt((norm2(r_p) + norm2(r_q))
                          / (norm2(b_p) + norm2(b_q))))
 
+    def verified_exit_shifts_pairs(self, b_pp, X_pp, shifts, claimed,
+                                   shift_r2, bound):
+        """The verified exit of a multi-shift solve (A + sigma_i) x_i =
+        b on A = 4m^2 - D_pq D_qp: the pair-form PC right-hand side
+        ``b_pp``, the N pair-form solutions ``X_pp`` (N, 3, 2, T, Z,
+        Y*Xh) and ``shifts`` (N,) -> (canonical parity solutions (N, T,
+        Z, Y, Xh, 1, 3), the N true residuals |b - (A + sigma_i) x_i| /
+        |b|, the loop's analytic residuals sqrt(``shift_r2``) / |b|,
+        N flags: ``claimed`` by the loop AND a true residual <=
+        ``bound``; a NaN fails).  The N solutions are a batch for the
+        batched hop (``M_pairs_mrhs``): ONE application for all
+        shifts, links read once.  The residual is the PC system's own,
+        not amplified by 1 / 2m as the full system's is.  Meant to be
+        traced (solvers/program.py) on the f32 operator."""
+        f32 = jnp.float32
+        b, X = b_pp.astype(f32), X_pp.astype(f32)
+        sig = shifts.astype(f32).reshape((-1,) + (1,) * b.ndim)
+        r = b[None] - (self.M_pairs_mrhs(X).astype(f32) + sig * X)
+        b2 = jnp.sum(b * b)
+        true_res = jnp.sqrt(jnp.sum(r * r, axis=tuple(range(1, r.ndim)))
+                            / b2)
+        return (self.solution_from_pairs_mrhs(X), true_res,
+                jnp.sqrt(shift_r2.astype(f32) / b2),
+                jnp.logical_and(claimed, true_res <= bound))
+
     # -- multi-RHS boundary helpers (the invert_multi_src_quda route) ---
     def prepare_pairs_mrhs(self, b_even_b, b_odd_b):
         """Batched canonical complex parity sources (N, T,Z,Y,Xh,1,3) ->

@@ -215,8 +215,9 @@ METRICS: dict[str, dict] = {
     "ks_term_total": {
         "type": COUNTER,
         "help": "uses of the resident KS pair operators "
-                "(load_fat_long_quda, asqtad / hisq invert_quda and "
-                "invert_multi_src_quda on the pair routes) by outcome: "
+                "(load_fat_long_quda, asqtad / hisq invert_quda, "
+                "invert_multi_src_quda and invert_multishift_quda on the "
+                "pair routes) by outcome: "
                 "'built' nothing was resident, "
                 "'reused' the resident operators served, 'rebuilt' "
                 "another matpc, boundary or kernel route replaced them; "
@@ -238,6 +239,13 @@ METRICS: dict[str, dict] = {
                 "first M's second hop it is the batched CG's pAp = "
                 "|g5 M p|^2, from the residual hop its new |r|^2), "
                 "'none' the bare hop"},
+    "multishift_shift_total": {
+        "type": COUNTER,
+        "help": "shifts of invert_multishift_quda calls on the resident "
+                "KS route by outcome of the verified exit: 'converged' "
+                "the loop claimed the shift and its true residual, "
+                "recomputed by the exit program, is within the "
+                "verified-exit margin x tol; 'failed' anything else"},
     "staggered_mrhs_route_total": {
         "type": COUNTER,
         "help": "traced calls of the batched staggered hop "

@@ -421,6 +421,7 @@ class SolveService:
         failure path carries the batch's request ids (the flight-
         capture analysis rule pins this wrapping)."""
         import jax.numpy as jnp
+        import numpy as np
 
         from ..interfaces import quda_api as api
         from ..obs import postmortem as opm
@@ -434,17 +435,22 @@ class SolveService:
                 # batcher.solve_key) take their own API entry point; x
                 # is the stacked per-shift solution batch, results are
                 # the batch-level param fields (converged_multi holds
-                # the per-shift claims)
+                # the per-shift claims) and the LARGEST per-shift true
+                # residual: param.true_res is shift 0's, and a request
+                # is not good on one shift
                 if getattr(param, "num_offset", 0):
                     x = api.invert_multishift_quda(grp[0].source,
                                                    param)
+                    # np.max: a NaN shift is the largest
+                    true_res = float(np.max(param.true_res_offset))
                 else:
                     x = api.invert_quda(grp[0].source, param)
+                    true_res = param.true_res
                 st = (getattr(param, "solve_status", None)
                       or ("converged" if param.converged
                           else "unconverged"))
                 return ([x], [st], [param.converged],
-                        [param.iter_count], [param.true_res])
+                        [param.iter_count], [true_res])
             B = jnp.stack([jnp.asarray(r.source) for r in grp])
             X = api.invert_multi_src_quda(B, param)
         conv = list(getattr(param, "converged_multi", None)

@@ -6,10 +6,12 @@ every shift via the shifted-CG zeta recurrences (a single matvec per
 iteration); per-shift convergence is tracked through the analytically known
 shifted residual |r_s| = zeta_s |r|.
 
-The shift vector is a static (Python) tuple; the shifted iterates are a
-stacked leading axis so the per-shift axpys are one fused broadcast —
-QUDA's hand-written multi-shift update kernels (multi_blas) fall out of XLA
-fusion for free.
+The shifts are an ARRAY (``multishift_cg_loop``: an operand of the
+cached program, solvers/program.multishift_cg, so an RHMC that changes
+its poles between the force and the action compiles once); the shifted
+iterates are a stacked leading axis so the per-shift axpys are one fused
+broadcast — QUDA's hand-written multi-shift update kernels (multi_blas)
+fall out of XLA fusion for free.
 """
 
 from __future__ import annotations
@@ -33,6 +35,8 @@ class MultiShiftResult(NamedTuple):
     # optional typed breakdown code (robust/sentinel.py; None on
     # unguarded solves — see solvers/cg.SolverResult.breakdown)
     breakdown: object = None
+    # (n_shifts,) analytic shifted residuals zeta_i^2 |r|^2 at exit
+    shift_r2: object = None
 
 
 def multishift_cg(matvec: Callable, b: jnp.ndarray,
@@ -42,27 +46,58 @@ def multishift_cg(matvec: Callable, b: jnp.ndarray,
     """Solve (matvec + shift_i) x_i = b, matvec Hermitian positive
     semi-definite and every shift >= 0 (the RHMC setting).
 
-    Shifts are offset so the BASE system includes the smallest shift (QUDA
-    orders shifts ascending and iterates the zeroth); convergence of shift i
-    is |r_i|^2 = zeta_i^2 |r|^2 <= tol^2 |b|^2.
+    The BASE system is shift 0's, which must be the smallest (QUDA
+    takes its offsets ascending and iterates the zeroth; anything else
+    is refused here, where the shifts are still host numbers);
+    convergence of shift i is |r_i|^2 = zeta_i^2 |r|^2 <= tol^2 |b|^2.
+
+    ``iters`` counts the loop's iterations: one application of
+    ``matvec`` each, shared by every shift (the loop runs until the
+    slowest shift, the base system, is under ``tol`` or ``maxiter`` is
+    reached; it is NOT a sum over shifts).
 
     ``record=True`` additionally returns per-iteration base residual
     norms and the analytically-known per-shift residuals
     (|r_s|^2 = zeta_s^2 |r|^2) as ``history`` for obs/convergence.py.
+
+    Resolves the sentinel and the armed dslash fault from host state
+    and runs ``multishift_cg_loop``; callable eagerly with any closure.
     """
     from ..robust import faultinject as finj
     from ..robust import sentinel as rsent
-    sent = rsent.make()
-    fault_k = finj.iteration_fault("dslash")
-    shifts = tuple(float(s) for s in shifts)
-    ns = len(shifts)
-    s0 = min(shifts)
-    sig = jnp.asarray([s - s0 for s in shifts], b.real.dtype)  # >= 0
-    base = lambda v: matvec(v) + (s0 * v if s0 != 0.0 else 0.0 * v)
+    shifts = [float(s) for s in shifts]
+    if min(shifts) < shifts[0]:
+        raise ValueError(
+            f"multishift_cg: shift 0 ({shifts[0]:g}) must be the "
+            f"smallest, got {shifts}")
+    return multishift_cg_loop(
+        matvec, b, jnp.asarray(shifts, b.real.dtype), tol, maxiter,
+        record, rsent.make(), finj.iteration_fault("dslash"))
 
+
+def multishift_cg_loop(matvec: Callable, b: jnp.ndarray, shifts,
+                       tol, maxiter, record: bool = False, sent=None,
+                       fault_k=None) -> MultiShiftResult:
+    """The body of ``multishift_cg`` with every host-state knob an
+    argument (``sent``: robust/sentinel.Sentinel or None; ``fault_k``:
+    the armed dslash fault iteration or None), so it can sit under a
+    cached ``jax.jit`` (solvers/program.py).  ``shifts`` is an
+    (n_shifts,) real array, shift 0 the smallest, and may be traced, as
+    may ``tol`` and (unless ``record`` sizes the history by it)
+    ``maxiter``."""
+    from ..robust import faultinject as finj
+    from ..robust import sentinel as rsent
+    ns = shifts.shape[0]
     b2 = blas.norm2(b)
-    stop = (tol ** 2) * b2
     rdt = b2.dtype
+    shifts = shifts.astype(rdt)
+    s0 = shifts[0]
+    sig = shifts - s0                                  # >= 0
+    base = lambda v: matvec(v) + s0.astype(v.dtype) * v
+
+    # tol in the residual's dtype first: a host float and the cached
+    # program's operand then stop at the same iteration
+    stop = (jnp.asarray(tol, rdt) ** 2) * b2
 
     def expand(a):
         """(ns,) scalars -> broadcastable over stacked fields."""
@@ -139,4 +174,4 @@ def multishift_cg(matvec: Callable, b: jnp.ndarray,
             else None)
     conv, bk = rsent.finalize(sent, out.get("sent"), conv)
     return MultiShiftResult(out["x"], out["k"], out["r2"], conv, hist,
-                            bk)
+                            bk, shift_r2(out))

@@ -1,20 +1,24 @@
 """The solver loop as ONE cached, jitted program per key.
 
-``mixed.cg_reliable`` and ``block.batched_cg_pairs`` run
-``lax.while_loop`` over closures; called eagerly from the API with
-operators rebuilt per call, every call re-traced the loop (a dozen
-pallas_calls), re-lowered it through Mosaic and hashed the module before
-the persistent cache could serve the executable: 1.2-1.4 s of host time
-per call at 24^4 with the device idle (PERF_LEDGER, PR 25 lines).  Here
-the same loop bodies (``cg_reliable_loop`` / ``batched_cg_pairs_loop``,
-no solver logic copied) sit under one module-level ``jax.jit`` each, so
-a later call with the same key is an in-process executable lookup.
+``mixed.cg_reliable``, ``block.batched_cg_pairs`` and
+``multishift.multishift_cg`` run ``lax.while_loop`` over closures;
+called eagerly from the API with operators rebuilt per call, every call
+re-traced the loop (a dozen pallas_calls), re-lowered it through Mosaic
+and hashed the module before the persistent cache could serve the
+executable: 1.2-1.4 s of host time per call at 24^4 with the device idle
+(PERF_LEDGER, PR 25 lines).  Here the same loop bodies
+(``cg_reliable_loop`` / ``batched_cg_pairs_loop`` /
+``multishift_cg_loop``, no solver logic copied) sit under one
+module-level ``jax.jit`` each, so a later call with the same key is an
+in-process executable lookup.
 
 * **Operands** (new values reuse the executable; nothing the size of a
   field is closed over): the source, the operators' resident links and
   kappa (the operator is a pytree: models/wilson
-  ``DiracWilsonPCPackedSloppy.tree_flatten``), ``tol``, and ``maxiter``
-  unless ``record`` sizes the history by it.
+  ``DiracWilsonPCPackedSloppy.tree_flatten``), ``tol``, ``maxiter``
+  unless ``record`` sizes the history by it, and the multi-shift
+  program's shifts (their number is the operand's shape: an RHMC that
+  changes its poles between the force and the action compiles once).
 * **Key** (a change gives a new program, never a stale one), all
   resolved OUTSIDE the trace on every call: the operators' static
   signature (class, dims, matpc, tb_sign, pallas version, block_z,
@@ -52,6 +56,9 @@ operator offers: the fault corrupts ``A p``, which only that step has.
 Which of the two is the operand's class and static signature and
 ``knobs.fault_k``, so the key has no field for it.  With a leading
 source axis ``verified_exit`` and ``prepare`` are the batched route's.
+``multishift_cg`` is the third loop, on one right-hand side and N
+shifted systems; its exit (``verified_exit_shifts``) takes the N
+solutions as a batch for the operator's batched hop.
 """
 
 from __future__ import annotations
@@ -61,7 +68,7 @@ from typing import NamedTuple, Optional
 
 import jax
 
-from . import block, mixed
+from . import block, mixed, multishift
 
 # program bodies run only while jax traces them: a call that leaves
 # this count unchanged was served by the in-process executable cache
@@ -147,6 +154,48 @@ def batched_cg_pairs(op, B, tol: float, maxiter: int,
            bool(getattr(op, "hermitian", False)))
     return _run(_batched_cg_pairs_program, op, B, float(tol),
                 int(maxiter), key=key)
+
+
+@partial(jax.jit, static_argnames=("key",))
+def _multishift_program(op, b, shifts, tol, maxiter, key):
+    _traces[0] += 1
+    knobs, hermitian = key
+    return multishift.multishift_cg_loop(
+        getattr(op, "M_pairs" if hermitian else "MdagM_pairs"), b,
+        shifts, tol, knobs.maxiter if knobs.record else maxiter,
+        knobs.record, knobs.sentinel, knobs.fault_k)
+
+
+def multishift_cg(op, b, shifts, tol: float, maxiter: int,
+                  record: bool = False):
+    """``multishift.multishift_cg`` on ``op.M_pairs`` where the operator
+    says it is ``hermitian`` (else ``op.MdagM_pairs``) through the
+    cached program.  ``shifts`` is an (n,) f32 array, shift 0 the
+    smallest: an operand, so other values of the same count reuse the
+    executable.  Returns ``(MultiShiftResult, hit)``."""
+    key = (_loop_knobs(record, maxiter),
+           bool(getattr(op, "hermitian", False)))
+    return _run(_multishift_program, op, b, shifts, float(tol),
+                int(maxiter), key=key)
+
+
+@jax.jit
+def _verified_exit_shifts_program(op, b_pp, X_pp, shifts, claimed,
+                                  shift_r2, bound):
+    _traces[0] += 1
+    return op.verified_exit_shifts_pairs(b_pp, X_pp, shifts, claimed,
+                                         shift_r2, bound)
+
+
+def verified_exit_shifts(op, b_pp, res, shifts, bound: float):
+    """The verified exit of a multi-shift solve on the f32 pair operator
+    ``op`` (``verified_exit_shifts_pairs``) through the cached program:
+    the pair-form PC right-hand side, the loop's ``MultiShiftResult``
+    and the shifts -> ``((canonical parity solutions (N, ...), true
+    residuals (N,), the loop's analytic residuals (N,), verified flags
+    (N,): the loop's claim AND a true residual <= bound), hit)``."""
+    return _run(_verified_exit_shifts_program, op, b_pp, res.x, shifts,
+                res.converged, res.shift_r2, float(bound))
 
 
 @jax.jit

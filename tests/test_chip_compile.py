@@ -636,3 +636,62 @@ def test_hisq_batched_programs_compile_for_v5e(one_chip, program):
     big = [c for c in _hlo_values(hlo, "constant") if c[0] > 2 ** 20]
     assert not big, f"fields baked into the executable: {big}"
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
+@pytest.mark.parametrize("program", ["solve", "verified-exit"])
+def test_hisq_multishift_programs_compile_for_v5e(one_chip, program):
+    """The two programs of a multi-shift improved-staggered call that
+    no other route builds (solvers/program.py on the resident f32
+    DiracStaggeredPCPairs, fourteen shifts at 24^4; its prepare is the
+    single-source one above) compile for the described chip on abstract
+    operands: links AND shifts are parameters (other offsets of the
+    same count are the same executable), the loop applies the
+    single-source served form four passes an iteration, and the exit
+    applies the MRHS form to the fourteen solutions as one batch."""
+    import re
+    from quda_tpu.fields.geometry import LatticeGeometry
+    from quda_tpu.models.staggered import DiracStaggeredPCPairs
+    from quda_tpu.solvers import program as sprog
+    geom = LatticeGeometry(DIMS)
+    lshape = (4, 3, 3, 2, L, L, YXH)
+    n = 14
+
+    def operator(fe, fo, le, lo):
+        return DiracStaggeredPCPairs.from_packed(
+            geom, (fe, fo), (le, lo), 0.04, 0, F32, use_pallas=True,
+            pallas_interpret=False)
+
+    def lower():
+        lk = jax.ShapeDtypeStruct(lshape, F32)
+        op = jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(
+                s.shape, s.dtype, sharding=one_chip,
+                weak_type=s.weak_type),
+            jax.eval_shape(operator, lk, lk, lk, lk))
+        assert op.hermitian and sprog.presents(op)
+        b = jax.ShapeDtypeStruct((3, 2, L, L, YXH), F32,
+                                 sharding=one_chip)
+        per_shift = lambda dt: jax.ShapeDtypeStruct((n,), dt,
+                                                    sharding=one_chip)
+        if program == "solve":
+            key = (sprog._LoopKnobs(False, None, None, None), True)
+            return sprog._multishift_program.lower(
+                op, b, per_shift(F32), 1e-6, 10000, key=key)
+        x = jax.ShapeDtypeStruct((n, 3, 2, L, L, YXH), F32,
+                                 sharding=one_chip)
+        return sprog._verified_exit_shifts_program.lower(
+            op, b, x, per_shift(F32), per_shift(jnp.bool_),
+            per_shift(F32), 1e-4)
+    compiled = _aot(lower)
+    hlo = compiled.as_text()
+    name = {"solve": "dslash_staggered_eo_pallas_v3",
+            "verified-exit": "dslash_staggered_eo_pallas_v3_mrhs"}[program]
+    calls = re.findall(rf"%{name}[.\d]* = f32\[[^\n]*tpu_custom_call", hlo)
+    assert len(calls) == 4, calls
+    params = _hlo_values(hlo, "parameter")
+    links = ",".join(str(d) for d in lshape)
+    assert sum(p[1:] == ("f32", links) for p in params) >= 4
+    assert sum(p[1:] == ("f32", str(n)) for p in params) >= 1
+    big = [c for c in _hlo_values(hlo, "constant") if c[0] > 2 ** 20]
+    assert not big, f"fields baked into the executable: {big}"
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
