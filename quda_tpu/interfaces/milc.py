@@ -70,7 +70,8 @@ def qudaMultishiftInvert(mass: float, offsets: Sequence[float], source,
     per-shift ``target_residual[]``) is accepted where every entry
     equals ``tol``.  A dict given as ``info`` is filled with what
     MILC's ``final_residual[]`` carries: ``true_res_offset`` and
-    ``iter_res_offset`` (per shift), ``converged_multi``, ``iters``."""
+    ``iter_res_offset`` (per shift), ``converged_multi``, ``iters`` and
+    ``iter_count_offset`` (the iterations each shift was updated in)."""
     p = InvertParam(
         dslash_type="hisq" if improved else "staggered",
         inv_type="multi-shift-cg", solve_type="normop-pc", mass=mass,
@@ -82,7 +83,8 @@ def qudaMultishiftInvert(mass: float, offsets: Sequence[float], source,
         info.update(true_res_offset=list(p.true_res_offset),
                     iter_res_offset=list(p.iter_res_offset),
                     converged_multi=list(p.converged_multi),
-                    iters=p.iter_count)
+                    iters=p.iter_count,
+                    iter_count_offset=list(p.iter_count_offset))
     return xs
 
 

@@ -119,9 +119,12 @@ class InvertParam:
     # true_res_offset[] / iter_res_offset[]): per shift the true
     # residual |b - (A + offset_i) x_i| / |b| recomputed at the exit,
     # and the loop's own analytic zeta_i |r| / |b|; ``true_res`` stays
-    # shift 0's as in QUDA, ``converged_multi`` is per shift
+    # shift 0's as in QUDA, ``converged_multi`` is per shift;
+    # ``iter_count_offset`` the iterations each shift was updated in
+    # (a converged shift leaves the update; shift 0's is ``iter_count``)
     true_res_offset: Sequence[float] = ()
     iter_res_offset: Sequence[float] = ()
+    iter_count_offset: Sequence[int] = ()
     # convergence trace (populated when QUDA_TPU_TRACE is on —
     # obs/convergence.py): res_history = per-check-point entries
     # [{"iter", "r2", "relres"}, ...] (every iteration at cadence 1),

@@ -2481,12 +2481,39 @@ def _account_multishift(param: InvertParam, d):
                           param.dslash_type, param.cuda_prec)
 
 
+def _read_shift_iterations(param: InvertParam, res):
+    """ONE host read of a MultiShiftResult's counts: ``iter_count`` the
+    loop's iterations, ``iter_count_offset`` the iterations each shift
+    was updated in (a converged shift leaves the update).  A function
+    of its own, as ``_note_shift_iterations`` is: their locals in the
+    API function's frame lengthened the lowering of the exit program
+    under it by 2-6 s (PERF.md section 7 (22))."""
+    iters, shift_iters = jax.device_get((res.iters, res.shift_iters))
+    param.iter_count = int(iters)
+    param.iter_count_offset = [int(n) for n in shift_iters]
+
+
+def _note_shift_iterations(param: InvertParam, solve_span):
+    """``active_share`` on the solve span and
+    ``multishift_shift_iterations_total{state}``: the share of the
+    N x iters shifted updates the loop made."""
+    from ..obs import metrics as omet
+    updated = sum(param.iter_count_offset)
+    total = len(param.offset) * param.iter_count
+    solve_span.set(active_share=round(updated / max(total, 1), 6))
+    for state, n in (("updated", updated), ("skipped", total - updated)):
+        if n:
+            omet.inc("multishift_shift_iterations_total", float(n),
+                     state=state)
+
+
 def _shift_residuals(param: InvertParam, mv, rhs, xs, res=None):
     """The eager routes' per-shift exit: ``true_res_offset[i]`` =
     |rhs - (mv + offset_i) x_i| / |rhs| for EVERY shift, one more
     application of ``mv`` each; ``true_res`` stays shift 0's, as in
-    QUDA; ``iter_res_offset`` the loop's analytic zeta_i |r| where
-    ``res`` (a MultiShiftResult) carries it."""
+    QUDA; ``iter_res_offset`` the loop's analytic zeta_i |r| and
+    ``iter_count_offset`` the iterations each shift was updated in,
+    where ``res`` (a MultiShiftResult) carries them."""
     import numpy as np
     b2 = blas.norm2(rhs)
     param.true_res_offset = [
@@ -2497,6 +2524,9 @@ def _shift_residuals(param: InvertParam, mv, rhs, xs, res=None):
         [] if res is None else
         [float(v) for v in np.sqrt(np.asarray(res.shift_r2)
                                    / float(b2))])
+    param.iter_count_offset = (
+        [] if res is None else
+        [int(v) for v in np.asarray(res.shift_iters)])
 
 
 def _invert_multishift_resident(b, param: InvertParam, recording: bool):
@@ -2536,9 +2566,10 @@ def _invert_multishift_resident(b, param: InvertParam, recording: bool):
                 record=recording)
         _note_solve_program(solve_span, api, form, "multishift-cg", hit)
         # the first host read of the program's result: the wait for
-        # its device time
+        # its device time (the per-shift counts ride the same read)
         with otr.span("wait", cat="solver"):
-            param.iter_count = int(res.iters)
+            _read_shift_iterations(param, res)
+        _note_shift_iterations(param, solve_span)
     param.secs = time.perf_counter() - t0
     _account_multishift(param, d)
     with otr.phase("epilogue", api):

@@ -144,6 +144,12 @@ SPAN_ATTRS: dict[str, dict] = {
                              "build accounting's records (obs/build.py), "
                              "which program_build_seconds counts by "
                              "program"},
+    "active_share": {"spans": ("solve:multishift-cg",),
+                     "doc": "the share of the N x iters shifted updates "
+                            "the multi-shift loop made: a converged "
+                            "shift leaves the update "
+                            "(MultiShiftResult.shift_iters summed over "
+                            "N x iters; 1.0 = no shift retired early)"},
 }
 
 # -- metrics (obs/metrics.py registry) --------------------------------------
@@ -246,6 +252,14 @@ METRICS: dict[str, dict] = {
                 "the loop claimed the shift and its true residual, "
                 "recomputed by the exit program, is within the "
                 "verified-exit margin x tol; 'failed' anything else"},
+    "multishift_shift_iterations_total": {
+        "type": COUNTER,
+        "help": "shift-iterations of invert_multishift_quda calls on "
+                "the resident KS route by state: 'updated' the loop "
+                "updated the shift's x and p in that iteration "
+                "(MultiShiftResult.shift_iters), 'skipped' the shift "
+                "had converged and left the update; updated + skipped "
+                "= N x iterations"},
     "staggered_mrhs_route_total": {
         "type": COUNTER,
         "help": "traced calls of the batched staggered hop "

@@ -158,3 +158,63 @@ def axpy_norm2_pallas(a, x, y, interpret: bool = False,
         interpret=interpret,
     )(a2d, _as2d(x), _as2d(y))
     return yo.reshape(shape), acc[0, 0]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "block_rows"))
+def multishift_update_pallas(n_active, alpha_s, zeta, beta_s, x, p, r,
+                             interpret: bool = False,
+                             block_rows: int | None = None):
+    """The shifted update of a multi-shift CG over its first
+    ``n_active`` shifts, in place on the stacked iterates:
+    ``x[i] += alpha_s[i] p[i]``, ``p[i] = zeta[i] r + beta_s[i] p[i]``
+    for i < n_active (QUDA's multi_blas update with ``num_offset_now``);
+    rows from ``n_active`` on are neither read nor written.  ``x``,
+    ``p``: (N,) + r.shape, real; the three coefficients (N,);
+    ``n_active`` a traced int32 in [1, N].
+
+    Grid (row-block, shift), the shift innermost so ``r``'s block is
+    fetched once a row-block; ``n_active`` and the coefficients are
+    scalar-prefetched and the index maps of ``x`` and ``p`` clamp the
+    shift to the last live one, so a skipped step revisits the block
+    it holds and moves nothing; the results alias ``x`` and ``p``."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    ns, C = x.shape[0], x.shape[-1]
+    R = r.size // C
+    br = block_rows if block_rows is not None else _pick_rows(R, C, 5)
+    if R % br != 0:
+        raise ValueError(f"block_rows={br} does not divide rows={R}")
+    # five blocks (x, p, r in; x, p out), double-buffered, as tiled
+    need = 10 * (-(-br // 8) * 8) * (-(-C // 128) * 128) * x.dtype.itemsize
+    na = jnp.reshape(n_active, (1,)).astype(jnp.int32)
+    coef = jnp.stack([alpha_s, zeta, beta_s]).astype(F32)
+
+    def kernel(na_ref, coef_ref, x_ref, p_ref, r_ref, xo_ref, po_ref):
+        s = pl.program_id(1)
+
+        @pl.when(s < na_ref[0])
+        def _():
+            pv = p_ref[0].astype(F32)
+            xo_ref[0] = (x_ref[0].astype(F32)
+                         + coef_ref[0, s] * pv).astype(xo_ref.dtype)
+            po_ref[0] = (coef_ref[1, s] * r_ref[...].astype(F32)
+                         + coef_ref[2, s] * pv).astype(po_ref.dtype)
+
+    row = pl.BlockSpec((1, br, C), lambda i, s, na, coef:
+                       (jnp.minimum(s, na[0] - 1), i, 0))
+    xo, po = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(R // br, ns),
+            in_specs=[row, row,
+                      pl.BlockSpec((br, C), lambda i, s, na, coef: (i, 0))],
+            out_specs=[row, row]),
+        out_shape=[jax.ShapeDtypeStruct((ns, R, C), x.dtype),
+                   jax.ShapeDtypeStruct((ns, R, C), p.dtype)],
+        input_output_aliases={2: 0, 3: 1},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=max(16 * 2 ** 20, need + 4 * 2 ** 20)),
+        interpret=interpret,
+    )(na, coef, x.reshape(ns, R, C), p.reshape(ns, R, C), _as2d(r))
+    return xo.reshape(x.shape), po.reshape(p.shape)

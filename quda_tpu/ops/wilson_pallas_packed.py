@@ -53,6 +53,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+# the MRHS ``pallas_call`` is made from a frame that opens a chunk of
+# CPython's frame stack of its own (PERF.md section 7 (22))
+from ..utils.frames import (
+    on_a_stack_chunk_of_its_own as _on_a_stack_chunk_of_its_own)
 from .wilson_packed import TABLES
 
 F32 = jnp.float32
@@ -695,30 +699,6 @@ def _mrhs_wrap(kernel, n_psi: int = 5, n_out: int = 1):
         kernel(*psi, *refs[n_psi:n_in], _LeadAxisRef(refs[n_in]),
                *refs[n_in + 1:])
     return wrapped
-
-
-def _big_frame_caller(n_locals: int = 4200):
-    """``call(fn) -> fn()`` from a frame of ``n_locals`` locals.
-
-    CPython (3.11 on) keeps a thread's interpreter frames on a stack of
-    16 KiB chunks and gives a chunk back to the allocator when its
-    first frame returns.  A loop whose calls straddle a chunk boundary
-    maps and unmaps a chunk a call, and tracing a kernel body is such a
-    loop (some 10^4 equations, each bound a dozen frames down): the
-    same trace takes 0.7 s or 1.8 here, 2.2 or 4.9 s on the chip's host
-    (PERF.md section 7 (22)), by where on that stack the caller stands,
-    which any local variable more in any frame above it moves.  A
-    frame too large for what is left of any 16 KiB chunk always opens a
-    chunk of its own (64 KiB for 4,200 locals, 30 of them free below
-    it), so what runs under it stands at the same place whoever
-    calls."""
-    names = " = ".join(f"_{i}" for i in range(n_locals))
-    scope = {}
-    exec(f"def call(fn):\n    {names} = None\n    return fn()\n", scope)
-    return scope["call"]
-
-
-_on_a_stack_chunk_of_its_own = _big_frame_caller()
 
 
 # What the full-Z route may ask of a core's VMEM: three eighths of a

@@ -9,13 +9,23 @@ shifted residual |r_s| = zeta_s |r|.
 The shifts are an ARRAY (``multishift_cg_loop``: an operand of the
 cached program, solvers/program.multishift_cg, so an RHMC that changes
 its poles between the force and the action compiles once); the shifted
-iterates are a stacked leading axis so the per-shift axpys are one fused
-broadcast — QUDA's hand-written multi-shift update kernels (multi_blas)
-fall out of XLA fusion for free.
+iterates are a stacked leading axis.
+
+The shifted update runs over the LIVE shifts only, as the reference's
+does (``num_offset_now``): a shift whose own recurrence says
+zeta_i |r| <= tol |b| is retired, its ``x_i`` left as it is and its row
+of the stacks neither read nor written again.  What is updated is a
+prefix ``[:n_active]`` of the stacks, 1 + the largest index still above
+tol: right for any order of the offsets, least work for ascending ones
+(QUDA's contract, MILC's practice), where the prefix is exactly the
+live set.  Updating all N to the end cost a quarter of the HISQ RHMC
+solve at fourteen shifts on a v5e, three quarters of it on shifts that
+had converged (PERF.md section 6, PR 41).
 """
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Callable, NamedTuple, Sequence
 
 import jax
@@ -35,8 +45,13 @@ class MultiShiftResult(NamedTuple):
     # optional typed breakdown code (robust/sentinel.py; None on
     # unguarded solves — see solvers/cg.SolverResult.breakdown)
     breakdown: object = None
-    # (n_shifts,) analytic shifted residuals zeta_i^2 |r|^2 at exit
+    # (n_shifts,) analytic shifted residuals zeta_i^2 |r|^2 of the x_i
+    # returned: a retired shift's is the one at its retirement
     shift_r2: object = None
+    # (n_shifts,) int32: the iterations in which shift i was updated
+    # (``iters`` for shift 0; their sum over N x iters is the share of
+    # the full update's work that was done)
+    shift_iters: object = None
 
 
 def multishift_cg(matvec: Callable, b: jnp.ndarray,
@@ -49,7 +64,10 @@ def multishift_cg(matvec: Callable, b: jnp.ndarray,
     The BASE system is shift 0's, which must be the smallest (QUDA
     takes its offsets ascending and iterates the zeroth; anything else
     is refused here, where the shifts are still host numbers);
-    convergence of shift i is |r_i|^2 = zeta_i^2 |r|^2 <= tol^2 |b|^2.
+    convergence of shift i is |r_i|^2 = zeta_i^2 |r|^2 <= tol^2 |b|^2,
+    and a shift that has converged leaves the update: its ``x_i`` is
+    the iterate of that iteration, ``shift_r2[i]`` the residual it was
+    retired at and ``shift_iters[i]`` the iterations it was updated in.
 
     ``iters`` counts the loop's iterations: one application of
     ``matvec`` each, shared by every shift (the loop runs until the
@@ -60,8 +78,9 @@ def multishift_cg(matvec: Callable, b: jnp.ndarray,
     norms and the analytically-known per-shift residuals
     (|r_s|^2 = zeta_s^2 |r|^2) as ``history`` for obs/convergence.py.
 
-    Resolves the sentinel and the armed dslash fault from host state
-    and runs ``multishift_cg_loop``; callable eagerly with any closure.
+    Resolves the sentinel, the armed dslash fault and the form of the
+    update (``update_form``) from host state and runs
+    ``multishift_cg_loop``; callable eagerly with any closure.
     """
     from ..robust import faultinject as finj
     from ..robust import sentinel as rsent
@@ -72,21 +91,92 @@ def multishift_cg(matvec: Callable, b: jnp.ndarray,
             f"smallest, got {shifts}")
     return multishift_cg_loop(
         matvec, b, jnp.asarray(shifts, b.real.dtype), tol, maxiter,
-        record, rsent.make(), finj.iteration_fault("dslash"))
+        record, rsent.make(), finj.iteration_fault("dslash"),
+        update_form(b))
+
+
+def update_form(b) -> str:
+    """How ``multishift_cg_loop`` updates its live shifts for a source
+    like ``b``: ``"pallas"``, the kernel
+    (ops/blas_pallas.multishift_update_pallas), where the backend is a
+    TPU and ``b`` a real array on ONE device whose row-blocks fit the
+    kernel's VMEM budget (the pair representation of every TPU solve);
+    ``"xla"`` (``update_live_xla``) everywhere else: a complex source,
+    the CPU, and a source whose placement cannot be seen (a host array;
+    a tracer of an outer jit, which GSPMD may have sharded: a kernel is
+    not partitioned).  Read from host state, outside any trace."""
+    from ..ops import blas_pallas as bpl
+    if (jax.default_backend() != "tpu" or not isinstance(b, jax.Array)
+            or isinstance(b, jax.core.Tracer) or b.ndim < 2
+            or not jnp.issubdtype(b.dtype, jnp.floating)
+            or len(b.sharding.device_set) != 1):
+        return "xla"
+    try:
+        bpl._pick_rows(b.size // b.shape[-1], b.shape[-1], 5)
+    except ValueError:
+        return "xla"
+    return "pallas"
+
+
+def update_live_xla(n_active, alpha_s, zeta, beta_s, x, p, r):
+    """``x[i] += alpha_s[i] p[i]``, ``p[i] = zeta[i] r + beta_s[i] p[i]``
+    for i < ``n_active`` (traced, in [1, N]) on the stacked iterates,
+    any dtype: a ``lax.switch`` over the N static prefix lengths, each
+    branch ONE elementwise fusion that ends in a dynamic-update-slice
+    of the stacks, so rows from ``n_active`` on are neither read nor
+    written.  On a TPU XLA carries a stack in on-chip memory and copies
+    it out of and back into it around the conditional, every
+    iteration: there the real-valued solves take the kernel
+    (``update_form``)."""
+    ns = x.shape[0]
+
+    def rows(a):
+        return a.reshape(a.shape + (1,) * r.ndim).astype(x.dtype)
+
+    def prefix(n):
+        def f(x, p):
+            if n == 1:
+                # the base shift alone is plain CG's update on row 0,
+                # written on that row: XLA's CPU code then rounds it as
+                # it rounds the full update (the one-row slice of the
+                # general form contracts its multiply-add the other way)
+                a, z, bt = (v[0].astype(x.dtype)
+                            for v in (alpha_s, zeta, beta_s))
+                xn, pn = (x[0] + a * p[0])[None], (z * r + bt * p[0])[None]
+            else:
+                xn = x[:n] + rows(alpha_s[:n]) * p[:n]
+                pn = rows(zeta[:n]) * r[None] + rows(beta_s[:n]) * p[:n]
+            if n == ns:
+                return xn, pn
+            return (jax.lax.dynamic_update_slice_in_dim(x, xn, 0, 0),
+                    jax.lax.dynamic_update_slice_in_dim(p, pn, 0, 0))
+        return f
+
+    return jax.lax.switch(n_active - 1,
+                          [prefix(n) for n in range(1, ns + 1)], x, p)
 
 
 def multishift_cg_loop(matvec: Callable, b: jnp.ndarray, shifts,
                        tol, maxiter, record: bool = False, sent=None,
-                       fault_k=None) -> MultiShiftResult:
+                       fault_k=None,
+                       update: str = "xla") -> MultiShiftResult:
     """The body of ``multishift_cg`` with every host-state knob an
     argument (``sent``: robust/sentinel.Sentinel or None; ``fault_k``:
-    the armed dslash fault iteration or None), so it can sit under a
-    cached ``jax.jit`` (solvers/program.py).  ``shifts`` is an
-    (n_shifts,) real array, shift 0 the smallest, and may be traced, as
-    may ``tol`` and (unless ``record`` sizes the history by it)
-    ``maxiter``."""
+    the armed dslash fault iteration or None; ``update``: what
+    ``update_form`` read, or ``"pallas-interpret"`` for the kernel
+    interpreted), so it can sit under a cached ``jax.jit``
+    (solvers/program.py).
+    ``shifts`` is an (n_shifts,) real array, shift 0 the smallest, and
+    may be traced, as may ``tol`` and (unless ``record`` sizes the
+    history by it) ``maxiter``."""
     from ..robust import faultinject as finj
     from ..robust import sentinel as rsent
+    if update == "xla":
+        update_live = update_live_xla
+    else:
+        from ..ops import blas_pallas as bpl
+        update_live = partial(bpl.multishift_update_pallas,
+                              interpret=update == "pallas-interpret")
     ns = shifts.shape[0]
     b2 = blas.norm2(b)
     rdt = b2.dtype
@@ -99,9 +189,7 @@ def multishift_cg_loop(matvec: Callable, b: jnp.ndarray, shifts,
     # program's operand then stop at the same iteration
     stop = (jnp.asarray(tol, rdt) ** 2) * b2
 
-    def expand(a):
-        """(ns,) scalars -> broadcastable over stacked fields."""
-        return a.reshape((ns,) + (1,) * b.ndim)
+    idx = jnp.arange(ns)
 
     state = dict(
         x=jnp.zeros((ns,) + b.shape, b.dtype),
@@ -113,6 +201,9 @@ def multishift_cg_loop(matvec: Callable, b: jnp.ndarray, shifts,
         alpha_old=jnp.ones((), rdt),
         beta_old=jnp.zeros((), rdt),
         k=jnp.int32(0),
+        # zeta_i^2 |r|^2 of x_i as it stands: a retired shift's stays
+        shift_r2=jnp.full((ns,), b2, rdt),
+        shift_iters=jnp.zeros((ns,), jnp.int32),
     )
     if record:
         state["hist"] = jnp.full((maxiter + 1,), jnp.nan, rdt)
@@ -120,11 +211,8 @@ def multishift_cg_loop(matvec: Callable, b: jnp.ndarray, shifts,
     if sent is not None:
         state["sent"] = sent.init(b2)
 
-    def shift_r2(c):
-        return (c["zeta"] ** 2) * c["r2"]
-
     def cond(c):
-        go = jnp.logical_and(jnp.max(shift_r2(c)) > stop,
+        go = jnp.logical_and(jnp.max(c["shift_r2"]) > stop,
                              c["k"] < maxiter)
         if sent is not None:
             go = jnp.logical_and(go, sent.ok(c["sent"]))
@@ -138,40 +226,43 @@ def multishift_cg_loop(matvec: Callable, b: jnp.ndarray, shifts,
         pAp = blas.redot(p0, Ap).astype(rdt)
         alpha = c["r2"] / pAp
 
-        # zeta recurrence (Frommer/van der Vorst shifted CG)
+        # zeta recurrence (Frommer/van der Vorst shifted CG): scalars,
+        # carried on for every shift, retired or not
         zn = c["zeta"] * c["zeta_old"] * c["alpha_old"]
         zd = (alpha * c["beta_old"] * (c["zeta_old"] - c["zeta"])
               + c["zeta_old"] * c["alpha_old"] * (1.0 + sig * alpha))
         zeta_new = jnp.where(zd != 0, zn / jnp.where(zd != 0, zd, 1.0), 0.0)
-        alpha_s = alpha * jnp.where(c["zeta"] != 0,
-                                    zeta_new / jnp.where(c["zeta"] != 0,
-                                                         c["zeta"], 1.0), 0.0)
-
-        x = c["x"] + expand(alpha_s).astype(b.dtype) * c["p"]
+        ratio = jnp.where(c["zeta"] != 0,
+                          zeta_new / jnp.where(c["zeta"] != 0,
+                                               c["zeta"], 1.0), 0.0)
         r = c["r"] - alpha.astype(b.dtype) * Ap
         r2_new = blas.norm2(r).astype(rdt)
         beta = r2_new / c["r2"]
-        beta_s = beta * jnp.where(
-            c["zeta"] != 0,
-            (zeta_new / jnp.where(c["zeta"] != 0, c["zeta"], 1.0)) ** 2, 0.0)
-        p = (expand(zeta_new).astype(b.dtype) * r[None]
-             + expand(beta_s).astype(b.dtype) * c["p"])
+
+        # the live prefix: 1 + the last shift still above tol (a row
+        # past it holds a residual under tol that no longer changes, so
+        # the prefix never grows back; shift 0 is in it to the end)
+        n_active = jnp.max(jnp.where(c["shift_r2"] > stop, idx + 1, 1))
+        live = idx < n_active
+        x, p = update_live(n_active, alpha * ratio, zeta_new,
+                           beta * ratio ** 2, c["x"], c["p"], r)
+        sr2 = jnp.where(live, (zeta_new ** 2) * r2_new, c["shift_r2"])
 
         nxt = dict(x=x, p=p, r=r, r2=r2_new, zeta=zeta_new,
                    zeta_old=c["zeta"], alpha_old=alpha, beta_old=beta,
-                   k=c["k"] + 1)
+                   k=c["k"] + 1, shift_r2=sr2,
+                   shift_iters=c["shift_iters"] + live.astype(jnp.int32))
         if record:
             nxt["hist"] = c["hist"].at[c["k"]].set(r2_new)
-            nxt["shist"] = c["shist"].at[c["k"]].set(
-                (zeta_new ** 2) * r2_new)
+            nxt["shist"] = c["shist"].at[c["k"]].set(sr2)
         if sent is not None:
             nxt["sent"] = sent.step(c["sent"], r2_new, denom=pAp)
         return nxt
 
     out = jax.lax.while_loop(cond, body, state)
-    conv = shift_r2(out) <= stop
+    conv = out["shift_r2"] <= stop
     hist = ({"r2": out["hist"], "shift_r2": out["shist"]} if record
             else None)
     conv, bk = rsent.finalize(sent, out.get("sent"), conv)
     return MultiShiftResult(out["x"], out["k"], out["r2"], conv, hist,
-                            bk, shift_r2(out))
+                            bk, out["shift_r2"], out["shift_iters"])

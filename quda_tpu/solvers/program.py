@@ -25,7 +25,8 @@ in-process executable lookup.
   precision form, storage dtype, pallas/interpret flags: the treedef),
   the operand avals, and the loop's own knobs below (``delta``, the
   codec's fused-tail choice, ``check_every``, ``record``, the sentinel,
-  the armed fault iteration).  The key holds no array and no operator
+  the armed fault iteration, the multi-shift loop's form of its update:
+  ``multishift.update_form``).  The key holds no array and no operator
   identity.
 
 The verified exit of the Wilson and staggered pair routes is a program
@@ -68,6 +69,7 @@ from typing import NamedTuple, Optional
 
 import jax
 
+from ..utils.frames import on_a_stack_chunk_of_its_own
 from . import block, mixed, multishift
 
 # program bodies run only while jax traces them: a call that leaves
@@ -159,11 +161,15 @@ def batched_cg_pairs(op, B, tol: float, maxiter: int,
 @partial(jax.jit, static_argnames=("key",))
 def _multishift_program(op, b, shifts, tol, maxiter, key):
     _traces[0] += 1
-    knobs, hermitian = key
-    return multishift.multishift_cg_loop(
-        getattr(op, "M_pairs" if hermitian else "MdagM_pairs"), b,
-        shifts, tol, knobs.maxiter if knobs.record else maxiter,
-        knobs.record, knobs.sentinel, knobs.fault_k)
+    knobs, hermitian, update = key
+    # traced from a stack chunk of its own: the operator's kernels
+    # trace in 0.8 s from there and in 6-9 s from where this frame
+    # happened to stand (PERF.md section 6, PR 41; section 7 (22))
+    return on_a_stack_chunk_of_its_own(
+        lambda: multishift.multishift_cg_loop(
+            getattr(op, "M_pairs" if hermitian else "MdagM_pairs"), b,
+            shifts, tol, knobs.maxiter if knobs.record else maxiter,
+            knobs.record, knobs.sentinel, knobs.fault_k, update))
 
 
 def multishift_cg(op, b, shifts, tol: float, maxiter: int,
@@ -174,7 +180,8 @@ def multishift_cg(op, b, shifts, tol: float, maxiter: int,
     smallest: an operand, so other values of the same count reuse the
     executable.  Returns ``(MultiShiftResult, hit)``."""
     key = (_loop_knobs(record, maxiter),
-           bool(getattr(op, "hermitian", False)))
+           bool(getattr(op, "hermitian", False)),
+           multishift.update_form(b))
     return _run(_multishift_program, op, b, shifts, float(tol),
                 int(maxiter), key=key)
 

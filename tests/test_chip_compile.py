@@ -116,6 +116,18 @@ def _axpy_norm2():
     return (bp.axpy_norm2_pallas, [((), F32), v, v])
 
 
+def _multishift_update(n, field):
+    """The multi-shift loop's update of its live shifts on a stack of
+    ``n`` pair fields (solvers/multishift.update_form takes it for
+    any real source on the chip): the staggered colour vector of the
+    cell and the Wilson spinor of the eager route."""
+    from quda_tpu.ops import blas_pallas as bp
+    coef = ((n,), F32)
+    return (bp.multishift_update_pallas,
+            [((), jnp.int32), coef, coef, coef, ((n,) + field, F32),
+             ((n,) + field, F32), (field, F32)])
+
+
 def _staggered_eo_fused():
     from quda_tpu.ops import staggered_pallas as sp
     lk = ((4, 3, 3, 2, L, L, YXH), F32)
@@ -187,6 +199,10 @@ CASES = {
     "cg_update_norm2_f32": lambda: _cg_update(F32),
     "cg_update_norm2_bf16": lambda: _cg_update(BF16),
     "axpy_norm2_f32": _axpy_norm2,
+    "multishift_update_n14_staggered": lambda: _multishift_update(
+        14, (3, 2, L, L, YXH)),
+    "multishift_update_n4_wilson": lambda: _multishift_update(
+        4, (4, 3, 2, L, L, YXH)),
     # one case per other operator family the solve API routes to a kernel
     "staggered_eo_fused": _staggered_eo_fused,
     "staggered_eo_mrhs_n8_scatter_even": lambda: _staggered_eo_mrhs(
@@ -646,8 +662,9 @@ def test_hisq_multishift_programs_compile_for_v5e(one_chip, program):
     single-source one above) compile for the described chip on abstract
     operands: links AND shifts are parameters (other offsets of the
     same count are the same executable), the loop applies the
-    single-source served form four passes an iteration, and the exit
-    applies the MRHS form to the fourteen solutions as one batch."""
+    single-source served form four passes an iteration and updates its
+    live shifts in place, and the exit applies the MRHS form to the
+    fourteen solutions as one batch."""
     import re
     from quda_tpu.fields.geometry import LatticeGeometry
     from quda_tpu.models.staggered import DiracStaggeredPCPairs
@@ -674,7 +691,10 @@ def test_hisq_multishift_programs_compile_for_v5e(one_chip, program):
         per_shift = lambda dt: jax.ShapeDtypeStruct((n,), dt,
                                                     sharding=one_chip)
         if program == "solve":
-            key = (sprog._LoopKnobs(False, None, None, None), True)
+            # the update's form as multishift.update_form reads it on
+            # a TPU backend (here the backend is the CPU): the kernel
+            key = (sprog._LoopKnobs(False, None, None, None), True,
+                   "pallas")
             return sprog._multishift_program.lower(
                 op, b, per_shift(F32), 1e-6, 10000, key=key)
         x = jax.ShapeDtypeStruct((n, 3, 2, L, L, YXH), F32,
@@ -688,6 +708,17 @@ def test_hisq_multishift_programs_compile_for_v5e(one_chip, program):
             "verified-exit": "dslash_staggered_eo_pallas_v3_mrhs"}[program]
     calls = re.findall(rf"%{name}[.\d]* = f32\[[^\n]*tpu_custom_call", hlo)
     assert len(calls) == 4, calls
+    if program == "solve":
+        # the live shifts' update is ONE kernel on the carried stacks
+        # (operands and results bitcasts of them): the operator is
+        # traced once whatever the number of live shifts, and nothing
+        # the size of a stack is copied (a conditional around an XLA
+        # update copies one out of and into on-chip memory a branch)
+        assert len(re.findall(r"%multishift_update_pallas[.\d]* = \("
+                              r"[^\n]*tpu_custom_call", hlo)) == 1
+        stack = L * L * YXH * 3 * 2 * n * 4
+        assert not [c for c in _hlo_values(hlo, "copy") if c[0] >= stack]
+        assert "conditional(" not in hlo
     params = _hlo_values(hlo, "parameter")
     links = ",".join(str(d) for d in lshape)
     assert sum(p[1:] == ("f32", links) for p in params) >= 4

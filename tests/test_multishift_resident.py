@@ -307,3 +307,76 @@ def test_milc_multishift_returns_the_per_shift_residuals(solved):
     assert info["iter_res_offset"] == solved["p"].iter_res_offset
     assert info["converged_multi"] == [True] * N
     assert info["iters"] == solved["p"].iter_count
+    assert info["iter_count_offset"] == solved["p"].iter_count_offset
+
+
+# (g) converged shifts leave the update ---------------------------------------
+
+def test_shift_iterations_reach_the_param_and_the_counter(solved):
+    """The reference's fourteen ascending offsets on the 4^3 x 8 HISQ
+    operator: every shifted system retires before the base one, each
+    with the residual its own recurrence read at that iteration (a
+    claim just under tol, not one polished on for nothing)."""
+    p = solved["p"]
+    n = p.iter_count_offset
+    assert len(n) == N and n[0] == p.iter_count
+    assert all(a >= b for a, b in zip(n, n[1:])) and n[-1] < n[0] // 4
+    assert all(0.1 * p.tol < r <= p.tol for r in p.iter_res_offset)
+    before = _counts("multishift_shift_iterations_total", ("state",))
+    q = _param(solved["ref"].OFFSETS)
+    api.invert_multishift_quda(solved["src"], q)
+    assert q.iter_count_offset == n
+    assert _delta(before, _counts("multishift_shift_iterations_total",
+                                  ("state",))) == {
+        ("updated",): sum(n), ("skipped",): N * p.iter_count - sum(n)}
+
+
+def test_traced_call_carries_the_active_share(solved, tmp_path):
+    """Under a trace session the loop records its history through the
+    same body: the solve span carries the share of the N x iters
+    updates that were made, and each shift's lane says where it went
+    under tol, the iteration it was last updated in."""
+    import json
+    from quda_tpu.obs import trace as otr
+    otr.start(str(tmp_path))
+    try:
+        p = _param(solved["ref"].OFFSETS)
+        xs = api.invert_multishift_quda(solved["src"], p)
+    finally:
+        spans = [json.loads(ln) for ln in open(otr.stop()["jsonl"])]
+    np.testing.assert_array_equal(np.asarray(xs), np.asarray(solved["xs"]))
+    n = solved["p"].iter_count_offset
+    assert p.iter_count_offset == n
+    share = [s["active_share"] for s in spans
+             if s.get("name") == "solve:multishift-cg"]
+    assert share == [round(sum(n) / (N * p.iter_count), 6)]
+    assert 0.1 < share[0] < 0.6
+    at = {e["shift"]: e["iter"] for e in p.events
+          if e["type"] == "shift_converged"}
+    assert at == dict(enumerate(n))
+
+
+def test_solve_program_is_traced_from_a_stack_chunk_of_its_own(monkeypatch):
+    """The loop (and with it the operator's kernels) is traced under
+    utils/frames.on_a_stack_chunk_of_its_own, whatever stands above the
+    program (PERF.md section 7 (22): 0.8 s against 6-9 on the chip)."""
+    import sys
+    from quda_tpu.solvers import multishift, program as sprog
+    from quda_tpu.utils.frames import on_a_stack_chunk_of_its_own as call
+
+    def loop(matvec, *args):
+        f, above = sys._getframe(1), []
+        while f is not None and len(above) < 3:
+            above.append(f.f_code)
+            f = f.f_back
+        return matvec, args[-1], above
+    monkeypatch.setattr(multishift, "multishift_cg_loop", loop)
+
+    class Op:
+        M_pairs, MdagM_pairs = "M", "MdagM"
+    key = (sprog._LoopKnobs(False, 0, None, None), True, "xla")
+    matvec, update, above = sprog._multishift_program.__wrapped__(
+        Op(), None, None, 1e-6, 10, key)
+    assert (matvec, update) == ("M", "xla")
+    assert call.__code__ in above
+    assert call.__code__.co_nlocals * 8 > 2 * 16 * 1024
