@@ -12,6 +12,7 @@ module-level context the way interface_quda.cpp keeps gaugePrecise etc.
 from __future__ import annotations
 
 import time
+from functools import partial
 from typing import Optional, Sequence
 
 import jax
@@ -36,6 +37,7 @@ _ctx = {
     "clover": None,         # resident clover term (load_clover_quda)
     "wilson": None,         # resident Wilson pair operators
     "ks": None,             # resident KS pair operators (fat + long)
+    "mobius": None,         # resident Möbius pair operators
     "gauge_epoch": 0,       # bumped whenever the resident gauge changes
     "ks_epoch": 0,          # bumped whenever the fat / long links change
 }
@@ -407,8 +409,9 @@ def _antiperiodic():
 _CLOVER_FIELD = "resident_clover"     # their rows in the HBM ledger
 _WILSON_FIELD = "resident_wilson"
 _KS_FIELD = "resident_ks"
+_MOBIUS_FIELD = "resident_mobius"
 _RESIDENT_FIELDS = {"clover": _CLOVER_FIELD, "wilson": _WILSON_FIELD,
-                    "ks": _KS_FIELD}
+                    "ks": _KS_FIELD, "mobius": _MOBIUS_FIELD}
 
 
 def _pair_store(prec: str):
@@ -489,6 +492,107 @@ def _resident_wilson(param: InvertParam, stores=()) -> dict:
             _ctx["wilson"] = term
             omem.track("wilson", _WILSON_FIELD, term["ops"])
     omet.inc("wilson_term_total", outcome=outcome)
+    return term
+
+
+def _mobius_resident_route(param: InvertParam) -> bool:
+    """Whether ``invert_quda`` solves this on ``_resident_mobius``:
+    Möbius, CG on the normal equations of the 4d-PC system
+    (``normop-pc``) on the packed pair representation at f32 or with
+    bf16 sloppy storage.  Everything else of the family (5d-PC domain
+    wall, EOFA, other solvers and solve types, an f64 solve) keeps the
+    canonical classes and the eager loop."""
+    on_tpu = jax.default_backend() == "tpu"
+    return (param.dslash_type == "mobius" and param.inv_type == "cg"
+            and param.solve_type == "normop-pc" and not param.num_offset
+            and (param.cuda_prec == "single" or on_tpu)
+            and _packed_enabled(on_tpu)
+            and (on_tpu or _resolve_sloppy(param)
+                 in ("single", "half", "quarter")))
+
+
+def _mobius_term_keys(param: InvertParam, on_tpu: bool) -> tuple:
+    """(what the resident Möbius operators' LINKS depend on, what
+    their BLOCKS depend on): the gauge generation, matpc, the fermion
+    boundary, Ls and the kernel route (pallas or not, interpreted or
+    not, what the hop set-up reads from the environment and the hop
+    form's knob); and (b5, c5, M5, mf), which a new value of rebuilds
+    four (Ls, Ls) block pairs and nothing else."""
+    from ..models.wilson import hop_route_knobs
+    from ..utils import config as qconf
+    matpc = EVEN if param.matpc_type == "even-even" else ODD
+    return ((_ctx["gauge_epoch"], matpc, _antiperiodic(), int(param.Ls),
+             _pallas_enabled(on_tpu), _pallas_interpret(on_tpu),
+             hop_route_knobs(),
+             str(qconf.get("QUDA_TPU_DWF_FORM", fresh=True))),
+            (float(param.b5), float(param.c5), float(param.m5),
+             float(param.mass)))
+
+
+@partial(jax.jit, static_argnames=("geom", "static", "stores"))
+def _mobius_term_program(gauge, blocks, geom, static, stores):
+    """The resident Möbius pair operators of ``stores`` from the
+    resident gauge, ONE program: boundary fold, even-odd split, packing,
+    pairs at each storage dtype and the pre-shifted backward links.
+    The operators are pytrees, so they are what it returns."""
+    from ..models.domain_wall import DiracMobiusPCPairs
+    from ..ops import wilson as wops
+    from ..ops import wilson_packed as wpk
+    from ..ops.boundary import apply_t_boundary
+    matpc, ap, ls, use_pallas, interpret = static
+    links = wpk.pack_gauge_eo(wops.split_gauge_eo(
+        apply_t_boundary(gauge, geom, -1 if ap else 1), geom))
+    return {st: DiracMobiusPCPairs.from_packed(
+        geom, links, ls, blocks, matpc, st, use_pallas=use_pallas,
+        pallas_interpret=interpret, tb_sign=ap) for st in stores}
+
+
+def _resident_mobius(param: InvertParam, stores=()) -> dict:
+    """The Möbius packed pair operators of (resident gauge, matpc,
+    boundary, Ls, kernel route; b5, c5, M5, mf) at the storage dtypes
+    ``stores`` (f32 always).  The resident ones when both keys match
+    (``reused``); with the same links under other (b5, c5, M5, mf) the
+    four block pairs are made anew on the host and the links stay
+    (``rebuilt``); else everything is built from the resident gauge by
+    one program (``built`` when nothing was resident, ``rebuilt``
+    otherwise) and kept in ``_ctx``.  No canonical DiracMobius* is
+    constructed and no hop form is raced
+    (models/domain_wall.served_ls_hop_form)."""
+    from ..models import domain_wall as mdw
+    from ..obs import memory as omem
+    from ..obs import metrics as omet
+    from ..obs import trace as otr
+    on_tpu = jax.default_backend() == "tpu"
+    hop_key, block_key = _mobius_term_keys(param, on_tpu)
+    term = _ctx.get("mobius")
+    outcome = ("built" if term is None else "reused"
+               if (term["key"], term["blocks"]) == (hop_key, block_key)
+               else "rebuilt")
+    with otr.span("mobius_term", cat="setup", outcome=outcome):
+        stores = tuple(dict.fromkeys(
+            jnp.dtype(s) for s in (jnp.float32,) + tuple(stores)))
+        if outcome != "reused":
+            b5, c5, m5, mf = block_key
+            blocks = mdw.m5_block_pairs(hop_key[3], -m5, mf, b5, c5)
+            if term is not None and term["key"] == hop_key:
+                term = {"key": hop_key, "blocks": block_key, "sops": blocks,
+                        "ops": {st: op.with_blocks(blocks)
+                                for st, op in term["ops"].items()}}
+            else:
+                _drop_resident("mobius")
+                term = {"key": hop_key, "blocks": block_key,
+                        "sops": blocks, "ops": {}}
+        missing = tuple(st for st in stores if st not in term["ops"])
+        if missing:
+            _, matpc, ap, ls, use_pallas, interpret, _, _ = hop_key
+            built = _mobius_term_program(
+                _ctx["gauge"], term["sops"], _ctx["geom"],
+                (matpc, ap, ls, use_pallas, interpret), missing)
+            term["ops"].update(jax.block_until_ready(built))
+        if outcome != "reused" or missing:
+            _ctx["mobius"] = term
+            omem.track("mobius", _MOBIUS_FIELD, term["ops"])
+    omet.inc("mobius_term_total", outcome=outcome)
     return term
 
 
@@ -1400,7 +1504,73 @@ def _verified_exit(api: str, form: str, op, b, x_pp):
             return x_full, np.asarray(true_res)
 
 
+def _invert_mobius_resident(source, param: InvertParam):
+    """The Möbius 4d-PC CG solve on the resident pair operators
+    (``_resident_mobius``): entry (the 5-d source split by 4d parity,
+    ``prepare`` and ``Mdag``), the reliable-update CG on the normal
+    equations and the verified exit (reconstruction and the full 5-d
+    residual of what is returned) are one cached program each
+    (solvers/program.py), the links and the (Ls, Ls) blocks operands:
+    every (mf, M5, b5, c5) of one Ls shares the executables.  The first
+    trace of each stands on a stack chunk of its own (PERF.md section 7
+    (22))."""
+    from ..obs import convergence as oconv
+    from ..obs import trace as otr
+    from ..solvers import program as sprog
+    from ..utils import timer as qtimer
+    from ..utils.frames import on_a_stack_chunk_of_its_own as footed
+    api, inv = "invert_quda", param.inv_type
+    recording = otr.enabled()
+    b = jnp.asarray(source, complex_dtype(param.cuda_prec))
+    t0 = time.perf_counter()
+    with otr.phase("setup", api):
+        store = _pair_store(_resolve_sloppy(param))
+        term = _resident_mobius(param, (store,))
+        op = term["ops"][jnp.dtype(jnp.float32)]
+        form = _solve_form(op)
+        with otr.span("prepare", cat="setup") as span:
+            rhs, hit = footed(lambda: sprog.prepare(op, b))
+            _note_solve_program(span, api, form, "prepare", hit)
+    t_solve0 = time.perf_counter()
+    with otr.phase("compute", api), \
+            otr.span(f"solve:{inv}", cat="solver", tol=param.tol,
+                     maxiter=param.maxiter) as solve_span:
+        with otr.span("dispatch", cat="solver"):
+            res, hit = footed(lambda: sprog.cg_reliable(
+                op, term["ops"][jnp.dtype(store)], rhs, tol=param.tol,
+                maxiter=param.maxiter, delta=param.reliable_delta,
+                record=recording))
+        _note_solve_program(solve_span, api, form, inv, hit)
+        with otr.span("wait", cat="solver"):
+            jax.block_until_ready(res)
+    t_solve = time.perf_counter() - t_solve0
+    _record_solve_metrics(api, form, inv, t_solve, param.dslash_type,
+                          param.cuda_prec)
+    with otr.phase("epilogue", api):
+        x_full, true_res = footed(
+            lambda: _verified_exit(api, form, op, b, res.x))
+        param.iter_count = int(res.iters)
+        param.true_res = float(true_res)
+        param.secs = time.perf_counter() - t0
+        # DiracMobiusPC's count per updated site, two M an iteration
+        flops = 2 * 1320 + 3 * 96 * op.ls
+        sites = op.ls * _ctx["geom"].volume // 2
+        param.gflops = param.iter_count * 2.0 * flops * sites / 1e9
+        _solve_supervision(param, api, res.converged,
+                           getattr(res, "breakdown", None))
+    qtimer.add_flops(param.gflops * 1e9)
+    if recording:
+        oconv.publish(oconv.harvest(inv, res, tol=param.tol,
+                                    b2=float(blas.norm2(rhs))), param)
+    qlog.printq(
+        f"invert_quda[{param.dslash_type}/{inv}]: {param.iter_count} "
+        f"iters, true_res {param.true_res:.2e}, {param.secs:.2f} s")
+    return x_full
+
+
 def _invert_quda_body(source, param: InvertParam):
+    if _mobius_resident_route(param):
+        return _invert_mobius_resident(source, param)
     from .. import solvers
     from ..obs import convergence as oconv
     from ..obs import trace as otr

@@ -32,6 +32,8 @@ kernels needed.
 
 from __future__ import annotations
 
+import copy
+
 import jax
 import jax.numpy as jnp
 
@@ -40,7 +42,37 @@ from ..ops import wilson as wops
 from ..ops.boundary import apply_t_boundary
 from ..ops.dwf import SOp, apply_sop, identity_sop, m5_sop
 from .dirac import Dirac, DiracPC, MATPC_EVEN_EVEN, apply_gamma5
-from .wilson import _PackedHopMixin
+from .wilson import _PackedHopMixin, _ProgramOperand
+
+# The Ls-batched hop's two forms, read on the chip (one v5e, 24^4 x 12,
+# in the CG loop; PERF.md section 6, PR 42): an operator built
+# ``from_packed`` (the API's resident route) serves the winner WITHOUT
+# a race; ``QUDA_TPU_DWF_FORM=pallas|xla`` still pins.  The canonical
+# constructor keeps formsel's race (the eager route).
+MEASURED_LS_HOP_FORM = "pallas"
+
+
+def m5_block_pairs(ls: int, m5: float, mf: float, b5: float, c5: float):
+    """The four real (Ls, Ls) chirality-block pairs of the 4d-PC Möbius
+    operator as host arrays, (M5, M5', M5" = M5' M5^-1, M5^-1): what a
+    resident operator's leaves are made from (``m5`` positive, this
+    module's sign)."""
+    dw_diag = 4.0 - m5
+    s_m5 = m5_sop(ls, b5 * dw_diag + 1.0, c5 * dw_diag - 1.0, mf)
+    s_m5p = m5_sop(ls, b5, c5, mf)
+    s_m5i = s_m5.inv()
+    return s_m5, s_m5p, s_m5p @ s_m5i, s_m5i
+
+
+def _real_f32(block):
+    """One (Ls, Ls) chirality block as an f32 array; a host block must
+    be real (the pair-form s-operators assume it)."""
+    import numpy as np
+    if isinstance(block, np.ndarray) and np.iscomplexobj(block):
+        assert np.allclose(block.imag, 0), \
+            "pair-form s-ops assume real chirality blocks"
+        block = block.real
+    return jnp.asarray(block, jnp.float32)
 
 
 class DiracMobius(Dirac):
@@ -106,11 +138,9 @@ class DiracMobiusPC(DiracPC):
         g = apply_t_boundary(gauge, geom, -1 if antiperiodic_t else 1)
         self.antiperiodic_t = antiperiodic_t
         self.gauge_eo = wops.split_gauge_eo(g, geom)
-        dw_diag = 4.0 - m5
-        self.s_m5 = m5_sop(ls, b5 * dw_diag + 1.0, c5 * dw_diag - 1.0, mf)
-        self.s_m5p = m5_sop(ls, b5, c5, mf)
-        self.s_m5i = self.s_m5.inv()
-        self.s_mix = self.s_m5p @ self.s_m5i   # M5" = M5' M5^{-1} (commute)
+        # M5" = M5' M5^{-1} (they commute)
+        self.s_m5, self.s_m5p, self.s_mix, self.s_m5i = m5_block_pairs(
+            ls, m5, mf, b5, c5)
 
     def _hop_to(self, psi, target_parity):
         return jax.vmap(
@@ -188,7 +218,7 @@ class _LsPairIOMixin:
                 * sign.reshape(1, 4, 1, 1, 1, 1, 1)).astype(x.dtype)
 
 
-class DiracMobiusPCPairs(_LsPairIOMixin, _PackedHopMixin):
+class DiracMobiusPCPairs(_ProgramOperand, _LsPairIOMixin, _PackedHopMixin):
     """Complex-free packed pair-form of DiracMobiusPC (incl. EOFA).
 
     The domain-wall/Möbius analog of DiracWilsonPCPackedSloppy /
@@ -207,14 +237,22 @@ class DiracMobiusPCPairs(_LsPairIOMixin, _PackedHopMixin):
     Reference behavior: QUDA's Möbius solves run in float2/half native
     orders with the fused m5 kernels (lib/dslash_mdw_fused.in.cu); here
     the fusion of s-block x 4d-hop chains is XLA's job.
+
+    A solve-program operand (solvers/program.py): the links and the
+    four block pairs are the leaves, so one executable serves every
+    (mf, M5, b5, c5) of one Ls; Ls, the kernel route and the served hop
+    form are part of the static signature.
     """
 
     hermitian = False
 
+    _PROGRAM_ARRAYS = ("gauge_eo_pp", "_u_bw", "_gauge_q", "_gauge_s",
+                       "_m5", "_m5p", "_mix", "_m5i")
+    _PROGRAM_STATIC = _ProgramOperand._PROGRAM_STATIC + ("ls", "_op_form")
+
     def __init__(self, dpc: DiracMobiusPC, store_dtype=jnp.float32,
                  use_pallas: bool = False, pallas_interpret: bool = False,
                  form: str | None = None):
-        import numpy as np
         from ..ops import wilson_packed as wpk
         self._setup_hop(dpc.geom, wpk.pack_gauge_eo(dpc.gauge_eo),
                         store_dtype, use_pallas, pallas_interpret,
@@ -222,18 +260,7 @@ class DiracMobiusPCPairs(_LsPairIOMixin, _PackedHopMixin):
                                         True))
         self.ls = dpc.ls
         self.matpc = dpc.matpc
-
-        def blocks(sop):
-            ap, am = np.asarray(sop.ap), np.asarray(sop.am)
-            assert (np.allclose(np.imag(ap), 0)
-                    and np.allclose(np.imag(am), 0)), \
-                "pair-form s-ops assume real chirality blocks"
-            return (jnp.asarray(np.real(ap), jnp.float32),
-                    jnp.asarray(np.real(am), jnp.float32))
-
-        self._m5p = blocks(dpc.s_m5p)
-        self._mix = blocks(dpc.s_mix)
-        self._m5i = blocks(dpc.s_m5i)
+        self._set_blocks((dpc.s_m5, dpc.s_m5p, dpc.s_mix, dpc.s_m5i))
         from ..obs import memory as omem
         omem.track("dwf", "m5_pair_blocks",
                    self._m5p + self._mix + self._m5i)
@@ -243,6 +270,40 @@ class DiracMobiusPCPairs(_LsPairIOMixin, _PackedHopMixin):
             "dwf", form, self,
             race=lambda: formsel.race_ls_hop("dwf", self, aux=aux),
             aux=aux)
+
+    @classmethod
+    def from_packed(cls, geom, gauge_eo_packed, ls, blocks, matpc,
+                    store_dtype=jnp.float32, use_pallas: bool = False,
+                    pallas_interpret: bool = False, tb_sign: bool = True
+                    ) -> "DiracMobiusPCPairs":
+        """From the boundary-folded packed links alone
+        (wilson_packed.pack_gauge_eo) and the host block pairs
+        (``m5_block_pairs``): what a resident Möbius term is built
+        from, no canonical DiracMobiusPC in between.  The hop form is
+        the knob's pin, else ``MEASURED_LS_HOP_FORM`` wherever the
+        Ls-batched kernel can run (interpreted kernels keep the
+        vmapped stencil, as formsel does): nothing is raced."""
+        op = object.__new__(cls)
+        op._setup_hop(geom, gauge_eo_packed, store_dtype, use_pallas,
+                      pallas_interpret, tb_sign=tb_sign)
+        op.ls = int(ls)
+        op.matpc = matpc
+        op._set_blocks(blocks)
+        op._op_form = served_ls_hop_form(op)
+        return op
+
+    def _set_blocks(self, blocks):
+        """(M5, M5', M5", M5^-1), each a (+, -) chirality pair of real
+        (Ls, Ls) blocks (an SOp is one) -> the f32 leaves."""
+        self._m5, self._m5p, self._mix, self._m5i = (
+            tuple(_real_f32(m) for m in pair) for pair in blocks)
+
+    def with_blocks(self, blocks):
+        """The same resident links under other block pairs (leaves of
+        the pytree: another mf, M5, b5 or c5 shares the executable)."""
+        op = copy.copy(self)
+        op._set_blocks(blocks)
+        return op
 
     # -- building blocks ------------------------------------------------
     def _apply_blocks(self, blk, x, adjoint=False, out_dtype=None):
@@ -268,7 +329,10 @@ class DiracMobiusPCPairs(_LsPairIOMixin, _PackedHopMixin):
         version-aware eo stencil vmapped over the leading Ls axis
         (batch outermost — links re-fetched per plane)."""
         odt = out_dtype or self.store_dtype
-        if (form or self._op_form) == "pallas":
+        form = form or self._op_form
+        from ..obs import metrics as omet
+        omet.inc("dwf_hop_route_total", form=form, ls=str(self.ls))
+        if form == "pallas":
             from ..ops import dwf_pallas as dwp
             return dwp.dslash_eo_pallas_packed_ls(
                 self.gauge_eo_pp[target_parity],
@@ -322,32 +386,99 @@ class DiracMobiusPCPairs(_LsPairIOMixin, _PackedHopMixin):
                                 x.dtype)
 
     # -- prepare / reconstruct in pair space ----------------------------
+    def _m5i_plus_half_hop(self, b_pp, blk, v_pp, parity):
+        """M5i (b + 1/2 hop_to(parity) blk v) on pair arrays, f32: with
+        (b_p, M5", b_q, p) it is ``prepare``, with (b_q, M5', x_p,
+        1 - p) the other parity's solution."""
+        t = self._hop_to_pairs(self._apply_blocks(blk, v_pp), parity,
+                               out_dtype=jnp.float32)
+        return self._apply_blocks(
+            self._m5i, b_pp.astype(jnp.float32) + 0.5 * t,
+            out_dtype=jnp.float32)
+
     def prepare_pairs(self, b_even, b_odd):
         """Canonical complex parity-split 5d sources -> pair-form PC rhs
         (mirrors DiracMobiusPC.prepare)."""
         p = self.matpc
         b_p, b_q = (b_even, b_odd) if p == EVEN else (b_odd, b_even)
-        bp_pp, bq_pp = self._to_pairs(b_p), self._to_pairs(b_q)
-        t = self._hop_to_pairs(self._apply_blocks(self._mix, bq_pp), p,
-                               out_dtype=jnp.float32)
-        rhs = self._apply_blocks(
-            self._m5i, bp_pp.astype(jnp.float32) + 0.5 * t,
-            out_dtype=jnp.float32)
-        return rhs.astype(self.store_dtype)
+        return self._m5i_plus_half_hop(
+            self._to_pairs(b_p), self._mix, self._to_pairs(b_q),
+            p).astype(self.store_dtype)
 
     def reconstruct_pairs(self, x_pp, b_even, b_odd):
         """Pair-form PC solution -> canonical complex (x_even, x_odd)
         (mirrors DiracMobiusPC.reconstruct)."""
         p = self.matpc
         b_q = b_odd if p == EVEN else b_even
-        t = self._hop_to_pairs(self._apply_blocks(self._m5p, x_pp), 1 - p,
-                               out_dtype=jnp.float32)
-        xq_pp = self._apply_blocks(
-            self._m5i, self._to_pairs(b_q).astype(jnp.float32) + 0.5 * t,
-            out_dtype=jnp.float32)
+        xq_pp = self._m5i_plus_half_hop(self._to_pairs(b_q), self._m5p,
+                                        x_pp, 1 - p)
         x_p = self._from_pairs(x_pp, b_q.dtype)
         x_q = self._from_pairs(xq_pp, b_q.dtype)
         return (x_p, x_q) if p == EVEN else (x_q, x_p)
+
+    # -- the API's entry and verified exit, each meant to be traced as
+    # -- ONE program on the f32 operator (solvers/program.py) ----------
+    def _split_pairs(self, b):
+        """Canonical full 5d field (Ls,T,Z,Y,X,4,3) -> its (p, q)
+        parity halves in pair form, f32."""
+        from ..fields.spinor import even_odd_split
+        halves = jax.vmap(lambda v: even_odd_split(v, self.geom))(b)
+        b_p, b_q = halves if self.matpc == EVEN else halves[::-1]
+        return (self._to_pairs(b_p).astype(jnp.float32),
+                self._to_pairs(b_q).astype(jnp.float32))
+
+    def prepare_normal_pairs(self, b):
+        """The entry of a normal-equation solve: the canonical full 5d
+        source, split by 4d parity, through ``prepare`` and ``Mdag``:
+        the right-hand side of MdagM x_p = Mdag b', pair form."""
+        b_p, b_q = self._split_pairs(b)
+        return self.Mdag_pairs(self._m5i_plus_half_hop(
+            b_p, self._mix, b_q, self.matpc).astype(self.store_dtype))
+
+    def verified_exit_pairs(self, b, x_pp):
+        """The API's verified exit on the pair representation: the
+        canonical full 5d source ``b`` (Ls,T,Z,Y,X,4,3) and the
+        pair-form PC solution -> (canonical full 5d solution,
+        |b - M x| / |b|).  x_q = M5i (b_q + 1/2 hop M5' x_p) is the
+        reconstruction; the residual is that of the RETURNED solution
+        under the full M = M5 - 1/2 hop M5', parity by parity with this
+        operator's own hop and blocks in f32: no canonical (...,4,3)
+        temporary beyond the two boundaries."""
+        from ..fields.spinor import even_odd_join
+        f32, p = jnp.float32, self.matpc
+        blk = lambda m, v: self._apply_blocks(m, v, out_dtype=f32)
+        hop = lambda v, par: self._hop_to_pairs(v, par, out_dtype=f32)
+        b_p, b_q = self._split_pairs(b)
+        x_p = x_pp.astype(f32)
+        # hop M5' x_p serves the reconstruction and the q half of M x
+        h_p = hop(blk(self._m5p, x_p), 1 - p)
+        x_q = blk(self._m5i, b_q + 0.5 * h_p)
+        r_p = b_p - (blk(self._m5, x_p)
+                     - 0.5 * hop(blk(self._m5p, x_q), p))
+        r_q = b_q - (blk(self._m5, x_q) - 0.5 * h_p)
+        norm2 = lambda v: jnp.sum(v * v)
+        x_e, x_o = (self._from_pairs(v, b.dtype)
+                    for v in ((x_p, x_q) if p == EVEN else (x_q, x_p)))
+        x = jax.vmap(lambda e, o: even_odd_join(e, o, self.geom))(x_e, x_o)
+        return x, jnp.sqrt((norm2(r_p) + norm2(r_q))
+                           / (norm2(b_p) + norm2(b_q)))
+
+
+jax.tree_util.register_pytree_node_class(DiracMobiusPCPairs)
+
+
+def served_ls_hop_form(op) -> str:
+    """The hop form a resident Möbius operator serves: the knob's pin
+    (``QUDA_TPU_DWF_FORM=pallas|xla``), else the chip's measured winner
+    wherever the Ls-batched kernel can run natively; never a race."""
+    from ..utils import config as qconf
+    from . import formsel
+    req = str(qconf.get(formsel.KNOBS["dwf"], fresh=True))
+    if formsel.fused_capable(op) is not None:
+        return "xla"
+    if req in ("pallas", "xla"):
+        return req
+    return "xla" if op._pallas_interpret else MEASURED_LS_HOP_FORM
 
 
 # ---------------------------------------------------------------------------

@@ -228,6 +228,25 @@ METRICS: dict[str, dict] = {
                 "'reused' the resident operators served, 'rebuilt' "
                 "another matpc, boundary or kernel route replaced them; "
                 "new fat / long links or a new gauge drop them"},
+    "mobius_term_total": {
+        "type": COUNTER,
+        "help": "uses of the resident Möbius pair operators (mobius "
+                "invert_quda on the 4d-PC CG pair route) by outcome: "
+                "'built' nothing was resident, 'reused' the resident "
+                "operators served, 'rebuilt' another (b5, c5, M5, mf) "
+                "replaced the four (Ls, Ls) block pairs on the same "
+                "links, or another matpc, boundary, Ls or kernel route "
+                "replaced everything; a new gauge drops them"},
+    "dwf_hop_route_total": {
+        "type": COUNTER,
+        "help": "traced calls of the Möbius pair operator's 4-d hop over "
+                "its s-slices "
+                "(models/domain_wall.DiracMobiusPCPairs._hop_to_pairs) "
+                "by form: 'pallas' the multi-RHS Wilson kernel with Ls "
+                "on its source axis (counted by route in "
+                "wilson_mrhs_route_total too), 'xla' jax.vmap of the "
+                "single-slice stencil; and by ls, the planes a call "
+                "had"},
     "wilson_mrhs_route_total": {
         "type": COUNTER,
         "help": "traced calls of the multi-RHS Wilson kernel "

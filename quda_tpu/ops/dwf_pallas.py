@@ -17,10 +17,12 @@ fit VMEM the full-Z route reads each spinor plane twice, 288 + 576/Ls
 bytes per site per plane (three times, 384 + 576/Ls, where only one
 slice fits); larger local volumes fall back to z-blocks and five
 reads, 576 + 576/Ls.  The route follows the shapes, Ls included
-nowhere: these wrappers pass ``block_z`` through and nothing else.  No
-cell runs a 5d operator: the chip reading of this seam is the Wilson
-batch's (PERF.md section 6, PR 31: eight planes 1,070 us at 24^4,
-twelve 1,518).
+nowhere: these wrappers pass ``block_z`` through and nothing else.  The
+benchmark's cell ``mobius24_single.strange`` runs this seam at 24^4 x 12
+(PERF.md section 6, PR 42: twelve bf16 planes 1,467 us a hop in the CG
+loop, 1,669 alone; the vmapped stencil 1,995, in f32 4,179 against
+1,855): the API's resident Möbius route serves it without a race
+(``models/domain_wall.MEASURED_LS_HOP_FORM``).
 
 The dense (Ls, Ls) m5 algebra (ops/dwf.py SOp blocks, applied as
 einsum GEMMs in models/domain_wall) stays in XLA: it is

@@ -29,8 +29,8 @@ in-process executable lookup.
   ``multishift.update_form``).  The key holds no array and no operator
   identity.
 
-The verified exit of the Wilson and staggered pair routes is a program
-of the same kind (``verified_exit``): from the canonical source and the
+The verified exit of the Wilson, staggered and Möbius pair routes is a
+program of the same kind (``verified_exit``): from the canonical source and the
 pair-form solution to the canonical solution and its true residual, the
 resident f32 pair operator an operand.  ``prepare`` is the entry's: the
 canonical source, split by parity, to the pair-form PC right-hand side.
@@ -225,6 +225,9 @@ def verified_exit(op, b, x_pp):
 def _prepare_program(op, b):
     _traces[0] += 1
     from ..fields.spinor import even_odd_split
+    if hasattr(op, "prepare_normal_pairs"):
+        # a 5-d operator: the leading axis is Ls, never a batch
+        return op.prepare_normal_pairs(b)
     if b.ndim == 7:
         return op.prepare_pairs_mrhs(
             *jax.vmap(lambda v: even_odd_split(v, op.geom))(b))
@@ -236,5 +239,8 @@ def prepare(op, b):
     full-lattice source, split by parity and through
     ``op.prepare_pairs``, to the pair-form PC right-hand side, as one
     cached program; a batch of sources (a leading axis) through
-    ``op.prepare_pairs_mrhs``.  Returns ``(rhs, hit)``."""
+    ``op.prepare_pairs_mrhs``; a 5-d operator's
+    ``prepare_normal_pairs`` (the Möbius pair operator: the split over
+    every s-slice, ``prepare`` and ``Mdag``, so what comes back is the
+    normal equations' right-hand side).  Returns ``(rhs, hit)``."""
     return _run(_prepare_program, op, b)

@@ -260,7 +260,11 @@ _register("QUDA_TPU_DWF_FORM", "choice", "auto",
           "v2 kernel (ops/dwf_pallas — Ls innermost, gauge tile "
           "fetched once per (t, z-block) while Ls spinor planes stream "
           "through: 576+576/Ls B/site/plane), 'xla' = the vmap-over-s "
-          "stencil, 'auto' = race and cache per (volume, dtype, Ls). "
+          "stencil, 'auto' = race and cache per (volume, dtype, Ls) "
+          "where the operator is built per call from canonical arrays; "
+          "the API's resident Möbius route never races: 'pallas' / "
+          "'xla' pin, anything else serves the chip's measured winner "
+          "(models/domain_wall.MEASURED_LS_HOP_FORM). "
           "The dense (Ls,Ls) m5 algebra stays XLA-batched either way. "
           "Read at operator construction only, hence NOT trace-safe",
           ("", "auto", "pallas", "xla"),

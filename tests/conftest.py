@@ -144,12 +144,13 @@ FILE_SECONDS = {
     "test_domain_wall.py": 240, "test_clover_resident.py": 240,
     "test_mixed.py": 220, "test_wilson_resident.py": 200,
     "test_twisted.py": 180, "test_interface.py": 180,
-    "test_pair_gauge.py": 180, "test_chip_compile.py": 170,
+    "test_pair_gauge.py": 180, "test_chip_compile.py": 190,
     "test_pair_eig.py": 140, "test_serve.py": 140,
     "test_pallas_sharded.py": 120, "test_ks_resident.py": 110,
     "test_staggered_mg.py": 90, "test_packed.py": 80, "test_madwf.py": 80,
     "test_eig.py": 80, "test_milc_rhmc.py": 80, "test_mg_3level.py": 80,
     "test_mg_gemm_coarse.py": 70, "test_clover.py": 70, "test_live.py": 70,
+    "test_mobius_resident.py": 70,
     "test_heatbath.py": 60, "test_schwarz.py": 60, "test_solvers.py": 60,
     "test_smear_force.py": 50, "test_mg.py": 50, "test_parallel.py": 50,
 }

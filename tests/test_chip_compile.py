@@ -726,3 +726,74 @@ def test_hisq_multishift_programs_compile_for_v5e(one_chip, program):
     big = [c for c in _hlo_values(hlo, "constant") if c[0] > 2 ** 20]
     assert not big, f"fields baked into the executable: {big}"
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
+MOBIUS_LS = 12
+
+
+@pytest.mark.parametrize("program", ["solve", "verified-exit"])
+def test_mobius_programs_compile_for_v5e_under_14_gib(one_chip, program):
+    """The solve and exit programs of the Möbius resident route
+    (solvers/program.py on DiracMobiusPCPairs, the operators the shapes
+    interfaces/quda_api._mobius_term_program returns) at 24^4 x Ls 12,
+    the cell's size: each compiles
+    for the described chip with the Ls-batched kernel serving the hop
+    (``MEASURED_LS_HOP_FORM``: the MRHS Wilson kernel, twelve planes on
+    its source axis, f32 and bf16), the links and the (Ls, Ls) blocks
+    parameters (nothing the size of a field a constant), and arguments,
+    results and temporaries together under 14 GiB of the chip's 15.75:
+    the canonical 5-d source and solution of the exit are 1.9 GiB each
+    as the chip tiles them (a 24-wide minor axis), the rule on the peak
+    of ISSUE 42 (the term and entry programs compile in a scratch run
+    to 1.8 and 6.2 GiB: PERF.md, PR 42)."""
+    from quda_tpu.fields.geometry import LatticeGeometry
+    from quda_tpu.interfaces import quda_api as api
+    from quda_tpu.models import domain_wall as mdw
+    from quda_tpu.solvers import mixed
+    from quda_tpu.solvers import program as sprog
+    geom = LatticeGeometry(DIMS)
+    blocks = mdw.m5_block_pairs(MOBIUS_LS, 1.8, 0.03, 1.5, 0.5)
+    static = (0, True, MOBIUS_LS, True, False)
+    stores = (jnp.dtype(F32), jnp.dtype(BF16))
+    on_chip = lambda tree: jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip,
+                                       weak_type=s.weak_type), tree)
+
+    def lower():
+        g = jax.ShapeDtypeStruct((4,) + DIMS + (3, 3), jnp.complex64,
+                                 sharding=one_chip)
+        ops = on_chip(jax.eval_shape(
+            lambda g: api._mobius_term_program(g, blocks, geom, static,
+                                               stores), g))
+        hi, lo = ops[stores[0]], ops[stores[1]]
+        assert hi._op_form == lo._op_form == mdw.MEASURED_LS_HOP_FORM
+        b = jax.ShapeDtypeStruct((MOBIUS_LS,) + DIMS + (4, 3),
+                                 jnp.complex64, sharding=one_chip)
+        x = jax.ShapeDtypeStruct(*_psi(F32, (MOBIUS_LS,)),
+                                 sharding=one_chip)
+        if program == "verified-exit":
+            return sprog._verified_exit_program.lower(hi, b, x)
+        key = (0.1, mixed.pair_inplace_config(BF16, False, False),
+               sprog._LoopKnobs(False, None, None, None), False)
+        return sprog._cg_reliable_program.lower(hi, lo, x, 1e-6, 10000,
+                                                key=key)
+    compiled = _aot(lower)
+    ma = compiled.memory_analysis()
+    peak = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes)
+    assert peak < 14 * 2 ** 30, ma
+    hlo = compiled.as_text()
+    big = [c for c in _hlo_values(hlo, "constant") if c[0] > 2 ** 20]
+    assert not big, f"fields baked into the executable: {big}"
+    import re
+    hops = re.findall(r"%dslash_eo_pallas_packed_mrhs[.\d]* = (\w+)\["
+                      r"[^\n]*tpu_custom_call", hlo)
+    # exit: the hop the reconstruction and M x share, and the other
+    # parity's; the solve: four a sloppy MdagM, four a precise one
+    if program == "verified-exit":
+        assert hops == ["f32"] * 2, hops
+    else:
+        assert hops.count("bf16") >= 2 and "f32" in hops, hops
+    links = ",".join(str(d) for d in _links(F32)[0])
+    params = _hlo_values(hlo, "parameter")
+    assert sum(p[1:] == ("f32", links) for p in params) == 4
