@@ -134,25 +134,29 @@ def pytest_collection_modifyitems(config, items):
 # same hour).  So under `--dist load` the scheduler is xdist's own
 # loadfile one, made to hand out the heaviest file that is left: longest
 # first keeps the last worker's tail short.  FILE_SECONDS is each
-# file's test time in that 1,105 s run, to the nearest ten, for files
-# of 50 s and more.  It only orders the hand-out: a stale or missing
-# entry costs balance and nothing else.
+# file's test time in PR 43's whole run (1,194 s, 7,035 test-seconds on
+# six workers), to the nearest ten, for files of 50 s and more.  It
+# only orders the hand-out: a stale or missing entry costs balance and
+# nothing else.
 FILE_SECONDS = {
-    "test_solve_program.py": 770, "test_multirhs.py": 810,
-    "test_staggered_pallas.py": 380, "test_pallas.py": 380,
-    "test_pair_mg.py": 300, "test_precision_forms.py": 290,
-    "test_domain_wall.py": 240, "test_clover_resident.py": 240,
-    "test_mixed.py": 220, "test_wilson_resident.py": 200,
-    "test_twisted.py": 180, "test_interface.py": 180,
-    "test_pair_gauge.py": 180, "test_chip_compile.py": 190,
-    "test_pair_eig.py": 140, "test_serve.py": 140,
-    "test_pallas_sharded.py": 120, "test_ks_resident.py": 110,
-    "test_staggered_mg.py": 90, "test_packed.py": 80, "test_madwf.py": 80,
-    "test_eig.py": 80, "test_milc_rhmc.py": 80, "test_mg_3level.py": 80,
-    "test_mg_gemm_coarse.py": 70, "test_clover.py": 70, "test_live.py": 70,
-    "test_mobius_resident.py": 70,
-    "test_heatbath.py": 60, "test_schwarz.py": 60, "test_solvers.py": 60,
-    "test_smear_force.py": 50, "test_mg.py": 50, "test_parallel.py": 50,
+    "test_solve_program.py": 630, "test_multirhs.py": 470,
+    "test_staggered_pallas.py": 420, "test_multirhs_kernels.py": 400,
+    "test_pallas.py": 360, "test_pair_mg.py": 360,
+    "test_precision_forms.py": 240, "test_clover_resident.py": 240,
+    "test_domain_wall.py": 240, "test_chip_compile.py": 220,
+    "test_mixed.py": 210, "test_wilson_resident.py": 170,
+    "test_interface.py": 170, "test_pair_gauge.py": 170,
+    "test_twisted.py": 160, "test_serve.py": 150, "test_pair_eig.py": 130,
+    "test_ks_resident.py": 120, "test_mobius_resident.py": 110,
+    "test_live.py": 100, "test_staggered_mg.py": 90, "test_madwf.py": 90,
+    "test_eig.py": 90, "test_mg_3level.py": 90, "test_milc_rhmc.py": 80,
+    "test_mg_gemm_coarse.py": 80, "test_packed.py": 80,
+    "test_pallas_sharded.py": 80, "test_clover.py": 80,
+    "test_heatbath.py": 70, "test_build_accounting.py": 70,
+    "test_schwarz.py": 70, "test_smear_force.py": 60,
+    "test_parallel.py": 60, "test_solvers.py": 60,
+    "test_multishift_resident.py": 60, "test_multishift.py": 60,
+    "test_metrics.py": 50, "test_eigcg_gmresdr.py": 50,
 }
 OTHER_FILE_SECONDS = 20
 

@@ -54,11 +54,13 @@ def test_pallas_packed_matches_xla_packed(shape, antiperiodic):
     assert np.allclose(got, want, atol=3e-6 * np.max(np.abs(want)))
 
 
-@pytest.mark.parametrize("bz", [1, 2])
+@pytest.mark.parametrize(
+    "bz", [pytest.param(1, marks=pytest.mark.slow), 2])
 def test_pallas_packed_multi_z_block(bz):
     """The z-blocked grid (the configuration the 24^4 headline bench
     runs: nzb > 1) splices boundary rows from neighbouring z-blocks —
-    must bit-match the single-block kernel."""
+    must bit-match the single-block kernel.  Tier-1 keeps three blocks
+    of two rows; six of one (40 s of compile in a whole run) is slow."""
     from quda_tpu.fields.geometry import LatticeGeometry
     from quda_tpu.fields.gauge import GaugeField
     from quda_tpu.fields.spinor import ColorSpinorField
@@ -151,7 +153,8 @@ def test_pallas_eo_operator_in_cg():
     assert err < 1e-5
 
 
-@pytest.mark.parametrize("antiperiodic", [True, False])
+@pytest.mark.parametrize(
+    "antiperiodic", [True, pytest.param(False, marks=pytest.mark.slow)])
 @pytest.mark.parametrize("kernel", ["full_lattice", "eo"])
 def test_pallas_recon12_matches_full(kernel, antiperiodic):
     """Reconstruct-12 storage (rows 0-1 + in-kernel cross-product third
@@ -160,7 +163,8 @@ def test_pallas_recon12_matches_full(kernel, antiperiodic):
     folded antiperiodic-t phase (whose sign must be re-applied to the
     reconstructed row: at t = T-1 on the forward links, at t = 0 on the
     pre-shifted backward ones), on the full-lattice and the even-odd
-    kernel."""
+    kernel.  Tier-1 keeps the antiperiodic cases, the same kernels with
+    the sign planes live; each is a 20-30 s compile."""
     from quda_tpu.fields.spinor import even_odd_split
     from quda_tpu.ops import blas
     from quda_tpu.ops import wilson_packed as wpk

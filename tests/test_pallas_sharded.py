@@ -265,7 +265,8 @@ def _run_sharded_eo_v2(dims, g_eo_pp, parity, src_pp, policy,
     return jax.jit(fn)(uh_s, ub_s, src_s)
 
 
-@pytest.mark.parametrize("parity", [0, 1])
+@pytest.mark.parametrize(
+    "parity", [0, pytest.param(1, marks=pytest.mark.slow)])
 def test_sharded_wilson_eo_v2_matches_single_device(parity):
     """THE round-8 acceptance test: the v2 (gather, pre-shifted backward
     links) eo kernel — the measured single-chip winner, PERF.md round 5
@@ -275,9 +276,9 @@ def test_sharded_wilson_eo_v2_matches_single_device(parity):
     if len(jax.devices()) < 4:
         pytest.skip("needs >= 4 virtual devices")
     # tiny geometry + a 2x2 grid over 4 devices: the interpret-mode
-    # compile dominates, and this test must stay inside the 30s
-    # non-slow budget (tier-1 wall clock) — the 4-shard/edge-sign
-    # coverage lives in the slow recon-12 variants below
+    # compile dominates (50 s a parity in a whole run, so tier-1 keeps
+    # one) — the 4-shard/edge-sign coverage lives in the slow recon-12
+    # variants below
     dims, g_eo_pp, (pe, po) = _eo_fixture(shape=(4, 4, 4, 8))
     src = pe if parity == 1 else po
     src_pp = wpk.to_packed_pairs(wpk.pack_spinor(src), jnp.float32)

@@ -18,6 +18,8 @@ Bitwise claims are exact by construction and asserted exactly:
   decompress-at-setup route built from the same (q, scale) pair.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -153,26 +155,31 @@ def test_wilson_precision_mrhs_matches_single(form, n):
         assert np.array_equal(ob[i], oi), (form, n, i)
 
 
+@functools.lru_cache(maxsize=None)
+def _staggered_fused_out(pform, parity):
+    """(the fused hop of the ``pform`` operator on one seeded field, the
+    operator), once per process: both forms are held to the same
+    ``full`` reference, an interpreted compile of its own."""
+    T, Z, Y, X = GEOM.lattice_shape
+    psi = _psi((3, 2, T, Z, Y * X // 2), seed=7)
+    op = _staggered_dpc().pairs(jnp.float32, use_pallas=True,
+                                pallas_interpret=True, form="fused",
+                                precision_form=pform)
+    # XLA:CPU's fusion emitters (jax 0.9.0) round a multiply-add chain
+    # by where the fusion boundary falls, which the fold layout moves;
+    # the bit-match is about the kernels' adds, so compile without.
+    return np.asarray(jax.jit(
+        lambda p: op.D_to_pairs(p, parity, jnp.float32),
+        compiler_options={"xla_cpu_use_fusion_emitters": False})(psi)), op
+
+
 @pytest.mark.parametrize(
     "parity", [0, pytest.param(1, marks=pytest.mark.slow)])
 @pytest.mark.parametrize("pform", ["r12", "fold"])
 def test_staggered_fused_precision_forms_match_full(pform, parity):
-    dpc = _staggered_dpc()
-    T, Z, Y, X = GEOM.lattice_shape
-    psi = _psi((3, 2, T, Z, Y * X // 2), seed=7)
-    ref_op = dpc.pairs(jnp.float32, use_pallas=True,
-                       pallas_interpret=True, form="fused",
-                       precision_form="full")
-    op = dpc.pairs(jnp.float32, use_pallas=True, pallas_interpret=True,
-                   form="fused", precision_form=pform)
+    ref, _ = _staggered_fused_out("full", parity)
+    out, op = _staggered_fused_out(pform, parity)
     assert op._precision_form == pform
-    # XLA:CPU's fusion emitters (jax 0.9.0) round a multiply-add chain
-    # by where the fusion boundary falls, which the fold layout moves;
-    # the bit-match is about the kernels' adds, so compile without.
-    ref, out = (np.asarray(jax.jit(
-        lambda p, o=o: o.D_to_pairs(p, parity, jnp.float32),
-        compiler_options={"xla_cpu_use_fusion_emitters": False})(psi))
-        for o in (ref_op, op))
     if pform == "fold":
         assert np.array_equal(out, ref)
     else:

@@ -3,13 +3,20 @@ traced once per process per key, and everything that differs from call
 to call is an operand.
 
 Every count is read from the ``solve_program_total`` counter the
-mechanism itself reports (obs/metrics).  8^4, interpret-mode kernels,
-through ``invert_quda`` (mixed f32/bf16 reliable CG on the Wilson packed
-pair operator) and ``invert_multi_src_quda`` (the f32 batched-pairs
-route).  A miss costs the CPU a 20-40 s compile of interpreted kernels,
-so the first call of each route is made once per worker, in a fixture,
-and what does not need the kernels (the comparison with the eager
-solver, the operand's own tests) runs on the XLA pair stencil at 4^4.
+mechanism itself reports (obs/metrics).  4 x 8 x 2 x 4 (the smallest
+lattice the packed pair kernels' full-Z route takes), interpret-mode
+kernels, through ``invert_quda`` (mixed f32/bf16 reliable CG on the
+Wilson packed pair operator) and ``invert_multi_src_quda`` (the f32
+batched-pairs route).  A miss costs the CPU 40-60 s, whatever the
+lattice: ten seconds of lowering for every interpreted kernel in the
+program, and XLA's compile.  So the first call of each route is made
+once per worker, in a fixture, and what does not need the kernels runs
+on the XLA pair stencil: the comparison with the eager solver and the
+operand's own tests at 4^4, and the batched route's flipped knobs
+through the same API call with ``QUDA_TPU_PALLAS=0`` (a miss there is
+XLA's 20 s and no kernel's lowering; ``invert_quda``'s Wilson route off
+the kernels is the eager solver and reaches no program, so its flips
+stay on the kernels).
 
 Both routes solve on the resident Wilson pair operators
 (``wilson_term_total``) and leave through the verified-exit program
@@ -32,7 +39,9 @@ from quda_tpu.solvers import program as sprog
 from quda_tpu.utils import config as qconf
 from tests.host_reference.wilson_ref import wilson_mat_ref
 
-L = 8
+DIMS = (4, 2, 8, 4)                     # (x, y, z, t)
+LAT = tuple(reversed(DIMS))             # array order (T, Z, Y, X)
+HALF = LAT[:2] + (LAT[2] * LAT[3] // 2,)
 KAPPA = 0.12
 ROUTES = ("single", "multi")
 KEY = {"single": dict(api="invert_quda", form="wilson_v2", solver="cg"),
@@ -45,13 +54,13 @@ def _gauge(seed):
     from quda_tpu.fields.gauge import GaugeField
     from quda_tpu.fields.geometry import LatticeGeometry
     g = GaugeField.random(jax.random.PRNGKey(seed),
-                          LatticeGeometry((L,) * 4))
+                          LatticeGeometry(DIMS))
     return np.asarray(g.data.astype(jnp.complex64))
 
 
 def _sources(seed, n):
     rng = np.random.default_rng(seed)
-    shape = (n, L, L, L, L, 4, 3)
+    shape = (n,) + LAT + (4, 3)
     return (rng.standard_normal(shape)
             + 1j * rng.standard_normal(shape)).astype(np.complex64)
 
@@ -115,6 +124,10 @@ def _host_residual(gauge, b, x, kappa):
     return float(np.linalg.norm(r) / np.linalg.norm(b))
 
 
+def _load(gauge):
+    api.load_gauge_quda(gauge, GaugeParam(X=DIMS, cuda_prec="single"))
+
+
 @pytest.fixture(scope="module")
 def gauges():
     return {"A": _gauge(11), "B": _gauge(12)}
@@ -138,8 +151,7 @@ def quda(gauges, tmp_path_factory):
     otr.stop(flush_files=False)
     api.init_quda()
     omet.start(str(tmp_path_factory.mktemp("solve_program")))
-    api.load_gauge_quda(gauges["A"], GaugeParam(X=(L,) * 4,
-                                                cuda_prec="single"))
+    _load(gauges["A"])
     yield
     omet.stop(flush_files=False)
     api.end_quda()
@@ -167,8 +179,9 @@ def warm(route, first_calls):
 
 @pytest.fixture
 def knobs(monkeypatch):
-    """Set or clear knobs for one test; everything is put back (and the
-    fault registry disarmed) after it."""
+    """Set or clear knobs for one test; everything is put back (the
+    fault registry disarmed, the kernel route's resident term built
+    again if the test left the route) after it."""
     def set_(**env):
         for k, v in env.items():
             if v is None:
@@ -182,6 +195,7 @@ def knobs(monkeypatch):
     qconf.reset_cache()
     finj.reset()
     otr.stop(flush_files=False)
+    api._resident_wilson(_param())
 
 
 # (a), (d): sources and links are operands ------------------------------------
@@ -192,13 +206,11 @@ def test_new_source_and_new_gauge_reuse_the_program(route, warm, gauges):
     before, before_exit = _counts(route), _counts(route, EXIT)
     b2, x2, p2 = _solve(route, seed=2)
     try:
-        api.load_gauge_quda(gauges["B"], GaugeParam(X=(L,) * 4,
-                                                    cuda_prec="single"))
+        _load(gauges["B"])
         terms = _term_counts()
         b3, x3, p3 = _solve(route, seed=3)
     finally:
-        api.load_gauge_quda(gauges["A"], GaugeParam(X=(L,) * 4,
-                                                    cuda_prec="single"))
+        _load(gauges["A"])
     assert _delta(route, before) == (0, 2)
     assert _delta(route, before_exit, EXIT) == (0, 2)
     # the resident term went with gauge A: the second call built its own
@@ -266,8 +278,7 @@ def test_batched_route_operator_combines_in_its_second_hops(quda, method,
     op = api._resident_wilson(_param())["ops"][jnp.dtype(jnp.float32)]
     op = op.with_kappa(KAPPA)
     before = _mrhs_route_counts()
-    batch = jax.ShapeDtypeStruct((n, 4, 3, 2, L, L, L * L // 2),
-                                 jnp.float32)
+    batch = jax.ShapeDtypeStruct((n, 4, 3, 2) + HALF, jnp.float32)
     lanes = jax.ShapeDtypeStruct((n,), jnp.float32)
     want = {("fullz", "none", "none"): 1, ("fullz", "combine", "norm2"): 1}
     if method == "MdagM_pairs_mrhs":
@@ -299,7 +310,7 @@ def test_second_kappa_and_batch_are_a_hit_of_the_batched_program(
     op = api._resident_wilson(_param())["ops"][jnp.dtype(jnp.float32)]
     other = op.with_kappa(0.105)
     b = jnp.asarray(np.random.default_rng(21).standard_normal(
-        (2, 4, 3, 2, L, L, L * L // 2)), jnp.float32)
+        (2, 4, 3, 2) + HALF), jnp.float32)
     before = _mrhs_route_counts()
     res, hit = sprog.batched_cg_pairs(other, b, tol=1e-6, maxiter=500)
     assert hit and _mrhs_route_delta(before) == {}
@@ -324,12 +335,8 @@ def test_kernel_pAp_stops_the_batched_solve_where_the_dot_does(
     b, x, p = _solve(route, seed=12)
     assert _delta(route, before) == (0, 1) and p.converged
     iters = list(p.iter_count_multi)
-    try:
-        knobs(QUDA_TPU_PALLAS="0")
-        _, x_dot, p_dot = _solve(route, seed=12)
-    finally:
-        knobs(QUDA_TPU_PALLAS="1")
-        api._resident_wilson(_param())      # the kernel route's, again
+    knobs(QUDA_TPU_PALLAS="0")
+    _, x_dot, p_dot = _solve(route, seed=12)
     assert p_dot.converged
     assert all(abs(i - j) <= 2
                for i, j in zip(iters, p_dot.iter_count_multi))
@@ -339,10 +346,6 @@ def test_kernel_pAp_stops_the_batched_solve_where_the_dot_does(
 
 
 # the resident term and the verified exit, through the API -----------------
-
-def _load(gauge):
-    api.load_gauge_quda(gauge, GaugeParam(X=(L,) * 4, cuda_prec="single"))
-
 
 @pytest.mark.parametrize("route", ROUTES)
 def test_load_then_two_solves_build_once_and_trace_the_exit_once(
@@ -433,15 +436,28 @@ FLIPS = {
 # Sentinel, fault and record are resolved by one function for both
 # programs (program._loop_knobs), and a miss on the single-source route
 # compiles twice as long: there the sentinel and the fault flip
-# together, and one at a time on the batched route.
+# together, and one at a time on the batched route.  What is tested is
+# the key, not a kernel: the batched route's calls run on the XLA pair
+# stencil (the same API call reaches the same program with
+# ``use_pallas`` False in the operator's signature; invert_quda's
+# Wilson route off the kernels reaches no program).
 @pytest.mark.parametrize("route,flip", [
-    ("single", "fused_tail"), ("single", "robust+fault"),
-    ("single", "record"),
+    ("single", "fused_tail"),
+    # 50 s each alone: the single-source program's miss and way back
+    # stay in tier 1 with the flip only it has, the sentinel, the fault
+    # and the record with the batched cases
+    pytest.param("single", "robust+fault", marks=pytest.mark.slow),
+    pytest.param("single", "record", marks=pytest.mark.slow),
     ("multi", "check_every"), ("multi", "robust"), ("multi", "fault"),
     ("multi", "record")])
 def test_a_flipped_knob_is_a_miss_and_back_a_hit(route, flip, warm, knobs,
-                                                 tmp_path):
+                                                 first_calls, tmp_path):
     env = FLIPS[flip]
+    if route == "multi":
+        knobs(QUDA_TPU_PALLAS="0")
+        if "xla" not in first_calls:    # the worker's first call there
+            _solve(route, seed=1)
+            first_calls["xla"] = True
     before = _counts(route)
     knobs(**env)
     if flip == "record":
@@ -557,7 +573,12 @@ def _packed(seed, kappa, lat=4):
 
 @pytest.mark.parametrize("store,pallas,form", [
     (jnp.float32, True, None), (jnp.bfloat16, False, None),
-    (jnp.float32, True, "r12f"), (jnp.bfloat16, True, "int8")])
+    (jnp.float32, True, "r12f"), (jnp.bfloat16, True, "int8"),
+    # the XLA stencil operators the batched route's flipped knobs run on
+    (jnp.float32, False, None), (jnp.float32, False, "int8"),
+    # the other storage forms a solve program may be handed
+    (jnp.bfloat16, True, "fold"), (jnp.float32, True, "bzfull"),
+    (jnp.float32, True, "r12")])
 def test_operator_is_arrays_plus_a_small_static_key(store, pallas, form):
     kw = dict(use_pallas=pallas, pallas_interpret=True,
               precision_form=form)
