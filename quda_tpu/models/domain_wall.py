@@ -230,13 +230,16 @@ class DiracMobiusPCPairs(_ProgramOperand, _LsPairIOMixin, _PackedHopMixin):
 
     The 4-d hop is the packed eo Wilson stencil vmapped over the Ls axis
     (optionally the pallas kernel — jax.vmap turns its grid into
-    (Ls, T, Z/bz)); the s-operators are the REAL dense (Ls, Ls)
-    chirality blocks of ops/dwf.py applied as f32 einsums (MXU), so no
-    complex arithmetic remains anywhere.
+    (Ls, T, Z/bz)) or the Ls-batched kernel; the s-operators are the
+    REAL dense (Ls, Ls) chirality blocks of ops/dwf.py, so no complex
+    arithmetic remains anywhere: f32 einsums beside the vmapped hop,
+    a VPU kernel on the hop's own layout beside the Ls-batched one
+    (``_apply_blocks``), with gamma5 and the ``x - 1/4 ...`` in them.
 
     Reference behavior: QUDA's Möbius solves run in float2/half native
     orders with the fused m5 kernels (lib/dslash_mdw_fused.in.cu); here
-    the fusion of s-block x 4d-hop chains is XLA's job.
+    the s-block and the 4d-hop are two kernels on one layout, and the
+    block in the hop's prologue / epilogue is ROADMAP A12's next step.
 
     A solve-program operand (solvers/program.py): the links and the
     four block pairs are the leaves, so one executable serves every
@@ -265,7 +268,9 @@ class DiracMobiusPCPairs(_ProgramOperand, _LsPairIOMixin, _PackedHopMixin):
         omem.track("dwf", "m5_pair_blocks",
                    self._m5p + self._mix + self._m5i)
         from . import formsel
-        aux = f"{jnp.dtype(store_dtype).name}|ls{self.ls}"
+        # "|mpairs": the race times M_pairs since PR 44 (the form picks
+        # the s-blocks too); a winner cached from the hop alone is stale
+        aux = f"{jnp.dtype(store_dtype).name}|ls{self.ls}|mpairs"
         self._op_form = formsel.resolve_form(
             "dwf", form, self,
             race=lambda: formsel.race_ls_hop("dwf", self, aux=aux),
@@ -306,18 +311,51 @@ class DiracMobiusPCPairs(_ProgramOperand, _LsPairIOMixin, _PackedHopMixin):
         return op
 
     # -- building blocks ------------------------------------------------
-    def _apply_blocks(self, blk, x, adjoint=False, out_dtype=None):
+    def _apply_blocks(self, blk, x, adjoint=False, out_dtype=None,
+                      g5=False, axpy=None):
         """Apply real (Ls,Ls) chirality blocks to (Ls,4,3,2,T,Z,YXh):
         spins 0,1 through ap, spins 2,3 through am (chirality is
-        spin-pair diagonal in the DeGrand-Rossi basis)."""
+        spin-pair diagonal in the DeGrand-Rossi basis).  ``g5``: the
+        product with gamma5 = diag(+,+,-,-), which commutes with every
+        chirality-diagonal block, as a sign on am (exact).  ``axpy`` =
+        (y, a): ``y + a * (blocks x)`` from the f32 sums.
+
+        Where the hop is the Ls-batched kernel the product is one too
+        (ops/dwf_pallas.mobius_sblock_pallas: VPU multiply-adds on the
+        hop's own layout, f32 whatever the storage); the f32 einsum
+        everywhere else (the CPU, interpreted kernels unless the form
+        is pinned, QUDA_TPU_DWF_FORM=xla).  One form, ``_op_form``, for
+        the hop and the blocks: where it is raced the race times the
+        whole ``M_pairs`` (formsel.race_ls_hop), and the resident
+        route's ``MEASURED_LS_HOP_FORM`` is the chip's reading of the
+        whole solve (PERF.md section 6, PR 44)."""
         ap, am = blk
         if adjoint:
             ap, am = ap.T, am.T
+        if g5:
+            am = -am
+        odt = jnp.dtype(out_dtype or self.store_dtype)
+        form = "pallas" if self._op_form == "pallas" else "einsum"
+        from ..obs import metrics as omet
+        omet.inc("dwf_sblock_route_total", form=form, ls=str(self.ls))
+        if form == "pallas":
+            from ..ops import dwf_pallas as dwp
+            blocks = jnp.stack([ap, am])
+            if axpy is None:
+                return dwp.mobius_sblock_pallas(
+                    x, blocks, out_dtype=odt,
+                    interpret=self._pallas_interpret)
+            return dwp.mobius_sblock_axpy_pallas(
+                x, *axpy, blocks, out_dtype=odt,
+                interpret=self._pallas_interpret)
         f = x.astype(jnp.float32)
         up = jnp.einsum("st,t...->s...", ap, f[:, :2])
         dn = jnp.einsum("st,t...->s...", am, f[:, 2:])
         out = jnp.concatenate([up, dn], axis=1)
-        return out.astype(out_dtype or self.store_dtype)
+        if axpy is not None:
+            y, a = axpy
+            out = y.astype(jnp.float32) + a * out
+        return out.astype(odt)
 
     def _hop_to_pairs(self, x, target_parity, out_dtype=None,
                       form=None):
@@ -343,32 +381,25 @@ class DiracMobiusPCPairs(_ProgramOperand, _LsPairIOMixin, _PackedHopMixin):
         return jax.vmap(
             lambda v: self._d_to(v, target_parity, odt))(x)
 
-    def _hop_to_dag_pairs(self, x, target_parity, out_dtype=None):
-        return self._g5(self._hop_to_pairs(self._g5(x), target_parity,
-                                           out_dtype))
-
     # -- the operator (mirrors DiracMobiusPC.M / .Mdag) -----------------
     def M_pairs(self, x):
         p = self.matpc
         t = self._hop_to_pairs(self._apply_blocks(self._m5p, x), 1 - p)
         t = self._hop_to_pairs(self._apply_blocks(self._mix, t), p,
                                out_dtype=jnp.float32)
-        out = (x.astype(jnp.float32)
-               - 0.25 * self._apply_blocks(self._m5i, t,
-                                           out_dtype=jnp.float32))
-        return out.astype(self.store_dtype)
+        return self._apply_blocks(self._m5i, t, axpy=(x, -0.25))
 
     def Mdag_pairs(self, x):
+        """1 - 1/4 (M5'^T g5) hop M5"^T hop (g5 M5^-1^T): hop^dag = g5
+        hop g5, and the two gamma5 between the hops cancel through
+        M5"^T, so the four sign passes are the sign of two blocks."""
         p = self.matpc
-        t = self._apply_blocks(self._m5i, x, adjoint=True)
-        t = self._apply_blocks(self._mix,
-                               self._hop_to_dag_pairs(t, 1 - p),
+        t = self._apply_blocks(self._m5i, x, adjoint=True, g5=True)
+        t = self._apply_blocks(self._mix, self._hop_to_pairs(t, 1 - p),
                                adjoint=True)
-        t = self._apply_blocks(self._m5p,
-                               self._hop_to_dag_pairs(t, p),
-                               adjoint=True, out_dtype=jnp.float32)
-        out = x.astype(jnp.float32) - 0.25 * t
-        return out.astype(self.store_dtype)
+        return self._apply_blocks(self._m5p, self._hop_to_pairs(t, p),
+                                  adjoint=True, g5=True,
+                                  axpy=(x, -0.25))
 
     def MdagM_pairs(self, x):
         return self.Mdag_pairs(self.M_pairs(x))

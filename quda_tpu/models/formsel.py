@@ -184,22 +184,32 @@ def race_schur(family: str, op, aux: str = "") -> str:
 
 
 def race_ls_hop(family: str, op, aux: str = "") -> str:
-    """Race the Ls-batched 4d hop kernel vs the vmap-over-s stencil on
-    an (Ls, 4, 3, 2, T, Z, YXh) dummy — the Möbius/DWF hop seam (the
-    m5 block algebra is identical either way and stays out of the
-    race)."""
+    """Race the two forms of a Möbius pair operator on an
+    (Ls, 4, 3, 2, T, Z, YXh) dummy: the Ls-batched 4d hop kernel with
+    the s-block kernel beside it vs the vmap-over-s stencil with the
+    f32 einsum.  ``_op_form`` decides the hop and the s-blocks alike
+    (DiracMobiusPCPairs._apply_blocks), so the race times what it
+    decides: the whole ``M_pairs`` (two hops, three block products) on
+    a copy of ``op`` with the form pinned, never the attribute it is
+    about to set."""
+    import copy
+    import functools
     import jax
     import jax.numpy as jnp
     T, Z, _, _ = op.dims
     yxh = op.gauge_eo_pp[0].shape[-1]
     psi0 = jnp.zeros((op.ls, 4, 3, 2, T, Z, yxh), op.store_dtype)
-    p = op.matpc
-    cands = {
-        "pallas": jax.jit(
-            lambda v: op._hop_to_pairs(v, 1 - p, form="pallas")),
-        "xla": jax.jit(
-            lambda v: op._hop_to_pairs(v, 1 - p, form="xla")),
-    }
+    m_pairs = jax.jit(type(op).M_pairs)
+    cands = {}
+    for form in ("pallas", "xla"):
+        pinned = copy.copy(op)
+        pinned._op_form = form
+        # a pytree operator goes in as an ARGUMENT, as in race_schur:
+        # closed over, its links are constants of each candidate (225
+        # and 532 MB executables at 24^4 x 12: my chip run, PR 44)
+        cands[form] = (functools.partial(m_pairs, pinned)
+                       if pinned.program_signature is not None
+                       else jax.jit(pinned.M_pairs))
     return race_forms(family, op, cands, (psi0,), aux=aux)
 
 

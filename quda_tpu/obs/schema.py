@@ -247,6 +247,17 @@ METRICS: dict[str, dict] = {
                 "wilson_mrhs_route_total too), 'xla' jax.vmap of the "
                 "single-slice stencil; and by ls, the planes a call "
                 "had"},
+    "dwf_sblock_route_total": {
+        "type": COUNTER,
+        "help": "traced applications of the Möbius pair operator's real "
+                "(Ls, Ls) chirality blocks "
+                "(models/domain_wall.DiracMobiusPCPairs._apply_blocks) "
+                "by form: 'pallas' the VPU kernel on the hop's layout "
+                "(ops/dwf_pallas.mobius_sblock_pallas or its accumulate "
+                "form), served wherever the hop is the Ls-batched "
+                "kernel, 'einsum' XLA's f32 einsum (the CPU, "
+                "interpreted kernels, QUDA_TPU_DWF_FORM=xla); and by "
+                "ls"},
     "wilson_mrhs_route_total": {
         "type": COUNTER,
         "help": "traced calls of the multi-RHS Wilson kernel "

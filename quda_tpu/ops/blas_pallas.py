@@ -34,24 +34,32 @@ import jax.numpy as jnp
 F32 = jnp.float32
 
 
+def _vmem_budget() -> int:
+    """Bytes one set of a kernel's blocks may hold in VMEM, the pipeline
+    doubling it (QUDA_TPU_PALLAS_VMEM_MB, shared with the dslash
+    kernels' _pick_bz)."""
+    from ..utils import config as qconf
+    return int(float(qconf.get("QUDA_TPU_PALLAS_VMEM_MB",
+                               fresh=True)) * 2 ** 20)
+
+
+def _tile_bytes(rows: int, cols: int, itemsize: int = 4,
+                sublanes: int = 8) -> int:
+    """Bytes of a (rows, cols) block as VMEM holds it: rows padded to
+    the tile's sublanes, cols to 128 lanes."""
+    return (-(-rows // sublanes) * sublanes * (-(-cols // 128) * 128)
+            * itemsize)
+
+
 def _pick_rows(R: int, C: int, nbufs: int, itemsize: int = 4) -> int:
     """Largest hardware-legal row-block of an (R, C) view whose ``nbufs``
-    VMEM-resident buffers fit the scoped budget (QUDA_TPU_PALLAS_VMEM_MB,
-    shared with the dslash kernels' _pick_bz).  Legality: block rows
-    divisible by 8 or equal to R (round-5 Mosaic rule)."""
-    from ..utils import config as qconf
-    budget = int(float(qconf.get("QUDA_TPU_PALLAS_VMEM_MB",
-                                 fresh=True)) * 2 ** 20)
-    cpad = -(-C // 128) * 128
-    fitting = []
-    for br in range(1, R + 1):
-        if R % br != 0:
-            continue
-        if br % 8 != 0 and br != R:
-            continue
-        brp = -(-br // 8) * 8
-        if nbufs * brp * cpad * itemsize <= budget:
-            fitting.append(br)
+    VMEM-resident buffers fit the scoped budget (``_vmem_budget``).
+    Legality: block rows divisible by 8 or equal to R (round-5 Mosaic
+    rule)."""
+    budget = _vmem_budget()
+    fitting = [br for br in range(1, R + 1)
+               if R % br == 0 and (br % 8 == 0 or br == R)
+               and nbufs * _tile_bytes(br, C, itemsize) <= budget]
     if not fitting:
         raise ValueError(
             f"no row-block of R={R} fits the VMEM budget at C={C} "
@@ -186,7 +194,7 @@ def multishift_update_pallas(n_active, alpha_s, zeta, beta_s, x, p, r,
     if R % br != 0:
         raise ValueError(f"block_rows={br} does not divide rows={R}")
     # five blocks (x, p, r in; x, p out), double-buffered, as tiled
-    need = 10 * (-(-br // 8) * 8) * (-(-C // 128) * 128) * x.dtype.itemsize
+    need = 10 * _tile_bytes(br, C, x.dtype.itemsize)
     na = jnp.reshape(n_active, (1,)).astype(jnp.int32)
     coef = jnp.stack([alpha_s, zeta, beta_s]).astype(F32)
 

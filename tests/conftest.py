@@ -143,7 +143,7 @@ FILE_SECONDS = {
     "test_staggered_pallas.py": 420, "test_multirhs_kernels.py": 400,
     "test_pallas.py": 360, "test_pair_mg.py": 360,
     "test_precision_forms.py": 240, "test_clover_resident.py": 240,
-    "test_domain_wall.py": 240, "test_chip_compile.py": 220,
+    "test_domain_wall.py": 270, "test_chip_compile.py": 220,
     "test_mixed.py": 210, "test_wilson_resident.py": 170,
     "test_interface.py": 170, "test_pair_gauge.py": 170,
     "test_twisted.py": 160, "test_serve.py": 150, "test_pair_eig.py": 130,

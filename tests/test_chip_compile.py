@@ -168,6 +168,19 @@ def _dwf_ls8():
             [_links(F32), _links(F32), _psi(F32, (8,))])
 
 
+def _mobius_sblock(dt, axpy=False):
+    """The (Ls, Ls) chirality blocks of the Möbius cell on a 24^4 x 12
+    pair array (ops/dwf_pallas): the plain product at the loop's and
+    the exit's widths, the accumulate form ``y - 1/4 B x`` in f32."""
+    from quda_tpu.ops import dwf_pallas as dp
+    v = _psi(dt, (MOBIUS_LS,))
+    blocks = ((2, MOBIUS_LS, MOBIUS_LS), F32)
+    if axpy:
+        return (lambda x, y, b: dp.mobius_sblock_axpy_pallas(x, y, -0.25, b),
+                [v, v, blocks])
+    return (dp.mobius_sblock_pallas, [v, blocks])
+
+
 def _coarse():
     # 24^4 fine lattice, 4^4 blocks -> 6^4 = 1296 coarse sites, 24 null
     # vectors -> E = 2*Nc = 48 (the shape bench_mg_scale builds)
@@ -215,6 +228,9 @@ CASES = {
         "gather", 1),
     "clover_pc_k1": _clover_pc_k1,
     "dwf_eo_ls8": _dwf_ls8,
+    "mobius_sblock_ls12_bf16": lambda: _mobius_sblock(BF16),
+    "mobius_sblock_ls12_f32": lambda: _mobius_sblock(F32),
+    "mobius_sblock_axpy_ls12_f32": lambda: _mobius_sblock(F32, axpy=True),
     "mg_coarse_1296x48": _coarse,
 }
 
@@ -269,6 +285,12 @@ def _hlo_values(hlo, op):
     return [(_HLO_BYTES[dt] * math.prod(int(d) for d in dims.split(",")
                                         if d), dt, dims)
             for dt, dims in pat.findall(hlo)]
+
+
+def _hlo_computation(hlo, name):
+    """The text of one computation of an HLO module, by its name."""
+    start = hlo.index(f"\n%{name} (")
+    return hlo[start:hlo.index("\n}\n", start)]
 
 
 def test_solve_program_compiles_for_v5e_with_links_as_parameters(one_chip):
@@ -745,7 +767,10 @@ def test_mobius_programs_compile_for_v5e_under_14_gib(one_chip, program):
     the canonical 5-d source and solution of the exit are 1.9 GiB each
     as the chip tiles them (a 24-wide minor axis), the rule on the peak
     of ISSUE 42 (the term and entry programs compile in a scratch run
-    to 1.8 and 6.2 GiB: PERF.md, PR 42)."""
+    to 1.8 and 6.2 GiB: PERF.md, PR 42).  Since PR 44 the (Ls, Ls)
+    blocks are kernels on the hop's layout: the module holds no dot,
+    the loop body a sloppy MdagM's six s-block calls between its four
+    hops and no copy of a 5-d vector."""
     from quda_tpu.fields.geometry import LatticeGeometry
     from quda_tpu.interfaces import quda_api as api
     from quda_tpu.models import domain_wall as mdw
@@ -797,3 +822,25 @@ def test_mobius_programs_compile_for_v5e_under_14_gib(one_chip, program):
     links = ",".join(str(d) for d in _links(F32)[0])
     params = _hlo_values(hlo, "parameter")
     assert sum(p[1:] == ("f32", links) for p in params) == 4
+    # the (Ls, Ls) blocks are the VPU kernel on the hop's layout, not a
+    # dot XLA lays the vectors out for (PR 44): no dot in the module ...
+    assert not re.findall(r" (?:dot|convolution)\(", hlo)
+    sblock = lambda text: sorted(re.findall(
+        r"%(mobius_sblock(?:_axpy)?_pallas)[.\d]* = (\w+)\["
+        r"[^\n]*tpu_custom_call", text))
+    if program == "verified-exit":
+        # M5' x_p, M5^-1 (...), M5 x_p, M5' x_q, M5 x_q
+        assert sblock(hlo) == [("mobius_sblock_pallas", "f32")] * 5
+    else:
+        # ... and the loop body holds a sloppy MdagM's six products (the
+        # last of each M with x - 1/4 ... in it) between its four hops,
+        # with no copy of a 5-d vector around either
+        loop = _hlo_computation(hlo, re.search(
+            r" while\([^\n]*body=%([\w.\-]+)", hlo).group(1))
+        assert sblock(loop) == (
+            [("mobius_sblock_axpy_pallas", "bf16")] * 2
+            + [("mobius_sblock_pallas", "bf16")] * 4)
+        assert len(re.findall(r"%dslash_eo_pallas_packed_mrhs[.\d]* = ",
+                              loop)) == 4
+        vec = rf"\[{MOBIUS_LS},\d,3,2,{L},{L},{YXH}\]"
+        assert not re.findall(vec + r"\S* copy\(", loop)

@@ -300,6 +300,91 @@ def test_mobius_pairs_matches_complex(cfg, use_pallas):
         assert err < 1e-5, (fn, err)
 
 
+@pytest.mark.parametrize("case", ["eofa", "mobius-kernel"])
+def test_mobius_pairs_mdag_with_gamma5_in_the_blocks(cfg, case, tmp_path):
+    """``Mdag_pairs`` carries hop^dag = g5 hop g5 as a sign on the ``-``
+    block of its first and last product, and ``x - 1/4 ...`` inside the
+    last: equal to the complex ``DiracMobiusPC.Mdag`` (and ``M``), on
+    EOFA's dense corrected blocks through the einsum and, on Möbius's
+    own, with the s-block kernel forced (interpreted) beside the XLA
+    hop, the counter saying which form ran (the einsum on Möbius's own
+    blocks: ``test_mobius_pairs_matches_complex``)."""
+    from quda_tpu.obs import metrics as omet
+    gauge, psi = cfg
+    g = gauge.astype(jnp.complex64)
+    if case == "eofa":
+        dpc = DiracMobiusEofaPC(g, GEOM, LS, M5, MF, B5, C5, mq1=MF,
+                                mq2=0.08, mq3=0.2, eofa_shift=0.1)
+    else:
+        dpc = DiracMobiusPC(g, GEOM, LS, M5, MF, B5, C5)
+    op = dpc.pairs(jnp.float32)
+    assert op._op_form == "xla"
+    form = "einsum"
+    if case == "mobius-kernel":
+        hop = op._hop_to_pairs
+        op._hop_to_pairs = lambda *a, **k: hop(*a, form="xla", **k)
+        op._op_form, op._pallas_interpret, form = "pallas", True, "pallas"
+    pe = jax.vmap(lambda v: even_odd_split(v, GEOM)[0])(psi).astype(
+        jnp.complex64)
+
+    def applied():
+        return {dict(labels)["form"]: int(v) for (n, labels), v
+                in omet.snapshot()["counters"].items()
+                if n == "dwf_sblock_route_total"}
+    started = not omet.enabled()
+    if started:
+        omet.start(str(tmp_path))
+    try:
+        before = applied()
+        for fn in ("Mdag", "M"):
+            ref = getattr(dpc, fn)(pe)
+            got = getattr(op, fn)(pe)
+            err = float(jnp.sqrt(blas.norm2(ref - got) / blas.norm2(ref)))
+            assert err < 1e-5, (fn, err)
+        assert {k: v - before.get(k, 0) for k, v in applied().items()
+                if v != before.get(k, 0)} == {form: 6}
+    finally:
+        if started:
+            omet.stop(flush_files=False)
+
+
+def test_the_form_race_times_m_pairs_under_each_form(cfg, monkeypatch):
+    """``_op_form`` picks the hop and the s-blocks alike, so
+    formsel.race_ls_hop hands the race the whole ``M_pairs`` of a copy
+    pinned to each form (not the hop alone), under a cache key of its
+    own, the operator an argument of each candidate, and leaves the
+    operator's form as it was."""
+    from quda_tpu.models import formsel
+    gauge, psi = cfg
+    op = DiracMobiusPC(gauge.astype(jnp.complex64), GEOM, LS, M5, MF,
+                       B5, C5).pairs(jnp.float32)
+    assert op._op_form == "xla"
+    seen = {}
+
+    def race_forms(family, raced, cands, args, aux=""):
+        seen.update(family=family, raced=raced, cands=cands, args=args,
+                    aux=aux)
+        return "xla"
+    monkeypatch.setattr(formsel, "race_forms", race_forms)
+    assert formsel.race_ls_hop("dwf", op, aux="f32|ls4|mpairs") == "xla"
+    assert (seen["family"], seen["raced"], seen["aux"]) == (
+        "dwf", op, "f32|ls4|mpairs")
+    # the pinned copy is the jitted M_pairs' first ARGUMENT (a pytree
+    # operand: the links are no constants of a candidate)
+    assert {form: (c.func.__wrapped__, c.args[0]._op_form)
+            for form, c in seen["cands"].items()} == {
+        form: (type(op).M_pairs, form) for form in ("pallas", "xla")}
+    assert all(c.args[0] is not op for c in seen["cands"].values())
+    assert op._op_form == "xla"
+    (x0,) = seen["args"]
+    x = jnp.asarray(np.random.default_rng(3).standard_normal(
+        x0.shape).astype(np.float32))
+    assert x0.dtype == op.store_dtype and x0.shape[0] == LS
+    got, ref = seen["cands"]["xla"](x), op.M_pairs(x)
+    assert float(jnp.max(jnp.abs(got - ref))) <= 1e-6 * float(
+        jnp.max(jnp.abs(ref)))
+
+
 def test_mobius_pairs_full_solve_chain(cfg):
     """Complex-free prepare -> CGNR on MdagM_pairs -> reconstruct solves
     M x = b to the same solution as the complex chain (every Krylov

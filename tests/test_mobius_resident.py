@@ -268,6 +268,9 @@ def test_first_call_builds_then_every_action_reuses_links_and_programs(
         (r["family"], r["field"]) for r in omem.ledger()}
     assert _counts("dwf_hop_route_total", ("form", "ls"))[
         ("xla", str(LS))] >= 1
+    # ... and the s-blocks follow the hop's form: XLA's einsum here
+    assert set(_counts("dwf_sblock_route_total", ("form", "ls"))) == {
+        ("einsum", str(LS))}
     names = {r["program"] for r in obuild.snapshot()}
     assert "_mobius_term_program" in names
     # the same action, another source: the term, the programs
