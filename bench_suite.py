@@ -348,21 +348,12 @@ def main(argv):
                      stp.dslash_staggered_pallas(
                          g, fb, p, X, long_pl=g, long_bw_pl=lb)),
                  (g_pairs,), stag_p, stag_flops, stag_bytes))
-            # round-10 kernel-form A/B (PERF.md round 8 "re-measure
-            # before and after (a)"): the SAME operator through (i) the
-            # two-pass gather form above (1512 B/site model), (ii) the
-            # two-pass scatter form (984 B/site, no backward copies) and
-            # (iii) the FUSED single-pass fat+Naik kernel (864 B/site,
-            # one launch, one psi read, no XLA sum pass) — raced, not
-            # assumed
+            # kernel-form A/B: the SAME operator through (i) the
+            # two-pass gather form above (1512 B/site model) and (ii)
+            # the two-pass scatter form (984 B/site, no backward copies)
             cases.append(
                 ("improved_staggered_v3",
                  lambda g, p: stp.dslash_staggered_pallas_v3(
-                     g, p, X, long_pl=g),
-                 (g_pairs,), stag_p, stag_flops, stag_bytes))
-            cases.append(
-                ("improved_staggered_fused",
-                 lambda g, p: stp.dslash_staggered_pallas_fused(
                      g, p, X, long_pl=g),
                  (g_pairs,), stag_p, stag_flops, stag_bytes))
             # staggered MRHS amortization curve (the round-7 Wilson
@@ -557,55 +548,6 @@ def main(argv):
                                       "name": name,
                                       "error": str(e)[:140]}),
                           flush=True)
-
-            # staggered fused fat+Naik A/B: resident full links vs the
-            # in-kernel recon-12 Naik links (+ sign plane) vs the fold
-            try:
-                with jax.default_device(cpu_p):
-                    lngd24 = jax.device_put(
-                        (0.1 * gp_h24).astype(np.complex64), cpu_p)
-                    dst_p = DiracStaggeredPC(gpd24, geom, 0.1,
-                                             improved=True,
-                                             long_links=lngd24)
-                spsi_eo = jnp.asarray(rng.standard_normal(
-                    (3, 2, L, L, L * L // 2)), jnp.float32)
-                for name, pform, model in (
-                        ("staggered_fused_full", "full",
-                         "staggered_fat_naik_fused"),
-                        ("staggered_fused_r12", "r12",
-                         "staggered_fat_naik_fused_r12"),
-                        ("staggered_fused_fold", "fold",
-                         "staggered_fat_naik_fused_fold")):
-                    try:
-                        with jax.default_device(cpu_p):
-                            sop = dst_p.pairs(jnp.float32,
-                                              use_pallas=True,
-                                              form="fused",
-                                              precision_form=pform)
-                        for attr in ("fat_eo_pp", "long_eo_pp",
-                                     "_long_sign"):
-                            v = getattr(sop, attr, None)
-                            if v is not None:
-                                setattr(sop, attr, tuple(
-                                    jax.device_put(np.asarray(g))
-                                    for g in v))
-                        secs = _bench_op(
-                            lambda v, sop=sop: sop.D_to_pairs(
-                                v, 0, jnp.float32), spsi_eo)
-                        _emit("precision", name, secs,
-                              1146 * (vol // 2),
-                              model_bytes(model, jnp.float32),
-                              platform, lat, banner=banner,
-                              model=model)
-                    except Exception as e:
-                        print(json.dumps({"suite": "precision",
-                                          "name": name,
-                                          "error": str(e)[:140]}),
-                              flush=True)
-            except Exception as e:
-                print(json.dumps({"suite": "precision",
-                                  "name": "staggered_fused_ab",
-                                  "error": str(e)[:140]}), flush=True)
 
             # the int8+df64 contract row: quarter-storage links (int8
             # mantissas + per-link f32 scales, decompressed in-kernel)
@@ -907,14 +849,12 @@ def main(argv):
                 "cg_wilson_pc_f32pairs_pallas_24",
                 jax.jit(lambda b: cg(mv24, b, tol=1e-6, maxiter=600)),
                 rhs24, fl_iter_c, Lc)
-            # the fused-iteration pipeline: check cadence 10 + the
-            # single-pass pallas update+reduce tail
-            solver_row("cg_wilson_pc_f32pairs_pallas_fused_24",
+            # the fused-iteration pipeline: check cadence 10
+            solver_row("cg_wilson_pc_f32pairs_pallas_cadence10_24",
                        jax.jit(lambda b: fused_cg(
                            mv24, b, tol=1e-6, maxiter=600,
-                           check_every=10, use_pallas_tail=True)),
-                       rhs24, fl_iter_c, Lc,
-                       check_every=10, fused_tail="pallas")
+                           check_every=10)),
+                       rhs24, fl_iter_c, Lc, check_every=10)
             # multishift (the RHMC shape) on the shared-Krylov normal
             # equations; one matvec per counted iteration
             shifts_c = (0.0, 0.05, 0.25)
@@ -924,16 +864,15 @@ def main(argv):
                        jax.jit(lambda b: multishift_cg(
                            mv24, b, shifts_c, tol=1e-6, maxiter=600)),
                        nrm24, fl_iter_c, Lc, n_shifts=len(shifts_c))
-            # bf16-reliable with the fused pallas tail in the sloppy loop
+            # bf16-reliable: the bf16 pair operator in the sloppy loop
             mv24_bf = pairs_op(jnp.bfloat16, use_pallas=True,
                                dpk=dpk_c).MdagM_pairs
-            codec24 = pair_inplace_codec(jnp.bfloat16,
-                                         use_pallas_tail=True)
+            codec24 = pair_inplace_codec(jnp.bfloat16)
             solver_row("cg_reliable_bf16_pairs_pallas_24",
                        jax.jit(lambda b: cg_reliable(
                            mv24, mv24_bf, b, tol=1e-6, maxiter=600,
                            codec=codec24)),
-                       rhs24, fl_iter_c, Lc, fused_tail="pallas")
+                       rhs24, fl_iter_c, Lc)
             # batched multi-RHS solve (the invert_multi_src_quda hot
             # loop): 8 RHS through the MRHS pallas eo stencil — per
             # iteration ONE batched MdagM whose gauge tiles are read
@@ -1018,9 +957,10 @@ def main(argv):
 
             # --- staggered/HISQ chip solver row (round 10): the second
             # headline family through the SAME pallas-in-solver
-            # pipeline — the fused fat+Naik kernel inside the compiled
-            # CG loop (the PC operator is Hermitian positive definite,
-            # so the iteration is ONE M apply — no normal-equation wrap)
+            # pipeline — the served scatter (v3) kernel inside the
+            # compiled CG loop (the PC operator is Hermitian positive
+            # definite, so the iteration is ONE M apply — no
+            # normal-equation wrap)
             try:
                 from quda_tpu.models.staggered import DiracStaggeredPC
                 lng_c = (0.1 * gc_h).astype(np.complex64)
@@ -1030,11 +970,9 @@ def main(argv):
                     dst_pc = DiracStaggeredPC(gcd_s, geo_c, 0.1,
                                               improved=True,
                                               long_links=lcd_s)
-                    # form pinned (the construction-time race cannot
-                    # execute pallas on the CPU staging device; the
-                    # kernel-form A/B lives in the dslash suite rows)
-                    sop = dst_pc.pairs(jnp.float32, use_pallas=True,
-                                       form="fused")
+                    # the kernel-form A/B lives in the dslash suite
+                    # rows
+                    sop = dst_pc.pairs(jnp.float32, use_pallas=True)
                     pcs = jax.device_put(pc_h[..., :1, :], cpu0)
                     sbe, sbo = even_odd_split(pcs, geo_c)
                     srhs_c = dst_pc.prepare(sbe, sbo)
@@ -1049,7 +987,7 @@ def main(argv):
                 solver_row("cg_staggered_pc_f32pairs_pallas_24",
                            jax.jit(lambda b: cg(sop.M_pairs, b,
                                                 tol=1e-6, maxiter=600)),
-                           srhs_pp, fl_iter_st, Lc, form="fused",
+                           srhs_pp, fl_iter_st, Lc, form="v3",
                            mass=0.1)
             except Exception as e:
                 print(json.dumps({"suite": "solver",

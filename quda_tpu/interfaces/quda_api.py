@@ -623,15 +623,13 @@ def _ks_links_loaded(param: InvertParam) -> bool:
 def _ks_term_key(param: InvertParam, on_tpu: bool) -> tuple:
     """What the resident KS pair operators depend on: the generation of
     the fat / long pair, matpc, the fermion boundary and the kernel
-    route (pallas or not, interpreted or not, and the two form knobs
-    the operator's set-up reads).  The mass is NOT part of it: it is a
-    leaf of the operators."""
-    from ..utils import config as qconf
+    route (pallas or not, interpreted or not: the operator's set-up
+    decides its kernel forms from these, models/staggered.served_forms,
+    and reads no knob).  The mass is NOT part of it: it is a leaf of
+    the operators."""
     matpc = EVEN if param.matpc_type == "even-even" else ODD
     return (_ctx["ks_epoch"], matpc, _antiperiodic(),
-            _pallas_enabled(on_tpu), _pallas_interpret(on_tpu),
-            str(qconf.get("QUDA_TPU_STAGGERED_FORM", fresh=True)),
-            str(qconf.get("QUDA_TPU_PRECISION_FORM", fresh=True)))
+            _pallas_enabled(on_tpu), _pallas_interpret(on_tpu))
 
 
 def _resident_staggered(param: InvertParam, stores=()) -> dict:
@@ -664,7 +662,7 @@ def _resident_staggered(param: InvertParam, stores=()) -> dict:
             jnp.dtype(s) for s in (jnp.float32,) + tuple(stores))
             if st not in term["ops"]]
         if missing:
-            _, matpc, ap, use_pallas, interpret, _, _ = key
+            _, matpc, ap, use_pallas, interpret = key
             geom = _ctx["geom"]
             dims = tuple(geom.lattice_shape)
             with otr.phase("fold_split", prof):
@@ -1331,11 +1329,6 @@ def _solve_form(d) -> str:
                 # models/staggered.py); the halo transport is
                 # policy-dependent O(surface) and lives in the trace
                 return f"staggered_sharded_{base}"
-            if form == "fused":
-                pf = getattr(op, "_precision_form", "full")
-                if pf in ("r12", "fold"):
-                    return f"staggered_{base}_fused_{pf}"
-                return f"staggered_{base}_fused"
             if form == "v3":
                 return f"staggered_{base}_v3"
             if form == "two_pass":

@@ -133,27 +133,23 @@ class DiracStaggeredPC(DiracPC):
     def pairs(self, store_dtype=jnp.float32, use_pallas: bool = False,
               pallas_interpret: bool = False,
               form: str | None = None, mesh=None,
-              sharded_policy: str | None = None,
-              precision_form: str | None = None
+              sharded_policy: str | None = None
               ) -> "DiracStaggeredPCPairs":
         """Complex-free packed companion (f32 = the precise TPU solve
         path; bf16 = the sloppy operator); see DiracStaggeredPCPairs."""
         return DiracStaggeredPCPairs(self, store_dtype, use_pallas,
                                      pallas_interpret,
                                      form=form, mesh=mesh,
-                                     sharded_policy=sharded_policy,
-                                     precision_form=precision_form)
+                                     sharded_policy=sharded_policy)
 
 
 _STAG_FORM_NOTICED = False
 
 
 def _notice_staggered_form(form: str, policy: str | None, source: str):
-    """One-time provenance notice naming the staggered kernel form (and,
-    under a mesh, halo policy) actually selected and HOW — an env knob
-    or auto decision must never take effect without a trace (the
-    round-6 wilson.py notice rule; successor semantics of
-    _notice_sharded_policy for the second headline family)."""
+    """One-time provenance notice of what a mesh changed: a requested
+    form it cannot serve, and the halo policy it pinned or raced (the
+    round-6 wilson.py notice rule: no decision without a trace)."""
     global _STAG_FORM_NOTICED
     if _STAG_FORM_NOTICED:
         return
@@ -161,42 +157,59 @@ def _notice_staggered_form(form: str, policy: str | None, source: str):
     from ..utils import logging as qlog
     pol = f", halo policy {policy}" if policy else ""
     qlog.printq(
-        f"staggered dslash: pallas form {form}{pol} ({source}); pin via "
-        "QUDA_TPU_STAGGERED_FORM / QUDA_TPU_SHARDED_POLICY",
-        qlog.SUMMARIZE)
+        f"staggered dslash: pallas form {form}{pol} ({source}); pin the "
+        "halo policy via QUDA_TPU_SHARDED_POLICY", qlog.SUMMARIZE)
 
 
-def _notice_precision_form(requested: str, served: str, why: str):
-    """One-time precision-form provenance (shared seen-set with the
-    Wilson family — same knob, same rule: no silent downgrades)."""
-    from .wilson import _notice_precision_form as _notice
-    _notice(requested, served, why)
+STAGGERED_FORMS = ("two_pass", "v3")
 
 
-STAGGERED_FORMS = ("fused", "two_pass", "v3")
-STAGGERED_PRECISION_FORMS = ("full", "r12", "fold")
+def served_forms(improved: bool, use_pallas: bool, mesh_axes=(),
+                 form: str | None = None) -> tuple:
+    """(hop form, batched-hop form) a staggered pair operator serves:
+    the ONE place either is decided, from what the operator is built
+    with and nothing else (no environment read, no race).
 
-# Hop sets whose forms have been read on the chip: with the knob unset
-# they serve the winner WITHOUT a race (models/formsel.MEASURED is the
-# same rule for clover).  Fat + Naik, one v5e, 24^4 (PERF.md, PR 32):
-# as one M the forms are within 5 % of each other (bf16 v3 480 us,
-# two_pass 495, fused 503; f32 fused 743, v3 773, two_pass 797), and in
-# the CG loop of the cell v3 reads call_s 0.0883 s against two_pass
-# 0.0964 and fused 0.1027.  'auto' still races.
-MEASURED_FORMS = {"fat_naik": "v3"}
+    ``improved``: fat + Naik links (else fat only).  ``mesh_axes``: the
+    lattice axes a mesh partitions (names among t, z, y, x; empty on
+    one chip).  ``form``: a caller's request, ``two_pass`` (the gather
+    kernel on resident pre-shifted backward links) or ``v3`` (the
+    scatter kernel, no backward links), honoured wherever a kernel
+    runs that can serve it.
 
-# The batched hop (``_d_to_mrhs``) of a single-chip pallas operator on
-# full-storage links, by hop set: ``gather_two_pass`` the gather MRHS
-# kernel on pre-shifted backward links (five psi tiles a source),
-# ``scatter_two_pass`` the v3 scatter pass under the same RHS-innermost
-# wrap (three psi tiles, no backward links), ``vmap`` jax.vmap of the
-# single-source hop (the source axis outermost: links read once per
-# source).  Fat + Naik is served from the chip's reading, no race: one
-# v5e, 24^4, 8 sources, f32 (PERF.md section 6, PR 35), eagerly a hop
-# reads scatter 1,336 us, gather 1,406, vmap of v3 3,132, and in the
-# cell hisq24_mrhs8.strange call_s 0.636 / 0.656 / 1.304 s.  The
-# fat-only hop set, never read on the chip, keeps the gather kernel.
-MEASURED_MRHS_FORMS = {"fat_naik": "scatter_two_pass"}
+    * one chip, kernels, fat + Naik -> (``v3``, ``scatter_two_pass``):
+      the chip's readings.  One v5e, 24^4: in the CG loop of
+      hisq24_single.strange v3 reads call_s 0.0883 s against two_pass
+      0.0964 (PERF.md section 6, PR 32); 8 sources in f32, the v3 pass
+      under the RHS-innermost wrap reads 0.636 s against the gather
+      MRHS kernel's 0.656 and ``vmap`` of v3's 1.304 (PR 35).
+    * one chip, kernels, fat only -> (``two_pass``,
+      ``gather_two_pass``).  This hop set was never read on the chip
+      and no benchmark cell builds it; until PR 45 such an operator
+      raced two_pass against v3 at construction on a chip with tuning
+      on, and served two_pass everywhere else.
+    * a mesh -> ``two_pass``, or ``v3`` where a caller asks for it and
+      the mesh partitions t / z only (the scatter exterior shards no
+      y / x); the batched hop is ``vmap`` of the single-source hop.
+      A mesh needs the kernels.
+    * the XLA stencil -> (``two_pass``, ``vmap``): a label, there is
+      no kernel to pick.
+    """
+    if form is not None and form not in STAGGERED_FORMS:
+        raise ValueError(f"staggered form must be one of "
+                         f"{STAGGERED_FORMS}, got {form!r}")
+    if not use_pallas:
+        if mesh_axes:
+            raise ValueError(
+                "mesh-sharded staggered pair operators need "
+                "use_pallas=True (the XLA pair stencil shards via "
+                "GSPMD instead)")
+        return "two_pass", "vmap"
+    if mesh_axes:
+        t_z_only = not ({"y", "x"} & set(mesh_axes))
+        return ("v3" if form == "v3" and t_z_only else "two_pass"), "vmap"
+    return (form or ("v3" if improved else "two_pass"),
+            "scatter_two_pass" if improved else "gather_two_pass")
 
 
 class DiracStaggeredPCPairs(_ProgramOperand):
@@ -207,33 +220,17 @@ class DiracStaggeredPCPairs(_ProgramOperand):
     Mirrors models/wilson.DiracWilsonPCPackedSloppy: half-lattice links
     packed to (4,3,3,2,T,Z,Y*Xh) re/im planes at ``store_dtype``, spinors
     (3,2,T,Z,Y*Xh); compute f32.  ``use_pallas`` swaps the stencil for
-    the hand-tuned eo kernels (ops/staggered_pallas); the kernel FORM is
-    selected by ``form`` / QUDA_TPU_STAGGERED_FORM:
+    the hand-tuned eo kernels (ops/staggered_pallas); which kernel body
+    serves the hop and the batched hop is ``served_forms``' decision,
+    ``form`` a caller's request to it:
 
-    * ``fused``    — single-pass fat+Naik (one launch, one psi read, no
-                     XLA sum pass) — improved only.  The eo kernel reads
-                     all 16 link matrices of an output site once (8
-                     forward of its own parity, 8 backward of the other:
-                     1,152 B in f32) + 5 psi tiles + out, ~1,300 B/site
-                     against a needed 1,200; the "864 B" of
-                     ops/staggered_pallas.py counts the FULL-lattice
-                     kernel, whose backward x/y/z hops re-use the forward
-                     tiles (it leaves out the other parity's 6 x/y/z
-                     backward matrices, 432 B, that the eo form must
-                     fetch);
     * ``two_pass`` — separate fat/long gather launches with resident
-                     pre-shifted backward links (the pre-round-10
-                     form);
-    * ``v3``       — two-pass scatter form;
-    * unset        — ``MEASURED_FORMS``: the winner of the chip reading
-                     (fat+Naik: v3; PERF.md section 6, PR 32), no
-                     race; ``auto`` where the hop set has none;
-    * ``auto``     — race the applicable forms via utils.tune at
-                     construction and cache the winner.
-                     Off-chip (interpret mode) the race would
-                     time the interpreter, not the hardware, so auto
-                     resolves statically to the projected winner (fused
-                     for improved, two_pass for fat-only) with a notice.
+                     pre-shifted backward links;
+    * ``v3``       — the two-pass scatter form, no backward links (what
+                     fat + Naik serves on one chip).
+
+    Links are kept in full storage whatever QUDA_TPU_PRECISION_FORM
+    asks the Wilson family for (a one-time notice says so).
 
     ``mesh`` runs the hop under shard_map (t/z mesh axes partition T/Z)
     through the sharded staggered eo policies
@@ -244,27 +241,24 @@ class DiracStaggeredPCPairs(_ProgramOperand):
 
     Reference behavior: QUDA solves staggered systems in float2-pair
     native orders on device too (include/color_spinor_field_order.h);
-    this is that representation made explicit, and the form selection is
-    the dslash-policy race of lib/dslash_policy.hpp applied to
-    include/kernels/dslash_staggered.cuh's improved=true fusion.
+    this is that representation made explicit.
     """
 
     hermitian = True
 
     # the solve-program operand (solvers/program.py): resident links,
-    # the gather forms' pre-shifted backward links, the r12 sign planes
-    # and the mass are leaves; what picks the traced hop is static
+    # the gather forms' pre-shifted backward links and the mass are
+    # leaves; what picks the traced hop is static
     _PROGRAM_ARRAYS = ("fat_eo_pp", "long_eo_pp", "_fat_bw", "_long_bw",
-                       "_long_sign", "mass")
+                       "mass")
     _PROGRAM_STATIC = ("geom", "dims", "matpc", "store_dtype",
                        "use_pallas", "_pallas_interpret", "_pallas_form",
-                       "_precision_form", "_mrhs_form")
+                       "_mrhs_form")
 
     def __init__(self, dpc: DiracStaggeredPC, store_dtype=jnp.float32,
                  use_pallas: bool = False, pallas_interpret: bool = False,
                  form: str | None = None, mesh=None,
-                 sharded_policy: str | None = None,
-                 precision_form: str | None = None):
+                 sharded_policy: str | None = None):
         from ..ops import staggered_packed as spk
         from ..ops.wilson_packed import to_packed_pairs
         pack = lambda gs: tuple(
@@ -272,7 +266,7 @@ class DiracStaggeredPCPairs(_ProgramOperand):
         self._setup(dpc.geom, dpc.mass, dpc.matpc, pack(dpc.fat_eo),
                     pack(dpc.long_eo) if dpc.long_eo is not None
                     else None, store_dtype, use_pallas, pallas_interpret,
-                    form, mesh, sharded_policy, precision_form)
+                    form, mesh, sharded_policy)
 
     @classmethod
     def from_packed(cls, geom, fat_eo_pp, long_eo_pp, mass, matpc,
@@ -282,11 +276,11 @@ class DiracStaggeredPCPairs(_ProgramOperand):
         """From the phase- and boundary-folded (even, odd) pair links
         alone (ops/staggered_packed.ks_links_eo_pairs, at
         ``store_dtype``): what a resident KS term is built from, no
-        canonical DiracStaggeredPC in between.  Kernel and precision
-        form resolve as ``pairs()`` does without pins."""
+        canonical DiracStaggeredPC in between.  The kernel form is
+        ``served_forms``', as for ``pairs()``."""
         op = object.__new__(cls)
         op._setup(geom, mass, matpc, fat_eo_pp, long_eo_pp, store_dtype,
-                  use_pallas, pallas_interpret, form, None, None, None)
+                  use_pallas, pallas_interpret, form, None, None)
         return op
 
     def with_mass(self, mass: float):
@@ -298,7 +292,7 @@ class DiracStaggeredPCPairs(_ProgramOperand):
 
     def _setup(self, geom, mass, matpc, fat_eo_pp, long_eo_pp,
                store_dtype, use_pallas, pallas_interpret, form, mesh,
-               sharded_policy, precision_form):
+               sharded_policy):
         from ..utils import config as qconf
         self.geom = geom
         self.mass = float(mass)
@@ -318,66 +312,27 @@ class DiracStaggeredPCPairs(_ProgramOperand):
             finj.maybe_raise("pallas_build")
         self._pallas_interpret = pallas_interpret
         self._fat_bw = self._long_bw = None
-        improved = self.long_eo_pp is not None
-
-        # -- kernel-form resolution (explicit kwarg >
-        # QUDA_TPU_STAGGERED_FORM knob) -------------------------------
-        if form is None:
-            form = str(qconf.get("QUDA_TPU_STAGGERED_FORM", fresh=True))
-        if not form:
-            # knob unset: the chip's measured winner where one was
-            # read, no race; else as 'auto'
-            form = (MEASURED_FORMS.get(
-                "fat_naik" if improved else "fat", "auto")
-                if use_pallas and mesh is None else "auto")
-            if form != "auto":
-                _notice_staggered_form(
-                    form, None, "default: the chip's measured winner, "
-                    "no race (QUDA_TPU_STAGGERED_FORM=auto races)")
-        if form not in STAGGERED_FORMS + ("auto",):
-            raise ValueError(f"staggered form must be one of "
-                             f"{STAGGERED_FORMS + ('auto',)}, got "
-                             f"{form!r}")
-        if form == "fused" and not improved:
-            # the fused kernel IS the fat+Naik fusion; a fat-only
-            # operator has a single hop set (nothing to fuse)
-            _notice_staggered_form("two_pass", None,
-                                   "fused needs fat+Naik; fat-only "
-                                   "falls back")
-            form = "two_pass"
 
         # single-chip escape: a 1-device mesh shards nothing
         if mesh is not None and getattr(mesh, "size", 2) == 1:
             mesh = None
         self._mesh = mesh
         self._mesh_yx = None
+        mesh_axes = ()
         if mesh is not None:
-            if not use_pallas:
-                raise ValueError(
-                    "mesh-sharded staggered pair operators need "
-                    "use_pallas=True (the XLA pair stencil shards via "
-                    "GSPMD instead)")
-            ms = dict(mesh.shape)
-            yx_mesh = (int(ms.get("y", 1)) > 1
-                       or int(ms.get("x", 1)) > 1)
-            if form in ("auto", "fused"):
-                # sharded exteriors exist for the gather and scatter
-                # two-pass forms; fused-under-mesh is future work, and
-                # racing interpret/sharded candidates at construction
-                # would time the wrong thing — pin the measured
-                # single-chip default and say so
-                _notice_staggered_form(
-                    "two_pass", None,
-                    f"mesh pins two_pass (requested {form})")
-                form = "two_pass"
-            elif form == "v3" and yx_mesh:
-                # the scatter exterior shards t/z only: y/x-partitioned
-                # meshes pin the gather two-pass form
-                _notice_staggered_form(
-                    "two_pass", None,
-                    "v3 scatter exterior shards t/z only; y/x mesh "
-                    "pins two_pass")
-                form = "two_pass"
+            from ..parallel.pallas_dslash import AXIS_NAMES, _mesh_counts
+            mesh_axes = tuple(a for a, n in zip(AXIS_NAMES,
+                                                _mesh_counts(mesh))
+                              if n > 1)
+        self._pallas_form, self._mrhs_form = served_forms(
+            self.long_eo_pp is not None, use_pallas, mesh_axes, form)
+        if form is not None and form != self._pallas_form and use_pallas:
+            _notice_staggered_form(
+                self._pallas_form, None,
+                f"the scatter exterior shards t/z only; a y/x mesh "
+                f"serves {self._pallas_form} (requested {form})")
+        form = self._pallas_form
+        if mesh is not None:
             self._sharded_policy = (
                 sharded_policy
                 or str(qconf.get("QUDA_TPU_SHARDED_POLICY", fresh=True))
@@ -388,79 +343,20 @@ class DiracStaggeredPCPairs(_ProgramOperand):
                 # bare single-value form: maps onto every partitioned
                 # axis, with a one-time deprecation-style notice
                 notice_legacy_single_policy(self._sharded_policy)
-        elif use_pallas and form == "auto":
-            from ..utils import tune as qtune
-            default = "fused" if improved else "two_pass"
-            if pallas_interpret or not qtune.tuning_enabled():
-                _notice_staggered_form(
-                    default, None,
-                    "auto default (no chip race: interpret mode or "
-                    "tuning disabled)")
-                form = default
-            else:
-                form = self._race_form()
-                _notice_staggered_form(
-                    form, None,
-                    "warm cache (chip-keyed tunecache)"
-                    if getattr(self, "_form_from_warm_cache", False)
-                    else "raced+cached (QUDA_TPU_STAGGERED_FORM=auto)")
-        elif form == "auto":
-            # XLA stencil path: the form knob has no kernel to pick
-            form = "two_pass"
-        self._pallas_form = form
 
-        # -- precision storage form (PERF.md round 16), fused kernel
-        # only: 'r12' compresses the NAIK hop set (long links are
-        # ±SU(3) after KS-phase folding — two stored rows + in-kernel
-        # third-row recon, with a streamed sign plane re-applying the
-        # folded phase; fat links are smeared SUMS, never unitary,
-        # never reconstructable), 'fold' interleaves re/im into
-        # sublane rows so bf16 (16,128) tiles fill exactly.  The two
-        # are ALTERNATIVE raced forms, not composable (fold keeps full
-        # R=3 rows — ops/staggered_pallas._fold_links_r3).
-        pform = precision_form
-        if pform is None:
-            pform = str(qconf.get("QUDA_TPU_PRECISION_FORM",
-                                  fresh=True))
-        self._long_sign = None
-        pform = self._downgrade_precision_form(pform or "full")
-        if pform == "auto":
-            from ..utils import tune as qtune
-            if pallas_interpret or not qtune.tuning_enabled():
-                _notice_precision_form(
-                    "auto", "full",
-                    "staggered auto default (no chip race: interpret "
-                    "mode or tuning disabled)")
-                pform = "full"
-            else:
-                pform = self._race_precision_form()
-        self._precision_form = pform
-        if pform == "r12":
-            from ..ops import su3
-            rs = [su3.to_recon12_signed(g) for g in self.long_eo_pp]
-            self.long_eo_pp = tuple(q for q, _ in rs)
-            self._long_sign = tuple(s for _, s in rs)
-        elif pform == "fold":
-            from ..ops import wilson_pallas_packed as wpp
-            self.fat_eo_pp = tuple(wpp.to_fold(g)
-                                   for g in self.fat_eo_pp)
-            if self.long_eo_pp is not None:
-                self.long_eo_pp = tuple(wpp.to_fold(g)
-                                        for g in self.long_eo_pp)
+        # links stay in full storage: the precision storage forms of
+        # QUDA_TPU_PRECISION_FORM are the Wilson family's
+        pform = str(qconf.get("QUDA_TPU_PRECISION_FORM", fresh=True))
+        if pform and pform != "full":
+            from .wilson import _notice_precision_form
+            _notice_precision_form(
+                pform, "full", "the staggered family serves full "
+                "storage only")
 
-        # gather forms keep resident pre-shifted backward links (the
-        # scatter/fused forms read the opposite-parity links as-is)
+        # the gather form keeps resident pre-shifted backward links
+        # (the scatter form reads the opposite-parity links as they are)
         if use_pallas and mesh is None and form == "two_pass":
             self._ensure_bw()
-
-        # the batched hop: an MRHS kernel streams full R=3 link tiles
-        # on one chip; r12 / fold storage, a mesh and the XLA stencil
-        # vmap the single-source hop
-        self._mrhs_form = (
-            MEASURED_MRHS_FORMS.get("fat_naik" if improved else "fat",
-                                    "gather_two_pass")
-            if use_pallas and mesh is None and pform == "full"
-            else "vmap")
 
         # multi-chip: move the resident links (and the globally
         # pre-shifted backward links the gather form needs) onto the
@@ -473,7 +369,6 @@ class DiracStaggeredPCPairs(_ProgramOperand):
             # backward pre-shift (which needs the natural global
             # order), so the ("y","x") PartitionSpec hands every shard
             # whole local rows at the LOCAL row width
-            from ..parallel.pallas_dslash import _mesh_counts
             _, _, n_y, n_x = _mesh_counts(mesh)
             self._mesh_yx = (n_y, n_x)
             if n_x > 1:
@@ -507,10 +402,8 @@ class DiracStaggeredPCPairs(_ProgramOperand):
                     _policy_label, resolve_axis_policies)
                 pols = resolve_axis_policies(self._sharded_policy)
                 self._sharded_policy = pols
-                live = [a for a, n in zip(("t", "z", "y", "x"),
-                                          _mesh_counts(mesh)) if n > 1]
-                _notice_staggered_form(form, _policy_label(pols, live),
-                                       "pinned")
+                _notice_staggered_form(
+                    form, _policy_label(pols, list(mesh_axes)), "pinned")
 
     def _ensure_bw(self):
         """Resident pre-shifted backward links of the gather forms
@@ -527,145 +420,6 @@ class DiracStaggeredPCPairs(_ProgramOperand):
             spl.backward_links_eo(self.long_eo_pp[1 - p], self.dims,
                                   p, 3) for p in (0, 1))
             if self.long_eo_pp is not None else None)
-
-    def _downgrade_precision_form(self, pform: str) -> str:
-        """Clamp a requested precision form to what the staggered path
-        serves: the fused single-chip kernel speaks full/r12/fold; the
-        Wilson-only forms (r12f, bzfull, int8) and every non-fused
-        route downgrade with a one-time notice."""
-        choices = ("auto",) + STAGGERED_PRECISION_FORMS
-        wilson_only = ("r12f", "bzfull", "int8")
-        if pform in wilson_only:
-            _notice_precision_form(
-                pform, "full",
-                "wilson-only precision form on the staggered family")
-            return "full"
-        if pform not in choices:
-            raise ValueError(
-                f"staggered precision form {pform!r} not in "
-                f"{choices} (QUDA_TPU_PRECISION_FORM)")
-        if not (self.use_pallas and self._mesh is None
-                and self._pallas_form == "fused"):
-            if pform != "full":
-                _notice_precision_form(
-                    pform, "full",
-                    "mesh/two-pass/v3/XLA staggered routes serve "
-                    "full storage only")
-            return "full"
-        if pform == "r12" and self.long_eo_pp is None:
-            _notice_precision_form(
-                "r12", "full",
-                "r12 compresses the Naik links; fat-only has none")
-            return "full"
-        return pform
-
-    def _race_precision_form(self) -> str:
-        """QUDA_TPU_PRECISION_FORM=auto on the fused staggered kernel:
-        race full vs r12 (improved only) vs fold on concrete operands
-        via utils.tune and cache per (volume, improved, dtype).
-        Candidate storages are transient; the winner's resident storage
-        is rebuilt by __init__."""
-        from ..ops import staggered_pallas as spl
-        from ..ops import su3
-        from ..ops import wilson_pallas_packed as wpp
-        from ..utils import tune as qtune
-        p = self.matpc
-        itp = self._pallas_interpret
-        improved = self.long_eo_pp is not None
-        fat, lng = self.fat_eo_pp, self.long_eo_pp
-        cands = {
-            "full": lambda psi: spl.dslash_staggered_eo_pallas_fused(
-                fat[p], fat[1 - p], psi, self.dims, p,
-                long_here_pl=lng[p] if improved else None,
-                long_there_pl=lng[1 - p] if improved else None,
-                interpret=itp),
-        }
-        if improved:
-            l12 = [su3.to_recon12_signed(g) for g in lng]
-            cands["r12"] = lambda psi: spl.dslash_staggered_eo_pallas_fused(
-                fat[p], fat[1 - p], psi, self.dims, p,
-                long_here_pl=l12[p][0], long_there_pl=l12[1 - p][0],
-                long_sign_here_pl=l12[p][1],
-                long_sign_there_pl=l12[1 - p][1], interpret=itp)
-        fat_f = tuple(wpp.to_fold(g) for g in fat)
-        lng_f = (tuple(wpp.to_fold(g) for g in lng) if improved
-                 else None)
-        cands["fold"] = lambda psi: wpp.from_fold(
-            spl.dslash_staggered_eo_pallas_fused_fold(
-                fat_f[p], fat_f[1 - p], wpp.to_fold(psi), self.dims, p,
-                long_here_f=lng_f[p] if improved else None,
-                long_there_f=lng_f[1 - p] if improved else None,
-                interpret=itp))
-        T, Z, _, _ = self.dims
-        yxh = self.fat_eo_pp[0].shape[-1]
-        psi0 = jnp.zeros((3, 2, T, Z, yxh), self.store_dtype)
-        aux = (f"fused|{'fat_naik' if improved else 'fat'}|"
-               f"{jnp.dtype(self.store_dtype).name}")
-        warm = qtune.cached_param("staggered_eo_precision_form",
-                                  self.dims, aux=aux)
-        won = qtune.tune("staggered_eo_precision_form", self.dims,
-                         cands, (psi0,), aux=aux)
-        _notice_precision_form(
-            "auto", won,
-            "warm cache (chip-keyed tunecache)" if warm is not None
-            else "raced (QUDA_TPU_PRECISION_FORM=auto)")
-        return won
-
-    # -- form race (utils.tune at operator construction) ----------------
-    def _form_candidates(self):
-        """{form: callable(psi_pp)} applying one target-parity hop per
-        SELECTABLE form — the race candidates AND the bit-match test
-        surface (each callable runs exactly what D_to_pairs would run
-        with that form pinned)."""
-        from ..ops import staggered_pallas as spl
-        improved = self.long_eo_pp is not None
-        p = self.matpc
-        itp = self._pallas_interpret
-        cands = {}
-        if improved:
-            cands["fused"] = lambda psi: spl.dslash_staggered_eo_pallas_fused(
-                self.fat_eo_pp[p], self.fat_eo_pp[1 - p], psi, self.dims,
-                p, long_here_pl=self.long_eo_pp[p],
-                long_there_pl=self.long_eo_pp[1 - p], interpret=itp)
-
-        def two_pass(psi):
-            self._ensure_bw()
-            return spl.dslash_staggered_eo_pallas(
-                self.fat_eo_pp[p], self._fat_bw[p], psi, self.dims, p,
-                long_here_pl=(self.long_eo_pp[p] if improved else None),
-                long_bw_pl=(self._long_bw[p] if improved else None),
-                interpret=itp)
-
-        cands["two_pass"] = two_pass
-        cands["v3"] = lambda psi: spl.dslash_staggered_eo_pallas_v3(
-            self.fat_eo_pp[p], self.fat_eo_pp[1 - p], psi, self.dims, p,
-            long_here_pl=(self.long_eo_pp[p] if improved else None),
-            long_there_pl=(self.long_eo_pp[1 - p] if improved else None),
-            interpret=itp)
-        return cands
-
-    def _race_form(self) -> str:
-        """Race the applicable kernel forms on a concrete dummy spinor
-        via utils.tune (QUDA's tune.cpp:862 rule — policies are timed,
-        never assumed) and cache the winner per (volume, improved,
-        dtype) in the tunecache.  A form that cannot compile here
-        simply loses (tune skips failing candidates)."""
-        from ..utils import tune as qtune
-        T, Z, _, _ = self.dims
-        yxh = self.fat_eo_pp[0].shape[-1]
-        psi0 = jnp.zeros((3, 2, T, Z, yxh), self.store_dtype)
-        improved = self.long_eo_pp is not None
-        cands = {k: jax.jit(f)
-                 for k, f in self._form_candidates().items()}
-        aux = (f"{'fat_naik' if improved else 'fat'}|"
-               f"{jnp.dtype(self.store_dtype).name}")
-        # provenance for the construction notice: a winner already
-        # raced on THIS chip (platform-keyed tunecache) is served
-        # without re-racing
-        self._form_from_warm_cache = qtune.cached_param(
-            "staggered_eo_form", self.dims, aux=aux) is not None
-        return qtune.tune(
-            "staggered_eo_form", self.dims, cands, (psi0,), aux=aux)
 
     # -- sharded dispatch (the QUDA_TPU_SHARDED_POLICY seam) ------------
     def _build_sharded_fn(self, target_parity, out_dtype, policy):
@@ -800,32 +554,6 @@ class DiracStaggeredPCPairs(_ProgramOperand):
             if self._mesh is not None:
                 fn = self._sharded_d_to(p, out_dtype)
                 return fn(*self._sharded_args(p), psi_pp)
-            if self._pallas_form == "fused":
-                if getattr(self, "_precision_form", "full") == "fold":
-                    from ..ops import wilson_pallas_packed as wpp
-                    out = spl.dslash_staggered_eo_pallas_fused_fold(
-                        self.fat_eo_pp[p], self.fat_eo_pp[1 - p],
-                        wpp.to_fold(psi_pp), self.dims, p,
-                        long_here_f=(self.long_eo_pp[p]
-                                     if self.long_eo_pp is not None
-                                     else None),
-                        long_there_f=(self.long_eo_pp[1 - p]
-                                      if self.long_eo_pp is not None
-                                      else None),
-                        interpret=self._pallas_interpret,
-                        out_dtype=out_dtype)
-                    return wpp.from_fold(out)
-                sg = getattr(self, "_long_sign", None)
-                return spl.dslash_staggered_eo_pallas_fused(
-                    self.fat_eo_pp[p], self.fat_eo_pp[1 - p], psi_pp,
-                    self.dims, p,
-                    long_here_pl=self.long_eo_pp[p],
-                    long_there_pl=self.long_eo_pp[1 - p],
-                    long_sign_here_pl=sg[p] if sg is not None else None,
-                    long_sign_there_pl=(sg[1 - p] if sg is not None
-                                        else None),
-                    interpret=self._pallas_interpret,
-                    out_dtype=out_dtype)
             if self._pallas_form == "v3":
                 return spl.dslash_staggered_eo_pallas_v3(
                     self.fat_eo_pp[p], self.fat_eo_pp[1 - p], psi_pp,
@@ -850,7 +578,7 @@ class DiracStaggeredPCPairs(_ProgramOperand):
 
     def _d_to_mrhs(self, psi_b, target_parity, out_dtype=None):
         """Batched eo hop: psi_b (N,3,2,T,Z,Y*Xh), by ``_mrhs_form``
-        (see ``MEASURED_MRHS_FORMS``).  The MRHS kernels fetch the
+        (see ``served_forms``).  The MRHS kernels fetch the
         fat/long tiles once per (t, z-block) and stream the N spinor
         tiles through them; ``vmap`` is the single-RHS stencil per
         source.  Counted per traced call in

@@ -249,18 +249,6 @@ _FOOTPRINTS: Dict[str, dict] = {
                          "floor": lambda n: _G + 2 * _SPSI},
     "staggered_fat_naik_v3": {"family": "staggered_fat_naik",
                               "floor": lambda n: 2 * _G + 2 * _SPSI},
-    "staggered_fat_naik_fused": {"family": "staggered_fat_naik",
-                                 "floor": lambda n: 2 * _G + 2 * _SPSI},
-    # fused precision forms: non-eo operand basis like the fused row
-    # (fat + long link arrays + psi + out).  r12 swaps the long array
-    # for its R=2 storage + the streamed f32 sign plane (16 B/site);
-    # fold is a layout change at unchanged byte count
-    "staggered_fat_naik_fused_r12": {
-        "family": "staggered_fat_naik",
-        "floor": lambda n: _G + _G12 + 16.0 + 2 * _SPSI},
-    "staggered_fat_naik_fused_fold": {
-        "family": "staggered_fat_naik",
-        "floor": lambda n: 2 * _G + 2 * _SPSI},
     "staggered_mrhs": {"family": "staggered_fat_naik",
                        "floor": lambda n: 4 * _G / n + 2 * _SPSI},
     "staggered_fat_mrhs": {"family": "staggered_fat",

@@ -117,28 +117,8 @@ KERNEL_MODELS: Dict[str, dict] = {
     "staggered_fat_v3": {"flops_per_site": 570, "bytes_per_site": 456},
     "staggered_fat_naik_v3": {"flops_per_site": 1146,
                               "bytes_per_site": 984},
-    # FUSED single-pass fat+Naik (round 10 tentpole): one launch, one
-    # psi read, no XLA sum pass, no backward-link arrays — psi 5x24 +
-    # fat/long fwd links 2x288 + U_t planes at t-1/t-3 2x72 + out 24
-    # (z boundary rows are O(1/bz)).  1.75x less traffic than two-pass
-    "staggered_fat_naik_fused": {"flops_per_site": 1146,
-                                 "bytes_per_site": 864},
-    # fused + Naik-link recon-12 (PERF.md round 16): the LONG links are
-    # ±SU(3) after KS-phase folding, so only that hop set compresses
-    # (fat links are smeared sums — not unitary, no reconstruction):
-    # long fwd 288 -> 192 (-96), long t-plane 72 -> 48 (-24), plus the
-    # streamed f32 sign plane 4x4 B = 16 and its t-plane 4:
-    # 864 - 96 - 24 + 16 + 4 = 764
-    "staggered_fat_naik_fused_r12": {"flops_per_site": 1146,
-                                     "bytes_per_site": 764},
-    # fused + re/im sublane fold: full R=3 rows, same logical bytes —
-    # the row exists for the bf16 full-tile A/B (tile shape, not byte
-    # count, is what changes; measured points must not alias the
-    # unfolded fused attribution)
-    "staggered_fat_naik_fused_fold": {"flops_per_site": 1146,
-                                      "bytes_per_site": 864},
     # MRHS staggered, links amortized over N.  improved: the served
-    # scatter two-pass body (models/staggered.MEASURED_MRHS_FORMS) =
+    # scatter two-pass body (models/staggered.served_forms) =
     # 2 passes x (psi 72 + out 24) + sum 72 + 1152/N links; fat-only:
     # one gather pass (psi 120 + out 24), no sum
     "staggered_mrhs": {"flops_per_site": 1146,

@@ -136,8 +136,7 @@ def axpy_norm2(a, x, y):
 def triple_cg_update(a, p, Ap, x, r):
     """x += a p; r -= a Ap; return (x, r, |r|^2) — the fused CG-iteration
     tail (blas::axpyNorm-style): both updates and the residual reduction
-    share one traversal under jit.  Single-pass pallas form:
-    ops/blas_pallas.cg_update_norm2_pallas."""
+    share one traversal under jit."""
     xn = x + a * p
     rn = r - a * Ap
     return xn, rn, norm2(rn)

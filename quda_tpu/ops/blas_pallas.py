@@ -1,27 +1,21 @@
-"""Fused update+reduce BLAS kernels (pallas) — the CG tail in one VMEM pass.
+"""BLAS-shaped pallas kernels on the pair representation, and the
+row-block arithmetic they share with other kernels of this layout.
 
-Reference behavior: QUDA's update+reduce kernels (axpyNorm2 and friends,
-include/kernels/reduce_core.cuh:668, blas_core.cuh) exist because the CG
-tail is bandwidth-bound: fusing the vector update with the reduction
-halves its HBM traffic versus separate kernels.  Under jax.jit XLA
-usually performs that fusion, but the solver measurements are the product
-(VERDICT round 5), so the fusion must be *ownable*: these kernels pin the
-single-pass shape explicitly — each grid step streams one row-block
-through VMEM, applies the axpy family update, writes the result, and
-folds the block's partial |.|^2 into an SMEM accumulator.
+``multishift_update_pallas`` is the live-prefix update of the
+multi-shift CG (solvers/multishift.py): each grid step streams one
+row-block of one shift through VMEM, in place.  ``_vmem_budget``,
+``_tile_bytes`` and ``_pick_rows`` are the budget and the legal
+row-blocks of an (rows, lanes) view (lanes = the trailing axis; block
+second-to-minor extent divisible by 8 or equal to the array extent —
+interpret mode does not enforce it, hardware does); ops/dwf_pallas.py
+picks its blocks with them too.
+
+The CG tail (x += a p; r -= a Ap; |r|^2) has no kernel here: XLA fuses
+it under jit, in fewer passes on the chip than a kernel of its own
+made (ROADMAP C5).
 
 Layout: any REAL array (the pair-form representation every TPU solve
-uses; complex solves keep the jnp path in ops/blas.py).  The array is
-viewed as (rows, lanes) with lanes = the trailing axis; row-blocks obey
-the Mosaic legality rule learned in round 5 (block second-to-minor extent
-divisible by 8 or equal to the array extent — interpret mode does not
-enforce it, hardware does).
-
-Accumulation order note: the scalar is the sequential sum of per-block
-partials, which can differ from jnp.sum's reduction tree in the last
-ulp(s); the update outputs are bitwise identical to the unfused
-ops/blas.py path.  tests/test_fused_iter.py pins both properties in
-interpreter mode.
+uses; complex solves keep the jnp path in ops/blas.py).
 """
 
 from __future__ import annotations
@@ -69,103 +63,6 @@ def _pick_rows(R: int, C: int, nbufs: int, itemsize: int = 4) -> int:
 
 def _as2d(x):
     return x.reshape(-1, x.shape[-1])
-
-
-@functools.partial(jax.jit, static_argnames=("interpret", "block_rows"))
-def cg_update_norm2_pallas(alpha, p, Ap, x, r, interpret: bool = False,
-                           block_rows: int | None = None):
-    """x' = x + alpha p; r' = r - alpha Ap; return (x', r', |r'|^2), all
-    in ONE pass over the operands (blas.triple_cg_update as a single
-    pallas kernel).  Real arrays only (pair representation); bf16
-    storage computes in f32 and the norm is taken on the ROUNDED stored
-    value, matching the unfused codec semantics."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    shape = x.shape
-    C = shape[-1]
-    R = x.size // C
-    br = block_rows if block_rows is not None else _pick_rows(R, C, 6)
-    if R % br != 0:
-        raise ValueError(f"block_rows={br} does not divide rows={R}")
-    a2d = jnp.reshape(alpha.astype(F32), (1, 1))
-
-    def kernel(a_ref, p_ref, ap_ref, x_ref, r_ref, xo_ref, ro_ref,
-               acc_ref):
-        a = a_ref[0, 0]
-        xo = x_ref[...].astype(F32) + a * p_ref[...].astype(F32)
-        ro = r_ref[...].astype(F32) - a * ap_ref[...].astype(F32)
-        xo_ref[...] = xo.astype(xo_ref.dtype)
-        ro_s = ro.astype(ro_ref.dtype)
-        ro_ref[...] = ro_s
-        rf = ro_s.astype(F32)
-
-        @pl.when(pl.program_id(0) == 0)
-        def _():
-            acc_ref[0, 0] = jnp.float32(0.0)
-        acc_ref[0, 0] += jnp.sum(rf * rf).astype(F32)
-
-    smem = pl.BlockSpec((1, 1), lambda i: (0, 0),
-                        memory_space=pltpu.SMEM)
-    blk = pl.BlockSpec((br, C), lambda i: (i, 0),
-                       memory_space=pltpu.VMEM)
-    xo, ro, acc = pl.pallas_call(
-        kernel,
-        grid=(R // br,),
-        in_specs=[smem, blk, blk, blk, blk],
-        out_specs=[blk, blk, smem],
-        out_shape=[jax.ShapeDtypeStruct((R, C), x.dtype),
-                   jax.ShapeDtypeStruct((R, C), r.dtype),
-                   jax.ShapeDtypeStruct((1, 1), F32)],
-        interpret=interpret,
-    )(a2d, _as2d(p), _as2d(Ap), _as2d(x), _as2d(r))
-    return xo.reshape(shape), ro.reshape(shape), acc[0, 0]
-
-
-@functools.partial(jax.jit, static_argnames=("interpret", "block_rows"))
-def axpy_norm2_pallas(a, x, y, interpret: bool = False,
-                      block_rows: int | None = None):
-    """y' = y + a x; return (y', |y'|^2) in one VMEM pass — the
-    blas::axpyNorm2 bundle (include/kernels/reduce_core.cuh:668) as a
-    pallas kernel.  Real arrays only; the norm is taken on the value
-    rounded to y's storage dtype (codec semantics)."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    shape = y.shape
-    C = shape[-1]
-    R = y.size // C
-    br = block_rows if block_rows is not None else _pick_rows(R, C, 4)
-    if R % br != 0:
-        raise ValueError(f"block_rows={br} does not divide rows={R}")
-    a2d = jnp.reshape(a.astype(F32), (1, 1))
-
-    def kernel(a_ref, x_ref, y_ref, yo_ref, acc_ref):
-        av = a_ref[0, 0]
-        yo = y_ref[...].astype(F32) + av * x_ref[...].astype(F32)
-        yo_s = yo.astype(yo_ref.dtype)
-        yo_ref[...] = yo_s
-        yf = yo_s.astype(F32)
-
-        @pl.when(pl.program_id(0) == 0)
-        def _():
-            acc_ref[0, 0] = jnp.float32(0.0)
-        acc_ref[0, 0] += jnp.sum(yf * yf).astype(F32)
-
-    smem = pl.BlockSpec((1, 1), lambda i: (0, 0),
-                        memory_space=pltpu.SMEM)
-    blk = pl.BlockSpec((br, C), lambda i: (i, 0),
-                       memory_space=pltpu.VMEM)
-    yo, acc = pl.pallas_call(
-        kernel,
-        grid=(R // br,),
-        in_specs=[smem, blk, blk],
-        out_specs=[blk, smem],
-        out_shape=[jax.ShapeDtypeStruct((R, C), y.dtype),
-                   jax.ShapeDtypeStruct((1, 1), F32)],
-        interpret=interpret,
-    )(a2d, _as2d(x), _as2d(y))
-    return yo.reshape(shape), acc[0, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_rows"))

@@ -30,15 +30,14 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .wilson_pallas_packed import (_cadd, _cmul, _cmul_conj, _fold_tile,
-                                   _pick_bz, _recon12_wrap, _shift_xy,
-                                   _unfold_tile)
+from .wilson_pallas_packed import (_cadd, _cmul, _cmul_conj, _pick_bz,
+                                   _shift_xy)
 
 F32 = jnp.float32
 
 # Per-kernel VMEM budget: the staggered family picks z-blocks against
-# its OWN knob (raised default — the fused fat+Naik working set needs
-# it) while the Wilson kernels keep the proven 6 MB default.
+# its OWN knob (raised default) while the Wilson kernels keep the proven
+# 6 MB default.
 _STAG_VMEM_KNOB = "QUDA_TPU_PALLAS_VMEM_MB_STAGGERED"
 
 
@@ -300,17 +299,11 @@ def _link_at(ref, mu, a, b):
             ref[(mu, a, b, 1, 0) + pad].astype(F32))
 
 
-def _stag_link(ref, mu, row2_sign=None, link_at=None):
-    """(a, b) -> (re, im) link accessor: stored rows from an R=3 ref,
-    or in-kernel recon-12 of the third row from an R=2 ref (the shared
-    _recon12_wrap algebra).  For the Naik links the KS phase folding
-    leaves a ±SU(3) matrix, so the reconstructed (unit-determinant) row
-    is re-signed by the per-(mu, site) ``row2_sign`` plane
-    (ops/su3.to_recon12_signed).  ``link_at`` swaps the stored-element
-    reader (the fold variant injects its interleaved-row reader)."""
-    at = link_at or _link_at
-    return _recon12_wrap(lambda a, b: at(ref, mu, a, b),
-                         ref.shape[1], row2_sign)
+def _stag_link(ref, mu):
+    """(a, b) -> (re, im) accessor of direction ``mu``'s stored link
+    elements (full R=3 storage: fat links are smeared sums, never
+    reconstructable, and the Naik links are served as stored)."""
+    return lambda a, b: _link_at(ref, mu, a, b)
 
 
 def _mul3(get_psi, get_link, adjoint, scale):
@@ -329,35 +322,16 @@ def _mul3(get_psi, get_link, adjoint, scale):
 
 def _accumulate_hopset(acc, psi_c, psi_tp, psi_tm, psi_zp, psi_zm,
                        u, u_bwd, u_t_tm, u_z_zm, nhop: int,
-                       shift_x, shift_y, single_zb: bool, signs=None,
-                       psi_at=None, link_at=None):
+                       shift_x, shift_y, single_zb: bool):
     """One scatter-form hop set (all 8 hops of one nhop) accumulated
-    into ``acc`` (list of 3 f32 color pairs, mutated in place).
-
-    The SINGLE home for the staggered scatter-form hop algebra: the v3
-    two-pass kernels run it once per launch, the fused fat+Naik kernel
-    runs it twice (nhop=1 with the fat refs, nhop=3 with the long refs)
-    into separate accumulators — so the fused output is bit-identical
-    to the XLA sum of the two v3 passes by construction.
+    into ``acc`` (list of 3 f32 color pairs, mutated in place): the
+    staggered scatter-form hop algebra, run once per launch by the v3
+    passes.
 
     ``u_bwd`` supplies the backward x/y/z links (the forward array, or
     the opposite-parity array for the checkerboarded variant); ``u_t_tm``
     is the U_t plane at t-nhop; ``u_z_zm`` the U_z boundary rows at
-    z-nhop (unread when ``single_zb``).
-
-    ``signs`` (recon-12 long links only) is
-    (sg_fwd, sg_bwd, sg_t, sg_z): per-(mu, site) ±1 planes re-signing
-    the reconstructed third row — callables mu -> plane for the
-    forward/backward link arrays, the t-nhop plane, and the z boundary
-    rows.  R=3 refs ignore them (_stag_link passes straight through).
-    ``psi_at`` / ``link_at`` swap the element readers (fold variant)."""
-    p_at = psi_at or _psi_at
-    if signs is None:
-        s_fwd = s_bwd = lambda mu: None
-        s_t = s_z = None
-    else:
-        s_fwd, s_bwd, s_t, s_z = signs
-
+    z-nhop (unread when ``single_zb``)."""
     def acc_add(vals):
         for a in range(3):
             acc[a] = _cadd(acc[a], vals[a])
@@ -365,10 +339,10 @@ def _accumulate_hopset(acc, psi_c, psi_tp, psi_tm, psi_zp, psi_zm,
     # x, y: forward = shift psi then multiply; backward = multiply
     # with LOCAL links then shift the product
     for mu, shifter in ((0, shift_x), (1, shift_y)):
-        acc_add(_mul3(lambda c: shifter(p_at(psi_c, c), +1),
-                      _stag_link(u, mu, s_fwd(mu), link_at), False, 0.5))
-        m = _mul3(lambda c: p_at(psi_c, c),
-                  _stag_link(u_bwd, mu, s_bwd(mu), link_at), True, -0.5)
+        acc_add(_mul3(lambda c: shifter(_psi_at(psi_c, c), +1),
+                      _stag_link(u, mu), False, 0.5))
+        m = _mul3(lambda c: _psi_at(psi_c, c),
+                  _stag_link(u_bwd, mu), True, -0.5)
         acc_add([shifter(mc, -1) for mc in m])
 
     # z forward: nhop-row splice of the shifted central tile (a pure
@@ -376,30 +350,30 @@ def _accumulate_hopset(acc, psi_c, psi_tp, psi_tm, psi_zp, psi_zm,
     if single_zb:
         acc_add(_mul3(
             lambda c: tuple(jnp.roll(p, -nhop, axis=0)
-                            for p in p_at(psi_c, c)),
-            _stag_link(u, 2, s_fwd(2), link_at), False, 0.5))
-        m = _mul3(lambda c: p_at(psi_c, c),
-                  _stag_link(u_bwd, 2, s_bwd(2), link_at), True, -0.5)
+                            for p in _psi_at(psi_c, c)),
+            _stag_link(u, 2), False, 0.5))
+        m = _mul3(lambda c: _psi_at(psi_c, c),
+                  _stag_link(u_bwd, 2), True, -0.5)
         acc_add([tuple(jnp.roll(p, nhop, axis=0) for p in mc)
                  for mc in m])
     else:
-        acc_add(_mul3(lambda c: _splice_z(p_at(psi_c, c),
-                                          p_at(psi_zp, c), +1, nhop),
-                      _stag_link(u, 2, s_fwd(2), link_at), False, 0.5))
+        acc_add(_mul3(lambda c: _splice_z(_psi_at(psi_c, c),
+                                          _psi_at(psi_zp, c), +1, nhop),
+                      _stag_link(u, 2), False, 0.5))
         # z backward: local product shifted down, boundary rows
         # built from the z-nhop psi/U_z row inputs
-        m = _mul3(lambda c: p_at(psi_c, c),
-                  _stag_link(u_bwd, 2, s_bwd(2), link_at), True, -0.5)
-        m_b = _mul3(lambda c: p_at(psi_zm, c),
-                    _stag_link(u_z_zm, 0, s_z, link_at), True, -0.5)
+        m = _mul3(lambda c: _psi_at(psi_c, c),
+                  _stag_link(u_bwd, 2), True, -0.5)
+        m_b = _mul3(lambda c: _psi_at(psi_zm, c),
+                    _stag_link(u_z_zm, 0), True, -0.5)
         acc_add([_splice_z(mc, mbc, -1, nhop)
                  for mc, mbc in zip(m, m_b)])
 
     # t: whole neighbour planes, no shift
-    acc_add(_mul3(lambda c: p_at(psi_tp, c),
-                  _stag_link(u, 3, s_fwd(3), link_at), False, 0.5))
-    acc_add(_mul3(lambda c: p_at(psi_tm, c),
-                  _stag_link(u_t_tm, 0, s_t, link_at), True, -0.5))
+    acc_add(_mul3(lambda c: _psi_at(psi_tp, c),
+                  _stag_link(u, 3), False, 0.5))
+    acc_add(_mul3(lambda c: _psi_at(psi_tm, c),
+                  _stag_link(u_t_tm, 0), True, -0.5))
 
 
 def _eo_mask_r0(pl, psi_c, bz, eo):
@@ -702,367 +676,6 @@ def dslash_staggered_eo_pallas(fat_here_pl, fat_bw_pl, psi_pl, dims,
     return out.astype(odt)
 
 
-# -- fused single-pass fat+Naik kernel (round 10) ---------------------------
-#
-# The two-pass improved-staggered form above exists only because the
-# COMBINED gather working set (9 psi neighbour tiles + 4 link tile sets)
-# busts the default 6 MB single-buffer VMEM budget — the exact
-# split-launch tax QUDA avoids by fusing all hop sets in one kernel
-# (include/kernels/dslash_staggered.cuh improved=true runs fat and long
-# hops in a single launch).  PERF.md round 8 measured the price: the
-# two-pass kernel reads ~1512 B/site (psi fetched twice, the shift
-# network paid twice, two resident backward-link copies, an XLA sum
-# pass) and lands at 26% of the effective bandwidth the same chip
-# streams on the Wilson v2 kernel.
-#
-# The fused kernel runs BOTH hop sets in one launch in scatter form
-# (the v3 backward-hop restructuring): one psi read, one out write, no
-# XLA sum pass, no backward-link arrays at all.  Per-site traffic:
-#
-#     psi   c + t+-1 + t+-3             5 * 24 = 120 B
-#     z boundary rows                   ~0 (O(1/bz))
-#     links fat fwd + long fwd       2 * 288 = 576 B
-#     U_t planes at t-1 and t-3       2 * 72 = 144 B
-#     out                                       24 B
-#     total                                   ~864 B/site
-#
-# at the same 1146 flops/site — 1.75x less traffic than two-pass.  The
-# hop algebra is _accumulate_hopset (shared with the v3 kernels), run
-# once per hop set into SEPARATE accumulators summed at the end, so the
-# fused output is bit-identical to the XLA sum of the two v3 passes.
-# The kernel is raced against the two-pass forms via utils.tune at
-# operator construction (models/staggered.py) — A/B'd, not assumed,
-# since the scatter form LOST for Wilson on chip (PERF.md round 5).
-#
-# Block legality: the z boundary rows are sliced DIRECTLY from the
-# adjacent block's edge (no bz % nhop reshape constraint — the v3
-# two-pass limitation), so any hardware-legal bz >= 3 serves both hop
-# sets; the budget comes from QUDA_TPU_PALLAS_VMEM_MB_STAGGERED.
-
-# fused working set: 5 psi tiles (30 planes) + fat + long (72 each) +
-# two U_t planes (18 each) + out (6) = 216 bz-row planes (+ tiny
-# nhop-row inputs); the EO variant adds fat/long there_xyz (54 each).
-# recon-12 long links drop the stored third row (u_lng 72->48,
-# u_t_lng 18->12, eo lng_there 54->36) and add the f32 ±sign planes
-# (4 fwd [+4 bwd eo] + 1 t).  Fold planes are counted in interleaved
-# (bz2 = 2*bz)-row units: half the bz-row-equivalent count.
-_STAG_PLANES_FUSED = 222
-_STAG_PLANES_FUSED_EO = 330
-_STAG_PLANES_FUSED_R12 = 197
-_STAG_PLANES_FUSED_EO_R12 = 291
-_STAG_PLANES_FUSED_FOLD = 108
-_STAG_PLANES_FUSED_EO_FOLD = 162
-
-
-def _make_stag_kernel_fused(X: int, bz: int, eo: tuple | None = None,
-                            single_zb: bool = False,
-                            long_r12: bool = False):
-    """Fused fat+Naik kernel over one (t, z-block) tile.  Ref shapes:
-      psi_c/tp1/tm1/tp3/tm3:  (3, 2, 1, bz, YX)
-      psi_zp1/zm1:            (3, 2, 1, 1, YX)   fat boundary rows
-      psi_zp3/zm3:            (3, 2, 1, 3, YX)   Naik boundary rows
-      u_fat / u_lng:          (4, R, 3, 2, 1, bz, YX) forward links
-      [fat/lng_there_xyz:     (3, R, 3, 2, 1, bz, YX)  eo only]
-      u_t_fat / u_t_lng:      (1, R, 3, 2, 1, bz, YX) U_t at t-1 / t-3
-      u_z_fat / u_z_lng:      (1, R, 3, 2, 1, nhop, YX) U_z rows
-    With ``long_r12`` the long-link refs carry R=2 stored rows and the
-    trailing sign refs re-sign the in-kernel reconstructed third row:
-      sg_lng [, sg_lng_bwd eo]: (4, 1, bz, YX)
-      sg_t_lng:                 (1, 1, bz, YX)  at t-3
-      sg_z_lng:                 (1, 1, 1, 3, YX) z boundary rows
-    """
-    from jax.experimental import pallas as pl
-
-    def kernel(*refs):
-        signs = None
-        if long_r12:
-            if eo is None:
-                *refs, sg_lng, sg_t_lng, sg_z_lng, out_ref = refs
-                sg_bwd_ref = sg_lng
-            else:
-                (*refs, sg_lng, sg_lng_bwd, sg_t_lng, sg_z_lng,
-                 out_ref) = refs
-                sg_bwd_ref = sg_lng_bwd
-            refs = tuple(refs) + (out_ref,)
-            signs = ((lambda mu: sg_lng[mu, 0]),
-                     (lambda mu: sg_bwd_ref[mu, 0]),
-                     sg_t_lng[0, 0], sg_z_lng[0, 0, 0])
-        if eo is None:
-            (psi_c, psi_tp1, psi_tm1, psi_tp3, psi_tm3,
-             psi_zp1, psi_zm1, psi_zp3, psi_zm3,
-             u_fat, u_lng, u_t_fat, u_t_lng, u_z_fat, u_z_lng,
-             out_ref) = refs
-            fat_bwd, lng_bwd = u_fat, u_lng
-            mask_r0 = None
-        else:
-            (psi_c, psi_tp1, psi_tm1, psi_tp3, psi_tm3,
-             psi_zp1, psi_zm1, psi_zp3, psi_zm3,
-             u_fat, u_lng, fat_there, lng_there,
-             u_t_fat, u_t_lng, u_z_fat, u_z_lng, out_ref) = refs
-            fat_bwd, lng_bwd = fat_there, lng_there
-            mask_r0 = _eo_mask_r0(pl, psi_c, bz, eo)
-
-        def zero_acc():
-            return [(jnp.zeros(psi_c.shape[-2:], F32),
-                     jnp.zeros(psi_c.shape[-2:], F32)) for _ in range(3)]
-
-        # fat (1-hop) and Naik (3-hop) sets into SEPARATE accumulators:
-        # out = acc_fat + acc_lng reproduces the two-pass XLA sum
-        # bit-for-bit (same adds in the same order)
-        acc_fat = zero_acc()
-        sx1, sy1 = _make_shifts(X, 1, eo, mask_r0)
-        _accumulate_hopset(acc_fat, psi_c, psi_tp1, psi_tm1, psi_zp1,
-                           psi_zm1, u_fat, fat_bwd, u_t_fat, u_z_fat,
-                           1, sx1, sy1, single_zb)
-        acc_lng = zero_acc()
-        sx3, sy3 = _make_shifts(X, 3, eo, mask_r0)
-        _accumulate_hopset(acc_lng, psi_c, psi_tp3, psi_tm3, psi_zp3,
-                           psi_zm3, u_lng, lng_bwd, u_t_lng, u_z_lng,
-                           3, sx3, sy3, single_zb, signs=signs)
-
-        odt = out_ref.dtype
-        for c in range(3):
-            out_ref[c, 0, 0] = (acc_fat[c][0] + acc_lng[c][0]).astype(odt)
-            out_ref[c, 1, 0] = (acc_fat[c][1] + acc_lng[c][1]).astype(odt)
-
-    return kernel
-
-
-def _psi_z_rows(psi_pl, bz: int, nhop: int, nzb: int):
-    """(rows_zp, rows_zm) boundary-row arrays (3,2,T,nzb,nhop,YX) for
-    the z splice, sliced DIRECTLY from each block's edge rows (legal for
-    any bz >= nhop, unlike the v3 q-reshape which needed bz % nhop)."""
-    c, two, T, Z, YX = psi_pl.shape
-    q = psi_pl.reshape(c, two, T, nzb, bz, YX)
-    rows_zp = jnp.roll(q[:, :, :, :, :nhop], -1, axis=3)
-    rows_zm = jnp.roll(q[:, :, :, :, bz - nhop:], 1, axis=3)
-    return rows_zp, rows_zm
-
-
-def _u_z_rows(src, bz: int, nhop: int, nzb: int):
-    """U_z boundary rows (1,3,3,2,T,nzb,nhop,YX) at z-nhop (the previous
-    block's last nhop rows of the mu=2 plane of ``src``)."""
-    R = src.shape[1]
-    T, Z, YX = src.shape[-3:]
-    uq = src[2:3].reshape(1, R, 3, 2, T, nzb, bz, YX)
-    return jnp.roll(uq[:, :, :, :, :, :, bz - nhop:], 1, axis=5)
-
-
-def _pick_bz_fused(Z, YX, dtype, eo: bool = False,
-                   long_r12: bool = False):
-    if eo:
-        planes = (_STAG_PLANES_FUSED_EO_R12 if long_r12
-                  else _STAG_PLANES_FUSED_EO)
-    else:
-        planes = (_STAG_PLANES_FUSED_R12 if long_r12
-                  else _STAG_PLANES_FUSED)
-    _require_naik_z(Z, True)
-    return _pick_bz(Z, YX, dtype, planes=planes,
-                    min_bz=3 if Z > 3 else 1,
-                    vmem_knob=_STAG_VMEM_KNOB)
-
-
-def _stag_fused_call(fat_pl, long_pl, psi_pl, X, bz, interpret, eo=None,
-                     fat_there_pl=None, long_there_pl=None,
-                     long_sign_pl=None, long_sign_there_pl=None):
-    from jax.experimental import pallas as pl
-
-    _, _, T, Z, YX = psi_pl.shape
-    nzb = Z // bz
-    _check_long_bz(Z, bz, True, "fused fat+Naik kernel")
-
-    long_r12 = long_pl.shape[1] == 2
-    if long_r12 and long_sign_pl is None:
-        raise ValueError(
-            "recon-12 long links (R=2) need their ±SU(3) sign planes "
-            "(ops/su3.to_recon12_signed) — long_sign_pl is None")
-    if long_r12 and eo is not None and long_sign_there_pl is None:
-        raise ValueError(
-            "checkerboarded recon-12 long links need the opposite-parity "
-            "sign planes too — long_sign_there_pl is None")
-
-    fat_bwd_src = fat_pl if fat_there_pl is None else fat_there_pl
-    lng_bwd_src = long_pl if long_there_pl is None else long_there_pl
-    sgn_bwd = (long_sign_pl if long_sign_there_pl is None
-               else long_sign_there_pl)
-    Rl = long_pl.shape[1]
-
-    if nzb == 1:
-        # single z-block: in-tile rolls serve every z shift; the row
-        # refs are unread — pass minimal dummies
-        rows_zp1 = rows_zm1 = jnp.zeros((3, 2, T, 1, 1, YX),
-                                        psi_pl.dtype)
-        rows_zp3 = rows_zm3 = jnp.zeros((3, 2, T, 1, 3, YX),
-                                        psi_pl.dtype)
-        u_z_fat = jnp.zeros((1, 3, 3, 2, T, 1, 1, YX), fat_bwd_src.dtype)
-        u_z_lng = jnp.zeros((1, Rl, 3, 2, T, 1, 3, YX),
-                            lng_bwd_src.dtype)
-        sg_z_rows = (jnp.zeros((1, T, 1, 3, YX), jnp.float32)
-                     if long_r12 else None)
-    else:
-        rows_zp1, rows_zm1 = _psi_z_rows(psi_pl, bz, 1, nzb)
-        rows_zp3, rows_zm3 = _psi_z_rows(psi_pl, bz, 3, nzb)
-        u_z_fat = _u_z_rows(fat_bwd_src, bz, 1, nzb)
-        u_z_lng = _u_z_rows(lng_bwd_src, bz, 3, nzb)
-        if long_r12:
-            sq = sgn_bwd[2:3].reshape(1, T, nzb, bz, YX)
-            sg_z_rows = jnp.roll(sq[:, :, :, bz - 3:], 1, axis=2)
-        else:
-            sg_z_rows = None
-
-    def psi_spec(dt):
-        return pl.BlockSpec(
-            (3, 2, 1, bz, YX),
-            lambda t, zb, dt=dt: (0, 0, (t + dt) % T, zb, 0))
-
-    def psi_row_spec(nhop):
-        return pl.BlockSpec((3, 2, 1, 1, nhop, YX),
-                            lambda t, zb: (0, 0, t, zb, 0, 0))
-
-    def links_spec(R):
-        return pl.BlockSpec(
-            (4, R, 3, 2, 1, bz, YX), lambda t, zb: (0, 0, 0, 0, t, zb, 0))
-
-    def links_xyz_spec(R):
-        return pl.BlockSpec(
-            (3, R, 3, 2, 1, bz, YX), lambda t, zb: (0, 0, 0, 0, t, zb, 0))
-
-    def u_t_spec(nhop, R):
-        return pl.BlockSpec(
-            (1, R, 3, 2, 1, bz, YX),
-            lambda t, zb, nhop=nhop: (3, 0, 0, 0, (t - nhop) % T, zb, 0))
-
-    def u_z_spec(nhop, R):
-        return pl.BlockSpec((1, R, 3, 2, 1, 1, nhop, YX),
-                            lambda t, zb: (0, 0, 0, 0, t, zb, 0, 0))
-
-    in_specs = [psi_spec(0), psi_spec(+1), psi_spec(-1),
-                psi_spec(+3), psi_spec(-3),
-                psi_row_spec(1), psi_row_spec(1),
-                psi_row_spec(3), psi_row_spec(3),
-                links_spec(3), links_spec(Rl)]
-    args = [psi_pl, psi_pl, psi_pl, psi_pl, psi_pl,
-            rows_zp1, rows_zm1, rows_zp3, rows_zm3, fat_pl, long_pl]
-    if fat_there_pl is not None:
-        in_specs += [links_xyz_spec(3), links_xyz_spec(Rl)]
-        args += [fat_there_pl, long_there_pl]
-    in_specs += [u_t_spec(1, 3), u_t_spec(3, Rl),
-                 u_z_spec(1, 3), u_z_spec(3, Rl)]
-    args += [fat_bwd_src, lng_bwd_src, u_z_fat, u_z_lng]
-    if long_r12:
-        sg_spec = pl.BlockSpec((4, 1, bz, YX),
-                               lambda t, zb: (0, t, zb, 0))
-        sg_t_spec = pl.BlockSpec((1, 1, bz, YX),
-                                 lambda t, zb: (3, (t - 3) % T, zb, 0))
-        sg_z_spec = pl.BlockSpec((1, 1, 1, 3, YX),
-                                 lambda t, zb: (0, t, zb, 0, 0))
-        if eo is None:
-            in_specs += [sg_spec, sg_t_spec, sg_z_spec]
-            args += [long_sign_pl, long_sign_pl, sg_z_rows]
-        else:
-            in_specs += [sg_spec, sg_spec, sg_t_spec, sg_z_spec]
-            args += [long_sign_pl, sgn_bwd, sgn_bwd, sg_z_rows]
-
-    return pl.pallas_call(
-        _make_stag_kernel_fused(X, bz, eo, single_zb=(nzb == 1),
-                                long_r12=long_r12),
-        grid=(T, nzb),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((3, 2, 1, bz, YX),
-                               lambda t, zb: (0, 0, t, zb, 0)),
-        out_shape=jax.ShapeDtypeStruct(psi_pl.shape, jnp.float32),
-        interpret=interpret,
-    )(*args)
-
-
-@functools.partial(jax.jit, static_argnames=("X", "interpret", "block_z",
-                                             "out_dtype"))
-def dslash_staggered_pallas_fused(fat_pl: jnp.ndarray, psi_pl: jnp.ndarray,
-                                  X: int, long_pl: jnp.ndarray = None,
-                                  long_sign_pl: jnp.ndarray = None,
-                                  interpret: bool = False,
-                                  block_z: int | None = None,
-                                  out_dtype=None) -> jnp.ndarray:
-    """Improved-staggered D psi in ONE pallas launch (fat + Naik fused,
-    scatter-form backward hops): ~864 B/site vs the two-pass 1512.
-    Matches staggered_packed.dslash_staggered_packed_pairs; layouts as
-    dslash_staggered_pallas (no backward-link arrays needed).
-
-    recon-12 long links: pass ``long_pl`` with R=2 stored rows
-    (wilson_pallas_packed.to_recon12 of the long links) plus
-    ``long_sign_pl`` (4, T, Z, YX) from ops/su3.to_recon12_signed — the
-    KS-folded Naik links are ±SU(3), so the in-kernel reconstructed
-    third row is re-signed per (mu, site).  Fat links are non-unitary
-    sums and always stay R=3."""
-    if long_pl is None:
-        raise ValueError(
-            "the fused kernel IS the fat+Naik fusion; fat-only "
-            "staggered has a single hop set — use "
-            "dslash_staggered_pallas / _v3 for it")
-    _, _, _, Z, YX = psi_pl.shape
-    _require_naik_z(Z, True)
-    long_r12 = long_pl.shape[1] == 2
-    if block_z is not None:
-        bz = block_z
-        if Z % bz != 0:
-            raise ValueError(f"block_z={bz} does not divide Z={Z}")
-    else:
-        bz = _pick_bz_fused(Z, YX, psi_pl.dtype, long_r12=long_r12)
-
-    out = _stag_fused_call(fat_pl, long_pl, psi_pl, X, bz, interpret,
-                           long_sign_pl=long_sign_pl)
-    odt = out_dtype or psi_pl.dtype
-    return out.astype(odt)
-
-
-@functools.partial(jax.jit, static_argnames=("dims", "target_parity",
-                                             "interpret", "block_z",
-                                             "out_dtype"))
-def dslash_staggered_eo_pallas_fused(fat_here_pl, fat_there_pl, psi_pl,
-                                     dims, target_parity: int,
-                                     long_here_pl=None, long_there_pl=None,
-                                     long_sign_here_pl=None,
-                                     long_sign_there_pl=None,
-                                     interpret: bool = False,
-                                     block_z: int | None = None,
-                                     out_dtype=None) -> jnp.ndarray:
-    """Checkerboarded fused fat+Naik hop — the improved-staggered CG
-    hot path in one launch.  Backward hops read the UNSHIFTED
-    opposite-parity links (both hop sets flip parity — odd nhop), so no
-    backward_links_eo copies exist anywhere.
-
-    recon-12 long links: R=2 ``long_*_pl`` plus the per-parity
-    ``long_sign_*_pl`` (4, T, Z, YXh) sign planes (see
-    dslash_staggered_pallas_fused) — ~764 B/site vs the full-storage
-    fused 864."""
-    if long_here_pl is None:
-        raise ValueError(
-            "the fused kernel IS the fat+Naik fusion; fat-only "
-            "staggered has a single hop set — use "
-            "dslash_staggered_eo_pallas / _v3 for it")
-    T, Z, Y, X = dims
-    Xh = X // 2
-    _, _, _, _, YXh = psi_pl.shape
-    _require_naik_z(Z, True)
-    long_r12 = long_here_pl.shape[1] == 2
-    if block_z is not None:
-        bz = block_z
-        if Z % bz != 0:
-            raise ValueError(f"block_z={bz} does not divide Z={Z}")
-    else:
-        bz = _pick_bz_fused(Z, YXh, psi_pl.dtype, eo=True,
-                            long_r12=long_r12)
-
-    out = _stag_fused_call(fat_here_pl, long_here_pl, psi_pl, X, bz,
-                           interpret, eo=(target_parity, Xh),
-                           fat_there_pl=fat_there_pl,
-                           long_there_pl=long_there_pl,
-                           long_sign_pl=long_sign_here_pl,
-                           long_sign_there_pl=long_sign_there_pl)
-    odt = out_dtype or psi_pl.dtype
-    return out.astype(odt)
-
-
 # -- multi-RHS (MRHS) variants: gauge-amortized staggered -------------------
 #
 # Same pipeline move as wilson_pallas_packed.dslash_pallas_packed_mrhs
@@ -1292,277 +905,4 @@ def dslash_staggered_eo_pallas_v3_mrhs(fat_here_pl, fat_there_pl, psi_pl,
         out = out + _stag_pass_v3_mrhs(long_here_pl, long_there_pl, psi_pl,
                                        X, 3, bz, interpret, eo)
     odt = out_dtype or psi_pl.dtype
-    return out.astype(odt)
-
-
-# -- full-tile fold variant of the fused kernel -----------------------------
-#
-# bf16 tiles are (16, 128): a bz-row re plane and its im plane each pad
-# to 16 sublanes, so bf16 storage wastes half of every tile at bz=8.
-# The fold layout (wilson_pallas_packed.to_fold) interleaves re/im into
-# the sublane axis — (3, 2, T, Z, YX) -> (3, T, 2Z, YX) with row 2k the
-# re row of z=k and row 2k+1 its im row — so a bz2=16 block is 8 z-sites
-# of both components filling the bf16 tile EXACTLY.  z shifts become
-# row shifts by 2*nhop (re/im move together); the kernel deinterleaves
-# a (2n, YX) tile into f32 (n, YX) re/im planes at load, runs the SAME
-# _accumulate_hopset algebra (bit-identical to the unfolded fused
-# kernel for equal storage dtype), and re-interleaves at store.
-# Full-storage links only (R=3): fold and recon-12 are raced as
-# ALTERNATIVE precision forms, not composed.
-
-
-def _psi_at_fold(ref, c):
-    """f32 (re, im) color planes from a FOLDED psi ref.  Center blocks
-    are (3, 1, bz2, YX); boundary-row inputs carry one extra singleton
-    z-block axis (3, 1, 1, nhop2, YX)."""
-    pad = (0,) * (len(ref.shape) - 4)
-    return _unfold_tile(ref[(c, 0) + pad])
-
-
-def _link_at_fold(ref, mu, a, b):
-    """f32 (re, im) link-element planes from a FOLDED link ref
-    ((4, R, 3, 1, bz2, YX) center / (1, R, 3, T-collapsed...) rows)."""
-    pad = (0,) * (len(ref.shape) - 6)
-    return _unfold_tile(ref[(mu, a, b, 0) + pad])
-
-
-def _psi_z_rows_fold(psi_f, bz2: int, nhop2: int, nzb: int):
-    """(rows_zp, rows_zm) folded boundary rows (3, T, nzb, nhop2, YX):
-    nhop z-sites = 2*nhop interleaved rows, contiguous at each block
-    edge (re/im of a site are adjacent rows)."""
-    c, T, Z2, YX = psi_f.shape
-    q = psi_f.reshape(c, T, nzb, bz2, YX)
-    rows_zp = jnp.roll(q[:, :, :, :nhop2], -1, axis=2)
-    rows_zm = jnp.roll(q[:, :, :, bz2 - nhop2:], 1, axis=2)
-    return rows_zp, rows_zm
-
-
-def _u_z_rows_fold(src_f, bz2: int, nhop2: int, nzb: int):
-    """Folded U_z boundary rows (1, R, 3, T, nzb, nhop2, YX) at z-nhop."""
-    R = src_f.shape[1]
-    T, Z2, YX = src_f.shape[-3:]
-    uq = src_f[2:3].reshape(1, R, 3, T, nzb, bz2, YX)
-    return jnp.roll(uq[:, :, :, :, :, bz2 - nhop2:], 1, axis=4)
-
-
-def _make_stag_kernel_fused_fold(X: int, bz2: int,
-                                 eo: tuple | None = None,
-                                 single_zb: bool = False):
-    """Fused fat+Naik kernel on the FOLDED layout.  Ref shapes:
-      psi_c/tp1/tm1/tp3/tm3:  (3, 1, bz2, YX)
-      psi_zp1/zm1:            (3, 1, 1, 2, YX)   fat boundary rows
-      psi_zp3/zm3:            (3, 1, 1, 6, YX)   Naik boundary rows
-      u_fat / u_lng:          (4, 3, 3, 1, bz2, YX)
-      [fat/lng_there_xyz:     (3, 3, 3, 1, bz2, YX)  eo only]
-      u_t_fat / u_t_lng:      (1, 3, 3, 1, bz2, YX) at t-1 / t-3
-      u_z_fat / u_z_lng:      (1, 3, 3, 1, 1, nhop2, YX)
-    Accumulation runs on unfolded f32 (bz, YX) planes (bz = bz2 // 2) —
-    the same _accumulate_hopset calls as the unfolded fused kernel."""
-    from jax.experimental import pallas as pl
-
-    bz = bz2 // 2
-
-    def kernel(*refs):
-        if eo is None:
-            (psi_c, psi_tp1, psi_tm1, psi_tp3, psi_tm3,
-             psi_zp1, psi_zm1, psi_zp3, psi_zm3,
-             u_fat, u_lng, u_t_fat, u_t_lng, u_z_fat, u_z_lng,
-             out_ref) = refs
-            fat_bwd, lng_bwd = u_fat, u_lng
-            mask_r0 = None
-        else:
-            (psi_c, psi_tp1, psi_tm1, psi_tp3, psi_tm3,
-             psi_zp1, psi_zm1, psi_zp3, psi_zm3,
-             u_fat, u_lng, fat_there, lng_there,
-             u_t_fat, u_t_lng, u_z_fat, u_z_lng, out_ref) = refs
-            fat_bwd, lng_bwd = fat_there, lng_there
-            # the checkerboard mask lives on UNFOLDED (bz, YX) planes;
-            # _eo_mask_r0 would count interleaved rows as z sites
-            parity, Xh = eo
-            shape = (bz, psi_c.shape[-1])
-            z = (jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-                 + pl.program_id(1) * bz)
-            y = jax.lax.broadcasted_iota(jnp.int32, shape, 1) // Xh
-            mask_r0 = ((pl.program_id(0) + z + y + parity) % 2) == 0
-
-        def zero_acc():
-            return [(jnp.zeros((bz, psi_c.shape[-1]), F32),
-                     jnp.zeros((bz, psi_c.shape[-1]), F32))
-                    for _ in range(3)]
-
-        acc_fat = zero_acc()
-        sx1, sy1 = _make_shifts(X, 1, eo, mask_r0)
-        _accumulate_hopset(acc_fat, psi_c, psi_tp1, psi_tm1, psi_zp1,
-                           psi_zm1, u_fat, fat_bwd, u_t_fat, u_z_fat,
-                           1, sx1, sy1, single_zb,
-                           psi_at=_psi_at_fold, link_at=_link_at_fold)
-        acc_lng = zero_acc()
-        sx3, sy3 = _make_shifts(X, 3, eo, mask_r0)
-        _accumulate_hopset(acc_lng, psi_c, psi_tp3, psi_tm3, psi_zp3,
-                           psi_zm3, u_lng, lng_bwd, u_t_lng, u_z_lng,
-                           3, sx3, sy3, single_zb,
-                           psi_at=_psi_at_fold, link_at=_link_at_fold)
-
-        odt = out_ref.dtype
-        for c in range(3):
-            out_ref[c, 0] = _fold_tile(acc_fat[c][0] + acc_lng[c][0],
-                                       acc_fat[c][1] + acc_lng[c][1],
-                                       odt)
-
-    return kernel
-
-
-def _stag_fused_fold_call(fat_f, long_f, psi_f, X, bz2, interpret,
-                          eo=None, fat_there_f=None, long_there_f=None):
-    from jax.experimental import pallas as pl
-
-    _, T, Z2, YX = psi_f.shape
-    nzb = Z2 // bz2
-    _check_long_bz(Z2 // 2, bz2 // 2, True, "fused fold kernel")
-
-    fat_bwd_src = fat_f if fat_there_f is None else fat_there_f
-    lng_bwd_src = long_f if long_there_f is None else long_there_f
-
-    if nzb == 1:
-        rows_zp1 = rows_zm1 = jnp.zeros((3, T, 1, 2, YX), psi_f.dtype)
-        rows_zp3 = rows_zm3 = jnp.zeros((3, T, 1, 6, YX), psi_f.dtype)
-        u_z_fat = jnp.zeros((1, 3, 3, T, 1, 2, YX), fat_bwd_src.dtype)
-        u_z_lng = jnp.zeros((1, 3, 3, T, 1, 6, YX), lng_bwd_src.dtype)
-    else:
-        rows_zp1, rows_zm1 = _psi_z_rows_fold(psi_f, bz2, 2, nzb)
-        rows_zp3, rows_zm3 = _psi_z_rows_fold(psi_f, bz2, 6, nzb)
-        u_z_fat = _u_z_rows_fold(fat_bwd_src, bz2, 2, nzb)
-        u_z_lng = _u_z_rows_fold(lng_bwd_src, bz2, 6, nzb)
-
-    def psi_spec(dt):
-        return pl.BlockSpec(
-            (3, 1, bz2, YX),
-            lambda t, zb, dt=dt: (0, (t + dt) % T, zb, 0))
-
-    def psi_row_spec(nhop2):
-        return pl.BlockSpec((3, 1, 1, nhop2, YX),
-                            lambda t, zb: (0, t, zb, 0, 0))
-
-    links_spec = pl.BlockSpec(
-        (4, 3, 3, 1, bz2, YX), lambda t, zb: (0, 0, 0, t, zb, 0))
-    links_xyz_spec = pl.BlockSpec(
-        (3, 3, 3, 1, bz2, YX), lambda t, zb: (0, 0, 0, t, zb, 0))
-
-    def u_t_spec(nhop):
-        return pl.BlockSpec(
-            (1, 3, 3, 1, bz2, YX),
-            lambda t, zb, nhop=nhop: (3, 0, 0, (t - nhop) % T, zb, 0))
-
-    def u_z_spec(nhop2):
-        return pl.BlockSpec((1, 3, 3, 1, 1, nhop2, YX),
-                            lambda t, zb: (0, 0, 0, t, zb, 0, 0))
-
-    in_specs = [psi_spec(0), psi_spec(+1), psi_spec(-1),
-                psi_spec(+3), psi_spec(-3),
-                psi_row_spec(2), psi_row_spec(2),
-                psi_row_spec(6), psi_row_spec(6),
-                links_spec, links_spec]
-    args = [psi_f, psi_f, psi_f, psi_f, psi_f,
-            rows_zp1, rows_zm1, rows_zp3, rows_zm3, fat_f, long_f]
-    if fat_there_f is not None:
-        in_specs += [links_xyz_spec, links_xyz_spec]
-        args += [fat_there_f, long_there_f]
-    in_specs += [u_t_spec(1), u_t_spec(3), u_z_spec(2), u_z_spec(6)]
-    args += [fat_bwd_src, lng_bwd_src, u_z_fat, u_z_lng]
-
-    return pl.pallas_call(
-        _make_stag_kernel_fused_fold(X, bz2, eo, single_zb=(nzb == 1)),
-        grid=(T, nzb),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((3, 1, bz2, YX),
-                               lambda t, zb: (0, t, zb, 0)),
-        out_shape=jax.ShapeDtypeStruct(psi_f.shape, jnp.float32),
-        interpret=interpret,
-    )(*args)
-
-
-def _fold_bz2(Z2, YX, dtype, eo: bool):
-    planes = (_STAG_PLANES_FUSED_EO_FOLD if eo
-              else _STAG_PLANES_FUSED_FOLD)
-    bz2 = _pick_bz(Z2, YX, dtype, planes=planes,
-                   min_bz=6 if Z2 > 6 else 2,
-                   vmem_knob=_STAG_VMEM_KNOB, allow_bzfull=True)
-    if bz2 % 2 != 0:
-        raise ValueError(
-            f"fold block_z2={bz2} must be even (re/im row pairs)")
-    return bz2
-
-
-def _fold_links_r3(name, *arrs):
-    for a in arrs:
-        if a is not None and a.shape[1] != 3:
-            raise ValueError(
-                f"{name}: folded links must be full storage (R=3, got "
-                f"R={a.shape[1]}) — fold and recon-12 are alternative "
-                "precision forms, raced, not composed")
-
-
-@functools.partial(jax.jit, static_argnames=("X", "interpret", "block_z2",
-                                             "out_dtype"))
-def dslash_staggered_pallas_fused_fold(fat_f, psi_f, X: int, long_f=None,
-                                       interpret: bool = False,
-                                       block_z2: int | None = None,
-                                       out_dtype=None) -> jnp.ndarray:
-    """Fused fat+Naik D psi on the FOLDED layout (to_fold of every
-    operand; returns the folded result).  Bit-matches
-    dslash_staggered_pallas_fused for equal storage dtype; with bf16
-    storage the interleaved rows fill (16, 128) tiles exactly."""
-    if long_f is None:
-        raise ValueError("fused fold kernel needs the Naik links")
-    _fold_links_r3("dslash_staggered_pallas_fused_fold", fat_f, long_f)
-    _, _, Z2, YX = psi_f.shape
-    _require_naik_z(Z2 // 2, True)
-    if block_z2 is not None:
-        bz2 = block_z2
-        if Z2 % bz2 != 0 or bz2 % 2 != 0:
-            raise ValueError(
-                f"block_z2={bz2} must evenly divide 2*Z={Z2} and be even")
-    else:
-        bz2 = _fold_bz2(Z2, YX, psi_f.dtype, eo=False)
-
-    out = _stag_fused_fold_call(fat_f, long_f, psi_f, X, bz2, interpret)
-    odt = out_dtype or psi_f.dtype
-    return out.astype(odt)
-
-
-@functools.partial(jax.jit, static_argnames=("dims", "target_parity",
-                                             "interpret", "block_z2",
-                                             "out_dtype"))
-def dslash_staggered_eo_pallas_fused_fold(fat_here_f, fat_there_f, psi_f,
-                                          dims, target_parity: int,
-                                          long_here_f=None,
-                                          long_there_f=None,
-                                          interpret: bool = False,
-                                          block_z2: int | None = None,
-                                          out_dtype=None) -> jnp.ndarray:
-    """Checkerboarded fused fat+Naik hop on the FOLDED layout — the
-    bf16 full-tile staggered form (QUDA_TPU_PRECISION_FORM=fold).  All
-    operands are to_fold views of the eo pallas-layout arrays; the
-    folded output converts back with from_fold."""
-    if long_here_f is None:
-        raise ValueError("fused fold kernel needs the Naik links")
-    _fold_links_r3("dslash_staggered_eo_pallas_fused_fold",
-                   fat_here_f, fat_there_f, long_here_f, long_there_f)
-    T, Z, Y, X = dims
-    Xh = X // 2
-    _, _, Z2, YXh = psi_f.shape
-    _require_naik_z(Z, True)
-    if block_z2 is not None:
-        bz2 = block_z2
-        if Z2 % bz2 != 0 or bz2 % 2 != 0:
-            raise ValueError(
-                f"block_z2={bz2} must evenly divide 2*Z={Z2} and be even")
-    else:
-        bz2 = _fold_bz2(Z2, YXh, psi_f.dtype, eo=True)
-
-    out = _stag_fused_fold_call(fat_here_f, long_here_f, psi_f, X, bz2,
-                                interpret, eo=(target_parity, Xh),
-                                fat_there_f=fat_there_f,
-                                long_there_f=long_there_f)
-    odt = out_dtype or psi_f.dtype
     return out.astype(odt)

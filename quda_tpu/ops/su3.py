@@ -393,30 +393,3 @@ def reconstruct12(r: jnp.ndarray) -> jnp.ndarray:
     a, b = r[..., 0, :], r[..., 1, :]
     c = jnp.conjugate(jnp.cross(a, b))
     return jnp.concatenate([r, c[..., None, :]], axis=-2)
-
-
-def to_recon12_signed(links_pl: jnp.ndarray):
-    """Signed reconstruct-12 on the PACKED PAIR layout — for +-SU(3)
-    links (staggered long links after KS phase folding: det = +-1, so
-    row2 = sign * conj(row0 x row1) with one sign per link matrix).
-
-    links_pl: (4, 3, 3, 2, T, Z, YX) f32 ->
-      rows01: (4, 2, 3, 2, T, Z, YX)  (the stored rows)
-      sign:   (4, T, Z, YX) f32 +-1   (per-(direction, site) row-2 sign)
-
-    The sign is extracted by projecting the STORED third row onto the
-    unsigned reconstruction: sign = sgn(Re<row2_stored, conj(r0 x r1)>)
-    — exact for +-SU(3), and the kernels multiply it back onto the
-    reconstructed row (the same row2_sign seam the Wilson antiperiodic-t
-    boundary uses)."""
-    re, im = links_pl[..., 0, :, :, :], links_pl[..., 1, :, :, :]
-    u = re + 1j * im                                    # (4,3,3,T,Z,YX)
-    a, b, c = u[:, 0], u[:, 1], u[:, 2]                 # rows, (4,3,T,Z,YX)
-    # conj(cross(r0, r1)) with the color axis explicit
-    def cr(i, j):
-        return a[:, i] * b[:, j] - a[:, j] * b[:, i]
-    recon = jnp.stack([jnp.conjugate(cr(1, 2)), jnp.conjugate(cr(2, 0)),
-                       jnp.conjugate(cr(0, 1))], axis=1)  # (4,3,T,Z,YX)
-    dot = jnp.sum(c * jnp.conjugate(recon), axis=1).real  # (4,T,Z,YX)
-    sign = jnp.where(dot < 0, -1.0, 1.0).astype(jnp.float32)
-    return links_pl[:, :2], sign

@@ -507,8 +507,8 @@ def _pick_bz(Z: int, YX: int, dtype=jnp.float32, planes: int = 288,
 
     ``vmem_knob`` names the registered budget knob — the Wilson kernels
     use the proven QUDA_TPU_PALLAS_VMEM_MB default; the staggered family
-    passes its per-kernel override (QUDA_TPU_PALLAS_VMEM_MB_STAGGERED),
-    whose raised default admits the fused fat+Naik working set.
+    passes its per-kernel override (QUDA_TPU_PALLAS_VMEM_MB_STAGGERED)
+    with its raised default.
 
     ``allow_bzfull=True`` adds a LAST-RESORT full-block candidate: when
     no divisor fits the double-buffered knob budget, bz=Z is admitted if

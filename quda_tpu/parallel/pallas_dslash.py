@@ -454,9 +454,8 @@ def _stag_term(u_slab, psi_slab, adjoint: bool):
 def _stag_fix_faces(out, links_fwd, links_bwd, psi_pl, nhop: int, fio,
                     name, n, mu, exchange=_exchange_xla):
     """Fat (nhop=1) or Naik (nhop=3) face fixes for one partitioned
-    direction, scatter-form conventions (the v3 two-pass kernels AND the
-    fused fat+Naik kernel — its backward hops wrap the locally-computed
-    product exactly like v3, so the same fixes serve both):
+    direction, scatter-form conventions (the v3 two-pass kernels: their
+    backward hops wrap the locally-computed product):
 
     * forward hop, HIGH face: psi(x + nhop*mu) must come from the next
       shard's first nhop planes/rows/columns (the kernel wrapped the

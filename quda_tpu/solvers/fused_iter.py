@@ -9,10 +9,8 @@ the TPU answer: one place where every CG/PCG iteration body is collapsed
 into the smallest number of memory passes, with two levers:
 
 * **Fused tail.**  The iteration tail (x += a p; r -= a Ap; |r|^2) runs
-  as ONE traversal — `blas.triple_cg_update` (XLA-fused) or the explicit
-  single-VMEM-pass pallas kernel
-  (`ops/blas_pallas.cg_update_norm2_pallas`, the reduce_core.cuh:668
-  axpyNorm2 analog; `QUDA_TPU_FUSED_TAIL=1` or ``use_pallas_tail``).
+  as ONE traversal — `blas.triple_cg_update`, fused by XLA under jit
+  (the reduce_core.cuh:668 axpyNorm2 analog).
   The residual norm that the tail produces is REUSED as the next
   iteration's rz (precond-free CG), so the unfused path's duplicate
   norm2 disappears structurally, not just by compiler CSE.
@@ -29,9 +27,7 @@ into the smallest number of memory passes, with two levers:
 Numerical deltas vs the pre-fusion solvers/cg.py loop (documented
 bit-tolerance): alpha/beta denominators are guarded with the dtype tiny
 (as mixed.cg_reliable always did) — identical results for any convergent
-HPD system; the pallas tail's scalar accumulates per-block partials
-sequentially, which can differ from jnp.sum's reduction tree in the last
-ulp(s) (see ops/blas_pallas.py).
+HPD system.
 """
 
 from __future__ import annotations
@@ -52,36 +48,21 @@ def _resolve_check_every(check_every) -> int:
     return max(1, int(check_every))
 
 
-def _resolve_pallas_tail(use_pallas_tail, b) -> bool:
-    if use_pallas_tail is None:
-        from ..utils import config as qconf
-        use_pallas_tail = str(qconf.get("QUDA_TPU_FUSED_TAIL",
-                                        fresh=True)) == "1"
-    # the pallas kernel serves real (pair-form) fields only; complex
-    # solves keep the jnp-fused tail
-    return bool(use_pallas_tail) and not jnp.iscomplexobj(b)
-
-
 def fused_cg(matvec: Callable, b: jnp.ndarray,
              x0: Optional[jnp.ndarray] = None, tol: float = 1e-10,
              maxiter: int = 1000, precond: Optional[Callable] = None,
              tol_hq: float = 0.0, check_every: Optional[int] = None,
-             use_pallas_tail: Optional[bool] = None,
-             pallas_interpret: Optional[bool] = None,
              record: bool = False) -> SolverResult:
     """CG/PCG with a fused iteration body and check-cadence amortisation.
 
     Semantics match solvers/cg.cg (which delegates here): convergence at
     |r|^2 <= tol^2 |b|^2, optional heavy-quark residual (tol_hq),
     optional preconditioner (flexible PCG, r.K(r) inner products).
-    ``check_every``/``use_pallas_tail`` default to the config knobs
-    QUDA_TPU_CG_CHECK_EVERY / QUDA_TPU_FUSED_TAIL;
-    ``pallas_interpret=None`` resolves to interpret mode on non-TPU
-    backends (so the env knob works on CPU hosts instead of failing to
-    lower).  Both the convergence check AND maxiter are evaluated at
-    cadence boundaries: with cadence k the solve can run up to k-1
-    iterations past convergence or past maxiter — ``iters`` always
-    reports the iterations actually executed.
+    ``check_every`` defaults to the config knob
+    QUDA_TPU_CG_CHECK_EVERY.  Both the convergence check AND maxiter
+    are evaluated at cadence boundaries: with cadence k the solve can
+    run up to k-1 iterations past convergence or past maxiter —
+    ``iters`` always reports the iterations actually executed.
 
     ``record=True`` threads a NaN-padded |r|^2 history buffer through
     the loop carry, written at every convergence-check point (slot i =
@@ -91,9 +72,6 @@ def fused_cg(matvec: Callable, b: jnp.ndarray,
     record=False the carry is unchanged — zero recording overhead.
     """
     check_every = _resolve_check_every(check_every)
-    pallas_tail = _resolve_pallas_tail(use_pallas_tail, b)
-    if pallas_interpret is None:
-        pallas_interpret = jax.default_backend() != "tpu"
     # breakdown sentinel (robust/sentinel.py): None when QUDA_TPU_ROBUST
     # =off — the loop below then traces EXACTLY the unguarded
     # computation (bit-identical compiled solve, pinned by test); the
@@ -121,24 +99,14 @@ def fused_cg(matvec: Callable, b: jnp.ndarray,
     p = z
     r2 = blas.norm2(r)
 
-    if pallas_tail:
-        from ..ops import blas_pallas as bpl
-
-        def tail(alpha, p, Ap, x, r):
-            return bpl.cg_update_norm2_pallas(alpha, p, Ap, x, r,
-                                              interpret=pallas_interpret)
-    else:
-        def tail(alpha, p, Ap, x, r):
-            return blas.triple_cg_update(alpha.astype(x.dtype), p, Ap,
-                                         x, r)
-
     def one_iter(x, r, p, rz, k):
         Ap = matvec(p)
         if fault_k is not None:
             Ap = finj.corrupt(Ap, k, fault_k)
         pAp = blas.redot(p, Ap).astype(rdt)
         alpha = rz / jnp.maximum(pAp, tiny)
-        x, r, r2 = tail(alpha, p, Ap, x, r)
+        x, r, r2 = blas.triple_cg_update(alpha.astype(x.dtype), p, Ap,
+                                         x, r)
         r2 = r2.astype(rdt)
         if precond is None:
             z, rz_new = r, r2

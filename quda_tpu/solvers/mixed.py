@@ -41,9 +41,8 @@ class StorageCodec(NamedTuple):
     array) and the sloppy storage; ``norm2``/``redot`` reduce in storage;
     ``axpy(a, x, y) = y + a*x`` for REAL scalar a, computed at f32 and
     rounded back to storage; ``axpy_norm2(a, x, y) = (y + a*x, |..|^2)``
-    is the fused update+reduce tail (one traversal — the
-    reduce_core.cuh:668 axpyNorm2 analog; optionally the single-pass
-    pallas kernel, ops/blas_pallas.py).  Two instances cover the TPU
+    is the fused update+reduce tail (one traversal under jit — the
+    reduce_core.cuh:668 axpyNorm2 analog).  Two instances cover the TPU
     ladder: a plain dtype cast (single sloppy) and bf16/int8 pair
     storage (half/quarter — see ops/pair.py).
     """
@@ -67,15 +66,11 @@ def dtype_codec(sloppy_dtype, precise_dtype) -> StorageCodec:
         axpy_norm2=_axpy_norm2)
 
 
-def _make_pair_codec(down, up, store_dtype, use_pallas_tail: bool = False,
-                     pallas_interpret: bool = False) -> StorageCodec:
+def _make_pair_codec(down, up, store_dtype) -> StorageCodec:
     """Shared reductions/axpy for every pair-storage layout — ONE home
     for the f32-accumulate rounding policy the reliable updates rely on;
-    layouts differ only in their down/up converters.  With
-    ``use_pallas_tail`` the fused update+reduce runs as the single-pass
-    pallas kernel (the norm is taken on the ROUNDED stored value in both
-    forms, so the semantics match bit-for-bit up to the documented
-    block-accumulation order)."""
+    layouts differ only in their down/up converters.  The fused
+    update+reduce takes its norm on the ROUNDED stored value."""
     from ..ops import pair as pops
     f32 = jnp.float32
 
@@ -83,17 +78,9 @@ def _make_pair_codec(down, up, store_dtype, use_pallas_tail: bool = False,
         return (y.astype(f32) + a.astype(f32) * x.astype(f32)
                 ).astype(store_dtype)
 
-    if use_pallas_tail:
-        from ..ops import blas_pallas as bpl
-
-        def axpy_norm2(a, x, y):
-            out, n2 = bpl.axpy_norm2_pallas(a, x, y,
-                                            interpret=pallas_interpret)
-            return out, n2
-    else:
-        def axpy_norm2(a, x, y):
-            out = axpy(a, x, y)
-            return out, pops.pair_norm2(out)
+    def axpy_norm2(a, x, y):
+        out = axpy(a, x, y)
+        return out, pops.pair_norm2(out)
 
     return StorageCodec(
         down=down, up=up,
@@ -119,43 +106,23 @@ def packed_pair_codec(store_dtype, precise_dtype) -> StorageCodec:
         lambda x: wpk.from_packed_pairs(x, precise_dtype), store_dtype)
 
 
-def pair_inplace_config(store_dtype, use_pallas_tail: Optional[bool] = None,
-                        pallas_interpret: Optional[bool] = None) -> tuple:
-    """``pair_inplace_codec``'s arguments with every ``None`` resolved
-    (knob and backend read HERE, outside any trace): the hashable triple
-    the cached solve program (solvers/program.py) keys on and rebuilds
-    the codec from inside its trace."""
-    if use_pallas_tail is None:
-        from ..utils import config as qconf
-        use_pallas_tail = str(qconf.get("QUDA_TPU_FUSED_TAIL",
-                                        fresh=True)) == "1"
-    if pallas_interpret is None:
-        pallas_interpret = jax.default_backend() != "tpu"
-    return (jnp.dtype(store_dtype), bool(use_pallas_tail),
-            bool(pallas_interpret))
+def pair_inplace_config(store_dtype):
+    """What of ``pair_inplace_codec`` the cached solve program
+    (solvers/program.py) keys on and rebuilds the codec from inside its
+    trace: the storage dtype, hashable, and nothing else."""
+    return jnp.dtype(store_dtype)
 
 
-def pair_inplace_codec(store_dtype, use_pallas_tail: Optional[bool] = None,
-                       pallas_interpret: Optional[bool] = None
-                       ) -> StorageCodec:
+def pair_inplace_codec(store_dtype) -> StorageCodec:
     """Codec for when the PRECISE representation is itself an f32 pair
     array on the SAME layout as the sloppy storage — the fully
     complex-free solve path (TPU runtimes without complex64 execution;
     also the zero-conversion native-order path).  down/up are plain
-    dtype casts.  ``use_pallas_tail`` routes the fused update+reduce
-    through the single-pass pallas kernel (ops/blas_pallas.py);
-    ``None`` defers to QUDA_TPU_FUSED_TAIL so the env knob reaches the
-    reliable-update loops of the complex-free API solves too (a knob
-    silently doing nothing is the failure mode utils/config.py exists
-    to kill).  ``pallas_interpret=None`` resolves to interpret mode on
-    non-TPU backends."""
-    store_dtype, use_pallas_tail, pallas_interpret = pair_inplace_config(
-        store_dtype, use_pallas_tail, pallas_interpret)
+    dtype casts."""
+    store_dtype = pair_inplace_config(store_dtype)
     return _make_pair_codec(
         lambda x: x.astype(store_dtype),
-        lambda x: x.astype(jnp.float32), store_dtype,
-        use_pallas_tail=use_pallas_tail,
-        pallas_interpret=pallas_interpret)
+        lambda x: x.astype(jnp.float32), store_dtype)
 
 
 def cg_reliable(matvec_hi: Callable, matvec_lo: Callable, b: jnp.ndarray,

@@ -24,7 +24,7 @@ in-process executable lookup.
   signature (class, dims, matpc, tb_sign, pallas version, block_z,
   precision form, storage dtype, pallas/interpret flags: the treedef),
   the operand avals, and the loop's own knobs below (``delta``, the
-  codec's fused-tail choice, ``check_every``, ``record``, the sentinel,
+  codec's storage dtype, ``check_every``, ``record``, the sentinel,
   the armed fault iteration, the multi-shift loop's form of its update:
   ``multishift.update_form``).  The key holds no array and no operator
   identity.
@@ -113,7 +113,7 @@ def _cg_reliable_program(op_hi, op_lo, b, tol, maxiter, key):
     return mixed.cg_reliable_loop(
         getattr(op_hi, mv), getattr(op_lo, mv), b, tol,
         knobs.maxiter if knobs.record else maxiter, delta,
-        mixed.pair_inplace_codec(*codec_cfg), knobs.record,
+        mixed.pair_inplace_codec(codec_cfg), knobs.record,
         knobs.sentinel, knobs.fault_k)
 
 

@@ -207,26 +207,10 @@ _register("QUDA_TPU_PALLAS_VMEM_MB", "float", 6.0,
 _register("QUDA_TPU_PALLAS_VMEM_MB_STAGGERED", "float", 9.0,
           "per-kernel single-buffer VMEM budget (MB) for the STAGGERED "
           "pallas z-block selection, overriding QUDA_TPU_PALLAS_VMEM_MB "
-          "for that family only.  The fused single-pass fat+Naik kernel "
-          "keeps both hop sets' link tiles and the t+-1/t+-3 psi tiles "
-          "resident (the split-launch form existed only because that "
-          "working set busts the 6 MB default at useful block sizes, "
-          "PERF.md round 8 lever (a)); the raised default admits it "
-          "while the Wilson kernels keep the measured-proven 6 MB",
+          "for that family only: the raised default is what the "
+          "staggered z-blocks were read on the chip with, while the "
+          "Wilson kernels keep the measured-proven 6 MB",
           reference="tune.cpp shared-bytes tuning axis (per-kernel)")
-_register("QUDA_TPU_STAGGERED_FORM", "choice", "",
-          "staggered/HISQ pallas kernel form: 'fused' = single-pass "
-          "fat+Naik (one launch, one psi read, no XLA sum pass), "
-          "'two_pass' = separate fat/long gather launches with "
-          "pre-shifted backward links (the pre-round-10 form), 'v3' = "
-          "two-pass scatter, 'auto' = race all forms via utils.tune at "
-          "operator construction and cache the winner per (volume, "
-          "dtype, improved); '' = the winner of the chip reading where "
-          "the hop set has one (models/staggered.MEASURED_FORMS: "
-          "fat+Naik serves v3, PERF.md PR 32), no race, else as 'auto'",
-          ("", "auto", "fused", "two_pass", "v3"),
-          reference="dslash policy selection; tune.cpp:862 — policies "
-                    "are timed, never assumed")
 _register("QUDA_TPU_CLOVER_FORM", "choice", "",
           "clover PC pair-operator form: 'pallas' = the fused v2 "
           "kernel with the resident 2x6x6 chiral clover blocks applied "
@@ -293,15 +277,6 @@ _register("QUDA_TPU_CG_CHECK_EVERY", "int", 1,
           "iterations past convergence — and past maxiter, which is "
           "also only checked at cadence boundaries",
           reference="lib/inv_cg_quda.cpp per-iteration convergence check")
-_register("QUDA_TPU_FUSED_TAIL", "choice", "",
-          "route the CG tail (x += a p; r -= a Ap; |r|^2) through the "
-          "fused pallas update+reduce kernel (ops/blas_pallas.py): '1' "
-          "force, '0'/empty = the XLA-fused jnp path (measure on chip "
-          "before pinning).  Covers fused_cg/cg AND the reliable-update "
-          "loops of the complex-free pair routes (pair_inplace_codec); "
-          "complex-dtype solves always use the jnp path",
-          ("", "0", "1"),
-          reference="include/kernels/reduce_core.cuh:668 axpyNorm2")
 _register("QUDA_TPU_MAX_MULTI_RHS", "int", 32,
           "cap on simultaneously batched right-hand sides in block "
           "solvers", reference="QUDA_MAX_MULTI_RHS")
@@ -637,6 +612,13 @@ SUBSUMED = {
     "QUDA_TPU_PALLAS_VERSION":  # quda-lint: disable=env-knob  reason=the retired knob's own entry: named so that a user who still sets it is told
         "the one Wilson pallas kernel generation (the v2 gather kernel; "
         "v1 and v3 were deleted in PR 30)",
+    "QUDA_TPU_STAGGERED_FORM":  # quda-lint: disable=env-knob  reason=the retired knob's own entry: named so that a user who still sets it is told
+        "models/staggered.served_forms: one hop form per operator shape "
+        "(fat+Naik on one chip serves v3; the fused kernels were deleted "
+        "in PR 45)",
+    "QUDA_TPU_FUSED_TAIL":  # quda-lint: disable=env-knob  reason=the retired knob's own entry: named so that a user who still sets it is told
+        "the XLA-fused CG tail, the only one (the pallas update+reduce "
+        "kernels were deleted in PR 45)",
     "QUDA_ALLOW_JIT": "jit is the only execution model",
     "QUDA_DEVICE_RESET": "PJRT owns device lifetime",
 }

@@ -26,8 +26,8 @@ Warm start: :func:`warm_start` (called by ``init_quda``) re-loads the
 persistent cache under the current resource path and mirrors the load —
 entry counts, stale drops, platform — into the obs trace stream, so a
 fresh worker's first solve hits the raced winners of previous processes
-(policy races included: QUDA_TPU_SHARDED_POLICY / QUDA_TPU_STAGGERED_FORM
-auto-races go through `tune` and therefore through this store) without a
+(policy races included: QUDA_TPU_SHARDED_POLICY's auto-races go
+through `tune` and therefore through this store) without a
 compile/race storm, and the warm-start behavior is auditable in the
 chrome artifact next to the solves it accelerated.
 """

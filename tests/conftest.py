@@ -135,15 +135,19 @@ def pytest_collection_modifyitems(config, items):
 # loadfile one, made to hand out the heaviest file that is left: longest
 # first keeps the last worker's tail short.  FILE_SECONDS is each
 # file's test time in PR 43's whole run (1,194 s, 7,035 test-seconds on
-# six workers), to the nearest ten, for files of 50 s and more.  It
+# six workers), to the nearest ten, for files of 50 s and more (PR 45
+# took interpreted kernels out of test_solve_program.py,
+# test_staggered_pallas.py, test_precision_forms.py and
+# test_fused_iter.py, now under 50 s: theirs are from PR 45's whole
+# run, 966 s and 5,601 test-seconds, scaled to that run's).  It
 # only orders the hand-out: a stale or missing entry costs balance and
 # nothing else.
 FILE_SECONDS = {
-    "test_solve_program.py": 630, "test_multirhs.py": 470,
-    "test_staggered_pallas.py": 420, "test_multirhs_kernels.py": 400,
-    "test_pallas.py": 360, "test_pair_mg.py": 360,
-    "test_precision_forms.py": 240, "test_clover_resident.py": 240,
-    "test_domain_wall.py": 270, "test_chip_compile.py": 220,
+    "test_solve_program.py": 550, "test_multirhs.py": 470,
+    "test_multirhs_kernels.py": 400, "test_pallas.py": 360,
+    "test_pair_mg.py": 360, "test_staggered_pallas.py": 360,
+    "test_domain_wall.py": 270, "test_clover_resident.py": 240,
+    "test_chip_compile.py": 220, "test_precision_forms.py": 160,
     "test_mixed.py": 210, "test_wilson_resident.py": 170,
     "test_interface.py": 170, "test_pair_gauge.py": 170,
     "test_twisted.py": 160, "test_serve.py": 150, "test_pair_eig.py": 130,

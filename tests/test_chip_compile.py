@@ -104,18 +104,6 @@ def _eo_mrhs(n, dt=F32, block_z=None, combine=False, lat=L, bt=2,
             [links, links, psi, psi, ((), F32)])
 
 
-def _cg_update(dt):
-    from quda_tpu.ops import blas_pallas as bp
-    v = _psi(dt)
-    return (bp.cg_update_norm2_pallas, [((), F32), v, v, v, v])
-
-
-def _axpy_norm2():
-    from quda_tpu.ops import blas_pallas as bp
-    v = _psi(F32)
-    return (bp.axpy_norm2_pallas, [((), F32), v, v])
-
-
 def _multishift_update(n, field):
     """The multi-shift loop's update of its live shifts on a stack of
     ``n`` pair fields (solvers/multishift.update_form takes it for
@@ -128,17 +116,20 @@ def _multishift_update(n, field):
              ((n,) + field, F32), (field, F32)])
 
 
-def _staggered_eo_fused():
+def _staggered_eo_v3(dt):
+    """The served single-source fat + Naik hop at 24^4
+    (models/staggered.served_forms): bf16 in the mixed CG's loop, f32
+    in the multi-shift loop and at every exit."""
     from quda_tpu.ops import staggered_pallas as sp
-    lk = ((4, 3, 3, 2, L, L, YXH), F32)
-    return (lambda fh, ft, p, lh, lt: sp.dslash_staggered_eo_pallas_fused(
+    lk = ((4, 3, 3, 2, L, L, YXH), dt)
+    return (lambda fh, ft, p, lh, lt: sp.dslash_staggered_eo_pallas_v3(
                 fh, ft, p, DIMS, 0, long_here_pl=lh, long_there_pl=lt),
-            [lk, lk, ((3, 2, L, L, YXH), F32), lk, lk])
+            [lk, lk, ((3, 2, L, L, YXH), dt), lk, lk])
 
 
 def _staggered_eo_mrhs(form, parity, n=8):
     """The batched fat + Naik hop at 24^4, N sources, f32: the served
-    scatter form (models/staggered.MEASURED_MRHS_FORMS) and the gather
+    scatter form (models/staggered.served_forms) and the gather
     form it was read against; the fourth and fifth operand are the
     other parity's links (scatter) or the pre-shifted backward links
     (gather)."""
@@ -209,15 +200,13 @@ CASES = {
         8, residual=True, lat=32, bt=1),
     "wilson_eo_mrhs_n8_residual_zblock": lambda: _eo_mrhs(
         8, block_z=8, residual=True),
-    "cg_update_norm2_f32": lambda: _cg_update(F32),
-    "cg_update_norm2_bf16": lambda: _cg_update(BF16),
-    "axpy_norm2_f32": _axpy_norm2,
     "multishift_update_n14_staggered": lambda: _multishift_update(
         14, (3, 2, L, L, YXH)),
     "multishift_update_n4_wilson": lambda: _multishift_update(
         4, (4, 3, 2, L, L, YXH)),
     # one case per other operator family the solve API routes to a kernel
-    "staggered_eo_fused": _staggered_eo_fused,
+    "staggered_eo_v3_f32": lambda: _staggered_eo_v3(F32),
+    "staggered_eo_v3_bf16": lambda: _staggered_eo_v3(BF16),
     "staggered_eo_mrhs_n8_scatter_even": lambda: _staggered_eo_mrhs(
         "scatter", 0),
     "staggered_eo_mrhs_n8_scatter_odd": lambda: _staggered_eo_mrhs(
@@ -325,7 +314,7 @@ def test_solve_program_compiles_for_v5e_with_links_as_parameters(one_chip):
                     s.shape, s.dtype, sharding=one_chip,
                     weak_type=s.weak_type), ops)
             b = jax.ShapeDtypeStruct(*_psi(F32), sharding=one_chip)
-            key = (0.1, mixed.pair_inplace_config(BF16, False, False),
+            key = (0.1, mixed.pair_inplace_config(BF16),
                    sprog._LoopKnobs(False, None, None, None), False)
             compiled = sprog._cg_reliable_program.lower(
                 hi, lo, b, 1e-6, 10000, key=key).compile()
@@ -413,7 +402,7 @@ def test_clover_solve_program_compiles_for_v5e_with_blocks_as_parameters(
                 s.shape, s.dtype, sharding=one_chip,
                 weak_type=s.weak_type), ops)
         b = jax.ShapeDtypeStruct(*_psi(F32), sharding=one_chip)
-        key = (0.1, mixed.pair_inplace_config(BF16, False, False),
+        key = (0.1, mixed.pair_inplace_config(BF16),
                sprog._LoopKnobs(False, None, None, None), False)
         return sprog._cg_reliable_program.lower(hi, lo, b, 1e-6, 10000,
                                                 key=key)
@@ -570,7 +559,7 @@ def test_hisq_solve_program_compiles_for_v5e_with_links_as_parameters(
     (solvers/program.py on DiracStaggeredPCPairs, ``hermitian``: the
     operator applied once an iteration) at 24^4: compiles for the
     described chip with the fat and long links of both operators as
-    parameters, and the served kernel form (MEASURED_FORMS: the
+    parameters, and the served kernel form (served_forms: the
     two-pass scatter form v3) is there for both, under the name the
     benchmark's metrics read."""
     import re
@@ -598,7 +587,7 @@ def test_hisq_solve_program_compiles_for_v5e_with_links_as_parameters(
         assert hi._pallas_form == lo._pallas_form == "v3"
         b = jax.ShapeDtypeStruct((3, 2, L, L, YXH), F32,
                                  sharding=one_chip)
-        key = (0.1, mixed.pair_inplace_config(BF16, False, False),
+        key = (0.1, mixed.pair_inplace_config(BF16),
                sprog._LoopKnobs(False, None, None, None), True)
         return sprog._cg_reliable_program.lower(hi, lo, b, 1e-6, 10000,
                                                 key=key)
@@ -624,7 +613,7 @@ def test_hisq_batched_programs_compile_for_v5e(one_chip, program):
     (solvers/program.py on the resident f32 DiracStaggeredPCPairs, 8
     sources at 24^4) compile for the described chip on abstract
     operands: the links are parameters, the served MRHS form
-    (MEASURED_MRHS_FORMS) is the kernel in them, and the Hermitian
+    (models/staggered.served_forms) is the kernel in them, and the Hermitian
     solve applies it four times an iteration (one M = two hops = four
     passes), not eight."""
     import re
@@ -798,7 +787,7 @@ def test_mobius_programs_compile_for_v5e_under_14_gib(one_chip, program):
                                  sharding=one_chip)
         if program == "verified-exit":
             return sprog._verified_exit_program.lower(hi, b, x)
-        key = (0.1, mixed.pair_inplace_config(BF16, False, False),
+        key = (0.1, mixed.pair_inplace_config(BF16),
                sprog._LoopKnobs(False, None, None, None), False)
         return sprog._cg_reliable_program.lower(hi, lo, x, 1e-6, 10000,
                                                 key=key)

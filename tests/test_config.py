@@ -107,21 +107,26 @@ def test_packed_and_pallas_switches(monkeypatch):
     assert _pallas_enabled(False)
 
 
-def test_retired_pallas_version_knob_is_flagged_once(monkeypatch):
-    """QUDA_TPU_PALLAS_VERSION went with the v1 and v3 Wilson kernels:
-    it is unregistered (``get`` raises), and a user who still sets it is
-    told so by ``check_environment``: once, as retired, not as a typo."""
-    assert "QUDA_TPU_PALLAS_VERSION" not in qconf.knobs()
-    assert "QUDA_TPU_PALLAS_VERSION" in qconf.SUBSUMED
+@pytest.mark.parametrize("name", ["QUDA_TPU_PALLAS_VERSION",
+                                  "QUDA_TPU_STAGGERED_FORM",
+                                  "QUDA_TPU_FUSED_TAIL"])
+def test_a_retired_knob_is_flagged_once(name, monkeypatch):
+    """QUDA_TPU_PALLAS_VERSION went with the v1 and v3 Wilson kernels
+    (PR 30), QUDA_TPU_STAGGERED_FORM with the fused staggered kernels
+    and QUDA_TPU_FUSED_TAIL with the pallas CG tail (PR 45): each is
+    unregistered (``knobs()`` does not list it, ``get`` raises), and a
+    user who still sets it is told so by ``check_environment``: once,
+    as retired, not as a typo."""
+    assert name not in qconf.knobs()
+    assert name in qconf.SUBSUMED
     with pytest.raises(KeyError, match="unregistered"):
-        qconf.get("QUDA_TPU_PALLAS_VERSION")
-    monkeypatch.setenv("QUDA_TPU_PALLAS_VERSION", "3")
+        qconf.get(name)
+    monkeypatch.setenv(name, "1")
     msgs = []
-    assert qconf.check_environment(msgs.append) == [
-        "QUDA_TPU_PALLAS_VERSION"]
+    assert qconf.check_environment(msgs.append) == [name]
     (msg,) = msgs
     assert "no effect" in msg and "unrecognised" not in msg
-    assert "QUDA_TPU_PALLAS_VERSION" in qconf.describe()
+    assert name in qconf.describe()
 
 
 def test_force_monitor_logs(monkeypatch, capsys):

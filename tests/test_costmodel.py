@@ -98,7 +98,7 @@ def test_zoo_dwf_ls8_drift_row_passes():
 
 def test_checkable_forms_are_the_pallas_models():
     forms = set(ocost.checkable_forms())
-    assert "wilson_v2" in forms and "staggered_fat_naik_fused" in forms
+    assert "wilson_v2" in forms and "staggered_fat_naik_v3" in forms
     # honest flops-only rows are exempt by design
     assert "wilson_xla" not in forms and "generic" not in forms
 

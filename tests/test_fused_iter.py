@@ -1,15 +1,10 @@
-"""Fused-iteration CG pipeline (solvers/fused_iter.py), the pallas fused
-update+reduce tail (ops/blas_pallas.py), and the pallas-dslash-in-solver
-API routing — the round-6 tentpole surface.
+"""Fused-iteration CG pipeline (solvers/fused_iter.py) and the
+pallas-dslash-in-solver API routing — the round-6 tentpole surface.
 
-Bit-tolerance documented here and in the module docstrings: the cadence-k
+Bit-tolerance documented here and in the module docstring: the cadence-k
 solve follows the IDENTICAL iteration trajectory as cadence 1 and stops
 at the first multiple of k past convergence (same final residual, up to
-k-1 extra iterations); the pallas tail's update outputs match the unfused blas
-path to 1-ulp fma-contraction tolerance (XLA may contract a*p+x into an
-fma in one lowering and not the other), and its scalar accumulates
-per-block partials sequentially, which may differ from jnp.sum in the
-last ulp(s).
+k-1 extra iterations).
 
 The interpret-mode pallas-in-solver integration tests are marked ``slow``
 (their cost is the pallas interpreter COMPILE, ~20-60 s each): the tier-1
@@ -36,7 +31,6 @@ from quda_tpu.solvers.fused_iter import fused_cg
 # small lattices keep the interpret-mode pallas solves inside the tier-1
 # budget; the chip-sized configurations live in bench_suite.py
 GEOM = LatticeGeometry((6, 6, 6, 6))
-GEOM_PAIR = LatticeGeometry((4, 4, 4, 8))
 KAPPA = 0.12
 
 
@@ -49,21 +43,6 @@ def pc_problem():
     be, bo = even_odd_split(b, GEOM)
     rhs = dpc.Mdag(dpc.prepare(be, bo))
     return dpc, rhs
-
-
-@pytest.fixture(scope="module")
-def pair_problem():
-    """Complex-free packed pair-form PC normal system (the TPU solve
-    representation)."""
-    k1, k2 = jax.random.split(jax.random.PRNGKey(17))
-    gauge = GaugeField.random(k1, GEOM_PAIR).data.astype(jnp.complex64)
-    b = ColorSpinorField.gaussian(k2, GEOM_PAIR).data.astype(jnp.complex64)
-    dpk = DiracWilsonPC(gauge, GEOM_PAIR, KAPPA, matpc=EVEN).packed()
-    op = dpk.pairs(jnp.float32)
-    be, bo = even_odd_split(b, GEOM_PAIR)
-    rhs = op.prepare_pairs(be, bo)
-    nrm = op.Mdag_pairs(rhs)
-    return dpk, op, nrm
 
 
 # -- convergence-check cadence ----------------------------------------------
@@ -108,115 +87,6 @@ def test_pcg_with_cadence(pc_problem):
     rel = float(jnp.sqrt(blas.norm2(rhs - dpc.MdagM(res.x))
                          / blas.norm2(rhs)))
     assert rel < 1e-6
-
-
-# -- pallas fused update+reduce tail ----------------------------------------
-
-def test_cg_update_norm2_pallas_bit_matches_blas():
-    """The fused pallas kernel vs the unfused ops/blas.py path in
-    interpreter mode: update outputs to 1-ulp fma tolerance, scalar to
-    accumulation-order tolerance (see module docstring)."""
-    from quda_tpu.ops import blas_pallas as bpl
-    rng = np.random.default_rng(0)
-    shape = (4, 3, 2, 8, 8, 32)
-    p, Ap, x, r = (jnp.asarray(rng.standard_normal(shape), jnp.float32)
-                   for _ in range(4))
-    a = jnp.float32(0.37)
-    xo, ro, n2 = bpl.cg_update_norm2_pallas(a, p, Ap, x, r,
-                                            interpret=True)
-    xe, re, n2e = blas.triple_cg_update(a, p, Ap, x, r)
-    np.testing.assert_allclose(np.asarray(xo), np.asarray(xe),
-                               rtol=1e-6, atol=1e-6)
-    np.testing.assert_allclose(np.asarray(ro), np.asarray(re),
-                               rtol=1e-6, atol=1e-6)
-    assert np.isclose(float(n2), float(n2e), rtol=2e-5)
-
-
-def test_cg_update_norm2_pallas_multiblock():
-    """Grid accumulation across row-blocks matches the single-pass sum."""
-    from quda_tpu.ops import blas_pallas as bpl
-    rng = np.random.default_rng(1)
-    shape = (64, 40)
-    p, Ap, x, r = (jnp.asarray(rng.standard_normal(shape), jnp.float32)
-                   for _ in range(4))
-    a = jnp.float32(-1.25)
-    xo, ro, n2 = bpl.cg_update_norm2_pallas(a, p, Ap, x, r,
-                                            interpret=True,
-                                            block_rows=8)
-    xe, re, n2e = blas.triple_cg_update(a, p, Ap, x, r)
-    np.testing.assert_allclose(np.asarray(xo), np.asarray(xe),
-                               rtol=1e-6, atol=1e-6)
-    np.testing.assert_allclose(np.asarray(ro), np.asarray(re),
-                               rtol=1e-6, atol=1e-6)
-    assert np.isclose(float(n2), float(n2e), rtol=2e-5)
-
-
-def test_axpy_norm2_pallas_matches_blas():
-    from quda_tpu.ops import blas_pallas as bpl
-    rng = np.random.default_rng(2)
-    shape = (24, 8, 32)
-    x = jnp.asarray(rng.standard_normal(shape), jnp.float32)
-    y = jnp.asarray(rng.standard_normal(shape), jnp.float32)
-    a = jnp.float32(0.81)
-    yo, n2 = bpl.axpy_norm2_pallas(a, x, y, interpret=True)
-    ye, n2e = blas.axpy_norm2(a, x, y)
-    np.testing.assert_allclose(np.asarray(yo), np.asarray(ye),
-                               rtol=1e-6, atol=1e-6)
-    assert np.isclose(float(n2), float(n2e), rtol=2e-5)
-
-
-def test_axpy_norm2_pallas_bf16_storage_semantics():
-    """bf16 storage: the norm is taken on the ROUNDED stored value, the
-    unfused codec semantics (mixed.StorageCodec)."""
-    from quda_tpu.ops import blas_pallas as bpl
-    from quda_tpu.ops import pair as pops
-    rng = np.random.default_rng(3)
-    shape = (16, 32)
-    x = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
-    y = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
-    a = jnp.float32(0.5)
-    yo, n2 = bpl.axpy_norm2_pallas(a, x, y, interpret=True)
-    assert yo.dtype == jnp.bfloat16
-    ref = (y.astype(jnp.float32)
-           + a * x.astype(jnp.float32)).astype(jnp.bfloat16)
-    assert np.array_equal(np.asarray(yo, np.float32),
-                          np.asarray(ref, np.float32))
-    assert np.isclose(float(n2), float(pops.pair_norm2(ref)), rtol=2e-5)
-
-
-@pytest.mark.slow
-def test_fused_cg_pallas_tail_matches_blas_tail(pair_problem):
-    """The whole CG with the pallas tail inside the while_loop
-    (interpreter mode) lands on the same solution as the jnp tail."""
-    _, op, nrm = pair_problem
-    tol = 1e-6
-    r_jnp = fused_cg(op.MdagM_pairs, nrm, tol=tol, maxiter=300)
-    r_pl = fused_cg(op.MdagM_pairs, nrm, tol=tol, maxiter=300,
-                    use_pallas_tail=True, pallas_interpret=True)
-    assert bool(r_jnp.converged) and bool(r_pl.converged)
-    b2 = float(blas.norm2(nrm))
-    for res in (r_jnp, r_pl):
-        rel = float(jnp.sqrt(
-            blas.norm2(nrm - op.MdagM_pairs(res.x)) / b2))
-        assert rel < tol
-    assert abs(int(r_jnp.iters) - int(r_pl.iters)) <= 2
-
-
-@pytest.mark.slow
-def test_reliable_codec_pallas_tail(pair_problem):
-    """cg_reliable with the fused pallas tail in the sloppy loop (the
-    bf16-reliable 24^4 bench row's configuration, interpreter mode)."""
-    from quda_tpu.solvers.mixed import cg_reliable, pair_inplace_codec
-    dpk, op, nrm = pair_problem
-    op_bf = dpk.pairs(jnp.bfloat16)
-    codec = pair_inplace_codec(jnp.bfloat16, use_pallas_tail=True,
-                               pallas_interpret=True)
-    res = cg_reliable(op.MdagM_pairs, op_bf.MdagM_pairs, nrm, tol=1e-5,
-                      maxiter=400, codec=codec)
-    assert bool(res.converged)
-    rel = float(jnp.sqrt(blas.norm2(nrm - op.MdagM_pairs(res.x))
-                         / blas.norm2(nrm)))
-    assert rel < 1e-5
 
 
 # -- pallas-dslash-in-solver routing ----------------------------------------

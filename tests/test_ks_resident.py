@@ -163,8 +163,7 @@ def quda(tmp_path_factory):
     mp = pytest.MonkeyPatch()
     mp.setenv("QUDA_TPU_PACKED", "1")
     mp.setenv("QUDA_TPU_SLOPPY_PRECISION", "half")
-    for knob in ("QUDA_TPU_PALLAS", "QUDA_TPU_PRECISION_FORM",
-                 "QUDA_TPU_STAGGERED_FORM"):
+    for knob in ("QUDA_TPU_PALLAS", "QUDA_TPU_PRECISION_FORM"):
         mp.delenv(knob, raising=False)
     qconf.reset_cache()
     api.init_quda()
@@ -244,6 +243,41 @@ def test_load_builds_then_solves_reuse_and_hit(quda):
     assert _delta(t1, _counts("ks_term_total", ("outcome",))) == {
         ("reused",): 1}
     assert api._ctx["ks"] is term
+
+
+@pytest.mark.parametrize("knob,value", [
+    ("QUDA_TPU_STAGGERED_FORM", "two_pass"),
+    ("QUDA_TPU_PRECISION_FORM", "fold"),
+    ("QUDA_TPU_FUSED_TAIL", "1")])
+def test_a_form_knob_in_the_environment_changes_no_key(quda, knob, value,
+                                                       monkeypatch):
+    """The staggered hop form is ``served_forms``' and the CG tail has
+    one form: neither the term's key nor the single-source solve
+    program's reads the environment for them, so a process that sets a
+    retired form knob, or the Wilson family's storage form, runs the
+    executables it ran without (the term ``reused``, both programs a
+    ``hit``)."""
+    fat, lng = quda
+    api.load_fat_long_quda(fat, lng)        # warm: programs traced
+    api.invert_quda(_field(15, (L, L, L, L, 1, 3)), _param())
+    key = api._ks_term_key(_param(), False)
+    monkeypatch.setenv(knob, value)
+    qconf.reset_cache()
+    try:
+        assert api._ks_term_key(_param(), False) == key
+        t0 = _counts("ks_term_total", ("outcome",))
+        p0 = _counts("solve_program_total", ("solver", "outcome"))
+        p = _param()
+        api.invert_quda(_field(16, (L, L, L, L, 1, 3)), p)
+        assert p.converged
+        assert _delta(t0, _counts("ks_term_total", ("outcome",))) == {
+            ("reused",): 1}
+        assert _delta(p0, _counts("solve_program_total",
+                                  ("solver", "outcome"))) == {
+            ("cg", "hit"): 1, ("verified-exit", "hit"): 1}
+    finally:
+        monkeypatch.delenv(knob)
+        qconf.reset_cache()
 
 
 def test_new_links_rebuild_and_a_new_gauge_drops(quda):

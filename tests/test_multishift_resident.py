@@ -91,8 +91,7 @@ def solved(tmp_path_factory):
     mp = pytest.MonkeyPatch()
     mp.setenv("QUDA_TPU_PACKED", "1")
     for knob in ("QUDA_TPU_PALLAS", "QUDA_TPU_PRECISION_FORM",
-                 "QUDA_TPU_STAGGERED_FORM", "QUDA_TPU_ROBUST",
-                 "QUDA_TPU_FAULT"):
+                 "QUDA_TPU_ROBUST", "QUDA_TPU_FAULT"):
         mp.delenv(knob, raising=False)
     qconf.reset_cache()
     finj.reset()

@@ -18,8 +18,6 @@ Bitwise claims are exact by construction and asserted exactly:
   decompress-at-setup route built from the same (q, scale) pair.
 """
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -155,51 +153,31 @@ def test_wilson_precision_mrhs_matches_single(form, n):
         assert np.array_equal(ob[i], oi), (form, n, i)
 
 
-@functools.lru_cache(maxsize=None)
-def _staggered_fused_out(pform, parity):
-    """(the fused hop of the ``pform`` operator on one seeded field, the
-    operator), once per process: both forms are held to the same
-    ``full`` reference, an interpreted compile of its own."""
-    T, Z, Y, X = GEOM.lattice_shape
-    psi = _psi((3, 2, T, Z, Y * X // 2), seed=7)
-    op = _staggered_dpc().pairs(jnp.float32, use_pallas=True,
-                                pallas_interpret=True, form="fused",
-                                precision_form=pform)
-    # XLA:CPU's fusion emitters (jax 0.9.0) round a multiply-add chain
-    # by where the fusion boundary falls, which the fold layout moves;
-    # the bit-match is about the kernels' adds, so compile without.
-    return np.asarray(jax.jit(
-        lambda p: op.D_to_pairs(p, parity, jnp.float32),
-        compiler_options={"xla_cpu_use_fusion_emitters": False})(psi)), op
-
-
-@pytest.mark.parametrize(
-    "parity", [0, pytest.param(1, marks=pytest.mark.slow)])
-@pytest.mark.parametrize("pform", ["r12", "fold"])
-def test_staggered_fused_precision_forms_match_full(pform, parity):
-    ref, _ = _staggered_fused_out("full", parity)
-    out, op = _staggered_fused_out(pform, parity)
-    assert op._precision_form == pform
-    if pform == "fold":
-        assert np.array_equal(out, ref)
-    else:
-        # long links are +-SU(3) after KS-phase folding; the recon-12
-        # sign plane must re-apply the folded phase exactly
-        err = np.max(np.abs(out - ref)) / np.max(np.abs(ref))
-        assert err < 3e-5, err
-        assert op.long_eo_pp[0].shape[1] == 2
-        assert op._long_sign is not None
-
-
-def test_staggered_wilson_only_forms_downgrade():
-    """r12f/bzfull/int8 are Wilson forms: the staggered family serves
-    'full' (with a notice) instead of failing or mislabeling."""
+def test_staggered_wilson_only_forms_downgrade(monkeypatch):
+    """The precision storage forms are the Wilson family's: a staggered
+    operator built while QUDA_TPU_PRECISION_FORM asks for any of them
+    keeps full R=3 links in the plain layout and says so ONCE per
+    requested form; 'full' and the unset knob say nothing."""
+    from quda_tpu.models import wilson as mw
+    from quda_tpu.utils import logging as qlog
     dpc = _staggered_dpc()
-    for pform in ("r12f", "bzfull", "int8"):
-        op = dpc.pairs(jnp.float32, use_pallas=True,
-                       pallas_interpret=True, form="fused",
-                       precision_form=pform)
-        assert op._precision_form == "full", pform
+    monkeypatch.setattr(mw, "_PRECISION_NOTICED", set())
+    said = []
+    monkeypatch.setattr(qlog, "printq",
+                        lambda msg, *a, **kw: said.append(msg))
+    shapes = {tuple(g.shape) for g in dpc.pairs(jnp.float32).long_eo_pp}
+    for pform in ("", "full", "r12", "fold", "r12f", "bzfull", "int8",
+                  "auto"):
+        monkeypatch.setenv("QUDA_TPU_PRECISION_FORM", pform)
+        qconf.reset_cache()
+        for _ in range(2):
+            op = dpc.pairs(jnp.float32, use_pallas=True,
+                           pallas_interpret=True)
+            assert {tuple(g.shape) for g in op.long_eo_pp} == shapes
+            assert not hasattr(op, "_precision_form")
+        noticed = (pform, "full") in mw._PRECISION_NOTICED
+        assert noticed == (pform not in ("", "full")), pform
+    assert len(said) == 6, said
 
 
 def test_env_knob_resolution(monkeypatch):

@@ -61,18 +61,10 @@ def _staggered_ops():
     from quda_tpu.models.staggered import STAGGERED_FORMS
     for form, improved, mesh in itertools.product(
             STAGGERED_FORMS, (False, True), (None, object())):
-        if form == "fused" and not improved:
-            continue          # models/staggered.py forbids the combo
         yield _mk("DiracStaggeredPCPairs", use_pallas=True,
                   _pallas_form=form,
                   long_eo_pp=(object(),) if improved else None,
                   _mesh=mesh)
-    # fused precision storage forms (round 16): improved only, single
-    # chip only (models/staggered.py downgrades everything else)
-    for pform in ("full", "r12", "fold"):
-        yield _mk("DiracStaggeredPCPairs", use_pallas=True,
-                  _pallas_form="fused", long_eo_pp=(object(),),
-                  _mesh=None, _precision_form=pform)
     yield _mk("DiracStaggeredPCPairs", use_pallas=False,
               long_eo_pp=None)
 
@@ -128,17 +120,6 @@ def test_mg_coarse_bench_literal_is_harvested_and_modeled():
             if _in_roofline_namespace(s)}
     assert "mg_coarse_pallas" in lits
     assert "mg_coarse_pallas" in orf.KERNEL_MODELS
-
-
-def test_fused_model_meets_round10_traffic_target():
-    """Acceptance pin: the fused fat+Naik model must show <= ~900 B/site
-    against the two-pass 1512 (the 1.75x structural win the kernel
-    exists to realise), at identical flops."""
-    fused = orf.KERNEL_MODELS["staggered_fat_naik_fused"]
-    two_pass = orf.KERNEL_MODELS["staggered_fat_naik"]
-    assert fused["flops_per_site"] == two_pass["flops_per_site"] == 1146
-    assert fused["bytes_per_site"] <= 900
-    assert two_pass["bytes_per_site"] == 1512
 
 
 def test_mrhs_models_amortize_with_nrhs():

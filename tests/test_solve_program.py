@@ -144,7 +144,7 @@ def quda(gauges, tmp_path_factory):
     # split-grid route: the batched route is the one-chip route
     mp.setenv("QUDA_TPU_MULTI_SRC_SPLIT", "0")
     for knob in ("QUDA_TPU_ROBUST", "QUDA_TPU_FAULT", "QUDA_TPU_TRACE",
-                 "QUDA_TPU_FUSED_TAIL", "QUDA_TPU_CG_CHECK_EVERY"):
+                 "QUDA_TPU_CG_CHECK_EVERY"):
         mp.delenv(knob, raising=False)
     qconf.reset_cache()
     finj.reset()
@@ -423,7 +423,6 @@ def test_true_res_is_that_of_what_is_returned(route, warm, gauges):
 FLIPS = {
     # what the loop reads -> the environment that flips it ("record" is
     # a trace session, started in the test)
-    "fused_tail": {"QUDA_TPU_FUSED_TAIL": "1"},
     "check_every": {"QUDA_TPU_CG_CHECK_EVERY": "2"},
     "robust": {"QUDA_TPU_ROBUST": "verify"},
     "fault": {"QUDA_TPU_FAULT": "dslash:3"},
@@ -442,10 +441,11 @@ FLIPS = {
 # ``use_pallas`` False in the operator's signature; invert_quda's
 # Wilson route off the kernels reaches no program).
 @pytest.mark.parametrize("route,flip", [
-    ("single", "fused_tail"),
-    # 50 s each alone: the single-source program's miss and way back
-    # stay in tier 1 with the flip only it has, the sentinel, the fault
-    # and the record with the batched cases
+    # 50 s each alone on the interpreted kernels: the single-source
+    # program has no flip of its own (its codec is keyed by the storage
+    # dtype alone since PR 45); its sentinel, fault and record stay in
+    # tier 1 with the batched cases, and that no form knob moves its
+    # key is tests/test_ks_resident.py's
     pytest.param("single", "robust+fault", marks=pytest.mark.slow),
     pytest.param("single", "record", marks=pytest.mark.slow),
     ("multi", "check_every"), ("multi", "robust"), ("multi", "fault"),

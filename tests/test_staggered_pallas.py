@@ -287,148 +287,11 @@ def test_staggered_pairs_operator_cg(use_pallas):
     assert res < 1e-5
 
 
-# -- round 10: fused single-pass fat+Naik kernel ----------------------------
-
-def test_fused_bitmatches_two_pass_sum_folded_links():
-    """THE round-10 acceptance test: the fused fat+Naik kernel in ONE
-    pallas launch bit-matches the XLA sum of the two v3 scatter passes
-    (same hop algebra — _accumulate_hopset — run twice into separate
-    accumulators), and matches the pair stencil to fp tolerance.  Links
-    carry FOLDED staggered phases + antiperiodic t (the production
-    form), so the sign structure is live."""
-    from quda_tpu.fields.geometry import LatticeGeometry
-    from quda_tpu.ops.boundary import apply_staggered_phases
-
-    geom = LatticeGeometry((4, 4, 6, 4))
-    T, Z, Y, X = geom.lattice_shape
-    key = jax.random.PRNGKey(10)
-    k1, k2, k3 = jax.random.split(key, 3)
-    fat = apply_staggered_phases(
-        GaugeField.random(k1, geom).data.astype(jnp.complex64), geom,
-        True)
-    lng = apply_staggered_phases(
-        GaugeField.random(k2, geom).data.astype(jnp.complex64), geom,
-        True, nhop=3)
-    psi = (jax.random.normal(k3, (T, Z, Y, X, 1, 3), jnp.float32)
-           + 1j * jax.random.normal(jax.random.fold_in(k3, 1),
-                                    (T, Z, Y, X, 1, 3), jnp.float32)
-           ).astype(jnp.complex64)
-    fat_pp = to_packed_pairs(spk.pack_links(fat), jnp.float32)
-    long_pp = to_packed_pairs(spk.pack_links(lng), jnp.float32)
-    psi_pp = to_packed_pairs(spk.pack_staggered(psi), jnp.float32)
-
-    ref = spk.dslash_staggered_packed_pairs(fat_pp, psi_pp, X, Y,
-                                            long_pp)
-    # XLA:CPU's fusion emitters (jax 0.9.0) round a multiply-add chain
-    # by where the fusion boundary falls, which differs between the two
-    # forms; the claim is about the kernels' adds, so compile without.
-    exact = {"xla_cpu_use_fusion_emitters": False}
-    two_pass = jax.jit(
-        lambda f, p, l: spl.dslash_staggered_pallas_v3(
-            f, p, X, long_pl=l, interpret=True, block_z=Z),
-        compiler_options=exact)(fat_pp, psi_pp, long_pp)
-    fused = jax.jit(
-        lambda f, p, l: spl.dslash_staggered_pallas_fused(
-            f, p, X, long_pl=l, interpret=True, block_z=Z),
-        compiler_options=exact)(fat_pp, psi_pp, long_pp)
-    # bit-identical to the two-pass sum (same adds, same order)
-    assert bool(jnp.all(fused == two_pass))
-    err = float(jnp.sqrt(blas.norm2(ref - fused) / blas.norm2(ref)))
-    assert err < 1e-6
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("bz", [None, 3])
-def test_fused_multiblock_splice_matches_stencil(bz):
-    """Multi-z-block fused launch: the direct edge-row splice (no
-    bz % nhop constraint) must reproduce the stencil across z-block
-    boundaries for both hop sets."""
-    geom, fat_p, long_p, psi_p = _setup(jax.random.PRNGKey(11),
-                                        (4, 4, 6, 4))
-    T, Z, Y, X = geom.lattice_shape
-    fat_pp = to_packed_pairs(fat_p, jnp.float32)
-    long_pp = to_packed_pairs(long_p, jnp.float32)
-    psi_pp = to_packed_pairs(psi_p, jnp.float32)
-    ref = spk.dslash_staggered_packed_pairs(fat_pp, psi_pp, X, Y,
-                                            long_pp)
-    out = spl.dslash_staggered_pallas_fused(fat_pp, psi_pp, X,
-                                            long_pl=long_pp,
-                                            interpret=True, block_z=bz)
-    err = float(jnp.sqrt(blas.norm2(ref - out) / blas.norm2(ref)))
-    assert err < 1e-6
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("bz", [None, 3])
-@pytest.mark.parametrize("parity", [0, 1])
-def test_fused_eo_bitmatches_v3(parity, bz):
-    """Checkerboarded fused kernel == the eo v3 two-pass sum
-    (bit-exact) and the eo pair stencil (tolerance), both parities and
-    both z-blockings — bz=3 exercises the eo boundary-row splice
-    (_psi_z_rows/_u_z_rows), the production configuration whenever
-    _pick_bz_fused selects bz < Z (~32s interpreter compile each ->
-    slow per the >30s policy; the fast tier pins the fused hop algebra
-    through the full-lattice bit-match above, which shares the kernel
-    body)."""
-    _fused_eo_case(parity, bz)
-
-
-def _fused_eo_case(parity, bz=None):
-    from quda_tpu.fields.spinor import even_odd_split
-    from quda_tpu.ops.wilson import split_gauge_eo
-
-    geom = LatticeGeometry((4, 6, 6, 4))
-    T, Z, Y, X = geom.lattice_shape
-    dims = (T, Z, Y, X)
-    key = jax.random.PRNGKey(12)
-    k1, k2, k3 = jax.random.split(key, 3)
-    fat = GaugeField.random(k1, geom).data.astype(jnp.complex64)
-    lng = GaugeField.random(k2, geom).data.astype(jnp.complex64)
-    psi = (jax.random.normal(k3, (T, Z, Y, X, 1, 3), jnp.float32)
-           + 1j * jax.random.normal(jax.random.fold_in(k3, 1),
-                                    (T, Z, Y, X, 1, 3), jnp.float32)
-           ).astype(jnp.complex64)
-    fat_eo = split_gauge_eo(fat, geom)
-    long_eo = split_gauge_eo(lng, geom)
-    pe, po = even_odd_split(psi, geom)
-    src = pe if parity == 1 else po
-    fat_eo_pp = tuple(to_packed_pairs(spk.pack_links(g), jnp.float32)
-                      for g in fat_eo)
-    long_eo_pp = tuple(to_packed_pairs(spk.pack_links(g), jnp.float32)
-                       for g in long_eo)
-    src_pp = to_packed_pairs(spk.pack_staggered(src), jnp.float32)
-    ref = spk.dslash_staggered_eo_packed_pairs(
-        fat_eo_pp, src_pp, dims, parity, long_eo_pp)
-    v3 = spl.dslash_staggered_eo_pallas_v3(
-        fat_eo_pp[parity], fat_eo_pp[1 - parity], src_pp, dims, parity,
-        long_here_pl=long_eo_pp[parity],
-        long_there_pl=long_eo_pp[1 - parity], interpret=True,
-        block_z=Z)
-    fused = spl.dslash_staggered_eo_pallas_fused(
-        fat_eo_pp[parity], fat_eo_pp[1 - parity], src_pp, dims, parity,
-        long_here_pl=long_eo_pp[parity],
-        long_there_pl=long_eo_pp[1 - parity], interpret=True,
-        block_z=bz if bz is not None else Z)
-    assert bool(jnp.all(fused == v3))
-    err = float(jnp.sqrt(blas.norm2(ref - fused) / blas.norm2(ref)))
-    assert err < 1e-6
-
-
-def test_fused_requires_long_links():
-    """The fused kernel IS the fat+Naik fusion: a fat-only call must be
-    rejected loudly (one hop set has nothing to fuse)."""
-    geom, fat_p, _, psi_p = _setup(jax.random.PRNGKey(13), (4, 4, 4, 4))
-    fat_pp = to_packed_pairs(fat_p, jnp.float32)
-    psi_pp = to_packed_pairs(psi_p, jnp.float32)
-    with pytest.raises(ValueError, match="fat\\+Naik fusion"):
-        spl.dslash_staggered_pallas_fused(fat_pp, psi_pp, 4,
-                                          interpret=True)
-
-
 def test_long_bz_guard_raises_loudly():
-    """Satellite: 0 < block_z < 3 with a Naik pass would silently
-    corrupt the long-hop boundary rows (the splice only reaches the
-    adjacent z-block) — every entry point must reject it."""
+    """0 < block_z < 3 with a Naik pass would silently corrupt the
+    long-hop boundary rows (the gather splice only reaches the adjacent
+    z-block; the scatter pass blocks z in units of 3) — every entry
+    point rejects it while it traces, before any kernel is built."""
     geom, fat_p, long_p, psi_p = _setup(jax.random.PRNGKey(14),
                                         (4, 4, 6, 4))
     T, Z, Y, X = geom.lattice_shape
@@ -443,7 +306,11 @@ def test_long_bz_guard_raises_loudly():
                 fat_pp, fat_bw, psi_pp, X, long_pl=long_pp,
                 long_bw_pl=long_bw, interpret=True, block_z=bad)
         with pytest.raises(ValueError, match="block_z >= 3"):
-            spl.dslash_staggered_pallas_fused(
+            spl.dslash_staggered_pallas_mrhs(
+                fat_pp, fat_bw, psi_pp[None], X, long_pl=long_pp,
+                long_bw_pl=long_bw, interpret=True, block_z=bad)
+        with pytest.raises(ValueError, match="multiple of nhop=3"):
+            spl.dslash_staggered_pallas_v3(
                 fat_pp, psi_pp, X, long_pl=long_pp, interpret=True,
                 block_z=bad)
     # the automatic picker must never land in the illegal window:
@@ -454,7 +321,7 @@ def test_long_bz_guard_raises_loudly():
     assert bz == Z or bz >= 3
 
 
-# -- round 10: kernel-form selection on the solver operator -----------------
+# -- kernel-form selection on the solver operator --------------------------
 
 def _pairs_fixture(improved=True, dims=(4, 4, 4, 4)):
     from quda_tpu.models.staggered import DiracStaggeredPC
@@ -473,56 +340,90 @@ def _pairs_fixture(improved=True, dims=(4, 4, 4, 4)):
 
 @pytest.mark.slow
 def test_staggered_forms_agree_on_M_pairs():
-    """Every selectable kernel form computes the same PC operator: the
-    fused form bit-matches v3 (same hop algebra), and both match the
-    two-pass gather form to fp tolerance."""
+    """Both kernel forms compute the same PC operator: the scatter form
+    (v3) matches the two-pass gather form to fp tolerance."""
     dpc, x = _pairs_fixture()
     outs = {}
-    for form in ("fused", "two_pass", "v3"):
+    for form in ("two_pass", "v3"):
         op = dpc.pairs(jnp.float32, use_pallas=True,
                        pallas_interpret=True, form=form)
         assert op._pallas_form == form
         outs[form] = op.M_pairs(x)
-    assert bool(jnp.all(outs["fused"] == outs["v3"]))
     err = float(jnp.sqrt(
-        blas.norm2(outs["fused"] - outs["two_pass"])
+        blas.norm2(outs["v3"] - outs["two_pass"])
         / blas.norm2(outs["two_pass"])))
     assert err < 1e-6
 
 
-def test_staggered_form_auto_resolves_without_race_off_chip():
-    """'auto' in interpret mode must NOT race (timing the interpreter
-    is meaningless): it resolves statically to the projected winner —
-    fused for improved, two_pass for fat-only (nothing to fuse)."""
+def test_staggered_default_form_without_any_knob(monkeypatch):
+    """With nothing asked, an operator serves ``served_forms``' pair:
+    no race (utils.tune is never entered), no environment read of a
+    form (a stray QUDA_TPU_STAGGERED_FORM changes nothing), and the
+    gather form alone keeps pre-shifted backward links."""
+    from quda_tpu.utils import tune as qtune
+
+    def no_race(*a, **kw):
+        raise AssertionError("a staggered operator raced its form")
+
+    monkeypatch.setattr(qtune, "tune", no_race)
+    monkeypatch.setenv("QUDA_TPU_STAGGERED_FORM", "two_pass")
     dpc, _ = _pairs_fixture()
-    op = dpc.pairs(jnp.float32, use_pallas=True, pallas_interpret=True,
-                   form="auto")
-    assert op._pallas_form == "fused"
+    # pallas_interpret=False: what a chip builds (nothing compiles
+    # until a hop is applied)
+    for interpret in (True, False):
+        op = dpc.pairs(jnp.float32, use_pallas=True,
+                       pallas_interpret=interpret)
+        assert (op._pallas_form, op._mrhs_form) == ("v3",
+                                                    "scatter_two_pass")
+        assert op._fat_bw is None and op._long_bw is None
     dpc_fat, _ = _pairs_fixture(improved=False)
     op2 = dpc_fat.pairs(jnp.float32, use_pallas=True,
-                        pallas_interpret=True, form="auto")
-    assert op2._pallas_form == "two_pass"
+                        pallas_interpret=True)
+    assert (op2._pallas_form, op2._mrhs_form) == ("two_pass",
+                                                  "gather_two_pass")
+    assert op2._fat_bw is not None
+    op3 = dpc.pairs(jnp.bfloat16)
+    assert (op3._pallas_form, op3._mrhs_form) == ("two_pass", "vmap")
 
 
-def test_staggered_form_auto_races_via_tune(monkeypatch):
-    """'auto' on chip goes through utils.tune over ALL applicable forms
-    (A/B'd, not assumed) and honors the winner."""
-    from quda_tpu.utils import tune as qtune
-    seen = {}
+_TZ, _YX, _ONE = ("t", "z"), ("t", "y"), ()
 
-    def fake_tune(name, volume, candidates, args, aux="", **kw):
-        seen["name"] = name
-        seen["cands"] = sorted(candidates)
-        seen["aux"] = aux
-        return "v3"
 
-    monkeypatch.setattr(qtune, "tune", fake_tune)
-    dpc, x = _pairs_fixture()
-    # pallas_interpret=False + tuning enabled -> the race path runs
-    # (tune is mocked, so no pallas kernel actually compiles off-TPU)
-    op = dpc.pairs(jnp.float32, use_pallas=True, pallas_interpret=False,
-                   form="auto")
-    assert seen["name"] == "staggered_eo_form"
-    assert seen["cands"] == ["fused", "two_pass", "v3"]
-    assert "fat_naik" in seen["aux"]
-    assert op._pallas_form == "v3"
+@pytest.mark.parametrize("improved,use_pallas,mesh_axes,form,expect", [
+    # one chip, kernels: the chip's readings
+    (True, True, _ONE, None, ("v3", "scatter_two_pass")),
+    (False, True, _ONE, None, ("two_pass", "gather_two_pass")),
+    # a caller's request picks the hop, never the batched hop
+    (True, True, _ONE, "two_pass", ("two_pass", "scatter_two_pass")),
+    (False, True, _ONE, "v3", ("v3", "gather_two_pass")),
+    # the XLA stencil: a label, whatever is asked
+    (True, False, _ONE, None, ("two_pass", "vmap")),
+    (False, False, _ONE, None, ("two_pass", "vmap")),
+    (True, False, _ONE, "v3", ("two_pass", "vmap")),
+    # a mesh: the gather interior unless v3 is asked for on t / z
+    (True, True, _TZ, None, ("two_pass", "vmap")),
+    (False, True, _TZ, None, ("two_pass", "vmap")),
+    (True, True, _TZ, "v3", ("v3", "vmap")),
+    (False, True, ("z",), "v3", ("v3", "vmap")),
+    (True, True, _YX, None, ("two_pass", "vmap")),
+    # the scatter exterior shards no y / x: a y / x mesh refuses v3
+    (True, True, _YX, "v3", ("two_pass", "vmap")),
+    (False, True, ("x",), "v3", ("two_pass", "vmap")),
+    # a mesh needs the kernels; only two forms exist
+    (True, False, _TZ, None, ValueError),
+    (False, False, _YX, None, ValueError),
+    (True, True, _ONE, "fused", ValueError),
+    (True, True, _ONE, "auto", ValueError),
+])
+def test_served_forms_table(improved, use_pallas, mesh_axes, form,
+                            expect):
+    """The ONE decision of the staggered hop and batched-hop forms, as
+    a table: (fat only | fat + Naik) x (kernels | XLA) x (one chip |
+    t/z mesh | y/x mesh), with and without a caller's request."""
+    from quda_tpu.models.staggered import served_forms
+    if expect is ValueError:
+        with pytest.raises(ValueError):
+            served_forms(improved, use_pallas, mesh_axes, form)
+    else:
+        assert served_forms(improved, use_pallas, mesh_axes,
+                            form) == expect
