@@ -1036,6 +1036,12 @@ class _CloverResidentSolve(_ResidentPairSolve):
         from ..models.clover import DiracCloverFullPairs
         return DiracCloverFullPairs(self.op, self._term["a_q_pp"])
 
+    def with_full_diag(self):
+        """``op`` with the term's A_q blocks beside its own: the
+        operand of a verified exit that is a program
+        (``DiracCloverPCPairs.verified_exit_pairs``)."""
+        return self.op.with_full_diag(self._term["a_q_pp"])
+
     def flops_per_site_M(self) -> int:
         return 2 * 1320 + 2 * 504 + 48      # DiracCloverPC's count
 
@@ -1559,6 +1565,117 @@ def _invert_mobius_resident(source, param: InvertParam):
         f"invert_quda[{param.dslash_type}/{inv}]: {param.iter_count} "
         f"iters, true_res {param.true_res:.2e}, {param.secs:.2f} s")
     return x_full
+
+
+def _clover_batch_route(param: InvertParam) -> bool:
+    """Whether ``invert_multi_src_quda`` solves this batch on
+    ``_resident_clover``: Wilson-clover, CG on the normal equations of
+    the even-odd system (``normop-pc``) at tol >= 5e-8, on the packed
+    pair representation in f32, the lanes independent.  Everything else
+    of the family (other solvers, ``direct-pc``, true block CG, twisted
+    mass and twisted clover) keeps the batched route that builds its
+    operator per call; a deeper tolerance the per-source fallback."""
+    from ..utils import config as qconf
+    on_tpu = jax.default_backend() == "tpu"
+    return (param.dslash_type == "clover" and param.inv_type == "cg"
+            and param.solve_type == "normop-pc" and param.tol >= 5e-8
+            and (param.cuda_prec == "single" or on_tpu)
+            and _packed_enabled(on_tpu)
+            and str(qconf.get("QUDA_TPU_MULTI_SRC_BLOCK",
+                              fresh=True)) != "1")
+
+
+def _invert_clover_batch_resident(B, param: InvertParam, t0: float):
+    """The batched Wilson-clover CG solve on the resident clover term
+    (``_resident_clover``: reused after ``load_clover_quda``, built on
+    first use): entry (the parity split of the (N, T, Z, Y, X, 4, 3)
+    batch, ``prepare`` and ``Mdag``), the pure-f32 batched CG on the
+    normal equations and the verified exit (reconstruction and the full
+    M = A - kappa D residual of every returned solution, one host read)
+    are one cached program each (solvers/program.py), the links, the
+    blocks of both parities and kappa operands: every kappa, csw and
+    gauge of one (lattice, N) shares the executables, and no canonical
+    DiracClover* is built.  The first trace of each stands on a stack
+    chunk of its own (PERF.md section 7 (22))."""
+    import numpy as np
+
+    from ..obs import convergence as oconv
+    from ..obs import trace as otr
+    from ..solvers import program as sprog
+    from ..utils.frames import on_a_stack_chunk_of_its_own as footed
+    api, solver = "invert_multi_src_quda", "batched-cg-pairs"
+    form_b = "clover_batched_pairs"     # the solve programs' label
+    recording = otr.enabled()
+    n_src = B.shape[0]
+    with otr.phase("setup", api):
+        d = _CloverResidentSolve(_resident_clover(param, ()), param.kappa)
+        op = d.with_full_diag()
+        with otr.span("prepare", cat="setup") as span:
+            rhs, hit = footed(lambda: sprog.prepare(op, B))
+            _note_solve_program(span, api, form_b, "prepare", hit)
+    t_solve0 = time.perf_counter()
+    with otr.phase("compute", api), \
+            otr.span(f"solve:{solver}", cat="solver", nrhs=n_src,
+                     tol=param.tol) as solve_span:
+        with otr.span("dispatch", cat="solver"):
+            res, hit = footed(lambda: sprog.batched_cg_pairs(
+                op, rhs, tol=param.tol, maxiter=param.maxiter,
+                record=recording))
+        _note_solve_program(solve_span, api, form_b, solver, hit)
+        with otr.span("wait", cat="solver"):
+            iters = np.asarray(res.iters)
+    t_solve = time.perf_counter() - t_solve0
+    _record_solve_metrics(api, form_b, solver, t_solve, param.dslash_type,
+                          param.cuda_prec)
+    conv = np.asarray(res.converged)
+    if not conv.all():
+        qlog.warningq(
+            f"invert_multi_src_quda: {int((~conv).sum())} of {n_src} "
+            f"sources did not reach tol {param.tol:g} within "
+            f"{param.maxiter} iterations; per-RHS true_res_multi holds "
+            "the achieved residuals")
+    with otr.phase("epilogue", api):
+        x_full, true_res = footed(
+            lambda: _verified_exit(api, form_b, op, B, res.x))
+        param.iter_count_multi = [int(i) for i in iters]
+        param.true_res_multi = [float(r) for r in true_res]
+        param.iter_count = int(sum(param.iter_count_multi))
+        # np.max propagates a NaN lane into the headline
+        param.true_res = float(np.max(true_res))
+        param.secs = time.perf_counter() - t0
+        # per-RHS accounting as the other batched routes: each lane's
+        # own converged count, two M an iteration
+        param.gflops = (param.iter_count * 2.0 * d.flops_per_site_M()
+                        * (_ctx["geom"].volume // 2)) / 1e9
+        _solve_supervision(param, api,
+                           breakdown=getattr(res, "breakdown", None),
+                           converged_multi=conv)
+    qlog.printq(
+        f"invert_multi_src_quda[{param.dslash_type}/{param.inv_type}]: "
+        f"{n_src} sources, iters {param.iter_count_multi}, worst "
+        f"true_res {param.true_res:.2e}, {param.secs:.2f} s")
+    if recording:
+        from ..obs import roofline as orf
+        from ..solvers.block import _per_rhs_dot
+        oconv.publish(oconv.harvest(
+            solver, res, tol=param.tol,
+            b2=np.asarray(_per_rhs_dot(rhs, rhs))), param)
+        orf.record(_clover_mrhs_roofline_form(op), _ctx["geom"].volume // 2,
+                   float(np.max(iters)) * 2.0, t_solve, nrhs=n_src,
+                   flops_per_site=d.flops_per_site_M(),
+                   dslash_per_apply=2.0,
+                   label=f"invert_multi_src_quda:{solver}")
+    return x_full
+
+
+def _clover_mrhs_roofline_form(op) -> str:
+    """The roofline model (obs/roofline) of a clover batch: by the form
+    the batched operator SERVES (``_mrhs_form``), which is not always
+    the single-source ``_op_form``."""
+    if not getattr(op, "use_pallas", False):
+        return "generic"
+    return ("clover_pallas_mrhs" if op._mrhs_form() == "pallas"
+            else "clover_xla")
 
 
 def _invert_quda_body(source, param: InvertParam):
@@ -2103,16 +2220,18 @@ def _invert_multi_src_body(sources, param: InvertParam):
     # engages the df64 route (same 5e-8 threshold it uses)
     tol_ok = param.tol >= 5e-8
     stag_family = param.dslash_type in ("staggered", "asqtad", "hisq")
-    # Wilson AND the staggered/HISQ family ride the batched pairs
-    # pipeline (round 10: MILC-interface HISQ workloads no longer run
-    # the slow per-source path end to end); checked against ``mesh is
-    # None`` at the route decision below, AFTER the split-grid gate may
-    # have released an unusable mesh back to this route
-    # operator-zoo Schur families (round 18): clover/twisted-mass/
-    # twisted-clover ride the same batched-pairs pipeline via the
-    # _SchurPairOpBase MRHS suite.  Doublet (ndeg) and DWF operators
-    # stay per-source: the doublet flavor axis and the Ls axis already
-    # occupy the batch dimension their kernels lead with.
+    # the batched pairs pipeline, decided against ``mesh is None`` at
+    # the route decision below, AFTER the split-grid gate may have
+    # released an unusable mesh back to this route: Wilson and the
+    # improved-staggered family on their resident pair operators
+    # (entry, solve and exit cached programs; plain staggered builds
+    # its operator per call); clover CG on the resident clover term,
+    # a function of its own (_invert_clover_batch_resident); what is
+    # left of the Schur families (twisted mass, twisted clover, clover
+    # under another solver) on a _SchurPairOpBase operator built per
+    # call, eager entry and a canonical exit.  Doublet (ndeg) and DWF
+    # operators stay per-source: the doublet flavor axis and the Ls
+    # axis already occupy the batch dimension their kernels lead with.
     zoo_family = param.dslash_type in ("clover", "twisted-mass",
                                        "twisted-clover")
     batched_able = (pc
@@ -2253,6 +2372,8 @@ def _invert_multi_src_body(sources, param: InvertParam):
                            converged_rhs=np.asarray(conv_l),
                            breakdown=bk)
 
+    if route == "batched" and _clover_batch_route(param):
+        return _invert_clover_batch_resident(B, param, t0)
     if route == "batched":
         from ..solvers import program as sprog
         from ..solvers.block import (_per_rhs_dot, batched_cg_pairs,
@@ -2391,8 +2512,7 @@ def _invert_multi_src_body(sources, param: InvertParam):
             if not getattr(op, "use_pallas", False):
                 form = "generic"
             elif param.dslash_type == "clover":
-                form = ("clover_pallas_mrhs" if zoo_fused
-                        else "clover_xla")
+                form = _clover_mrhs_roofline_form(op)
             elif param.dslash_type == "twisted-mass":
                 form = ("twisted_mass_pallas_mrhs" if zoo_fused
                         else "twisted_xla")

@@ -16,6 +16,9 @@ so csw=0 reduces exactly to Wilson.  PC operator on parity p:
 
 from __future__ import annotations
 
+import copy
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 
@@ -24,6 +27,7 @@ from ..fields.spinor import even_odd_join, even_odd_split
 from ..ops import wilson as wops
 from ..ops.boundary import apply_t_boundary
 from ..ops.clover import apply_clover, clover_blocks, invert_clover
+from . import formsel
 from .dirac import Dirac, DiracPC, MATPC_EVEN_EVEN
 from .wilson import _ProgramOperand, _SchurPairOpBase
 
@@ -158,6 +162,27 @@ def apply_clover_pairs(blk_pp: jnp.ndarray, x_pp: jnp.ndarray,
     return out.reshape(x_pp.shape).astype(odt)
 
 
+def apply_clover_pairs_mrhs(blk_pp: jnp.ndarray, x_b: jnp.ndarray,
+                            out_dtype=None) -> jnp.ndarray:
+    """``apply_clover_pairs`` on a batch x_b (N,4,3,2,T,Z,YXh), the
+    blocks read once for all of it: the six products of a block row
+    multiplied out and summed elementwise at f32, so that XLA keeps the
+    lattice axes minor in one fusion.  The vmapped einsum becomes a
+    dot_general whose minor axes are (N, 6): at 24^4 and eight sources
+    every operand and result of it is a 1.27 GiB tile-padded copy,
+    9.9 GiB of temporaries in the exit program (the described-chip
+    compile, PR 46)."""
+    odt = out_dtype or x_b.dtype
+    f = x_b.astype(jnp.float32)
+    chi = f.reshape((f.shape[0], 2, 1, 6) + f.shape[3:])
+    a = blk_pp.astype(jnp.float32)[None]         # (1,2,6,6,2,T,Z,YXh)
+    ar, ai = a[:, :, :, :, 0], a[:, :, :, :, 1]  # (1,2,6,6,T,Z,YXh)
+    xr, xi = chi[:, :, :, :, 0], chi[:, :, :, :, 1]  # (N,2,1,6,T,Z,YXh)
+    out = jnp.stack([jnp.sum(ar * xr - ai * xi, axis=3),
+                     jnp.sum(ar * xi + ai * xr, axis=3)], axis=3)
+    return out.reshape(x_b.shape).astype(odt)
+
+
 class DiracCloverPCPairs(_ProgramOperand, _SchurPairOpBase):
     """Complex-free packed pair-form of DiracCloverPC — Wilson-clover
     solves on TPU runtimes without complex64 execution, and (bf16
@@ -171,7 +196,11 @@ class DiracCloverPCPairs(_ProgramOperand, _SchurPairOpBase):
 
     A solve-program operand (_ProgramOperand): links, blocks and kappa
     are the leaves; the fused-or-staged form, resolved when the
-    operator is built, is part of the static signature.
+    operator is built, is part of the static signature.  The blocks of
+    the OTHER parity's term, A_q, are a leaf too, ``None`` except on
+    the operator ``with_full_diag`` hands out: what
+    ``verified_exit_pairs`` needs beyond the PC operator's own arrays
+    to apply the full M = A - kappa D.
 
     Reference behavior: QUDA runs clover solves in native FloatN orders
     with the clover field in its own packed order
@@ -179,7 +208,10 @@ class DiracCloverPCPairs(_ProgramOperand, _SchurPairOpBase):
     """
 
     _PROGRAM_ARRAYS = _ProgramOperand._PROGRAM_ARRAYS + (
-        "clover_p_pp", "clover_inv_q_pp")
+        "clover_p_pp", "clover_inv_q_pp", "clover_q_pp")
+    # the form a batch (a leading source axis) is served in where the
+    # single-source form is the fused one: read on the chip, not raced
+    _MRHS_FORM = formsel.MEASURED_MRHS["clover"]
     _PROGRAM_STATIC = _ProgramOperand._PROGRAM_STATIC + ("_op_form",)
 
     def __init__(self, dpc: "DiracCloverPC", store_dtype=jnp.float32,
@@ -226,7 +258,7 @@ class DiracCloverPCPairs(_ProgramOperand, _SchurPairOpBase):
         self.matpc = matpc
         self.clover_p_pp = clover_p_pp
         self.clover_inv_q_pp = clover_inv_q_pp
-        from . import formsel
+        self.clover_q_pp = None
         aux = jnp.dtype(store_dtype).name
         self._op_form = formsel.resolve_form(
             "clover", form, self,
@@ -239,6 +271,12 @@ class DiracCloverPCPairs(_ProgramOperand, _SchurPairOpBase):
     def _Ainv_q_sign_pairs(self, x, sign, out_dtype):
         return apply_clover_pairs(self.clover_inv_q_pp, x, out_dtype)
 
+    def _diag_sign_pairs_mrhs(self, x, sign, out_dtype):
+        return apply_clover_pairs_mrhs(self.clover_p_pp, x, out_dtype)
+
+    def _Ainv_q_sign_pairs_mrhs(self, x, sign, out_dtype):
+        return apply_clover_pairs_mrhs(self.clover_inv_q_pp, x, out_dtype)
+
     # fused-epilogue descriptors (ops/clover_pallas via _SchurPairOpBase):
     # K1 = Ainv_q blocks post-hop, K2 = A_p blocks on the original x —
     # both sign-independent (the clover PC operator is g5-hermitian)
@@ -247,6 +285,65 @@ class DiracCloverPCPairs(_ProgramOperand, _SchurPairOpBase):
 
     def _fused_k2_params(self, sign):
         return self.clover_p_pp, None
+
+    # -- entry and verified exit as programs (solvers/program.py) --------
+    def with_full_diag(self, clover_q_pp):
+        """The same resident arrays with the A blocks of the other
+        parity beside them (a leaf: the executables are shared), for
+        ``verified_exit_pairs``."""
+        op = copy.copy(self)
+        op.clover_q_pp = clover_q_pp
+        return op
+
+    def prepare_normal_pairs(self, b):
+        """The entry of a CGNR solve, meant to be traced
+        (solvers/program.prepare): the canonical full-lattice source,
+        or a batch of them on a leading axis, split by parity, through
+        ``prepare`` and ``Mdag`` -> the normal equations' pair-form
+        right-hand side."""
+        if b.ndim == 7:
+            return self.Mdag_pairs_mrhs(self.prepare_pairs_mrhs(
+                *jax.vmap(lambda v: even_odd_split(v, self.geom))(b)))
+        return self.Mdag_pairs(
+            self.prepare_pairs(*even_odd_split(b, self.geom)))
+
+    def verified_exit_pairs(self, b, x_pp):
+        """The API's verified exit on the pair representation: the
+        canonical full-lattice source ``b`` and the pair-form PC
+        solution -> (canonical full-lattice solution, |b - M x| / |b|).
+        x_q = Ainv_q (b_q + kappa D x_p) is the reconstruction; the
+        residual is that of the RETURNED solution under the full
+        M = A - kappa D, applied parity by parity in f32 with this
+        operator's own hop, its A_p blocks and the A_q blocks of
+        ``with_full_diag`` (nothing inverted: the q half holds A_q
+        against its inverse).  With a leading source axis on both, the
+        batched hop and one residual per source.  Meant to be traced
+        (solvers/program.py) on the f32 operator."""
+        f32, p, kappa = jnp.float32, self.matpc, self.kappa
+        batched = b.ndim == 7
+        per_src = jax.vmap if batched else (lambda f: f)
+        hop = self._d_to_mrhs if batched else self._d_to
+        blocks = partial(
+            apply_clover_pairs_mrhs if batched else apply_clover_pairs,
+            out_dtype=f32)
+        to_pp = per_src(lambda v: self._to_pairs(v).astype(f32))
+        from_pp = per_src(lambda v: self._from_pairs(v, b.dtype))
+        norm2 = per_src(lambda v: jnp.sum(v * v))
+        halves = per_src(lambda v: even_odd_split(v, self.geom))(b)
+        b_p, b_q = (to_pp(h)
+                    for h in (halves if p == EVEN else halves[::-1]))
+        x_p = x_pp.astype(f32)
+        # D x_p serves the reconstruction and the q half of M x
+        d_xp = hop(x_p, 1 - p, f32)
+        x_q = blocks(self.clover_inv_q_pp, b_q + kappa * d_xp)
+        r_p = b_p - (blocks(self.clover_p_pp, x_p)
+                     - kappa * hop(x_q, p, f32))
+        r_q = b_q - (blocks(self.clover_q_pp, x_q) - kappa * d_xp)
+        x_e, x_o = (from_pp(v)
+                    for v in ((x_p, x_q) if p == EVEN else (x_q, x_p)))
+        x = per_src(lambda e, o: even_odd_join(e, o, self.geom))(x_e, x_o)
+        return x, jnp.sqrt((norm2(r_p) + norm2(r_q))
+                           / (norm2(b_p) + norm2(b_q)))
 
 
 jax.tree_util.register_pytree_node_class(DiracCloverPCPairs)

@@ -42,6 +42,15 @@ FORMS = ("", "auto", "pallas", "xla")
 # in every process, 90-130 s of load_clover_quda.  'auto' still races.
 MEASURED = {"clover": "pallas"}
 
+# The form a BATCH is served in (a leading source axis:
+# _SchurPairOpBase._M_sign_pairs_mrhs) where the family's single-source
+# form is the fused one: no race and no knob of its own (an operator
+# pinned or resolved to 'xla' serves its batch staged too).  Clover, one
+# v5e, 24^4, eight sources, f32, in a loop (PERF.md section 6, PR 46):
+# fused 9.15 ms a MdagM and 10.91 ms a batched-CG iteration against
+# staged 14.13 and 15.78 (the bare fullz hops + XLA's block products).
+MEASURED_MRHS = {"clover": "pallas"}
+
 _NOTICED: set = set()
 
 

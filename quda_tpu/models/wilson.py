@@ -866,6 +866,10 @@ class _SchurPairOpBase(_PackedHopMixin, _PairSloppyBase):
     # family construction; 'pallas' routes _M_sign_pairs through the
     # fused epilogue kernels of ops/clover_pallas)
     _op_form = "xla"
+    # the form a batch takes where ``_op_form`` is 'pallas' and the
+    # family's batched forms have been read on the chip
+    # (formsel.MEASURED_MRHS); None: the batch follows ``_op_form``
+    _MRHS_FORM = None
 
     def _diag_sign_pairs(self, x, sign, out_dtype):
         raise NotImplementedError
@@ -946,9 +950,23 @@ class _SchurPairOpBase(_PackedHopMixin, _PairSloppyBase):
         return jax.vmap(
             lambda v: self._Ainv_q_sign_pairs(v, sign, out_dtype))(x)
 
+    def _mrhs_form(self) -> str:
+        """The form the batched operator is served in: the fused MRHS
+        kernels only where the single-source operator is fused, and
+        then what the chip read for a batch where it has
+        (``_MRHS_FORM``)."""
+        if self._op_form != "pallas":
+            return "xla"
+        return self._MRHS_FORM or "pallas"
+
     def _M_sign_pairs_mrhs(self, x, sign, form=None):
+        from ..obs import metrics as omet
         p = self.matpc
-        if (form or self._op_form) == "pallas":
+        form = form or self._mrhs_form()
+        # counted where it is traced, as wilson_mrhs_route_total
+        for stage in ("post", "diag_hop"):
+            omet.inc("clover_mrhs_route_total", form=form, stage=stage)
+        if form == "pallas":
             from ..ops import clover_pallas as clp
             k1_blk, k1_twist = self._fused_k1_params(sign)
             k2_blk, k2_twist = self._fused_k2_params(sign)

@@ -188,9 +188,10 @@ METRICS: dict[str, dict] = {
     "solve_program_total": {
         "type": COUNTER,
         "help": "calls through a cached program (solvers/program.py: "
-                "the solve loops, solver='verified-exit' the Wilson "
-                "and staggered pair routes' verified exit, and "
-                "solver='prepare' the batched staggered route's entry), "
+                "the solve loops, solver='verified-exit' the Wilson, "
+                "staggered, Möbius and batched clover pair routes' "
+                "verified exit, and solver='prepare' the entry of the "
+                "staggered, Möbius and batched clover routes), "
                 "by api/form/solver/outcome: 'miss' traced (and lowered, "
                 "compiled or fetched) the program, 'hit' was an "
                 "in-process executable lookup"},
@@ -207,7 +208,8 @@ METRICS: dict[str, dict] = {
     "clover_term_total": {
         "type": COUNTER,
         "help": "uses of the resident clover term (load_clover_quda, "
-                "clover invert_quda) by outcome: 'built' nothing was "
+                "clover invert_quda and the batched clover route of "
+                "invert_multi_src_quda) by outcome: 'built' nothing was "
                 "resident, 'reused' the resident term served, "
                 "'rebuilt' another kappa*csw, matpc, gauge or kernel "
                 "route replaced it"},
@@ -275,6 +277,18 @@ METRICS: dict[str, dict] = {
                 "first M's second hop it is the batched CG's pAp = "
                 "|g5 M p|^2, from the residual hop its new |r|^2), "
                 "'none' the bare hop"},
+    "clover_mrhs_route_total": {
+        "type": COUNTER,
+        "help": "traced applications of a Schur pair operator to a batch "
+                "(models/wilson._SchurPairOpBase._M_sign_pairs_mrhs: "
+                "clover, and the twisted families on the same template) "
+                "by form: 'pallas' the fused MRHS kernels of "
+                "ops/clover_pallas (links AND blocks read once for all "
+                "sources), 'xla' the bare MRHS Wilson hop and XLA's "
+                "block products; and by stage: 'post' the first hop "
+                "with Ainv_q behind it, 'diag_hop' the second with the "
+                "diagonal and the combine; an M counts one of each "
+                "where it is traced"},
     "multishift_shift_total": {
         "type": COUNTER,
         "help": "shifts of invert_multishift_quda calls on the resident "

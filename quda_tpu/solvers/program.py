@@ -29,16 +29,20 @@ in-process executable lookup.
   ``multishift.update_form``).  The key holds no array and no operator
   identity.
 
-The verified exit of the Wilson, staggered and Möbius pair routes is a
-program of the same kind (``verified_exit``): from the canonical source and the
-pair-form solution to the canonical solution and its true residual, the
-resident f32 pair operator an operand.  ``prepare`` is the entry's: the
-canonical source, split by parity, to the pair-form PC right-hand side.
+The verified exit of the Wilson, staggered and Möbius pair routes and
+of the batched clover route is a program of the same kind
+(``verified_exit``): from the canonical source and the pair-form
+solution to the canonical solution and its true residual, the resident
+f32 pair operator an operand (the clover operator with the A blocks of
+its other parity a leaf beside its own: ``with_full_diag``).
+``prepare`` is the entry's: the canonical source, split by parity, to
+the pair-form PC right-hand side, or to the normal equations' where the
+operator folds ``Mdag`` in (``prepare_normal_pairs``: Möbius, clover).
 
 An operator goes through a program when it ``presents``: its class is a
 registered pytree with a ``program_signature``.  Everything else (a
-mesh operator, an MG closure, a bare lambda, the zoo pair operators)
-keeps the eager solver call.  ``cg_reliable`` solves the NORMAL
+mesh operator, an MG closure, a bare lambda, the twisted pair
+operators) keeps the eager solver call.  ``cg_reliable`` solves the NORMAL
 equations of a non-Hermitian PC operator (``MdagM_pairs``: Wilson,
 clover) and applies a ``hermitian`` one once an iteration (``M_pairs``:
 the staggered PC operator is already 4m^2 - D D); the batched program
@@ -213,8 +217,9 @@ def _verified_exit_program(op, b, x_pp):
 
 def verified_exit(op, b, x_pp):
     """The verified exit of a solve on the f32 packed pair operator
-    ``op`` (``verified_exit_pairs``: Wilson, staggered) through the cached
-    program: canonical source(s) and pair-form PC solution(s) ->
+    ``op`` (``verified_exit_pairs``: Wilson, staggered, Möbius, clover
+    ``with_full_diag``) through the cached program: canonical source(s)
+    and pair-form PC solution(s) ->
     ``((canonical full-lattice solution, true residual), hit)``.  With
     a leading source axis on ``b`` and ``x_pp`` the N residuals come
     back in one array."""
@@ -226,7 +231,9 @@ def _prepare_program(op, b):
     _traces[0] += 1
     from ..fields.spinor import even_odd_split
     if hasattr(op, "prepare_normal_pairs"):
-        # a 5-d operator: the leading axis is Ls, never a batch
+        # the operator's own entry, Mdag folded in: a 5-d operator (the
+        # leading axis is Ls, never a batch) or the clover operator
+        # (one source or a batch)
         return op.prepare_normal_pairs(b)
     if b.ndim == 7:
         return op.prepare_pairs_mrhs(
@@ -239,8 +246,10 @@ def prepare(op, b):
     full-lattice source, split by parity and through
     ``op.prepare_pairs``, to the pair-form PC right-hand side, as one
     cached program; a batch of sources (a leading axis) through
-    ``op.prepare_pairs_mrhs``; a 5-d operator's
-    ``prepare_normal_pairs`` (the Möbius pair operator: the split over
-    every s-slice, ``prepare`` and ``Mdag``, so what comes back is the
-    normal equations' right-hand side).  Returns ``(rhs, hit)``."""
+    ``op.prepare_pairs_mrhs``; an operator's own
+    ``prepare_normal_pairs`` where it has one (the Möbius pair
+    operator: the split over every s-slice, ``prepare`` and ``Mdag``;
+    the clover pair operator: the same for one source or a batch), so
+    that what comes back is the normal equations' right-hand side.
+    Returns ``(rhs, hit)``."""
     return _run(_prepare_program, op, b)

@@ -139,15 +139,17 @@ def pytest_collection_modifyitems(config, items):
 # took interpreted kernels out of test_solve_program.py,
 # test_staggered_pallas.py, test_precision_forms.py and
 # test_fused_iter.py, now under 50 s: theirs are from PR 45's whole
-# run, 966 s and 5,601 test-seconds, scaled to that run's).  It
+# run, 966 s and 5,601 test-seconds, scaled to that run's; PR 46 added
+# ~35 s of batched-route cases to test_clover_resident.py and ~13 s of
+# described-chip compiles to test_chip_compile.py).  It
 # only orders the hand-out: a stale or missing entry costs balance and
 # nothing else.
 FILE_SECONDS = {
     "test_solve_program.py": 550, "test_multirhs.py": 470,
     "test_multirhs_kernels.py": 400, "test_pallas.py": 360,
     "test_pair_mg.py": 360, "test_staggered_pallas.py": 360,
-    "test_domain_wall.py": 270, "test_clover_resident.py": 240,
-    "test_chip_compile.py": 220, "test_precision_forms.py": 160,
+    "test_domain_wall.py": 270, "test_clover_resident.py": 280,
+    "test_chip_compile.py": 230, "test_precision_forms.py": 160,
     "test_mixed.py": 210, "test_wilson_resident.py": 170,
     "test_interface.py": 170, "test_pair_gauge.py": 170,
     "test_twisted.py": 160, "test_serve.py": 150, "test_pair_eig.py": 130,

@@ -665,6 +665,82 @@ def test_hisq_batched_programs_compile_for_v5e(one_chip, program):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
 
 
+@pytest.mark.parametrize("program", ["prepare", "solve", "verified-exit"])
+def test_clover_batched_programs_compile_for_v5e(one_chip, program):
+    """The three programs of a batched clover call (solvers/program.py
+    on the resident f32 DiracCloverPCPairs ``with_full_diag``, 8 sources
+    at 24^4, the served batch form) compile for the described chip on
+    abstract operands: links, the blocks of both parities and A_q are
+    parameters; the solve applies the two fused MRHS kernels twice an
+    iteration (``_pick_bz`` finds their z-block at YXh 288 with the 144
+    block planes and the centre operand resident); the entry folds Mdag
+    in (one more of each fused kernel behind the bare hop of prepare);
+    the exit is two bare MRHS hops and XLA's block products, and what it
+    holds with its arguments and results leaves the rest of the chip to
+    the resident term and the caller's batch (ISSUE 46's rule on the
+    peak: 15.0 of 15.75 GiB)."""
+    import re
+    from quda_tpu.fields.geometry import LatticeGeometry
+    from quda_tpu.models.clover import DiracCloverPCPairs
+    from quda_tpu.solvers import program as sprog
+    from quda_tpu.solvers.fused_iter import _resolve_check_every
+    geom = LatticeGeometry(DIMS)
+    half = (L, L, YXH)
+    n = 8
+
+    def operator(links_e, links_o, a_p, ainv_q, a_q):
+        return DiracCloverPCPairs.from_packed(
+            geom, (links_e, links_o), 0.124, 0, a_p, ainv_q, F32,
+            use_pallas=True, pallas_interpret=False,
+            form="pallas").with_full_diag(a_q)
+
+    def lower():
+        lk = jax.ShapeDtypeStruct((4, 3, 3) + half, jnp.complex64)
+        bk = jax.ShapeDtypeStruct((2, 6, 6) + half, jnp.complex64)
+        aq = jax.ShapeDtypeStruct((2, 6, 6, 2) + half, F32)
+        op = jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(
+                s.shape, s.dtype, sharding=one_chip,
+                weak_type=s.weak_type),
+            jax.eval_shape(operator, lk, lk, bk, bk, aq))
+        assert sprog.presents(op) and op._mrhs_form() == "pallas"
+        x = jax.ShapeDtypeStruct(*_psi(F32, (n,)), sharding=one_chip)
+        b = jax.ShapeDtypeStruct((n,) + DIMS + (4, 3), jnp.complex64,
+                                 sharding=one_chip)
+        if program == "prepare":
+            return sprog._prepare_program.lower(op, b)
+        if program == "verified-exit":
+            return sprog._verified_exit_program.lower(op, b, x)
+        key = (_resolve_check_every(None),
+               sprog._LoopKnobs(False, None, None, None), False)
+        return sprog._batched_cg_pairs_program.lower(op, x, 1e-6, 10000,
+                                                     key=key)
+    compiled = _aot(lower)
+    hlo = compiled.as_text()
+    calls = sorted(re.findall(r"%(dslash_eo_pallas\w*?)[.\d]* = f32"
+                              r"\[[^\n]*tpu_custom_call", hlo))
+    fused = ["dslash_eo_pallas_diag_hop_mrhs", "dslash_eo_pallas_post_mrhs"]
+    bare = "dslash_eo_pallas_packed_mrhs"
+    assert calls == {"prepare": sorted(fused + [bare]),
+                     "solve": sorted(2 * fused),
+                     "verified-exit": [bare, bare]}[program], calls
+    params = _hlo_values(hlo, "parameter")
+    links = ",".join(str(d) for d in _links(F32)[0])
+    blocks = ",".join(str(d) for d in (2, 6, 6, 2, L, L, YXH))
+    assert sum(p[1:] == ("f32", links) for p in params) >= 2
+    assert sum(p[1:] == ("f32", blocks) for p in params) == {
+        "prepare": 2, "solve": 2, "verified-exit": 3}[program]
+    big = [c for c in _hlo_values(hlo, "constant") if c[0] > 2 ** 20]
+    assert not big, f"fields baked into the executable: {big}"
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes < n * 0.4 * 2 ** 30, ma
+    # beside 4.2 GiB of resident gauge and term (clover24_single's peak)
+    # and the caller's 1.3 GiB batch of sources
+    need = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes)
+    assert need < 7 * 2 ** 30, ma
+
+
 @pytest.mark.parametrize("program", ["solve", "verified-exit"])
 def test_hisq_multishift_programs_compile_for_v5e(one_chip, program):
     """The two programs of a multi-shift improved-staggered call that

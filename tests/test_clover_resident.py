@@ -362,3 +362,187 @@ def test_cached_program_equals_the_eager_solver_on_the_clover_operator():
     np.testing.assert_allclose(
         np.asarray(cached.x), np.asarray(eager.x), rtol=0,
         atol=1e-5 * float(jnp.max(jnp.abs(eager.x))))
+
+
+# (e) the batched route: invert_multi_src_quda on the resident term -----------
+# PR 46.  Three sources (N is not the point), 4^4, the staged XLA form;
+# the three programs of the route are compiled once, by ``batch_warm``,
+# and every case after it must be served by them (another gauge, kappa
+# or csw is an operand).
+
+N_SRC = 3
+
+
+def _batch(seed, lat=L):
+    return np.stack([_source(100 * seed + i, lat) for i in range(N_SRC)])
+
+
+def _program_counts():
+    """{(solver, outcome): n} of solve_program_total on the batched
+    clover route."""
+    out = {}
+    for (name, labels), v in omet.snapshot()["counters"].items():
+        lab = dict(labels)
+        if (name == "solve_program_total"
+                and lab["form"] == "clover_batched_pairs"):
+            assert lab["api"] == "invert_multi_src_quda"
+            out[lab["solver"], lab["outcome"]] = int(v)
+    return out
+
+
+def _route_counts():
+    return {(dict(l)["form"], dict(l)["stage"]): int(v)
+            for (n, l), v in omet.snapshot()["counters"].items()
+            if n == "clover_mrhs_route_total"}
+
+
+@pytest.fixture(scope="module")
+def batch_warm(warm):
+    """The worker's first batched clover call (after the single-source
+    ``warm``, so the term of gauge A is there): what it returned, the
+    counters' change and the route's own program counts."""
+    from quda_tpu.solvers import program as sprog
+    api.load_clover_quda(_param())
+    before, routes0 = _counts(), _route_counts()
+    B, p = _batch(1), _param()
+    n0 = sprog._traces[0]
+    x = api.invert_multi_src_quda(B, p)
+    routes = {k: v - routes0.get(k, 0) for k, v in _route_counts().items()}
+    return {"B": B, "x": np.asarray(x), "param": p,
+            "delta": _delta(before), "programs": _program_counts(),
+            "routes": routes, "traced": sprog._traces[0] - n0}
+
+
+def test_batch_first_call_builds_three_programs_on_the_resident_term(
+        batch_warm, gauges):
+    w = batch_warm
+    assert w["delta"] == {("clover_term_total", "reused"): 1,
+                          ("solve_program_total", "miss"): 3}
+    assert w["programs"] == {("prepare", "miss"): 1,
+                             ("batched-cg-pairs", "miss"): 1,
+                             ("verified-exit", "miss"): 1}
+    assert w["traced"] == 3
+    # the staged form off the chip; an M counts both stages where it is
+    # traced: Mdag of the entry, M and Mdag of the loop
+    assert w["routes"] == {("xla", "post"): 3, ("xla", "diag_hop"): 3}
+    p = w["param"]
+    assert all(p.converged_multi) and len(p.true_res_multi) == N_SRC
+    for i in range(N_SRC):
+        r = _host_residual(gauges["A"], w["B"][i], w["x"][i])
+        assert r < 5e-6
+        assert abs(p.true_res_multi[i] - r) / r < 0.1
+
+
+def test_batch_second_call_hits_and_another_csw_only_rebuilds_the_term(
+        batch_warm, gauges):
+    from quda_tpu.solvers import program as sprog
+    api.load_clover_quda(_param())
+    n0, progs0, before = sprog._traces[0], _program_counts(), _counts()
+    B, p = _batch(2), _param()
+    x = api.invert_multi_src_quda(B, p)
+    assert _delta(before) == {("clover_term_total", "reused"): 1,
+                              ("solve_program_total", "hit"): 3}
+    hits = {k: v - progs0.get(k, 0) for k, v in _program_counts().items()}
+    assert hits == {("prepare", "miss"): 0, ("batched-cg-pairs", "miss"): 0,
+                    ("verified-exit", "miss"): 0, ("prepare", "hit"): 1,
+                    ("batched-cg-pairs", "hit"): 1,
+                    ("verified-exit", "hit"): 1}
+    assert max(_host_residual(gauges["A"], B[i], x[i])
+               for i in range(N_SRC)) < 5e-6
+    # another kappa and csw: the term is rebuilt, the programs are not
+    before = _counts()
+    p = _param(kappa=0.11, csw=1.3)
+    x = api.invert_multi_src_quda(B, p)
+    assert _delta(before) == {("clover_term_total", "rebuilt"): 1,
+                              ("solve_program_total", "hit"): 3}
+    assert sprog._traces[0] == n0
+    for i in range(N_SRC):
+        assert _host_residual(gauges["A"], B[i], x[i], 0.11, 1.3) < 5e-6
+        assert _host_residual(gauges["A"], B[i], x[i]) > 1e-3
+
+
+def test_batch_equals_single_source_solves_on_the_same_term(batch_warm):
+    api.load_clover_quda(_param())
+    for i in range(N_SRC):
+        x1 = api.invert_quda(batch_warm["B"][i], _param())
+        assert _rel(jnp.asarray(batch_warm["x"][i]), x1) < 1e-5
+
+
+def test_batch_under_the_plain_reference(batch_warm, bench):
+    """Every source of a batched call on the benchmark's own links,
+    judged by the benchmark's own operator: the residual and the API's
+    own claim of it."""
+    data, ref = bench["data"], bench["reference.clover"]
+    lat = (L,) * 4
+    u = data.su3_field(data.key_of(101, 0), (4,), lat, 0.7)
+    b = data.gaussian_sources(data.key_of(5, 1000), lat, N_SRC)
+    before = _counts()
+    try:
+        _load(data.to_canonical_gauge(u, lat))
+        p = _param(kappa=0.2, csw=ref.CSW, maxiter=2000)
+        x = api.invert_multi_src_quda(data.to_canonical_spinors(b, lat), p)
+        got = data.from_canonical_spinors(x)
+        links = ref.fold_boundary(u, True)
+        for i in range(N_SRC):
+            r = ref.rel_residual(links, 0.2, lat[3], b[i], got[i])
+            assert p.converged_multi[i] and r <= 5e-6
+            assert abs(p.true_res_multi[i] - r) / r < 0.1
+        assert ("solve_program_total", "miss") not in _delta(before)
+    finally:
+        _load(np.asarray(_gauge(11, L)))
+
+
+def _pc_pairs(op, x):
+    """The pair-form PC solution inside canonical solution(s) ``x``."""
+    half = lambda v: op._to_pairs(even_odd_split(v, op.geom)[op.matpc])
+    return jax.vmap(half)(x) if x.ndim == 7 else half(x)
+
+
+@pytest.mark.parametrize("n", [1, N_SRC])
+def test_verified_exit_pairs_equals_the_eager_full_operator(batch_warm, n):
+    """``DiracCloverPCPairs.verified_exit_pairs`` for both ranks against
+    the single-source route's eager check (models/clover._full_m_pairs
+    with the term's A_q) on the same solutions."""
+    from quda_tpu.interfaces.quda_api import _CloverResidentSolve
+    api.load_clover_quda(_param())
+    d = _CloverResidentSolve(api._ctx["clover"], KAPPA)
+    op = d.with_full_diag()
+    B = jnp.asarray(batch_warm["B"][:n])
+    X = jnp.asarray(batch_warm["x"][:n])
+    if n == 1:
+        B, X = B[0], X[0]
+    shape = (n,) + (L,) * 4 + (4, 3)
+    full = d.full()
+    # the sound solutions, whose residuals are f32 rounding (two
+    # evaluations of them agree to a part in a hundred), and the same
+    # scaled by 1.01, whose residuals are not
+    for scale, rtol in ((1.0, 1e-2), (1.01, 1e-5)):
+        x_back, res = op.verified_exit_pairs(B, scale * _pc_pairs(op, X))
+        if scale == 1.0:
+            assert _rel(x_back, X) < 1e-6
+        want = [_rel(full.M(jnp.asarray(x)), b) for x, b in
+                zip(np.asarray(x_back).reshape(shape),
+                    np.asarray(B).reshape(shape))]
+        np.testing.assert_allclose(np.asarray(res).reshape(n), want,
+                                   rtol=rtol)
+        assert (scale == 1.0) == (max(want) < 5e-6)
+
+
+def test_an_altered_solution_raises_its_own_residual_only(batch_warm):
+    """The exit program's residual is per source: one PC solution of the
+    batch scaled by 1.01 reads ~1e-2 there and leaves the others'."""
+    from quda_tpu.interfaces.quda_api import _CloverResidentSolve
+    from quda_tpu.solvers import program as sprog
+    api.load_clover_quda(_param())
+    op = _CloverResidentSolve(api._ctx["clover"], KAPPA).with_full_diag()
+    B = jnp.asarray(batch_warm["B"])
+    x_pp = _pc_pairs(op, jnp.asarray(batch_warm["x"]))
+    (_, sound), hit = sprog.verified_exit(op, B, x_pp)
+    assert hit
+    (_, bad), hit = sprog.verified_exit(op, B, x_pp.at[1].multiply(1.01))
+    assert hit
+    sound, bad = np.asarray(sound), np.asarray(bad)
+    np.testing.assert_allclose(
+        sound, batch_warm["param"].true_res_multi, rtol=1e-3)
+    assert bad[1] > 1e3 * sound[1] and bad[1] > 1e-3
+    np.testing.assert_array_equal(bad[[0, 2]], sound[[0, 2]])
