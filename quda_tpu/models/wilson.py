@@ -963,9 +963,11 @@ class _SchurPairOpBase(_PackedHopMixin, _PairSloppyBase):
         from ..obs import metrics as omet
         p = self.matpc
         form = form or self._mrhs_form()
-        # counted where it is traced, as wilson_mrhs_route_total
-        for stage in ("post", "diag_hop"):
-            omet.inc("clover_mrhs_route_total", form=form, stage=stage)
+        # counted where it is traced, as wilson_mrhs_route_total, and by
+        # the route each fused call takes from its shapes
+        def count(stage, route="none"):
+            omet.inc("clover_mrhs_route_total", form=form, stage=stage,
+                     route=route)
         if form == "pallas":
             from ..ops import clover_pallas as clp
             k1_blk, k1_twist = self._fused_k1_params(sign)
@@ -973,17 +975,24 @@ class _SchurPairOpBase(_PackedHopMixin, _PairSloppyBase):
             dims = tuple(self.dims)
             itp = self._pallas_interpret
             bz = getattr(self, "_block_z", None)
+            u_q, u_p = self.gauge_eo_pp[1 - p], self.gauge_eo_pp[p]
+            count("post", clp.mrhs_route(u_q, x, None, k1_blk,
+                                         self.store_dtype, bz)[0])
             t = clp.dslash_eo_pallas_post_mrhs(
-                self.gauge_eo_pp[1 - p], self._u_bw[1 - p], x, dims,
+                u_q, self._u_bw[1 - p], x, dims,
                 1 - p, blk_pl=k1_blk, twist=k1_twist, interpret=itp,
                 block_z=bz, out_dtype=self.store_dtype,
                 tb_sign=self._tb_sign)
+            count("diag_hop", clp.mrhs_route(u_p, t, x, k2_blk,
+                                             jnp.float32, bz)[0])
             out = clp.dslash_eo_pallas_diag_hop_mrhs(
-                self.gauge_eo_pp[p], self._u_bw[p], t, x, dims, p,
+                u_p, self._u_bw[p], t, x, dims, p,
                 hop_coeff=-(self.kappa ** 2), blk_pl=k2_blk,
                 diag_twist=k2_twist, interpret=itp, block_z=bz,
                 out_dtype=jnp.float32, tb_sign=self._tb_sign)
             return out.astype(self.store_dtype)
+        count("post")
+        count("diag_hop")
         t = self._d_to_mrhs(x, 1 - p, self.store_dtype)
         t = self._Ainv_q_sign_pairs_mrhs(t, sign, self.store_dtype)
         dd = self._d_to_mrhs(t, p, jnp.float32)

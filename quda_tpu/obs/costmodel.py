@@ -54,8 +54,10 @@ from .roofline import KERNEL_MODELS
 FLOPS_RTOL = 0.5
 # analytic bytes_per_site vs the operand-footprint floor: must be >= 1x
 # (cannot move less than the data once) and <= this re-read factor.
-# Measured ratios across the registered forms: 1.15 (staggered two-pass)
-# to 2.14 (twisted-mass MRHS at the n=4 probe point); 2.5 leaves headroom
+# Ratios across the registered forms: 1.06 (the MG coarse stencil) to
+# 2.04 (the int8 links form; the twisted-mass MRHS row read 2.14 at the
+# n=4 probe point while its model was the z-blocked call's five reads,
+# before PR 47, and reads 1.29 on the full-Z route); 2.5 leaves headroom
 # while a factor-2 slip in either direction still fails (the
 # tests/test_costmodel.py fixtures pin both directions)
 BYTES_REREAD_MAX = 2.5
@@ -259,7 +261,10 @@ _FOOTPRINTS: Dict[str, dict] = {
     # the resident diagonal term's storage.  The clover/twisted-clover
     # rows read the packed chiral blocks once per pass; the twisted-mass
     # twist is two compiled-in scalars (zero bytes); the MRHS rows
-    # amortize links AND blocks over the RHS stream.  The r12 floors
+    # amortize links AND blocks over the RHS stream (either route of
+    # the fused MRHS call: the floor counts each operand once; the
+    # models' re-reads over it are the full-Z route's, PR 47: ratio
+    # 1.40 clover, 1.29 twisted mass at the n=4 probe point).  The r12 floors
     # charge the reconstruct-12 link storage at the FORM's dtype basis
     "clover_pallas": {"family": "clover",
                       "floor": lambda n: 2 * _PSI + 2 * _G + _BLK},

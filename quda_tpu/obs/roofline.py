@@ -154,10 +154,16 @@ KERNEL_MODELS: Dict[str, dict] = {
     "clover_pallas_r12": {"flops_per_site": 1824,
                           "bytes_per_site": 1536},
     # MRHS fused clover: links AND blocks amortize over the RHS stream
-    # (both index maps ignore n) — psi 480 + out 96 + (576+576)/N
+    # (both index maps ignore n).  The route follows the shapes
+    # (ops/clover_pallas.mrhs_route, PR 47); the row is what one
+    # chip's 24^4 runs: full-Z tiles, one time-slice a step with the
+    # 144 block planes resident — psi 3x96 + out 96 + (576+576)/N.
+    # The z-blocked fallback of larger local volumes reads psi five
+    # times (576 + 1152/N) and has no row of its own; the K2 stage's
+    # ``xc`` (96 more) is outside this per-pass model on either route
     "clover_pallas_mrhs": {
         "flops_per_site": 1824,
-        "bytes_per_site": lambda nrhs: 576.0 + 1152.0 / nrhs},
+        "bytes_per_site": lambda nrhs: 384.0 + 1152.0 / nrhs},
     # twisted mass: the twist is two STATIC scalars compiled into the
     # epilogue — zero extra traffic over the v2 hop; flops: hop 1320 +
     # twist rotate/combine 96
@@ -165,9 +171,11 @@ KERNEL_MODELS: Dict[str, dict] = {
                             "bytes_per_site": 1152},
     "twisted_mass_pallas_r12": {"flops_per_site": 1416,
                                 "bytes_per_site": 960},
+    # its batch has no blocks: the Wilson batch's route at 24^4, two
+    # time-slices a step — psi 2x96 + out 96 + 576/N
     "twisted_mass_pallas_mrhs": {
         "flops_per_site": 1416,
-        "bytes_per_site": lambda nrhs: 576.0 + 576.0 / nrhs},
+        "bytes_per_site": lambda nrhs: 288.0 + 576.0 / nrhs},
     # twisted clover: dense block term (the twist is folded into the
     # inverse blocks / added in-register) — clover traffic and flops
     "twisted_clover_pallas": {"flops_per_site": 1824,
@@ -176,7 +184,7 @@ KERNEL_MODELS: Dict[str, dict] = {
                                   "bytes_per_site": 1536},
     "twisted_clover_pallas_mrhs": {
         "flops_per_site": 1824,
-        "bytes_per_site": lambda nrhs: 576.0 + 1152.0 / nrhs},
+        "bytes_per_site": lambda nrhs: 384.0 + 1152.0 / nrhs},
     # Ls-batched DWF/Möbius 4d hop (ops/dwf_pallas): per UPDATED 4d
     # site per dslash invocation with Ls baked in — Ls spinor planes
     # stream through ONE gauge-tile fetch (576) on the MRHS kernel's

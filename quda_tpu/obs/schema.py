@@ -288,7 +288,15 @@ METRICS: dict[str, dict] = {
                 "block products; and by stage: 'post' the first hop "
                 "with Ainv_q behind it, 'diag_hop' the second with the "
                 "diagonal and the combine; an M counts one of each "
-                "where it is traced"},
+                "where it is traced; and by route, the fused call's own "
+                "from its shapes (ops/clover_pallas.mrhs_route): "
+                "'fullz' whole-Z tiles of links, blocks and spinors, "
+                "three psi operands a step, the epilogue per chunk of "
+                "the hop's loop, where they fit the full-Z VMEM cap "
+                "(24^4 f32: one time-slice a step), 'zblock' the "
+                "single-source call's z-blocks and five psi operands "
+                "(larger local volumes, a caller's block_z), 'none' the "
+                "'xla' form"},
     "multishift_shift_total": {
         "type": COUNTER,
         "help": "shifts of invert_multishift_quda calls on the resident "

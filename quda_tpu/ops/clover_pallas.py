@@ -32,14 +32,21 @@ spinor and blocks), as it does the Wilson kernel.
 
 The clover term enters as the resident packed pair blocks of
 models/clover.pack_clover_pairs — (2,6,6,2,T,Z,YXh), 576 B/site at f32
-(288 at bf16) — streamed per (t, z-block) tile exactly like the gauge
-tiles; spins (0,1)/(2,3) map to chirality block rows i = 3*(s%2)+c.
+(288 at bf16) — streamed tile by tile exactly like the gauge tiles (a
+(t, z-block) tile a step of the single-source calls); spins
+(0,1)/(2,3) map to chirality block rows i = 3*(s%2)+c.
 The twist is two STATIC floats (c = sign*a and a scale), compiled into
 the kernel — in-register, zero bytes.
 
 MRHS variants batch RHS innermost via the same _mrhs_wrap adapter as
 the Wilson kernels (gauge AND block index maps ignore the RHS index,
-so both stay tile-resident across the RHS stream); the full-lattice
+so both stay tile-resident across the RHS stream).  A batch streams
+from HBM, so they take their route from their shapes as the Wilson
+batch does (``mrhs_route``, PR 47): whole-Z tiles of ``bt``
+time-slices and three psi operands a step, the epilogue per chunk of
+the hop body's loop (``fullz``: the cell's 24^4 with one slice a step
+beside the 144 block planes), or the single-source call's z-blocks and
+five psi operands where those tiles do not fit (``zblock``).  The full-lattice
 ``clover_pallas_packed`` serves the unpreconditioned M = A - kappa D
 with the diagonal read from the center psi tile itself (no extra
 operand at all).
@@ -115,8 +122,15 @@ def _scale_sc(vals, k):
 
 
 def _epilogue_kernel(X, bz, eo, T, tb_sign, *, xc_mode, with_blk,
-                     twist, diag_twist, with_coeff):
+                     twist, diag_twist, with_coeff, z_rows="tiles"):
     """v2 hop kernel + family epilogue over the out tile.
+
+    z_rows: ``"tiles"``, the five psi refs of a (t, z-block) step and
+    the epilogue over its out tile; ``"centre"``, the three of a full-Z
+    step (wilson_pallas_packed._make_kernel), ``bz`` the rows of a
+    chunk: the epilogue then runs per chunk inside the hop body's one
+    loop, on the chunk's views of out, the diagonal operand and the
+    blocks, so its values stay a z-block step's.
 
     xc_mode: None (no diagonal operand), 'input' (sixth psi-layout
     ref), or 'center' (diagonal of the hop INPUT itself — the
@@ -130,14 +144,16 @@ def _epilogue_kernel(X, bz, eo, T, tb_sign, *, xc_mode, with_blk,
     benchmark's trace reduction names a kernel event by the element
     types of its result, first and LAST operand).
     """
-    base = wpp._make_kernel(X, bz, eo=eo, T=T, tb_sign=tb_sign)
+    base = wpp._make_kernel(X, bz, eo=eo, T=T, tb_sign=tb_sign,
+                            z_rows=z_rows)
+    n_psi = 3 if z_rows == "centre" else 5
 
     def kernel(*refs):
-        k = 5
+        k = n_psi
         xc_ref = None
         if xc_mode == "input":
-            xc_ref = refs[5]
-            k = 6
+            xc_ref = refs[k]
+            k += 1
         elif xc_mode == "center":
             xc_ref = refs[0]
         coeff_ref = None
@@ -147,26 +163,34 @@ def _epilogue_kernel(X, bz, eo, T, tb_sign, *, xc_mode, with_blk,
         g_c, g_m = refs[k], refs[k + 1]
         blk_ref = refs[k + 2] if with_blk else None
         out_ref = refs[-1]
+
+        def epilogue(out_ref, xc_ref, blk_ref):
+            hop = _load_sc(out_ref)
+            if not with_coeff:
+                v = _blk_mul(blk_ref, hop) if with_blk else hop
+                if twist is not None:
+                    c, scale = twist
+                    v = _add_sc(v, _ig5_rot(v, c))
+                    if scale != 1.0:
+                        v = _scale_sc(v, scale)
+            else:
+                x = _load_sc(xc_ref)
+                d = _blk_mul(blk_ref, x) if with_blk else x
+                if diag_twist is not None:
+                    d = _add_sc(d, _ig5_rot(x, diag_twist))
+                v = _add_sc(d, _scale_sc(hop, coeff_ref[0]))
+            _store_sc(out_ref, v)
+
         # the unchanged v2 hop body writes its accumulator to the out
         # tile (VMEM); the epilogue reads it straight back — for the
         # post kernels that write/read at the store dtype, which IS the
         # staged rounding of the XLA composition it replaces
-        base(*refs[:5], g_c, g_m, out_ref)
-        hop = _load_sc(out_ref)
-        if not with_coeff:
-            v = _blk_mul(blk_ref, hop) if with_blk else hop
-            if twist is not None:
-                c, scale = twist
-                v = _add_sc(v, _ig5_rot(v, c))
-                if scale != 1.0:
-                    v = _scale_sc(v, scale)
+        if z_rows == "centre":
+            base(*refs[:3], g_c, g_m, out_ref,
+                 epilogue=(epilogue, (xc_ref, blk_ref)))
         else:
-            x = _load_sc(xc_ref)
-            d = _blk_mul(blk_ref, x) if with_blk else x
-            if diag_twist is not None:
-                d = _add_sc(d, _ig5_rot(x, diag_twist))
-            v = _add_sc(d, _scale_sc(hop, coeff_ref[0]))
-        _store_sc(out_ref, v)
+            base(*refs[:5], g_c, g_m, out_ref)
+            epilogue(out_ref, xc_ref, blk_ref)
 
     return kernel
 
@@ -182,6 +206,37 @@ def _coeff_operand(hop_coeff):
     return jnp.asarray(hop_coeff, F32).reshape(1)
 
 
+def mrhs_route(u_pl, psi_pl, xc_pl, blk_pl, out_dtype=None, block_z=None):
+    """(route, bz, bt, vmem_limit_bytes) of a fused MRHS call on these
+    operands (arrays or abstract values), from their shapes:
+    wilson_pallas_packed._mrhs_route's rule with the epilogue's blocks
+    in the sums.  ``"fullz"`` where ``_mrhs_fullz_fit`` finds room
+    (24^4 f32 with the chiral blocks: one time-slice a step, 32.1 MiB
+    for ``post``, 33.8 with ``xc`` for ``diag_hop``; two slices would
+    need 55.7 / 59.1 of the 48 the route may ask for), the blocks and
+    the limit filed with the VMEM audit; ``"zblock"`` (``_pick_bz``'s
+    z-block, or the caller's) where it does not or ``block_z`` < Z
+    asks for z-blocks.  models/wilson labels
+    ``clover_mrhs_route_total`` with the same decision where it traces
+    the call."""
+    T, Z, YXh = psi_pl.shape[-3:]
+    R = u_pl.shape[1]
+    fit = wpp._mrhs_fullz_fit(
+        T, Z, YXh, psi_pl.dtype, out_dtype or psi_pl.dtype, R, block_z,
+        extra=[(n, v.dtype) for n, v in ((_BLK_PLANES, blk_pl),
+                                         (_XC_PLANES, xc_pl))
+               if v is not None])
+    if fit:
+        bt, blocks, need = fit
+        return "fullz", Z, bt, wpp._fullz_vmem_limit(blocks, need, Z)
+    bz = block_z if block_z is not None else wpp._pick_bz(
+        Z, YXh, psi_pl.dtype, planes=_planes(
+            R, None if xc_pl is None else "input", blk_pl is not None))
+    if Z % bz != 0:
+        raise ValueError(f"block_z={bz} does not divide Z={Z}")
+    return "zblock", bz, 1, None
+
+
 def _fused_eo_call(u_here_pl, u_bw_pl, psi_pl, xc_pl, blk_pl, coeff, dims,
                    target_parity, *, name, mrhs=False, twist=None,
                    diag_twist=None, interpret=False, block_z=None,
@@ -191,7 +246,21 @@ def _fused_eo_call(u_here_pl, u_bw_pl, psi_pl, xc_pl, blk_pl, coeff, dims,
     spinor operand a leading RHS axis, streamed innermost: gauge AND
     block index maps ignore the RHS index, so both stay tile-resident
     across the RHS stream (the MRHS amortisation carries over to the
-    576 B/site clover blocks, not just the links)."""
+    576 B/site clover blocks, not just the links).
+
+    A batch does not sit on chip as a single source does in a CG loop:
+    every psi operand of every step is a fresh DMA (the MRHS comment of
+    ops/wilson_pallas_packed).  So the MRHS call takes its route from
+    its shapes, as the Wilson batch does.  ``fullz``, where
+    ``_mrhs_fullz_fit`` says the blocks fit: grid (T/bt, 1, N), whole
+    (bt, Z, YXh) tiles of links, chiral blocks, ``xc`` and out, THREE
+    psi operands (the centre block and the single slices after and
+    before it), the body ``_make_kernel``'s chunk loop with the
+    epilogue per chunk, its own ``vmem_limit_bytes``.  ``zblock``, the
+    single-source call with the RHS axis: five psi operands a (t,
+    z-block) step, where full-Z does not fit or ``block_z`` < Z asks
+    for it.  Per source the two bit-match each other and the
+    single-source kernel."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -201,34 +270,62 @@ def _fused_eo_call(u_here_pl, u_bw_pl, psi_pl, xc_pl, blk_pl, coeff, dims,
     YXh = psi_pl.shape[-1]
     with_blk = blk_pl is not None
     xc_mode = "input" if xc_pl is not None else None
-    bz = block_z if block_z is not None else wpp._pick_bz(
-        Z, YXh, psi_pl.dtype, planes=_planes(R, xc_mode, with_blk))
-    if Z % bz != 0:
-        raise ValueError(f"block_z={bz} does not divide Z={Z}")
+    odt = out_dtype or psi_pl.dtype
+    if mrhs:
+        route, bz, bt, vmem_limit = mrhs_route(
+            u_here_pl, psi_pl, xc_pl, blk_pl, odt, block_z)
+    else:
+        route, bt, vmem_limit = "zblock", 1, None
+        bz = block_z if block_z is not None else wpp._pick_bz(
+            Z, YXh, psi_pl.dtype, planes=_planes(R, xc_mode, with_blk))
+        if Z % bz != 0:
+            raise ValueError(f"block_z={bz} does not divide Z={Z}")
     nzb = Z // bz
     lead = (1,) if mrhs else ()
+    if route == "fullz":
+        z_rows, body_rows = "centre", wpp._fullz_chunk(Z, psi_pl.dtype)
 
-    def psi_spec(dt, dz):
-        return pl.BlockSpec(
-            lead + (4, 3, 2, 1, bz, YXh),
-            lambda t, zb, *n: n + (0, 0, 0, (t + dt) % T,
-                                   (zb + dz) % nzb, 0))
+        def slice_spec(dt):
+            # one time-slice, dt slices off the block's first
+            return pl.BlockSpec(
+                (1, 4, 3, 2, 1, Z, YXh),
+                lambda tb, zb, n: (n, 0, 0, 0, (tb * bt + dt) % T, 0, 0))
 
-    def site_spec(*idx):
-        return pl.BlockSpec(
-            idx + (1, bz, YXh),
-            lambda t, zb, *n: (0,) * len(idx) + (t, zb, 0))
+        def site_spec(*idx):
+            return pl.BlockSpec(
+                idx + (bt, Z, YXh),
+                lambda tb, zb, n: (0,) * len(idx) + (tb, 0, 0))
 
-    kernel = _epilogue_kernel(X, bz, (target_parity, Xh), T, tb_sign,
+        centre_spec = pl.BlockSpec(
+            (1, 4, 3, 2, bt, Z, YXh),
+            lambda tb, zb, n: (n, 0, 0, 0, tb, 0, 0))
+        in_specs = [centre_spec, slice_spec(bt), slice_spec(T - 1)]
+    else:
+        z_rows, body_rows = "tiles", bz
+
+        def psi_spec(dt, dz):
+            return pl.BlockSpec(
+                lead + (4, 3, 2, 1, bz, YXh),
+                lambda t, zb, *n: n + (0, 0, 0, (t + dt) % T,
+                                       (zb + dz) % nzb, 0))
+
+        def site_spec(*idx):
+            return pl.BlockSpec(
+                idx + (1, bz, YXh),
+                lambda t, zb, *n: (0,) * len(idx) + (t, zb, 0))
+
+        centre_spec = psi_spec(0, 0)
+        in_specs = [centre_spec, psi_spec(+1, 0), psi_spec(-1, 0),
+                    psi_spec(0, +1), psi_spec(0, -1)]
+
+    kernel = _epilogue_kernel(X, body_rows, (target_parity, Xh), T, tb_sign,
                               xc_mode=xc_mode, with_blk=with_blk,
                               twist=twist, diag_twist=diag_twist,
-                              with_coeff=coeff is not None)
+                              with_coeff=coeff is not None, z_rows=z_rows)
 
-    in_specs = [psi_spec(0, 0), psi_spec(+1, 0), psi_spec(-1, 0),
-                psi_spec(0, +1), psi_spec(0, -1)]
-    operands = [psi_pl, psi_pl, psi_pl, psi_pl, psi_pl]
+    operands = [psi_pl] * len(in_specs)
     if xc_mode == "input":
-        in_specs.append(psi_spec(0, 0))
+        in_specs.append(centre_spec)
         operands.append(xc_pl)
     if mrhs:
         kernel = wpp._mrhs_wrap(kernel, n_psi=len(operands))
@@ -243,13 +340,14 @@ def _fused_eo_call(u_here_pl, u_bw_pl, psi_pl, xc_pl, blk_pl, coeff, dims,
 
     return pl.pallas_call(
         kernel,
-        grid=(T, nzb) + ((psi_pl.shape[0],) if mrhs else ()),
+        grid=(T // bt, nzb) + ((psi_pl.shape[0],) if mrhs else ()),
         in_specs=in_specs,
-        out_specs=psi_spec(0, 0),
-        out_shape=jax.ShapeDtypeStruct(psi_pl.shape,
-                                       out_dtype or psi_pl.dtype),
+        out_specs=centre_spec,
+        out_shape=jax.ShapeDtypeStruct(psi_pl.shape, odt),
         interpret=interpret,
         name=name,
+        compiler_params=None if vmem_limit is None else
+        pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
     )(*operands)
 
 

@@ -250,6 +250,12 @@ def _make_kernel(X: int, bz: int, eo: tuple | None = None,
     (``_EitherRef``); unrolling the slices instead doubles what every
     process lowers for 5 % of the kernel's time (PERF.md section 6,
     PR 31).  Same values per chunk, same hop algebra: the two bit-match.
+    A caller that goes on from the hop sum hands that loop its
+    ``epilogue=(fn, tiles)`` at call time (ops/clover_pallas: the
+    chiral blocks and the diagonal operand): ``fn`` runs after each
+    chunk's store on the chunk's views of out and of ``tiles``, still
+    one traced body; without it the loop is the plain one, trace for
+    trace.
     With ``eo = (target_parity, Xh)`` the tile is a checkerboarded half
     lattice (fused axis Y*Xh) and x shifts use the slot-parity select of
     wilson_packed.shift_eo_packed; g_c/g_m are then the target-parity
@@ -416,7 +422,10 @@ def _make_kernel(X: int, bz: int, eo: tuple | None = None,
             nrm[...] += sq
 
     def centre_kernel(psi_c, psi_tp, psi_tm, g_c, g_m, out_ref,
-                      xc=None, coeff=None, nrm=None, rc=None, alpha=None):
+                      xc=None, coeff=None, nrm=None, rc=None, alpha=None,
+                      epilogue=None):
+        # epilogue: (fn, tiles), see the docstring; a None among the
+        # tiles stays None
         bt, Z = psi_c.shape[-3:-1]
         nzc = Z // bz
         t0 = pl.program_id(0) * bt    # not inside the loop's body
@@ -444,6 +453,10 @@ def _make_kernel(X: int, bz: int, eo: tuple | None = None,
                    xc=None if xc is None else at(xc, i), coeff=coeff,
                    nrm=nrm, rc=None if rc is None else at(rc, i),
                    alpha=alpha)
+            if epilogue is not None:
+                fn, tiles = epilogue
+                fn(at(out_ref, i),
+                   *(None if r is None else at(r, i) for r in tiles))
             return carry
         if bt * nzc == 1:
             chunk(0, 0)
@@ -645,6 +658,15 @@ def dslash_pallas_packed(gauge_pl: jnp.ndarray, psi_pl: jnp.ndarray,
 #   fullz, bt 1   c, t+1, t-1             576 + N (288 + 96)   3,648  2,112
 #   fullz, bt 2   c (2 slices), t+2, t-1  576 + N (192 + 96)   2,880  2,112
 #
+# and the block-carrying calls of ops/clover_pallas on the same routes
+# (576 B a site of chiral blocks beside the links, once for all sources;
+# ``diag_hop`` reads its ``xc`` tile besides, 96 B a source):
+#
+#   post, zblock                          1,152 + N (480 + 96) 5,760  2,688
+#   post, fullz bt 1                      1,152 + N (288 + 96) 4,224  2,688
+#   diag_hop, zblock                      1,152 + N (576 + 96) 6,528  3,456
+#   diag_hop, fullz bt 1                  1,152 + N (384 + 96) 4,992  3,456
+#
 # The z-blocked route DMAs a whole z-neighbour tile for the one row the
 # body splices from it; at 24^4 (bz 8, 576 grid steps of 9 KB planes)
 # the cell wilson24_mrhs8.light read 1,643 us a call, 26 % of the
@@ -710,7 +732,8 @@ _MRHS_FULLZ_VMEM_CAP = 48 * 2 ** 20
 
 
 def _mrhs_fullz_vmem(Z: int, YX: int, dtype, out_dtype, R: int,
-                     bt: int = 1, xc_dtype=None, rc_dtype=None):
+                     bt: int = 1, xc_dtype=None, rc_dtype=None,
+                     extra: tuple = ()):
     """(block_bytes, need_bytes) of one full-Z MRHS step of ``bt``
     time-slices.  Blocks: bt + 2 psi tiles (24 planes each: the block's
     slices and the one after and before them), bt forward and backward
@@ -719,7 +742,10 @@ def _mrhs_fullz_vmem(Z: int, YX: int, dtype, out_dtype, R: int,
     in its residual form, bt more of ``rc`` (``rc_dtype``),
     every (Z, YX) plane padded to its dtype's (sublane, 128) tile as
     ``_pick_bz`` pads it, and the f32 block of the epilogue's sums of
-    squares, one chunk of the body's rows.
+    squares, one chunk of the body's rows.  ``extra``: (planes, dtype)
+    of each block a slice that a caller's own epilogue brings (the
+    fused clover kernels of ops/clover_pallas: 144 planes of chiral
+    blocks, 24 of their centre operand), bt of each.
     Need: the blocks double-buffered by the pipeline plus the body's
     own f32 tiles, which live in VMEM, not in vregs (accumulators, the
     loaded spinor, the hop's temporaries): six spinors' worth of full-Z
@@ -740,6 +766,7 @@ def _mrhs_fullz_vmem(Z: int, YX: int, dtype, out_dtype, R: int,
         blocks += bt * 24 * plane(xc_dtype) + rows * yx_pad * 4
     if rc_dtype is not None:
         blocks += bt * 24 * plane(rc_dtype)
+    blocks += sum(bt * n * plane(dt) for n, dt in extra)
     return blocks, 2 * blocks + 6 * 24 * plane(F32)
 
 
@@ -751,6 +778,38 @@ def _fullz_chunk(Z: int, dtype) -> int:
     the whole tile where Z is no multiple of it (24 rows of bf16)."""
     sub = _sublane_rows(dtype)
     return sub if Z % sub == 0 else Z
+
+
+def _mrhs_fullz_fit(T: int, Z: int, YX: int, dtype, out_dtype, R: int,
+                    block_z: int | None, xc_dtype=None, rc_dtype=None,
+                    extra: tuple = ()):
+    """(bt, block_bytes, need_bytes) of the full-Z route where an MRHS
+    call of these shapes takes it, else None: where a caller's
+    ``block_z`` asks for z-blocks, or where ``_mrhs_fullz_vmem``'s need
+    passes ``_MRHS_FULLZ_VMEM_CAP`` with one time-slice a step.  Two
+    slices (``bt``) where they fit too and T is even.  A function of
+    the shapes alone (the Wilson call's ``_mrhs_route`` and the
+    block-carrying calls of ops/clover_pallas both ask it)."""
+    if block_z not in (None, Z):
+        return None
+    for bt in (2, 1):
+        if T % bt == 0:
+            blocks, need = _mrhs_fullz_vmem(Z, YX, dtype, out_dtype, R, bt,
+                                            xc_dtype, rc_dtype, extra)
+            if need <= _MRHS_FULLZ_VMEM_CAP:
+                return bt, blocks, need
+    return None
+
+
+def _fullz_vmem_limit(blocks: int, need: int, Z: int) -> int:
+    """The ``vmem_limit_bytes`` of a full-Z call (its need, and not
+    under Mosaic's scoped default), filed with the VMEM audit under the
+    route's name beside ``_pick_bz``'s own decisions."""
+    from ..obs import memory as omem
+    limit = max(need, int(omem.SCOPED_VMEM_MB * 2 ** 20))
+    omem.vmem_audit("QUDA_TPU_PALLAS_VMEM_MB", blocks, limit, bz=Z,
+                    route="fullz")
+    return limit
 
 
 def _mrhs_route(T: int, Z: int, YX: int, dtype, out_dtype, R: int,
@@ -775,18 +834,13 @@ def _mrhs_route(T: int, Z: int, YX: int, dtype, out_dtype, R: int,
     z-block's), ``wilson_mrhs_route_total`` counts the call by route,
     epilogue (``none``, ``combine``, ``residual``) and reduce
     (``norm2``: the epilogue's sums)."""
-    from ..obs import memory as omem
     from ..obs import metrics as omet
-    fits = [(bt,) + _mrhs_fullz_vmem(Z, YX, dtype, out_dtype, R, bt,
-                                     xc_dtype, rc_dtype)
-            for bt in (2, 1) if T % bt == 0]
-    fits = [f for f in fits if f[2] <= _MRHS_FULLZ_VMEM_CAP]
-    if block_z in (None, Z) and fits:
+    fit = _mrhs_fullz_fit(T, Z, YX, dtype, out_dtype, R, block_z,
+                          xc_dtype, rc_dtype)
+    if fit:
         route, bz = "fullz", Z
-        bt, blocks, need = fits[0]
-        limit = max(need, int(omem.SCOPED_VMEM_MB * 2 ** 20))
-        omem.vmem_audit("QUDA_TPU_PALLAS_VMEM_MB", blocks, limit, bz=Z,
-                        route="fullz")
+        bt, blocks, need = fit
+        limit = _fullz_vmem_limit(blocks, need, Z)
     else:
         route, bt, limit = "zblock", 1, None
         bz = block_z if block_z is not None else _pick_bz(

@@ -141,7 +141,9 @@ def pytest_collection_modifyitems(config, items):
 # test_fused_iter.py, now under 50 s: theirs are from PR 45's whole
 # run, 966 s and 5,601 test-seconds, scaled to that run's; PR 46 added
 # ~35 s of batched-route cases to test_clover_resident.py and ~13 s of
-# described-chip compiles to test_chip_compile.py).  It
+# described-chip compiles to test_chip_compile.py; PR 47 ~65 s of
+# interpreted fused MRHS kernels to test_clover_pallas.py, which had
+# none in tier-1, and ~10 s to test_chip_compile.py).  It
 # only orders the hand-out: a stale or missing entry costs balance and
 # nothing else.
 FILE_SECONDS = {
@@ -149,7 +151,7 @@ FILE_SECONDS = {
     "test_multirhs_kernels.py": 400, "test_pallas.py": 360,
     "test_pair_mg.py": 360, "test_staggered_pallas.py": 360,
     "test_domain_wall.py": 270, "test_clover_resident.py": 280,
-    "test_chip_compile.py": 230, "test_precision_forms.py": 160,
+    "test_chip_compile.py": 240, "test_precision_forms.py": 160,
     "test_mixed.py": 210, "test_wilson_resident.py": 170,
     "test_interface.py": 170, "test_pair_gauge.py": 170,
     "test_twisted.py": 160, "test_serve.py": 150, "test_pair_eig.py": 130,
@@ -158,6 +160,7 @@ FILE_SECONDS = {
     "test_eig.py": 90, "test_mg_3level.py": 90, "test_milc_rhmc.py": 80,
     "test_mg_gemm_coarse.py": 80, "test_packed.py": 80,
     "test_pallas_sharded.py": 80, "test_clover.py": 80,
+    "test_clover_pallas.py": 80,
     "test_heatbath.py": 70, "test_build_accounting.py": 70,
     "test_schwarz.py": 70, "test_smear_force.py": 60,
     "test_parallel.py": 60, "test_solvers.py": 60,

@@ -152,6 +152,34 @@ def _clover_pc_k1():
             [_links(F32), _links(F32), _psi(F32), blk])
 
 
+def _clover_mrhs(stage, n=8, block_z=None, lat=L):
+    """A fused clover MRHS kernel as the shapes route it (PR 47): at
+    24^4 with the chiral blocks full-Z tiles, one time-slice a step,
+    three spinor operands (and ``xc`` for ``diag_hop``) and the
+    ``vmem_limit_bytes`` of ops/clover_pallas.mrhs_route (32.1 / 33.8
+    MiB); ``block_z = 8`` keeps the five-operand z-blocked fallback of
+    larger local volumes compiled for the chip."""
+    from quda_tpu.ops import clover_pallas as cp
+    dims, yxh = (lat,) * 4, lat * lat // 2
+    links = ((4, 3, 3, 2, lat, lat, yxh), F32)
+    psi = ((n, 4, 3, 2, lat, lat, yxh), F32)
+    blk = ((2, 6, 6, 2, lat, lat, yxh), F32)
+    u, p, b = (jax.ShapeDtypeStruct(*v) for v in (links, psi, blk))
+    route = cp.mrhs_route(u, p, p if stage == "diag_hop" else None, b, F32,
+                          block_z)
+    want = ("zblock", block_z, 1, None) if block_z else (
+        "fullz", lat, 1, {"post": 33619968, "diag_hop": 35389440}[stage])
+    assert route == want, route
+    if stage == "post":
+        return (lambda u, ub, p, b: cp.dslash_eo_pallas_post_mrhs(
+                    u, ub, p, dims, 1, blk_pl=b, block_z=block_z),
+                [links, links, psi, blk])
+    return (lambda u, ub, p, x, k, b: cp.dslash_eo_pallas_diag_hop_mrhs(
+                u, ub, p, x, dims, 0, hop_coeff=k, blk_pl=b,
+                block_z=block_z, out_dtype=F32),
+            [links, links, psi, psi, ((), F32), blk])
+
+
 def _dwf_ls8():
     from quda_tpu.ops import dwf_pallas as dp
     return (lambda u, ub, p: dp.dslash_eo_pallas_packed_ls(
@@ -216,6 +244,10 @@ CASES = {
     "staggered_eo_mrhs_n8_gather_odd": lambda: _staggered_eo_mrhs(
         "gather", 1),
     "clover_pc_k1": _clover_pc_k1,
+    "clover_mrhs_n8_post": lambda: _clover_mrhs("post"),
+    "clover_mrhs_n8_diag_hop": lambda: _clover_mrhs("diag_hop"),
+    "clover_mrhs_n8_diag_hop_zblock": lambda: _clover_mrhs(
+        "diag_hop", block_z=8),
     "dwf_eo_ls8": _dwf_ls8,
     "mobius_sblock_ls12_bf16": lambda: _mobius_sblock(BF16),
     "mobius_sblock_ls12_f32": lambda: _mobius_sblock(F32),
@@ -672,9 +704,13 @@ def test_clover_batched_programs_compile_for_v5e(one_chip, program):
     at 24^4, the served batch form) compile for the described chip on
     abstract operands: links, the blocks of both parities and A_q are
     parameters; the solve applies the two fused MRHS kernels twice an
-    iteration (``_pick_bz`` finds their z-block at YXh 288 with the 144
-    block planes and the centre operand resident); the entry folds Mdag
-    in (one more of each fused kernel behind the bare hop of prepare);
+    iteration, on the full-Z route since PR 47 (three spinor operands,
+    ``xc`` and the coefficient for ``diag_hop``, links, blocks LAST: six
+    and eight operands where the z-blocked calls had eight and ten; the
+    kernels alone, with their ``vmem_limit_bytes`` and the z-blocked
+    fallback, are cases of ``test_kernel_compiles_for_v5e``); the entry
+    folds Mdag in (one more of each fused kernel behind the bare hop of
+    prepare);
     the exit is two bare MRHS hops and XLA's block products, and what it
     holds with its arguments and results leaves the rest of the chip to
     the resident term and the caller's batch (ISSUE 46's rule on the
@@ -724,6 +760,14 @@ def test_clover_batched_programs_compile_for_v5e(one_chip, program):
     assert calls == {"prepare": sorted(fused + [bare]),
                      "solve": sorted(2 * fused),
                      "verified-exit": [bare, bare]}[program], calls
+    # the fused kernels took the full-Z route: three psi operands, not five
+    operands = {n: c.count("%") for n, c in re.findall(
+        r"%(dslash_eo_pallas_(?:post|diag_hop)_mrhs)[.\d]* = f32\[[^\n]*"
+        r"custom-call\(([^\n]*?)\), custom_call_target=\"tpu_custom_call\"",
+        hlo)}
+    assert operands == ({} if program == "verified-exit" else {
+        "dslash_eo_pallas_post_mrhs": 6,
+        "dslash_eo_pallas_diag_hop_mrhs": 8}), operands
     params = _hlo_values(hlo, "parameter")
     links = ",".join(str(d) for d in _links(F32)[0])
     blocks = ",".join(str(d) for d in (2, 6, 6, 2, L, L, YXH))

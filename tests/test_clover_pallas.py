@@ -225,3 +225,179 @@ def test_clover_blocks_in_hbm_ledger(cfg):
     # even site: 2 x 576 B/site x vol/2
     vol = 4 ** 4
     assert rows[("clover", "clover_pair_blocks")] == 2 * 576 * vol // 2
+
+
+# -- the fused MRHS calls' two routes (ops/clover_pallas.mrhs_route) --------
+#
+# PR 47.  Full-Z tiles (three psi operands, the epilogue per chunk of
+# the hop's loop) where links, chiral blocks, spinors and ``xc`` fit
+# the full-Z VMEM cap, z-blocks (the single-source call's five) where
+# they do not or ``block_z`` < Z asks; per source both are bitwise the
+# single-source kernel.  The single-source side is held to block_z = 8,
+# so it really splices rows of its z-neighbour tiles.
+
+_PROD = (4, 24, 24, 24)     # the cell's (Z, YXh) = (24, 288) tile: with
+                            # blocks, one time-slice a step, three chunks
+_SMALL = (4, 16, 2, 4)      # two f32 sublane tiles: two slices a step
+
+
+def _fused_problem(dims, nrhs, dtype, with_blk, seed=47):
+    T, Z, Y, X = dims
+    rng = np.random.default_rng(seed)
+
+    def draw(*shape):
+        return jnp.asarray(rng.standard_normal(shape + (T, Z, Y * X // 2)),
+                           jnp.float32).astype(dtype)
+    return (draw(4, 3, 3, 2), draw(4, 3, 3, 2), draw(nrhs, 4, 3, 2),
+            draw(nrhs, 4, 3, 2), draw(2, 6, 6, 2) if with_blk else None)
+
+
+def _fused_mrhs_case(stage, dims, parity, nrhs, dtype=jnp.float32,
+                     with_blk=True, zblock=True):
+    """fullz, zblock (``block_z`` = one sublane tile) and the vmapped
+    single-source kernel of one stage on one problem."""
+    from quda_tpu.ops import clover_pallas as cp
+    u, ub, psi, xc, blk = _fused_problem(dims, nrhs, dtype, with_blk)
+    bz = wpp._sublane_rows(dtype)
+    kw = dict(blk_pl=blk, interpret=True)
+    if stage == "post":
+        kw.update(twist=None if with_blk else (0.3, 0.9), out_dtype=dtype)
+        mrhs = lambda **k: cp.dslash_eo_pallas_post_mrhs(
+            u, ub, psi, dims, parity, **kw, **k)
+        single = jax.vmap(lambda p: cp.dslash_eo_pallas_post(
+            u, ub, p, dims, parity, block_z=bz, **kw))(psi)
+    else:
+        kw.update(diag_twist=None if with_blk else 0.3, hop_coeff=-0.0144,
+                  out_dtype=jnp.float32)
+        mrhs = lambda **k: cp.dslash_eo_pallas_diag_hop_mrhs(
+            u, ub, psi, xc, dims, parity, **kw, **k)
+        single = jax.vmap(lambda p, x: cp.dslash_eo_pallas_diag_hop(
+            u, ub, p, x, dims, parity, block_z=bz, **kw))(psi, xc)
+    route = cp.mrhs_route(u, psi, None if stage == "post" else xc, blk,
+                          kw["out_dtype"])[0]
+    return route, mrhs(), mrhs(block_z=bz) if zblock else None, single
+
+
+@pytest.mark.parametrize("stage,dims,parity,nrhs,dtype,with_blk,zblock", [
+    # tier-1: the cell's tile with one slice a step against both, and
+    # two slices a step with eight sources against the single kernel
+    ("diag_hop", _PROD, 0, 2, jnp.float32, True, True),
+    ("post", _SMALL, 1, 8, jnp.float32, True, False),
+    pytest.param("post", _PROD, 1, 2, jnp.float32, True, True,
+                 marks=pytest.mark.slow),
+    pytest.param("post", _PROD, 0, 8, jnp.float32, True, True,
+                 marks=pytest.mark.slow),
+    pytest.param("diag_hop", _PROD, 1, 8, jnp.float32, True, True,
+                 marks=pytest.mark.slow),
+    pytest.param("diag_hop", _SMALL, 1, 2, jnp.float32, True, True,
+                 marks=pytest.mark.slow),
+    pytest.param("post", _SMALL, 0, 2, jnp.float32, True, True,
+                 marks=pytest.mark.slow),
+    pytest.param("diag_hop", _SMALL, 0, 8, jnp.float32, True, True,
+                 marks=pytest.mark.slow),
+    # bf16 storage (two 16-row tiles) and the twist-only shapes of the
+    # twisted-mass operator (no blocks: the Wilson batch's VMEM sums)
+    pytest.param("post", (4, 32, 2, 4), 0, 2, jnp.bfloat16, True, True,
+                 marks=pytest.mark.slow),
+    pytest.param("diag_hop", (4, 32, 2, 4), 1, 2, jnp.bfloat16, True, True,
+                 marks=pytest.mark.slow),
+    pytest.param("post", _SMALL, 1, 2, jnp.float32, False, True,
+                 marks=pytest.mark.slow),
+    pytest.param("diag_hop", _SMALL, 0, 2, jnp.float32, False, True,
+                 marks=pytest.mark.slow)])
+def test_fused_mrhs_fullz_bitmatches_zblock_and_single_source(
+        stage, dims, parity, nrhs, dtype, with_blk, zblock):
+    route, fullz, zb, single = _fused_mrhs_case(
+        stage, dims, parity, nrhs, dtype, with_blk, zblock)
+    assert route == "fullz"
+    assert fullz.dtype == (dtype if stage == "post" else jnp.float32)
+    assert bool(jnp.all(fullz == single))
+    assert zb is None or bool(jnp.all(fullz == zb))
+
+
+_MIB = 2 ** 20
+
+
+@pytest.mark.parametrize("case,want", [
+    # (T, Z, YXh, dtype, out dtype, R, block_z, block dtype, xc dtype)
+    # 24^4 f32 with the chiral blocks: one slice a step, 32.1 MiB for
+    # post and 33.8 with xc for diag_hop (two would need 55.7 / 59.1)
+    ((24, 24, 288, jnp.float32, jnp.float32, 3, None, jnp.float32, None),
+     ("fullz", 24, 1, 33619968)),
+    ((24, 24, 288, jnp.float32, jnp.float32, 3, None, jnp.float32,
+      jnp.float32), ("fullz", 24, 1, 35389440)),
+    ((24, 24, 288, jnp.float32, jnp.float32, 3, 24, jnp.float32,
+      jnp.float32), ("fullz", 24, 1, 35389440)),
+    # bf16 storage and the twist-only calls hold two slices
+    ((24, 24, 288, jnp.bfloat16, jnp.float32, 3, None, jnp.bfloat16,
+      jnp.bfloat16), ("fullz", 24, 2, None)),
+    ((24, 24, 288, jnp.float32, jnp.float32, 3, None, None, jnp.float32),
+     ("fullz", 24, 2, None)),
+    # a caller's z-block, and local volumes whose full-Z tiles pass the
+    # cap: twist-only, 32^4 still holds one slice and Z = 40 finds a
+    # z-block; with the 144 block planes no z-block fits _pick_bz's
+    # budget either (as on the parent)
+    ((24, 24, 288, jnp.float32, jnp.float32, 3, 8, jnp.float32,
+      jnp.float32), ("zblock", 8, 1, None)),
+    ((32, 32, 512, jnp.float32, jnp.float32, 3, None, None, None),
+     ("fullz", 32, 1, None)),
+    ((40, 40, 640, jnp.float32, jnp.float32, 3, None, None, None),
+     ("zblock", 8, 1, None)),
+    ((32, 32, 512, jnp.float32, jnp.float32, 3, None, jnp.float32,
+      jnp.float32), ValueError),
+    ((40, 40, 640, jnp.float32, jnp.float32, 3, None, jnp.float32, None),
+     ValueError),
+    # the shapes the interpreted cases above run on
+    ((4, 24, 288, jnp.float32, jnp.float32, 3, None, jnp.float32,
+      jnp.float32), ("fullz", 24, 1, 35389440)),
+    ((4, 16, 4, jnp.float32, jnp.float32, 3, None, jnp.float32, None),
+     ("fullz", 16, 2, None)),
+    ((4, 32, 4, jnp.bfloat16, jnp.bfloat16, 3, None, jnp.bfloat16, None),
+     ("fullz", 32, 2, None)),
+    ((4, 16, 4, jnp.float32, jnp.float32, 3, 8, jnp.float32, None),
+     ("zblock", 8, 1, None))])
+def test_fused_mrhs_route_follows_the_shapes(case, want):
+    """The fused MRHS call's route is the Wilson batch's arithmetic with
+    the epilogue's blocks in the sums (144 planes of chiral blocks, 24
+    of ``xc``, a slice, double-buffered like the rest): no kernel runs
+    here.  models/wilson labels its counter with the same call."""
+    from quda_tpu.obs import memory as omem
+    from quda_tpu.ops import clover_pallas as cp
+    T, Z, YXh, dt, odt, R, block_z, blk_dt, xc_dt = case
+    S = jax.ShapeDtypeStruct
+    extra = [(n, d) for n, d in ((144, blk_dt), (24, xc_dt))
+             if d is not None]
+    operands = (
+        S((4, R, 3, 2, T, Z, YXh), dt), S((8, 4, 3, 2, T, Z, YXh), dt),
+        None if xc_dt is None else S((8, 4, 3, 2, T, Z, YXh), xc_dt),
+        None if blk_dt is None else S((2, 6, 6, 2, T, Z, YXh), blk_dt),
+        odt, block_z)
+    omem.reset()
+    if want is ValueError:
+        assert wpp._mrhs_fullz_fit(T, Z, YXh, dt, odt, R, block_z,
+                                   extra=extra) is None
+        with pytest.raises(ValueError, match="fits the VMEM budget"):
+            cp.mrhs_route(*operands)
+        return
+    route, bz, bt, limit = cp.mrhs_route(*operands)
+    assert (route, bz, bt) == want[:3]
+    rows = {r["knob"]: r for r in omem.audit_vmem_budgets()}
+    if route == "zblock":
+        assert limit is None
+        assert "QUDA_TPU_PALLAS_VMEM_MB[fullz]" not in rows
+        return
+    blocks, need = wpp._mrhs_fullz_vmem(Z, YXh, dt, odt, R, bt, extra=extra)
+    assert limit == max(need, 16 * _MIB) <= wpp._MRHS_FULLZ_VMEM_CAP
+    assert want[3] is None or limit == want[3]
+    # bt tiles of every block the epilogue brings, padded like the rest
+    def plane(d):
+        sub = wpp._sublane_rows(d)
+        return -(-Z // sub) * sub * -(-YXh // 128) * 128 \
+            * jnp.dtype(d).itemsize
+    assert blocks - wpp._mrhs_fullz_vmem(Z, YXh, dt, odt, R, bt)[0] == bt * (
+        sum(n * plane(d) for n, d in extra))
+    if bt == 1 and T % 2 == 0:
+        assert wpp._mrhs_fullz_vmem(Z, YXh, dt, odt, R, 2, extra=extra)[1] \
+            > wpp._MRHS_FULLZ_VMEM_CAP
+    row = rows["QUDA_TPU_PALLAS_VMEM_MB[fullz]"]
+    assert row["last_bz"] == Z and row["last_block_bytes"] == blocks

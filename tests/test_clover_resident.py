@@ -391,7 +391,8 @@ def _program_counts():
 
 
 def _route_counts():
-    return {(dict(l)["form"], dict(l)["stage"]): int(v)
+    """{(form, stage, route): n} of clover_mrhs_route_total."""
+    return {tuple(dict(l)[k] for k in ("form", "stage", "route")): int(v)
             for (n, l), v in omet.snapshot()["counters"].items()
             if n == "clover_mrhs_route_total"}
 
@@ -424,7 +425,8 @@ def test_batch_first_call_builds_three_programs_on_the_resident_term(
     assert w["traced"] == 3
     # the staged form off the chip; an M counts both stages where it is
     # traced: Mdag of the entry, M and Mdag of the loop
-    assert w["routes"] == {("xla", "post"): 3, ("xla", "diag_hop"): 3}
+    assert w["routes"] == {("xla", "post", "none"): 3,
+                           ("xla", "diag_hop", "none"): 3}
     p = w["param"]
     assert all(p.converged_multi) and len(p.true_res_multi) == N_SRC
     for i in range(N_SRC):
@@ -459,6 +461,46 @@ def test_batch_second_call_hits_and_another_csw_only_rebuilds_the_term(
     for i in range(N_SRC):
         assert _host_residual(gauges["A"], B[i], x[i], 0.11, 1.3) < 5e-6
         assert _host_residual(gauges["A"], B[i], x[i]) > 1e-3
+
+
+def test_batch_solve_program_in_the_fused_form_counts_the_fullz_route(
+        batch_warm, monkeypatch):
+    """The batched solve program on the resident operator in the fused
+    form (PR 47): traced, not compiled (an interpreted kernel costs ~20
+    s a module and proves nothing about a counter).  Each fused MRHS
+    call is counted where ``_M_sign_pairs_mrhs`` traces it, once a
+    stage for the loop's M and once for its Mdag, by the route the call
+    takes from its shapes: ``fullz`` at the test lattice as at 24^4;
+    the same program asked for again is the traced one and counts
+    nothing."""
+    from quda_tpu.solvers import program as sprog
+    from quda_tpu.solvers.fused_iter import _resolve_check_every
+    monkeypatch.setenv("QUDA_TPU_PALLAS", "1")
+    monkeypatch.setenv("QUDA_TPU_CLOVER_FORM", "pallas")
+    qconf.reset_cache()
+    try:
+        api.load_clover_quda(_param(cuda_prec_sloppy="single"))
+        op = api._ctx["clover"]["ops"][jnp.dtype(jnp.float32)]
+        assert op._mrhs_form() == "pallas"
+        x = jax.ShapeDtypeStruct((N_SRC, 4, 3, 2, L, L, L * L // 2),
+                                 jnp.float32)
+        key = (_resolve_check_every(None), sprog._loop_knobs(False, 100),
+               False)
+        trace = lambda: sprog._batched_cg_pairs_program.trace(
+            op, x, 1e-6, 100, key=key)
+        before = _route_counts()
+        trace()
+        first = _route_counts()
+        assert {k: v - before.get(k, 0) for k, v in first.items()
+                if v != before.get(k, 0)} == {
+            ("pallas", "post", "fullz"): 2,
+            ("pallas", "diag_hop", "fullz"): 2}
+        trace()
+        assert _route_counts() == first
+    finally:
+        monkeypatch.undo()
+        qconf.reset_cache()
+        api.load_clover_quda(_param())
 
 
 def test_batch_equals_single_source_solves_on_the_same_term(batch_warm):

@@ -128,13 +128,16 @@ def test_mrhs_models_amortize_with_nrhs():
     MRHS kernel's full-Z route reads psi twice where the single-RHS
     kernel reads it five times: 1152 - 3 x 96; the improved staggered
     batch serves the scatter pass, three psi reads where the gather
-    pass reads five: 1512 - 2 x 48)."""
+    pass reads five: 1512 - 2 x 48; the fused clover batch's full-Z
+    route holds one slice a step beside its block planes and reads psi
+    three times, 1728 - 2 x 96, the twisted-mass batch without blocks
+    two slices like the Wilson batch, 1152 - 3 x 96: PR 47)."""
     for form, n1 in (("staggered_mrhs", 1416.0),
                      ("staggered_fat_mrhs", 720.0),
                      ("wilson_mrhs", 864.0),
-                     ("clover_pallas_mrhs", 1728.0),
-                     ("twisted_mass_pallas_mrhs", 1152.0),
-                     ("twisted_clover_pallas_mrhs", 1728.0)):
+                     ("clover_pallas_mrhs", 1536.0),
+                     ("twisted_mass_pallas_mrhs", 864.0),
+                     ("twisted_clover_pallas_mrhs", 1536.0)):
         bps = orf.KERNEL_MODELS[form]["bytes_per_site"]
         assert callable(bps)
         assert bps(1) == n1
