@@ -959,40 +959,56 @@ class _SchurPairOpBase(_PackedHopMixin, _PairSloppyBase):
             return "xla"
         return self._MRHS_FORM or "pallas"
 
-    def _M_sign_pairs_mrhs(self, x, sign, form=None):
+    def _count_mrhs(self, form, stage, route="none", epilogue="none"):
+        """clover_mrhs_route_total, counted where a batched ``M`` is
+        traced (as wilson_mrhs_route_total), by the route each fused
+        call takes from its shapes and by its epilogue: ``none`` on
+        ``post``; on ``diag_hop`` ``combine`` (A x - kappa^2 D t),
+        ``norm2`` (gamma5 and the sums of squares besides) or
+        ``residual`` (r - alpha g5 of that, summed)."""
         from ..obs import metrics as omet
+        omet.inc("clover_mrhs_route_total", form=form, stage=stage,
+                 route=route, epilogue=epilogue)
+
+    def _M_sign_fused_mrhs(self, x, sign, **epilogue):
+        """``M(sign) x`` by the two fused MRHS kernels, f32 out.
+        ``epilogue``: ``g5``, ``nrm``, ``rc`` / ``alpha`` of
+        ops/clover_pallas.dslash_eo_pallas_diag_hop_mrhs, the second
+        kernel; with ``nrm`` or ``rc`` the result is the pair (batch,
+        its squared norms per source)."""
+        from ..ops import clover_pallas as clp
+        p = self.matpc
+        k1_blk, k1_twist = self._fused_k1_params(sign)
+        k2_blk, k2_twist = self._fused_k2_params(sign)
+        dims = tuple(self.dims)
+        itp = self._pallas_interpret
+        bz = getattr(self, "_block_z", None)
+        u_q, u_p = self.gauge_eo_pp[1 - p], self.gauge_eo_pp[p]
+        form, route = clp.mrhs_form(u_q, x, None, k1_blk, self.store_dtype,
+                                    bz)
+        self._count_mrhs("pallas", "post", route[0], form)
+        t = clp.dslash_eo_pallas_post_mrhs(
+            u_q, self._u_bw[1 - p], x, dims,
+            1 - p, blk_pl=k1_blk, twist=k1_twist, interpret=itp,
+            block_z=bz, out_dtype=self.store_dtype,
+            tb_sign=self._tb_sign)
+        form, route = clp.mrhs_form(
+            u_p, t, x, k2_blk, jnp.float32, bz, epilogue.get("nrm", False),
+            epilogue.get("rc"))
+        self._count_mrhs("pallas", "diag_hop", route[0], form)
+        return clp.dslash_eo_pallas_diag_hop_mrhs(
+            u_p, self._u_bw[p], t, x, dims, p,
+            hop_coeff=-(self.kappa ** 2), blk_pl=k2_blk,
+            diag_twist=k2_twist, interpret=itp, block_z=bz,
+            out_dtype=jnp.float32, tb_sign=self._tb_sign, **epilogue)
+
+    def _M_sign_pairs_mrhs(self, x, sign, form=None):
         p = self.matpc
         form = form or self._mrhs_form()
-        # counted where it is traced, as wilson_mrhs_route_total, and by
-        # the route each fused call takes from its shapes
-        def count(stage, route="none"):
-            omet.inc("clover_mrhs_route_total", form=form, stage=stage,
-                     route=route)
         if form == "pallas":
-            from ..ops import clover_pallas as clp
-            k1_blk, k1_twist = self._fused_k1_params(sign)
-            k2_blk, k2_twist = self._fused_k2_params(sign)
-            dims = tuple(self.dims)
-            itp = self._pallas_interpret
-            bz = getattr(self, "_block_z", None)
-            u_q, u_p = self.gauge_eo_pp[1 - p], self.gauge_eo_pp[p]
-            count("post", clp.mrhs_route(u_q, x, None, k1_blk,
-                                         self.store_dtype, bz)[0])
-            t = clp.dslash_eo_pallas_post_mrhs(
-                u_q, self._u_bw[1 - p], x, dims,
-                1 - p, blk_pl=k1_blk, twist=k1_twist, interpret=itp,
-                block_z=bz, out_dtype=self.store_dtype,
-                tb_sign=self._tb_sign)
-            count("diag_hop", clp.mrhs_route(u_p, t, x, k2_blk,
-                                             jnp.float32, bz)[0])
-            out = clp.dslash_eo_pallas_diag_hop_mrhs(
-                u_p, self._u_bw[p], t, x, dims, p,
-                hop_coeff=-(self.kappa ** 2), blk_pl=k2_blk,
-                diag_twist=k2_twist, interpret=itp, block_z=bz,
-                out_dtype=jnp.float32, tb_sign=self._tb_sign)
-            return out.astype(self.store_dtype)
-        count("post")
-        count("diag_hop")
+            return self._M_sign_fused_mrhs(x, sign).astype(self.store_dtype)
+        self._count_mrhs(form, "post")
+        self._count_mrhs(form, "diag_hop", epilogue="combine")
         t = self._d_to_mrhs(x, 1 - p, self.store_dtype)
         t = self._Ainv_q_sign_pairs_mrhs(t, sign, self.store_dtype)
         dd = self._d_to_mrhs(t, p, jnp.float32)
@@ -1009,6 +1025,35 @@ class _SchurPairOpBase(_PackedHopMixin, _PairSloppyBase):
 
     def MdagM_pairs_mrhs(self, x):
         return self.Mdag_pairs_mrhs(self.M_pairs_mrhs(x))
+
+    def MdagM_cg_step_pairs_mrhs(self, p, r, rz, k=None):
+        """The first half of a batched CG iteration on MdagM, what
+        solvers/block.batched_cg_pairs_loop applies: from the search
+        directions ``p``, the residuals ``r`` and their ``|r|^2``
+        ``rz`` to ``(r - alpha MdagM p, its squared norms per source,
+        alpha, pAp)``, taken from the fused kernels' epilogue where the
+        batch is served by them in f32.  MdagM is g5 M(-s) g5 M(+s) and
+        ``Mdag`` is ``M``'s adjoint for either twist sign, so ``p .
+        MdagM p = |q|^2`` with ``q = g5 M(+s) p``, which the K2 kernel
+        that stores ``q`` sums as it stores (the ``norm2`` form):
+        ``alpha`` is known before the second ``M``, whose K2 kernel
+        then writes ``r - alpha g5 M(-s) q`` in ``r``'s place and sums
+        that (the ``residual`` form).  ``MdagM p`` and the un-signed
+        twin of ``q`` never reach HBM, and no XLA pass over the batch
+        makes a gamma5, ``pAp``, the update of ``r`` or ``|r|^2``.
+        ``k``, the iteration, is the generic step's (an armed fault).
+        Everywhere else (the ``xla`` form, sloppy storage):
+        solvers/block.cg_step of ``MdagM_pairs_mrhs``, XLA's dot,
+        update and sum."""
+        from ..solvers import block
+        f32 = jnp.dtype(jnp.float32)
+        if (self._mrhs_form() != "pallas" or r.dtype != f32
+                or jnp.dtype(self.store_dtype) != f32):
+            return block.cg_step(self.MdagM_pairs_mrhs)(p, r, rz, k)
+        q, pAp = self._M_sign_fused_mrhs(p, +1, g5=True, nrm=True)
+        alpha = block.cg_alpha(rz, pAp)
+        r, r2 = self._M_sign_fused_mrhs(q, -1, g5=True, rc=r, alpha=alpha)
+        return r, r2, alpha, pAp
 
     def prepare_pairs_mrhs(self, b_even_b, b_odd_b):
         """Batched prepare: b_p + kappa D Ainv_q b_q with the MRHS hop

@@ -280,8 +280,9 @@ METRICS: dict[str, dict] = {
     "clover_mrhs_route_total": {
         "type": COUNTER,
         "help": "traced applications of a Schur pair operator to a batch "
-                "(models/wilson._SchurPairOpBase._M_sign_pairs_mrhs: "
-                "clover, and the twisted families on the same template) "
+                "(models/wilson._SchurPairOpBase._M_sign_pairs_mrhs and "
+                "the two M of its MdagM_cg_step_pairs_mrhs: clover, and "
+                "the twisted families on the same template) "
                 "by form: 'pallas' the fused MRHS kernels of "
                 "ops/clover_pallas (links AND blocks read once for all "
                 "sources), 'xla' the bare MRHS Wilson hop and XLA's "
@@ -296,7 +297,16 @@ METRICS: dict[str, dict] = {
                 "(24^4 f32: one time-slice a step), 'zblock' the "
                 "single-source call's z-blocks and five psi operands "
                 "(larger local volumes, a caller's block_z), 'none' the "
-                "'xla' form"},
+                "'xla' form; and by epilogue, what the call's store "
+                "does: 'none' on 'post'; on 'diag_hop' 'combine' (A x - "
+                "kappa^2 D t), 'norm2' (gamma5 in the store and its "
+                "squares summed per source: the batched CG's pAp = "
+                "|g5 M p|^2) or 'residual' (r - alpha g5 of that written "
+                "over r and summed: the new r and |r|^2).  One solve "
+                "program on the fused form counts post 2, norm2 1, "
+                "residual 1; with a dslash fault armed, or in the 'xla' "
+                "form, the loop takes solvers/block.cg_step and every "
+                "diag_hop is 'combine'"},
     "multishift_shift_total": {
         "type": COUNTER,
         "help": "shifts of invert_multishift_quda calls on the resident "

@@ -46,7 +46,21 @@ batch does (``mrhs_route``, PR 47): whole-Z tiles of ``bt``
 time-slices and three psi operands a step, the epilogue per chunk of
 the hop body's loop (``fullz``: the cell's 24^4 with one slice a step
 beside the 144 block planes), or the single-source call's z-blocks and
-five psi operands where those tiles do not fit (``zblock``).  The full-lattice
+five psi operands where those tiles do not fit (``zblock``).
+
+The MRHS K2 call has four forms under its one name
+(``dslash_eo_pallas_diag_hop_mrhs``; call-time keywords, off by
+default, links then blocks still the last operands), counted by
+``clover_mrhs_route_total{epilogue}``: ``combine``, the value above;
+``norm2``, gamma5 of it in the store (``g5``) and the squares of what
+is stored summed per source into a second, small f32 result (``nrm``);
+``residual``, ``rc - alpha[n] * g5`` of it written over ``rc`` and
+summed (``rc``, ``alpha``).  With them the first half of a batched CG
+iteration on ``MdagM = g5 M(-s) g5 M(+s)`` is four kernels and no XLA
+pass over the batch (models/wilson
+``_SchurPairOpBase.MdagM_cg_step_pairs_mrhs``, PR 48): ``pAp = |g5 M
+p|^2`` comes out of the first M's K2 call, the new ``r`` and ``|r|^2``
+out of the second's.  The full-lattice
 ``clover_pallas_packed`` serves the unpreconditioned M = A - kappa D
 with the diagonal read from the center psi tile itself (no extra
 operand at all).
@@ -121,8 +135,26 @@ def _scale_sc(vals, k):
     return [[(k * v[0], k * v[1]) for v in row] for row in vals]
 
 
+def _sub_sc(a, b):
+    return [[wpp._csub(a[s][c], b[s][c]) for c in range(3)]
+            for s in range(4)]
+
+
+def _sum_sq_sc(vals, dtype):
+    """One f32 tile: the sum over the 24 planes of their squares as
+    ``dtype`` stores them."""
+    sq = None
+    for row in vals:
+        for v in row:
+            for w in v:
+                w = w.astype(dtype).astype(F32)
+                sq = w * w if sq is None else sq + w * w
+    return sq
+
+
 def _epilogue_kernel(X, bz, eo, T, tb_sign, *, xc_mode, with_blk,
-                     twist, diag_twist, with_coeff, z_rows="tiles"):
+                     twist, diag_twist, with_coeff, z_rows="tiles",
+                     g5=False, nrm=False, residual=False):
     """v2 hop kernel + family epilogue over the out tile.
 
     z_rows: ``"tiles"``, the five psi refs of a (t, z-block) step and
@@ -143,7 +175,22 @@ def _epilogue_kernel(X, bz, eo, T, tb_sign, *, xc_mode, with_blk,
     spinor refs (links, then blocks, stay the last inputs: the
     benchmark's trace reduction names a kernel event by the element
     types of its result, first and LAST operand).
+    g5, nrm, residual (K2 of an MRHS call only, the batched CG's:
+    models/wilson._SchurPairOpBase.MdagM_cg_step_pairs_mrhs): ``g5``
+    negates spin rows 2, 3 of v = diag(x) + hop_coeff * hop before the
+    store (a sign: bit-exact against a gamma5 pass over the stored
+    values); ``residual`` brings ``rc`` (a spinor block on the centre
+    spec, after ``xc``) and ``alpha`` (one f32 a source in SMEM, after
+    ``hop_coeff``; the source is the grid's axis 2, read outside the
+    chunk loop) and the store writes ``rc - alpha[n] * [g5] v``;
+    ``nrm`` gives the kernel a second, small f32 output after the
+    spinor's, one (BZ, YXh) block a grid step, zeroed here: the sum
+    over the step's planes (and chunks) of the squares of what it
+    stores, after the rounding to the out dtype, which the caller sums
+    per source (wilson_pallas_packed._make_kernel's combine epilogue).
     """
+    from jax.experimental import pallas as pl
+
     base = wpp._make_kernel(X, bz, eo=eo, T=T, tb_sign=tb_sign,
                             z_rows=z_rows)
     n_psi = 3 if z_rows == "centre" else 5
@@ -156,15 +203,25 @@ def _epilogue_kernel(X, bz, eo, T, tb_sign, *, xc_mode, with_blk,
             k += 1
         elif xc_mode == "center":
             xc_ref = refs[0]
+        rc_ref = None
+        if residual:
+            rc_ref = refs[k]
+            k += 1
         coeff_ref = None
         if with_coeff:
             coeff_ref = refs[k]
             k += 1
+        alpha = None
+        if residual:
+            alpha = refs[k][pl.program_id(2)]
+            k += 1
         g_c, g_m = refs[k], refs[k + 1]
         blk_ref = refs[k + 2] if with_blk else None
-        out_ref = refs[-1]
+        out_ref, nrm_ref = (refs[-2], refs[-1]) if nrm else (refs[-1], None)
+        if nrm:
+            nrm_ref[...] = jnp.zeros(nrm_ref.shape, F32)
 
-        def epilogue(out_ref, xc_ref, blk_ref):
+        def epilogue(out_ref, xc_ref, blk_ref, rc_ref=None):
             hop = _load_sc(out_ref)
             if not with_coeff:
                 v = _blk_mul(blk_ref, hop) if with_blk else hop
@@ -179,7 +236,14 @@ def _epilogue_kernel(X, bz, eo, T, tb_sign, *, xc_mode, with_blk,
                 if diag_twist is not None:
                     d = _add_sc(d, _ig5_rot(x, diag_twist))
                 v = _add_sc(d, _scale_sc(hop, coeff_ref[0]))
+                if g5:
+                    v = v[:2] + [[(-re, -im) for re, im in row]
+                                 for row in v[2:]]
+                if residual:
+                    v = _sub_sc(_load_sc(rc_ref), _scale_sc(v, alpha))
             _store_sc(out_ref, v)
+            if nrm:
+                nrm_ref[...] += _sum_sq_sc(v, out_ref.dtype)
 
         # the unchanged v2 hop body writes its accumulator to the out
         # tile (VMEM); the epilogue reads it straight back — for the
@@ -187,18 +251,19 @@ def _epilogue_kernel(X, bz, eo, T, tb_sign, *, xc_mode, with_blk,
         # staged rounding of the XLA composition it replaces
         if z_rows == "centre":
             base(*refs[:3], g_c, g_m, out_ref,
-                 epilogue=(epilogue, (xc_ref, blk_ref)))
+                 epilogue=(epilogue, (xc_ref, blk_ref, rc_ref)))
         else:
             base(*refs[:5], g_c, g_m, out_ref)
-            epilogue(out_ref, xc_ref, blk_ref)
+            epilogue(out_ref, xc_ref, blk_ref, rc_ref)
 
     return kernel
 
 
-def _planes(R: int, xc_mode, with_blk: bool) -> int:
+def _planes(R: int, xc_mode, with_blk: bool, with_rc: bool = False) -> int:
     return ((288 if R == 3 else 240)
             + (_BLK_PLANES if with_blk else 0)
-            + (_XC_PLANES if xc_mode == "input" else 0))
+            + (_XC_PLANES if xc_mode == "input" else 0)
+            + (_XC_PLANES if with_rc else 0))
 
 
 def _coeff_operand(hop_coeff):
@@ -206,14 +271,17 @@ def _coeff_operand(hop_coeff):
     return jnp.asarray(hop_coeff, F32).reshape(1)
 
 
-def mrhs_route(u_pl, psi_pl, xc_pl, blk_pl, out_dtype=None, block_z=None):
+def mrhs_route(u_pl, psi_pl, xc_pl, blk_pl, out_dtype=None, block_z=None,
+               rc_pl=None):
     """(route, bz, bt, vmem_limit_bytes) of a fused MRHS call on these
     operands (arrays or abstract values), from their shapes:
     wilson_pallas_packed._mrhs_route's rule with the epilogue's blocks
     in the sums.  ``"fullz"`` where ``_mrhs_fullz_fit`` finds room
     (24^4 f32 with the chiral blocks: one time-slice a step, 32.1 MiB
-    for ``post``, 33.8 with ``xc`` for ``diag_hop``; two slices would
-    need 55.7 / 59.1 of the 48 the route may ask for), the blocks and
+    for ``post``, 33.8 with ``xc`` for ``diag_hop``, 35.5 with the
+    residual form's ``rc`` besides; two slices would need 55.7 / 59.1
+    of the 48 the route may ask for; the epilogue's 12 KiB of sums are
+    inside the body's allowance), the blocks and
     the limit filed with the VMEM audit; ``"zblock"`` (``_pick_bz``'s
     z-block, or the caller's) where it does not or ``block_z`` < Z
     asks for z-blocks.  models/wilson labels
@@ -223,6 +291,7 @@ def mrhs_route(u_pl, psi_pl, xc_pl, blk_pl, out_dtype=None, block_z=None):
     R = u_pl.shape[1]
     fit = wpp._mrhs_fullz_fit(
         T, Z, YXh, psi_pl.dtype, out_dtype or psi_pl.dtype, R, block_z,
+        rc_dtype=None if rc_pl is None else rc_pl.dtype,
         extra=[(n, v.dtype) for n, v in ((_BLK_PLANES, blk_pl),
                                          (_XC_PLANES, xc_pl))
                if v is not None])
@@ -231,16 +300,38 @@ def mrhs_route(u_pl, psi_pl, xc_pl, blk_pl, out_dtype=None, block_z=None):
         return "fullz", Z, bt, wpp._fullz_vmem_limit(blocks, need, Z)
     bz = block_z if block_z is not None else wpp._pick_bz(
         Z, YXh, psi_pl.dtype, planes=_planes(
-            R, None if xc_pl is None else "input", blk_pl is not None))
+            R, None if xc_pl is None else "input", blk_pl is not None,
+            rc_pl is not None))
     if Z % bz != 0:
         raise ValueError(f"block_z={bz} does not divide Z={Z}")
     return "zblock", bz, 1, None
 
 
+def mrhs_form(u_pl, psi_pl, xc_pl, blk_pl, out_dtype=None, block_z=None,
+              nrm=False, rc_pl=None):
+    """(epilogue, ``mrhs_route``'s tuple) of a fused MRHS call on these
+    operands: what its store does, by the name
+    ``clover_mrhs_route_total`` counts it under, and the route it
+    takes.  ``none`` for K1 (no ``xc_pl``); for K2 ``combine``,
+    ``norm2`` (``nrm``) or ``residual`` (``rc_pl``), and ``norm2`` too
+    where no route holds the ``rc`` block beside ``xc`` and the chiral
+    blocks: the call is then that form and XLA makes the update
+    (``dslash_eo_pallas_diag_hop_mrhs``)."""
+    if rc_pl is not None:
+        try:
+            return "residual", mrhs_route(u_pl, psi_pl, xc_pl, blk_pl,
+                                          out_dtype, block_z, rc_pl)
+        except ValueError:
+            nrm = True
+    return ("none" if xc_pl is None else "norm2" if nrm else "combine",
+            mrhs_route(u_pl, psi_pl, xc_pl, blk_pl, out_dtype, block_z))
+
+
 def _fused_eo_call(u_here_pl, u_bw_pl, psi_pl, xc_pl, blk_pl, coeff, dims,
                    target_parity, *, name, mrhs=False, twist=None,
                    diag_twist=None, interpret=False, block_z=None,
-                   out_dtype=None, tb_sign=True):
+                   out_dtype=None, tb_sign=True, g5=False, nrm=False,
+                   rc_pl=None, alpha=None):
     """The one pallas_call behind the four eo entry points.  ``coeff``
     (a (1,) f32 array) makes it the K2 stage; ``mrhs`` gives every
     spinor operand a leading RHS axis, streamed innermost: gauge AND
@@ -260,7 +351,16 @@ def _fused_eo_call(u_here_pl, u_bw_pl, psi_pl, xc_pl, blk_pl, coeff, dims,
     single-source call with the RHS axis: five psi operands a (t,
     z-block) step, where full-Z does not fit or ``block_z`` < Z asks
     for it.  Per source the two bit-match each other and the
-    single-source kernel."""
+    single-source kernel.
+
+    ``g5``, ``nrm``, ``rc_pl`` / ``alpha`` (the K2 stage of an MRHS
+    call): ``_epilogue_kernel``'s gamma5 store, sums of squares and
+    residual form.  With ``nrm`` or ``rc_pl`` the call returns ``(v,
+    |v|^2 per source)``, v what it wrote: the kernel's second output
+    holds one block of f32 partial sums a grid step, (T/bt, Z/bz, N,
+    rows, YXh), and XLA sums those few KB a source to (N,) f32.  The
+    residual form writes over ``rc_pl``'s own buffer (each step reads
+    and writes the same centre block of it) where the types agree."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -271,9 +371,22 @@ def _fused_eo_call(u_here_pl, u_bw_pl, psi_pl, xc_pl, blk_pl, coeff, dims,
     with_blk = blk_pl is not None
     xc_mode = "input" if xc_pl is not None else None
     odt = out_dtype or psi_pl.dtype
+    residual = rc_pl is not None
+    nrm = nrm or residual
     if mrhs:
-        route, bz, bt, vmem_limit = mrhs_route(
-            u_here_pl, psi_pl, xc_pl, blk_pl, odt, block_z)
+        form, (route, bz, bt, vmem_limit) = mrhs_form(
+            u_here_pl, psi_pl, xc_pl, blk_pl, odt, block_z, nrm, rc_pl)
+        if residual and form != "residual":
+            # no route holds the rc block: XLA's update and sum
+            v, _ = _fused_eo_call(
+                u_here_pl, u_bw_pl, psi_pl, xc_pl, blk_pl, coeff, dims,
+                target_parity, name=name, mrhs=True, diag_twist=diag_twist,
+                interpret=interpret, block_z=block_z, out_dtype=F32,
+                tb_sign=tb_sign, g5=g5, nrm=True)
+            v = (rc_pl.astype(F32)
+                 - alpha.reshape((-1,) + (1,) * 6) * v).astype(odt)
+            w = v.astype(F32)
+            return v, jnp.sum((w * w).reshape(w.shape[0], -1), axis=1)
     else:
         route, bt, vmem_limit = "zblock", 1, None
         bz = block_z if block_z is not None else wpp._pick_bz(
@@ -321,34 +434,60 @@ def _fused_eo_call(u_here_pl, u_bw_pl, psi_pl, xc_pl, blk_pl, coeff, dims,
     kernel = _epilogue_kernel(X, body_rows, (target_parity, Xh), T, tb_sign,
                               xc_mode=xc_mode, with_blk=with_blk,
                               twist=twist, diag_twist=diag_twist,
-                              with_coeff=coeff is not None, z_rows=z_rows)
+                              with_coeff=coeff is not None, z_rows=z_rows,
+                              g5=g5, nrm=nrm, residual=residual)
 
     operands = [psi_pl] * len(in_specs)
     if xc_mode == "input":
         in_specs.append(centre_spec)
         operands.append(xc_pl)
+    aliases = {}
+    if residual:
+        # the new r takes the old one's buffer where their types agree
+        # (without the alias XLA copies the batch once an iteration to
+        # carry it: PERF.md section 6, PR 39)
+        if rc_pl.dtype == odt:
+            aliases = {len(operands): 0}
+        in_specs.append(centre_spec)
+        operands.append(rc_pl)
     if mrhs:
-        kernel = wpp._mrhs_wrap(kernel, n_psi=len(operands))
-    if coeff is not None:
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-        operands.append(coeff)
+        kernel = wpp._mrhs_wrap(kernel, n_psi=len(operands), n_out=1 + nrm)
+    # the epilogue's scalars precede the links, in SMEM
+    for k in (coeff, alpha if residual else None):
+        if k is not None:
+            in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+            operands.append(k)
     in_specs += [site_spec(4, R, 3, 2)] * 2
     operands += [u_here_pl, u_bw_pl]
     if with_blk:
         in_specs.append(site_spec(2, 6, 6, 2))
         operands.append(blk_pl)
+    out_specs = centre_spec
+    out_shape = jax.ShapeDtypeStruct(psi_pl.shape, odt)
+    if nrm:
+        # one (rows, YXh) block of partial sums a grid step
+        N = psi_pl.shape[0]
+        out_specs = [out_specs, pl.BlockSpec(
+            (None, None, None, body_rows, YXh),
+            lambda tb, zb, n: (tb, zb, n, 0, 0))]
+        out_shape = [out_shape, jax.ShapeDtypeStruct(
+            (T // bt, nzb, N, body_rows, YXh), F32)]
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=(T // bt, nzb) + ((psi_pl.shape[0],) if mrhs else ()),
         in_specs=in_specs,
-        out_specs=centre_spec,
-        out_shape=jax.ShapeDtypeStruct(psi_pl.shape, odt),
+        out_specs=out_specs,
+        out_shape=out_shape,
+        input_output_aliases=aliases,
         interpret=interpret,
         name=name,
         compiler_params=None if vmem_limit is None else
         pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
     )(*operands)
+    if not nrm:
+        return out
+    return out[0], jnp.sum(out[1], axis=(0, 1, 3, 4))
 
 
 # -- public entry points ----------------------------------------------------
@@ -415,20 +554,39 @@ def dslash_eo_pallas_post_mrhs(u_here_pl, u_bw_pl, psi_pl, dims,
                           tb_sign=tb_sign)
 
 
-@functools.partial(jax.jit, static_argnames=_DIAG_HOP_STATIC)
+@functools.partial(jax.jit,
+                   static_argnames=_DIAG_HOP_STATIC + ("g5", "nrm"))
 def dslash_eo_pallas_diag_hop_mrhs(u_here_pl, u_bw_pl, psi_pl, xc_pl,
                                    dims, target_parity, *, hop_coeff,
                                    blk_pl=None, diag_twist=None,
                                    interpret=False, block_z=None,
-                                   out_dtype=None, tb_sign=True):
-    """MRHS ``dslash_eo_pallas_diag_hop`` (x batched like psi)."""
+                                   out_dtype=None, tb_sign=True,
+                                   g5=False, nrm=False, rc=None,
+                                   alpha=None):
+    """MRHS ``dslash_eo_pallas_diag_hop`` (x batched like psi): the
+    ``combine`` form, v = diag(x) + hop_coeff * D psi.  Three more, all
+    call-time and under this one name (a capture tells a kernel by it):
+    ``g5`` stores gamma5 v (``Mdag = g5 M(-s) g5``'s outer sign, or the
+    inner one, in the store); ``nrm`` returns ``(that batch, its (N,)
+    f32 squared norms per source)``, summed by the epilogue from what
+    it stores (the ``norm2`` form: with ``g5`` on ``M p`` they are the
+    batched CG's ``p . MdagM p``); ``rc`` (a batch as ``x``) and
+    ``alpha`` ((N,) f32, one a source) make it the ``residual`` form:
+    the store writes ``rc - alpha * [g5] v`` in ``rc``'s place and
+    sums that, the new ``r`` and ``|r|^2`` of a batched CG iteration,
+    and ``v`` never reaches HBM.  Where no route holds ``rc`` beside
+    ``x`` and the blocks the call is the ``norm2`` form and XLA makes
+    the update."""
+    if rc is not None:
+        alpha = jnp.asarray(alpha, F32).reshape(psi_pl.shape[0])
     return _fused_eo_call(u_here_pl, u_bw_pl, psi_pl, xc_pl, blk_pl,
                           _coeff_operand(hop_coeff), tuple(dims),
                           target_parity,
                           name="dslash_eo_pallas_diag_hop_mrhs",
                           mrhs=True, diag_twist=diag_twist,
                           interpret=interpret, block_z=block_z,
-                          out_dtype=out_dtype, tb_sign=tb_sign)
+                          out_dtype=out_dtype, tb_sign=tb_sign, g5=g5,
+                          nrm=nrm, rc_pl=rc, alpha=alpha)
 
 
 @functools.partial(jax.jit, static_argnames=(

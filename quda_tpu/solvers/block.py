@@ -229,8 +229,9 @@ def batched_cg_pairs_loop(step: Callable, B: jnp.ndarray, tol, maxiter,
     (``k``: the iteration): the loop makes no pass over the batch for
     ``pAp``, for ``r`` or for ``|r|^2``, and keeps ``x += alpha p``,
     ``beta`` and ``p = r + beta p``.  It comes from the operator where
-    that has the whole of it for one more tile read (the Wilson pair
-    operator's ``MdagM_cg_step_pairs_mrhs``: ``pAp = |g5 M p|^2`` out
+    that has the whole of it for one more tile read (the Wilson and
+    the clover-type Schur pair operators'
+    ``MdagM_cg_step_pairs_mrhs``: ``pAp = |g5 M p|^2`` out
     of the first ``M``, so the last hop's epilogue writes the new ``r``
     and sums it) and from ``cg_step`` of the batched matvec everywhere
     else, which is also where an armed dslash fault corrupts ``A p``:

@@ -51,10 +51,12 @@ takes the first half of an iteration from one callable, ``(p, r, rz,
 k) -> (r - alpha A p, its squared norms, alpha, p . A p)``, and keeps
 the updates of ``x`` and ``p``: the operator's own
 ``MdagM_cg_step_pairs_mrhs`` / ``M_cg_step_pairs_mrhs`` where its class
-has one (the Wilson pair operator: ``pAp`` is ``|g5 M p|^2``, summed in
-the epilogue of the kernel that stores ``g5 M p``, so ``alpha`` is
-known before the last hop, whose epilogue writes ``r - alpha A p`` in
-``r``'s place and sums it: ``A p`` is never stored), else
+has one (the Wilson pair operator and, since PR 48, the clover-type
+Schur pair operators on their fused kernels: ``pAp`` is ``|g5 M p|^2``,
+summed in the epilogue of the kernel that stores ``g5 M p``, so
+``alpha`` is known before the last hop, whose epilogue writes ``r -
+alpha A p`` in ``r``'s place and sums it: ``A p`` is never stored),
+else
 ``block.cg_step`` of the matvec (XLA's dot, update and sum over the
 batch).  With a dslash fault armed it is ``block.cg_step`` whatever the
 operator offers: the fault corrupts ``A p``, which only that step has.

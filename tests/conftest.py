@@ -143,7 +143,9 @@ def pytest_collection_modifyitems(config, items):
 # ~35 s of batched-route cases to test_clover_resident.py and ~13 s of
 # described-chip compiles to test_chip_compile.py; PR 47 ~65 s of
 # interpreted fused MRHS kernels to test_clover_pallas.py, which had
-# none in tier-1, and ~10 s to test_chip_compile.py).  It
+# none in tier-1, and ~10 s to test_chip_compile.py; PR 48 ~100 s of
+# the K2 kernel's norm2 and residual forms and the clover operator's
+# own CG step to test_clover_pallas.py).  It
 # only orders the hand-out: a stale or missing entry costs balance and
 # nothing else.
 FILE_SECONDS = {
@@ -160,7 +162,7 @@ FILE_SECONDS = {
     "test_eig.py": 90, "test_mg_3level.py": 90, "test_milc_rhmc.py": 80,
     "test_mg_gemm_coarse.py": 80, "test_packed.py": 80,
     "test_pallas_sharded.py": 80, "test_clover.py": 80,
-    "test_clover_pallas.py": 80,
+    "test_clover_pallas.py": 180,
     "test_heatbath.py": 70, "test_build_accounting.py": 70,
     "test_schwarz.py": 70, "test_smear_force.py": 60,
     "test_parallel.py": 60, "test_solvers.py": 60,

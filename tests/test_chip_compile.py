@@ -158,18 +158,32 @@ def _clover_mrhs(stage, n=8, block_z=None, lat=L):
     three spinor operands (and ``xc`` for ``diag_hop``) and the
     ``vmem_limit_bytes`` of ops/clover_pallas.mrhs_route (32.1 / 33.8
     MiB); ``block_z = 8`` keeps the five-operand z-blocked fallback of
-    larger local volumes compiled for the chip."""
+    larger local volumes compiled for the chip.  ``residual`` (PR 48):
+    the K2 call as the last kernel of a batched CG iteration, gamma5 in
+    the store, ``rc`` and the per-source ``alpha`` besides, the new
+    ``r`` over the old and its sums of squares a second result: ten
+    operands, still ``fullz`` with one slice a step, 35.4 of the 48
+    MiB."""
     from quda_tpu.ops import clover_pallas as cp
+    from quda_tpu.ops import wilson_pallas_packed as wpp
     dims, yxh = (lat,) * 4, lat * lat // 2
     links = ((4, 3, 3, 2, lat, lat, yxh), F32)
     psi = ((n, 4, 3, 2, lat, lat, yxh), F32)
     blk = ((2, 6, 6, 2, lat, lat, yxh), F32)
     u, p, b = (jax.ShapeDtypeStruct(*v) for v in (links, psi, blk))
-    route = cp.mrhs_route(u, p, p if stage == "diag_hop" else None, b, F32,
-                          block_z)
+    route = cp.mrhs_route(u, p, None if stage == "post" else p, b, F32,
+                          block_z, p if stage == "residual" else None)
     want = ("zblock", block_z, 1, None) if block_z else (
-        "fullz", lat, 1, {"post": 33619968, "diag_hop": 35389440}[stage])
+        "fullz", lat, 1, {"post": 33619968, "diag_hop": 35389440,
+                          "residual": 37158912}[stage])
     assert route == want, route
+    assert block_z or route[3] <= wpp._MRHS_FULLZ_VMEM_CAP == 48 * 2 ** 20
+    if stage == "residual":
+        return (lambda u, ub, p, x, k, b, r, a:
+                cp.dslash_eo_pallas_diag_hop_mrhs(
+                    u, ub, p, x, dims, 0, hop_coeff=k, blk_pl=b,
+                    block_z=block_z, out_dtype=F32, g5=True, rc=r, alpha=a),
+                [links, links, psi, psi, ((), F32), blk, psi, ((n,), F32)])
     if stage == "post":
         return (lambda u, ub, p, b: cp.dslash_eo_pallas_post_mrhs(
                     u, ub, p, dims, 1, blk_pl=b, block_z=block_z),
@@ -248,6 +262,7 @@ CASES = {
     "clover_mrhs_n8_diag_hop": lambda: _clover_mrhs("diag_hop"),
     "clover_mrhs_n8_diag_hop_zblock": lambda: _clover_mrhs(
         "diag_hop", block_z=8),
+    "clover_mrhs_n8_diag_hop_residual": lambda: _clover_mrhs("residual"),
     "dwf_eo_ls8": _dwf_ls8,
     "mobius_sblock_ls12_bf16": lambda: _mobius_sblock(BF16),
     "mobius_sblock_ls12_f32": lambda: _mobius_sblock(F32),
@@ -708,7 +723,13 @@ def test_clover_batched_programs_compile_for_v5e(one_chip, program):
     ``xc`` and the coefficient for ``diag_hop``, links, blocks LAST: six
     and eight operands where the z-blocked calls had eight and ten; the
     kernels alone, with their ``vmem_limit_bytes`` and the z-blocked
-    fallback, are cases of ``test_kernel_compiles_for_v5e``); the entry
+    fallback, are cases of ``test_kernel_compiles_for_v5e``), and since
+    PR 48 it takes its step from the operator: the first M's K2 call
+    has a second result, the sums of squares that are ``pAp``, the
+    second's is the residual form (ten operands: ``rc`` and ``alpha``
+    besides; the new ``r`` and ``|r|^2``), and the loop's body is those
+    four custom calls and ONE fusion over a batch-sized operand, the
+    update of ``x`` and ``p``; the entry
     folds Mdag in (one more of each fused kernel behind the bare hop of
     prepare);
     the exit is two bare MRHS hops and XLA's block products, and what it
@@ -753,7 +774,7 @@ def test_clover_batched_programs_compile_for_v5e(one_chip, program):
                                                      key=key)
     compiled = _aot(lower)
     hlo = compiled.as_text()
-    calls = sorted(re.findall(r"%(dslash_eo_pallas\w*?)[.\d]* = f32"
+    calls = sorted(re.findall(r"%(dslash_eo_pallas\w*?)[.\d]* = \(?f32"
                               r"\[[^\n]*tpu_custom_call", hlo))
     fused = ["dslash_eo_pallas_diag_hop_mrhs", "dslash_eo_pallas_post_mrhs"]
     bare = "dslash_eo_pallas_packed_mrhs"
@@ -761,13 +782,26 @@ def test_clover_batched_programs_compile_for_v5e(one_chip, program):
                      "solve": sorted(2 * fused),
                      "verified-exit": [bare, bare]}[program], calls
     # the fused kernels took the full-Z route: three psi operands, not five
-    operands = {n: c.count("%") for n, c in re.findall(
-        r"%(dslash_eo_pallas_(?:post|diag_hop)_mrhs)[.\d]* = f32\[[^\n]*"
-        r"custom-call\(([^\n]*?)\), custom_call_target=\"tpu_custom_call\"",
-        hlo)}
-    assert operands == ({} if program == "verified-exit" else {
-        "dslash_eo_pallas_post_mrhs": 6,
-        "dslash_eo_pallas_diag_hop_mrhs": 8}), operands
+    operands = sorted((n, t, c.count("%")) for n, t, c in re.findall(
+        r"%(dslash_eo_pallas_(?:post|diag_hop)_mrhs)[.\d]* = (\(?)f32\["
+        r"[^\n]*custom-call\(([^\n]*?)\), "
+        r"custom_call_target=\"tpu_custom_call\"", hlo))
+    post, k2 = "dslash_eo_pallas_post_mrhs", "dslash_eo_pallas_diag_hop_mrhs"
+    assert operands == {
+        "verified-exit": [], "prepare": [(k2, "", 8), (post, "", 6)],
+        "solve": [(k2, "(", 8), (k2, "(", 10), (post, "", 6),
+                  (post, "", 6)]}[program], operands
+    if program == "solve":
+        # XLA makes no gamma5, dot, r update or |r|^2: what is left of
+        # the iteration over the batch is the update of x and p
+        loop = _hlo_computation(hlo, re.search(
+            r" while\([^\n]*body=%([\w.\-]+)", hlo).group(1))
+        assert len(re.findall(r"tpu_custom_call", loop)) == 4
+        batch = "f32[" + ",".join(str(d) for d in _psi(F32, (n,))[0]) + "]"
+        fused = [ln.split(" = ")[0].strip() for ln in loop.split("\n")
+                 if " fusion(" in ln and batch in ln]
+        assert len(fused) == 1, fused
+        assert not re.findall(re.escape(batch) + r"\S* copy\(", loop)
     params = _hlo_values(hlo, "parameter")
     links = ",".join(str(d) for d in _links(F32)[0])
     blocks = ",".join(str(d) for d in (2, 6, 6, 2, L, L, YXH))

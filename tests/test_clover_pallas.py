@@ -401,3 +401,262 @@ def test_fused_mrhs_route_follows_the_shapes(case, want):
             > wpp._MRHS_FULLZ_VMEM_CAP
     row = rows["QUDA_TPU_PALLAS_VMEM_MB[fullz]"]
     assert row["last_bz"] == Z and row["last_block_bytes"] == blocks
+
+
+# -- the K2 kernel's gamma5, norm2 and residual forms, and the step --------
+#
+# PR 48.  ``dslash_eo_pallas_diag_hop_mrhs`` stores gamma5 of its value,
+# sums the squares of what it stores per source, and in the residual
+# form writes ``rc - alpha[n] * g5 v`` over ``rc``:
+# ``_SchurPairOpBase.MdagM_cg_step_pairs_mrhs`` makes the first half of
+# a batched CG iteration of them.  One operator and one batch of eight
+# serve the kernel cases and the step, with exactly the step's static
+# arguments, so each form is lowered once in this file (~15 s a form
+# interpreted, whatever the lattice).
+
+N_STEP = 8
+
+
+def _g5(x):
+    return x * jnp.asarray([1, 1, -1, -1], jnp.float32).reshape(
+        (4,) + (1,) * 5)
+
+
+def _per_source(a):
+    return jnp.sum((a * a).reshape(a.shape[0], -1), axis=1)
+
+
+def _k2(op, t, x, sign=+1, parity=None, **kw):
+    """The K2 call as ``_M_sign_fused_mrhs`` makes it."""
+    from quda_tpu.ops import clover_pallas as cp
+    p = op.matpc if parity is None else parity
+    blk, twist = op._fused_k2_params(sign)
+    return cp.dslash_eo_pallas_diag_hop_mrhs(
+        op.gauge_eo_pp[p], op._u_bw[p], t, x, tuple(op.dims), p,
+        hop_coeff=-(op.kappa ** 2), blk_pl=blk, diag_twist=twist,
+        interpret=True, out_dtype=jnp.float32, tb_sign=op._tb_sign,
+        **{"block_z": None, **kw})
+
+
+def _step_batch(seed=48):
+    rng = np.random.default_rng(seed)
+    draw = lambda: jnp.asarray(rng.standard_normal(
+        (N_STEP, 4, 3, 2, 4, 4, 8)), jnp.float32)
+    alpha = jnp.asarray(0.2 + 0.17 * np.arange(N_STEP), jnp.float32)
+    return draw(), draw(), draw(), alpha
+
+
+@pytest.fixture(scope="module")
+def k2_forms(cfg):
+    """The fused and the XLA-stencil clover operator on one gauge, a
+    batch ``(t, x, rc, alpha)`` of eight, and the three full-Z K2 calls
+    on it: ``combine`` (the plain one), ``norm2``, ``residual``."""
+    g, _ = cfg
+    dpc = DiracCloverPC(g, GEOM, KAPPA, CSW, matpc=EVEN)
+    op = dpc.pairs(jnp.float32, use_pallas=True, pallas_interpret=True,
+                   form="pallas")
+    xla = dpc.pairs(jnp.float32, form="xla")
+    t, x, rc, alpha = _step_batch()
+    return {"op": op, "xla": xla, "t": t, "x": x, "rc": rc, "alpha": alpha,
+            "combine": _k2(op, t, x),
+            "norm2": _k2(op, t, x, g5=True, nrm=True),
+            "residual": _k2(op, t, x, g5=True, rc=rc, alpha=alpha)}
+
+
+def test_k2_norm2_form_is_gamma5_of_the_plain_call_and_sums_it(k2_forms):
+    v, n2 = k2_forms["norm2"]
+    assert v.dtype == jnp.float32 and n2.shape == (N_STEP,)
+    assert n2.dtype == jnp.float32
+    # a sign in the store: bitwise XLA's gamma5 pass over the plain call
+    assert bool(jnp.all(v == _g5(k2_forms["combine"])))
+    np.testing.assert_allclose(np.asarray(n2), np.asarray(_per_source(v)),
+                               rtol=2e-6)
+
+
+def test_k2_residual_form_updates_rc_with_a_source_s_own_alpha(k2_forms):
+    r, r2 = k2_forms["residual"]
+    alpha = k2_forms["alpha"]
+    assert len({float(a) for a in alpha}) == N_STEP
+    want = k2_forms["rc"] - alpha.reshape((N_STEP,) + (1,) * 6) \
+        * k2_forms["norm2"][0]
+    assert r.dtype == jnp.float32 and r.shape == want.shape
+    scale = float(jnp.max(jnp.abs(want)))
+    assert float(jnp.max(jnp.abs(r - want))) <= 4e-7 * scale
+    np.testing.assert_allclose(np.asarray(r2), np.asarray(_per_source(want)),
+                               rtol=2e-6)
+    # another order of the sources is another alpha a source
+    assert float(jnp.max(jnp.abs(r[::-1] - want))) > 1e-2 * scale
+
+
+@pytest.mark.parametrize("form,parity,block_z", [
+    # tier-1: the residual form (gamma5, rc, alpha and the sums) on
+    # z-blocks at the other parity; the rest of the table is slow
+    ("residual", ODD, 2),
+    pytest.param("norm2", ODD, 2, marks=pytest.mark.slow),
+    pytest.param("residual", EVEN, 2, marks=pytest.mark.slow),
+    pytest.param("norm2", EVEN, 2, marks=pytest.mark.slow),
+    pytest.param("residual", ODD, None, marks=pytest.mark.slow),
+    pytest.param("norm2", ODD, None, marks=pytest.mark.slow)])
+def test_k2_forms_on_both_routes_and_parities(cfg, k2_forms, form, parity,
+                                              block_z):
+    """Against XLA's composition on the XLA stencil (the hop's sums in
+    another order: f32 round-off), per route (``block_z`` < Z asks for
+    z-blocks) and parity; where the shared fixture holds the same form
+    on full-Z tiles of the same parity, the spinor bitwise against it."""
+    from quda_tpu.ops import clover_pallas as cp
+    g, _ = cfg
+    if parity == EVEN:
+        op, xla = k2_forms["op"], k2_forms["xla"]
+    else:
+        dpc = DiracCloverPC(g, GEOM, KAPPA, CSW, matpc=ODD)
+        op = dpc.pairs(jnp.float32, use_pallas=True, pallas_interpret=True,
+                       form="pallas")
+        xla = dpc.pairs(jnp.float32, form="xla")
+    t, x, rc, alpha = (k2_forms[k] for k in ("t", "x", "rc", "alpha"))
+    blk = op._fused_k2_params(+1)[0]
+    u = op.gauge_eo_pp[parity]
+    name, route = cp.mrhs_form(u, t, x, blk, jnp.float32, block_z, True,
+                               rc if form == "residual" else None)
+    assert name == form
+    assert route[:2] == (("zblock", block_z) if block_z else ("fullz", 4))
+    v = _g5(xla._diag_sign_pairs_mrhs(x, +1, jnp.float32)
+            - (xla.kappa ** 2) * xla._d_to_mrhs(t, parity, jnp.float32))
+    if form == "residual":
+        got, sums = _k2(op, t, x, g5=True, rc=rc, alpha=alpha,
+                        block_z=block_z)
+        want = rc - alpha.reshape((N_STEP,) + (1,) * 6) * v
+    else:
+        got, sums = _k2(op, t, x, g5=True, nrm=True, block_z=block_z)
+        want = v
+    scale = float(jnp.max(jnp.abs(want)))
+    assert float(jnp.max(jnp.abs(got - want))) <= 4e-6 * scale
+    np.testing.assert_allclose(np.asarray(sums),
+                               np.asarray(_per_source(want)), rtol=1e-5)
+    if parity == EVEN:
+        assert bool(jnp.all(got == k2_forms[form][0]))
+
+
+def _step_close(got, want, rtol=1e-5):
+    assert [(v.shape, v.dtype) for v in got] == [
+        (v.shape, v.dtype) for v in want]
+    for g, w in zip(got[1:], want[1:]):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=rtol)
+    scale = float(jnp.max(jnp.abs(want[0])))
+    assert float(jnp.max(jnp.abs(got[0] - want[0]))) <= rtol * scale
+
+
+def test_cg_step_of_the_clover_pair_operator_is_the_generic_step(k2_forms):
+    """``MdagM_cg_step_pairs_mrhs`` on the fused form (``pAp`` out of
+    the first M's K2 epilogue, the new ``r`` and ``|r|^2`` out of the
+    second's) against ``block.cg_step`` of the XLA-stencil operator's
+    ``MdagM_pairs_mrhs``, to f32 round-off; in the ``xla`` form the
+    method IS that generic step, bit for bit."""
+    from quda_tpu.solvers.block import cg_step
+    op, xla = k2_forms["op"], k2_forms["xla"]
+    p, r = k2_forms["x"], k2_forms["rc"]
+    rz = jnp.asarray(0.7 + 0.9 * np.arange(N_STEP), jnp.float32)
+    assert op._mrhs_form() == "pallas" and xla._mrhs_form() == "xla"
+    want = cg_step(xla.MdagM_pairs_mrhs)(p, r, rz, 0)
+    _step_close(op.MdagM_cg_step_pairs_mrhs(p, r, rz), want)
+    assert len({float(a) for a in want[2]}) == N_STEP
+    for g, w in zip(xla.MdagM_cg_step_pairs_mrhs(p, r, rz, 0), want):
+        assert bool(jnp.all(g == w))
+
+
+@pytest.fixture
+def clover_route_counts(tmp_path):
+    """A metrics session of the test's own; calling the fixture reads
+    ``clover_mrhs_route_total`` as {(form, stage, route, epilogue): n}."""
+    from quda_tpu.obs import memory as omem
+    from quda_tpu.obs import metrics as omet
+    omet.stop(flush_files=False)
+    omem.reset()
+    omet.start(str(tmp_path))
+
+    def read():
+        return {tuple(dict(lab)[k] for k in ("form", "stage", "route",
+                                             "epilogue")): int(v)
+                for (n, lab), v in omet.snapshot()["counters"].items()
+                if n == "clover_mrhs_route_total"}
+    yield read
+    omet.stop(flush_files=False)
+    omem.reset()
+
+
+def test_cg_step_counts_its_kernels_by_epilogue(cfg, k2_forms,
+                                                clover_route_counts):
+    """Traced, not run: the fused f32 step is two ``post``, one
+    ``norm2`` and one ``residual`` K2 call; sloppy storage keeps the
+    generic step (the kernel's sums would be of f32 values the next
+    hop does not read) and every K2 call is ``combine``, as in the
+    ``xla`` form."""
+    g, _ = cfg
+    p, r = k2_forms["x"], k2_forms["rc"]
+    rz = jnp.ones((N_STEP,), jnp.float32)
+    jax.eval_shape(k2_forms["op"].MdagM_cg_step_pairs_mrhs, p, r, rz)
+    fused = {("pallas", "post", "fullz", "none"): 2,
+             ("pallas", "diag_hop", "fullz", "norm2"): 1,
+             ("pallas", "diag_hop", "fullz", "residual"): 1}
+    assert clover_route_counts() == fused
+    lo = DiracCloverPC(g, GEOM, KAPPA, CSW, matpc=EVEN).pairs(
+        jnp.bfloat16, use_pallas=True, pallas_interpret=True, form="pallas")
+    out = jax.eval_shape(lo.MdagM_cg_step_pairs_mrhs,
+                         p.astype(jnp.bfloat16), r.astype(jnp.bfloat16), rz)
+    assert out[0].dtype == jnp.bfloat16
+    assert clover_route_counts() == {
+        **fused, ("pallas", "post", "fullz", "none"): 4,
+        ("pallas", "diag_hop", "fullz", "combine"): 2}
+    jax.eval_shape(k2_forms["xla"].MdagM_cg_step_pairs_mrhs, p, r, rz, 0)
+    assert {k: v for k, v in clover_route_counts().items()
+            if k[0] == "xla"} == {("xla", "post", "none", "none"): 2,
+                                  ("xla", "diag_hop", "none", "combine"): 2}
+
+
+@pytest.mark.slow
+def test_cg_step_of_a_twisted_clover_operator_changes_the_twist_sign(cfg):
+    """(36 s: two more K2 forms with the twist compiled in; no served
+    path runs it.)  The step is written on ``Mdag = g5 M(-s) g5``: the second M of a
+    twisted-clover operator takes the other twist sign and the other
+    inverse blocks.  With a twist that matters (mu 0.3), against the
+    generic step of the XLA-stencil operator."""
+    from quda_tpu.models.twisted import DiracTwistedCloverPC
+    from quda_tpu.solvers.block import cg_step
+    g, _ = cfg
+    dpc = DiracTwistedCloverPC(g, GEOM, KAPPA, 0.3, CSW)
+    op = dpc.pairs(jnp.float32, use_pallas=True, pallas_interpret=True,
+                   form="pallas")
+    xla = dpc.pairs(jnp.float32, form="xla")
+    assert op._mrhs_form() == "pallas" and op.a != 0
+    _, p, r, _ = _step_batch(49)
+    rz = jnp.asarray(0.7 + 0.9 * np.arange(N_STEP), jnp.float32)
+    want = cg_step(xla.MdagM_pairs_mrhs)(p, r, rz, 0)
+    _step_close(op.MdagM_cg_step_pairs_mrhs(p, r, rz), want)
+    # the twist is felt: the same sign in the second M is ten times the
+    # tolerance off
+    wrong = cg_step(lambda x: xla._g5_pairs_mrhs(xla._M_sign_pairs_mrhs(
+        xla._g5_pairs_mrhs(xla._M_sign_pairs_mrhs(x, +1)), +1)))(p, r, rz, 0)
+    assert float(jnp.max(jnp.abs(wrong[0] - want[0]))) \
+        > 1e-4 * float(jnp.max(jnp.abs(want[0])))
+
+
+@pytest.mark.slow
+def test_batched_cg_loop_on_the_clover_operators_own_step(k2_forms):
+    """(80 s: the loop's module holds four interpreted kernels; on the
+    chip the benchmark's ``correct`` holds every call of the cell to
+    the plain reference, and tests/test_chip_compile.py the loop's
+    shape.)  ``batched_cg_pairs_loop`` on the fused operator's own step against
+    the loop on the generic step of the XLA-stencil operator: the same
+    iterations to a source within one check, the same solutions to the
+    solver's tolerance."""
+    from quda_tpu.solvers.block import batched_cg_pairs_loop, cg_step
+    op, xla, b = k2_forms["op"], k2_forms["xla"], k2_forms["rc"][:3]
+    tol = 1e-6
+    solve = lambda step: jax.jit(lambda b: batched_cg_pairs_loop(
+        step, b, tol, 400, 1, False, None))(b)
+    got = solve(op.MdagM_cg_step_pairs_mrhs)
+    want = solve(cg_step(xla.MdagM_pairs_mrhs))
+    assert bool(jnp.all(got.converged)) and bool(jnp.all(want.converged))
+    assert np.all(np.abs(np.asarray(got.iters) - np.asarray(want.iters)) <= 1)
+    for i in range(3):
+        assert _rel(got.x[i], want.x[i]) < 10 * tol
+        assert _rel(xla.MdagM_pairs_mrhs(got.x)[i], b[i]) < 5 * tol

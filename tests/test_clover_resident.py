@@ -391,8 +391,9 @@ def _program_counts():
 
 
 def _route_counts():
-    """{(form, stage, route): n} of clover_mrhs_route_total."""
-    return {tuple(dict(l)[k] for k in ("form", "stage", "route")): int(v)
+    """{(form, stage, route, epilogue): n} of clover_mrhs_route_total."""
+    return {tuple(dict(l)[k] for k in ("form", "stage", "route",
+                                       "epilogue")): int(v)
             for (n, l), v in omet.snapshot()["counters"].items()
             if n == "clover_mrhs_route_total"}
 
@@ -425,8 +426,8 @@ def test_batch_first_call_builds_three_programs_on_the_resident_term(
     assert w["traced"] == 3
     # the staged form off the chip; an M counts both stages where it is
     # traced: Mdag of the entry, M and Mdag of the loop
-    assert w["routes"] == {("xla", "post", "none"): 3,
-                           ("xla", "diag_hop", "none"): 3}
+    assert w["routes"] == {("xla", "post", "none", "none"): 3,
+                           ("xla", "diag_hop", "none", "combine"): 3}
     p = w["param"]
     assert all(p.converged_multi) and len(p.true_res_multi) == N_SRC
     for i in range(N_SRC):
@@ -463,28 +464,41 @@ def test_batch_second_call_hits_and_another_csw_only_rebuilds_the_term(
         assert _host_residual(gauges["A"], B[i], x[i]) > 1e-3
 
 
+@pytest.mark.parametrize("fault_k", [None, 7])
 def test_batch_solve_program_in_the_fused_form_counts_the_fullz_route(
-        batch_warm, monkeypatch):
+        batch_warm, monkeypatch, fault_k):
     """The batched solve program on the resident operator in the fused
     form (PR 47): traced, not compiled (an interpreted kernel costs ~20
     s a module and proves nothing about a counter).  Each fused MRHS
-    call is counted where ``_M_sign_pairs_mrhs`` traces it, once a
-    stage for the loop's M and once for its Mdag, by the route the call
-    takes from its shapes: ``fullz`` at the test lattice as at 24^4;
-    the same program asked for again is the traced one and counts
+    call is counted where it is traced, by the route the call takes
+    from its shapes (``fullz`` at the test lattice as at 24^4) and by
+    its epilogue.  Since PR 48 the loop takes its step from the
+    operator (``MdagM_cg_step_pairs_mrhs``): two ``post``, the first
+    M's K2 call in the ``norm2`` form (``pAp``), the second's in the
+    ``residual`` form (the new ``r`` and ``|r|^2``).  With a dslash
+    fault armed the program takes ``block.cg_step`` of
+    ``MdagM_pairs_mrhs`` whatever the operator offers (the fault
+    corrupts ``A p``, which only that step has): ``combine`` only.
+    The same program asked for again is the traced one and counts
     nothing."""
+    from quda_tpu.solvers import block
     from quda_tpu.solvers import program as sprog
     from quda_tpu.solvers.fused_iter import _resolve_check_every
     monkeypatch.setenv("QUDA_TPU_PALLAS", "1")
     monkeypatch.setenv("QUDA_TPU_CLOVER_FORM", "pallas")
     qconf.reset_cache()
+    steps = []
+    generic = block.cg_step
+    monkeypatch.setattr(block, "cg_step", lambda mv, k=None: (
+        steps.append(k), generic(mv, k))[1])
     try:
         api.load_clover_quda(_param(cuda_prec_sloppy="single"))
         op = api._ctx["clover"]["ops"][jnp.dtype(jnp.float32)]
         assert op._mrhs_form() == "pallas"
         x = jax.ShapeDtypeStruct((N_SRC, 4, 3, 2, L, L, L * L // 2),
                                  jnp.float32)
-        key = (_resolve_check_every(None), sprog._loop_knobs(False, 100),
+        key = (_resolve_check_every(None),
+               sprog._loop_knobs(False, 100)._replace(fault_k=fault_k),
                False)
         trace = lambda: sprog._batched_cg_pairs_program.trace(
             op, x, 1e-6, 100, key=key)
@@ -492,9 +506,14 @@ def test_batch_solve_program_in_the_fused_form_counts_the_fullz_route(
         trace()
         first = _route_counts()
         assert {k: v - before.get(k, 0) for k, v in first.items()
-                if v != before.get(k, 0)} == {
-            ("pallas", "post", "fullz"): 2,
-            ("pallas", "diag_hop", "fullz"): 2}
+                if v != before.get(k, 0)} == ({
+            ("pallas", "post", "fullz", "none"): 2,
+            ("pallas", "diag_hop", "fullz", "norm2"): 1,
+            ("pallas", "diag_hop", "fullz", "residual"): 1}
+            if fault_k is None else {
+            ("pallas", "post", "fullz", "none"): 2,
+            ("pallas", "diag_hop", "fullz", "combine"): 2})
+        assert steps == ([] if fault_k is None else [fault_k])
         trace()
         assert _route_counts() == first
     finally:
