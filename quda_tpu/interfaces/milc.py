@@ -521,19 +521,26 @@ def qudaCloverInvert(kappa: float, csw: float, source, tol: float = 1e-10,
 
 
 def qudaCloverMultishiftInvert(kappa: float, csw: float, offsets, source,
-                               tol: float = 1e-10, maxiter: int = 10000):
-    """qudaCloverMultishiftInvert (quda_milc_interface.h:711): shifted
-    solves on the clover normal operator."""
-    from ..fields.spinor import even_odd_split
-    from ..models.clover import DiracCloverPC
-    from ..solvers.multishift import multishift_cg
-    geom = api._ctx["geom"]
-    d = DiracCloverPC(api._ctx["gauge"], geom, kappa, csw)
-    be, bo = even_odd_split(jnp.asarray(source), geom)
-    rhs = d.Mdag(d.prepare(be, bo))
-    mv = lambda v: d.Mdag(d.M(v))
-    res = multishift_cg(mv, rhs, tuple(offsets), tol=tol, maxiter=maxiter)
-    return res.x, {"iters": int(res.iters)}
+                               tol: float = 1e-10, maxiter: int = 10000,
+                               prec="double"):
+    """qudaCloverMultishiftInvert (quda_milc_interface.h:711): the
+    shifted solves (Mdag M + offset_i) x_i = Mdag b_p on the even-odd
+    clover operator, through ``invert_multishift_quda`` as
+    ``qudaMultishiftInvert`` goes: in single precision on the packed
+    route the solve runs on the resident clover term.  Returns
+    (solutions, info): ``iters``, and per shift ``true_res_offset``,
+    ``iter_res_offset``, ``iter_count_offset`` and ``converged``."""
+    p = InvertParam(
+        dslash_type="clover", kappa=kappa, csw=csw,
+        inv_type="multi-shift-cg", solve_type="normop-pc", tol=tol,
+        maxiter=maxiter, cuda_prec=prec, num_offset=len(offsets),
+        offset=tuple(offsets))
+    xs = api.invert_multishift_quda(source, p)
+    return xs, {"iters": p.iter_count,
+                "true_res_offset": list(p.true_res_offset),
+                "iter_res_offset": list(p.iter_res_offset),
+                "iter_count_offset": list(p.iter_count_offset),
+                "converged": list(p.converged_multi)}
 
 
 def qudaEigCGCloverInvert(kappa: float, csw: float, source, n_ev: int = 8,

@@ -2879,6 +2879,96 @@ def _invert_multishift_resident(b, param: InvertParam, recording: bool):
     return xs.astype(b.dtype)
 
 
+def _clover_shift_route(param: InvertParam) -> bool:
+    """Whether ``invert_multishift_quda`` solves these shifted systems
+    on ``_resident_clover``, given the packed pair representation
+    serves the call: Wilson-clover, the multi-shift CG on the normal
+    equations of the even-odd system (``normop-pc``), no explicit
+    ``half`` / ``quarter`` sloppy request (the loop is pure f32).
+    Everything else (Wilson, the twisted family, another solve type)
+    keeps the branch it has in ``_invert_multishift_body``."""
+    return (param.dslash_type == "clover"
+            and param.inv_type == "multi-shift-cg"
+            and param.solve_type == "normop-pc"
+            and param.cuda_prec_sloppy not in ("half", "quarter"))
+
+
+def _invert_clover_multishift_resident(b, param: InvertParam,
+                                       recording: bool):
+    """The Wilson-clover multi-shift solve (Mdag M + sigma_i) x_i =
+    Mdag b_p on the resident clover term (``_resident_clover``: reused
+    after ``load_clover_quda``, built on first use), M the even-odd
+    operator of ``matpc`` and b_p what ``prepare`` makes of the source:
+    entry (parity split, ``prepare`` and ``Mdag``), the shared-Krylov
+    loop (two M an iteration, pure f32) and the exit are one cached
+    program each (solvers/program.py), the links, the blocks, kappa and
+    the offsets operands: every kappa, csw, gauge and set of offsets of
+    one (lattice, N) shares the executables, and no canonical
+    DiracClover* is built.  The exit verifies EVERY shift as
+    ``_invert_multishift_resident`` does: the N solutions are one batch
+    for the batched operator, and a shift counts as converged when the
+    loop claimed it and its true residual is within the verified-exit
+    margin.  The solutions live on the p sites (no reconstruction).
+    The first trace of entry and exit stands on a stack chunk of its
+    own, as the loop's does (PERF.md section 7 (22))."""
+    import numpy as np
+
+    from ..obs import metrics as omet
+    from ..obs import trace as otr
+    from ..solvers import program as sprog
+    from ..utils import config as qconf
+    from ..utils.frames import on_a_stack_chunk_of_its_own as footed
+    api = "invert_multishift_quda"
+    t0 = time.perf_counter()
+    with otr.phase("setup", api):
+        d = _CloverResidentSolve(_resident_clover(param, ()), param.kappa)
+        form = _solve_form(d)
+        # a host array: an operand of the two programs, no eager op
+        shifts = np.asarray(param.offset, np.float32)
+        with otr.span("prepare", cat="setup") as span:
+            rhs, hit = footed(lambda: sprog.prepare(d.op, b))
+            _note_solve_program(span, api, form, "prepare", hit)
+    with otr.phase("compute", api), \
+            otr.span("solve:multishift-cg", cat="solver",
+                     n_shifts=len(param.offset), tol=param.tol,
+                     maxiter=param.maxiter) as solve_span:
+        with otr.span("dispatch", cat="solver"):
+            res, hit = sprog.multishift_cg(
+                d.op, rhs, shifts, tol=param.tol, maxiter=param.maxiter,
+                record=recording)
+        _note_solve_program(solve_span, api, form, "multishift-cg", hit)
+        # the first host read of the program's result: the wait for
+        # its device time (the per-shift counts ride the same read)
+        with otr.span("wait", cat="solver"):
+            _read_shift_iterations(param, res)
+        _note_shift_iterations(param, solve_span)
+    param.secs = time.perf_counter() - t0
+    _account_multishift(param, d)
+    with otr.phase("epilogue", api):
+        margin = float(qconf.get("QUDA_TPU_ROBUST_VERIFY_MARGIN",
+                                 fresh=True))
+        with otr.span("verified_exit", cat="epilogue") as span:
+            (xs, *numbers), hit = footed(
+                lambda: sprog.verified_exit_shifts(
+                    d.op, rhs, res, shifts, margin * param.tol))
+            _note_solve_program(span, api, form, "verified-exit", hit)
+            with otr.span("exit_read", cat="epilogue"):
+                true_res, iter_res, ok = jax.device_get(numbers)
+        param.true_res_offset = [float(r) for r in true_res]
+        param.iter_res_offset = [float(r) for r in iter_res]
+        param.true_res = param.true_res_offset[0]
+        for outcome, n in (("converged", int(ok.sum())),
+                           ("failed", int((~ok).sum()))):
+            if n:
+                omet.inc("multishift_shift_total", float(n),
+                         outcome=outcome)
+        _solve_supervision(param, api,
+                           breakdown=getattr(res, "breakdown", None),
+                           converged_multi=ok)
+    _publish_multishift(res, rhs, param)
+    return xs.astype(b.dtype)
+
+
 def _invert_multishift_body(source, param: InvertParam):
     from ..obs import trace as otr
     from ..solvers.multishift import multishift_cg
@@ -2889,6 +2979,8 @@ def _invert_multishift_body(source, param: InvertParam):
                 and _packed_enabled(on_tpu))
     if pairs_ok and _ks_links_loaded(param):
         return _invert_multishift_resident(b, param, recording)
+    if pairs_ok and _clover_shift_route(param):
+        return _invert_clover_multishift_resident(b, param, recording)
     d = _build_dirac(param, True)
     be, bo = _split(b, param, d)
 

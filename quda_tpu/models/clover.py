@@ -345,6 +345,33 @@ class DiracCloverPCPairs(_ProgramOperand, _SchurPairOpBase):
         return x, jnp.sqrt((norm2(r_p) + norm2(r_q))
                            / (norm2(b_p) + norm2(b_q)))
 
+    def verified_exit_shifts_pairs(self, b_pp, X_pp, shifts, claimed,
+                                   shift_r2, bound):
+        """The verified exit of a multi-shift solve (Mdag M + sigma_i)
+        x_i = b' on the even-odd operator M: the pair-form right-hand
+        side of the normal equations ``b_pp`` (what
+        ``prepare_normal_pairs`` made), the N pair-form solutions
+        ``X_pp`` (N, 4, 3, 2, T, Z, Y*Xh) and ``shifts`` (N,) ->
+        (canonical parity solutions (N, T, Z, Y, Xh, 4, 3), the N true
+        residuals |b' - (Mdag M + sigma_i) x_i| / |b'|, the loop's
+        analytic residuals sqrt(``shift_r2``) / |b'|, N flags:
+        ``claimed`` by the loop AND a true residual <= ``bound``; a NaN
+        fails).  The N solutions are ONE batch for the batched operator
+        (``MdagM_pairs_mrhs``): links and blocks read once a hop for
+        all shifts.  The residual is the PC normal system's own, no
+        reconstruction.  Meant to be traced (solvers/program.py) on the
+        f32 operator."""
+        f32 = jnp.float32
+        b, X = b_pp.astype(f32), X_pp.astype(f32)
+        sig = shifts.astype(f32).reshape((-1,) + (1,) * b.ndim)
+        r = b[None] - (self.MdagM_pairs_mrhs(X).astype(f32) + sig * X)
+        b2 = jnp.sum(b * b)
+        true_res = jnp.sqrt(jnp.sum(r * r, axis=tuple(range(1, r.ndim)))
+                            / b2)
+        return (self.solution_from_pairs_mrhs(X), true_res,
+                jnp.sqrt(shift_r2.astype(f32) / b2),
+                jnp.logical_and(claimed, true_res <= bound))
+
 
 jax.tree_util.register_pytree_node_class(DiracCloverPCPairs)
 

@@ -189,9 +189,10 @@ METRICS: dict[str, dict] = {
         "type": COUNTER,
         "help": "calls through a cached program (solvers/program.py: "
                 "the solve loops, solver='verified-exit' the Wilson, "
-                "staggered, Möbius and batched clover pair routes' "
-                "verified exit, and solver='prepare' the entry of the "
-                "staggered, Möbius and batched clover routes), "
+                "staggered, Möbius, batched clover and shifted clover "
+                "pair routes' verified exit, and solver='prepare' the "
+                "entry of the staggered, Möbius, batched clover and "
+                "shifted clover routes), "
                 "by api/form/solver/outcome: 'miss' traced (and lowered, "
                 "compiled or fetched) the program, 'hit' was an "
                 "in-process executable lookup"},
@@ -208,8 +209,9 @@ METRICS: dict[str, dict] = {
     "clover_term_total": {
         "type": COUNTER,
         "help": "uses of the resident clover term (load_clover_quda, "
-                "clover invert_quda and the batched clover route of "
-                "invert_multi_src_quda) by outcome: 'built' nothing was "
+                "clover invert_quda, the batched clover route of "
+                "invert_multi_src_quda and the clover route of "
+                "invert_multishift_quda) by outcome: 'built' nothing was "
                 "resident, 'reused' the resident term served, "
                 "'rebuilt' another kappa*csw, matpc, gauge or kernel "
                 "route replaced it"},
@@ -310,14 +312,16 @@ METRICS: dict[str, dict] = {
     "multishift_shift_total": {
         "type": COUNTER,
         "help": "shifts of invert_multishift_quda calls on the resident "
-                "KS route by outcome of the verified exit: 'converged' "
+                "KS and clover routes by outcome of the verified exit: "
+                "'converged' "
                 "the loop claimed the shift and its true residual, "
                 "recomputed by the exit program, is within the "
                 "verified-exit margin x tol; 'failed' anything else"},
     "multishift_shift_iterations_total": {
         "type": COUNTER,
         "help": "shift-iterations of invert_multishift_quda calls on "
-                "the resident KS route by state: 'updated' the loop "
+                "the resident KS and clover routes by state: 'updated' "
+                "the loop "
                 "updated the shift's x and p in that iteration "
                 "(MultiShiftResult.shift_iters), 'skipped' the shift "
                 "had converged and left the update; updated + skipped "

@@ -145,7 +145,10 @@ def pytest_collection_modifyitems(config, items):
 # interpreted fused MRHS kernels to test_clover_pallas.py, which had
 # none in tier-1, and ~10 s to test_chip_compile.py; PR 48 ~100 s of
 # the K2 kernel's norm2 and residual forms and the clover operator's
-# own CG step to test_clover_pallas.py).  It
+# own CG step to test_clover_pallas.py; PR 49 added
+# test_clover_multishift_resident.py, ~70 s: three programs and the
+# plain reference on the XLA stencil, no interpreted kernel, and ~10 s
+# of described-chip compiles to test_chip_compile.py).  It
 # only orders the hand-out: a stale or missing entry costs balance and
 # nothing else.
 FILE_SECONDS = {
@@ -165,6 +168,7 @@ FILE_SECONDS = {
     "test_clover_pallas.py": 180,
     "test_heatbath.py": 70, "test_build_accounting.py": 70,
     "test_schwarz.py": 70, "test_smear_force.py": 60,
+    "test_clover_multishift_resident.py": 70,
     "test_parallel.py": 60, "test_solvers.py": 60,
     "test_multishift_resident.py": 60, "test_multishift.py": 60,
     "test_metrics.py": 50, "test_eigcg_gmresdr.py": 50,

@@ -246,6 +246,8 @@ CASES = {
         14, (3, 2, L, L, YXH)),
     "multishift_update_n4_wilson": lambda: _multishift_update(
         4, (4, 3, 2, L, L, YXH)),
+    "multishift_update_n14_wilson": lambda: _multishift_update(
+        14, (4, 3, 2, L, L, YXH)),
     # one case per other operator family the solve API routes to a kernel
     "staggered_eo_v3_f32": lambda: _staggered_eo_v3(F32),
     "staggered_eo_v3_bf16": lambda: _staggered_eo_v3(BF16),
@@ -891,6 +893,102 @@ def test_hisq_multishift_programs_compile_for_v5e(one_chip, program):
     big = [c for c in _hlo_values(hlo, "constant") if c[0] > 2 ** 20]
     assert not big, f"fields baked into the executable: {big}"
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
+@pytest.mark.parametrize("program", ["solve", "verified-exit"])
+def test_clover_multishift_programs_compile_for_v5e(one_chip, program):
+    """The two programs of a multi-shift Wilson-clover call that no
+    other route builds (solvers/program.py on the resident f32
+    DiracCloverPCPairs, fourteen shifts at 24^4; its prepare is the
+    single-source case of ``prepare_normal_pairs``) compile for the
+    described chip on abstract operands: links, blocks AND shifts are
+    parameters (other offsets, kappa or csw are the same executable);
+    the loop takes the normal-equations side of ``_multishift_program``
+    (``hermitian`` False in its key): the two fused single-source f32
+    kernels twice an iteration, and updates its live shifts in place on
+    two 223 MB stacks (neither fits the chip's 128 MiB of on-chip
+    memory); the exit applies the fused MRHS kernels to the fourteen
+    solutions as one batch, which ``mrhs_route`` serves on the full-Z
+    route at N = 14 as at 8 (three spinor operands).  The solve
+    program's HBM need is asserted under 8 GiB as an upper bound: such
+    bounds over-state (PERF.md section 7 (40))."""
+    import re
+    from quda_tpu.fields.geometry import LatticeGeometry
+    from quda_tpu.models.clover import DiracCloverPCPairs
+    from quda_tpu.solvers import program as sprog
+    geom = LatticeGeometry(DIMS)
+    half = (L, L, YXH)
+    n = 14
+
+    def operator(links_e, links_o, a_p, ainv_q):
+        return DiracCloverPCPairs.from_packed(
+            geom, (links_e, links_o), 0.32, 0, a_p, ainv_q, F32,
+            use_pallas=True, pallas_interpret=False, form="pallas")
+
+    def lower():
+        lk = jax.ShapeDtypeStruct((4, 3, 3) + half, jnp.complex64)
+        bk = jax.ShapeDtypeStruct((2, 6, 6) + half, jnp.complex64)
+        op = jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(
+                s.shape, s.dtype, sharding=one_chip,
+                weak_type=s.weak_type),
+            jax.eval_shape(operator, lk, lk, bk, bk))
+        assert sprog.presents(op) and not getattr(op, "hermitian", False)
+        assert op._mrhs_form() == "pallas"
+        b = jax.ShapeDtypeStruct(*_psi(F32), sharding=one_chip)
+        per_shift = lambda dt: jax.ShapeDtypeStruct((n,), dt,
+                                                    sharding=one_chip)
+        if program == "solve":
+            # the update's form as multishift.update_form reads it on
+            # a TPU backend (here the backend is the CPU): the kernel
+            key = (sprog._LoopKnobs(False, None, None, None), False,
+                   "pallas")
+            return sprog._multishift_program.lower(
+                op, b, per_shift(F32), 1e-6, 10000, key=key)
+        x = jax.ShapeDtypeStruct(*_psi(F32, (n,)), sharding=one_chip)
+        return sprog._verified_exit_shifts_program.lower(
+            op, b, x, per_shift(F32), per_shift(jnp.bool_),
+            per_shift(F32), 1e-4)
+    compiled = _aot(lower)
+    hlo = compiled.as_text()
+    calls = sorted(re.findall(r"%(dslash_eo_pallas\w*?)[.\d]* = \(?f32"
+                              r"\[[^\n]*tpu_custom_call", hlo))
+    single = ["dslash_eo_pallas_diag_hop", "dslash_eo_pallas_post"]
+    assert calls == sorted(2 * ([s + "_mrhs" for s in single]
+                                if program == "verified-exit"
+                                else single)), calls
+    if program == "solve":
+        # the live shifts' update is ONE kernel on the carried stacks
+        # and nothing the size of a stack is copied
+        assert len(re.findall(r"%multishift_update_pallas[.\d]* = \("
+                              r"[^\n]*tpu_custom_call", hlo)) == 1
+        stack = L * L * YXH * 24 * n * 4
+        assert not [c for c in _hlo_values(hlo, "copy") if c[0] >= stack]
+        assert "conditional(" not in hlo
+    else:
+        # the fused MRHS kernels took the full-Z route at N = 14:
+        # three psi operands (six and eight operands in all), not five
+        operands = sorted(c.count("%") for c in re.findall(
+            r"%dslash_eo_pallas_(?:post|diag_hop)_mrhs[.\d]* = \(?f32\["
+            r"[^\n]*custom-call\(([^\n]*?)\), "
+            r"custom_call_target=\"tpu_custom_call\"", hlo))
+        assert operands == [6, 6, 8, 8], operands
+    params = _hlo_values(hlo, "parameter")
+    links = ",".join(str(d) for d in _links(F32)[0])
+    blocks = ",".join(str(d) for d in (2, 6, 6, 2, L, L, YXH))
+    assert sum(p[1:] == ("f32", links) for p in params) >= 2
+    assert sum(p[1:] == ("f32", blocks) for p in params) == 2
+    assert sum(p[1:] == ("f32", str(n)) for p in params) >= 1
+    big = [c for c in _hlo_values(hlo, "constant") if c[0] > 2 ** 20]
+    assert not big, f"fields baked into the executable: {big}"
+    ma = compiled.memory_analysis()
+    need = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes)
+    print(f"clover multishift {program}: arguments "
+          f"{ma.argument_size_in_bytes / 2**30:.2f} GiB, outputs "
+          f"{ma.output_size_in_bytes / 2**30:.2f}, temporaries "
+          f"{ma.temp_size_in_bytes / 2**30:.2f}")
+    assert need < 8 * 2 ** 30, ma
 
 
 MOBIUS_LS = 12

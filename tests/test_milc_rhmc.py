@@ -159,6 +159,33 @@ def test_clover_family(ctx):
     assert np.allclose(np.asarray(f), np.asarray(dagger(f)), atol=1e-12)
 
 
+def test_clover_multishift_is_the_api_call(ctx):
+    """qudaCloverMultishiftInvert goes through invert_multishift_quda:
+    the API's solutions and per-shift numbers, shift by shift (here in
+    double precision, the API's canonical branch; the resident route in
+    single precision is tests/test_clover_multishift_resident.py's)."""
+    from quda_tpu.fields.spinor import ColorSpinorField
+    from quda_tpu.interfaces.params import InvertParam
+    milc.qudaLoadGauge(ctx, GEOM.dims)
+    b = ColorSpinorField.gaussian(jax.random.PRNGKey(21), GEOM).data
+    offsets = (0.01, 0.1, 1.0)
+    xs, info = milc.qudaCloverMultishiftInvert(0.12, 1.0, offsets, b,
+                                               tol=1e-9, maxiter=500)
+    p = InvertParam(dslash_type="clover", kappa=0.12, csw=1.0,
+                    inv_type="multi-shift-cg", solve_type="normop-pc",
+                    tol=1e-9, maxiter=500, cuda_prec="double",
+                    num_offset=3, offset=offsets)
+    want = api.invert_multishift_quda(b, p)
+    assert xs.shape == (3,) + GEOM.lattice_shape[:3] + (2, 4, 3)
+    np.testing.assert_array_equal(np.asarray(xs), np.asarray(want))
+    assert info == {"iters": p.iter_count,
+                    "true_res_offset": list(p.true_res_offset),
+                    "iter_res_offset": list(p.iter_res_offset),
+                    "iter_count_offset": list(p.iter_count_offset),
+                    "converged": [True] * 3}
+    assert max(info["true_res_offset"]) < 1e-8 and info["iters"] > 5
+
+
 def test_oprod_shapes(ctx):
     qs = jnp.stack([_stag_source(30)[..., 0, :],
                     _stag_source(31)[..., 0, :]])
