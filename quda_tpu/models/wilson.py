@@ -893,32 +893,57 @@ class _SchurPairOpBase(_PackedHopMixin, _PairSloppyBase):
         """-> (blk_pl or None, diag_twist c or None)."""
         raise NotImplementedError
 
+    def _count_route(self, form, stage, epilogue="none"):
+        """clover_route_total, counted where a single-source ``M`` is
+        traced: the ``_count_mrhs`` labels without a route (one source
+        is z-blocks, always)."""
+        from ..obs import metrics as omet
+        omet.inc("clover_route_total", form=form, stage=stage,
+                 epilogue=epilogue)
+
+    def _M_sign_fused(self, x, sign, **epilogue):
+        """``M(sign) x`` by the two fused kernels, at the storage dtype.
+        ``epilogue``: ``g5``, ``nrm``, ``rc`` / ``alpha`` of
+        ops/clover_pallas.dslash_eo_pallas_diag_hop, the second kernel;
+        with ``nrm`` or ``rc`` the result is the pair (spinor, its
+        squared norm)."""
+        from ..ops import clover_pallas as clp
+        p = self.matpc
+        k1_blk, k1_twist = self._fused_k1_params(sign)
+        k2_blk, k2_twist = self._fused_k2_params(sign)
+        dims = tuple(self.dims)
+        itp = self._pallas_interpret
+        bz = getattr(self, "_block_z", None)
+        self._count_route("pallas", "post")
+        # K1: Ainv_q(D_{q<-p} x) in one pass; the hop accumulator
+        # rounds to store_dtype through the out-tile read-back, so
+        # the staged rounding of the XLA composition is preserved
+        t = clp.dslash_eo_pallas_post(
+            self.gauge_eo_pp[1 - p], self._u_bw[1 - p], x, dims,
+            1 - p, blk_pl=k1_blk, twist=k1_twist, interpret=itp,
+            block_z=bz, out_dtype=self.store_dtype,
+            tb_sign=self._tb_sign)
+        self._count_route(
+            "pallas", "diag_hop",
+            "residual" if epilogue.get("rc") is not None
+            else "norm2" if epilogue.get("nrm") else "combine")
+        # K2: diag_p(x) - kappa^2 D_{p<-q} t, combined in f32 (the hop
+        # sum in the f32 out tile, or in an f32 scratch under a
+        # narrower one) and rounded to storage once, in the store, as
+        # the staged composition rounds at its boundary
+        return clp.dslash_eo_pallas_diag_hop(
+            self.gauge_eo_pp[p], self._u_bw[p], t, x, dims, p,
+            hop_coeff=-(self.kappa ** 2), blk_pl=k2_blk,
+            diag_twist=k2_twist, interpret=itp, block_z=bz,
+            out_dtype=self.store_dtype, tb_sign=self._tb_sign, **epilogue)
+
     def _M_sign_pairs(self, x, sign, form=None):
         p = self.matpc
-        if (form or self._op_form) == "pallas":
-            from ..ops import clover_pallas as clp
-            k1_blk, k1_twist = self._fused_k1_params(sign)
-            k2_blk, k2_twist = self._fused_k2_params(sign)
-            dims = tuple(self.dims)
-            itp = self._pallas_interpret
-            bz = getattr(self, "_block_z", None)
-            # K1: Ainv_q(D_{q<-p} x) in one pass; the hop accumulator
-            # rounds to store_dtype through the out-tile read-back, so
-            # the staged rounding of the XLA composition is preserved
-            t = clp.dslash_eo_pallas_post(
-                self.gauge_eo_pp[1 - p], self._u_bw[1 - p], x, dims,
-                1 - p, blk_pl=k1_blk, twist=k1_twist, interpret=itp,
-                block_z=bz, out_dtype=self.store_dtype,
-                tb_sign=self._tb_sign)
-            # K2: diag_p(x) - kappa^2 D_{p<-q} t, f32 out (lossless
-            # read-back), cast to storage at the boundary as the
-            # staged composition does
-            out = clp.dslash_eo_pallas_diag_hop(
-                self.gauge_eo_pp[p], self._u_bw[p], t, x, dims, p,
-                hop_coeff=-(self.kappa ** 2), blk_pl=k2_blk,
-                diag_twist=k2_twist, interpret=itp, block_z=bz,
-                out_dtype=jnp.float32, tb_sign=self._tb_sign)
-            return out.astype(self.store_dtype)
+        form = form or self._op_form
+        if form == "pallas":
+            return self._M_sign_fused(x, sign)
+        self._count_route(form, "post")
+        self._count_route(form, "diag_hop", "combine")
         t = self._d_to(x, 1 - p, self.store_dtype)
         t = self._Ainv_q_sign_pairs(t, sign, self.store_dtype)
         dd = self._d_to(t, p, jnp.float32)
@@ -934,6 +959,36 @@ class _SchurPairOpBase(_PackedHopMixin, _PairSloppyBase):
 
     def MdagM_pairs(self, x):
         return self.Mdag_pairs(self.M_pairs(x))
+
+    @property
+    def MdagM_cg_step_pairs(self):
+        """The first half of a mixed-precision CG iteration on MdagM,
+        what solvers/mixed.cg_reliable_loop applies, where the operator
+        is served by its fused kernels (``MdagM_cg_step_pairs_mrhs``
+        for one source, in any storage); None everywhere else (the
+        ``xla`` form, so also a mesh or ``use_pallas`` off), and the
+        solve program then takes solvers/mixed.cg_step of
+        ``MdagM_pairs`` in the one loop, as for every other family."""
+        return self._MdagM_cg_step_fused if self._op_form == "pallas" \
+            else None
+
+    def _MdagM_cg_step_fused(self, p, r, r2, k=None):
+        """From the search direction ``p``, the sloppy residual ``r``
+        and its ``|r|^2`` ``r2`` to ``(r - alpha MdagM p, its squared
+        norm, alpha, pAp)``, in storage, out of the fused kernels'
+        epilogue (a K2 call that stores narrower than f32 keeps its hop
+        sum in f32 on chip).  ``q = g5 M(+s) p`` is stored once,
+        rounded, with ``pAp = |q|^2`` summed from the values as stored;
+        the second ``M``'s K2 call writes ``r - alpha g5 M(-s) q`` in
+        ``r``'s place, rounded once from f32, and sums it.  ``MdagM p``
+        never reaches HBM and no XLA pass over the vectors makes a
+        cast, a gamma5, the dot, the update of ``r`` or ``|r|^2``.
+        ``k``, the iteration, is the generic step's (an armed fault)."""
+        from ..solvers.block import cg_alpha
+        q, pAp = self._M_sign_fused(p, +1, g5=True, nrm=True)
+        alpha = cg_alpha(r2, pAp)
+        r, r2 = self._M_sign_fused(q, -1, g5=True, rc=r, alpha=alpha)
+        return r, r2, alpha, pAp
 
     # -- multi-RHS forms ------------------------------------------------
     # The _PairSloppyBase MRHS defaults encode the WILSON composition

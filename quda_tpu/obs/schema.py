@@ -309,6 +309,24 @@ METRICS: dict[str, dict] = {
                 "residual 1; with a dslash fault armed, or in the 'xla' "
                 "form, the loop takes solvers/block.cg_step and every "
                 "diag_hop is 'combine'"},
+    "clover_route_total": {
+        "type": COUNTER,
+        "help": "traced applications of a Schur pair operator to one "
+                "source (models/wilson._SchurPairOpBase._M_sign_pairs "
+                "and the two M of its MdagM_cg_step_pairs), the "
+                "single-source sibling of clover_mrhs_route_total "
+                "under the same labels, without a route (one source is "
+                "z-blocks): by form 'pallas' / 'xla', by stage 'post' / "
+                "'diag_hop', and by epilogue: 'none' on 'post'; on "
+                "'diag_hop' 'combine' (A x - kappa^2 D t), 'norm2' "
+                "(gamma5 in the store and its squares summed: the "
+                "mixed-precision CG's pAp = |g5 M p|^2) or 'residual' "
+                "(r - alpha g5 of that written over r and summed).  "
+                "One cg_reliable program on the fused form counts, for "
+                "its sloppy operator, post 2, norm2 1, residual 1; with "
+                "a dslash fault armed, or in the 'xla' form, the loop "
+                "takes solvers/mixed.cg_step and every diag_hop is "
+                "'combine'"},
     "multishift_shift_total": {
         "type": COUNTER,
         "help": "shifts of invert_multishift_quda calls on the resident "

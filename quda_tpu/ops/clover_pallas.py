@@ -23,7 +23,10 @@ or the twist in the kernel epilogue, never as a second pass):
   whose BlockSpec matches the center spinor block; ``hop_coeff`` is a
   one-element f32 OPERAND in SMEM, not a compiled-in constant, so a
   solve program that takes kappa as an operand (solvers/program.py)
-  serves every mass with one executable.
+  serves every mass with one executable.  The combine is made of f32
+  values: an f32 out tile holds the hop sum, and under a narrower one
+  (the sloppy operator's bf16) the single-source call keeps it in an
+  f32 VMEM scratch and writes the tile once, rounded.
 
 Each of the two is its own jitted function, so a profiler trace names
 the kernel event after it (``dslash_eo_pallas_post.N`` /
@@ -48,17 +51,21 @@ the hop body's loop (``fullz``: the cell's 24^4 with one slice a step
 beside the 144 block planes), or the single-source call's z-blocks and
 five psi operands where those tiles do not fit (``zblock``).
 
-The MRHS K2 call has four forms under its one name
-(``dslash_eo_pallas_diag_hop_mrhs``; call-time keywords, off by
+The K2 call has four forms under its one name, single-source
+(``dslash_eo_pallas_diag_hop``) and MRHS
+(``dslash_eo_pallas_diag_hop_mrhs``) alike (call-time keywords, off by
 default, links then blocks still the last operands), counted by
-``clover_mrhs_route_total{epilogue}``: ``combine``, the value above;
+``clover_route_total{epilogue}`` / ``clover_mrhs_route_total{epilogue}``:
+``combine``, the value above;
 ``norm2``, gamma5 of it in the store (``g5``) and the squares of what
 is stored summed per source into a second, small f32 result (``nrm``);
 ``residual``, ``rc - alpha[n] * g5`` of it written over ``rc`` and
-summed (``rc``, ``alpha``).  With them the first half of a batched CG
+summed (``rc``, ``alpha``).  With them the first half of a CG
 iteration on ``MdagM = g5 M(-s) g5 M(+s)`` is four kernels and no XLA
-pass over the batch (models/wilson
-``_SchurPairOpBase.MdagM_cg_step_pairs_mrhs``, PR 48): ``pAp = |g5 M
+pass over the vectors (models/wilson
+``_SchurPairOpBase.MdagM_cg_step_pairs_mrhs``, PR 48, for a batch in
+f32; ``MdagM_cg_step_pairs``, PR 50, for one source in any storage,
+the mixed-precision CG's bf16 among them): ``pAp = |g5 M
 p|^2`` comes out of the first M's K2 call, the new ``r`` and ``|r|^2``
 out of the second's.  The full-lattice
 ``clover_pallas_packed`` serves the unpreconditioned M = A - kappa D
@@ -154,7 +161,8 @@ def _sum_sq_sc(vals, dtype):
 
 def _epilogue_kernel(X, bz, eo, T, tb_sign, *, xc_mode, with_blk,
                      twist, diag_twist, with_coeff, z_rows="tiles",
-                     g5=False, nrm=False, residual=False):
+                     g5=False, nrm=False, residual=False, src_axis=2,
+                     hop_scratch=False):
     """v2 hop kernel + family epilogue over the out tile.
 
     z_rows: ``"tiles"``, the five psi refs of a (t, z-block) step and
@@ -175,19 +183,26 @@ def _epilogue_kernel(X, bz, eo, T, tb_sign, *, xc_mode, with_blk,
     spinor refs (links, then blocks, stay the last inputs: the
     benchmark's trace reduction names a kernel event by the element
     types of its result, first and LAST operand).
-    g5, nrm, residual (K2 of an MRHS call only, the batched CG's:
-    models/wilson._SchurPairOpBase.MdagM_cg_step_pairs_mrhs): ``g5``
-    negates spin rows 2, 3 of v = diag(x) + hop_coeff * hop before the
-    store (a sign: bit-exact against a gamma5 pass over the stored
-    values); ``residual`` brings ``rc`` (a spinor block on the centre
-    spec, after ``xc``) and ``alpha`` (one f32 a source in SMEM, after
-    ``hop_coeff``; the source is the grid's axis 2, read outside the
-    chunk loop) and the store writes ``rc - alpha[n] * [g5] v``;
-    ``nrm`` gives the kernel a second, small f32 output after the
-    spinor's, one (BZ, YXh) block a grid step, zeroed here: the sum
-    over the step's planes (and chunks) of the squares of what it
-    stores, after the rounding to the out dtype, which the caller sums
-    per source (wilson_pallas_packed._make_kernel's combine epilogue).
+    g5, nrm, residual (the K2 stage, a CG step's:
+    models/wilson._SchurPairOpBase.MdagM_cg_step_pairs and its
+    ``_mrhs`` twin): ``g5`` negates spin rows 2, 3 of v = diag(x) +
+    hop_coeff * hop before the store (a sign: bit-exact against a
+    gamma5 pass over the stored values); ``residual`` brings ``rc`` (a
+    spinor block on the centre spec, after ``xc``) and ``alpha`` (one
+    f32 a source in SMEM, after ``hop_coeff``; the source is the
+    grid's axis ``src_axis``, None for a single-source call and its
+    one alpha; read outside the chunk loop) and the store writes ``rc
+    - alpha[n] * [g5] v``; ``nrm`` gives the kernel a second, small
+    f32 output after the spinor's, one (BZ, YXh) block a grid step,
+    zeroed here: the sum over the step's planes (and chunks) of the
+    squares of what it stores, after the rounding to the out dtype,
+    which the caller sums per source
+    (wilson_pallas_packed._make_kernel's combine epilogue).
+    hop_scratch (``tiles`` only): the last ref is an f32 VMEM scratch
+    of the out tile's shape that takes the hop sum in the out tile's
+    place, so that an out tile narrower than f32 is written once, from
+    f32 values: ``round([g5] (diag(x) + c * hop))``, bit for bit what
+    a cast of the f32 call's result stores.
     """
     from jax.experimental import pallas as pl
 
@@ -196,6 +211,9 @@ def _epilogue_kernel(X, bz, eo, T, tb_sign, *, xc_mode, with_blk,
     n_psi = 3 if z_rows == "centre" else 5
 
     def kernel(*refs):
+        hop_ref = None
+        if hop_scratch:
+            *refs, hop_ref = refs
         k = n_psi
         xc_ref = None
         if xc_mode == "input":
@@ -213,7 +231,8 @@ def _epilogue_kernel(X, bz, eo, T, tb_sign, *, xc_mode, with_blk,
             k += 1
         alpha = None
         if residual:
-            alpha = refs[k][pl.program_id(2)]
+            alpha = refs[k][0 if src_axis is None
+                            else pl.program_id(src_axis)]
             k += 1
         g_c, g_m = refs[k], refs[k + 1]
         blk_ref = refs[k + 2] if with_blk else None
@@ -221,8 +240,8 @@ def _epilogue_kernel(X, bz, eo, T, tb_sign, *, xc_mode, with_blk,
         if nrm:
             nrm_ref[...] = jnp.zeros(nrm_ref.shape, F32)
 
-        def epilogue(out_ref, xc_ref, blk_ref, rc_ref=None):
-            hop = _load_sc(out_ref)
+        def epilogue(out_ref, xc_ref, blk_ref, rc_ref=None, hop_ref=None):
+            hop = _load_sc(out_ref if hop_ref is None else hop_ref)
             if not with_coeff:
                 v = _blk_mul(blk_ref, hop) if with_blk else hop
                 if twist is not None:
@@ -253,8 +272,9 @@ def _epilogue_kernel(X, bz, eo, T, tb_sign, *, xc_mode, with_blk,
             base(*refs[:3], g_c, g_m, out_ref,
                  epilogue=(epilogue, (xc_ref, blk_ref, rc_ref)))
         else:
-            base(*refs[:5], g_c, g_m, out_ref)
-            epilogue(out_ref, xc_ref, blk_ref, rc_ref)
+            base(*refs[:5], g_c, g_m,
+                 out_ref if hop_ref is None else hop_ref)
+            epilogue(out_ref, xc_ref, blk_ref, rc_ref, hop_ref)
 
     return kernel
 
@@ -353,14 +373,22 @@ def _fused_eo_call(u_here_pl, u_bw_pl, psi_pl, xc_pl, blk_pl, coeff, dims,
     for it.  Per source the two bit-match each other and the
     single-source kernel.
 
-    ``g5``, ``nrm``, ``rc_pl`` / ``alpha`` (the K2 stage of an MRHS
-    call): ``_epilogue_kernel``'s gamma5 store, sums of squares and
-    residual form.  With ``nrm`` or ``rc_pl`` the call returns ``(v,
-    |v|^2 per source)``, v what it wrote: the kernel's second output
-    holds one block of f32 partial sums a grid step, (T/bt, Z/bz, N,
-    rows, YXh), and XLA sums those few KB a source to (N,) f32.  The
+    ``g5``, ``nrm``, ``rc_pl`` / ``alpha`` (the K2 stage):
+    ``_epilogue_kernel``'s gamma5 store, sums of squares and residual
+    form.  With ``nrm`` or ``rc_pl`` the call returns ``(v, |v|^2 per
+    source)``, v what it wrote: the kernel's second output holds one
+    block of f32 partial sums a grid step, (T/bt, Z/bz, N, rows, YXh),
+    and XLA sums those few KB a source to (N,) f32; a single-source
+    call has no N, one ``alpha`` ((1,) f32) and one f32 sum.  The
     residual form writes over ``rc_pl``'s own buffer (each step reads
-    and writes the same centre block of it) where the types agree."""
+    and writes the same centre block of it) where the types agree.
+
+    Where a single-source K2 call stores narrower than f32 the hop sum
+    stays on chip in an f32 VMEM scratch (24 planes of a z-block, not
+    double-buffered) and the out tile is written once, rounded from the
+    f32 combine: bit for bit the cast of the f32 call's result, without
+    the f32 array in HBM and XLA's pass over it.  An f32 call and every
+    MRHS call read the hop sum back from the out tile as before."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -390,10 +418,13 @@ def _fused_eo_call(u_here_pl, u_bw_pl, psi_pl, xc_pl, blk_pl, coeff, dims,
     else:
         route, bt, vmem_limit = "zblock", 1, None
         bz = block_z if block_z is not None else wpp._pick_bz(
-            Z, YXh, psi_pl.dtype, planes=_planes(R, xc_mode, with_blk))
+            Z, YXh, psi_pl.dtype, planes=_planes(R, xc_mode, with_blk,
+                                                 residual))
         if Z % bz != 0:
             raise ValueError(f"block_z={bz} does not divide Z={Z}")
     nzb = Z // bz
+    hop_scratch = (not mrhs and coeff is not None
+                   and jnp.dtype(odt).itemsize < 4)
     lead = (1,) if mrhs else ()
     if route == "fullz":
         z_rows, body_rows = "centre", wpp._fullz_chunk(Z, psi_pl.dtype)
@@ -435,7 +466,9 @@ def _fused_eo_call(u_here_pl, u_bw_pl, psi_pl, xc_pl, blk_pl, coeff, dims,
                               xc_mode=xc_mode, with_blk=with_blk,
                               twist=twist, diag_twist=diag_twist,
                               with_coeff=coeff is not None, z_rows=z_rows,
-                              g5=g5, nrm=nrm, residual=residual)
+                              g5=g5, nrm=nrm, residual=residual,
+                              src_axis=2 if mrhs else None,
+                              hop_scratch=hop_scratch)
 
     operands = [psi_pl] * len(in_specs)
     if xc_mode == "input":
@@ -466,12 +499,12 @@ def _fused_eo_call(u_here_pl, u_bw_pl, psi_pl, xc_pl, blk_pl, coeff, dims,
     out_shape = jax.ShapeDtypeStruct(psi_pl.shape, odt)
     if nrm:
         # one (rows, YXh) block of partial sums a grid step
-        N = psi_pl.shape[0]
+        src = (psi_pl.shape[0],) if mrhs else ()
         out_specs = [out_specs, pl.BlockSpec(
-            (None, None, None, body_rows, YXh),
-            lambda tb, zb, n: (tb, zb, n, 0, 0))]
+            (None,) * (2 + len(src)) + (body_rows, YXh),
+            lambda tb, zb, *n: (tb, zb) + n + (0, 0))]
         out_shape = [out_shape, jax.ShapeDtypeStruct(
-            (T // bt, nzb, N, body_rows, YXh), F32)]
+            (T // bt, nzb) + src + (body_rows, YXh), F32)]
 
     out = pl.pallas_call(
         kernel,
@@ -479,6 +512,8 @@ def _fused_eo_call(u_here_pl, u_bw_pl, psi_pl, xc_pl, blk_pl, coeff, dims,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((4, 3, 2, 1, bz, YXh), F32)]
+        if hop_scratch else (),
         input_output_aliases=aliases,
         interpret=interpret,
         name=name,
@@ -487,7 +522,7 @@ def _fused_eo_call(u_here_pl, u_bw_pl, psi_pl, xc_pl, blk_pl, coeff, dims,
     )(*operands)
     if not nrm:
         return out
-    return out[0], jnp.sum(out[1], axis=(0, 1, 3, 4))
+    return out[0], jnp.sum(out[1], axis=(0, 1, 3, 4) if mrhs else None)
 
 
 # -- public entry points ----------------------------------------------------
@@ -517,25 +552,37 @@ def dslash_eo_pallas_post(u_here_pl, u_bw_pl, psi_pl, dims,
                           out_dtype=out_dtype, tb_sign=tb_sign)
 
 
-@functools.partial(jax.jit, static_argnames=_DIAG_HOP_STATIC)
+@functools.partial(jax.jit,
+                   static_argnames=_DIAG_HOP_STATIC + ("g5", "nrm"))
 def dslash_eo_pallas_diag_hop(u_here_pl, u_bw_pl, psi_pl, xc_pl, dims,
                               target_parity, *, hop_coeff, blk_pl=None,
                               diag_twist=None, interpret=False,
                               block_z=None, out_dtype=None,
-                              tb_sign=True):
+                              tb_sign=True, g5=False, nrm=False, rc=None,
+                              alpha=None):
     """diag(x) + hop_coeff * D_{p<-q} psi in one VMEM pass — the K2
     stage: diag(x) = blk x (+ i c g5 x with ``diag_twist=c``), x riding
     a sixth psi-layout operand whose BlockSpec is the center block;
     ``hop_coeff`` a float or an f32 scalar array (an operand either
-    way).  Pass out_dtype=f32 so the hop read-back loses nothing before
-    the f32 combine (the caller casts the final result to storage)."""
+    way).  The combine is made of f32 values whatever ``out_dtype``:
+    an f32 out tile holds the hop sum itself, a narrower one is
+    written once, rounded, from an f32 scratch (``_fused_eo_call``).
+    ``g5``, ``nrm``, ``rc`` / ``alpha`` as the ``_mrhs`` twin has them,
+    call-time and under this one name: gamma5 in the store; ``(v, its
+    f32 squared norm as stored)`` (the ``norm2`` form); ``rc - alpha *
+    [g5] v`` written in ``rc``'s place and summed, ``alpha`` one f32
+    (the ``residual`` form): the first half of a mixed-precision CG
+    iteration (models/wilson ``_SchurPairOpBase.MdagM_cg_step_pairs``)."""
+    if rc is not None:
+        alpha = jnp.asarray(alpha, F32).reshape(1)
     return _fused_eo_call(u_here_pl, u_bw_pl, psi_pl, xc_pl, blk_pl,
                           _coeff_operand(hop_coeff), tuple(dims),
                           target_parity,
                           name="dslash_eo_pallas_diag_hop",
                           diag_twist=diag_twist, interpret=interpret,
                           block_z=block_z, out_dtype=out_dtype,
-                          tb_sign=tb_sign)
+                          tb_sign=tb_sign, g5=g5, nrm=nrm, rc_pl=rc,
+                          alpha=alpha)
 
 
 @functools.partial(jax.jit, static_argnames=_POST_STATIC)

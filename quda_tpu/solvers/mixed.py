@@ -16,7 +16,10 @@ so the codecs below are unchanged.  Two strategies are provided:
   the sloppy precision inside one lax.while_loop; when the sloppy residual
   falls below ``delta`` * (max residual since the last update), recompute the
   true residual with the precise operator and re-inject it (lax.cond keeps
-  this branch-free for XLA).  The whole solve is ONE compiled computation.
+  this branch-free for XLA; where the sloppy operator owns the CG step the
+  update stands between two stretches of an inner while_loop instead, so
+  the iterations' vectors can stay on chip).  The whole solve is ONE
+  compiled computation.
 
 * ``solve_refined``: outer defect-correction (iterative refinement) driving
   any inner solver — the pattern QUDA calls refinement in multi-shift
@@ -31,6 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import blas
+from .block import cg_alpha
 from .cg import SolverResult, cg
 
 
@@ -152,22 +156,68 @@ def cg_reliable(matvec_hi: Callable, matvec_lo: Callable, b: jnp.ndarray,
     # exact unguarded computation
     from ..robust import faultinject as finj
     from ..robust import sentinel as rsent
-    return cg_reliable_loop(matvec_hi, matvec_lo, b, tol, maxiter, delta,
-                            codec, record, rsent.make(),
-                            finj.iteration_fault("dslash"))
+    step = cg_step(matvec_lo, codec, finj.iteration_fault("dslash"))
+    return cg_reliable_loop(matvec_hi, step, b, tol, maxiter, delta,
+                            codec, record, rsent.make())
 
 
-def cg_reliable_loop(matvec_hi: Callable, matvec_lo: Callable,
-                     b: jnp.ndarray, tol, maxiter, delta: float,
-                     codec: StorageCodec, record: bool, sent,
-                     fault_k: Optional[int]) -> SolverResult:
-    """``cg_reliable`` with every knob already resolved by the caller
-    (``sent``: robust/sentinel.Sentinel or None; ``fault_k``: the armed
-    dslash fault iteration or None), so nothing here reads host state:
-    the body the cached solve program (solvers/program.py) traces once
-    per key.  ``tol`` and ``maxiter`` may be traced scalars, except
-    that ``record`` sizes the history by a concrete ``maxiter``."""
+def cg_step(matvec_lo: Callable, codec: StorageCodec,
+            fault_k: Optional[int] = None) -> Callable:
+    """The generic first half of a ``cg_reliable_loop`` iteration on a
+    sloppy matvec: ``step(p, r_lo, r2_lo, k) -> (r_lo - alpha A p, its
+    squared norm, alpha, p . A p)``, ``A p`` stored, the dot, the
+    update and the sum the codec's (XLA passes over the stored
+    vectors), the scalars at ``r2_lo``'s dtype.  ``fault_k``: the armed
+    dslash fault iteration or None; the fault corrupts ``A p``, which
+    only this step has."""
     from ..robust import faultinject as finj
+
+    def step(p, r_lo, r2_lo, k):
+        rdt = r2_lo.dtype
+        Ap = matvec_lo(p)
+        if fault_k is not None:
+            Ap = finj.corrupt(Ap, k, fault_k)
+        pAp = codec.redot(p, Ap).astype(rdt)
+        alpha = cg_alpha(r2_lo, pAp)
+        # fused residual update+reduce: one traversal (optionally the
+        # single-pass pallas kernel, see StorageCodec.axpy_norm2)
+        if codec.axpy_norm2 is not None:
+            r_lo, r2_new = codec.axpy_norm2(-alpha, Ap, r_lo)
+        else:
+            r_lo = codec.axpy(-alpha, Ap, r_lo)
+            r2_new = codec.norm2(r_lo)
+        return r_lo, r2_new.astype(rdt), alpha, pAp
+    return step
+
+
+def cg_reliable_loop(matvec_hi: Callable, step: Callable,
+                     b: jnp.ndarray, tol, maxiter, delta: float,
+                     codec: StorageCodec, record: bool,
+                     sent, stretches: bool = False) -> SolverResult:
+    """``cg_reliable`` with every knob already resolved by the caller
+    (``sent``: robust/sentinel.Sentinel or None), so nothing here reads
+    host state: the body the cached solve program (solvers/program.py)
+    traces once per key.  ``tol`` and ``maxiter`` may be traced
+    scalars, except that ``record`` sizes the history by a concrete
+    ``maxiter``.
+
+    ``step(p, r_lo, r2_lo, k) -> (r_lo - alpha A p, its squared norm,
+    alpha, p . A p)`` is the first half of an iteration on the sloppy
+    operator, in storage: ``cg_step`` of a matvec and the codec (with
+    the armed dslash fault, if any), or the operator's own where its
+    kernels make those four on the way (models/wilson
+    ``_SchurPairOpBase.MdagM_cg_step_pairs``: ``A p`` is never stored
+    and no pass over the vectors makes a dot or a sum).  The loop keeps
+    ``x_lo``, ``beta``, ``p``, the sentinel, the reliable update on
+    ``matvec_hi`` and the exit, as ``block.batched_cg_pairs_loop``
+    does for a batch.
+
+    ``stretches``: the same arithmetic in the same order as two nested
+    loops (``_cg_reliable_stretches``), where the caller's step is the
+    operator's own."""
+    if stretches:
+        return _cg_reliable_stretches(matvec_hi, step, b, tol, maxiter,
+                                      delta, codec, record, sent)
     from ..robust import sentinel as rsent
     b2 = blas.norm2(b)
     stop = (tol ** 2) * b2
@@ -187,20 +237,9 @@ def cg_reliable_loop(matvec_hi: Callable, matvec_lo: Callable,
         return go
 
     def body(c):
-        Ap = matvec_lo(c["p"])
-        if fault_k is not None:
-            Ap = finj.corrupt(Ap, c["k"], fault_k)
-        pAp = codec.redot(c["p"], Ap).astype(rdt)
-        alpha = c["r2_lo"] / jnp.maximum(pAp, jnp.finfo(rdt).tiny)
+        r_lo, r2_new, alpha, pAp = step(c["p"], c["r_lo"], c["r2_lo"],
+                                        c["k"])
         x_lo = codec.axpy(alpha, c["p"], c["x_lo"])
-        # fused residual update+reduce: one traversal (optionally the
-        # single-pass pallas kernel, see StorageCodec.axpy_norm2)
-        if codec.axpy_norm2 is not None:
-            r_lo, r2_new = codec.axpy_norm2(-alpha, Ap, c["r_lo"])
-            r2_new = r2_new.astype(rdt)
-        else:
-            r_lo = codec.axpy(-alpha, Ap, c["r_lo"])
-            r2_new = codec.norm2(r_lo).astype(rdt)
         beta = r2_new / c["r2_lo"]
         p = codec.axpy(beta, c["p"], r_lo)
         r2max = jnp.maximum(c["r2max"], r2_new)
@@ -259,6 +298,99 @@ def cg_reliable_loop(matvec_hi: Callable, matvec_lo: Callable,
             else None)
     conv, bk = rsent.finalize(sent, out.get("sent"), r2_fin <= stop)
     return SolverResult(x_fin, out["k"], r2_fin, conv, hist, bk)
+
+
+def _cg_reliable_stretches(matvec_hi, step, b, tol, maxiter, delta,
+                           codec, record, sent) -> SolverResult:
+    """``cg_reliable_loop`` as two nested loops, the same arithmetic in
+    the same order: the inner runs sloppy iterations until one asks for
+    a reliable update (``due``) or ends the solve, the outer makes that
+    update and goes on.  No conditional anywhere: a ``lax.cond`` in the
+    iterations' loop sends every vector it carries through HBM each
+    iteration and keeps XLA from holding them on chip, and an
+    operator's own step, with no XLA pass left between its kernels,
+    earns nothing end to end under one (PERF.md section 6, PR 50).  A
+    stretch that ends the solve without asking (``maxiter``, the
+    sentinel) gets the update too: one precise matvec more at the end
+    of a solve that failed, ``x`` folded where the exit would fold it,
+    the same ``SolverResult``.  The loops on the generic step keep the
+    one-loop form, which the benchmark's loop readers count right
+    (PERF.md section 7 (44))."""
+    from ..robust import sentinel as rsent
+    b2 = blas.norm2(b)
+    stop = (tol ** 2) * b2
+    rdt = jnp.zeros((), b.dtype).real.dtype
+    r2 = b2.astype(rdt)
+    r_lo = codec.down(b)           # the precise residual at x = 0 is b
+
+    def go(c):
+        s = c["lo"]
+        ok = jnp.logical_and(s["r2"] > stop, s["k"] < maxiter)
+        if sent is not None:
+            ok = jnp.logical_and(ok, sent.ok(s["sent"]))
+        return ok
+
+    def sloppy(s):
+        r_lo, r2_new, alpha, pAp = step(s["p"], s["r_lo"], s["r2_lo"],
+                                        s["k"])
+        x_lo = codec.axpy(alpha, s["p"], s["x_lo"])
+        beta = r2_new / s["r2_lo"]
+        p = codec.axpy(beta, s["p"], r_lo)
+        r2max = jnp.maximum(s["r2max"], r2_new)
+        d = dict(s, p=p, r_lo=r_lo, x_lo=x_lo, r2_lo=r2_new,
+                 r2=r2_new.astype(rdt), r2max=r2max, k=s["k"] + 1,
+                 due=jnp.logical_or(r2_new < (delta ** 2) * r2max,
+                                    r2_new < stop))
+        if record:
+            d["hist"] = s["hist"].at[s["k"]].set(r2_new.astype(rdt))
+        if sent is not None:
+            d["sent"] = sent.step(s["sent"], r2_new, denom=pAp)
+        return d
+
+    def reliable(c):
+        s = c["lo"]
+        x_new = c["x"] + codec.up(s["x_lo"])
+        r_true = b - matvec_hi(x_new)
+        # compensated, and the direction restarted, as in the one loop
+        r2_true = blas.norm2_comp(r_true).astype(rdt)
+        r_lo = codec.down(r_true)
+        d = dict(c, x=x_new, lo=dict(
+            s, r_lo=r_lo, p=r_lo, x_lo=jnp.zeros_like(r_lo), r2=r2_true,
+            r2_lo=r2_true, r2max=r2_true, due=jnp.bool_(False)))
+        if record:
+            # the update belongs to the iteration that asked for it
+            # (none did where the stretch ended the solve)
+            at = s["k"] - 1
+            d["lo"]["hist"] = s["hist"].at[at].set(
+                jnp.where(s["due"], r2_true, s["hist"][at]))
+            d["rel"] = c["rel"].at[at].set(s["due"])
+        return d
+
+    def stretch(c):
+        lo = jax.lax.while_loop(
+            lambda s: jnp.logical_and(go({"lo": s}),
+                                      jnp.logical_not(s["due"])),
+            sloppy, c["lo"])
+        return reliable(dict(c, lo=lo))
+
+    lo = dict(r_lo=r_lo, p=r_lo, x_lo=jnp.zeros_like(r_lo), r2=r2,
+              r2_lo=r2, r2max=r2, k=jnp.int32(0), due=jnp.bool_(False))
+    init = dict(x=jnp.zeros_like(b), lo=lo)
+    if record:
+        lo["hist"] = jnp.full((maxiter + 1,), jnp.nan, rdt)
+        init["rel"] = jnp.zeros((maxiter + 1,), bool)
+    if sent is not None:
+        lo["sent"] = sent.init(r2)
+    out = jax.lax.while_loop(go, stretch, init)
+    lo = out["lo"]
+    # final fold of any un-injected sloppy contribution
+    x_fin = out["x"] + codec.up(lo["x_lo"])
+    r_fin = b - matvec_hi(x_fin)
+    r2_fin = blas.norm2_comp(r_fin)
+    hist = ({"r2": lo["hist"], "reliable": out["rel"]} if record
+            else None)
+    conv, bk = rsent.finalize(sent, lo.get("sent"), r2_fin <= stop)
+    return SolverResult(x_fin, lo["k"], r2_fin, conv, hist, bk)
 
 
 def cg_reliable_df(op_df, matvec_lo: Callable, rhs_df, codec: StorageCodec,

@@ -46,22 +46,29 @@ operators) keeps the eager solver call.  ``cg_reliable`` solves the NORMAL
 equations of a non-Hermitian PC operator (``MdagM_pairs``: Wilson,
 clover) and applies a ``hermitian`` one once an iteration (``M_pairs``:
 the staggered PC operator is already 4m^2 - D D); the batched program
-does the same on ``MdagM_pairs_mrhs`` / ``M_pairs_mrhs``.  Its loop
-takes the first half of an iteration from one callable, ``(p, r, rz,
-k) -> (r - alpha A p, its squared norms, alpha, p . A p)``, and keeps
-the updates of ``x`` and ``p``: the operator's own
-``MdagM_cg_step_pairs_mrhs`` / ``M_cg_step_pairs_mrhs`` where its class
-has one (the Wilson pair operator and, since PR 48, the clover-type
-Schur pair operators on their fused kernels: ``pAp`` is ``|g5 M p|^2``,
-summed in the epilogue of the kernel that stores ``g5 M p``, so
-``alpha`` is known before the last hop, whose epilogue writes ``r -
-alpha A p`` in ``r``'s place and sums it: ``A p`` is never stored),
-else
-``block.cg_step`` of the matvec (XLA's dot, update and sum over the
-batch).  With a dslash fault armed it is ``block.cg_step`` whatever the
-operator offers: the fault corrupts ``A p``, which only that step has.
-Which of the two is the operand's class and static signature and
-``knobs.fault_k``, so the key has no field for it.  With a leading
+does the same on ``MdagM_pairs_mrhs`` / ``M_pairs_mrhs``.  Both loops
+take the first half of an iteration from one callable, ``(p, r, rz,
+k) -> (r - alpha A p, its squared norm(s), alpha, p . A p)``, and keep
+the updates of ``x`` and ``p`` (the single-source loop its reliable
+update too): the operator's own ``MdagM_cg_step_pairs`` /
+``M_cg_step_pairs`` (the sloppy operator's, in its storage; since PR
+50 the clover-type Schur pair operators on their fused kernels) or,
+for a batch, ``MdagM_cg_step_pairs_mrhs`` / ``M_cg_step_pairs_mrhs``
+(the Wilson pair operator and, since PR 48, the Schur pair operators)
+where its class has one: ``pAp`` is ``|g5 M p|^2``, summed in the
+epilogue of the kernel that stores ``g5 M p``, so ``alpha`` is known
+before the last hop, whose epilogue writes ``r - alpha A p`` in
+``r``'s place and sums it: ``A p`` is never stored.  Else
+``mixed.cg_step`` of the matvec and the codec, ``block.cg_step`` for a
+batch (XLA's dot, update and sum).  With a dslash fault armed it is
+the generic step whatever the operator offers: the fault corrupts ``A
+p``, which only that step has.  Which of the two is the operand's
+class and static signature and ``knobs.fault_k``, so the key has no
+field for it.  The single-source loop runs an operator's own step in
+stretches (``mixed._cg_reliable_stretches``: the iterations in a
+``while`` of their own, the reliable update between two of them, no
+``lax.cond``) and the generic step in the one loop it always had, the
+same arithmetic in the same order either way.  With a leading
 source axis ``verified_exit`` and ``prepare`` are the batched route's.
 ``multishift_cg`` is the third loop, on one right-hand side and N
 shifted systems; its exit (``verified_exit_shifts``) takes the N
@@ -115,12 +122,23 @@ def _run(program, *operands, **static):
 def _cg_reliable_program(op_hi, op_lo, b, tol, maxiter, key):
     _traces[0] += 1
     delta, codec_cfg, knobs, hermitian = key
-    mv = "M_pairs" if hermitian else "MdagM_pairs"
-    return mixed.cg_reliable_loop(
-        getattr(op_hi, mv), getattr(op_lo, mv), b, tol,
-        knobs.maxiter if knobs.record else maxiter, delta,
-        mixed.pair_inplace_codec(codec_cfg), knobs.record,
-        knobs.sentinel, knobs.fault_k)
+    mv = "M" if hermitian else "MdagM"
+    codec = mixed.pair_inplace_codec(codec_cfg)
+    step = (getattr(op_lo, mv + "_cg_step_pairs", None)
+            if knobs.fault_k is None else None)
+    own = step is not None
+    if not own:
+        step = mixed.cg_step(getattr(op_lo, mv + "_pairs"), codec,
+                             knobs.fault_k)
+    # the operator's own step in the loop of stretches (no conditional
+    # around its kernels), the generic step in the one loop as before;
+    # traced from a stack chunk of its own, as the multi-shift loop is
+    # (PERF.md section 7 (22))
+    return on_a_stack_chunk_of_its_own(
+        lambda: mixed.cg_reliable_loop(
+            getattr(op_hi, mv + "_pairs"), step, b, tol,
+            knobs.maxiter if knobs.record else maxiter, delta, codec,
+            knobs.record, knobs.sentinel, stretches=own))
 
 
 def cg_reliable(op_hi, op_lo, b, tol: float, maxiter: int, delta: float,
@@ -128,7 +146,9 @@ def cg_reliable(op_hi, op_lo, b, tol: float, maxiter: int, delta: float,
     """``mixed.cg_reliable`` on ``op_hi.MdagM_pairs`` (precise) and
     ``op_lo.MdagM_pairs`` (sloppy storage, the in-place pair codec)
     through the cached program; on ``M_pairs`` where the operators say
-    they are ``hermitian``.  Returns ``(SolverResult, hit)``."""
+    they are ``hermitian``; with the sloppy operator's own
+    ``*_cg_step_pairs`` where it has one and no dslash fault is armed.
+    Returns ``(SolverResult, hit)``."""
     key = (float(delta), mixed.pair_inplace_config(op_lo.store_dtype),
            _loop_knobs(record, maxiter),
            bool(getattr(op_hi, "hermitian", False)))

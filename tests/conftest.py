@@ -148,15 +148,20 @@ def pytest_collection_modifyitems(config, items):
 # own CG step to test_clover_pallas.py; PR 49 added
 # test_clover_multishift_resident.py, ~70 s: three programs and the
 # plain reference on the XLA stencil, no interpreted kernel, and ~10 s
-# of described-chip compiles to test_chip_compile.py).  It
+# of described-chip compiles to test_chip_compile.py; PR 50 ~100 s of
+# the single-source K2 forms and the mixed CG's step to
+# test_clover_pallas.py (seven interpreted kernels in two fixtures:
+# bf16 ~65 s, f32 ~35 s), ~10 s of traced programs to
+# test_clover_resident.py and a ~10 s compile to
+# test_chip_compile.py).  It
 # only orders the hand-out: a stale or missing entry costs balance and
 # nothing else.
 FILE_SECONDS = {
     "test_solve_program.py": 550, "test_multirhs.py": 470,
     "test_multirhs_kernels.py": 400, "test_pallas.py": 360,
     "test_pair_mg.py": 360, "test_staggered_pallas.py": 360,
-    "test_domain_wall.py": 270, "test_clover_resident.py": 280,
-    "test_chip_compile.py": 240, "test_precision_forms.py": 160,
+    "test_domain_wall.py": 270, "test_clover_resident.py": 290,
+    "test_chip_compile.py": 250, "test_precision_forms.py": 160,
     "test_mixed.py": 210, "test_wilson_resident.py": 170,
     "test_interface.py": 170, "test_pair_gauge.py": 170,
     "test_twisted.py": 160, "test_serve.py": 150, "test_pair_eig.py": 130,
@@ -165,7 +170,7 @@ FILE_SECONDS = {
     "test_eig.py": 90, "test_mg_3level.py": 90, "test_milc_rhmc.py": 80,
     "test_mg_gemm_coarse.py": 80, "test_packed.py": 80,
     "test_pallas_sharded.py": 80, "test_clover.py": 80,
-    "test_clover_pallas.py": 180,
+    "test_clover_pallas.py": 290,
     "test_heatbath.py": 70, "test_build_accounting.py": 70,
     "test_schwarz.py": 70, "test_smear_force.py": 60,
     "test_clover_multishift_resident.py": 70,

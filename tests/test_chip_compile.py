@@ -152,6 +152,25 @@ def _clover_pc_k1():
             [_links(F32), _links(F32), _psi(F32), blk])
 
 
+def _clover_pc_k2_residual_bf16():
+    """The sloppy K2 call as the last kernel of a mixed-precision CG
+    iteration (PR 50): bf16 links, blocks and spinors, gamma5 in the
+    store, ``rc`` and one ``alpha`` besides, the hop sum in an f32 VMEM
+    scratch under the bf16 out tile, the new ``r`` over the old and
+    its sum of squares a second result; z-blocks of 8 rows under
+    Mosaic's default scoped limit (no ``vmem_limit_bytes``)."""
+    from quda_tpu.ops import clover_pallas as cp
+    from quda_tpu.ops import wilson_pallas_packed as wpp
+    assert wpp._pick_bz(L, YXH, BF16, planes=cp._planes(
+        3, "input", True, True)) == 8
+    blk = ((2, 6, 6, 2, L, L, YXH), BF16)
+    return (lambda u, ub, p, x, k, b, r, a: cp.dslash_eo_pallas_diag_hop(
+                u, ub, p, x, DIMS, 0, hop_coeff=k, blk_pl=b, g5=True,
+                rc=r, alpha=a),
+            [_links(BF16), _links(BF16), _psi(BF16), _psi(BF16),
+             ((), F32), blk, _psi(BF16), ((), F32)])
+
+
 def _clover_mrhs(stage, n=8, block_z=None, lat=L):
     """A fused clover MRHS kernel as the shapes route it (PR 47): at
     24^4 with the chiral blocks full-Z tiles, one time-slice a step,
@@ -260,6 +279,7 @@ CASES = {
     "staggered_eo_mrhs_n8_gather_odd": lambda: _staggered_eo_mrhs(
         "gather", 1),
     "clover_pc_k1": _clover_pc_k1,
+    "clover_pc_k2_residual_bf16": _clover_pc_k2_residual_bf16,
     "clover_mrhs_n8_post": lambda: _clover_mrhs("post"),
     "clover_mrhs_n8_diag_hop": lambda: _clover_mrhs("diag_hop"),
     "clover_mrhs_n8_diag_hop_zblock": lambda: _clover_mrhs(
@@ -456,14 +476,19 @@ def test_clover_solve_program_compiles_for_v5e_with_blocks_as_parameters(
         return sprog._cg_reliable_program.lower(hi, lo, b, 1e-6, 10000,
                                                 key=key)
     hlo = _aot(lower).as_text()
-    # every M of the loop is one post + one diag_hop kernel; the post
-    # kernel's result carries the operator's storage type (diag_hop
-    # always returns f32, the caller rounds)
+    # every M of the loop is one post + one diag_hop kernel, and each
+    # result carries its operator's storage type: the sloppy diag_hop
+    # rounds in its store (PR 50: the loop's step is the operator's,
+    # its two bf16 diag_hop calls the norm2 and the residual form, the
+    # spinor and its f32 sums a tuple)
     calls = re.findall(r"%(dslash_eo_pallas_post|dslash_eo_pallas_diag_hop)"
-                       r"[.\d]* = (\w+)\[[^\n]*tpu_custom_call", hlo)
-    post = [dt for k, dt in calls if k == "dslash_eo_pallas_post"]
+                       r"[.\d]* = (\(?)(\w+)\[[^\n]*tpu_custom_call", hlo)
+    post = [dt for k, _, dt in calls if k == "dslash_eo_pallas_post"]
     assert set(post) == {"f32", "bf16"}, calls
-    assert len(calls) == 2 * len(post) and post.count("bf16") >= 2
+    assert len(calls) == 2 * len(post) and post.count("bf16") == 2
+    assert sorted((tup, dt) for k, tup, dt in calls
+                  if k == "dslash_eo_pallas_diag_hop") == sorted(
+        [("(", "bf16")] * 2 + [("", "f32")] * (len(post) - 2)), calls
     params = _hlo_values(hlo, "parameter")
     links = ",".join(str(d) for d in _links(F32)[0])
     blocks = ",".join(str(d) for d in (2, 6, 6, 2, L, L, YXH))

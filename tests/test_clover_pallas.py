@@ -660,3 +660,100 @@ def test_batched_cg_loop_on_the_clover_operators_own_step(k2_forms):
     for i in range(3):
         assert _rel(got.x[i], want.x[i]) < 10 * tol
         assert _rel(xla.MdagM_pairs_mrhs(got.x)[i], b[i]) < 5 * tol
+
+
+# -- one source: the mixed-precision CG's step (PR 50) ---------------------
+#
+# ``dslash_eo_pallas_diag_hop`` has its ``_mrhs`` twin's forms, and
+# where it stores narrower than f32 the hop sum stays in an f32 VMEM
+# scratch: ``_SchurPairOpBase.MdagM_cg_step_pairs`` makes the first half
+# of a ``cg_reliable_loop`` iteration of them, in any storage.  Three
+# interpreted kernels a storage (``post``, the ``norm2`` and the
+# ``residual`` K2 form) and, in bf16, the parent's f32-out K2 call on
+# the step's own operands, all paid in the fixture.
+
+@pytest.fixture(scope="module", params=[
+    pytest.param(jnp.bfloat16, id="bf16"),
+    pytest.param(jnp.float32, id="f32")])
+def one_source_step(cfg, request):
+    """The fused and the XLA-stencil clover operator at one storage,
+    ``(p, r, r2)`` in it, the fused operator's own step on them, the
+    generic step of the XLA-stencil operator's ``MdagM_pairs`` and
+    what the step's first K2 call (the ``norm2`` form) returned; in
+    bf16 also ``parent``: that call again on the same operands as the
+    parent made it, an f32 result that XLA casts."""
+    from quda_tpu.ops import clover_pallas as cp
+    from quda_tpu.solvers import mixed
+    store = request.param
+    g, _ = cfg
+    dpc = DiracCloverPC(g, GEOM, KAPPA, CSW, matpc=EVEN)
+    op = dpc.pairs(store, use_pallas=True, pallas_interpret=True,
+                   form="pallas")
+    xla = dpc.pairs(store, form="xla")
+    rng = np.random.default_rng(50)
+    p, r = (jnp.asarray(rng.standard_normal((4, 3, 2, 4, 4, 8)),
+                        jnp.float32).astype(store) for _ in range(2))
+    r2 = jnp.float32(3.7)
+    want = mixed.cg_step(xla.MdagM_pairs,
+                         mixed.pair_inplace_codec(store))(p, r, r2, 0)
+    k2, calls = cp.dslash_eo_pallas_diag_hop, []
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(cp, "dslash_eo_pallas_diag_hop", lambda *a, **k: (
+            calls.append((a, k, k2(*a, **k))), calls[-1][2])[1])
+        got = op.MdagM_cg_step_pairs(p, r, r2)
+    (a, k, (q, pAp)), _ = calls
+    assert k["g5"] and k["nrm"] and k["out_dtype"] == store
+    parent = None
+    if store == jnp.bfloat16:
+        parent = k2(*a, **dict(k, g5=False, nrm=False,
+                               out_dtype=jnp.float32)).astype(store)
+    return {"store": store, "xla": xla, "p": p, "r": r, "r2": r2,
+            "want": want, "got": got, "q": q, "pAp": pAp, "parent": parent,
+            "eps": float(jnp.finfo(store).eps)}
+
+
+def _f32(v):
+    return np.asarray(v.astype(jnp.float32))
+
+
+def test_cg_step_of_one_source_on_the_fused_form_is_the_generic_step(
+        one_source_step):
+    """``MdagM_cg_step_pairs`` on the fused form against
+    ``mixed.cg_step`` of the XLA-stencil operator's ``MdagM_pairs``
+    and the in-place pair codec on the same operands: ``pAp``
+    (``|q|^2`` where the generic step takes ``p . A p``), ``alpha``,
+    the new ``r`` (rounded once from f32 where the generic step rounds
+    ``A p`` first) and its sum within the storage's rounding.  In the
+    ``xla`` form the operator offers no step (the program takes the
+    generic one)."""
+    s = one_source_step
+    got, want, eps = s["got"], s["want"], s["eps"]
+    assert [(v.shape, v.dtype) for v in got] == [
+        (v.shape, v.dtype) for v in want]
+    for g_, w in zip(got[1:], want[1:]):
+        np.testing.assert_allclose(np.asarray(g_), np.asarray(w),
+                                   rtol=max(2e-2 * eps, 1e-5))
+    assert np.max(np.abs(_f32(got[0]) - _f32(want[0]))) \
+        <= max(eps, 4e-6) * np.max(np.abs(_f32(want[0])))
+    assert s["xla"].MdagM_cg_step_pairs is None
+
+
+def test_norm2_form_of_one_source_stores_what_the_parent_stored(
+        one_source_step):
+    """The step's first ``M``: ``q`` in storage, ``pAp`` the f32 sum
+    of its squares as stored, ``q`` gamma5 of the XLA-stencil
+    operator's ``M p`` to the storage's rounding; in bf16 BITWISE
+    gamma5 of what the parent stored, the f32-out K2 call cast by XLA:
+    the f32 scratch under the bf16 out tile changes no value."""
+    s = one_source_step
+    q, pAp, store, eps = s["q"], s["pAp"], s["store"], s["eps"]
+    assert q.dtype == store and pAp.dtype == jnp.float32
+    assert float(pAp) == float(s["got"][3])
+    np.testing.assert_allclose(
+        float(pAp), float(np.sum(_f32(q).astype(np.float64) ** 2)),
+        rtol=2e-6)
+    qx = _f32(_g5(s["xla"].M_pairs(s["p"]).astype(jnp.float32)))
+    assert np.max(np.abs(_f32(q) - qx)) <= max(eps, 4e-6) * np.max(np.abs(qx))
+    if store == jnp.bfloat16:
+        assert bool(jnp.all(
+            q == _g5(s["parent"].astype(jnp.float32)).astype(store)))

@@ -364,6 +364,74 @@ def test_cached_program_equals_the_eager_solver_on_the_clover_operator():
         atol=1e-5 * float(jnp.max(jnp.abs(eager.x))))
 
 
+@pytest.mark.parametrize("fault_k", [None, 7])
+def test_solve_program_in_the_fused_form_takes_the_operators_step(
+        quda, monkeypatch, fault_k):
+    """The single-source solve program on the resident operators in the
+    fused form (PR 50): traced, not compiled.  ``cg_reliable_loop``
+    takes its step from the sloppy operator (``MdagM_cg_step_pairs``):
+    of its two ``diag_hop`` calls an iteration the first is the
+    ``norm2`` form (``pAp``), the second the ``residual`` form (the new
+    ``r`` and ``|r|^2``), counted by ``clover_route_total`` where they
+    are traced; the precise operator's ``MdagM_pairs`` (the reliable
+    update and the exit: two traces) stays ``combine``.  With a dslash
+    fault armed the program takes ``mixed.cg_step`` of ``MdagM_pairs``
+    whatever the operand offers: ``combine`` only, and the one loop
+    with its ``lax.cond`` where the operator's step runs in stretches.  The same program
+    asked for again is the traced one and counts nothing."""
+    from quda_tpu.solvers import mixed
+    from quda_tpu.solvers import program as sprog
+    monkeypatch.setenv("QUDA_TPU_PALLAS", "1")
+    monkeypatch.setenv("QUDA_TPU_CLOVER_FORM", "pallas")
+    qconf.reset_cache()
+    steps = []
+    generic = mixed.cg_step
+    monkeypatch.setattr(mixed, "cg_step", lambda mv, codec, k=None: (
+        steps.append(k), generic(mv, codec, k))[1])
+
+    def counts():
+        return {tuple(dict(l)[k] for k in ("form", "stage", "epilogue")):
+                int(v) for (n, l), v in omet.snapshot()["counters"].items()
+                if n == "clover_route_total"}
+    try:
+        api.load_clover_quda(_param())
+        hi, lo = (api._ctx["clover"]["ops"][jnp.dtype(dt)]
+                  for dt in (jnp.float32, jnp.bfloat16))
+        assert hi._op_form == lo._op_form == "pallas"
+        assert lo.MdagM_cg_step_pairs is not None
+        b = jax.ShapeDtypeStruct((4, 3, 2, L, L, L * L // 2), jnp.float32)
+        key = (0.1, mixed.pair_inplace_config(jnp.bfloat16),
+               sprog._loop_knobs(False, 100)._replace(fault_k=fault_k),
+               False)
+        trace = lambda: sprog._cg_reliable_program.trace(
+            hi, lo, b, 1e-6, 100, key=key)
+        before = counts()
+        loops = str(trace().jaxpr)
+        first = counts()
+        # the operator's step runs in stretches (a loop in a loop, no
+        # conditional), the generic step in the one loop with the
+        # reliable update's branch
+        assert (loops.count(" while["), loops.count(" cond[")) == (
+            (2, 0) if fault_k is None else (1, 1))
+        lo_calls = ({("pallas", "diag_hop", "norm2"): 1,
+                     ("pallas", "diag_hop", "residual"): 1}
+                    if fault_k is None else
+                    {("pallas", "diag_hop", "combine"): 2})
+        want = {("pallas", "post", "none"): 2 + 4,
+                ("pallas", "diag_hop", "combine"): 4}
+        for k, v in lo_calls.items():
+            want[k] = want.get(k, 0) + v
+        assert {k: v - before.get(k, 0) for k, v in first.items()
+                if v != before.get(k, 0)} == want
+        assert steps == ([] if fault_k is None else [fault_k])
+        trace()
+        assert counts() == first
+    finally:
+        monkeypatch.undo()
+        qconf.reset_cache()
+        api.free_clover_quda()
+
+
 # (e) the batched route: invert_multi_src_quda on the resident term -----------
 # PR 46.  Three sources (N is not the point), 4^4, the staged XLA form;
 # the three programs of the route are compiled once, by ``batch_warm``,

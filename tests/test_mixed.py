@@ -104,6 +104,128 @@ def test_cg_reliable_bf16_pairs_reaches_double_tol(problem):
     assert int(res.iters) < 2 * int(res_d.iters)
 
 
+def _parent_cg_reliable_loop(matvec_hi, matvec_lo, b, tol, maxiter, delta,
+                             codec):
+    """``mixed.cg_reliable_loop`` as it stood before the loop took a
+    step (PR 49's, without history, sentinel and fault): the reference
+    the generic step is held to, bit for bit."""
+    b2 = blas.norm2(b)
+    stop = (tol ** 2) * b2
+    rdt = jnp.zeros((), b.dtype).real.dtype
+    r_lo = codec.down(b)
+
+    def cond(c):
+        return jnp.logical_and(c["r2"] > stop, c["k"] < maxiter)
+
+    def body(c):
+        Ap = matvec_lo(c["p"])
+        pAp = codec.redot(c["p"], Ap).astype(rdt)
+        alpha = c["r2_lo"] / jnp.maximum(pAp, jnp.finfo(rdt).tiny)
+        x_lo = codec.axpy(alpha, c["p"], c["x_lo"])
+        r_lo, r2_new = codec.axpy_norm2(-alpha, Ap, c["r_lo"])
+        r2_new = r2_new.astype(rdt)
+        beta = r2_new / c["r2_lo"]
+        p = codec.axpy(beta, c["p"], r_lo)
+        r2max = jnp.maximum(c["r2max"], r2_new)
+        do_reliable = jnp.logical_or(r2_new < (delta ** 2) * r2max,
+                                     r2_new < stop)
+
+        def reliable(_):
+            x_new = c["x"] + codec.up(x_lo)
+            r_true = c["b"] - matvec_hi(x_new)
+            r2_true = blas.norm2_comp(r_true).astype(rdt)
+            return dict(c, x=x_new, r2=r2_true, r_lo=codec.down(r_true),
+                        p=codec.down(r_true), x_lo=jnp.zeros_like(x_lo),
+                        r2_lo=r2_true, r2max=r2_true, k=c["k"] + 1)
+
+        def keep(_):
+            return dict(c, p=p, r_lo=r_lo, x_lo=x_lo, r2_lo=r2_new,
+                        r2=r2_new.astype(rdt), r2max=r2max, k=c["k"] + 1)
+        return jax.lax.cond(do_reliable, reliable, keep, None)
+
+    r2 = b2.astype(rdt)
+    out = jax.lax.while_loop(cond, body, dict(
+        b=b, x=jnp.zeros_like(b), r2=r2, r_lo=r_lo, p=r_lo,
+        x_lo=jnp.zeros_like(r_lo), r2_lo=r2, r2max=r2, k=jnp.int32(0)))
+    x_fin = out["x"] + codec.up(out["x_lo"])
+    return x_fin, out["k"], blas.norm2_comp(b - matvec_hi(x_fin))
+
+
+def _dense_problem():
+    """A dense Hermitian positive matrix of 48 unknowns, condition
+    ~1e3, precise (complex64) and under the bf16 pair codec."""
+    from quda_tpu.ops import pair as pops
+    from quda_tpu.solvers.mixed import pair_codec
+    rng = np.random.default_rng(50)
+    n = 48
+    q, _ = np.linalg.qr(rng.standard_normal((n, n))
+                        + 1j * rng.standard_normal((n, n)))
+    a = jnp.asarray((q * np.logspace(0, 3, n)) @ q.conj().T, jnp.complex64)
+    b = jnp.asarray(rng.standard_normal(n) + 1j * rng.standard_normal(n),
+                    jnp.complex64)
+    hi = lambda x: a @ x
+    lo = lambda x: pops.to_pairs(a @ pops.from_pairs(x, jnp.complex64),
+                                 jnp.bfloat16)
+    return hi, lo, b, pair_codec(jnp.bfloat16, jnp.complex64)
+
+
+@pytest.mark.parametrize("maxiter,stretches", [
+    (400, False), (400, True), (7, True)])
+def test_loop_on_the_generic_step_is_the_parents_loop_bit_for_bit(
+        maxiter, stretches):
+    """``cg_reliable_loop`` applies a step since PR 50.  On ``cg_step``
+    of a matvec and the codec it is the loop it was: the same ``x``,
+    ``k`` and ``r2`` to the bit on a dense Hermitian positive matrix
+    under the bf16 pair codec (no kernel, 48 unknowns, condition ~1e3:
+    some forty iterations and several reliable updates).  So is the
+    form an operator's own step runs in (``stretches``: the sloppy
+    iterations in a loop of their own between reliable updates, no
+    ``lax.cond``), also where ``maxiter`` ends the solve in the middle
+    of a stretch (the update that nobody asked for folds ``x`` as the
+    exit would)."""
+    from quda_tpu.solvers.mixed import cg_reliable_loop, cg_step
+    hi, lo, b, codec = _dense_problem()
+    tol, delta = 1e-5, 0.1
+    want = jax.jit(lambda b: _parent_cg_reliable_loop(
+        hi, lo, b, tol, maxiter, delta, codec))(b)
+    got = jax.jit(lambda b: cg_reliable_loop(
+        hi, cg_step(lo, codec), b, tol, maxiter, delta, codec, False,
+        None, stretches=stretches))(b)
+    if maxiter == 7:
+        assert int(want[1]) == 7 and not bool(got.converged)
+    else:
+        assert 10 < int(want[1]) < maxiter and bool(got.converged)
+    assert int(got.iters) == int(want[1])
+    assert bool(jnp.all(got.x == want[0]))
+    assert float(got.r2) == float(want[2])
+
+
+@pytest.mark.parametrize("maxiter", [400, 7])
+def test_stretches_keep_the_history_and_the_sentinel_of_the_one_loop(
+        maxiter):
+    """With the history recorded and the breakdown sentinel in the
+    carry the two forms of ``cg_reliable_loop`` agree entry for entry:
+    residuals, reliable-update flags, iterations, verdict; where
+    ``maxiter`` cuts a stretch the update nobody asked for is in
+    neither history."""
+    from quda_tpu.robust.sentinel import Sentinel
+    from quda_tpu.solvers.mixed import cg_reliable_loop, cg_step
+    hi, lo, b, codec = _dense_problem()
+    one, two = (jax.jit(lambda b, s=s: cg_reliable_loop(
+        hi, cg_step(lo, codec), b, 1e-5, maxiter, 0.1, codec, True,
+        Sentinel(), stretches=s))(b) for s in (False, True))
+    assert int(one.iters) == int(two.iters) > 6
+    assert bool(one.converged) == bool(two.converged) == (maxiter == 400)
+    np.testing.assert_array_equal(np.asarray(one.history["r2"]),
+                                  np.asarray(two.history["r2"]))
+    np.testing.assert_array_equal(np.asarray(one.history["reliable"]),
+                                  np.asarray(two.history["reliable"]))
+    assert int(np.sum(np.asarray(one.history["reliable"]))) >= (
+        2 if maxiter == 400 else 0)
+    assert bool(jnp.all(one.x == two.x)) and float(one.r2) == float(two.r2)
+    assert int(one.breakdown) == int(two.breakdown) == 0
+
+
 def test_cg_reliable_int8_pairs_converges(problem):
     """Quarter (int8 block-float gauge) sloppy operator still converges
     under reliable updates."""
